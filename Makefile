@@ -1,5 +1,5 @@
 # Dev targets (reference: Makefile style/quality; upgraded to ruff).
-.PHONY: test test-fast test-shard1 test-shard2 test-shard3 test-multihost fleet-drill lint typecheck quality style bench bench-reference bench-smoke bench-trajectory obs-smoke acceptance-network sanitize-drill
+.PHONY: test test-fast test-shard1 test-shard2 test-shard3 test-multihost fleet-drill lint typecheck quality style bench bench-reference bench-smoke chip-smoke obs-smoke acceptance-network sanitize-drill
 
 TEST_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
@@ -22,7 +22,8 @@ test-shard1:
 	$(TEST_ENV) python -m pytest tests/ -q -m "not slow" \
 	    && $(TEST_ENV) python -m pytest -q -m slow \
 	        tests/test_flash.py tests/test_ring_attention.py tests/test_generate.py \
-	        tests/test_weight_quant.py tests/test_hf_stream.py
+	        tests/test_weight_quant.py tests/test_hf_stream.py \
+	        tests/test_decode_attention.py
 
 test-shard2:
 	$(TEST_ENV) python -m pytest -q -m slow \
@@ -76,7 +77,7 @@ lint:
 	python -m trlx_tpu.analysis trlx_tpu/
 	python -m trlx_tpu.analysis --select $(SCRIPT_LINT_RULES) \
 	    bench.py bench_smoke.py bench_decode_probe.py bench_reference.py \
-	    bench_trajectory.py obs_smoke.py acceptance_network.py
+	    bench_trajectory.py chip_smoke.py obs_smoke.py acceptance_network.py
 
 # graftrace runtime half, fully armed: the thread-heavy suites (resilience
 # fault drills, overlap pipeline, rollout engine) under
@@ -105,8 +106,18 @@ quality:
 style:
 	ruff format trlx_tpu/ tests/ examples/ bench.py
 
+# On the chip only: bench.py fails without a TPU. Its parent process stays
+# off JAX and runs one child per size, so the children get the chip.
 bench:
 	python bench.py
+
+# The quickest proof that the PPO main path still starts on the chip: every
+# Pallas kernel compiled/run/compared at the GPT-J-6B shapes, then two PPO
+# iterations through trlx_tpu.train at the flagship widths. One process per
+# chip; exits non-zero without a TPU (`--rehearsal` is the tiny CPU run,
+# `--devices 4` drives a four-chip host). ~5 min cold on a v5e.
+chip-smoke:
+	python chip_smoke.py
 
 # CPU head-to-head vs the reference's own training loop (writes HEADTOHEAD.json).
 bench-reference:
@@ -121,13 +132,6 @@ bench-reference:
 # exactly-once asserted, 2-worker speedup > 1.3x). Writes BENCH_SMOKE.json.
 bench-smoke:
 	$(TEST_ENV) python bench_smoke.py
-
-# Bench-trajectory regression gate, stdlib-only, seconds: folds the tracked
-# BENCH_r0*.json / BENCH_SMOKE.json artifacts into BENCH_TRAJECTORY.json and
-# exits 1 when samples/s/chip or train MFU regresses >10% vs the best prior
-# run with the same bench config. Non-blocking CI job.
-bench-trajectory:
-	python bench_trajectory.py
 
 # CPU observability smoke, ~1 min: a short overlapped PPO run with span
 # tracing, device telemetry, the slow_step anomaly drill, the health monitor

@@ -410,6 +410,7 @@ def test_scripts_lint_clean_with_script_rule_subset():
             "bench_decode_probe.py",
             "bench_reference.py",
             "bench_trajectory.py",
+            "chip_smoke.py",
             "obs_smoke.py",
             "acceptance_network.py",
         )

@@ -105,8 +105,8 @@ def test_indivisible_batch_and_chunk_fail_at_construction(tmp_path):
 
 
 def test_r4_train_config_fields_round_trip():
-    """watch_interval / compile_cache_dir survive dict round-trips and carry
-    their documented defaults (off)."""
+    """watch_interval survives dict round-trips and carries its documented
+    default (off)."""
     from trlx_tpu.data.configs import TRLConfig
 
     cfg = TRLConfig.from_dict(
@@ -116,13 +116,12 @@ def test_r4_train_config_fields_round_trip():
                 "total_steps": 1, "seq_length": 8, "epochs": 1, "batch_size": 2,
                 "lr_ramp_steps": 1, "lr_decay_steps": 1, "weight_decay": 0.0,
                 "learning_rate_init": 1e-3, "learning_rate_target": 1e-4,
-                "watch_interval": 7, "compile_cache_dir": "/tmp/xla-cache",
+                "watch_interval": 7,
             },
             "method": {"name": "ppoconfig"},
         }
     )
     assert cfg.train.watch_interval == 7
-    assert cfg.train.compile_cache_dir == "/tmp/xla-cache"
     default = TRLConfig.from_dict(
         {
             "model": {"model_path": "", "tokenizer_path": "", "model_type": "ppo"},
@@ -135,4 +134,3 @@ def test_r4_train_config_fields_round_trip():
         }
     )
     assert default.train.watch_interval == 0
-    assert default.train.compile_cache_dir is None

@@ -5,8 +5,8 @@ einsum decode path — including the dequant-folding identity
 (ks·dot(K_int8, q) == dot(K_int8·ks, q) up to fp32 reassociation) and the
 additive bias masking — for int8 AND bf16 caches, tile-aligned AND ragged
 cache lengths (the masked tail block), and fully-masked rows. CPU CI runs
-the same kernel code via pallas interpret mode (the on-TPU routing gate and
-lowering probe are tested separately)."""
+the same kernel code via pallas interpret mode (lowering and compiling for
+a TPU target: tests/test_tpu_lowering.py)."""
 
 import numpy as np
 import pytest
@@ -131,19 +131,17 @@ def test_pick_t_block():
 
 
 def test_eligibility_gate():
-    # off-TPU the gate must refuse (einsum path stands in CI)
-    assert not decode_attn_eligible(16, 256, 1024, True) or jax.default_backend() == "tpu"
-    if jax.default_backend() == "tpu":  # pragma: no cover — CPU CI
-        assert decode_attn_eligible(16, 256, 1024, True)
-        # masked tail: unaligned cache lengths are eligible now
-        assert decode_attn_eligible(16, 256, 831, True)
-        assert not decode_attn_eligible(16, 200, 1024, True)  # lanes not 128-aligned
-        assert not decode_attn_eligible(3, 256, 1024, True)  # sub-tile head count
+    """No shape is routed to the kernel, on any backend: it compiles and
+    matches on the chip but loses to XLA's fused einsum there
+    (decode_attention.DECODE_KERNEL_ROUTED; the head-layout and one-device
+    rules behind it are pinned in tests/test_tpu_lowering.py)."""
+    assert not decode_attn_eligible(16, 256, 1024, True)
+    assert not decode_attn_eligible(16, 256, 831, False)
 
 
 def test_supported_probe_is_cached_and_safe_off_tpu():
-    """The routing probe must answer (and cache) without a TPU: the static
-    tile check runs everywhere, the Mosaic lowering attempt only on TPU."""
+    """The routing verdict must answer (and cache) without a TPU: the static
+    tile check runs everywhere, the must-lower step only on a TPU backend."""
     from trlx_tpu.ops import decode_attention as da
 
     da._PROBE_CACHE.clear()
@@ -231,15 +229,9 @@ def test_paged_quant_matches_dequantized_gathered_einsum():
 def test_paged_eligibility_gate():
     from trlx_tpu.ops.decode_attention import paged_decode_eligible
 
-    # off-TPU the gate must refuse (the gathered einsum stands in CI)
-    on_tpu = jax.default_backend() == "tpu"
-    assert paged_decode_eligible(16, 256, 128, 8, True) == on_tpu
-    if on_tpu:  # pragma: no cover — CPU CI
-        # the bias tile: block_size % 128 unless the slot is one block
-        assert not paged_decode_eligible(16, 256, 96, 8, True)
-        assert paged_decode_eligible(16, 256, 96, 1, True)
-        assert not paged_decode_eligible(16, 200, 128, 8, True)
-        assert not paged_decode_eligible(3, 256, 128, 8, True)
+    # same verdict as the fixed-cache kernel: never routed today
+    assert not paged_decode_eligible(16, 256, 128, 8, True)
+    assert not paged_decode_eligible(16, 256, 96, 1, False)
 
 
 def test_paged_supported_probe_is_cached_and_safe_off_tpu():

@@ -158,8 +158,12 @@ def test_auto_routing_thresholds(monkeypatch):
     auto = LMConfig(attn_impl="auto")
     assert not flash_eligible(auto, 512, has_cache=False)
 
-    # On TPU, auto takes long aligned full-sequence passes only.
+    # On TPU with a one-device mesh, auto takes long aligned full-sequence
+    # passes only.
+    from trlx_tpu.parallel import mesh as mesh_mod
+
     monkeypatch.setattr(lm_mod.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", None)
     assert not flash_eligible(auto, 64, has_cache=False)  # short RLHF seqs
     assert flash_eligible(auto, 512, has_cache=False)
     assert flash_eligible(auto, 768, has_cache=False)  # 128-aligned, non-512
@@ -169,6 +173,10 @@ def test_auto_routing_thresholds(monkeypatch):
     assert flash_eligible(auto, 512, has_cache=True, prefill_at_zero=True)
     assert not flash_eligible(auto, 64, has_cache=True, prefill_at_zero=True)
     assert not flash_eligible(auto, 300, has_cache=False)  # unaligned
+    # a mesh larger than one closes the auto gate (no kernel is under
+    # shard_map; jax refuses to partition a Mosaic call)
+    monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", mesh_mod.make_mesh([2, 1, 1, 1], jax.devices()[:2]))
+    assert not flash_eligible(auto, 512, has_cache=False)
     forced = LMConfig(attn_impl="flash")
     assert flash_eligible(forced, 48, has_cache=False)
     assert not flash_eligible(LMConfig(attn_impl="xla"), 512, has_cache=False)

@@ -97,42 +97,6 @@ def test_watch_interval_logs_grad_norms_and_histograms(tmp_path):
     assert hist_names, "no parameter histograms logged"
 
 
-def test_compile_cache_dir_populates(tmp_path):
-    """train.compile_cache_dir: trainer construction with the knob set drops
-    compiled programs into the persistent cache (warm restarts skip the
-    cold-start compile measured in the head-to-head).
-
-    Isolation: JAX's persistent cache binds to the FIRST directory it was
-    initialized with for the life of the process, so the trainer resets it
-    when the configured dir changes (trainer/base.py) and this test restores
-    the unconfigured state on exit so later tests never write into this
-    test's (deleted) tmp_path."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    cache = tmp_path / "xla_cache"
-    # A warm process compiles these tiny programs in well under the persistent
-    # cache's default min-compile-time threshold (1s), which silently skips
-    # the write — the other half of the original order-dependent flake.
-    min_compile = jax.config.jax_persistent_cache_min_compile_time_secs
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        trainer = _tiny_trainer(tmp_path, **{"train.compile_cache_dir": str(cache)})
-        # run one compiled program so at least one entry lands
-        rng = np.random.default_rng(0)
-        P = trainer.prompt_length
-        trainer.sample(
-            {"input_ids": rng.integers(1, 15, size=(8, P)).astype(np.int32),
-             "attention_mask": np.ones((8, P), np.int32)},
-            n_samples=8,
-        )
-        assert cache.exists() and any(cache.iterdir()), "compile cache stayed empty"
-    finally:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_compile)
-        jax.config.update("jax_compilation_cache_dir", None)
-        cc.reset_cache()
-
-
 def test_ppo_headtohead_assets_round_trip(tmp_path):
     """Guards bench_reference.py's PPO harness against bitrot: the shared
     init checkpoint + char tokenizer build offline, the tokenizer encodes/

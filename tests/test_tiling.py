@@ -1,11 +1,13 @@
 """Tier-1 (fast, CPU) static tile-legality tests for the Pallas kernels.
 
 The Mosaic last-two-dims (8, 128)-or-full rule only bites at lowering time
-on a real TPU — exactly how the old decode-attention kernel's (1, 1, d)
+for a TPU — exactly how the old decode-attention kernel's (1, 1, d)
 blocks survived CPU CI and then crashed BENCH_r05 mid-bench. These tests
 run the rule statically at the REAL bench shapes (B=32, h=16, d=256,
 T=832), so an illegal block mapping in ops/ fails the fast tier without
-any TPU."""
+any TPU. The rule is the first of Mosaic's checks, not the last:
+tests/test_tpu_lowering.py lowers and compiles the same kernels for a TPU
+target."""
 
 import pytest
 
@@ -65,10 +67,12 @@ def test_old_decode_specs_are_rejected():
 def test_new_decode_specs_are_legal_at_bench_shape(quant):
     layouts = decode_block_layout(BENCH_B, BENCH_T, BENCH_H, BENCH_D, quant)
     check_layout(layouts)  # raises on violation
-    # the q/out blocks really are the full [n_head, head_dim] planes
+    # the cache streams in lane-dense [bt, n_head * head_dim] blocks and the
+    # q/out rows carry every head
     by_name = {l.name: l for l in layouts}
-    assert by_name["q"].block_shape == (1, BENCH_H, BENCH_D)
-    assert by_name["out"].block_shape == (1, BENCH_H, BENCH_D)
+    assert by_name["k_cache"].block_shape == (1, 128, BENCH_H * BENCH_D)
+    assert by_name["q"].block_shape == (1, 1, BENCH_H * BENCH_D)
+    assert by_name["out"].block_shape == (1, 1, BENCH_H * BENCH_D)
 
 
 @pytest.mark.parametrize(
@@ -96,8 +100,9 @@ def test_flash_specs_legal_at_bench_shape():
 
 def test_routing_probe_refuses_illegal_layout(monkeypatch):
     """decode_attn_supported answers False (with a warning, once) when the
-    static layout check fails — the einsum fallback path in the model layer
-    keys off this instead of crashing in Mosaic."""
+    static layout check fails — a stated CPU-side rule the model layer's
+    einsum route keys off. (What passes the rule must lower on a TPU
+    backend: tests/test_tpu_lowering.py.)"""
     import warnings
 
     from trlx_tpu.ops import decode_attention as da
@@ -160,8 +165,8 @@ def test_fused_logprob_layout_rejects_unaligned_vocab_tile():
 
 def test_fused_probe_refuses_illegal_layout(monkeypatch):
     """fused_logprob_supported answers False (with a warning, once) when the
-    static layout check fails — the model's head routing keys off this
-    instead of crashing in Mosaic mid-train."""
+    static layout check fails — the stated rule the model's head routing
+    keys off."""
     import warnings
 
     from trlx_tpu.ops import fused_logprob as fl

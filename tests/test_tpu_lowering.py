@@ -1,0 +1,169 @@
+"""Every Pallas entry point in ops/ lowers — and compiles — for a TPU, on CPU.
+
+The static tile rule (tests/test_tiling.py) is only the first of Mosaic's
+checks. Twice a kernel passed it, passed its interpret-mode parity tests and
+could not run on the chip: a block mapping the lowering rejected, then a
+batched matrix-vector `dot_general` Mosaic cannot express (the decode
+kernels, three PRs deep, hidden by a probe that caught the exception and
+routed to einsum). Neither needs a chip to find:
+
+- `jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))` runs the
+  jaxpr → Mosaic lowering for a TPU target in seconds on a CPU;
+- `jax.experimental.topologies` describes a v5e host to the installed
+  libtpu, and `.lower(...).compile()` against it runs the REST of the
+  compiler — Mosaic's layout passes and the scoped-VMEM limit — with no
+  device attached.
+
+Shapes are the GPT-J-6B ones chip_smoke.py runs (flagship train batch,
+decode chunk, logprob head). Run this before spending chip time on a kernel.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from trlx_tpu.ops.decode_attention import (
+    DECODE_KERNEL_ROUTED,
+    decode_attention,
+    decode_attn_eligible,
+    paged_decode_attention,
+    paged_decode_eligible,
+)
+from trlx_tpu.ops.flash_attention import flash_attention
+from trlx_tpu.ops.fused_logprob import fused_logprob
+
+B, T, H, D = 8, 1024, 16, 256  # train batch, seq, heads x head_dim
+C = 32  # rollout chunk (decode batch)
+N, DM, V = 2056, 4096, 50400  # logprob head: 8 x 257 rows, d_model, vocab
+BLOCKS, BS, BPS = 256, 128, 8  # paged pool
+SCALE = 1.0 / 16.0
+
+
+def _flash_fwd(q, k, v, m):
+    return flash_attention(q, k, v, m, scale=SCALE, causal=True, interpret=False)
+
+
+def _flash(q, k, v, m):
+    """value_and_grad: the forward kernel and both backward kernels."""
+    loss = lambda q, k, v: _flash_fwd(q, k, v, m).astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _fused(x, w, y, b):
+    """value_and_grad: the forward kernel and both backward kernels."""
+    loss = lambda x, w, b: sum(
+        o.sum() for o in fused_logprob(x, w, y, b, tied=False, interpret=False)
+    )
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w, b)
+
+
+def _decode(q, k, v, ks, vs, bias):
+    return decode_attention(q, k, v, ks, vs, bias, scale=SCALE, interpret=False)
+
+
+def _paged(q, k, v, ks, vs, tbl, bias):
+    return paged_decode_attention(q, k, v, ks, vs, tbl, bias, scale=SCALE, interpret=False)
+
+
+def _cases(s):
+    """(name, fn, abstract args) with `s(shape, dtype)` building each arg."""
+    bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+    qkv = s((B, T, H, D), bf16)
+    head = (s((N, DM), bf16), s((DM, V), bf16), s((N,), i32), s((V,), bf16))
+    cases = [
+        ("flash fwd+bwd", _flash, (qkv, qkv, qkv, s((B, T), f32))),
+        ("fused_logprob fwd+bwd", _fused, head),
+    ]
+    for quant in (False, True):
+        kind = "int8" if quant else "bf16"
+        # the int8 case at a ragged cache length: the masked tail block
+        Tc = T - 191 if quant else T
+        kv = s((C, Tc, H, D), i8 if quant else bf16)
+        sc = s((C, Tc, H), f32) if quant else None
+        cases.append(
+            (f"decode {kind} T={Tc}", _decode,
+             (s((C, H, D), bf16), kv, kv, sc, sc, s((C, Tc), f32)))
+        )
+        pool = s((BLOCKS, BS, H, D), i8 if quant else bf16)
+        psc = s((BLOCKS, BS, H), f32) if quant else None
+        cases.append(
+            (f"paged decode {kind}", _paged,
+             (s((C, H, D), bf16), pool, pool, psc, psc, s((C, BPS), i32), s((C, BPS * BS), f32)))
+        )
+    return cases
+
+
+CASE_NAMES = [name for name, _, _ in _cases(jax.ShapeDtypeStruct)]
+
+
+@pytest.mark.parametrize("name", CASE_NAMES)
+def test_kernel_lowers_for_tpu(name):
+    """jaxpr → Mosaic for a TPU target. Catches the block-mapping class of
+    failure and the dot_general-Mosaic-cannot-express class."""
+    (fn, args), = [(f, a) for n, f, a in _cases(jax.ShapeDtypeStruct) if n == name]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+@pytest.fixture(scope="module")
+def v5e_sharding():
+    """A one-device sharding on a described (not attached) v5e host."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    except Exception as e:  # noqa: BLE001 — no libtpu to describe a TPU with
+        pytest.skip(f"no TPU topology description available here: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("name", CASE_NAMES)
+def test_kernel_compiles_for_v5e(name, v5e_sharding):
+    """The whole compiler, device-free: Mosaic's layout passes and the
+    scoped-VMEM limit run at compile(), which lowering alone never reaches."""
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+    (fn, args), = [(f, a) for n, f, a in _cases(s) if n == name]
+    jax.jit(fn).lower(*args).compile()
+
+
+def test_mosaic_kernels_refuse_a_multi_device_jit(v5e_sharding):
+    """Why every model-layer gate requires a one-device mesh
+    (flash_attention.one_device_tpu): jax will not partition a Mosaic call."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    sh = NamedSharding(Mesh(np.array(topo.devices), ("dp",)), PartitionSpec("dp"))
+    qkv = jax.ShapeDtypeStruct((B, 256, H, D), jnp.bfloat16, sharding=sh)
+    mask = jax.ShapeDtypeStruct((B, 256), jnp.float32, sharding=sh)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(_flash_fwd).lower(qkv, qkv, qkv, mask)
+
+
+def test_decode_kernels_are_ineligible_by_the_measured_rule():
+    """The decode kernels lower, compile and match einsum, and lose to it on
+    the chip (PERF.md): no shape is routed to them, on any backend."""
+    assert DECODE_KERNEL_ROUTED is False
+    assert not decode_attn_eligible(H, D, T, True)
+    assert not decode_attn_eligible(H, D, T, False)
+    assert not paged_decode_eligible(H, D, BS, BPS, True)
+
+
+def test_one_device_rule(monkeypatch):
+    """On a TPU backend the gates open on a one-device mesh and close on a
+    larger one; off TPU they are closed."""
+    from trlx_tpu.ops import flash_attention as fa
+    from trlx_tpu.ops.fused_logprob import fused_logprob_eligible
+    from trlx_tpu.parallel import mesh as mesh_mod
+
+    assert not fa.one_device_tpu()  # the CPU backend of this test
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", None)
+    assert fa.one_device_tpu() and fused_logprob_eligible(DM, V)
+    monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", mesh_mod.make_mesh([1, 1, 1, 1], jax.devices()[:1]))
+    assert fa.one_device_tpu()
+    monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", mesh_mod.make_mesh([4, 1, 1, 1], jax.devices()[:4]))
+    assert not fa.one_device_tpu() and not fused_logprob_eligible(DM, V)

@@ -126,11 +126,6 @@ class TrainConfig:
     # example watches the model (reference:
     # examples/ppo_softprompt_sentiments.py:38-39).
     watch_interval: int = 0
-    # Persistent XLA compilation cache directory (None = off). A warm cache
-    # removes the one-time compile cost from restarts/resumes — measured on
-    # the CPU head-to-head it was the entire cold-start gap (BASELINE.md r4:
-    # 0.995x cold vs 1.117x warm).
-    compile_cache_dir: Optional[str] = None
 
     # --- resilience (trlx_tpu/resilience/) ---
     # On-device non-finite guard: the jitted train step skips the parameter
@@ -234,7 +229,8 @@ class TrainConfig:
     # each monitored program's first dispatch and derive per-window
     # obs/train_mfu_pct + kernel-routing/device-memory gauges in
     # metrics.jsonl. One synchronous AOT compile per program at first
-    # dispatch (absorbed by compile_cache_dir when set).
+    # dispatch (absorbed by the persistent compile cache,
+    # utils/compile_cache.py).
     # TRLX_TPU_DEVICE_TELEMETRY=1 overrides to on.
     device_telemetry: bool = False
     # Anomaly capture: a step slower than anomaly_factor × rolling-p50 step

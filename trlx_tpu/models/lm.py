@@ -210,16 +210,14 @@ def flash_eligible(cfg: LMConfig, q_len: int, has_cache: bool, prefill_at_zero: 
     """
     if cfg.attn_impl not in ("auto", "flash", "xla"):
         raise ValueError(f"attn_impl must be auto|flash|xla, got {cfg.attn_impl!r}")
-    from trlx_tpu.ops.flash_attention import _HAVE_PLTPU
-
-    if cfg.attn_impl == "xla" or not _HAVE_PLTPU:
+    if cfg.attn_impl == "xla":
         return False
     if has_cache and not (q_len > 1 and prefill_at_zero):
         return False
     if cfg.attn_impl == "auto":
-        from trlx_tpu.ops.flash_attention import auto_flash_ok
+        from trlx_tpu.ops.flash_attention import auto_flash_ok, one_device_tpu
 
-        return auto_flash_ok(q_len)
+        return one_device_tpu() and auto_flash_ok(q_len)
     return True
 
 
@@ -462,10 +460,10 @@ class Attention(nn.Module):
                     return buf
 
             def kernel_ok(quant):
-                # Two gates, both static at trace time: the cheap eligibility
-                # rule, then the one-time cached lowering probe — a shape the
-                # Mosaic lowering rejects warns and takes the einsum path
-                # instead of crashing the compiled rollout program mid-run.
+                # Two gates, both static at trace time: the eligibility rule,
+                # then the cached tile check. On a TPU backend a shape that
+                # passes both and still does not lower is an error there,
+                # not an einsum fallback.
                 if paged:
                     return paged_decode_eligible(
                         cfg.n_head, hd, blk, bps, quant

@@ -23,8 +23,8 @@ Capture cost and safety:
   dispatch, BEFORE calling ``fn`` (donated buffers are still alive then).
   Tracing is shared with the call path (the jaxpr cache), so no re-trace;
   the AOT ``compile()`` may duplicate the executable build once per program
-  — a one-time cost that the persistent compile cache absorbs when
-  ``train.compile_cache_dir`` is set. Programs whose capture fails (e.g. a
+  — a one-time cost that the persistent compile cache
+  (utils/compile_cache.py) absorbs. Programs whose capture fails (e.g. a
   fn that is not lowerable) record the error and keep running unmonitored.
 - The wrapper delegates attribute access to the wrapped fn, so decorated
   closures keep their public surface (``make_generate_fn``'s ``num_traces``
@@ -45,7 +45,8 @@ import numpy as np
 
 __all__ = [
     "DeviceMonitor",
-    "PEAK_TFLOPS",
+    "CHIP_PEAKS",
+    "chip_peaks",
     "detect_peak_flops",
     "kernel_routing_gauges",
     "device_memory_gauges",
@@ -54,26 +55,41 @@ __all__ = [
 
 PROGRAMS_FILENAME = "programs.json"
 
-# Peak dense bf16 TFLOP/s per chip by device-kind prefix. Keep in sync with
-# bench.py's PEAK_TFLOPS (duplicated, not imported: bench.py is a CLI script
-# whose import would drag its argparse surface into the library).
-PEAK_TFLOPS = {
-    "TPU v6": 918.0,
-    "TPU v5p": 459.0,
-    "TPU v5e": 197.0,
-    "TPU v5": 197.0,
-    "TPU v4": 275.0,
-    "TPU v3": 123.0,
-    "TPU v2": 45.0,
+# The one table of chip peaks (bench.py reads it too), keyed by the
+# device_kind JAX reports: (dense bf16 TFLOP/s, HBM GB/s) per chip, from
+# Google Cloud's per-generation TPU documentation. A TPU that is not here is
+# an error, not a default — add its row with its source.
+CHIP_PEAKS = {
+    "TPU v2": (45.0, 700),
+    "TPU v3": (123.0, 900),
+    "TPU v4": (275.0, 1228),
+    "TPU v5 lite": (197.0, 819),  # v5e, as jax 0.9 / libtpu 0.0.34 name it
+    "TPU v5e": (197.0, 819),
+    "TPU v5p": (459.0, 2765),
+    "TPU v6 lite": (918.0, 1638),  # v6e (Trillium)
+    "TPU v6e": (918.0, 1638),
 }
 
 
-def detect_peak_flops():
-    """Peak per-chip FLOP/s, or None when unknown (CPU, new TPU kind).
+def chip_peaks(device_kind: str):
+    """(bf16 TFLOP/s, HBM GB/s) for a device_kind; raises on an unknown one."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"device_kind {device_kind!r} is not in devicemon.CHIP_PEAKS "
+            f"(known: {sorted(CHIP_PEAKS)}) — add its published peaks; an MFU "
+            "against a guessed peak is not a measurement"
+        ) from None
 
-    ``TRLX_TPU_PEAK_TFLOPS`` overrides the table — the only way to get an
-    MFU gauge on CPU smoke runs, and the escape hatch for hardware the
-    table postdates."""
+
+def detect_peak_flops():
+    """Peak per-chip FLOP/s of this process's device: from CHIP_PEAKS on a
+    TPU (an unknown kind raises), None on any other platform — a CPU has no
+    row, so no MFU gauge is derived there.
+
+    ``TRLX_TPU_PEAK_TFLOPS`` overrides both — the only way to get an MFU
+    gauge on CPU smoke runs."""
     env = os.environ.get("TRLX_TPU_PEAK_TFLOPS")
     if env:
         try:
@@ -82,11 +98,10 @@ def detect_peak_flops():
             pass
     import jax
 
-    kind = jax.devices()[0].device_kind
-    for prefix, tflops in PEAK_TFLOPS.items():
-        if kind.startswith(prefix):
-            return tflops * 1e12
-    return None
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    return chip_peaks(device.device_kind)[0] * 1e12
 
 
 def _signature(args, kwargs) -> tuple:
@@ -189,8 +204,6 @@ class DeviceMonitor:
         try:
             compiled = fn.lower(*args, **kwargs).compile()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # backend-version dependent
-                cost = cost[0] if cost else {}
             rec["flops"] = float(cost.get("flops", 0.0) or 0.0)
             rec["bytes_accessed"] = float(cost.get("bytes accessed", 0.0) or 0.0)
             mem = compiled.memory_analysis()

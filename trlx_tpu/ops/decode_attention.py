@@ -1,75 +1,75 @@
 """Pallas TPU flash-decode attention over the (int8) KV cache.
 
 Decode is HBM-bound on cache reads. The XLA einsum path for a decode step
-dequantizes the int8 cache into materialized bf16 k/v before the
-contraction (trlx_tpu/models/lm.py Attention decode branch) — measured on a
-v5e, that costs ~387 us/layer/step at [B=32, T=832, h=16, d=256] against an
-int8-bytes floor of ~266 us (DECODE_PROBE.json: ~4.7 ms/step of decode time
-the byte model couldn't explain). This kernel reads the int8 cache
-DIRECTLY and folds dequantization into the attention algebra, so the HBM
-traffic is exactly the int8 bytes:
+dequantizes the int8 cache into bf16 k/v before the contraction
+(trlx_tpu/models/lm.py Attention decode branch). This kernel reads the int8
+cache DIRECTLY and folds dequantization into the attention algebra, so the
+HBM traffic is exactly the int8 bytes:
 
     scores[t] = ks[t] * dot(K_int8[t, :], q) * scale       (per-key scale
     out[d]    = sum_t softmax(scores)[t] * vs[t] * V_int8[t, d]   factors out)
 
-Grid (batch, T-blocks): each program carries ALL heads — the blocks' last
-two dims are the full [n_head, head_dim] (16 x 256 at the bench config),
-which satisfies the Mosaic last-two-dims (8, 128)-or-full tiling rule by
-construction. (The previous revision walked a (batch, head) grid with
-per-head (1, 1, d) q blocks and whole-cache (1, T, 1, d) KV blocks; those
-singleton trailing dims cannot lower — the exact ValueError that crashed
-BENCH_r05 at the flagship size.) The cache streams through VMEM in
-fixed-size T-blocks with online-softmax running max/sum scratch, so
-arbitrarily long caches fit VMEM, and the final (possibly partial) block is
-masked in-kernel — cache lengths need NOT be tile-aligned anymore.
+A decode step is a matrix-VECTOR product per head: the query has one row,
+so there is nothing for the MXU to amortize, and Mosaic has no batched
+matvec (a `dot_general` whose left operand has no free dimension, or whose
+batch dimension sits in the middle of the right operand, does not lower —
+the failure the [bt, h, d] revision of this kernel died of on jax 0.9).
+The contractions therefore run on the VPU, in the one layout that needs no
+relayout of the cache:
 
-Operand layout notes: per-key int8 scales arrive as [B, T, h] cache columns
-and are transposed to [B, h, T] in the wrapper (an XLA transpose of <1% of
-the cache bytes) so the kernel's scale block is (1, h, bt) — head-major
-like the score matrix, no in-kernel transpose. The bias row is lifted to
-[B, 1, T] for the same reason: a (1, bt) block of a [B, T] array has an
-illegal singleton sublane dim, a (1, 1, bt) block of [B, 1, T] is full/
-divisible. The block layouts live in tiling.decode_block_layout — the
-validator and this wrapper read the SAME description, and the routing layer
-(decode_attn_supported) re-checks it plus a one-time real lowering probe
-before ever tracing the kernel, warning and falling back to einsum instead
-of killing a run mid-bench.
+- the cache [B, T, h, d] is viewed as [B, T, h*d] (a free reshape) and
+  streams through VMEM in (bt, h*d) blocks — lane-dense for any head count,
+  no int8 sublane padding of a 16-row head plane;
+- head `hh` is the lane-aligned static slice [:, hh*d:(hh+1)*d] (d % 128
+  == 0 is the eligibility rule), so per head the scores are a broadcast
+  multiply by q and a lane reduction → a [bt, 1] column, and the value
+  contraction is a lane-broadcast multiply and a sublane reduction;
+- the per-key scales are read in their natural [bt, h] cache layout and the
+  online softmax runs on a [bt, h] matrix (keys on sublanes, heads on
+  lanes) with [1, h] running max/sum scratch.
 
-Masking is the same additive bias row the einsum path uses. Inference-only
-(decode never differentiates) — no VJP.
+Grid (batch, T-blocks); the T walk is the online-softmax accumulation order
+and stays sequential, and the final (possibly partial) block is masked
+in-kernel, so cache lengths need not be tile-aligned. The additive bias row
+([B, T], the einsum path's mask) arrives as [B, 1, T] — a (1, 1, bt) block
+of it is tile-legal where a (1, bt) block of [B, T] is not — and is turned
+into the [bt, 1] column the score matrix needs by a masked lane reduction.
+The block layouts live in tiling.decode_block_layout / paged_decode_layout:
+the validator and these wrappers read the SAME description.
 
-The reference has no counterpart (HF `generate` materializes fp16 caches,
-reference: trlx/model/accelerate_base_model.py:105-116); this is the
-TPU-native design the hardware wants. Engagement mirrors flash_attention:
-real TPU backend, else the einsum path stands (interpret mode keeps CPU CI
-coverage, tests/test_decode_attention.py).
+Routing (`*_eligible` then `*_supported`) is static: the measured verdict
+DECODE_KERNEL_ROUTED (today: False — XLA's fused einsum is faster on the
+chip), backend, a one-device mesh, the head layout, and the CPU-side tile
+check. On a TPU backend an eligible shape that then fails to lower is an
+ERROR naming the kernel and the shape (tiling.require_lowering) — never a
+quiet einsum fallback.
+
+Inference-only (decode never differentiates) — no VJP. The reference has no
+counterpart (HF `generate` materializes fp16 caches, reference:
+trlx/model/accelerate_base_model.py:105-116). Interpret mode keeps CPU
+coverage (tests/test_decode_attention.py).
 """
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from trlx_tpu.ops.flash_attention import (
-    _HAVE_PLTPU,
     M_INIT,
     MASK_VAL,
     _interpret_default,
     _scratch,
-    pl,
+    one_device_tpu,
 )
+from trlx_tpu.ops.flash_attention import _compiler_params as _grid_compiler_params
+from trlx_tpu.ops.flash_attention import _vmem_spec as _vmem
 
-if _HAVE_PLTPU:  # pragma: no branch
-    from jax.experimental.pallas import tpu as pltpu
-else:  # pragma: no cover
-    pltpu = None
-
-# Default KV T-block: 128 slots/block keeps the double-buffered int8 k+v
-# blocks plus their fp32 compute copies comfortably inside ~16 MB VMEM at
-# the bench head layout (128*16*256 int8 = 512 KB/block), and 128 divides
-# the lane tile so the scale/bias blocks stay legal when the cache is
-# longer than one block.
+# Default KV T-block: 128 slots/block is one lane tile of keys — the
+# double-buffered int8 k+v blocks at the GPT-J head layout are 2 x 512 KB
+# each and the per-head fp32 working set stays in vregs.
 BLOCK_T = 128
 
 
@@ -80,228 +80,165 @@ def pick_t_block(cache_len: int, block_t: int = BLOCK_T) -> int:
     return cache_len if cache_len <= block_t else block_t
 
 
-def _vmem(shape, index_map):
-    if _HAVE_PLTPU:
-        return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
-    return pl.BlockSpec(shape, index_map)
+# batch parallel; the T-block walk is the online-softmax accumulation order
+# and must stay sequential.
+_compiler_params = functools.partial(
+    _grid_compiler_params, semantics=("parallel", "arbitrary")
+)
 
 
-def _compiler_params(interpret):
-    """batch parallel; the T-block walk is the online-softmax accumulation
-    order and must stay sequential."""
-    if not _HAVE_PLTPU or interpret:
-        return {}
-    return {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    }
+def _decode_kernel(*refs, scale, T, bt, h, d, quant, paged):
+    """One T-block of online-softmax decode attention, all heads.
 
+    Refs (blocks): q [1, 1, h*d]; k/v [1, bt, h*d] (int8 or compute dtype);
+    ks/vs [1, bt, h] per-key scales (quant only); bias [1, 1, bt] additive
+    mask row; out [1, 1, h*d]; scratch acc [1, h*d], m/l [1, h] fp32. The
+    paged variant's leading scalar-prefetched block table is consumed by
+    the BlockSpec index maps, never by the body: the virtual walk `it` is
+    all the tail masking needs."""
+    if paged:
+        refs = refs[1:]
+    if quant:
+        q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        q_ref, k_ref, v_ref, bias_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        ks_ref = vs_ref = None
+    it = pl.program_id(1)
+    nt = pl.num_programs(1)
 
-def _decode_block(q, k, v, ks, vs, bias, it, acc_ref, m_ref, l_ref, *, scale, T, bt):
-    """One T-block of online-softmax decode attention, all heads at once.
+    @pl.when(it == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, M_INIT)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q: [h, d] fp32. k/v: [bt, h, d] (int8 or compute dtype). ks/vs: [h, bt]
-    fp32 per-key scales or None. bias: [1, bt] fp32 additive mask row."""
-    # scores[h, t] = sum_d q[h, d] * k[t, h, d] — batched over heads.
-    scores = jax.lax.dot_general(
-        q,
-        k.astype(jnp.float32),
-        (((1,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32,
-    )  # [h, bt]
+    def head(hh):
+        return slice(hh * d, (hh + 1) * d)
+
+    # scores[t, hh] = sum_d k[t, hh, d] * q[hh, d]: per head a [bt, 1]
+    # column, placed into lane hh of the [bt, h] score matrix.
+    lane_h = jax.lax.broadcasted_iota(jnp.int32, (bt, h), 1)
+    scores = jnp.zeros((bt, h), jnp.float32)
+    for hh in range(h):
+        k_h = k_ref[0, :, head(hh)].astype(jnp.float32)
+        q_h = q_ref[0, :, head(hh)].astype(jnp.float32)
+        s_col = jnp.sum(k_h * q_h, axis=-1, keepdims=True)
+        scores = jnp.where(lane_h == hh, s_col, scores)
     scores = scores * scale
-    if ks is not None:
-        scores = scores * ks  # per-key int8 k scale, factored out of the dot
-    scores = scores + bias
+    if quant:
+        scores = scores * ks_ref[0].astype(jnp.float32)  # factored-out k scale
+    # Bias row [1, bt] → column [bt, 1]: a masked lane reduction over the
+    # diagonal (exact: one term plus zeros).
+    diag = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 0) == jax.lax.broadcasted_iota(
+        jnp.int32, (bt, bt), 1
+    )
+    scores = scores + jnp.sum(jnp.where(diag, bias_ref[0], 0.0), axis=-1, keepdims=True)
     # Tail mask: slots past the cache end exist only as block padding. Their
     # memory is undefined (int8 garbage / non-finite scale garbage), so the
     # score is REPLACED, not biased, and p is re-zeroed after the exp (a
     # fully-masked row has m == MASK_VAL, where exp(MASK_VAL - m) == 1).
-    kpos = it * bt + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    in_range = kpos < T
-    scores = jnp.where(in_range, scores, MASK_VAL)
+    ragged = T % bt != 0
+    if ragged:
+        in_range = it * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0) < T
+        scores = jnp.where(in_range, scores, MASK_VAL)
 
-    m_prev = m_ref[:, :1]
-    m_cur = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+    m_prev = m_ref[...]
+    m_cur = jnp.maximum(m_prev, jnp.max(scores, axis=0, keepdims=True))
     alpha = jnp.exp(m_prev - m_cur)
     p = jnp.exp(scores - m_cur)
-    p = jnp.where(in_range, p, 0.0)
-    l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-    if vs is not None:
+    if ragged:
+        p = jnp.where(in_range, p, 0.0)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+    m_ref[...] = m_cur
+    if quant:
         # per-key int8 v scale, folded into the weights — zeroed on tail
         # padding, where the scale memory is undefined (0 * NaN would
         # poison the contraction that p's zeros alone cannot protect).
-        p = p * jnp.where(in_range, vs, 0.0)
-    # out[h, d] += sum_t p[h, t] * v[t, h, d]. Tail-padding v rows are
-    # undefined memory: zero them so they cannot reach the accumulator
-    # even multiplied by a zero weight.
-    t_valid = (
-        it * bt + jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], 1, 1), 0) < T
-    )
-    vf = jnp.where(t_valid, v.astype(jnp.float32), 0.0)
-    pv = jax.lax.dot_general(
-        p,
-        vf,
-        (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32,
-    )  # [h, d]
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-
-def _finalize(o_ref, acc_ref, l_ref):
-    # l == 0 cannot happen for in-range keys (even fully-masked rows sum
-    # positive p), but guard the division like the flash kernel does.
-    l_safe = jnp.maximum(l_ref[:, :1], 1e-30)
-    o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-
-
-def _kernel_quant(q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, scale, T, bt):
-    it = pl.program_id(1)
-    nt = pl.num_programs(1)
-
-    @pl.when(it == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, M_INIT)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    _decode_block(
-        q_ref[0].astype(jnp.float32),
-        k_ref[0],
-        v_ref[0],
-        ks_ref[0].astype(jnp.float32),
-        vs_ref[0].astype(jnp.float32),
-        bias_ref[0],
-        it,
-        acc_ref,
-        m_ref,
-        l_ref,
-        scale=scale,
-        T=T,
-        bt=bt,
-    )
+        vs = vs_ref[0].astype(jnp.float32)
+        p = p * (jnp.where(in_range, vs, 0.0) if ragged else vs)
+    # out[hh, d] += sum_t p[t, hh] * v[t, hh, d]. Tail-padding v rows are
+    # undefined memory: zero them so they cannot reach the accumulator even
+    # multiplied by a zero weight.
+    for hh in range(h):
+        v_h = v_ref[0, :, head(hh)].astype(jnp.float32)
+        if ragged:
+            v_h = jnp.where(in_range, v_h, 0.0)
+        pv = jnp.sum(p[:, hh : hh + 1] * v_h, axis=0, keepdims=True)
+        acc_ref[:, head(hh)] = acc_ref[:, head(hh)] * alpha[:, hh : hh + 1] + pv
 
     @pl.when(it == nt - 1)
     def _():
-        _finalize(o_ref, acc_ref, l_ref)
+        # l == 0 cannot happen for in-range keys (even fully-masked rows sum
+        # positive p), but guard the division like the flash kernel does.
+        l_safe = jnp.maximum(l_ref[...], 1e-30)
+        for hh in range(h):
+            o_ref[0, :, head(hh)] = (
+                acc_ref[:, head(hh)] / l_safe[:, hh : hh + 1]
+            ).astype(o_ref.dtype)
 
 
-def _kernel_plain(q_ref, k_ref, v_ref, bias_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, scale, T, bt):
-    it = pl.program_id(1)
-    nt = pl.num_programs(1)
-
-    @pl.when(it == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, M_INIT)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    _decode_block(
-        q_ref[0].astype(jnp.float32),
-        k_ref[0],
-        v_ref[0],
-        None,
-        None,
-        bias_ref[0],
-        it,
-        acc_ref,
-        m_ref,
-        l_ref,
-        scale=scale,
-        T=T,
-        bt=bt,
-    )
-
-    @pl.when(it == nt - 1)
-    def _():
-        _finalize(o_ref, acc_ref, l_ref)
+def _scratch_shapes(h, d):
+    return [
+        _scratch((1, h * d)),  # fp32 output accumulator
+        _scratch((1, h)),  # running max
+        _scratch((1, h)),  # running sum
+    ]
 
 
-def _paged_kernel_quant(tbl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref,
-                        o_ref, acc_ref, m_ref, l_ref, *, scale, T, bt):
-    """Block-table-indirect variant: identical online-softmax body, but the
-    K/V (and scale) operands were fetched by the BlockSpec index maps through
-    the scalar-prefetched table, so the kernel itself never sees a physical
-    block id — the virtual walk `it` is all it needs for tail masking."""
-    del tbl_ref  # consumed by the index maps, not the body
-    _kernel_quant(q_ref, k_ref, v_ref, ks_ref, vs_ref, bias_ref, o_ref,
-                  acc_ref, m_ref, l_ref, scale=scale, T=T, bt=bt)
-
-
-def _paged_kernel_plain(tbl_ref, q_ref, k_ref, v_ref, bias_ref,
-                        o_ref, acc_ref, m_ref, l_ref, *, scale, T, bt):
-    del tbl_ref
-    _kernel_plain(q_ref, k_ref, v_ref, bias_ref, o_ref,
-                  acc_ref, m_ref, l_ref, scale=scale, T=T, bt=bt)
+# The routing verdict, from the chip (TPU v5 lite, PR 21; PERF.md has the
+# table): at the flagship decode shape [B=32, T=1024, h=16, d=256] these
+# kernels take 1585 us (int8 cache) / 2408 us (bf16) per layer-step against
+# 584 / 793 us for XLA's fused dequantize-einsum, which already runs at
+# 57% / 83% of the HBM floor. A VPU matvec does not win anywhere measured,
+# so no shape is routed to the kernels; they stay compiled, run, compared
+# and timed by chip_smoke.py so that ROADMAP A3 has a baseline to beat and
+# one constant to flip.
+DECODE_KERNEL_ROUTED = False
 
 
 def decode_attn_eligible(n_head: int, head_dim: int, cache_len: int, quant: bool) -> bool:
-    """Static routing: real TPU backend + a head layout the MXU/VPU tile
-    cleanly (the full-[h, d] blocks are tile-LEGAL for any shape; the gate
-    keeps sub-tile head layouts — tiny test models — on the einsum path
-    where they are faster). The masked tail block removed the old
-    `cache_len % sublane == 0` restriction: any cache length is eligible.
-    `cache_len`/`quant` stay in the signature as the routing key the
-    lowering probe is cached on."""
-    if not _HAVE_PLTPU or jax.default_backend() != "tpu":
-        return False
-    return head_dim % 128 == 0 and n_head % 8 == 0
+    """Static routing: the DECODE_KERNEL_ROUTED verdict above, then a TPU
+    backend with a one-device mesh and a head layout whose per-head lane
+    slices are tile-aligned (head_dim % 128; the n_head % 8 rule keeps tiny
+    test models on the einsum path). Any cache length is eligible (masked
+    tail block). `cache_len`/`quant` stay in the signature as the routing
+    key the caller builds."""
+    return (
+        DECODE_KERNEL_ROUTED
+        and one_device_tpu()
+        and head_dim % 128 == 0
+        and n_head % 8 == 0
+    )
 
 
 _PROBE_CACHE = {}
 
 
 def decode_attn_supported(B: int, T: int, h: int, d: int, quant: bool, dtype=jnp.bfloat16) -> bool:
-    """One-time cached lowering probe: can THIS shape's kernel actually
-    lower? Two stages, both off the hot path (the result is cached per
-    shape key for the life of the process):
+    """Tile-check verdict (and, on TPU, must-lower step) for the kernel at
+    this call-site shape — tiling.routing_verdict, cached per shape key for
+    the life of the process."""
+    from trlx_tpu.ops.tiling import decode_block_layout, routing_verdict
 
-    1. the CPU-runnable static tile check (tiling.check_layout over the
-       real block layouts) — catches any (8, 128) violation instantly;
-    2. on a real TPU backend, an abstract `jax.jit(...).lower()` of the
-       kernel call, which runs the genuine Mosaic block-mapping checks.
+    def lower():
+        s = jax.ShapeDtypeStruct
+        kv = s((B, T, h, d), jnp.int8 if quant else dtype)
+        sc = s((B, T, h), jnp.float32) if quant else None
 
-    Any failure warns ONCE and answers False — the model layer then routes
-    the step through the einsum path instead of letting the ValueError
-    surface mid-bench from inside a compiled rollout program (the BENCH_r05
-    failure mode)."""
-    key = (B, T, h, d, bool(quant), jnp.dtype(dtype).name, jax.default_backend())
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    try:
-        from trlx_tpu.ops.tiling import check_layout, decode_block_layout
+        def probe(q, k, v, ks, vs, bias):
+            return decode_attention(q, k, v, ks, vs, bias, scale=1.0, interpret=False)
 
-        check_layout(decode_block_layout(B, T, h, d, bool(quant)))
-        if _HAVE_PLTPU and jax.default_backend() == "tpu":
-            s = jax.ShapeDtypeStruct
-            args = [s((B, h, d), dtype), s((B, T, h, d), jnp.int8 if quant else dtype)]
-            args.append(args[1])
-            if quant:
-                args += [s((B, T, h), jnp.float32)] * 2
-            else:
-                args += [None, None]
-            args.append(s((B, T), jnp.float32))
+        return probe, s((B, h, d), dtype), kv, kv, sc, sc, s((B, T), jnp.float32)
 
-            def probe(q, k, v, ks, vs, bias):
-                return decode_attention(q, k, v, ks, vs, bias, scale=1.0, interpret=False)
-
-            jax.jit(probe).lower(*args)
-        ok = True
-    except Exception as e:  # noqa: BLE001 — ANY probe failure must fall back
-        warnings.warn(
-            f"decode-attention kernel unavailable for shape [B={B}, T={T}, "
-            f"h={h}, d={d}, quant={quant}] — falling back to the einsum "
-            f"path ({type(e).__name__}: {str(e)[:300]})"
-        )
-        ok = False
-    _PROBE_CACHE[key] = ok
-    return ok
+    return routing_verdict(
+        _PROBE_CACHE,
+        (B, T, h, d, bool(quant), jnp.dtype(dtype).name, jax.default_backend()),
+        "decode-attention",
+        f"[B={B}, T={T}, h={h}, d={d}, quant={quant}]",
+        decode_block_layout(B, T, h, d, bool(quant)),
+        "einsum",
+        lower,
+    )
 
 
 def spec_verify_supported(
@@ -313,9 +250,9 @@ def spec_verify_supported(
     program runs the einsum attention path, which lowers for any shape — so
     this is a layout blessing, not a routing gate: the engine calls it once
     at arm time and WARNS on an illegal layout so a future kernel port
-    inherits a shape that already tiles, instead of rediscovering the
-    BENCH_r05 failure mode. `pick_t_block` keeps the T-tail masked exactly
-    like the single-token kernel, so any cache length stays legal."""
+    inherits a shape that already tiles. `pick_t_block` keeps the T-tail
+    masked exactly like the single-token kernel, so any cache length stays
+    legal."""
     from trlx_tpu.ops.tiling import is_tile_legal, spec_verify_layout
 
     return is_tile_legal(
@@ -338,66 +275,51 @@ def decode_attention(q, k_cache, v_cache, ks, vs, bias_row, *, scale,
     quant = ks is not None
     interpret = _interpret_default() if interpret is None else interpret
     bt = pick_t_block(T) if block_t is None else block_t
-    nt = -(-T // bt)
-    grid = (B, nt)
 
-    # The wrapper's operands and specs come from the SAME layout description
-    # the tiling validator checks (tiling.decode_block_layout).
+    # The wrapper's specs come from the SAME layout description the tiling
+    # validator checks (tiling.decode_block_layout).
     layout = {
         lay.name: lay for lay in decode_block_layout(B, T, h, d, quant, block_t=bt)
     }
-    q_spec = _vmem(layout["q"].block_shape, lambda b, it: (b, 0, 0))
-    kv_spec = _vmem(layout["k_cache"].block_shape, lambda b, it: (b, it, 0, 0))
-    bias_spec = _vmem(layout["bias"].block_shape, lambda b, it: (b, 0, it))
-    out_spec = _vmem(layout["out"].block_shape, lambda b, it: (b, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((B, h, d), q.dtype)
-    scratch = [
-        _scratch((h, d)),    # fp32 output accumulator
-        _scratch((h, 128)),  # running max
-        _scratch((h, 128)),  # running sum
+    row = lambda b, it: (b, 0, 0)
+    walk = lambda b, it: (b, it, 0)
+    kv_spec = _vmem(layout["k_cache"].block_shape, walk)
+    in_specs = [_vmem(layout["q"].block_shape, row), kv_spec, kv_spec]
+    operands = [
+        q.reshape(B, 1, h * d),
+        k_cache.reshape(B, T, h * d),
+        v_cache.reshape(B, T, h * d),
     ]
-    bias3 = bias_row.astype(jnp.float32)[:, None, :]  # [B, 1, T]
-    common = dict(
-        grid=grid,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
+    if quant:
+        sc_spec = _vmem(layout["k_scale"].block_shape, walk)
+        in_specs += [sc_spec, sc_spec]
+        operands += [ks, vs]
+    in_specs.append(_vmem(layout["bias"].block_shape, lambda b, it: (b, 0, it)))
+    operands.append(bias_row.astype(jnp.float32)[:, None, :])  # [B, 1, T]
+    out = pl.pallas_call(
+        functools.partial(
+            _decode_kernel, scale=scale, T=T, bt=bt, h=h, d=d, quant=quant, paged=False
+        ),
+        grid=(B, -(-T // bt)),
+        in_specs=in_specs,
+        out_specs=_vmem(layout["out"].block_shape, row),
+        out_shape=jax.ShapeDtypeStruct((B, 1, h * d), q.dtype),
+        scratch_shapes=_scratch_shapes(h, d),
         interpret=interpret,
         **_compiler_params(interpret),
-    )
-    if quant:
-        sc_spec = _vmem(layout["k_scale"].block_shape, lambda b, it: (b, 0, it))
-        # Head-major scales: [B, T, h] -> [B, h, T]. An XLA transpose of the
-        # fp32 scale planes (<1% of the int8 cache bytes) buys a kernel with
-        # no in-kernel transposes.
-        ks_t = jnp.swapaxes(ks, 1, 2)
-        vs_t = jnp.swapaxes(vs, 1, 2)
-        out = pl.pallas_call(
-            functools.partial(_kernel_quant, scale=scale, T=T, bt=bt),
-            in_specs=[q_spec, kv_spec, kv_spec, sc_spec, sc_spec, bias_spec],
-            **common,
-        )(q, k_cache, v_cache, ks_t, vs_t, bias3)
-    else:
-        out = pl.pallas_call(
-            functools.partial(_kernel_plain, scale=scale, T=T, bt=bt),
-            in_specs=[q_spec, kv_spec, kv_spec, bias_spec],
-            **common,
-        )(q, k_cache, v_cache, bias3)
-    return out[:, None]  # [B, 1, h, d]
+    )(*operands)
+    return out.reshape(B, 1, h, d)
 
 
 def paged_decode_eligible(
     n_head: int, head_dim: int, block_size: int, blocks_per_slot: int, quant: bool
 ) -> bool:
-    """Static routing for the block-table-indirect kernel: real TPU backend,
-    the same MXU-clean head layout as ``decode_attn_eligible``, and a
-    lane-divisible block_size (the bias block (1, 1, block_size) is the one
-    strict tile in the paged layout — a single-block table is the full-array
-    escape hatch). `quant` stays in the signature as part of the routing
-    key."""
-    if not _HAVE_PLTPU or jax.default_backend() != "tpu":
-        return False
-    if head_dim % 128 != 0 or n_head % 8 != 0:
+    """Static routing for the block-table-indirect kernel: the same rule as
+    ``decode_attn_eligible`` plus a lane-divisible block_size (the bias
+    block (1, 1, block_size) is the one strict tile in the paged layout — a
+    single-block table is the full-array escape hatch). `quant` stays in
+    the signature as part of the routing key."""
+    if not decode_attn_eligible(n_head, head_dim, block_size * blocks_per_slot, quant):
         return False
     return block_size % 128 == 0 or blocks_per_slot == 1
 
@@ -412,60 +334,41 @@ def paged_decode_supported(
     quant: bool,
     dtype=jnp.bfloat16,
 ) -> bool:
-    """One-time cached lowering probe for the paged kernel, mirror of
-    ``decode_attn_supported``: (1) the CPU-runnable tile check over
-    tiling.paged_decode_layout — the SAME description the wrapper builds its
-    specs from; (2) on a real TPU backend, an abstract jit lower of the
-    kernel call, which additionally exercises the scalar-prefetch block
-    mapping. Any failure warns once and answers False so the model layer
-    routes through the gather-einsum path instead of dying mid-rollout."""
-    key = (
-        "paged", n_slots, n_blocks, block_size, blocks_per_slot, h, d,
-        bool(quant), jnp.dtype(dtype).name, jax.default_backend(),
-    )
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    try:
-        from trlx_tpu.ops.tiling import check_layout, paged_decode_layout
+    """Mirror of ``decode_attn_supported`` for the paged kernel; the TPU
+    must-lower probe additionally exercises the scalar-prefetch mapping."""
+    from trlx_tpu.ops.tiling import paged_decode_layout, routing_verdict
 
-        check_layout(
-            paged_decode_layout(
-                n_slots, n_blocks, block_size, blocks_per_slot, h, d, bool(quant)
+    def lower():
+        s = jax.ShapeDtypeStruct
+        kv = s((n_blocks, block_size, h, d), jnp.int8 if quant else dtype)
+        sc = s((n_blocks, block_size, h), jnp.float32) if quant else None
+
+        def probe(q, k, v, ks, vs, tbl, bias):
+            return paged_decode_attention(
+                q, k, v, ks, vs, tbl, bias, scale=1.0, interpret=False
             )
-        )
-        if _HAVE_PLTPU and jax.default_backend() == "tpu":
-            s = jax.ShapeDtypeStruct
-            t_virt = blocks_per_slot * block_size
-            kv = s((n_blocks, block_size, h, d), jnp.int8 if quant else dtype)
-            args = [s((n_slots, h, d), dtype), kv, kv]
-            if quant:
-                args += [s((n_blocks, block_size, h), jnp.float32)] * 2
-            else:
-                args += [None, None]
-            args += [
-                s((n_slots, blocks_per_slot), jnp.int32),
-                s((n_slots, t_virt), jnp.float32),
-            ]
 
-            def probe(q, k, v, ks, vs, tbl, bias):
-                return paged_decode_attention(
-                    q, k, v, ks, vs, tbl, bias, scale=1.0, interpret=False
-                )
-
-            jax.jit(probe).lower(*args)
-        ok = True
-    except Exception as e:  # noqa: BLE001 — ANY probe failure must fall back
-        warnings.warn(
-            f"paged decode-attention kernel unavailable for shape "
-            f"[S={n_slots}, n_blocks={n_blocks}, bs={block_size}, "
-            f"bps={blocks_per_slot}, h={h}, d={d}, quant={quant}] — falling "
-            f"back to the gather-einsum path "
-            f"({type(e).__name__}: {str(e)[:300]})"
+        return (
+            probe, s((n_slots, h, d), dtype), kv, kv, sc, sc,
+            s((n_slots, blocks_per_slot), jnp.int32),
+            s((n_slots, blocks_per_slot * block_size), jnp.float32),
         )
-        ok = False
-    _PROBE_CACHE[key] = ok
-    return ok
+
+    return routing_verdict(
+        _PROBE_CACHE,
+        (
+            "paged", n_slots, n_blocks, block_size, blocks_per_slot, h, d,
+            bool(quant), jnp.dtype(dtype).name, jax.default_backend(),
+        ),
+        "paged decode-attention",
+        f"[S={n_slots}, n_blocks={n_blocks}, bs={block_size}, "
+        f"bps={blocks_per_slot}, h={h}, d={d}, quant={quant}]",
+        paged_decode_layout(
+            n_slots, n_blocks, block_size, blocks_per_slot, h, d, bool(quant)
+        ),
+        "gather-einsum",
+        lower,
+    )
 
 
 def paged_decode_attention(q, k_pool, v_pool, ks_pool, vs_pool, block_tables,
@@ -480,27 +383,20 @@ def paged_decode_attention(q, k_pool, v_pool, ks_pool, vs_pool, block_tables,
     [S, T_virt] additive fp32 mask over the slot's VIRTUAL address space
     (T_virt = blocks_per_slot * block_size). Returns [S, 1, h, d] in q.dtype.
 
-    Same online-softmax body as ``decode_attention``; the only new machinery
-    is the scalar-prefetched table: the grid walks (slot, virtual block) and
-    the K/V/scale index maps dereference `table[s, it]` so each program DMAs
-    the slot's own physical block. T_virt is an exact multiple of block_size,
-    so the tail-mask arithmetic in the shared body is inert — raggedness and
-    dead virtual columns are entirely the bias row's job, exactly like the
-    slot-decode path."""
+    Same kernel body as ``decode_attention``; the only new machinery is the
+    scalar-prefetched table: the grid walks (slot, virtual block) and the
+    K/V/scale index maps dereference `table[s, it]` so each program DMAs the
+    slot's own physical block. T_virt is an exact multiple of block_size, so
+    the tail-mask arithmetic in the shared body is compiled out — raggedness
+    and dead virtual columns are entirely the bias row's job, exactly like
+    the slot-decode path."""
     from trlx_tpu.ops.tiling import paged_decode_layout
 
-    if not _HAVE_PLTPU:  # pragma: no cover — container always ships pltpu
-        raise RuntimeError(
-            "paged_decode_attention needs jax.experimental.pallas.tpu for "
-            "PrefetchScalarGridSpec; route via paged_decode_supported first"
-        )
     S, h, d = q.shape
     n_blocks, bs = k_pool.shape[:2]
     bps = block_tables.shape[1]
-    t_virt = bps * bs
     quant = ks_pool is not None
     interpret = _interpret_default() if interpret is None else interpret
-    grid = (S, bps)
 
     layout = {
         lay.name: lay
@@ -508,51 +404,39 @@ def paged_decode_attention(q, k_pool, v_pool, ks_pool, vs_pool, block_tables,
     }
     # Index maps receive the grid indices first and the scalar-prefetched
     # table ref LAST: (s, it, tbl).
-    q_spec = _vmem(layout["q"].block_shape, lambda s, it, tbl: (s, 0, 0))
-    kv_spec = _vmem(
-        layout["k_pool"].block_shape, lambda s, it, tbl: (tbl[s, it], 0, 0, 0)
-    )
-    bias_spec = _vmem(layout["bias"].block_shape, lambda s, it, tbl: (s, 0, it))
-    out_spec = _vmem(layout["out"].block_shape, lambda s, it, tbl: (s, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((S, h, d), q.dtype)
-    scratch = [
-        _scratch((h, d)),    # fp32 output accumulator
-        _scratch((h, 128)),  # running max
-        _scratch((h, 128)),  # running sum
+    row = lambda s, it, tbl: (s, 0, 0)
+    phys = lambda s, it, tbl: (tbl[s, it], 0, 0)
+    kv_spec = _vmem(layout["k_pool"].block_shape, phys)
+    in_specs = [_vmem(layout["q"].block_shape, row), kv_spec, kv_spec]
+    operands = [
+        block_tables.astype(jnp.int32),
+        q.reshape(S, 1, h * d),
+        k_pool.reshape(n_blocks, bs, h * d),
+        v_pool.reshape(n_blocks, bs, h * d),
     ]
-    bias3 = bias_row.astype(jnp.float32)[:, None, :]  # [S, 1, T_virt]
-    tables = block_tables.astype(jnp.int32)
     if quant:
-        sc_spec = _vmem(
-            layout["k_scale"].block_shape, lambda s, it, tbl: (tbl[s, it], 0, 0)
-        )
-        # Head-major scales: [n_blocks, bs, h] -> [n_blocks, h, bs], same
-        # trade as the non-paged wrapper (cheap XLA transpose, no in-kernel
-        # transpose).
-        ks_t = jnp.swapaxes(ks_pool, 1, 2)
-        vs_t = jnp.swapaxes(vs_pool, 1, 2)
-        in_specs = [q_spec, kv_spec, kv_spec, sc_spec, sc_spec, bias_spec]
-        kernel = functools.partial(_paged_kernel_quant, scale=scale, T=t_virt, bt=bs)
-        operands = (tables, q, k_pool, v_pool, ks_t, vs_t, bias3)
-    else:
-        in_specs = [q_spec, kv_spec, kv_spec, bias_spec]
-        kernel = functools.partial(_paged_kernel_plain, scale=scale, T=t_virt, bt=bs)
-        operands = (tables, q, k_pool, v_pool, bias3)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        scratch_shapes=scratch,
-    )
+        sc_spec = _vmem(layout["k_scale"].block_shape, phys)
+        in_specs += [sc_spec, sc_spec]
+        operands += [ks_pool, vs_pool]
+    in_specs.append(_vmem(layout["bias"].block_shape, lambda s, it, tbl: (s, 0, it)))
+    operands.append(bias_row.astype(jnp.float32)[:, None, :])  # [S, 1, T_virt]
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        functools.partial(
+            _decode_kernel, scale=scale, T=bps * bs, bt=bs, h=h, d=d,
+            quant=quant, paged=True,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S, bps),
+            in_specs=in_specs,
+            out_specs=_vmem(layout["out"].block_shape, row),
+            scratch_shapes=_scratch_shapes(h, d),
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, 1, h * d), q.dtype),
         interpret=interpret,
         **_compiler_params(interpret),
     )(*operands)
-    return out[:, None]  # [S, 1, h, d]
+    return out.reshape(S, 1, h, d)
 
 
 def paged_slot_decode_attention(q, k_pool, v_pool, ks_pool, vs_pool,

@@ -30,14 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is unavailable on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 M_INIT = -1e30  # running-max init (finite: fully-masked rows degrade to
 # uniform attention exactly like the XLA path's -1e9 bias)
@@ -46,6 +39,23 @@ MASK_VAL = -1e9
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def one_device_tpu() -> bool:
+    """The rule every model-layer kernel gate in ops/ shares: a TPU backend
+    and a one-device mesh. No pallas_call here is under shard_map, and a jit
+    over more than one device refuses to lower one ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a shard_map",
+    jax 0.9) — so on a mesh larger than one the XLA paths stand until the
+    kernels are wrapped (ROADMAP A6). Ring attention calls the flash kernel
+    per shard inside its own shard_map and gates on ``auto_flash_ok``
+    alone."""
+    if jax.default_backend() != "tpu":
+        return False
+    from trlx_tpu.parallel.mesh import peek_mesh
+
+    mesh = peek_mesh()
+    return mesh is None or mesh.size == 1
 
 
 def pick_block(q_len: int) -> int:
@@ -61,48 +71,35 @@ def pick_block(q_len: int) -> int:
 def auto_flash_ok(q_len: int) -> bool:
     """The shared auto-routing gate: a real TPU backend (interpret-mode
     pallas is far slower than einsum) and a long 128-aligned sequence. Used
-    by both the model layer and the ring-attention per-chunk path so the
-    eligibility rule and the block choice cannot drift apart."""
-    return (
-        _HAVE_PLTPU
-        and jax.default_backend() == "tpu"
-        and q_len >= 256
-        and q_len % 128 == 0
-    )
+    by both the model layer (which adds ``one_device_tpu``) and the
+    ring-attention per-chunk path so the eligibility rule and the block
+    choice cannot drift apart."""
+    return jax.default_backend() == "tpu" and q_len >= 256 and q_len % 128 == 0
 
 
 def _vmem_spec(shape, index_map):
-    if _HAVE_PLTPU:
-        return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
-    return pl.BlockSpec(shape, index_map)
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _scratch(shape):
-    if not _HAVE_PLTPU:  # pragma: no cover
-        raise RuntimeError("jax.experimental.pallas.tpu unavailable")
     return pltpu.VMEM(shape, jnp.float32)
 
 
 def _smem_spec():
     """Whole (1,1) scalar operand in SMEM (the traced ring-chunk offset)."""
-    if _HAVE_PLTPU:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec((1, 1), lambda *_: (0, 0))  # pragma: no cover
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _compiler_params(interpret):
+def _compiler_params(interpret, semantics=("parallel", "parallel", "arbitrary")):
     """Mark the (bh, outer-block) grid dims parallel so Mosaic pipelines
     across grid steps instead of serializing them; only the innermost dim
     (the online-softmax / accumulation walk) is order-dependent. Without
     this the kernel is grid-step-latency-bound: at [8,1024,16,256] the
-    forward drops from ~18ms to ~3ms on a v5e."""
-    if not _HAVE_PLTPU or interpret:
+    forward drops from ~18ms to ~3ms on a v5e. The decode and fused-logprob
+    kernels pass their own two-dim semantics."""
+    if interpret:
         return {}
-    return {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    }
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=semantics)}
 
 
 # ---------------------------------------------------------------------------

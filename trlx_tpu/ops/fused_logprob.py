@@ -32,10 +32,11 @@ the online-softmax accumulation order); the weight streams in bv-wide tiles
 (128-divisible, so ragged GPT-2/J vocab sizes get a partial tail block that
 is masked in-kernel, exactly like the flash-decode T tail). Block layouts
 live in tiling.fused_logprob_block_layout — the validator and this wrapper
-read the SAME description, and the routing probe (fused_logprob_supported)
-re-checks it plus a one-time real lowering before the model layer ever
-traces the kernel, warning and falling back to the materialized
-log_softmax path instead of crashing a train run.
+read the SAME description, and the routing gate (fused_logprob_supported)
+re-checks it before the model layer ever traces the kernel: a tile-illegal
+shape takes the materialized log_softmax path by that stated rule, and a
+shape that passes must lower on a TPU backend or the run stops with an
+error naming the kernel and the shape.
 
 Engagement mirrors flash/decode attention: real TPU backend (or explicit
 interpret mode for CPU CI parity tests, tests/test_losses.py); tiny test
@@ -43,25 +44,22 @@ models stay on the einsum fallback where they are faster.
 """
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.experimental import pallas as pl
+
 from trlx_tpu.ops.flash_attention import (
-    _HAVE_PLTPU,
     M_INIT,
     MASK_VAL,
     _interpret_default,
     _scratch,
-    pl,
+    one_device_tpu,
 )
-
-if _HAVE_PLTPU:  # pragma: no branch
-    from jax.experimental.pallas import tpu as pltpu
-else:  # pragma: no cover
-    pltpu = None
+from trlx_tpu.ops.flash_attention import _compiler_params as _grid_compiler_params
+from trlx_tpu.ops.flash_attention import _vmem_spec as _vmem
 
 # Forward vocab tile: 512 columns/tile keeps the [D, bv] weight block at
 # 4 MB (bf16, D=4096) — comfortable VMEM with double buffering. The
@@ -79,22 +77,11 @@ def pick_v_block(V: int, block_v: int = BLOCK_V) -> int:
     return V if V <= block_v else block_v
 
 
-def _vmem(shape, index_map):
-    if _HAVE_PLTPU:
-        return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
-    return pl.BlockSpec(shape, index_map)
-
-
-def _compiler_params(interpret):
-    """N-blocks are independent; the V walk is the online accumulation
-    order and must stay sequential."""
-    if not _HAVE_PLTPU or interpret:
-        return {}
-    return {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    }
+# N-blocks are independent; the V walk is the online accumulation order and
+# must stay sequential.
+_compiler_params = functools.partial(
+    _grid_compiler_params, semantics=("parallel", "arbitrary")
+)
 
 
 def _tile_scores(x_ref, w_ref, b_ref, j, *, V, bv, tied):
@@ -474,18 +461,17 @@ def naive_logprob(x, w, labels, bias=None, *, tied=False, mask=None):
 
 
 # ---------------------------------------------------------------------------
-# Routing: static eligibility + one-time cached lowering probe
+# Routing: static eligibility + cached tile check (+ must-lower on TPU)
 # ---------------------------------------------------------------------------
 
 
 def fused_logprob_eligible(d_model: int, vocab_size: int) -> bool:
-    """Static routing gate: a real TPU backend and a head layout worth
-    tiling (full-[D] blocks are always tile-legal; the gate keeps tiny test
-    models on the materialized path, where XLA's fused softmax is faster
-    than grid overhead)."""
-    if not _HAVE_PLTPU or jax.default_backend() != "tpu":
-        return False
-    return d_model % 128 == 0 and vocab_size >= BLOCK_V
+    """Static routing gate: a TPU backend with a one-device mesh
+    (flash_attention.one_device_tpu) and a head layout worth tiling
+    (full-[D] blocks are always tile-legal; the gate keeps tiny test models
+    on the materialized path, where XLA's fused softmax is faster than grid
+    overhead)."""
+    return one_device_tpu() and d_model % 128 == 0 and vocab_size >= BLOCK_V
 
 
 _PROBE_CACHE = {}
@@ -493,53 +479,41 @@ _PROBE_CACHE = {}
 
 def fused_logprob_supported(N: int, D: int, V: int, tied: bool,
                             has_bias: bool, dtype=jnp.bfloat16) -> bool:
-    """One-time cached probe for a call-site shape, same two stages as
-    decode_attn_supported: (1) the CPU-runnable static tile check over the
-    real block layouts; (2) on TPU, an abstract jax.jit(...).lower() of the
-    kernel's forward AND backward, which runs the genuine Mosaic checks.
-    Any failure warns ONCE and answers False — the model layer then takes
-    the materialized log_softmax path instead of crashing mid-run."""
-    key = (N, D, V, bool(tied), bool(has_bias), jnp.dtype(dtype).name,
-           jax.default_backend())
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    try:
-        from trlx_tpu.ops.tiling import check_layout, fused_logprob_block_layout
+    """Cached verdict for a call-site shape (tiling.routing_verdict, the
+    same two stages as decode_attn_supported): the CPU-runnable static tile
+    check over the real block layouts may refuse the shape; a shape that
+    passes it must, on a TPU backend, lower forward AND backward."""
+    from trlx_tpu.ops.tiling import fused_logprob_block_layout, routing_verdict
 
-        bn = BLOCK_N
-        bv = pick_v_block(V)
-        Np = -(-N // bn) * bn
-        check_layout(fused_logprob_block_layout(Np, D, V, bn, bv, tied, has_bias))
-        if _HAVE_PLTPU and jax.default_backend() == "tpu":
-            s = jax.ShapeDtypeStruct
-            args = [s((N, D), dtype), s((V, D) if tied else (D, V), dtype),
-                    s((N,), jnp.int32)]
-            if has_bias:
-                args.append(s((V,), jnp.float32))
+    def lower():
+        s = jax.ShapeDtypeStruct
+        args = [s((N, D), dtype), s((V, D) if tied else (D, V), dtype),
+                s((N,), jnp.int32)]
+        if has_bias:
+            args.append(s((V,), jnp.float32))
 
-            def probe(x, w, y, *rest):
-                def f(x, w, *b):
-                    lp, lse, ent = fused_logprob(
-                        x, w, y, b[0] if b else None, tied=tied, interpret=False
-                    )
-                    return jnp.sum(lp) + jnp.sum(lse) + jnp.sum(ent)
+        def probe(x, w, y, *b):
+            def f(x, w, *b):
+                lp, lse, ent = fused_logprob(
+                    x, w, y, b[0] if b else None, tied=tied, interpret=False
+                )
+                return jnp.sum(lp) + jnp.sum(lse) + jnp.sum(ent)
 
-                if rest:
-                    return jax.grad(f, argnums=(0, 1, 2))(x, w, rest[0])
-                return jax.grad(f, argnums=(0, 1))(x, w)
+            return jax.grad(f, argnums=tuple(range(2 + len(b))))(x, w, *b)
 
-            jax.jit(probe).lower(*args)
-        ok = True
-    except Exception as e:  # noqa: BLE001 — ANY probe failure must fall back
-        warnings.warn(
-            f"fused-logprob kernel unavailable for shape [N={N}, D={D}, "
-            f"V={V}, tied={tied}, bias={has_bias}] — falling back to the "
-            f"log_softmax path ({type(e).__name__}: {str(e)[:300]})"
-        )
-        ok = False
-    _PROBE_CACHE[key] = ok
-    return ok
+        return (probe, *args)
+
+    Np = -(-N // BLOCK_N) * BLOCK_N
+    return routing_verdict(
+        _PROBE_CACHE,
+        (N, D, V, bool(tied), bool(has_bias), jnp.dtype(dtype).name,
+         jax.default_backend()),
+        "fused-logprob",
+        f"[N={N}, D={D}, V={V}, tied={tied}, bias={has_bias}]",
+        fused_logprob_block_layout(Np, D, V, BLOCK_N, pick_v_block(V), tied, has_bias),
+        "log_softmax",
+        lower,
+    )
 
 
 def routed_logprob(x, w, labels, bias=None, *, tied=False, mode="auto", mask=None):

@@ -3,16 +3,18 @@
 The Mosaic TPU lowering requires that the LAST TWO dimensions of every
 BlockSpec block shape are divisible by (8, 128) — or equal the respective
 dimensions of the overall array (a "full" block needs no tiling). Violations
-only surface at lowering time ON A TPU, as a mid-run ValueError: exactly how
-the old decode-attention kernel's per-head `(1, 1, d)` q block killed
-BENCH_r05 at the flagship size (rc=1, decode_attention.py:61).
+only surface at lowering time for a TPU, as a mid-run ValueError: exactly
+how an early decode-attention kernel's per-head `(1, 1, d)` q block killed a
+flagship bench run.
 
 This module makes the rule checkable on CPU, without lowering anything:
 kernel modules describe their real block layouts (`decode_block_layout`,
 `flash_block_layout`) and tier-1 tests assert legality at the real bench
-shapes. The decode-attention runtime probe also runs `check_layout` first,
-so an illegal shape is refused (and routed to einsum) before any Mosaic
-lowering is attempted.
+shapes. The routing gates run `layout_issues` first, so a tile-illegal shape
+is refused (and routed to einsum) by a stated rule; what passes the rule
+must then lower (`require_lowering`) — the tile rule is only the first of
+Mosaic's checks, and a kernel that passes it and still cannot lower is a
+defect to surface, not a route to take quietly.
 """
 
 from typing import NamedTuple, Optional, Sequence, Tuple
@@ -78,21 +80,75 @@ def block_tile_issues(
     return issues
 
 
-def check_layout(layouts: Sequence[BlockLayout]) -> None:
-    """Raise TileError listing every violation across a kernel's specs."""
+def layout_issues(layouts: Sequence[BlockLayout]) -> list:
+    """Every (8, 128)-or-full violation across a kernel's specs."""
     issues = []
     for lay in layouts:
         issues.extend(block_tile_issues(lay.block_shape, lay.array_shape, lay.name))
+    return issues
+
+
+def check_layout(layouts: Sequence[BlockLayout]) -> None:
+    """Raise TileError listing every violation across a kernel's specs."""
+    issues = layout_issues(layouts)
     if issues:
         raise TileError("; ".join(issues))
 
 
 def is_tile_legal(layouts: Sequence[BlockLayout]) -> bool:
+    return not layout_issues(layouts)
+
+
+def routing_verdict(cache: dict, key, kernel: str, shape: str, layouts, fallback: str, lower) -> bool:
+    """Cached routing verdict for one call-site shape of a kernel whose
+    static eligibility rule already passed.
+
+    The CPU-runnable tile check over the kernel's real block layouts may
+    refuse the shape — a stated rule, warned once, answered False, and the
+    caller takes `fallback`. A shape that passes must, on a TPU backend,
+    lower: `lower()` returns `(fn, *abstract_args)` for `require_lowering`,
+    which raises naming the kernel and the shape. `cache` is the kernel
+    module's probe cache (devicemon's routing gauges read it)."""
+    import warnings
+
+    import jax
+
+    hit = cache.get(key)
+    if hit is not None:
+        return hit
+    issues = layout_issues(layouts)
+    if issues:
+        warnings.warn(
+            f"{kernel} kernel refused for shape {shape} by the static tile "
+            f"check — falling back to the {fallback} path "
+            f"({'; '.join(issues)[:300]})"
+        )
+    elif jax.default_backend() == "tpu":
+        require_lowering(kernel, shape, *lower())
+    cache[key] = not issues
+    return cache[key]
+
+
+class KernelLoweringError(RuntimeError):
+    """A kernel that its static rules call eligible does not lower."""
+
+
+def require_lowering(kernel: str, shape: str, fn, *abstract_args) -> None:
+    """Lower `fn` for the current (TPU) backend at abstract operands; a
+    failure raises KernelLoweringError naming the kernel and the shape.
+    Routing never reads the outcome: an eligible shape either lowers or the
+    run stops here with the Mosaic diagnostic attached, instead of printing
+    einsum numbers under the kernel's name."""
+    import jax
+
     try:
-        check_layout(layouts)
-        return True
-    except TileError:
-        return False
+        jax.jit(fn).lower(*abstract_args)
+    except Exception as e:
+        raise KernelLoweringError(
+            f"{kernel} kernel is eligible for shape {shape} but does not "
+            f"lower on the {jax.default_backend()} backend "
+            f"({type(e).__name__}: {str(e)[:500]})"
+        ) from e
 
 
 # ---------------------------------------------------------------------------
@@ -106,22 +162,25 @@ def decode_block_layout(
     B: int, T: int, h: int, d: int, quant: bool, block_t: Optional[int] = None
 ) -> list:
     """The flash-decode kernel's block layouts at a given shape (see
-    trlx_tpu.ops.decode_attention: grid (batch, T-blocks), full [h, d]
-    blocks, scales pre-transposed to [B, h, T], bias as [B, 1, T])."""
+    trlx_tpu.ops.decode_attention: grid (batch, T-blocks), the cache viewed
+    as [B, T, h*d] and streamed in lane-dense (bt, h*d) blocks, q/out as
+    [B, 1, h*d] rows, scales in their natural [B, T, h] cache layout, bias
+    as [B, 1, T])."""
     from trlx_tpu.ops.decode_attention import pick_t_block
 
     bt = pick_t_block(T) if block_t is None else block_t
+    hd = h * d
     layouts = [
-        BlockLayout("q", (1, h, d), (B, h, d)),
-        BlockLayout("k_cache", (1, bt, h, d), (B, T, h, d)),
-        BlockLayout("v_cache", (1, bt, h, d), (B, T, h, d)),
+        BlockLayout("q", (1, 1, hd), (B, 1, hd)),
+        BlockLayout("k_cache", (1, bt, hd), (B, T, hd)),
+        BlockLayout("v_cache", (1, bt, hd), (B, T, hd)),
         BlockLayout("bias", (1, 1, bt), (B, 1, T)),
-        BlockLayout("out", (1, h, d), (B, h, d)),
+        BlockLayout("out", (1, 1, hd), (B, 1, hd)),
     ]
     if quant:
         layouts[3:3] = [
-            BlockLayout("k_scale", (1, h, bt), (B, h, T)),
-            BlockLayout("v_scale", (1, h, bt), (B, h, T)),
+            BlockLayout("k_scale", (1, bt, h), (B, T, h)),
+            BlockLayout("v_scale", (1, bt, h), (B, T, h)),
         ]
     return layouts
 
@@ -188,34 +247,33 @@ def paged_decode_layout(
 ) -> list:
     """Block layouts of the block-table-indirect paged decode step
     (trlx_tpu.ops.decode_attention.paged_decode_attention): the KV cache is
-    ONE shared pool ``[n_blocks, block_size, h, d]`` and each slot walks its
-    own ``blocks_per_slot`` virtual blocks through a per-slot block table,
-    so the grid is (slot, virtual-block) and the K/V BlockSpec index map
-    reads the scalar-prefetched table — ``(table[s, it], 0, 0, 0)`` — to
-    fetch each slot's physical block. The pool blocks' last two dims are the
-    full ``[h, d]`` (tile-legal by construction, same as
-    ``decode_block_layout``); the per-block scale planes are pre-transposed
-    to ``[n_blocks, h, block_size]`` so their trailing dim is the full
-    block_size; the bias row covers the slot's VIRTUAL address space
+    ONE shared pool ``[n_blocks, block_size, h, d]`` — viewed, like the
+    fixed cache in ``decode_block_layout``, as ``[n_blocks, block_size,
+    h*d]`` — and each slot walks its own ``blocks_per_slot`` virtual blocks
+    through a per-slot block table, so the grid is (slot, virtual-block) and
+    the K/V/scale BlockSpec index maps read the scalar-prefetched table —
+    ``(table[s, it], 0, 0)`` — to fetch each slot's physical block. Pool and
+    scale blocks are full in their last two dims (tile-legal by
+    construction); the bias row covers the slot's VIRTUAL address space
     ``[n_slots, 1, blocks_per_slot * block_size]`` in block_size-wide tiles
     — the one operand whose lane dim is a strict tile, so kernel legality
     requires ``block_size % 128 == 0`` (or a single-block table). The
-    legality verdict is CPU-runnable via ``check_layout``; the routing gate
-    (decode_attention.paged_decode_supported) consumes this SAME description
-    plus a one-time lowering probe, so GL006 provenance and the kernel gate
-    share one source of truth."""
+    routing gate (decode_attention.paged_decode_supported) consumes this
+    SAME description, so GL006 provenance and the kernel gate share one
+    source of truth."""
     t_virt = blocks_per_slot * block_size
+    hd = h * d
     layouts = [
-        BlockLayout("q", (1, h, d), (n_slots, h, d)),
-        BlockLayout("k_pool", (1, block_size, h, d), (n_blocks, block_size, h, d)),
-        BlockLayout("v_pool", (1, block_size, h, d), (n_blocks, block_size, h, d)),
+        BlockLayout("q", (1, 1, hd), (n_slots, 1, hd)),
+        BlockLayout("k_pool", (1, block_size, hd), (n_blocks, block_size, hd)),
+        BlockLayout("v_pool", (1, block_size, hd), (n_blocks, block_size, hd)),
         BlockLayout("bias", (1, 1, block_size), (n_slots, 1, t_virt)),
-        BlockLayout("out", (1, h, d), (n_slots, h, d)),
+        BlockLayout("out", (1, 1, hd), (n_slots, 1, hd)),
     ]
     if quant:
         layouts[3:3] = [
-            BlockLayout("k_scale", (1, h, block_size), (n_blocks, h, block_size)),
-            BlockLayout("v_scale", (1, h, block_size), (n_blocks, h, block_size)),
+            BlockLayout("k_scale", (1, block_size, h), (n_blocks, block_size, h)),
+            BlockLayout("v_scale", (1, block_size, h), (n_blocks, block_size, h)),
         ]
     return layouts
 

@@ -91,20 +91,18 @@ def resolve_mesh_shape(shape: Sequence[int], n_devices: Optional[int] = None) ->
 def make_mesh(shape: Sequence[int] = (-1, 1, 1, 1), devices=None) -> Mesh:
     """Build the 4-axis (dp, fsdp, tp, sp) device mesh.
 
-    ``devices`` defaults to all addressable+remote devices in row-major order;
-    `mesh_utils.create_device_mesh` is used when possible so the tp axis rides
-    ICI-adjacent chips.
+    ``devices`` defaults to all addressable+remote devices;
+    `mesh_utils.create_device_mesh` places them so the tp axis rides
+    ICI-adjacent chips. A shape it cannot place on the physical topology is
+    an error — a row-major reshape would run, on links the axis order was
+    chosen to avoid.
     """
+    from jax.experimental import mesh_utils
+
     if devices is None:
         devices = jax.devices()
     shape = resolve_mesh_shape(shape, len(devices))
-    try:
-        from jax.experimental import mesh_utils
-
-        device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        device_array = np.asarray(devices).reshape(shape)
-    return Mesh(device_array, MESH_AXES)
+    return Mesh(mesh_utils.create_device_mesh(shape, devices=devices), MESH_AXES)
 
 
 def set_mesh(mesh: Mesh):

@@ -21,19 +21,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.7 stabilized name
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=check_rep)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                              check_rep=check_rep)
+from jax import shard_map
 
 from trlx_tpu.parallel.mesh import AXIS_SP, AXIS_TP, DATA_AXES, get_mesh
 
@@ -370,9 +358,10 @@ def ring_attention_sharded(q, k, v, kv_mask, *, scale: float, causal: bool = Tru
         )
         out = shard_map(
             lambda q, k, v, m: body(q, k, v, m),
-            mesh,
+            mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
             out_specs=qkv_spec,
+            check_vma=False,
         )(
             jnp.take(q, zz, axis=1),
             jnp.take(k, zz, axis=1),
@@ -387,7 +376,8 @@ def ring_attention_sharded(q, k, v, kv_mask, *, scale: float, causal: bool = Tru
     )
     return shard_map(
         lambda q, k, v, m: body(q, k, v, m),
-        mesh,
+        mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
         out_specs=qkv_spec,
+        check_vma=False,
     )(q, k, v, kv_mask)

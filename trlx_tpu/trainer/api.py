@@ -10,14 +10,10 @@ from trlx_tpu.data.configs import TRLConfig
 # same via package imports, reference: trlx/model/__init__.py:17-36).
 import trlx_tpu.trainer.ppo  # noqa: F401
 import trlx_tpu.trainer.ppo_softprompt  # noqa: F401
+import trlx_tpu.trainer.ilql  # noqa: F401
 import trlx_tpu.orchestrator.ppo_orchestrator  # noqa: F401
+import trlx_tpu.orchestrator.offline_orchestrator  # noqa: F401
 import trlx_tpu.pipeline.prompt_pipeline  # noqa: F401
-
-try:  # ILQL lands as its own module; keep PPO usable while it builds out
-    import trlx_tpu.trainer.ilql  # noqa: F401
-    import trlx_tpu.orchestrator.offline_orchestrator  # noqa: F401
-except ImportError:  # pragma: no cover
-    pass
 
 from trlx_tpu.orchestrator import get_orchestrator
 from trlx_tpu.pipeline.prompt_pipeline import PromptPipeline

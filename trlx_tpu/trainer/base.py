@@ -57,6 +57,7 @@ from trlx_tpu.resilience.faults import poison_nan
 from trlx_tpu.trainer import BaseRLTrainer
 from trlx_tpu.utils import Clock
 from trlx_tpu.utils import sanitize
+from trlx_tpu.utils.compile_cache import setup_compile_cache
 from trlx_tpu.utils.logging import Tracker
 
 
@@ -111,31 +112,9 @@ class JaxBaseTrainer(BaseRLTrainer):
     def __init__(self, config: TRLConfig, **kwargs):
         super().__init__(config, train_mode=True)
 
-        if config.train.compile_cache_dir:
-            # Persistent XLA compile cache: restarts/resumes skip the
-            # one-time compilation cost (the entire cold-start gap in the
-            # measured CPU head-to-head, BASELINE.md r4). Safe to set after
-            # backend init; programs compiled earlier in the process simply
-            # weren't cached.
-            os.makedirs(config.train.compile_cache_dir, exist_ok=True)
-            # The persistent-cache backend binds at the FIRST compile of the
-            # process — including to "no directory" when the dir was unset
-            # then — and a later jax.config.update of the dir alone is
-            # ignored for the rest of the process (observed as the
-            # order-dependent test_compile_cache_dir_populates flake). Reset
-            # the backend whenever this trainer's dir differs from what the
-            # process may have initialized with (None included) so its
-            # programs land where ITS config points.
-            prev_dir = jax.config.jax_compilation_cache_dir
-            if prev_dir != config.train.compile_cache_dir:
-                from jax.experimental.compilation_cache import compilation_cache as _cc
-
-                _cc.reset_cache()
-            jax.config.update("jax_compilation_cache_dir", config.train.compile_cache_dir)
-            # 0.0, not a threshold: production programs all compile >1s, and
-            # a threshold would silently skip caching small test/dev models
-            # (making the knob look broken exactly where users first try it).
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        # Persistent XLA compile cache: restarts/resumes skip the one-time
+        # compilation cost. Where it lives is utils/compile_cache.py's rule.
+        setup_compile_cache()
 
         init_distributed()
         self.mesh = make_mesh(config.train.mesh, devices=kwargs.pop("mesh_devices", None))

@@ -1,7 +1,7 @@
 """User-facing `train()` dispatch (reference: trlx/trlx.py:13-93).
 
-Filled in as trainer/orchestrator/pipeline layers land; the dispatch contract
-is identical to the reference: reward_fn → online PPO, dataset → offline ILQL.
+The dispatch contract is identical to the reference: reward_fn → online PPO,
+dataset → offline ILQL.
 """
 
 from typing import Callable, List, Optional, Tuple
@@ -24,13 +24,10 @@ def train(
     """Dispatch to online PPO (reward_fn) or offline ILQL (dataset)
     (reference: trlx/trlx.py:13-93). `backend` accepts "tpu"/"jax" for
     drop-in compatibility with `trlx.train(..., backend='tpu')`."""
-    # Import here: trainer modules register themselves at import time.
-    try:
-        from trlx_tpu.trainer.api import train as _train
-    except ImportError as e:
-        raise NotImplementedError(
-            "trlx_tpu.trainer is not available yet in this build"
-        ) from e
+    # Imported here, not at module scope: `import trlx_tpu` stays jax-free
+    # (trlx_tpu/__init__.py), and trainer modules register themselves at
+    # import time.
+    from trlx_tpu.trainer.api import train as _train
 
     return _train(
         model_path=model_path,

@@ -9,8 +9,11 @@ import os
 import time
 from typing import Any, Iterable, List
 
-import jax
 import numpy as np
+
+# jax is imported inside the three pytree helpers below, not here: the
+# package must import without the accelerator stack (utils/manifest.py is
+# what bench.py's JAX-free parent process journals with).
 
 
 def flatten(L: Iterable[Iterable[Any]]) -> List[Any]:
@@ -73,6 +76,8 @@ class Clock:
 
 def tree_size_bytes(tree) -> int:
     """Total bytes of all arrays in a pytree (for memory telemetry)."""
+    import jax
+
     return sum(
         x.size * x.dtype.itemsize
         for x in jax.tree_util.tree_leaves(tree)
@@ -82,11 +87,15 @@ def tree_size_bytes(tree) -> int:
 
 def tree_param_count(tree) -> int:
     """Total number of elements in a pytree of arrays."""
+    import jax
+
     return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(tree) if hasattr(x, "shape"))
 
 
 def to_host(tree):
     """Device→host transfer of a pytree (numpy)."""
+    import jax
+
     return jax.tree_util.tree_map(lambda x: np.asarray(x), tree)
 
 
