@@ -91,3 +91,28 @@ print("jax" in sys.modules)
     src = open(os.path.join(REPO, "bench.py")).read()
     main_src = src[src.index("def main():"):src.index("def device_sync(")]
     assert "import jax" not in main_src and "jax." not in main_src
+
+
+def test_chip_smoke_schedules_no_checkpoint(tmp_path):
+    """chip_smoke.py's run writes no checkpoint (the flagship state is 5.7 GB
+    in files of over a gigabyte; a machine with a file-size limit refuses
+    them), and `checkpoint_interval: 0` is the rule that says so: neither the
+    periodic save nor the one `learn` leaves at its end."""
+    from types import SimpleNamespace
+
+    sys.path.insert(0, REPO)
+    import chip_smoke
+    from trlx_tpu.trainer.base import JaxBaseTrainer
+
+    config = chip_smoke.ppo_config(chip_smoke.REHEARSAL, 1, str(tmp_path), True)
+    assert config.train.checkpoint_interval == 0
+
+    saves = []
+    stub = SimpleNamespace(config=config, save=lambda: saves.append(1))
+    assert not any(JaxBaseTrainer.intervals(stub, s)["do_checkpoint"] for s in range(4))
+    JaxBaseTrainer._save_at_end(stub)
+    assert saves == []
+    config.train.checkpoint_interval = 2
+    assert [JaxBaseTrainer.intervals(stub, s)["do_checkpoint"] for s in (1, 2)] == [False, True]
+    JaxBaseTrainer._save_at_end(stub)
+    assert saves == [1]

@@ -77,9 +77,10 @@ class BaseRLTrainer:
         """Which per-step side effects fire
         (reference: trlx/model/__init__.py:131-140 — which reads a
         log_interval field its TrainConfig never defines; here the field
-        exists and works)."""
+        exists and works). ``checkpoint_interval: 0`` schedules no save."""
+        ci = self.config.train.checkpoint_interval
         return {
-            "do_checkpoint": steps % self.config.train.checkpoint_interval == 0,
+            "do_checkpoint": ci > 0 and steps % ci == 0,
             "do_eval": steps % self.config.train.eval_interval == 0,
             "do_log": steps % self.config.train.log_interval == 0,
         }

@@ -1073,6 +1073,12 @@ class JaxBaseTrainer(BaseRLTrainer):
                 # leaking our handler.
                 signal.signal(signal.SIGTERM, old_handler if old_handler is not None else signal.SIG_DFL)
 
+    def _save_at_end(self):
+        """The checkpoint `learn` leaves behind; `checkpoint_interval: 0`
+        turns it off along with the periodic ones."""
+        if self.config.train.checkpoint_interval > 0:
+            self.save()
+
     def _save_on_preemption(self):
         self.save()
         self.tracker.log({"preempted_at_step": self.iter_count}, step=self.iter_count)
@@ -1416,7 +1422,7 @@ class JaxBaseTrainer(BaseRLTrainer):
                         return None
 
                     if self.iter_count >= self.total_steps:
-                        self.save()
+                        self._save_at_end()
                         return self.evaluate()
                 if timer is not None:
                     train_dt = max(0.0, time.time() - train_t0 - self._phase_exclude_s)
@@ -1425,7 +1431,7 @@ class JaxBaseTrainer(BaseRLTrainer):
             self._close_batch_feed()
             self.post_epoch_callback()
 
-        self.save()
+        self._save_at_end()
         return self.evaluate()
 
     # ------------------------------------------------------------ checkpoint
