@@ -1,0 +1,19 @@
+"""Model FLOP/s utilization of the train step: the operations forward and
+backward need (benchmark/flops.py; no recomputation, no weight gradients of
+frozen blocks) over the median device time of one execution of the
+train-step program times the chip's bf16 peak."""
+
+
+def read(ctx, spec):
+    red, peaks, s = ctx["reduction"], ctx["peaks"], ctx["shapes"]
+    if not red or not peaks:
+        return None
+    seconds = ctx["trace"].median_execution_seconds(red, spec["programs"])
+    if not seconds:
+        return None
+    f = ctx["flops"]
+    if s["method"] == "ppo":
+        ops = f.ppo_train_step_flops(ctx["arch"], s["batch"], s["prompt"], s["response"], s["unfrozen"])
+    else:
+        ops = f.ilql_train_step_flops(ctx["arch"], s["batch"], s["seq"], s["unfrozen"], s["two_qs"])
+    return 100.0 * ops / (seconds * peaks["bf16_flops_per_s"])

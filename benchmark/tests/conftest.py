@@ -1,0 +1,13 @@
+"""Tests of the benchmark's own yardstick. Run by hand and by the builder:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+
+They are not part of tests/ (the repo's tier-1 suite)."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
