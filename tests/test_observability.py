@@ -38,6 +38,7 @@ from trlx_tpu.observability import spans as obs_spans  # noqa: E402
 def _span_isolation():
     """The tracer is a process global armed by trainers/tests — always disarm
     so one test's spans.jsonl (in a deleted tmp_path) never leaks forward."""
+    obs_spans.drain()
     yield
     obs_spans.shutdown()
     obs_graftscope.shutdown()
@@ -47,16 +48,27 @@ def _span_isolation():
 # ------------------------------------------------------------------- spans
 
 
-def test_trace_span_disabled_is_shared_noop():
+def _xs(events, name=None):
+    return [e for e in events if e["ph"] == "X" and (name is None or e["name"] == name)]
+
+
+def test_unarmed_span_times_and_accumulates_without_a_file(tmp_path):
+    """Unarmed is not off: a span still reads the clock twice, knows its
+    parent and feeds the accumulators; only the file is missing."""
     obs_spans.shutdown()
+    obs_spans.drain()
     assert not obs_spans.enabled()
-    a = obs_spans.trace_span("x", step=1)
-    b = obs_spans.trace_span("y")
-    assert a is b  # shared singleton: no per-call allocation on the off path
-    with a:
-        pass
-    obs_spans.complete("z", time.time())  # no-ops, no file appears
-    obs_spans.instant("w")
+    with obs_spans.trace_span("x", step=1) as s:
+        time.sleep(0.005)
+    assert 0.004 < s.seconds < 1.0 and s.parent is None and s.args == {"step": 1}
+    assert abs(s.end_s - time.time()) < 5.0  # wall clock, the profiler's
+    obs_spans.instant("w")  # kept only when armed
+    obs_spans.flush()  # nothing to write, nowhere to write it
+    acc = obs_spans.drain()
+    assert acc["self_s"] == {"x": pytest.approx(s.seconds)}
+    assert acc["top_s"] == pytest.approx(s.seconds)
+    assert obs_spans.drain() == {"self_s": {}, "top_s": 0.0}  # drained
+    assert not hasattr(obs_spans, "complete")  # the retroactive form is gone
 
 
 def test_span_lanes_survive_os_thread_ident_reuse(tmp_path):
@@ -84,10 +96,9 @@ def test_span_lanes_survive_os_thread_ident_reuse(tmp_path):
     meta = {e["tid"]: e["args"]["name"] for e in events if e["ph"] == "M"}
     assert len(meta) == 3  # three threads -> three lanes, no merging
     assert {"MainThread", "lane-a", "lane-b"} <= set(meta.values())
-    xs = [e for e in events if e["ph"] == "X"]
-    assert len({e["tid"] for e in xs if e["name"] == "bg/work"}) == 2
-    main_span = next(e for e in xs if e["name"] == "main/work")
-    assert main_span["args"] == {"step": 1}
+    assert len({e["tid"] for e in _xs(events, "bg/work")}) == 2
+    (main_span,) = _xs(events, "main/work")
+    assert main_span["args"]["step"] == 1
     assert meta[main_span["tid"]] == "MainThread"
     instant = next(e for e in events if e["ph"] == "i")
     assert instant["s"] == "t" and instant["tid"] == main_span["tid"]
@@ -97,11 +108,20 @@ def test_span_exit_on_exception_annotates_error(tmp_path):
     path = str(tmp_path / "spans.jsonl")
     obs_spans.configure(path)
     with pytest.raises(ValueError):
-        with obs_spans.trace_span("rollout/decode", step=3):
-            raise ValueError("boom")
+        with obs_spans.trace_span("outer"):
+            with obs_spans.trace_span("rollout/decode", step=3):
+                raise ValueError("boom")
+    with obs_spans.trace_span("after"):
+        pass
     obs_spans.shutdown()
-    span = next(e for e in obs_spans.read_spans(path) if e["ph"] == "X")
-    assert span["args"] == {"step": 3, "error": "ValueError"}
+    events = obs_spans.read_spans(path)
+    (inner,) = _xs(events, "rollout/decode")
+    (outer,) = _xs(events, "outer")
+    assert inner["args"]["step"] == 3 and inner["args"]["error"] == "ValueError"
+    assert outer["args"]["error"] == "ValueError" and inner["args"]["parent"] == outer["args"]["id"]
+    # the stack unwound: the next span is top-level again and carries no error
+    (after,) = _xs(events, "after")
+    assert after["args"]["parent"] is None and "error" not in after["args"]
 
 
 def test_span_file_torn_tail_tolerated_like_metrics(tmp_path):
@@ -111,7 +131,8 @@ def test_span_file_torn_tail_tolerated_like_metrics(tmp_path):
     path = str(tmp_path / "spans.jsonl")
     obs_spans.configure(path)
     for i in range(3):
-        obs_spans.complete("train/step", time.time() - 0.01, step=i)
+        with obs_spans.trace_span("train/step", step=i):
+            pass
     obs_spans.shutdown()
     with open(path, "ab") as f:
         f.write(b'{"name": "train/step", "ph": "X", "ts": 12')  # torn mid-record
@@ -127,16 +148,20 @@ def test_span_file_torn_tail_tolerated_like_metrics(tmp_path):
 
 
 def test_concurrent_span_writers_never_interleave(tmp_path):
-    """Line-atomicity under contention: many threads hammering one tracer
-    (unbuffered O_APPEND, one write(2) per record) must yield a file where
-    EVERY line parses — no interleaved or split records."""
+    """Line-atomicity under contention: many threads making spans while
+    others flush (unbuffered O_APPEND, one write(2) per batch of whole lines)
+    must yield a file where EVERY line parses and no span is lost or
+    written twice."""
     path = str(tmp_path / "spans.jsonl")
     obs_spans.configure(path)
     n_threads, n_spans = 8, 200
 
     def hammer(k):
         for i in range(n_spans):
-            obs_spans.complete("stress/span", time.time(), writer=k, i=i)
+            with obs_spans.trace_span("stress/span", writer=k, i=i):
+                pass
+            if i % 50 == k:
+                obs_spans.flush()
 
     threads = [threading.Thread(target=hammer, args=(k,), name=f"stress-{k}") for k in range(n_threads)]
     for t in threads:
@@ -148,9 +173,12 @@ def test_concurrent_span_writers_never_interleave(tmp_path):
     with open(path, "rb") as f:
         lines = [ln for ln in f.read().split(b"\n") if ln.strip()]
     events = [json.loads(ln) for ln in lines]  # raises if any line tore
-    xs = [e for e in events if e["ph"] == "X"]
+    xs = _xs(events)
     assert len(xs) == n_threads * n_spans
+    assert len({e["args"]["id"] for e in xs}) == len(xs)  # ids are unique across threads
     assert len({e["tid"] for e in xs}) == n_threads
+    acc = obs_spans.drain()
+    assert set(acc["self_s"]) == {"stress/span"} and acc["top_s"] == 0.0  # worker threads' names; not this thread's wall
 
 
 def test_span_writer_disarms_on_io_error_instead_of_raising(tmp_path):
@@ -158,10 +186,160 @@ def test_span_writer_disarms_on_io_error_instead_of_raising(tmp_path):
     obs_spans.configure(path)
     # simulate the disk going away mid-run: close the fd under the tracer
     obs_spans._STATE["tracer"]._file.close()
+    obs_spans.instant("after_close")
     with pytest.warns(UserWarning, match="span tracing disabled"):
-        obs_spans.instant("after_close")
+        obs_spans.flush()
     assert not obs_spans.enabled()
     obs_spans.instant("noop")  # disarmed: silent no-op, run continues
+    with obs_spans.trace_span("still/timed") as s:
+        pass
+    assert s.seconds >= 0.0
+
+
+@pytest.mark.parametrize("where", ["main", "worker"])
+def test_span_records_parent_and_iteration(tmp_path, where):
+    """A span names the span that encloses it ON ITS OWN THREAD and the
+    iteration the loop last set, which worker threads share."""
+    path = str(tmp_path / "spans.jsonl")
+    obs_spans.configure(path)
+
+    def work():
+        with obs_spans.trace_span("parent"):
+            with obs_spans.trace_span("child"):
+                with obs_spans.trace_span("grandchild"):
+                    pass
+            with obs_spans.trace_span("sibling"):
+                pass
+
+    obs_spans.set_iteration(7)
+    assert obs_spans.iteration() == 7
+    with obs_spans.trace_span("main/outer"):  # open on the main thread all along
+        if where == "main":
+            work()
+        else:
+            t = threading.Thread(target=work, name="trlx-worker")
+            t.start()
+            t.join()
+    obs_spans.set_iteration(8)
+    with obs_spans.trace_span("next"):
+        pass
+    obs_spans.shutdown()
+    by_name = {e["name"]: e["args"] for e in _xs(obs_spans.read_spans(path))}
+    outer = by_name["main/outer"]["id"]
+    # another thread's open span is not a cause
+    assert by_name["parent"]["parent"] == (outer if where == "main" else None)
+    assert by_name["child"]["parent"] == by_name["parent"]["id"]
+    assert by_name["grandchild"]["parent"] == by_name["child"]["id"]
+    assert by_name["sibling"]["parent"] == by_name["parent"]["id"]
+    assert {by_name[n]["iter"] for n in ("main/outer", "parent", "child", "grandchild", "sibling")} == {7}
+    assert by_name["next"]["iter"] == 8 and by_name["next"]["parent"] is None
+
+
+def test_span_self_time_and_accumulators_drain_into_the_record():
+    """Self time is duration minus children; the accumulators sum to the
+    top-level wall; a worker thread accumulates under its own names and does
+    not count towards the draining thread's top-level seconds."""
+    obs_spans.drain()
+
+    def work():
+        with obs_spans.trace_span("score/host"):
+            time.sleep(0.01)
+
+    with obs_spans.trace_span("p") as p:
+        with obs_spans.trace_span("c") as c1:
+            time.sleep(0.01)
+        with obs_spans.trace_span("c") as c2:
+            time.sleep(0.01)
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    acc = obs_spans.drain()
+    children = c1.seconds + c2.seconds
+    assert acc["self_s"]["c"] == pytest.approx(children)
+    assert acc["self_s"]["p"] == pytest.approx(p.seconds - children)
+    assert acc["self_s"]["score/host"] >= 0.01
+    assert acc["top_s"] == pytest.approx(p.seconds)  # the worker's wall is not this thread's
+    assert sum(v for k, v in acc["self_s"].items() if k != "score/host") == pytest.approx(p.seconds)
+
+
+@pytest.mark.parametrize("boundary", ["flush", "shutdown"])
+def test_armed_spans_are_written_only_at_a_boundary(tmp_path, boundary):
+    """No write(2) from inside the step loop: spans wait in memory until the
+    loop's boundary flush (or shutdown), then land as one batch."""
+    path = str(tmp_path / "spans.jsonl")
+    obs_spans.configure(path)
+    for i in range(50):
+        with obs_spans.trace_span("train/step", step=i):
+            with obs_spans.trace_span("train/dispatch"):
+                pass
+        obs_spans.instant("tick", i=i)
+    assert os.path.getsize(path) == 0
+    getattr(obs_spans, boundary)()
+    events = obs_spans.read_spans(path)
+    assert len(_xs(events, "train/step")) == 50 and len(_xs(events, "train/dispatch")) == 50
+    assert sum(e["ph"] == "i" for e in events) == 50
+    size = os.path.getsize(path)
+    obs_spans.flush()  # nothing new: nothing written
+    assert os.path.getsize(path) == size
+    obs_spans.shutdown()
+
+
+def test_spans_lie_on_the_profilers_host_plane_and_clock(tmp_path):
+    """Under a profiler session every span is a TraceAnnotation on the host
+    plane's `python` line (where benchmark/trace.py looks for the label of an
+    idle gap), armed or not, and the span's own clock reads agree with the
+    profiler's event to well under a millisecond."""
+    import jax
+    from jax.profiler import ProfileData
+
+    obs_spans.shutdown()  # unarmed: the annotation does not depend on the file
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with obs_spans.trace_span("train/step") as step:
+            with obs_spans.trace_span("train/dispatch") as dispatch:
+                time.sleep(0.003)
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = [os.path.join(b, f) for b, _, fs in os.walk(str(tmp_path)) for f in fs if f.endswith(".xplane.pb")]
+    found = {}
+    for plane in ProfileData.from_file(xplane).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                if line.name == "python":
+                    found.update({e.name: e for e in line.events if e.name.startswith("train/")})
+    assert set(found) == {"train/step", "train/dispatch"}
+    for name, span in (("train/step", step), ("train/dispatch", dispatch)):
+        assert abs(found[name].duration_ns - (span.t1 - span.t0)) < 200_000, name
+    # one clock: the child starts as long after its parent on both
+    on_profiler = found["train/dispatch"].start_ns - found["train/step"].start_ns
+    assert abs(on_profiler - (dispatch.t0 - step.t0)) < 200_000
+
+
+def test_compile_listener_counts_requests_and_marks_them(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    path = str(tmp_path / "spans.jsonl")
+    obs_spans.configure(path)
+    obs_spans.install_compile_listener()
+    obs_spans.install_compile_listener()  # once per process, whoever asks
+    x = jnp.ones((3,)).block_until_ready()
+    obs_spans.take_compiles()
+
+    def a_new_program(x):
+        return x * 3 + 1
+
+    jax.jit(a_new_program)(x).block_until_ready()
+    assert obs_spans.take_compiles() == 1
+    assert obs_spans.take_compiles() == 0
+    obs_spans.shutdown()
+    marks = [e for e in obs_spans.read_spans(path) if e["name"] == "compile"]  # the input's programs too
+    (mark,) = [e for e in marks if "a_new_program" in e["args"]["fun_name"]]
+    assert mark["ph"] == "i" and mark["args"]["seconds"] > 0
 
 
 # ------------------------------------------------------------------ anomaly
@@ -425,6 +603,91 @@ def test_e2e_overlapped_run_spans_telemetry_incident_report(task, tmp_path, monk
     assert report.main([str(tmp_path), "-o", str(out_md), "--trace-out", str(trace_out)]) == 0
     assert "slow_step" in out_md.read_text()
     assert json.loads(trace_out.read_text())["traceEvents"]
+
+
+def _main_lane_coverage(events):
+    """Share of the main thread's wall, first span start to last span end,
+    that lies inside its top-level spans (they never overlap on one thread)."""
+    main_tid = next(e["tid"] for e in events if e["ph"] == "M" and e["args"]["name"] == "MainThread")
+    top = [e for e in _xs(events) if e["tid"] == main_tid and e["args"]["parent"] is None]
+    wall = max(e["ts"] + e["dur"] for e in top) - min(e["ts"] for e in top)
+    return sum(e["dur"] for e in top) / wall
+
+
+@pytest.mark.parametrize("method", ["ppo", "ilql"])
+def test_e2e_main_path_writes_span_keys_and_leaves_little_unspanned(task, tmp_path, method):
+    """The main path of each method, tiny, on the CPU: the step records carry
+    `time/step_host_ms` and `obs/compiles`, PPO's phase-window records the
+    keys drained from the spans' accumulators with under 5% of the window's
+    wall outside every span, and the armed file groups spans by parent and
+    iteration."""
+    walks, logit_mask, metric_fn, reward_fn = task
+    config = base_config(method, 15, 8)
+    config.train.total_steps = 12
+    config.train.epochs = 6
+    config.train.batch_size = 16
+    config.train.eval_interval = 100
+    config.train.checkpoint_interval = 0
+    config.train.checkpoint_dir = str(tmp_path)
+    config.train.trace_spans = True
+    if method == "ppo":
+        config.method.num_rollouts = 16
+        config.method.chunk_size = 16
+
+        def slow_reward_fn(rows):  # a reward model takes its time; 12 calls
+            time.sleep(0.05)
+            return reward_fn(rows)
+
+        prompts = [[int(np.random.default_rng(i).integers(1, 15))] for i in range(32)]
+        trlx_tpu.train(reward_fn=slow_reward_fn, prompts=prompts, eval_prompts=[[1]], metric_fn=metric_fn,
+                       config=config, logit_mask=logit_mask)
+    else:
+        trlx_tpu.train(dataset=(walks, metric_fn(walks)["lengths"]), eval_prompts=[[1]], metric_fn=metric_fn,
+                       config=config, logit_mask=logit_mask)
+    obs_spans.shutdown()
+
+    with open(os.path.join(str(tmp_path), "metrics.jsonl")) as f:
+        records = [json.loads(line) for line in f]
+    steps = [r for r in records if "step_time" in r]
+    assert len(steps) == 12
+    for r in steps:
+        assert r["time/step_host_ms"] >= 0.0 and r["obs/compiles"] >= 0
+        # at most the wall since the previous record (plus that record's own log)
+        assert r["time/step_host_ms"] < 1e3 * (r.get("step_gap", r["step_time"]) + 0.1)
+    assert steps[0]["obs/compiles"] >= 1 and steps[-1]["obs/compiles"] == 0  # the step that compiled says so
+
+    events = obs_spans.read_spans(os.path.join(str(tmp_path), "spans.jsonl"))
+    spans_by_id = {e["args"]["id"]: e for e in _xs(events)}
+    names = {e["name"] for e in spans_by_id.values()}
+    assert {"train/data_wait", "train/batch", "train/step", "train/dispatch", "train/stats_read", "train/log"} <= names
+    for e in spans_by_id.values():
+        if e["name"] in ("train/dispatch", "train/stats_read", "train/log", "train/polyak_sync"):
+            assert spans_by_id[e["args"]["parent"]]["name"] == "train/step"
+        if e["name"] == "train/stats_wait":
+            assert spans_by_id[e["args"]["parent"]]["name"] == "train/stats_read"
+    train_steps = sorted(_xs(events, "train/step"), key=lambda e: e["args"]["step"])
+    assert [e["args"]["step"] for e in train_steps] == list(range(1, 13))
+    assert _main_lane_coverage(events) > 0.95
+
+    windows = [r for r in records if "time/window_wall_s" in r]
+    if method == "ilql":
+        assert not windows and "train/polyak_sync" in names
+        assert [e["args"]["iter"] for e in train_steps] == list(range(1, 13))  # ILQL: one step, one iteration
+        return
+    assert {"rollout/generate", "rollout/generate_dispatch", "rollout/pull", "rollout/decode", "rollout/reward_fn",
+            "rollout/score_device", "rollout/push", "boundary/kl_flush", "boundary/refresh_weights",
+            "boundary/store_clear", "boundary/loader", "boundary/phase_log"} <= names
+    assert [e["args"]["iter"] for e in train_steps] == [i // 4 for i in range(12)]  # 4 steps an iteration
+    assert len(windows) == 2  # after iterations 0 and 1 (the run ends inside the third)
+    for w in windows:
+        for key in ("time/generate_s", "time/score_device_s", "time/push_s", "time/boundary_s", "time/unspanned_s"):
+            assert w[key] >= 0.0, key
+        assert w["rollout/decode_steps"] == 7.0  # max_length 8, one-token prompts
+        assert w["time/unspanned_s"] < 0.05 * w["time/window_wall_s"]
+        # the old keys keep their meaning: rollout = generate + device scoring + push
+        assert w["time/rollout_s"] == pytest.approx(
+            w["time/generate_s"] + w["time/score_device_s"] + w["time/push_s"], rel=0.02, abs=2e-3)
+        assert w["time/score_s"] >= 0.05  # host decode + reward_fn, the sleep inside
 
 
 # ---------------------------------------------------- graftscope ledger (PR 12)

@@ -220,10 +220,11 @@ class TrainConfig:
     fleet_lease_ttl: float = 0.0
 
     # --- observability (trlx_tpu/observability/) ---
-    # Cross-thread span tracing: host-side spans from the train loop, the
-    # pipeline threads, checkpointing, and the collective guards land as
-    # Chrome trace events in <checkpoint_dir>/spans.jsonl (one lane per
-    # thread per host; open in Perfetto). TRLX_TPU_SPANS=1 overrides to on.
+    # The span FILE: host-side spans from the train loop, the pipeline
+    # threads, checkpointing, and the collective guards also land as Chrome
+    # trace events in <checkpoint_dir>/spans.jsonl (one lane per thread per
+    # host; open in Perfetto). TRLX_TPU_SPANS=1 overrides to on. The spans
+    # themselves (profiler annotations, time/* keys) need no switch.
     trace_spans: bool = False
     # Compiled-cost telemetry: capture cost_analysis()/memory_analysis() at
     # each monitored program's first dispatch and derive per-window

@@ -479,22 +479,20 @@ class RolloutEngine:
                 steps = int(n_gen[i])
                 if scope is not None:
                     # Slot-timeline harvest (host side only — GL003 keeps
-                    # clock reads out of the traced decode body): one
-                    # "engine/slot" span covering the admit→harvest life of
-                    # this episode, a harvest instant, and the straggler
-                    # sample (bucket width → decode steps) for the ledger.
+                    # clock reads out of the traced decode body): a harvest
+                    # instant and the straggler sample (bucket width →
+                    # decode steps) for the ledger.
                     now = time.time()
                     self._slot_free_t[i] = now
                     admit_t = meta.get("admit_t")
                     width = int(meta.get("width", len(meta["prompt_ids"])))
-                    if admit_t is not None:
-                        obs_spans.complete(
-                            "engine/slot", admit_t, slot=i, width=width, steps=steps
-                        )
-                    obs_spans.instant("engine/slot/harvest", slot=i, steps=steps)
-                    scope.record_harvest(
-                        i, width, steps, (now - admit_t) if admit_t is not None else 0.0
+                    life_s = (now - admit_t) if admit_t is not None else 0.0
+                    # The admit and harvest instants bracket the episode's
+                    # life in the slot; the harvest carries its length.
+                    obs_spans.instant(
+                        "engine/slot/harvest", slot=i, steps=steps, width=width, life_s=life_s
                     )
+                    scope.record_harvest(i, width, steps, life_s)
                     if self.spec_decode:
                         # Per-episode accept-rate sample (accepted tokens
                         # over window positions paid) for the /metrics

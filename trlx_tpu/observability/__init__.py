@@ -5,9 +5,11 @@
 
 Nine parts, all off-hot-path and off by default:
 
-- ``spans``     — cross-thread Chrome-trace span tracing into
-                  ``<ckpt_dir>/spans.jsonl`` (``train.trace_spans`` /
-                  ``TRLX_TPU_SPANS=1``);
+- ``spans``     — the one way host work is timed: ``with trace_span(name)``
+                  is always a profiler annotation and feeds the ``time/*``
+                  keys; armed (``train.trace_spans`` / ``TRLX_TPU_SPANS=1``)
+                  it also writes Chrome trace events to
+                  ``<ckpt_dir>/spans.jsonl``;
 - ``devicemon`` — compiled-cost capture (``cost_analysis`` /
                   ``memory_analysis``) for every jitted program, real-FLOPs
                   MFU gauges, kernel-routing + device-memory gauges

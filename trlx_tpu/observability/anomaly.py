@@ -121,6 +121,12 @@ class IncidentCapture:
                 return ""
             self.captured += 1
         bundle = os.path.join(self.directory, str(int(step)))
+        n = 1
+        while os.path.exists(os.path.join(bundle, "incident.json")):
+            # a second incident at the same step (another detector, another
+            # thread) keeps the first one's evidence: <step>.2, <step>.3 ...
+            n += 1
+            bundle = os.path.join(self.directory, f"{int(step)}.{n}")
         os.makedirs(bundle, exist_ok=True)
 
         t0 = time.time()
@@ -199,6 +205,7 @@ class IncidentCapture:
         from trlx_tpu.observability import spans
 
         spans.instant("incident", step=int(step), reason=reason)
+        spans.flush()  # an incident is a boundary: its span tail is evidence
         print(
             f"[trlx_tpu.observability] incident captured at step {step} "
             f"({reason}) -> {bundle}",

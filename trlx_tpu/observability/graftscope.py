@@ -32,7 +32,7 @@ nothing to diagnose". This module adds both halves:
   died — bench_trajectory.py surfaces that instead of ``no_data``.
 
 Armed by ``train.graftscope`` / ``TRLX_TPU_GRAFTSCOPE``, off by default.
-Disabled, every hook is one module-dict load (the spans.py contract): no
+Disabled, every hook is one module-dict load: no
 clock read, no allocation — the serial path is byte-identical. Armed, the
 ledger must never take down the run it observes: fence failures (donated
 buffers already consumed by the next step) are counted and dropped, and

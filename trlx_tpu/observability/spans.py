@@ -1,47 +1,65 @@
-"""Cross-thread span tracing: Chrome-trace-event JSONL with thread lanes.
+"""Host spans: the one way the main path times host work.
 
-The overlapped pipeline (pipeline/overlap.py) runs four concurrent actors —
-the main train loop, ``trlx-rollout-producer``, ``trlx-score-worker``, and
-``trlx-prefetch`` — but metrics.jsonl only records per-window scalar sums, so
-"the overlap fraction was 0.4" is the MOST detailed statement the framework
-can make about where a window's wall clock went. This tracer turns that into
-a picture: host-side code wraps its phases in ``with trace_span(name):`` and
-each span lands as one Chrome trace event (``ph:"X"``) in
-``<checkpoint_dir>/spans.jsonl``, with ``pid`` = the JAX process index and
-``tid`` = a synthetic per-thread lane id, so Perfetto (https://ui.perfetto.dev
-— it opens JSONL event streams directly) renders one lane per thread per host
-and the producer/train overlap is visible as literally-overlapping boxes.
+``with trace_span(name, **args) as s:`` is the only form. Every span, armed or
+not,
 
-Design constraints, in order:
+- enters a ``jax.profiler.TraceAnnotation(name)``: a no-op unless a profiler
+  session is open, and then the span lies on the profiler's own timeline
+  beside the device's operations, so an idle gap of the device reads as the
+  span the host was in (benchmark/trace.py labels gaps this way);
+- reads the clock twice (``time.time_ns()``, the clock the profiler stamps its
+  events with); ``s.seconds`` is available after exit;
+- knows the span that encloses it on the same thread (its cause) and the
+  **iteration id** the loop last set with ``set_iteration(n)`` (PPO: one per
+  rollout + the training on it; ILQL: one per step);
+- adds its **self-seconds** (duration minus its children's) to a process-wide
+  accumulator keyed by name, and a top-level span adds its duration to its
+  thread's total. ``drain()`` hands both to the loop, which writes them into
+  the phase-window record it already writes (``time/generate_s``,
+  ``time/boundary_s``, ``time/unspanned_s`` ...). Worker threads accumulate
+  under their own span names.
 
-- **Off by default, zero residue.** ``trace_span`` returns a shared no-op
-  context manager until ``configure(path=...)`` arms the module global — no
-  allocation, no clock read, no branch beyond one dict load. The serial
-  path with spans disabled is byte-identical to pre-instrumentation runs.
-- **Crash-tolerant like metrics.jsonl.** The file is opened unbuffered in
-  O_APPEND mode and every event is ONE complete newline-terminated
-  ``write(2)`` — a process killed mid-run (preemption, ``host_kill`` drill)
-  can tear at most the final line, which ``read_spans`` tolerates, and
-  concurrent appenders (multiple threads; multiple hosts sharing a
-  checkpoint dir) can never interleave mid-record.
-- **Never kill the run it observes.** Every write is wrapped: an I/O error
-  disables the tracer with one warning instead of propagating into the
-  train loop.
+**Only when armed** (``configure(path=...)``: ``train.trace_spans`` /
+``TRLX_TPU_SPANS=1``) spans are also kept in memory and land as Chrome trace
+events (``ph:"X"``) in ``<checkpoint_dir>/spans.jsonl``, with ``pid`` = the
+JAX process index, ``tid`` = a synthetic per-thread lane id, and
+``args.id`` / ``args.parent`` / ``args.iter`` beside the site's own args, so
+Perfetto (https://ui.perfetto.dev opens JSONL event streams directly)
+renders one lane per thread per host. The file is written by ``flush()`` at
+iteration boundaries (PPO: the end of ``post_epoch_callback``; ILQL: the log
+boundary), at an incident and at ``shutdown()``: one ``write(2)`` per batch of
+lines, never from inside a span.
+
+File contracts, as for metrics.jsonl:
+
+- **Crash-tolerant.** The file is opened unbuffered in O_APPEND mode and a
+  batch is ONE ``write(2)`` of whole lines: a process killed mid-run can
+  tear at most the final line, which ``read_spans`` tolerates, and concurrent
+  appenders (multiple hosts sharing a checkpoint dir) never interleave
+  mid-record. What a killed process loses is the spans since the last
+  boundary.
+- **Never kill the run it observes.** An I/O error disarms the writer with
+  one warning instead of propagating into the train loop.
 
 Event vocabulary (the Chrome trace-event format's subset we emit):
 
-- ``ph:"X"`` complete spans — ``ts``/``dur`` in microseconds of wall clock
-  (``time.time()`` base, so multi-host lanes align on real time);
-- ``ph:"i"`` instants — point events (collective timeouts, watchdog fires);
-- ``ph:"M"`` metadata — one ``thread_name`` record per (pid, tid), emitted
-  lazily at the thread's first event, so lanes carry the ``trlx-*`` names.
+- ``ph:"X"`` complete spans, ``ts``/``dur`` in microseconds of wall clock
+  (Unix epoch, so multi-host lanes align on real time);
+- ``ph:"i"`` instants: point events (collective timeouts, watchdog fires,
+  ``compile`` with the program's name and seconds);
+- ``ph:"M"`` metadata: one ``thread_name`` record per (pid, tid), emitted
+  at the thread's first event, so lanes carry the ``trlx-*`` names.
 """
 
+import itertools
+import json
 import os
 import re
 import threading
 import time
 import warnings
+
+import jax
 
 from trlx_tpu.utils import jsonl
 
@@ -49,9 +67,14 @@ __all__ = [
     "configure",
     "shutdown",
     "enabled",
+    "flush",
     "trace_span",
-    "complete",
     "instant",
+    "set_iteration",
+    "iteration",
+    "drain",
+    "install_compile_listener",
+    "take_compiles",
     "read_spans",
     "read_fleet_spans",
     "host_spans_filename",
@@ -70,6 +93,9 @@ FLEET_CLOCK_FILENAME = "fleet_clock.jsonl"
 # k * TID_STRIDE + t and overlapping tids across hosts can never collide
 # even if a file's pid tags are missing or wrong.
 TID_STRIDE = 1000
+# The event jax.monitoring reports each backend compile request under (cache
+# retrievals included); benchmark/harness.py's CompileLog listens to the same.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _HOST_SPANS_RE = re.compile(r"^spans\.host(\d+)\.jsonl$")
 
@@ -82,28 +108,15 @@ def host_spans_filename(process_index: int) -> str:
     return f"spans.host{int(process_index)}.jsonl"
 
 
-class _NullSpan:
-    """Shared, reentrant no-op context manager — the disabled fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc_info):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class SpanTracer:
-    """Appends Chrome trace events to one JSONL file, line-atomically."""
+    """Keeps Chrome trace events in memory and appends them to one JSONL
+    file a batch at a time."""
 
     def __init__(self, path: str, process_index: int = 0):
         self.path = path
         self.pid = int(process_index)
         self._file = jsonl.open_line_atomic(path)
+        self._events = []  # list.append is atomic under the GIL
         # Synthetic per-thread-OBJECT lane ids, stored thread-locally. Raw
         # thread.ident would be simpler but the OS reuses idents: a rollout
         # producer starting after an epoch's prefetch thread exits can
@@ -113,22 +126,13 @@ class SpanTracer:
         self._next_tid = 0
         self._name_lock = threading.Lock()
 
-    def _emit(self, event: dict):
-        try:
-            # ONE write call per record → line-atomic under O_APPEND.
-            jsonl.write_record(self._file, event)
-        except (OSError, ValueError):
-            # ValueError: write on a closed file (late event during teardown).
-            # Tracing must never take down the run it observes — disarm.
-            _disarm_on_error(self)
-
     def _tid(self) -> int:
         tid = getattr(self._local, "tid", None)
         if tid is None:
             with self._name_lock:
                 self._next_tid += 1
                 tid = self._local.tid = self._next_tid
-            self._emit(
+            self._events.append(
                 {
                     "name": "thread_name",
                     "ph": "M",
@@ -139,43 +143,65 @@ class SpanTracer:
             )
         return tid
 
-    def complete(self, name: str, t0: float, t1: float, args: dict):
-        self._emit(
+    def span(self, span, error):
+        args = dict(span.args, id=span.id, parent=span.parent.id if span.parent else None, iter=span.iter)
+        if error is not None:
+            args["error"] = error
+        self._events.append(
             {
-                "name": name,
+                "name": span.name,
                 "ph": "X",
                 "pid": self.pid,
                 "tid": self._tid(),
-                "ts": int(t0 * 1e6),
-                "dur": max(0, int((t1 - t0) * 1e6)),
-                **({"args": args} if args else {}),
+                "ts": span.t0 // 1000,
+                "dur": max(0, (span.t1 - span.t0) // 1000),
+                "args": args,
             }
         )
 
     def instant(self, name: str, args: dict):
-        self._emit(
+        self._events.append(
             {
                 "name": name,
                 "ph": "i",
                 "s": "t",  # thread-scoped instant
                 "pid": self.pid,
                 "tid": self._tid(),
-                "ts": int(time.time() * 1e6),
+                "ts": time.time_ns() // 1000,
                 **({"args": args} if args else {}),
             }
         )
 
+    def flush(self):
+        events, self._events = self._events, []
+        if not events:
+            return
+        try:
+            # ONE write call per batch of whole lines -> line-atomic under O_APPEND.
+            self._file.write("".join(json.dumps(e) + "\n" for e in events).encode("utf-8"))
+        except (OSError, ValueError):
+            # ValueError: write on a closed file (late flush during teardown).
+            # Tracing must never take down the run it observes: disarm.
+            _disarm_on_error(self)
+
     def close(self):
+        self.flush()
         try:
             self._file.close()
         except OSError:
             pass
 
 
-# Process-global tracer, armed once by the trainer. A module global (not a
-# trainer attribute) because the emitting sites span orchestrators, pipeline
+# Process-global state, armed once by the trainer. Module globals (not trainer
+# attributes) because the emitting sites span orchestrators, pipeline
 # threads, and resilience guards that do not all hold a trainer reference.
-_STATE = {"tracer": None}
+_STATE = {"tracer": None, "iter": 0, "compiles": 0, "listening": False}
+# .stack: this thread's open spans, outermost first; .top_ns: nanoseconds it
+# spent inside top-level spans since it last drained
+_LOCAL = threading.local()
+_IDS = itertools.count(1)  # next() is atomic under the GIL
+_ACC_LOCK = threading.Lock()
+_SELF_S = {}  # span name -> self-seconds since the last drain(), every thread's
 
 
 def _disarm_on_error(tracer):
@@ -183,15 +209,15 @@ def _disarm_on_error(tracer):
         _STATE["tracer"] = None
         warnings.warn(
             f"span tracing disabled: writing {tracer.path} failed "
-            "(disk full / closed file?) — the run continues untraced",
+            "(disk full / closed file?): the run continues untraced",
             stacklevel=3,
         )
 
 
 def configure(path=None, process_index=0):
-    """Arm (path given) or disarm (path=None) the process-global tracer.
+    """Arm (path given) or disarm (path=None) the span file.
 
-    ``process_index`` becomes the trace's ``pid`` lane group — pass
+    ``process_index`` becomes the trace's ``pid`` lane group: pass
     ``jax.process_index()`` so multi-host runs sharing a checkpoint dir get
     one lane group per host."""
     old, _STATE["tracer"] = _STATE["tracer"], None
@@ -209,50 +235,119 @@ def enabled() -> bool:
     return _STATE["tracer"] is not None
 
 
-class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+def flush():
+    """Write the spans kept since the last flush (armed only). The loops call
+    this at their iteration boundary, never from inside a step."""
+    tracer = _STATE["tracer"]
+    if tracer is not None:
+        tracer.flush()
 
-    def __init__(self, tracer, name, args):
-        self._tracer = tracer
-        self._name = name
-        self._args = args
+
+def set_iteration(n: int):
+    """The id every span records until the next call, on every thread."""
+    _STATE["iter"] = int(n)
+
+
+def iteration() -> int:
+    return _STATE["iter"]
+
+
+class _Span:
+    __slots__ = ("name", "args", "id", "parent", "iter", "t0", "t1", "_children_ns", "_annotation")
+
+    def __init__(self, name, args):
+        self.name = name
+        self.args = args
+        self.t1 = None
 
     def __enter__(self):
-        self._t0 = time.time()
+        stack = getattr(_LOCAL, "stack", None)
+        if stack is None:
+            stack = _LOCAL.stack = []
+        self.parent = stack[-1] if stack else None
+        self.id = next(_IDS)
+        self.iter = _STATE["iter"]
+        self._children_ns = 0
+        stack.append(self)
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self.t0 = time.time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if exc_type is not None:
-            self._args = dict(self._args or {})
-            self._args["error"] = exc_type.__name__
-        self._tracer.complete(self._name, self._t0, time.time(), self._args)
+        self.t1 = time.time_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
+        _LOCAL.stack.pop()
+        duration = self.t1 - self.t0
+        with _ACC_LOCK:
+            _SELF_S[self.name] = _SELF_S.get(self.name, 0.0) + (duration - self._children_ns) * 1e-9
+        if self.parent is None:
+            _LOCAL.top_ns = getattr(_LOCAL, "top_ns", 0) + duration
+        else:
+            self.parent._children_ns += duration
+        tracer = _STATE["tracer"]
+        if tracer is not None:
+            tracer.span(self, exc_type.__name__ if exc_type is not None else None)
         return False
+
+    @property
+    def start_s(self) -> float:
+        """Start, in seconds of wall clock (``time.time()``'s scale)."""
+        return self.t0 * 1e-9
+
+    @property
+    def end_s(self) -> float:
+        return self.t1 * 1e-9
+
+    @property
+    def seconds(self) -> float:
+        """Duration; readable after exit."""
+        return (self.t1 - self.t0) * 1e-9
 
 
 def trace_span(name: str, **args):
-    """``with trace_span("rollout/decode", step=n):`` — records one complete
-    span on the calling thread's lane. Returns a shared no-op when tracing
-    is off, so instrumented code pays one dict load on the serial path."""
-    tracer = _STATE["tracer"]
-    if tracer is None:
-        return _NULL_SPAN
-    return _Span(tracer, name, args)
+    """``with trace_span("rollout/decode", step=n) as s:`` times one interval
+    of host work on the calling thread (module docstring)."""
+    return _Span(name, args)
 
 
-def complete(name: str, t0: float, **args):
-    """Emit a span that STARTED at ``t0`` (``time.time()`` seconds) and ends
-    now — for sites that already hold a phase start timestamp (the per-step
-    train span) and must not restructure into a ``with`` block."""
-    tracer = _STATE["tracer"]
-    if tracer is not None:
-        tracer.complete(name, t0, time.time(), args)
+def drain() -> dict:
+    """Hand over and reset the accumulators: ``{"self_s": {name: self-seconds
+    of every span that ended since the last drain, on any thread}, "top_s":
+    seconds the CALLING thread spent inside its top-level spans}``."""
+    with _ACC_LOCK:
+        self_s = dict(_SELF_S)
+        _SELF_S.clear()
+    top_ns, _LOCAL.top_ns = getattr(_LOCAL, "top_ns", 0), 0
+    return {"self_s": self_s, "top_s": top_ns * 1e-9}
 
 
 def instant(name: str, **args):
-    """Emit a point event (watchdog fired, collective timed out, incident)."""
+    """Emit a point event (watchdog fired, collective timed out, incident);
+    kept only when armed."""
     tracer = _STATE["tracer"]
     if tracer is not None:
         tracer.instant(name, args)
+
+
+def _on_duration(event, duration, **kw):
+    if event == COMPILE_EVENT:
+        _STATE["compiles"] += 1
+        instant("compile", fun_name=str(kw.get("fun_name", "?")), seconds=float(duration))
+
+
+def install_compile_listener():
+    """Count the process's backend compile requests (jax.monitoring) and mark
+    each as a ``compile`` instant; once per process, whoever calls."""
+    if not _STATE["listening"]:
+        _STATE["listening"] = True
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def take_compiles() -> int:
+    """Compile requests since the last call (the step record's ``obs/compiles``)."""
+    n, _STATE["compiles"] = _STATE["compiles"], 0
+    return n
 
 
 def read_spans(path: str):

@@ -211,6 +211,7 @@ def _fwd(q, k, v, kmask, off, scale, causal, window, bq, bk, interpret):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",  # how a device trace names the call
         grid=(BH, nq, nk),
         in_specs=[
             _smem_spec(),
@@ -357,6 +358,7 @@ def _flash_lse_bwd(scale, causal, window, bq, bk, interpret, res, cts):
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
+        name="flash_bwd_dq",
         grid=(BH, nq, nk),
         in_specs=[
             _smem_spec(),
@@ -379,6 +381,7 @@ def _flash_lse_bwd(scale, causal, window, bq, bk, interpret, res, cts):
     # accumulate in VMEM scratch across the whole q range.
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
+        name="flash_bwd_dkv",
         grid=(BH, nk, nq),
         in_specs=[
             _smem_spec(),

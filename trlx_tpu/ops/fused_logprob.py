@@ -309,6 +309,7 @@ def _fwd_call(x, w, bias, labels, tied, bn, bv, interpret):
     operands = [x, w] + ([bias] if has_bias else []) + [labels]
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, V=V, bv=bv, tied=tied, has_bias=has_bias),
+        name="logprob_head_fwd",  # how a device trace names the call
         grid=grid,
         in_specs=in_specs,
         out_specs=[row_spec] * 3,
@@ -333,6 +334,7 @@ def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv, inte
     operands = [x, w] + ([bias] if has_bias else []) + row_operands
     dx = pl.pallas_call(
         functools.partial(_bwd_dx_kernel, V=V, bv=bv, tied=tied, has_bias=has_bias),
+        name="logprob_head_bwd_dx",
         grid=(N // bn, nv),
         in_specs=in_specs,
         out_specs=_vmem((bn, D), lambda i, j: (i, 0)),
@@ -355,6 +357,7 @@ def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv, inte
     if has_bias:
         out = pl.pallas_call(
             functools.partial(_bwd_dw_kernel, V=V, bv=bv, tied=tied, has_bias=True),
+            name="logprob_head_bwd_dw",
             grid=(nv, N // bn),
             in_specs=in_specs,
             out_specs=[dw_spec, _vmem((1, bv), lambda j, i: (0, j))],
@@ -367,6 +370,7 @@ def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv, inte
     else:
         dw = pl.pallas_call(
             functools.partial(_bwd_dw_kernel, V=V, bv=bv, tied=tied, has_bias=False),
+            name="logprob_head_bwd_dw",
             grid=(nv, N // bn),
             in_specs=in_specs,
             out_specs=dw_spec,

@@ -127,15 +127,18 @@ def generate(
                 "replicate the cache per device"
             )
     extra = apply_kwargs or {}
-    out = model.apply(
-        variables,
-        input_ids=prompt_ids,
-        attention_mask=prompt_mask,
-        cache=cache,
-        cache_index=0,
-        cache_mask=with_soft(mask),
-        **extra,
-    )
+    # prefill and the decode loop are one program; the scopes tell them apart
+    # in a device trace.
+    with jax.named_scope("prefill"):
+        out = model.apply(
+            variables,
+            input_ids=prompt_ids,
+            attention_mask=prompt_mask,
+            cache=cache,
+            cache_index=0,
+            cache_mask=with_soft(mask),
+            **extra,
+        )
     prefill_extras = {k: out[k] for k in prefill_collect}
 
     def last_pos(tree):
@@ -239,7 +242,8 @@ def generate(
             }
         return new_s
 
-    final = jax.lax.while_loop(cond, body, state)
+    with jax.named_scope("decode_loop"):
+        final = jax.lax.while_loop(cond, body, state)
     if step_stats_fn is not None and prefill_collect:
         return final["tokens"], final["mask"], final["stats"], prefill_extras
     if step_stats_fn is not None:

@@ -26,7 +26,7 @@ from collections import deque
 import jax
 import numpy as np
 
-from trlx_tpu.observability.spans import complete as span_complete, trace_span
+from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
 from trlx_tpu.pipeline.overlap import ScoreWorker
 from trlx_tpu.resilience.faults import FaultInjected
@@ -264,34 +264,33 @@ class PPOOrchestrator(Orchestrator):
             # Store holds process-local rows; put_batch re-shards them on the
             # way back to the device at train time.
             nonlocal push_s
-            t0 = time.time()
-            # With prompt bucketing the chunks arrive at per-bucket widths P,
-            # but the rollout store fixes its query width on the FIRST push
-            # and the train step compiles at the single full prompt_length —
-            # so the query region is re-left-padded to the trainer's global
-            # width here, on the host, before storage. Pad rows are mask-0:
-            # the training forward sees exactly the tokens generation saw.
-            q_ids, q_mask = tokens_h[:, :P], mask_h[:, :P]
-            P_full = int(getattr(rl, "prompt_length", P))
-            if P < P_full:
-                pad_id = int(getattr(rl, "pad_token_id", 0))
-                pad = np.full((q_ids.shape[0], P_full - P), pad_id, dtype=np.asarray(q_ids).dtype)
-                q_ids = np.concatenate([pad, q_ids], axis=1)
-                q_mask = np.concatenate([np.zeros_like(pad), np.asarray(q_mask)], axis=1)
-            rows = {
-                "query_tensors": q_ids,
-                "query_mask": q_mask,
-                "response_tensors": tokens_h[:, P:],
-                "response_mask": mask_h[:, P:],
-                "logprobs": logprobs,
-                "values": values,
-                "rewards": rewards,
-            }
-            if record_staleness:
-                rows["staleness"] = np.full((q_ids.shape[0], 1), float(staleness), dtype=np.float32)
-            store.push_batch(rows)
-            push_s += time.time() - t0
-            span_complete("rollout/push", t0, rows=int(q_ids.shape[0]))
+            with trace_span("rollout/push", rows=int(tokens_h.shape[0])) as span:
+                # With prompt bucketing the chunks arrive at per-bucket widths P,
+                # but the rollout store fixes its query width on the FIRST push
+                # and the train step compiles at the single full prompt_length —
+                # so the query region is re-left-padded to the trainer's global
+                # width here, on the host, before storage. Pad rows are mask-0:
+                # the training forward sees exactly the tokens generation saw.
+                q_ids, q_mask = tokens_h[:, :P], mask_h[:, :P]
+                P_full = int(getattr(rl, "prompt_length", P))
+                if P < P_full:
+                    pad_id = int(getattr(rl, "pad_token_id", 0))
+                    pad = np.full((q_ids.shape[0], P_full - P), pad_id, dtype=np.asarray(q_ids).dtype)
+                    q_ids = np.concatenate([pad, q_ids], axis=1)
+                    q_mask = np.concatenate([np.zeros_like(pad), np.asarray(q_mask)], axis=1)
+                rows = {
+                    "query_tensors": q_ids,
+                    "query_mask": q_mask,
+                    "response_tensors": tokens_h[:, P:],
+                    "response_mask": mask_h[:, P:],
+                    "logprobs": logprobs,
+                    "values": values,
+                    "rewards": rewards,
+                }
+                if record_staleness:
+                    rows["staleness"] = np.full((q_ids.shape[0], 1), float(staleness), dtype=np.float32)
+                store.push_batch(rows)
+            push_s += span.seconds
 
         def finish_chunk(ctx, scored):
             # Device scoring + pulls + store push for one scored chunk. Runs
@@ -299,18 +298,17 @@ class PPOOrchestrator(Orchestrator):
             # on one thread, so program order is deterministic.
             nonlocal score_s, last_scores, last_kl
             scores, reward_call = scored
-            t0 = time.time()
-            if ctx["gen_aux"] is not None:
-                logprobs, values, rewards, kl = rl.rollout_score_fused(
-                    ctx["tokens"], ctx["mask"], scores, ctx["gen_aux"], snapshot=snapshot
-                )
-            else:
-                logprobs, values, rewards, kl = rl.rollout_score(
-                    ctx["tokens"], ctx["mask"], scores, snapshot=snapshot
-                )
-            logprobs, values, rewards, kl = rl.to_local_host((logprobs, values, rewards, kl))
-            score_s += time.time() - t0
-            span_complete("rollout/score_device", t0, step=iter_count)
+            with trace_span("rollout/score_device", step=iter_count) as span:
+                if ctx["gen_aux"] is not None:
+                    logprobs, values, rewards, kl = rl.rollout_score_fused(
+                        ctx["tokens"], ctx["mask"], scores, ctx["gen_aux"], snapshot=snapshot
+                    )
+                else:
+                    logprobs, values, rewards, kl = rl.rollout_score(
+                        ctx["tokens"], ctx["mask"], scores, snapshot=snapshot
+                    )
+                logprobs, values, rewards, kl = rl.to_local_host((logprobs, values, rewards, kl))
+            score_s += span.seconds
             push_rows(ctx["tokens_h"], ctx["mask_h"], ctx["P"], logprobs, values, rewards)
             note_chunk(ctx["tokens_h"], ctx["mask_h"], ctx["P"], scores, reward_call)
             last_scores, last_kl = np.asarray(scores), kl
@@ -330,10 +328,13 @@ class PPOOrchestrator(Orchestrator):
             # Lands on whichever thread runs the scoring (the ScoreWorker's
             # lane when overlap is on, the main lane otherwise) — exactly the
             # attribution the trace viewer should show.
-            with trace_span("rollout/decode", step=iter_count):
+            nonlocal reward_s
+            with trace_span("rollout/decode", step=iter_count) as decode:
                 texts_or_tokens = rl.decode(tokens_h, mask_h)
-            with trace_span("rollout/reward_fn", step=iter_count):
+            with trace_span("rollout/reward_fn", step=iter_count) as reward:
                 scores = np.asarray(self.score(texts_or_tokens), dtype=np.float32)
+            if worker is None:
+                reward_s += decode.seconds + reward.seconds  # the worker times its own busy_s
             # The call index this chunk was scored under (scoring runs
             # sequentially on one thread, so the counter is stable here) —
             # finish_chunk hands it to the health monitor's lineage feed.
@@ -347,10 +348,10 @@ class PPOOrchestrator(Orchestrator):
             worker = ScoreWorker(host_score, depth=depth)
             inflight = deque()
 
-        t = time.time()
-        pending = self._generate_next_chunk(snapshot=snapshot)
-        gen_s += time.time() - t
-        span_complete("rollout/generate", t, step=iter_count, dispatch=True)
+        with trace_span("rollout/generate", step=iter_count, dispatch=True) as span:
+            with trace_span("rollout/generate_dispatch"):
+                pending = self._generate_next_chunk(snapshot=snapshot)
+        gen_s += span.seconds
         heartbeat = getattr(rl, "heartbeat", None)
         aborted = False
         try:
@@ -379,17 +380,17 @@ class PPOOrchestrator(Orchestrator):
                     )
                 chunk_rows = int(tokens.shape[0]) // n_proc
                 need_more = n_collected + chunk_rows < num_rollouts
-                t = time.time()
-                if need_more:
-                    pending = self._generate_next_chunk(snapshot=snapshot)
-
-                # ONE device→host pull of the generation grids per chunk —
-                # both reward paths and the store push reuse these host rows.
-                tokens_h, mask_h = rl.to_local_host((tokens, mask))
-                gen_s += time.time() - t
-                # Generate-BLOCKED wall (next-chunk dispatch + this chunk's
-                # grid pull): the span twin of the gen_s accounting above.
-                span_complete("rollout/generate", t, step=iter_count)
+                # Generate-BLOCKED wall: the next chunk's dispatch and this
+                # chunk's grid pull, each a child span.
+                with trace_span("rollout/generate", step=iter_count) as span:
+                    if need_more:
+                        with trace_span("rollout/generate_dispatch"):
+                            pending = self._generate_next_chunk(snapshot=snapshot)
+                    # ONE device→host pull of the generation grids per chunk —
+                    # both reward paths and the store push reuse these host rows.
+                    with trace_span("rollout/pull"):
+                        tokens_h, mask_h = rl.to_local_host((tokens, mask))
+                gen_s += span.seconds
                 ds = rl.rollout_decode_stats(mask_h, P)
                 gen_tokens += ds["gen_tokens"]
                 decode_steps.append(ds["decode_steps"])
@@ -401,16 +402,15 @@ class PPOOrchestrator(Orchestrator):
                     # logprobs/values, hydra ref KL, RM scores) is ONE fused
                     # sharded program — no decode, no host reward boundary
                     # (and so nothing for a score worker to overlap).
-                    t = time.time()
-                    logprobs, values, rewards, kl, scores = rl.rollout_score_rm(
-                        tokens, mask, snapshot=snapshot
-                    )
-                    scores = rl.to_local_host(scores)
-                    logprobs, values, rewards, kl = rl.to_local_host(
-                        (logprobs, values, rewards, kl)
-                    )
-                    score_s += time.time() - t
-                    span_complete("rollout/score_rm", t, step=iter_count)
+                    with trace_span("rollout/score_rm", step=iter_count) as span:
+                        logprobs, values, rewards, kl, scores = rl.rollout_score_rm(
+                            tokens, mask, snapshot=snapshot
+                        )
+                        scores = rl.to_local_host(scores)
+                        logprobs, values, rewards, kl = rl.to_local_host(
+                            (logprobs, values, rewards, kl)
+                        )
+                    score_s += span.seconds
                     push_rows(tokens_h, mask_h, P, logprobs, values, rewards)
                     note_chunk(tokens_h, mask_h, P, scores)
                     last_scores, last_kl = np.asarray(scores), kl
@@ -433,9 +433,7 @@ class PPOOrchestrator(Orchestrator):
                     while inflight and (len(inflight) > depth or worker.ready()):
                         finish_chunk(inflight.popleft(), worker.result())
                 else:
-                    t = time.time()
                     scores = host_score((tokens_h, mask_h))
-                    reward_s += time.time() - t
                     # Device: score rollouts. Fused: ref-branch replay only,
                     # the policy stats rode along with generation. Unfused:
                     # full policy forward + ref logits + KL in one program.
@@ -504,7 +502,10 @@ class PPOOrchestrator(Orchestrator):
         if record_staleness:
             stats["exp_staleness"] = float(staleness)
         # Surfaced by progress_line at the next log boundary.
-        rl._last_exp_stats = {"exp_per_sec": stats["exp_per_sec"]}
+        rl._last_exp_stats = {
+            "exp_per_sec": stats["exp_per_sec"],
+            "rollout/decode_steps": stats["exp_decode_dispatches"],
+        }
         rl.tracker.log(stats, step=iter_count)
 
     def _make_experience_engine(
@@ -588,23 +589,22 @@ class PPOOrchestrator(Orchestrator):
         def push_rows(tokens_h, mask_h, logprobs, values, rewards):
             # Episodes are assembled at P_full already — no re-padding.
             nonlocal push_s
-            t0 = time.time()
-            rows = {
-                "query_tensors": tokens_h[:, :P_full],
-                "query_mask": mask_h[:, :P_full],
-                "response_tensors": tokens_h[:, P_full:],
-                "response_mask": mask_h[:, P_full:],
-                "logprobs": logprobs,
-                "values": values,
-                "rewards": rewards,
-            }
-            if record_staleness:
-                rows["staleness"] = np.full(
-                    (tokens_h.shape[0], 1), float(staleness), dtype=np.float32
-                )
-            store.push_batch(rows)
-            push_s += time.time() - t0
-            span_complete("rollout/push", t0, rows=int(tokens_h.shape[0]))
+            with trace_span("rollout/push", rows=int(tokens_h.shape[0])) as span:
+                rows = {
+                    "query_tensors": tokens_h[:, :P_full],
+                    "query_mask": mask_h[:, :P_full],
+                    "response_tensors": tokens_h[:, P_full:],
+                    "response_mask": mask_h[:, P_full:],
+                    "logprobs": logprobs,
+                    "values": values,
+                    "rewards": rewards,
+                }
+                if record_staleness:
+                    rows["staleness"] = np.full(
+                        (tokens_h.shape[0], 1), float(staleness), dtype=np.float32
+                    )
+                store.push_batch(rows)
+            push_s += span.seconds
 
         def finish_chunk(ctx, scored):
             # Device scoring + pulls + store push; make_experience thread
@@ -612,26 +612,25 @@ class PPOOrchestrator(Orchestrator):
             # path always scores UNFUSED (full policy forward): sampled-token
             # stats never rode along with slot decode.
             nonlocal score_s, last_scores, last_kl
-            t0 = time.time()
-            if has_rm:
-                # On-device learned RM over the harvested chunk: policy
-                # logprobs/values, hydra ref KL, and RM scores in ONE
-                # sharded program — the same rollout_score_rm the chunked
-                # path runs, fed assembled engine episodes. ``scored`` is
-                # None on this branch (host_score never ran).
-                reward_call = None
-                logprobs, values, rewards, kl, scores = rl.rollout_score_rm(
-                    ctx["tokens"], ctx["mask"], snapshot=snapshot
-                )
-                scores = rl.to_local_host(scores)
-            else:
-                scores, reward_call = scored
-                logprobs, values, rewards, kl = rl.rollout_score(
-                    ctx["tokens"], ctx["mask"], scores, snapshot=snapshot
-                )
-            logprobs, values, rewards, kl = rl.to_local_host((logprobs, values, rewards, kl))
-            score_s += time.time() - t0
-            span_complete("rollout/score_device", t0, step=iter_count)
+            with trace_span("rollout/score_device", step=iter_count) as span:
+                if has_rm:
+                    # On-device learned RM over the harvested chunk: policy
+                    # logprobs/values, hydra ref KL, and RM scores in ONE
+                    # sharded program — the same rollout_score_rm the chunked
+                    # path runs, fed assembled engine episodes. ``scored`` is
+                    # None on this branch (host_score never ran).
+                    reward_call = None
+                    logprobs, values, rewards, kl, scores = rl.rollout_score_rm(
+                        ctx["tokens"], ctx["mask"], snapshot=snapshot
+                    )
+                    scores = rl.to_local_host(scores)
+                else:
+                    scores, reward_call = scored
+                    logprobs, values, rewards, kl = rl.rollout_score(
+                        ctx["tokens"], ctx["mask"], scores, snapshot=snapshot
+                    )
+                logprobs, values, rewards, kl = rl.to_local_host((logprobs, values, rewards, kl))
+            score_s += span.seconds
             push_rows(ctx["tokens_h"], ctx["mask_h"], logprobs, values, rewards)
             if monitor is not None:
                 monitor.observe_chunk(
@@ -727,10 +726,9 @@ class PPOOrchestrator(Orchestrator):
                     # collective guard must turn this into exit 117 + an
                     # incident bundle naming this host and their slot states.
                     os._exit(1)
-                t = time.time()
-                eps = engine.step()
-                gen_s += time.time() - t
-                span_complete("rollout/generate", t, step=iter_count, engine=True)
+                with trace_span("rollout/generate", step=iter_count, engine=True) as span:
+                    eps = engine.step()
+                gen_s += span.seconds
                 finished_buf.extend(eps)
                 if not eps and engine.idle and n_collected + len(finished_buf) < num_rollouts:
                     raise RuntimeError(

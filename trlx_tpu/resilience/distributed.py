@@ -294,6 +294,7 @@ class collective_guard:
             _obs_spans.instant(
                 "collective_timeout", collective=self.name, deadline_s=self.deadline
             )
+            _obs_spans.flush()  # the default handler never reaches a boundary
             _obs_anomaly.emergency_capture(
                 "collective_timeout", detail={"collective": self.name, **extra}
             )
@@ -332,7 +333,7 @@ class collective_guard:
         )
 
     def __enter__(self):
-        self._span_t0 = None
+        self._span = None
         self._fleet_t0 = None
         # Fleet arrival stamp BEFORE the deadline gate: straggler attribution
         # works even on guards left at deadline 0. One dict load disarmed.
@@ -344,8 +345,11 @@ class collective_guard:
             return self
         from trlx_tpu.observability import spans as _obs_spans
 
-        if _obs_spans.enabled():
-            self._span_t0 = time.time()
+        # A lane of collective/<name> boxes per host: the waiters' spans
+        # stretch toward the deadline, the culprit's never starts. The guard
+        # is the `with` block; it carries the span through.
+        self._span = _obs_spans.trace_span(f"collective/{self.name}")
+        self._span.__enter__()
         hb = _CONFIG["heartbeat"]
         if hb is not None:
             # Mark this host as INSIDE the collective: the stall report can
@@ -368,13 +372,9 @@ class collective_guard:
             # checkpoint dir, so no collective rides on the hot path.
             _obs_fleet.collective_complete(self.name, self._fleet_t0, time.time())
             self._fleet_t0 = None
-        if self._span_t0 is not None:
-            from trlx_tpu.observability import spans as _obs_spans
-
-            # A lane of collective/<name> boxes per host: the waiters' spans
-            # stretch toward the deadline, the culprit's never starts.
-            _obs_spans.complete(f"collective/{self.name}", self._span_t0)
-            self._span_t0 = None
+        if self._span is not None:
+            self._span.__exit__(*exc_info)
+            self._span = None
         return False
 
 

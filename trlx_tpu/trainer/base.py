@@ -42,6 +42,7 @@ from trlx_tpu.observability import fleet as obs_fleet
 from trlx_tpu.observability import graftscope as obs_graftscope
 from trlx_tpu.observability import numerics as obs_numerics
 from trlx_tpu.observability import spans as obs_spans
+from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.parallel import make_mesh, set_mesh, shard_pytree
 from trlx_tpu.parallel.mesh import DATA_AXES, barrier, init_distributed, is_main_process
 from trlx_tpu.resilience import (
@@ -190,6 +191,8 @@ class JaxBaseTrainer(BaseRLTrainer):
         self._rollbacks = 0
         self.skipped_steps = 0  # total guard-skipped updates (host count)
         self._res_pending = []  # buffered per-step device scalars (no sync)
+        self._host_t0 = None  # where time/step_host_ms counts from (learn loop)
+        self._step_wait_s = 0.0  # device waits inside a step, outside its stats read
         # Parallel host-side batch refs for the graftnum nonfinite census:
         # populated ONLY when incident capture is armed (None placeholders
         # otherwise), so default runs keep zero extra references alive.
@@ -256,6 +259,7 @@ class JaxBaseTrainer(BaseRLTrainer):
             # trainer in this process (tests build several) must not keep
             # appending this run's thread spans to its old file.
             obs_spans.shutdown()
+        obs_spans.install_compile_listener()
         self._devicemon = None
         if (
             config.train.device_telemetry
@@ -986,6 +990,8 @@ class JaxBaseTrainer(BaseRLTrainer):
         # the first MFU window must span from HERE (covering every dispatch
         # whose FLOPs the monitor accumulated), not just the last step.
         self._telemetry_t0 = time.time()
+        self._host_t0, self._step_wait_s = None, 0.0
+        obs_spans.set_iteration(0)
 
         def profiler_tick():
             if not profile_dir or not is_main_process():
@@ -1029,6 +1035,7 @@ class JaxBaseTrainer(BaseRLTrainer):
             self._close_batch_feed()
             self._shutdown_experience_pipeline()
             self.end_progress()
+            obs_spans.flush()
             # An async interval save may still be in flight — its sidecars
             # (manifest, latest.txt) only land at finalize, so the exit path
             # must drain it or the checkpoint is invisible to resume.
@@ -1146,15 +1153,21 @@ class JaxBaseTrainer(BaseRLTrainer):
         for epoch in range(self.config.train.epochs):
             feed = self._train_batch_feed()
             while True:
-                data_t0 = time.time()
-                try:
-                    # put_batch already ran (inline via SerialFeed, or ahead
-                    # of time on the prefetch thread) — this pop measures the
-                    # residual host→device blocking the train step pays.
-                    device_batch, host_extras = next(feed)
-                except StopIteration:
+                if timer is None:
+                    # No rollout phases (ILQL): an iteration is one batch, its
+                    # data wait and its step(s).
+                    obs_spans.set_iteration(self.iter_count + 1)
+                # put_batch already ran (inline via SerialFeed, or ahead of
+                # time on the prefetch thread): this pop measures the
+                # residual host→device blocking the train step pays.
+                with trace_span("train/data_wait") as wait:
+                    item = next(feed, None)
+                if item is None:
                     break
-                self._data_s = getattr(self, "_data_s", 0.0) + (time.time() - data_t0)
+                device_batch, host_extras = item
+                if self._host_t0 is None:
+                    self._host_t0 = wait.start_s  # first batch, or the first after a rollout
+                self._data_s = getattr(self, "_data_s", 0.0) + wait.seconds
                 self._last_batch_extras = host_extras
                 # SIGTERM may land during the (long) rollout phase that
                 # rebuilt this dataloader — checkpoint before spending a
@@ -1164,275 +1177,311 @@ class JaxBaseTrainer(BaseRLTrainer):
                 if self._preemption_agreed():
                     self._save_on_preemption()
                     return None
-                train_t0 = time.time()
                 self._phase_exclude_s = 0.0  # eval/save wall inside the window
-                for _ in range(self.n_updates_per_batch):
-                    profiler_tick()
-                    forward_t0 = time.time()
-                    step_batch = device_batch
-                    if self.fault_plan and self.fault_plan.fire(
-                        "nan_grad", self.iter_count + 1
-                    ):
-                        # Injected numeric blow-up: NaN-poison the float
-                        # leaves of THIS step's batch (fault drill for the
-                        # on-device non-finite guard).
-                        step_batch = poison_nan(device_batch)
-                    if self.fault_plan and self.fault_plan.fire(
-                        "nan_layer", self.iter_count + 1
-                    ):
-                        # NaN-provenance drill: same batch poison (the guard
-                        # genuinely trips) PLUS a latched tap injection so the
-                        # graftnum bisector's re-forward must name that layer
-                        # as first-NaN. One @N gives both the step tick and
-                        # the target block (clamped to the model's depth).
-                        step_batch = poison_nan(device_batch)
-                        n_layer = int(self.model.cfg.n_layer)
-                        tap = f"block_{min(self.iter_count + 1, n_layer - 1)}"
-                        obs_numerics.latch_injection(tap)
-                    with self._dispatch_lock:
-                        prev_state = self.state
-                        self.state, stats = self.train_step(self.state, step_batch)
-                    # Donation handoff: train_step donates the old state
-                    # (donate_argnums=(0,)); record it so a stale host read
-                    # raises with this site named (no-op unless
-                    # TRLX_TPU_SANITIZE=donation).
-                    sanitize.mark_donated(prev_state, "train_step(state) [learn loop]")
-                    del prev_state
-                    self.iter_count += 1
-                    if self.heartbeat is not None:
-                        # Progress stamp (cheap attribute stores; the
-                        # heartbeat thread does the file I/O) — a host whose
-                        # stamp freezes here is the one the CollectiveTimeout
-                        # diagnostic will name.
-                        self.heartbeat.beat(step=self.iter_count, phase="train")
-                    self._fire_host_faults()
-
-                    # Every step gets the DEVICE stats dict (async, no sync):
-                    # subclasses buffer what they need (the adaptive KL
-                    # controller queues each step's mean_kl scalar and applies
-                    # the per-step updates at its next flush, so log_interval
-                    # no longer blinds or rescales the controller).
-                    self.post_backward_callback(stats)
-
-                    # Buffer this step's resilience scalars (un-fetched
-                    # device values — the same zero-sync discipline as the
-                    # KL buffer); flushed at log boundaries below.
-                    if self.watchdog is not None or "resilience/bad_steps" in stats:
-                        self._res_pending.append(
-                            (
-                                stats.get("loss"),
-                                stats.get("resilience/nonfinite"),
-                                stats.get("resilience/bad_steps"),
-                            )
-                        )
-                        # Batch ref for the guard-skip census (popped in
-                        # lockstep by _flush_resilience). Kept ONLY when a
-                        # trip could produce an incident bundle — None
-                        # placeholders otherwise, so default runs pin no
-                        # extra device memory.
-                        self._res_batch_refs.append(
-                            step_batch if self._incidents is not None else None
-                        )
-                        if len(self._res_pending) >= max(self.config.train.log_interval, 8):
-                            self._flush_resilience()
-
-                    if self.fault_plan and self.fault_plan.fire("sigterm", self.iter_count):
-                        # Synthetic preemption notice (fault drill for the
-                        # SIGTERM save/resume path) — delivered for real so
-                        # the actual signal handler runs.
-                        os.kill(os.getpid(), signal.SIGTERM)
-
-                    intervals = self.intervals(self.iter_count)
-                    if intervals["do_checkpoint"]:
-                        # Interval saves follow train.async_checkpointing:
-                        # async dispatches the orbax write and returns — the
-                        # save overlaps training and only blocks at the next
-                        # save/exit (_finalize_pending_save).
-                        self.save(block=not self.config.train.async_checkpointing)
-                    if intervals["do_log"] or intervals["do_eval"]:
-                        self._flush_resilience()
-                        # Reading stats forces a device sync — the price of
-                        # logging (per-step by default, as in the reference's
-                        # accelerator.log, reference:
-                        # trlx/model/accelerate_base_model.py:244). With
-                        # log_interval > 1 the device queue stays full
-                        # between logs.
-                        stats_host = {k: float(v) for k, v in stats.items()}
-                        # step_time BEFORE any evaluate(): the stats read just
-                        # above synced the step; folding eval seconds in would
-                        # make the logged throughput wrong by orders of
-                        # magnitude on eval steps.
-                        stats_host["step_time"] = time.time() - forward_t0
-                        # Span for the logged step (dispatch + the stats
-                        # sync above) on the main thread's lane — against the
-                        # producer/score lanes this is where overlap shows.
-                        obs_spans.complete(
-                            "train/step", forward_t0, step=self.iter_count
-                        )
-                        if self._anomaly is not None and self._anomaly.observe(
-                            stats_host["step_time"]
-                        ):
-                            self._incidents.capture(
-                                self.iter_count,
-                                "slow_step",
-                                detail={
-                                    "step_time": stats_host["step_time"],
-                                    "p50": self._anomaly.p50(),
-                                    "factor": self._anomaly.factor,
-                                },
-                            )
-                        if self._devicemon is not None and getattr(self, "_phase_timer", None) is None:
-                            # Trainers without a phase timer (ILQL) flush the
-                            # device telemetry here; PPO flushes at its
-                            # rollout-window boundary (_log_phase_window)
-                            # where the true per-phase seconds live.
-                            now = time.time()
-                            since = now - getattr(self, "_telemetry_t0", forward_t0)
-                            self._telemetry_t0 = now
-                            # The whole inter-flush stretch is train-lane
-                            # host time for the attribution ledger.
-                            obs_graftscope.host_interval("train", now - since, now)
-                            stats_host.update(
-                                self._flush_device_telemetry(
-                                    {"train": since, "wall": since}
-                                )
-                            )
-                        stats_host["samples_per_sec"] = (
-                            self.config.train.batch_size / max(stats_host["step_time"], 1e-9)
-                        )
-                        # Cumulative host→device batch-transfer seconds since
-                        # the last log (phase attribution: the "data" phase).
-                        stats_host["data_time"] = getattr(self, "_data_s", 0.0)
-                        self._data_s = 0.0
-                        # Wall since the previous log flushed: step_gap −
-                        # step_time = loop overhead outside the jitted step
-                        # (callbacks, intervals, logging, loader advance).
-                        # _last_log_t is re-stamped AFTER eval+log below so
-                        # eval wall never pollutes the next record's gap.
-                        if getattr(self, "_last_log_t", None) is not None:
-                            stats_host["step_gap"] = time.time() - self._last_log_t
-                        if intervals["do_eval"]:
-                            stats_host.update(self.evaluate())
-                            # Eval wall must not count as train-phase time in
-                            # the overlap window (single-host reads it back;
-                            # non-main pod hosts return a reduced stats dict).
-                            self._phase_exclude_s += stats_host.get("eval_wall_time", 0.0)
-                        extras = getattr(self, "_last_batch_extras", None)
-                        if extras:
-                            # Host-side batch metadata (e.g. the staleness
-                            # column from the pipelined producer): log-boundary
-                            # stats only, never device traffic.
-                            for k, v in extras.items():
-                                v = np.asarray(v)
-                                stats_host[f"{k}/mean"] = float(v.mean())
-                                stats_host[f"{k}/max"] = float(v.max())
-                        if self._graftnum is not None:
-                            # Numerics feed BEFORE the health gauges merge:
-                            # the grad-spike / update-ratio detectors judge
-                            # this record's num/* scalars, so their
-                            # health/*_state gauges below reflect THIS step.
-                            self._graftnum.observe_train(stats_host)
-                        if self._health is not None:
-                            # Health feed: judge the synced per-step stats,
-                            # then ride the health/* gauges along in the same
-                            # record. The entropy_collapse drill latches here
-                            # (stats-only — training never sees it).
-                            if self.fault_plan and self.fault_plan.fire(
-                                "entropy_collapse", self.iter_count
-                            ):
-                                self._health.inject_entropy_collapse()
-                            kl_ctl = getattr(self, "kl_ctl", None)
-                            self._health.observe_train(
-                                stats_host,
-                                self.iter_count,
-                                kl_coef=getattr(kl_ctl, "value", None),
-                                kl_target=getattr(kl_ctl, "target", None),
-                                kl_init_coef=getattr(
-                                    self.config.method, "init_kl_coef", None
-                                ),
-                            )
-                            stats_host.update(self._health.gauges())
-                            self._health.maybe_log_lineage(
-                                self.tracker, self.iter_count
-                            )
-                        if self._graftnum is not None:
-                            # Quant-error gauges from the latest weight
-                            # handoff; detector states ride along only when
-                            # no health monitor already emits them.
-                            stats_host.update(
-                                self._graftnum.gauges(
-                                    include_states=self._health is None
-                                )
-                            )
-                        self._export_metrics(stats_host)
-                        if self._fleet is not None:
-                            # Fleet window rollup AFTER _export_metrics'
-                            # collective gather: the fleet/* keys exist only
-                            # on process 0, and mismatched key sets across
-                            # hosts would misalign the rollup's allgather.
-                            stats_host.update(
-                                self._fleet.on_log_boundary(
-                                    self.iter_count,
-                                    exporter=self._metrics_exporter,
-                                )
-                            )
-                        self.tracker.log(stats_host, step=self.iter_count)
-                        self.progress_line(stats_host)
-                        self._last_log_t = time.time()
-
-                    # Independent of the log cadence (a nested check would
-                    # silently thin the histograms to lcm(log, watch)).
-                    wi = self.config.train.watch_interval
-                    if wi and self.iter_count % wi == 0:
-                        self.log_param_watch()
-
-                    # Cross-host consistency guard: every N steps, compare
-                    # [step, replicated-param crc, rng crc] fingerprints and
-                    # raise HostDesync naming the diverged host — keyed on
-                    # iter_count so every host enters the collective at the
-                    # identical step.
-                    di = self.config.train.desync_check_interval
-                    if di and self.iter_count % di == 0:
-                        self._check_desync()
-
-                    # graftfleet clock resync: two tiny guarded allgathers
-                    # every train.fleet_resync_interval steps — collective,
-                    # keyed on iter_count so every host enters at the
-                    # identical step.
-                    if self._fleet is not None:
-                        self._fleet.maybe_resync(self.iter_count)
-
-                    # Mid-batch reaction is single-process by default: a
-                    # per-step agreement collective would tax the hot loop,
-                    # and a local-only save would deadlock a pod — pods
-                    # react at the next batch boundary, or every
-                    # train.preempt_check_interval steps when set (tighter
-                    # preemption windows at one tiny allgather per N steps).
-                    if jax.process_count() == 1 and self._preempted:
-                        self._save_on_preemption()
-                        return None
-                    pi = self.config.train.preempt_check_interval
-                    if (
-                        pi
-                        and jax.process_count() > 1
-                        and self.iter_count % pi == 0
-                        and self._preemption_agreed()
-                    ):
-                        self._save_on_preemption()
-                        return None
-
-                    if self.iter_count >= self.total_steps:
-                        self._save_at_end()
-                        return self.evaluate()
+                stop = None
+                with trace_span("train/batch") as batch_span:
+                    for _ in range(self.n_updates_per_batch):
+                        profiler_tick()
+                        stop = self._train_one_step(device_batch)
+                        if stop is not None:
+                            break
+                if stop == "preempted":
+                    self._save_on_preemption()
+                    return None
+                if stop == "finished":
+                    self._save_at_end()
+                    return self.evaluate()
                 if timer is not None:
-                    train_dt = max(0.0, time.time() - train_t0 - self._phase_exclude_s)
+                    train_dt = max(0.0, batch_span.seconds - self._phase_exclude_s)
                     timer.add("train", train_dt)
-                    obs_graftscope.host_interval("train", train_t0, train_t0 + train_dt)
+                    obs_graftscope.host_interval("train", batch_span.start_s, batch_span.start_s + train_dt)
             self._close_batch_feed()
             self.post_epoch_callback()
+            self._host_t0 = None
 
         self._save_at_end()
         return self.evaluate()
+
+    def _train_one_step(self, device_batch):
+        """One jitted update on ``device_batch`` and what follows it on the
+        host. Returns None to go on, "preempted" or "finished" to stop; the
+        caller saves and evaluates outside the step's spans."""
+        with trace_span("train/step", step=self.iter_count + 1) as step_span:
+            step_batch = device_batch
+            if self.fault_plan and self.fault_plan.fire(
+                "nan_grad", self.iter_count + 1
+            ):
+                # Injected numeric blow-up: NaN-poison the float
+                # leaves of THIS step's batch (fault drill for the
+                # on-device non-finite guard).
+                step_batch = poison_nan(device_batch)
+            if self.fault_plan and self.fault_plan.fire(
+                "nan_layer", self.iter_count + 1
+            ):
+                # NaN-provenance drill: same batch poison (the guard
+                # genuinely trips) PLUS a latched tap injection so the
+                # graftnum bisector's re-forward must name that layer
+                # as first-NaN. One @N gives both the step tick and
+                # the target block (clamped to the model's depth).
+                step_batch = poison_nan(device_batch)
+                n_layer = int(self.model.cfg.n_layer)
+                tap = f"block_{min(self.iter_count + 1, n_layer - 1)}"
+                obs_numerics.latch_injection(tap)
+            with trace_span("train/dispatch"), self._dispatch_lock:
+                prev_state = self.state
+                self.state, stats = self.train_step(self.state, step_batch)
+            # Donation handoff: train_step donates the old state
+            # (donate_argnums=(0,)); record it so a stale host read
+            # raises with this site named (no-op unless
+            # TRLX_TPU_SANITIZE=donation).
+            sanitize.mark_donated(prev_state, "train_step(state) [learn loop]")
+            del prev_state
+            self.iter_count += 1
+            if self.heartbeat is not None:
+                # Progress stamp (cheap attribute stores; the
+                # heartbeat thread does the file I/O) — a host whose
+                # stamp freezes here is the one the CollectiveTimeout
+                # diagnostic will name.
+                self.heartbeat.beat(step=self.iter_count, phase="train")
+            self._fire_host_faults()
+
+            # Every step gets the DEVICE stats dict (async, no sync):
+            # subclasses buffer what they need (the adaptive KL
+            # controller queues each step's mean_kl scalar and applies
+            # the per-step updates at its next flush, so log_interval
+            # no longer blinds or rescales the controller).
+            self.post_backward_callback(stats)
+
+            # Buffer this step's resilience scalars (un-fetched
+            # device values — the same zero-sync discipline as the
+            # KL buffer); flushed at log boundaries below.
+            if self.watchdog is not None or "resilience/bad_steps" in stats:
+                self._res_pending.append(
+                    (
+                        stats.get("loss"),
+                        stats.get("resilience/nonfinite"),
+                        stats.get("resilience/bad_steps"),
+                    )
+                )
+                # Batch ref for the guard-skip census (popped in
+                # lockstep by _flush_resilience). Kept ONLY when a
+                # trip could produce an incident bundle — None
+                # placeholders otherwise, so default runs pin no
+                # extra device memory.
+                self._res_batch_refs.append(
+                    step_batch if self._incidents is not None else None
+                )
+                if len(self._res_pending) >= max(self.config.train.log_interval, 8):
+                    self._flush_resilience()
+
+            if self.fault_plan and self.fault_plan.fire("sigterm", self.iter_count):
+                # Synthetic preemption notice (fault drill for the
+                # SIGTERM save/resume path) — delivered for real so
+                # the actual signal handler runs.
+                os.kill(os.getpid(), signal.SIGTERM)
+
+            intervals = self.intervals(self.iter_count)
+            if intervals["do_checkpoint"]:
+                # Interval saves follow train.async_checkpointing:
+                # async dispatches the orbax write and returns — the
+                # save overlaps training and only blocks at the next
+                # save/exit (_finalize_pending_save).
+                self.save(block=not self.config.train.async_checkpointing)
+            if intervals["do_log"] or intervals["do_eval"]:
+                self._log_step(stats, step_span, intervals)
+
+        # Independent of the log cadence (a nested check would
+        # silently thin the histograms to lcm(log, watch)).
+        wi = self.config.train.watch_interval
+        if wi and self.iter_count % wi == 0:
+            self.log_param_watch()
+
+        # Cross-host consistency guard: every N steps, compare
+        # [step, replicated-param crc, rng crc] fingerprints and
+        # raise HostDesync naming the diverged host — keyed on
+        # iter_count so every host enters the collective at the
+        # identical step.
+        di = self.config.train.desync_check_interval
+        if di and self.iter_count % di == 0:
+            self._check_desync()
+
+        # graftfleet clock resync: two tiny guarded allgathers
+        # every train.fleet_resync_interval steps — collective,
+        # keyed on iter_count so every host enters at the
+        # identical step.
+        if self._fleet is not None:
+            self._fleet.maybe_resync(self.iter_count)
+
+        # Mid-batch reaction is single-process by default: a
+        # per-step agreement collective would tax the hot loop,
+        # and a local-only save would deadlock a pod — pods
+        # react at the next batch boundary, or every
+        # train.preempt_check_interval steps when set (tighter
+        # preemption windows at one tiny allgather per N steps).
+        if jax.process_count() == 1 and self._preempted:
+            return "preempted"
+        pi = self.config.train.preempt_check_interval
+        if (
+            pi
+            and jax.process_count() > 1
+            and self.iter_count % pi == 0
+            and self._preemption_agreed()
+        ):
+            return "preempted"
+
+        if self.iter_count >= self.total_steps:
+            return "finished"
+        return None
+
+    def _log_step(self, stats, step_span, intervals):
+        """The log boundary of one step: the blocking stats read, then the
+        record (health, export, tracker, progress line) and the evaluation."""
+        # Reading stats forces a device sync — the price of
+        # logging (per-step by default, as in the reference's
+        # accelerator.log, reference:
+        # trlx/model/accelerate_base_model.py:244). With
+        # log_interval > 1 the device queue stays full
+        # between logs. The wait for the step is a span of its own
+        # (the resilience flush reads the same step's scalars, so armed it
+        # pays the wait); pulling the scalars, one transfer each, is host
+        # work the device idles through.
+        with trace_span("train/stats_read") as read:
+            with trace_span("train/stats_wait") as wait:
+                self._flush_resilience()
+                jax.block_until_ready(stats)
+            stats_host = {k: float(v) for k, v in stats.items()}
+        with trace_span("train/log") as log:
+            self._write_step_record(stats_host, step_span, read, wait, intervals)
+        self._last_log_t = log.end_s
+        if getattr(self, "_phase_timer", None) is None:
+            obs_spans.flush()  # no rollout boundary (ILQL): the log boundary is the iteration's
+
+    def _write_step_record(self, stats_host, step_span, read, wait, intervals):
+        # step_time BEFORE any evaluate(): the stats read synced the
+        # step; folding eval seconds in would make the logged throughput
+        # wrong by orders of magnitude on eval steps. From the step's span
+        # start (dispatch) to the end of the read, both already on record.
+        stats_host["step_time"] = read.end_s - step_span.start_s
+        # Host wall from the end of the previous stats read to the end of this
+        # one (the first step after a rollout: since its batch was asked for)
+        # less the waits for the device (this read's, and what a callback
+        # added to _step_wait_s), evaluation left out: the log, the loop's
+        # tail, the data wait, the dispatch and the pull of the stats. With
+        # log_interval 1 the device idles through nearly all of it.
+        waited = wait.seconds + self._step_wait_s
+        stats_host["time/step_host_ms"] = max(0.0, read.end_s - self._host_t0 - waited) * 1e3
+        self._host_t0, self._step_wait_s = read.end_s, 0.0
+        stats_host["obs/compiles"] = obs_spans.take_compiles()
+        if self._anomaly is not None and self._anomaly.observe(
+            stats_host["step_time"]
+        ):
+            self._incidents.capture(
+                self.iter_count,
+                "slow_step",
+                detail={
+                    "step_time": stats_host["step_time"],
+                    "p50": self._anomaly.p50(),
+                    "factor": self._anomaly.factor,
+                },
+            )
+        if self._devicemon is not None and getattr(self, "_phase_timer", None) is None:
+            # Trainers without a phase timer (ILQL) flush the
+            # device telemetry here; PPO flushes at its
+            # rollout-window boundary (_log_phase_window)
+            # where the true per-phase seconds live.
+            now = read.end_s
+            since = now - self._telemetry_t0
+            self._telemetry_t0 = now
+            # The whole inter-flush stretch is train-lane
+            # host time for the attribution ledger.
+            obs_graftscope.host_interval("train", now - since, now)
+            stats_host.update(
+                self._flush_device_telemetry(
+                    {"train": since, "wall": since}
+                )
+            )
+        stats_host["samples_per_sec"] = (
+            self.config.train.batch_size / max(stats_host["step_time"], 1e-9)
+        )
+        # Cumulative host→device batch-transfer seconds since
+        # the last log (phase attribution: the "data" phase).
+        stats_host["data_time"] = getattr(self, "_data_s", 0.0)
+        self._data_s = 0.0
+        # Wall since the previous log flushed: step_gap −
+        # step_time = loop overhead outside the jitted step
+        # (callbacks, intervals, logging, loader advance).
+        # _last_log_t is re-stamped AFTER eval+log below so
+        # eval wall never pollutes the next record's gap.
+        if getattr(self, "_last_log_t", None) is not None:
+            stats_host["step_gap"] = read.end_s - self._last_log_t
+        if intervals["do_eval"]:
+            stats_host.update(self.evaluate())
+            # Eval wall must not count as train-phase time in
+            # the overlap window (single-host reads it back;
+            # non-main pod hosts return a reduced stats dict).
+            self._phase_exclude_s += stats_host.get("eval_wall_time", 0.0)
+            self._host_t0 += stats_host.get("eval_wall_time", 0.0)
+        extras = getattr(self, "_last_batch_extras", None)
+        if extras:
+            # Host-side batch metadata (e.g. the staleness
+            # column from the pipelined producer): log-boundary
+            # stats only, never device traffic.
+            for k, v in extras.items():
+                v = np.asarray(v)
+                stats_host[f"{k}/mean"] = float(v.mean())
+                stats_host[f"{k}/max"] = float(v.max())
+        if self._graftnum is not None:
+            # Numerics feed BEFORE the health gauges merge:
+            # the grad-spike / update-ratio detectors judge
+            # this record's num/* scalars, so their
+            # health/*_state gauges below reflect THIS step.
+            self._graftnum.observe_train(stats_host)
+        if self._health is not None:
+            # Health feed: judge the synced per-step stats,
+            # then ride the health/* gauges along in the same
+            # record. The entropy_collapse drill latches here
+            # (stats-only — training never sees it).
+            if self.fault_plan and self.fault_plan.fire(
+                "entropy_collapse", self.iter_count
+            ):
+                self._health.inject_entropy_collapse()
+            kl_ctl = getattr(self, "kl_ctl", None)
+            self._health.observe_train(
+                stats_host,
+                self.iter_count,
+                kl_coef=getattr(kl_ctl, "value", None),
+                kl_target=getattr(kl_ctl, "target", None),
+                kl_init_coef=getattr(
+                    self.config.method, "init_kl_coef", None
+                ),
+            )
+            stats_host.update(self._health.gauges())
+            self._health.maybe_log_lineage(
+                self.tracker, self.iter_count
+            )
+        if self._graftnum is not None:
+            # Quant-error gauges from the latest weight
+            # handoff; detector states ride along only when
+            # no health monitor already emits them.
+            stats_host.update(
+                self._graftnum.gauges(
+                    include_states=self._health is None
+                )
+            )
+        self._export_metrics(stats_host)
+        if self._fleet is not None:
+            # Fleet window rollup AFTER _export_metrics'
+            # collective gather: the fleet/* keys exist only
+            # on process 0, and mismatched key sets across
+            # hosts would misalign the rollup's allgather.
+            stats_host.update(
+                self._fleet.on_log_boundary(
+                    self.iter_count,
+                    exporter=self._metrics_exporter,
+                )
+            )
+        self.tracker.log(stats_host, step=self.iter_count)
+        self.progress_line(stats_host)
 
     # ------------------------------------------------------------ checkpoint
 
@@ -1704,27 +1753,27 @@ class JaxBaseTrainer(BaseRLTrainer):
         construction: latest.txt is only repointed AFTER the data is fully
         committed, so a crash mid-async-save leaves the previous checkpoint
         as the resume point."""
-        save_t0 = time.time()
-        directory = os.path.abspath(directory or self.config.train.checkpoint_dir)
-        self._finalize_pending_save()  # at most one save in flight
-        name = f"state_{int(jax.device_get(self.state.step))}"
-        self._save_count += 1
-        self._pending_save = {
-            "directory": directory,
-            "name": name,
-            "t0": time.time(),
-            "save_index": self._save_count,
-            # Captured NOW — by finalize time the host state (RNG, KL
-            # coefficient) may have advanced past this checkpoint's step.
-            "host_state": self.host_state_dict(),
-        }
-        self._ckptr.save(os.path.join(directory, name), self.state, force=True)
-        if block:
-            self._finalize_pending_save()
         # Covers exactly the wall the train loop PAID: through finalize when
         # blocking, dispatch-only when async (the deferred commit then shows
         # up as its own ckpt/finalize span).
-        obs_spans.complete("ckpt/save", save_t0, ckpt=name, blocking=bool(block))
+        with trace_span("ckpt/save", blocking=bool(block)) as span:
+            directory = os.path.abspath(directory or self.config.train.checkpoint_dir)
+            self._finalize_pending_save()  # at most one save in flight
+            name = f"state_{int(jax.device_get(self.state.step))}"
+            span.args["ckpt"] = name
+            self._save_count += 1
+            self._pending_save = {
+                "directory": directory,
+                "name": name,
+                "t0": time.time(),
+                "save_index": self._save_count,
+                # Captured NOW — by finalize time the host state (RNG, KL
+                # coefficient) may have advanced past this checkpoint's step.
+                "host_state": self.host_state_dict(),
+            }
+            self._ckptr.save(os.path.join(directory, name), self.state, force=True)
+            if block:
+                self._finalize_pending_save()
 
     def _finalize_pending_save(self):
         """Drain the in-flight async save: wait for the orbax commit, then
@@ -1734,47 +1783,46 @@ class JaxBaseTrainer(BaseRLTrainer):
         pending, self._pending_save = self._pending_save, None
         if pending is None:
             return None
-        fin_t0 = time.time()
-        directory, name = pending["directory"], pending["name"]
-        self._ckptr.wait_until_finished()
-        if jax.process_count() > 1:
-            # All-hosts-committed barrier: every host's shards are on disk
-            # before rank 0 writes the sidecars and flips latest.txt — the
-            # pointer must never lead a straggler host's data, or a
-            # preemption save could advertise a checkpoint missing shards.
-            barrier(f"ckpt_commit_{name}")
-        if getattr(self, "tracker", None) is not None:
-            self.tracker.log(
-                {"save_time": time.time() - pending["t0"]}, step=self.iter_count
-            )
-        if is_main_process():
-            step = ckpt_util.checkpoint_step(name)
-            ckpt_util.atomic_write_json(
-                os.path.join(directory, f"{name}.host.json"), pending["host_state"]
-            )
-            ckpt_util.write_manifest(directory, name, step if step is not None else 0)
-            # basename, not abspath: checkpoint dirs get synced/remounted
-            # between the preempted VM and its replacement. Written LAST and
-            # atomically — a crash anywhere above leaves the old pointer.
-            ckpt_util.atomic_write_text(os.path.join(directory, "latest.txt"), name)
-            if self.fault_plan and self.fault_plan.fire(
-                "ckpt_corrupt", pending["save_index"]
-            ):
-                rel = ckpt_util.corrupt_checkpoint(directory, name)
-                print(
-                    f"[trlx_tpu.resilience] injected checkpoint corruption: "
-                    f"truncated {name}/{rel}",
-                    file=sys.stderr,
+        with trace_span("ckpt/finalize", ckpt=pending["name"]):
+            directory, name = pending["directory"], pending["name"]
+            self._ckptr.wait_until_finished()
+            if jax.process_count() > 1:
+                # All-hosts-committed barrier: every host's shards are on disk
+                # before rank 0 writes the sidecars and flips latest.txt — the
+                # pointer must never lead a straggler host's data, or a
+                # preemption save could advertise a checkpoint missing shards.
+                barrier(f"ckpt_commit_{name}")
+            if getattr(self, "tracker", None) is not None:
+                self.tracker.log(
+                    {"save_time": time.time() - pending["t0"]}, step=self.iter_count
                 )
-            ckpt_util.gc_checkpoints(
-                directory, self.config.train.keep_checkpoints, protect=(name,)
-            )
-        if jax.process_count() > 1:
-            # Visibility barrier: no host returns (and, on a preemption
-            # save, exits) until rank 0's pointer flip is durable — every
-            # host's view of "the save is done" includes latest.txt.
-            barrier(f"ckpt_visible_{name}")
-        obs_spans.complete("ckpt/finalize", fin_t0, ckpt=name)
+            if is_main_process():
+                step = ckpt_util.checkpoint_step(name)
+                ckpt_util.atomic_write_json(
+                    os.path.join(directory, f"{name}.host.json"), pending["host_state"]
+                )
+                ckpt_util.write_manifest(directory, name, step if step is not None else 0)
+                # basename, not abspath: checkpoint dirs get synced/remounted
+                # between the preempted VM and its replacement. Written LAST and
+                # atomically — a crash anywhere above leaves the old pointer.
+                ckpt_util.atomic_write_text(os.path.join(directory, "latest.txt"), name)
+                if self.fault_plan and self.fault_plan.fire(
+                    "ckpt_corrupt", pending["save_index"]
+                ):
+                    rel = ckpt_util.corrupt_checkpoint(directory, name)
+                    print(
+                        f"[trlx_tpu.resilience] injected checkpoint corruption: "
+                        f"truncated {name}/{rel}",
+                        file=sys.stderr,
+                    )
+                ckpt_util.gc_checkpoints(
+                    directory, self.config.train.keep_checkpoints, protect=(name,)
+                )
+            if jax.process_count() > 1:
+                # Visibility barrier: no host returns (and, on a preemption
+                # save, exits) until rank 0's pointer flip is durable — every
+                # host's view of "the save is done" includes latest.txt.
+                barrier(f"ckpt_visible_{name}")
         return name
 
     def save_pretrained(self, out_dir: str, family: Optional[str] = None):
@@ -1840,96 +1888,94 @@ class JaxBaseTrainer(BaseRLTrainer):
         checkpoint used to produce."""
         import json
 
-        load_t0 = time.time()
-        self._finalize_pending_save()  # a pending async save IS the latest
-        directory = os.path.abspath(directory or self.config.train.checkpoint_dir)
-        latest_path = os.path.join(directory, "latest.txt")
-        latest = None
-        if os.path.exists(latest_path):
-            with open(latest_path) as f:
-                latest = f.read().strip() or None
+        with trace_span("ckpt/load") as span:
+            self._finalize_pending_save()  # a pending async save IS the latest
+            directory = os.path.abspath(directory or self.config.train.checkpoint_dir)
+            latest_path = os.path.join(directory, "latest.txt")
+            latest = None
+            if os.path.exists(latest_path):
+                with open(latest_path) as f:
+                    latest = f.read().strip() or None
 
-        # Candidate order: the latest pointer first, then every other
-        # state_* directory newest-step-first.
-        candidates = []
-        if latest is not None:
-            candidates.append(latest)
-        for name in ckpt_util.list_checkpoints(directory):
-            if name != os.path.basename(candidates[0] if candidates else ""):
-                candidates.append(name)
-        if not candidates:
-            raise CheckpointError(
-                f"no checkpoint found in {directory}: "
-                + ("latest.txt is empty" if os.path.exists(latest_path) else "latest.txt is missing")
-                + " and no state_* directories exist — nothing to resume from "
-                "(set train.resume_from_checkpoint=False to start fresh, or "
-                "point train.checkpoint_dir at the directory that holds the run)"
-            )
-
-        attempts = []
-        for i, cand in enumerate(candidates):
-            name = os.path.basename(cand)
-            # Older checkpoints stored an absolute path; fall back to its
-            # basename under the current directory when it moved.
-            path = (
-                cand
-                if os.path.isabs(cand) and os.path.exists(cand)
-                else os.path.join(directory, name)
-            )
-            # In-use marker: another process GC-ing this directory (e.g. a
-            # concurrent run finalizing its own save) must not delete a
-            # candidate out from under the verify/restore below.
-            with ckpt_util.mark_in_use(os.path.dirname(path), name):
-                if not os.path.isdir(path):
-                    ok, reason = False, "checkpoint directory missing"
-                else:
-                    ok, reason = ckpt_util.verify_checkpoint(os.path.dirname(path), name)
-                if jax.process_count() > 1:
-                    # Cross-host agreement BEFORE the collective restore:
-                    # the orbax restore must be entered by every host or by
-                    # none, and a checkpoint torn on ONE host's view of the
-                    # filesystem fails the candidate for ALL — otherwise
-                    # the fleet deadlocks split across two candidates.
-                    from trlx_tpu.parallel.mesh import allgather_host
-
-                    oks = allgather_host(np.asarray([ok], dtype=np.int32)).reshape(-1)
-                    if not oks.all():
-                        bad = [int(p) for p in np.flatnonzero(oks == 0)]
-                        attempts.append(
-                            f"{name}: failed verification on host(s) {bad}"
-                            + (f" (local: {reason})" if not ok else "")
-                        )
-                        continue
-                elif not ok:
-                    attempts.append(f"{name}: {reason}")
-                    continue
-                try:
-                    self.state = self._ckptr.restore(path, self.state)
-                except Exception as e:  # noqa: BLE001 — fall back to older checkpoint
-                    attempts.append(f"{name}: orbax restore failed ({type(e).__name__}: {e})")
-                    continue
-                self.last_restore_fallback = i > 0
-                if i > 0 and is_main_process():
-                    print(
-                        f"[trlx_tpu.resilience] latest checkpoint unusable "
-                        f"({'; '.join(attempts)}) — fell back to {name}",
-                        file=sys.stderr,
-                    )
-                host_file = f"{path}.host.json"
-                if os.path.exists(host_file):
-                    with open(host_file) as f:
-                        self.load_host_state(json.load(f))
-                obs_spans.complete(
-                    "ckpt/load", load_t0, ckpt=name, fallback=bool(i > 0)
+            # Candidate order: the latest pointer first, then every other
+            # state_* directory newest-step-first.
+            candidates = []
+            if latest is not None:
+                candidates.append(latest)
+            for name in ckpt_util.list_checkpoints(directory):
+                if name != os.path.basename(candidates[0] if candidates else ""):
+                    candidates.append(name)
+            if not candidates:
+                raise CheckpointError(
+                    f"no checkpoint found in {directory}: "
+                    + ("latest.txt is empty" if os.path.exists(latest_path) else "latest.txt is missing")
+                    + " and no state_* directories exist — nothing to resume from "
+                    "(set train.resume_from_checkpoint=False to start fresh, or "
+                    "point train.checkpoint_dir at the directory that holds the run)"
                 )
-                return self.state
 
-        raise CheckpointError(
-            f"no restorable checkpoint in {directory} — every candidate "
-            f"failed verification or restore: {'; '.join(attempts)}. "
-            "If the data is gone, set train.resume_from_checkpoint=False to "
-            "start fresh."
-        )
+            attempts = []
+            for i, cand in enumerate(candidates):
+                name = os.path.basename(cand)
+                # Older checkpoints stored an absolute path; fall back to its
+                # basename under the current directory when it moved.
+                path = (
+                    cand
+                    if os.path.isabs(cand) and os.path.exists(cand)
+                    else os.path.join(directory, name)
+                )
+                # In-use marker: another process GC-ing this directory (e.g. a
+                # concurrent run finalizing its own save) must not delete a
+                # candidate out from under the verify/restore below.
+                with ckpt_util.mark_in_use(os.path.dirname(path), name):
+                    if not os.path.isdir(path):
+                        ok, reason = False, "checkpoint directory missing"
+                    else:
+                        ok, reason = ckpt_util.verify_checkpoint(os.path.dirname(path), name)
+                    if jax.process_count() > 1:
+                        # Cross-host agreement BEFORE the collective restore:
+                        # the orbax restore must be entered by every host or by
+                        # none, and a checkpoint torn on ONE host's view of the
+                        # filesystem fails the candidate for ALL — otherwise
+                        # the fleet deadlocks split across two candidates.
+                        from trlx_tpu.parallel.mesh import allgather_host
+
+                        oks = allgather_host(np.asarray([ok], dtype=np.int32)).reshape(-1)
+                        if not oks.all():
+                            bad = [int(p) for p in np.flatnonzero(oks == 0)]
+                            attempts.append(
+                                f"{name}: failed verification on host(s) {bad}"
+                                + (f" (local: {reason})" if not ok else "")
+                            )
+                            continue
+                    elif not ok:
+                        attempts.append(f"{name}: {reason}")
+                        continue
+                    try:
+                        self.state = self._ckptr.restore(path, self.state)
+                    except Exception as e:  # noqa: BLE001 — fall back to older checkpoint
+                        attempts.append(f"{name}: orbax restore failed ({type(e).__name__}: {e})")
+                        continue
+                    self.last_restore_fallback = i > 0
+                    if i > 0 and is_main_process():
+                        print(
+                            f"[trlx_tpu.resilience] latest checkpoint unusable "
+                            f"({'; '.join(attempts)}) — fell back to {name}",
+                            file=sys.stderr,
+                        )
+                    host_file = f"{path}.host.json"
+                    if os.path.exists(host_file):
+                        with open(host_file) as f:
+                            self.load_host_state(json.load(f))
+                    span.args.update(ckpt=name, fallback=bool(i > 0))
+                    return self.state
+
+            raise CheckpointError(
+                f"no restorable checkpoint in {directory} — every candidate "
+                f"failed verification or restore: {'; '.join(attempts)}. "
+                "If the data is gone, set train.resume_from_checkpoint=False to "
+                "start fresh."
+            )
 
     # ------------------------------------------------------- BaseRL protocol
 

@@ -23,6 +23,7 @@ from trlx_tpu.data import ILQLBatch
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.heads import LMWithILQLHeads
 from trlx_tpu.observability import numerics as obs_numerics
+from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.ops.fused_logprob import fused_logprob_eligible, routed_logprob
 from trlx_tpu.ops.generate import make_generate_fn
 from trlx_tpu.ops.ilql_loss import action_tokens, ilql_loss, ilql_loss_terms
@@ -361,7 +362,7 @@ class ILQLTrainer(JaxBaseTrainer):
             # GL001: polyak sync is a jitted dispatch like any other — it must
             # enqueue under the lock so it cannot interleave with a concurrent
             # generate/train dispatch from another thread.
-            with self._dispatch_lock:
+            with trace_span("train/polyak_sync"), self._dispatch_lock:
                 prev_extras = self.state.extras
                 new_extras = self._sync_fn(self.state.params, self.state.extras, self.config.method.alpha)
             # _sync_fn donates the old target heads (donate_argnums=(1,)).

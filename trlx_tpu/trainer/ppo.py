@@ -24,6 +24,8 @@ from trlx_tpu.ops.generate import make_generate_fn
 from trlx_tpu.ops.modeling import logprobs_from_logits
 from trlx_tpu.ops.rl_losses import kl_penalty_rewards, ppo_loss
 from trlx_tpu.observability import numerics as obs_numerics
+from trlx_tpu.observability import spans as obs_spans
+from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.ops.sampling import GenerateConfig
 from trlx_tpu.parallel.mesh import DATA_AXES
 from trlx_tpu.pipeline.overlap import PhaseTimer, RolloutProducer
@@ -803,7 +805,11 @@ class PPOTrainer(JaxBaseTrainer):
             self._kl_pending.append(stats["mean_kl"])
             # Keep the buffer (and the retained device scalars) bounded.
             if len(self._kl_pending) >= max(self.config.train.log_interval, 8):
-                self._flush_kl_updates()
+                # The pull waits for the step just dispatched: device wait,
+                # not host work, so time/step_host_ms leaves it out.
+                with trace_span("train/kl_flush") as flush:
+                    self._flush_kl_updates()
+                self._step_wait_s += flush.seconds
 
     def _flush_kl_updates(self):
         if not self._kl_pending:
@@ -830,9 +836,14 @@ class PPOTrainer(JaxBaseTrainer):
 
     def post_epoch_callback(self):
         """Alternate back to rollout
-        (reference: trlx/model/accelerate_ppo_model.py:157-161)."""
-        self._flush_kl_updates()  # rollout rewards consume kl_ctl.value
-        self._refresh_decode_weights()  # sampler follows the updated policy
+        (reference: trlx/model/accelerate_ppo_model.py:157-161). Each piece
+        of the boundary is a span of its own (short ones: a profiler that
+        starts inside the rollout still sees the next one begin)."""
+        obs_spans.set_iteration(obs_spans.iteration() + 1)  # rollout n+1 and the training on it
+        with trace_span("boundary/kl_flush"):
+            self._flush_kl_updates()  # rollout rewards consume kl_ctl.value
+        with trace_span("boundary/refresh_weights"):
+            self._refresh_decode_weights()  # sampler follows the updated policy
         if self._fleet_feed is not None:
             # Disaggregated/colocated fleet: publish the post-train weights
             # (versioned broadcast), then consume the next stream batch.
@@ -849,7 +860,8 @@ class PPOTrainer(JaxBaseTrainer):
         elif self._rollout_producer is None:
             # Serial schedule: generate the next iteration's experience
             # inline, into the (cleared) long-lived store.
-            self.store.clear_history()
+            with trace_span("boundary/store_clear"):
+                self.store.clear_history()
             self.orch.make_experience(self.config.method.num_rollouts, self.iter_count)
         else:
             # Pipelined schedule: release the producer (one iteration fully
@@ -861,13 +873,16 @@ class PPOTrainer(JaxBaseTrainer):
             snapshot = self._rollout_snapshot() if self.max_staleness > 0 else None
             self._rollout_producer.consume_done(snapshot=snapshot)
             self.store = self._rollout_producer.next_store()
-        self.train_dataloader = self.store.create_loader(
-            self.config.train.batch_size,
-            shuffle=True,
-            pack=self._pack_train_batch,
-            rows_multiple=self._pack_rows_multiple,
-        )
-        self._log_phase_window()
+        with trace_span("boundary/loader"):
+            self.train_dataloader = self.store.create_loader(
+                self.config.train.batch_size,
+                shuffle=True,
+                pack=self._pack_train_batch,
+                rows_multiple=self._pack_rows_multiple,
+            )
+        with trace_span("boundary/phase_log"):
+            self._log_phase_window()
+        obs_spans.flush()
 
     def _prepare_batch(self, batch):
         """Also meter the train phase's token throughput: count the tokens
@@ -893,6 +908,18 @@ class PPOTrainer(JaxBaseTrainer):
         overlaps — and feeds time/* + overlap_fraction to the tracker and
         the progress line."""
         stats = self._phase_timer.window()
+        # The spans' accumulators, drained on the same cadence: self-seconds
+        # by span name since the last window, and what this thread spent
+        # inside its top-level spans (the rest of the wall is unspanned).
+        acc = obs_spans.drain()
+        self_s = acc["self_s"]
+        stats["time/generate_s"] = sum(
+            v for k, v in self_s.items() if k.startswith("rollout/generate") or k == "rollout/pull"
+        )
+        stats["time/score_device_s"] = self_s.get("rollout/score_device", 0.0)
+        stats["time/push_s"] = self_s.get("rollout/push", 0.0)
+        stats["time/boundary_s"] = sum(v for k, v in self_s.items() if k.startswith("boundary/"))
+        stats["time/unspanned_s"] = max(0.0, stats["time/window_wall_s"] - acc["top_s"])
         window_tokens, self._window_tokens = self._window_tokens, []
         window_fill, self._window_fill = self._window_fill, []
         train_s = stats.get("time/train_s", 0.0)
