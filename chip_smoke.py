@@ -223,6 +223,48 @@ def _masks(rng, batch, length):
     return valid
 
 
+def _time_ranged_read(q, cache, bias, T, scale, steps=64):
+    """What a decode kernel has to beat now: XLA's read inside a loop that
+    carries the int8 cache, as the generate program's does (alone, the same
+    read compiles to another program and takes two to three times as long).
+    Each step writes the frontier's slot and reads: the whole cache, as before
+    PR 24, beside the program's own read (ops/kv_read.py) with the frontier in
+    the middle of the cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.ops.kv_read import attend_range, kv_read_bucket, kv_read_ranges, ranged_read
+
+    def loop(ranged):
+        def body(i, carry):
+            q, cache = carry
+            index = T // 2 + i % 8  # traced, like the generate loop's write offset
+            cache = tuple(jax.lax.dynamic_update_slice_in_dim(a, a[:, :1], index, axis=1) for a in cache)
+            if ranged:
+                out = ranged_read(T, 1, index)(q, cache, bias, scale, jnp.bfloat16)
+            else:
+                out = attend_range(q, cache, bias, 0, T, scale, jnp.bfloat16)
+            # the next query depends on this read: nothing of it can be hoisted
+            return q + out * jnp.bfloat16(1e-3), cache
+
+        return jax.jit(lambda q, cache: jax.lax.fori_loop(0, steps, body, (q, cache))[0])
+
+    per_step = lambda ranged: round(_time_us(loop(ranged), q[:, None], cache, iters=3) / steps)
+    bias = bias[:, None, None, :]
+    lo, hi = kv_read_ranges(T)[(T // 2) // kv_read_bucket(T)]
+    verdict = {
+        "kernel": f"xla read in a loop, int8 cache [{','.join(map(str, cache[0].shape))}]",
+        "compiled": True, "within_tol": True, "route": "xla einsum (ranged read)",
+        "whole_cache_us": per_step(False), "range": [lo, hi], "range_us": per_step(True),
+    }
+    print(
+        f"[kernel] {verdict['kernel']}: route={verdict['route']} info: a step reading the whole cache "
+        f"{verdict['whole_cache_us']} us, the ranged read at frontier {T // 2} ([{lo},{hi})) {verdict['range_us']} us",
+        flush=True,
+    )
+    return verdict
+
+
 def kernel_phase(size, interpret):
     import jax
     import jax.numpy as jnp
@@ -352,6 +394,8 @@ def kernel_phase(size, interpret):
             einsum_decode, (qd, kq, vq, ks, vs, bias), FWD_TOL,
             decode_route(da.decode_attn_eligible(h, d, T, quant)),
         ))
+        if quant and not interpret:
+            verdicts.append(_time_ranged_read(qd, (kq, vq, ks, vs), bias, T, scale))
         pools = tuple(None if a is None else pool(a) for a in (kq, vq, ks, vs))
         verdicts.append(check(
             f"paged_decode_attention {kind} {C * bps} blocks x {bs}, {bps} per slot",

@@ -1,0 +1,205 @@
+"""Decode attention's ranged cache read (trlx_tpu/ops/kv_read.py).
+
+The static generate path reads, on each decode step, only a static-size
+slice of the KV cache that holds every key the bias can admit, chosen by a
+`lax.switch` on the write frontier. These tests pin: the same logits and
+tokens as the full-cache read, the ranges as a pure function, that the
+engine's per-row and paged steps do not take it, that the decode loop stays
+one `while`, and the host counter `rollout/kv_read_share`.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import trlx_tpu.models.lm as lm
+from trlx_tpu.models.lm import LMConfig, TransformerLM, init_cache, init_paged_cache, make_attn_bias
+from trlx_tpu.ops.generate import generate
+from trlx_tpu.ops.kv_read import (
+    KV_READ_BUCKET,
+    KV_READ_MAX_BRANCHES,
+    kv_keys_read,
+    kv_read_bucket,
+    kv_read_ranges,
+    ranged_read,
+)
+from trlx_tpu.ops.sampling import GenerateConfig
+
+# Prompt 120 + 200 new tokens: a cache of 320 (323 with soft slots) has the
+# branches [0,128) [0,256) [0,320); the frontier starts in the first and
+# crosses both bucket edges. Window 100 < cache: the last local branch starts
+# at 128, so a range with lo > 0 is exercised too.
+B, P, N, WINDOW, N_SOFT = 2, 120, 200, 100, 3
+
+
+def _tiny(quant, local, soft, n_layer=2):
+    cfg = LMConfig(
+        vocab_size=64, n_layer=n_layer, n_head=2, d_model=32, max_position=512,
+        pos_type="learned" if local else "rotary", rotary_dim=8,
+        attention_layers=("global", "local") * (n_layer // 2) if local else (),
+        window_size=WINDOW if local else 0,
+        n_soft_tokens=N_SOFT if soft else 0, kv_cache_quant=quant, dtype="float32",
+    )
+    model = TransformerLM(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (B, P), 1, cfg.vocab_size)
+    mask = jnp.asarray(np.arange(P)[None, :] >= np.array([[17], [0]]), jnp.int32)  # row 0 left-padded
+    params = model.init(jax.random.PRNGKey(0), ids[:, :4], mask[:, :4])
+    return cfg, model, params, ids * mask, mask
+
+
+def _greedy(model, params, ids, mask, new_tokens=N):
+    """Greedy generate; returns (tokens, [b, N, V] logits each step sampled from)."""
+    gcfg = GenerateConfig(max_new_tokens=new_tokens, do_sample=False, eos_token_id=None)
+    fn = lambda p, i, m: generate(
+        p, i, m, jax.random.PRNGKey(2), model=model, gcfg=gcfg,
+        step_stats_fn=lambda tok, s: {"logits": s["last_logits"]},
+    )
+    tokens, _, stats = jax.jit(fn)(params, ids, mask)
+    return np.asarray(tokens), np.asarray(stats["logits"])
+
+
+@pytest.mark.parametrize("soft", [False, True], ids=["nosoft", "soft"])
+@pytest.mark.parametrize("local", [False, True], ids=["global", "alternating-local"])
+@pytest.mark.parametrize("quant", [False, True], ids=["plain-cache", "int8-cache"])
+def test_ranged_read_matches_full_read(monkeypatch, quant, local, soft):
+    """(a) Same logits (float32) and the same greedy tokens as the read of
+    the whole cache, while the frontier crosses two bucket edges."""
+    cfg, model, params, ids, mask = _tiny(quant, local, soft)
+    assert len(kv_read_ranges(P + N + cfg.n_soft_tokens, 0)) == 3
+    tokens, logits = _greedy(model, params, ids, mask)
+    monkeypatch.setattr(lm, "ranged_read", lambda *a, **k: None)  # today's read
+    tokens_full, logits_full = _greedy(model, params, ids, mask)
+    np.testing.assert_array_equal(tokens, tokens_full)
+    np.testing.assert_allclose(logits, logits_full, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("window", [0, 1, 100, 256, 5000])
+@pytest.mark.parametrize("cache_len", [1, 100, 128, 129, 320, 512, 1024, 1500, 2048, 4100])
+def test_ranges_hold_every_admitted_key(cache_len, window):
+    """(b) Pure function: at every cache_index of a branch, every key the
+    bias admits lies inside the branch's [lo, hi)."""
+    bucket = kv_read_bucket(cache_len)
+    ranges = kv_read_ranges(cache_len, window)
+    assert bucket % KV_READ_BUCKET == 0 and 1 <= len(ranges) <= KV_READ_MAX_BRANCHES
+    assert len(ranges) == -(-cache_len // bucket)
+    if cache_len <= KV_READ_BUCKET:
+        assert ranges == ((0, cache_len),)  # one branch: the read of today
+    index = np.arange(cache_len)
+    lo, hi = np.array(ranges)[np.minimum(index // bucket, len(ranges) - 1)].T
+    first_admitted = np.maximum(index - window + 1, 0) if window > 0 else np.zeros_like(index)
+    assert (lo <= first_admitted).all() and (index < hi).all() and (hi <= cache_len).all()
+    assert (lo % bucket == 0).all()
+    # and against the bias itself, at the branches' first and last frontiers
+    for c in {0, cache_len - 1, *(r[1] - 1 for r in ranges), *(k * bucket for k in range(len(ranges)))}:
+        row = np.asarray(make_attn_bias(jnp.ones((1, cache_len), jnp.int32), 1, jnp.int32(c), window=window))[0, 0, 0]
+        admitted = np.flatnonzero(row == 0.0)
+        k = min(c // bucket, len(ranges) - 1)
+        assert ranges[k][0] <= admitted.min() and admitted.max() < ranges[k][1], (c, ranges[k])
+
+
+def _count(jaxpr, name):
+    """Equations of primitive `name`, through every sub-jaxpr."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count(sub, name)
+    return n
+
+
+@pytest.mark.parametrize("path", ["vector-index", "spec-verify", "paged", "paged-scalar-index", "prefill"])
+@pytest.mark.parametrize("quant", [False, True], ids=["plain-cache", "int8-cache"])
+def test_engine_and_paged_steps_keep_the_full_read(monkeypatch, quant, path):
+    """(c) A per-row write offset (slot decode, spec verify), a block table
+    and a multi-token prefill lower to the jaxpr of the full read: no
+    conditional, and the same text as with the ranged read switched off."""
+    cfg, model, params, _, _ = _tiny(quant, local=True, soft=False)
+    T = 320
+    vec = jnp.array([5, 290], jnp.int32)
+    q_len = {"spec-verify": 4, "prefill": 4}.get(path, 1)
+    index = {"paged-scalar-index": jnp.int32(7), "prefill": 0}.get(path, vec)
+    if path.startswith("paged"):
+        cache, tables, kv = init_paged_cache(cfg, 20, 32), jnp.arange(20, dtype=jnp.int32).reshape(B, 10), 320
+        index = index if path == "paged-scalar-index" else vec % 300
+    else:
+        cache, tables, kv = init_cache(cfg, B, T), None, T
+    assert ranged_read(kv, q_len, index, WINDOW) is None or path == "paged-scalar-index"
+
+    def step(p):
+        return model.apply(
+            p, input_ids=jnp.ones((B, q_len), jnp.int32), attention_mask=jnp.ones((B, q_len), jnp.int32),
+            cache=cache, cache_index=index, cache_mask=jnp.ones((B, kv), jnp.int32), block_tables=tables,
+        )["logits"]
+
+    text = str(jax.make_jaxpr(step)(params))
+    assert _count(jax.make_jaxpr(step)(params).jaxpr, "cond") == 0
+    monkeypatch.setattr(lm, "ranged_read", lambda *a, **k: None)
+    assert text == str(jax.make_jaxpr(lambda p: step(p))(params))
+
+
+def test_generate_is_one_while_with_one_switch_a_layer(monkeypatch):
+    """(d) The decode loop stays ONE `while` (decode_ms_per_step reads the
+    largest `while` of the program): the switch wraps the read, per layer,
+    inside the body; nothing is cut into segments."""
+    cfg, model, params, ids, mask = _tiny(quant=True, local=True, soft=False, n_layer=4)
+    gcfg = GenerateConfig(max_new_tokens=N, do_sample=True, eos_token_id=None)
+    fn = lambda p, i, m: generate(p, i, m, jax.random.PRNGKey(2), model=model, gcfg=gcfg)
+    jaxpr = jax.make_jaxpr(fn)(params, ids, mask).jaxpr
+    whiles = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    assert len(whiles) == 1 and _count(jaxpr, "while") == 1
+    assert _count(jaxpr, "cond") == _count(whiles[0].params["body_jaxpr"].jaxpr, "cond") == cfg.n_layer
+    # no branch returns a cache: every conditional yields the [b, 1, h, d] read
+    for eqn in whiles[0].params["body_jaxpr"].jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            assert [v.aval.shape for v in eqn.outvars] == [(B, 1, cfg.n_head, cfg.head_dim)]
+    # the lowered program has as many loops as with the full read (the other
+    # two are the CPU lowering of the sampler's random bits, outside the scope)
+    loops = jax.jit(fn).lower(params, ids, mask).as_text().count("stablehlo.while")
+    monkeypatch.setattr(lm, "ranged_read", lambda *a, **k: None)
+    assert loops == jax.jit(lambda p, i, m: fn(p, i, m)).lower(params, ids, mask).as_text().count("stablehlo.while")
+
+
+@pytest.mark.parametrize("soft", [0, N_SOFT], ids=["nosoft", "soft"])
+def test_kv_read_share_by_hand(soft):
+    """(e) The counter's two sums for the shapes of (a), against the share
+    worked out by hand from the three branches."""
+    cache_len, first = P + N + soft, P + soft
+    # steps whose write slot lies in [120,128) [128,256) [256,320) (soft: +3 on both sides)
+    in_branch = [128 - first, 128, N - 128 - (128 - first)]
+    global_keys = in_branch[0] * 128 + in_branch[1] * 256 + in_branch[2] * cache_len
+    local_keys = in_branch[0] * 128 + in_branch[1] * 256 + in_branch[2] * (cache_len - 128)
+    assert kv_keys_read(cache_len, first, N, [0]) == (global_keys, cache_len * N)
+    assert kv_keys_read(cache_len, first, N, [0, WINDOW]) == (global_keys + local_keys, 2 * cache_len * N)
+    if not soft:
+        assert global_keys / (cache_len * N) == pytest.approx(0.848)
+        assert local_keys / (cache_len * N) == pytest.approx(0.72)
+    # a cache of one bucket reads all of it; an early exit counts only its steps
+    assert kv_keys_read(100, 40, 60, [0, 30]) == (2 * 100 * 60, 2 * 100 * 60)
+    assert kv_keys_read(cache_len, first, 8 - soft, [0]) == ((8 - soft) * 128, (8 - soft) * cache_len)
+    # the benchmark's rollout shapes (PERF.md, PR 24)
+    # (cache 1024 has buckets of 256: 128 steps read 256 keys, then 256 steps each 512, 768, 1024)
+    assert kv_keys_read(1024, 128, 896, [0] * 8)[0] / (1024 * 896 * 8) == pytest.approx(19 / 28)
+    assert kv_keys_read(512, 256, 256, [0, 256] * 12)[0] / (512 * 256 * 24) == pytest.approx(0.8125)
+    assert kv_keys_read(1024, 768, 256, [0] * 8)[0] / (1024 * 256 * 8) == 1.0  # the frontier starts in the last bucket
+
+
+def test_a_partitioned_mesh_keeps_the_full_read():
+    """The read's layout request is a custom call that GSPMD would answer by
+    replicating the cache in every branch (a device-free v5e compile over
+    fsdp x tp shows 128 all-gathers), so on a mesh of more than one device the
+    generate program keeps the read of the whole cache."""
+    from trlx_tpu.parallel import make_mesh
+    from trlx_tpu.parallel.mesh import peek_mesh, set_mesh
+
+    cfg, model, params, ids, mask = _tiny(quant=True, local=True, soft=False)
+    gcfg = GenerateConfig(max_new_tokens=N, do_sample=False, eos_token_id=None)
+    fn = lambda p, i, m: generate(p, i, m, jax.random.PRNGKey(2), model=model, gcfg=gcfg)
+    assert _count(jax.make_jaxpr(fn)(params, ids, mask).jaxpr, "cond") == cfg.n_layer
+    prior = peek_mesh()
+    set_mesh(make_mesh((1, 2, 4, 1)))
+    try:
+        assert _count(jax.make_jaxpr(lambda p, i, m: fn(p, i, m))(params, ids, mask).jaxpr, "cond") == 0
+        assert kv_keys_read(P + N, P, N, [0, WINDOW]) == (2 * (P + N) * N,) * 2  # and the counter says so
+    finally:
+        set_mesh(prior)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trlx_tpu.observability import numerics as obs_numerics
+from trlx_tpu.ops.kv_read import attend, ranged_read
 
 Dtype = Any
 
@@ -380,6 +381,7 @@ class Attention(nn.Module):
         new_cache = None
         decode_kernel_kv = None  # set → route this step through the fused
         # pallas decode-attention kernel (single-token, cache-resident)
+        read = None  # set → the einsum read covers a slice of the cache
         if cache is not None:
             from trlx_tpu.ops.decode_attention import (
                 decode_attn_eligible,
@@ -459,6 +461,13 @@ class Attention(nn.Module):
                     # Legacy per-slot buffers ARE the virtual cache.
                     return buf
 
+            # One traced write offset for the whole batch on a fixed buffer
+            # (a decode step of the static generate path): the einsum read
+            # covers only the slots the bias can admit (ops/kv_read.py). A
+            # per-row index or a block table keeps the full read below.
+            if flash_mask is None and not paged:
+                read = ranged_read(int(cache[0].shape[1]), q_len, cache_index, window)
+
             def kernel_ok(quant):
                 # Two gates, both static at trace time: the eligibility rule,
                 # then the cached tile check. On a TPU backend a shape that
@@ -496,7 +505,7 @@ class Attention(nn.Module):
                         # folded into the attention algebra) — HBM traffic
                         # is exactly the int8 bytes.
                         decode_kernel_kv = (k_cache, v_cache, ks_cache, vs_cache)
-                    else:
+                    elif read is None:
                         # Dequantize on read for the einsum path (paged:
                         # gather the virtual view first).
                         k = gather_virt(k_cache).astype(dtype) * gather_virt(ks_cache)[..., None].astype(dtype)
@@ -514,7 +523,7 @@ class Attention(nn.Module):
                 if flash_mask is None:
                     if single_step and kernel_ok(False):
                         decode_kernel_kv = (k_cache, v_cache, None, None)
-                    else:
+                    elif read is None:
                         k, v = gather_virt(k_cache), gather_virt(v_cache)
 
         scale = 1.0 / np.sqrt(hd) if cfg.scale_attn else 1.0
@@ -553,13 +562,10 @@ class Attention(nn.Module):
                 out = decode_attention(
                     q[:, 0], kc, vc, ksc, vsc, attn_bias[:, 0, 0, :], scale=scale
                 ).astype(dtype)
+        elif read is not None:
+            out = read(q, new_cache, attn_bias, scale, dtype)
         else:
-            # [b, n_head, q, kv] scores in fp32 for a stable softmax.
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
-            scores = scores * scale
-            scores = scores + attn_bias  # additive -inf mask [b, 1, q, kv]
-            probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-            out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(dtype))
+            out = attend(q, k, v, attn_bias, scale, dtype)
         out = out.reshape(b, q_len, cfg.d_model)
         out = dense(cfg.d_model, "c_proj", cfg.out_bias)(out)
         return out, new_cache

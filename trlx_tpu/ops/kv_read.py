@@ -1,0 +1,152 @@
+"""The einsum read of a fixed KV cache, over only the keys the bias can admit.
+
+On the static generate path (`ops/generate.py`: prompts are left-padded, so
+every row writes slot `P + step`) the additive bias admits key `j` for the
+query at slot `c` only if `j <= c` (and `j > c - window` on a local layer).
+The full-cache read dequantizes and contracts every slot and lets the bias
+zero the rest: a masked key contributes `exp(-1e9 - max)`, which is exactly 0
+in float32, so leaving it out of the read is the same mathematics on fewer
+bytes.
+
+Two pieces, both pure:
+
+- `kv_read_ranges(cache_len, window)`: the static `[lo, hi)` of each branch;
+  branch `k` serves every `cache_index` in `[k * bucket, (k + 1) * bucket)`.
+- `attend_range(q, cache, attn_bias, lo, hi, ...)`: slice K, V, their scales
+  and the bias to the range BEFORE the dequantize and the two contractions,
+  so XLA fuses the slice into the operand load as it fuses the int8 convert.
+
+`ranged_read` is the rule `Attention.__call__` asks: which of its calls take
+the ranged read at all. The cache WRITE is not this module's business: it
+stays one `dynamic_update_slice` on the whole buffer.
+"""
+
+from functools import partial
+from typing import Callable, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
+
+from trlx_tpu.parallel.mesh import peek_mesh
+
+# The bucket is a function of the cache length alone: 128 keys (one lane tile
+# of the bias row and of the scales, so every slice edge is tile-aligned),
+# doubled until at most 4 branches cover the cache. Swept once on the v5e at
+# the benchmark's rollout shapes (PERF.md, PR 24): finer buckets read fewer
+# keys but each branch is another copy of the read's code, and past four the
+# loop's other operations are scheduled worse than the keys saved are worth
+# (cache 1024: 256 keys beat 128 and 64; cache 512: 128 keys). Not a config
+# field: the right value depends on the chip and the compiler, not on the
+# model or the run.
+KV_READ_BUCKET = 128
+KV_READ_MAX_BRANCHES = 4
+
+
+def kv_read_bucket(cache_len: int) -> int:
+    bucket = KV_READ_BUCKET
+    while -(-cache_len // bucket) > KV_READ_MAX_BRANCHES:
+        bucket *= 2
+    return bucket
+
+
+def kv_read_ranges(cache_len: int, window: int = 0) -> Tuple[Tuple[int, int], ...]:
+    """Static `[lo, hi)` per branch; branch `k` is `cache_index // bucket`.
+
+    Every key the bias admits at any `cache_index` of branch `k` lies inside
+    its range: `hi` is the branch's last frontier + 1 (clipped to the cache),
+    `lo` is 0 on a global layer and, on a local one, the bucket edge at or
+    below the first key the window can still see from the branch's first
+    frontier. A cache of one bucket or less has one branch: the whole cache.
+    """
+    bucket = kv_read_bucket(cache_len)
+    ranges = []
+    for k in range(-(-cache_len // bucket)):
+        lo = max(0, k * bucket - window) // bucket * bucket if window > 0 else 0
+        ranges.append((lo, min((k + 1) * bucket, cache_len)))
+    return tuple(ranges)
+
+
+def attend(q, k, v, attn_bias, scale, dtype):
+    """Softmax attention of `q` [b, q, h, d] over `k`/`v` [b, kv, h, d]."""
+    # [b, n_head, q, kv] scores in fp32 for a stable softmax.
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
+    scores = scores * scale
+    scores = scores + attn_bias  # additive -inf mask [b, 1, q, kv]
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(dtype))
+
+
+def attend_range(q, cache, attn_bias, lo: int, hi: int, scale, dtype):
+    """`attend` over cache slots `[lo, hi)`; `cache` is `(k, v)` or the int8
+    `(k, v, k_scale, v_scale)`, each whole."""
+    cut = lambda a: jax.lax.slice_in_dim(a, lo, hi, axis=1)
+    k, v = cut(cache[0]), cut(cache[1])
+    if len(cache) == 4:
+        k = k.astype(dtype) * cut(cache[2])[..., None].astype(dtype)
+        v = v.astype(dtype) * cut(cache[3])[..., None].astype(dtype)
+    return attend(q, k, v, jax.lax.slice_in_dim(attn_bias, lo, hi, axis=3), scale, dtype)
+
+
+def _partitioned() -> bool:
+    """A mesh of more than one device keeps the full read: the layout request
+    in `ranged_read` is a custom call, and GSPMD replicates what it cannot
+    partition, so every branch would all-gather the cache."""
+    mesh = peek_mesh()
+    return mesh is not None and mesh.size > 1
+
+
+def _read_branch(q, cache, attn_bias, *, lo, hi, scale, dtype):
+    # A conditional's operands take the default, batch-major layout, and with
+    # them the cache the loop carries. Outside a conditional XLA keeps it
+    # slot-major ([T, h, b, d]: a slice of slots is one contiguous block, a
+    # [b, d] tile is full), and batch-major the GPT-Neo decode loop took 5.71 s
+    # against 3.56 s (PERF.md, PR 24): ask for slot-major.
+    cache = tuple(
+        with_layout_constraint(a, Layout(major_to_minor=(1, 2, 0, 3)[: a.ndim])) for a in cache
+    )
+    return attend_range(q, cache, attn_bias, lo, hi, scale, dtype)
+
+
+def ranged_read(cache_len: int, q_len: int, cache_index, window: int = 0) -> Optional[Callable]:
+    """The read for one decode step on a fixed cache with ONE traced write
+    offset for the whole batch, as `read(q, cache, attn_bias, scale, dtype)`:
+    a `lax.switch` over `kv_read_ranges`, around the read only. Its operands
+    are read-only and its result is `[b, 1, h, d]`, so no branch returns (or
+    copies) a cache. None where the caller keeps its full read: more than one
+    query token, a per-row (vector) offset, a cache of a single branch, or a
+    mesh of more than one device.
+    """
+    scalar = not isinstance(cache_index, (int, np.integer)) and jnp.ndim(cache_index) == 0
+    ranges = kv_read_ranges(cache_len, window)
+    if q_len != 1 or not scalar or len(ranges) == 1 or _partitioned():
+        return None
+    bucket = kv_read_bucket(cache_len)
+
+    def read(q, cache, attn_bias, scale, dtype):
+        branches = [
+            partial(_read_branch, lo=lo, hi=hi, scale=scale, dtype=dtype) for lo, hi in ranges
+        ]
+        return jax.lax.switch(cache_index // bucket, branches, q, tuple(cache), attn_bias)
+
+    return read
+
+
+def kv_keys_read(
+    cache_len: int, first_index: int, steps: int, windows: Sequence[int]
+) -> Tuple[int, int]:
+    """(keys read, keys a full-cache read would have touched) by the decode
+    steps of one rollout, summed over layers (`windows`: each layer's window,
+    0 = global) and over `steps` steps writing slots `first_index`,
+    `first_index + 1`, ... Counted on the host from shapes alone; their ratio
+    over a rollout phase is the counter `rollout/kv_read_share`."""
+    full = cache_len * steps * len(windows)
+    if _partitioned():
+        return full, full
+    branch = (first_index + np.arange(steps)) // kv_read_bucket(cache_len)
+    read = 0
+    for window in windows:
+        width = np.array([hi - lo for lo, hi in kv_read_ranges(cache_len, window)])
+        read += int(width[branch].sum())
+    return read, full

@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
+from trlx_tpu.ops.kv_read import kv_keys_read
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
 from trlx_tpu.pipeline.overlap import ScoreWorker
 from trlx_tpu.resilience.faults import FaultInjected
@@ -255,6 +256,15 @@ class PPOOrchestrator(Orchestrator):
         decode_steps = []
         episode_steps = []
         step_budget = 0
+        # Keys the decode steps' attention read, and what full-cache reads
+        # would have touched (ops/kv_read.py): from shapes and step counts.
+        lm_cfg = rl.model.cfg
+        n_soft = lm_cfg.n_soft_tokens
+        layer_windows = [
+            lm_cfg.window_size if kind == "local" else 0
+            for kind in lm_cfg.attention_layers or ("global",) * lm_cfg.n_layer
+        ]
+        kv_keys = np.zeros(2, dtype=np.int64)
         # Final-chunk stats for logging; placeholders are never logged (the
         # aborted path returns before the tracker call).
         last_scores = np.zeros((1,), dtype=np.float32)
@@ -394,6 +404,9 @@ class PPOOrchestrator(Orchestrator):
                 ds = rl.rollout_decode_stats(mask_h, P)
                 gen_tokens += ds["gen_tokens"]
                 decode_steps.append(ds["decode_steps"])
+                kv_keys += np.array(kv_keys_read(
+                    mask_h.shape[1] + n_soft, P + n_soft, ds["decode_steps"], layer_windows
+                ))
                 episode_steps.extend(int(v) for v in ds["episode_steps"])
                 step_budget = ds["decode_step_budget"]
 
@@ -505,6 +518,7 @@ class PPOOrchestrator(Orchestrator):
         rl._last_exp_stats = {
             "exp_per_sec": stats["exp_per_sec"],
             "rollout/decode_steps": stats["exp_decode_dispatches"],
+            "rollout/kv_read_share": float(kv_keys[0] / kv_keys[1]) if kv_keys[1] else 1.0,
         }
         rl.tracker.log(stats, step=iter_count)
 
