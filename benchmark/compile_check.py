@@ -14,7 +14,9 @@ never a result: nothing runs, and the analysis counts one program, not what
 else the process keeps on the device (decode copies, the rollout cache).
 """
 
+import math
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -36,6 +38,8 @@ def abstract_train_step(cell, config, arch):
     from trlx_tpu.trainer.ppo import make_ppo_train_step
 
     lm_cfg = build_lm_config(config)
+    if math.prod(config.train.mesh) > 1:
+        lm_cfg = lm_cfg.replace(onehot_embed=True)  # as JaxBaseTrainer.finalize_lm_config does on a mesh
     k, n = config.model.num_layers_unfrozen, lm_cfg.n_layer
     ppo = cell["method"] == "ppo"
     if ppo:
@@ -75,34 +79,57 @@ def abstract_train_step(cell, config, arch):
 def main(names):
     import jax
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
+    from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
     from benchmark import harness
     from benchmark.manifest import Manifest
     from trlx_tpu.ops import tiling
+    from trlx_tpu.parallel import make_mesh, set_mesh
+    from trlx_tpu.parallel.mesh import DATA_AXES
+    from trlx_tpu.parallel.sharding import lm_partition_rules, match_partition_rules, sanitize_specs, specs_to_shardings
 
     # the kernel gates' view of the machine: a TPU backend, a one-device mesh
     jax.default_backend = lambda: "tpu"
     tiling.require_lowering = lambda *a, **kw: None  # the compile below is the stricter check
     jax.config.update("jax_enable_compilation_cache", False)
 
-    chip = SingleDeviceSharding(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
-    place = lambda tree: jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree)
+    devices = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
+    chip = SingleDeviceSharding(devices[0])
+    with_shardings = lambda tree, shardings: jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), tree, shardings)
     manifest = Manifest(ROOT)
     for name in names:
         cell = manifest.cell(name)
         config, arch = harness.build_config(cell, manifest.config(cell["config"]), 0, "/nonexistent", False)
+        # A cell on a mesh: the trainer's own placement (parallel/sharding.py's
+        # rules for the state, the batch over the data axes) on the described
+        # chips; the kernel gates see the mesh through `set_mesh`, as they do
+        # in the trainer. memory_analysis() is then one chip's bytes.
+        mesh = make_mesh(config.train.mesh, devices=devices[:cell["chips"]]) if cell["chips"] > 1 else None
+        set_mesh(mesh)
         kernels = {}
         with harness.record_pallas_calls(kernels):
             step, state, batch = abstract_train_step(cell, config, arch)
-            compiled = step.lower(place(state), place(batch)).compile()
+            everywhere = lambda sharding, tree: jax.tree_util.tree_map(lambda s: sharding, tree)
+            if mesh is None:
+                state_at, batch_at = everywhere(chip, state), everywhere(chip, batch)
+            else:
+                specs = sanitize_specs(mesh, state, match_partition_rules(lm_partition_rules(), state))
+                state_at = specs_to_shardings(mesh, specs)
+                batch_at = everywhere(NamedSharding(mesh, PartitionSpec(DATA_AXES, None)), batch)
+            compiled = step.lower(with_shardings(state, state_at), with_shardings(batch, batch_at)).compile()
+        set_mesh(None)
         ma = compiled.memory_analysis()
         gb = lambda b: round(b / 1e9, 3)
-        print(f"[compile_check] {name}: train step compiles for v5e; "
+        text = compiled.as_text()
+        collectives = {op: len(re.findall(rf" {op}(?:-start)?\(", text))
+                       for op in ("all-gather", "reduce-scatter", "all-reduce", "all-to-all", "collective-permute")}
+        collectives["reduce-scatter"] += text.count(", calls=%all-reduce-scatter")  # how a v5e compile writes one
+        print(f"[compile_check] {name}: train step compiles for v5e"
+              f"{'' if mesh is None else ' over mesh ' + str(dict(mesh.shape))}; "
               f"arguments {gb(ma.argument_size_in_bytes)} GB, temporaries {gb(ma.temp_size_in_bytes)} GB, "
               f"outputs {gb(ma.output_size_in_bytes)} GB (aliased {gb(ma.alias_size_in_bytes)} GB), "
-              f"tpu_custom_calls {compiled.as_text().count('tpu_custom_call')}, "
+              f"tpu_custom_calls {text.count('tpu_custom_call')}, collectives {collectives}, "
               f"kernels {sorted(kernels)}", flush=True)
     return 0
 

@@ -31,6 +31,13 @@ ILQL_TRACED_STEPS = 30
 # host stretches, so it is set-up too.
 PPO_WARMUP_ITERATIONS = 2
 PPO_TRACE_FROM_CALL = 4  # trace reward call 4 -> 5: one whole steady cycle
+# What a traced PPO run traces is the cell file's `traced_cycle`. "whole" (a cell
+# that names none): reward call 4 to 5. "train_steps": the last train steps of
+# iteration 3, eight at most, and `learn()` ends with them. The second is for a
+# cell whose decode loop alone leaves millions of events: on four chips at 28
+# layers the profiler took 91 s to hand a whole cycle over (some 35 us an event)
+# and the run passed the 360 s a run may take (PERF.md section 6, PR 25).
+PPO_TRACED_TRAIN_STEPS = 8
 
 
 class BenchFailure(RuntimeError):
@@ -59,6 +66,24 @@ def place_process(chips, rehearsal):
         os.environ.setdefault("TPU_VISIBLE_CHIPS", "0")
         os.environ.setdefault("TPU_CHIPS_PER_PROCESS_BOUNDS", "1,1,1")
         os.environ.setdefault("TPU_PROCESS_BOUNDS", "1,1,1")
+
+
+def setup_cache():
+    """Where the program's own rule puts the persistent compile cache
+    (`JAX_COMPILATION_CACHE_DIR`, else `<checkout>/.jax_cache`), with no cap on
+    its size. Under a cap JAX evicts the least recently used entries, and the
+    programs of the four-chip 28-layer cell are larger than the 192 MiB the
+    chip tool's machine sets (`JAX_COMPILATION_CACHE_MAX_SIZE`): each run
+    evicted what the one before had written, no run ever hit, and every run
+    compiled for 300 s (PERF.md section 6, PR 25). Only a cell's first run in a
+    checkout may compile."""
+    import jax
+
+    from trlx_tpu.utils.compile_cache import setup_compile_cache
+
+    directory = setup_compile_cache()
+    jax.config.update("jax_compilation_cache_max_size", -1)
+    return directory
 
 
 class CompileLog:
@@ -139,9 +164,15 @@ def merged(base, override):
     return out
 
 
+def cell_mesh(cell):
+    return list(cell.get("mesh") or [cell["chips"], 1, 1, 1])
+
+
 def build_config(cell, config_spec, seed, out_dir, rehearsal):
     """The TRLConfig of a cell: the method's default yml, then the
-    configuration's architecture and dtypes, then the cell's recipe."""
+    configuration's architecture and dtypes, then the cell's recipe. The mesh
+    (`dp, fsdp, tp, sp`) is the cell file's `mesh`; a cell that names none
+    is data-parallel over its chips."""
     from trlx_tpu.trainer.api import default_config
 
     arch = dict(config_spec["rehearsal_arch"] if rehearsal else config_spec["model_arch"])
@@ -158,7 +189,7 @@ def build_config(cell, config_spec, seed, out_dir, rehearsal):
     if rehearsal:
         config.model.remat = False  # interpret-mode kernels and remat add minutes on a CPU
     config.train.seed = seed
-    config.train.mesh = [cell["chips"], 1, 1, 1]
+    config.train.mesh = cell_mesh(cell)
     config.train.epochs = 10**6
     config.train.total_steps = 10**9
     config.train.log_interval = 1
@@ -221,6 +252,7 @@ class Tracer:
 
         self.t_stop = time.time()
         jax.profiler.stop_trace()
+        mark("trace_stopped")
 
     @property
     def active(self):
@@ -250,12 +282,14 @@ class IterationStop:
     scoring). Counts the iteration's non-pad tokens, decides on which whole
     iteration `learn()` ends, and drives the profiler in a traced run."""
 
-    def __init__(self, metrics_path, steps_per_iter, seconds, tracer):
+    def __init__(self, metrics_path, steps_per_iter, seconds, tracer, traced_cycle="whole"):
         self.metrics_path, self.spi, self.seconds, self.tracer = metrics_path, steps_per_iter, seconds, tracer
+        self.traced_cycle = traced_cycle
         self.trainer = None
         self.calls = []  # (wall time, non-pad tokens, rows) per rollout
         self.last_iteration = None
         self.traced_calls = None
+        self.step_trace = None  # "train_steps": the thread that starts the profiler
 
     def on_rollout(self, rows):
         if self.last_iteration is not None and len(self.calls) >= self.last_iteration:
@@ -263,6 +297,16 @@ class IterationStop:
         self.calls.append((time.time(), int(sum(len(r) for r in rows)), len(rows)))
         i = len(self.calls)  # this rollout feeds iteration i
         if i <= PPO_WARMUP_ITERATIONS or self.last_iteration is not None:
+            return
+        if self.tracer is not None and self.traced_cycle == "train_steps":
+            # This rollout feeds the first measured iteration: trace its last
+            # train steps and end with them. The profiler starts when the first
+            # of those is dispatched, never inside this call, whose seconds the
+            # program books on the phase record before the iteration.
+            self._end_after(i)
+            first = i * self.spi - min(PPO_TRACED_TRAIN_STEPS, self.spi - 1) + 1
+            self.step_trace = TraceFromStep(self.trainer, self.tracer, first)
+            self.step_trace.start()
             return
         if self.tracer is not None:
             if i == PPO_TRACE_FROM_CALL:
@@ -283,6 +327,34 @@ class IterationStop:
     def _end_after(self, iteration):
         self.last_iteration = iteration
         self.trainer.total_steps = iteration * self.spi
+
+
+class TraceFromStep(threading.Thread):
+    """PPO, `traced_cycle: train_steps`: starts the profiler from beside
+    `learn()` when the program has dispatched train step `first_step`
+    (`trainer.iter_count`), as StepStop does for ILQL. `run_ppo` stops it when
+    `learn()` has returned."""
+
+    def __init__(self, trainer, tracer, first_step):
+        super().__init__(name="bench-trace-from-step", daemon=True)
+        self.trainer, self.tracer, self.first_step = trainer, tracer, first_step
+        self.done = threading.Event()
+        self.error = None
+
+    def run(self):
+        try:
+            while self.trainer.iter_count < self.first_step and not self.done.is_set():
+                time.sleep(0.005)
+            if not self.done.is_set():
+                self.tracer.start()
+        except Exception as e:  # surfaced by the main thread after learn()
+            self.error = e
+
+    def check(self):
+        if self.error is not None:
+            raise self.error
+        if self.tracer.t_start is None:
+            raise BenchFailure(f"learn() ended before train step {self.first_step}: nothing was traced")
 
 
 class StepStop(threading.Thread):
@@ -328,7 +400,7 @@ def run_ppo(cell, config, arch, seed, seconds, tracer):
     prompts = traffic_gen.ppo_prompts(tp, vocab, seed)
     steps_per_iter = config.method.ppo_epochs * (config.method.num_rollouts // config.train.batch_size)
     stop = IterationStop(os.path.join(config.train.checkpoint_dir, "metrics.jsonl"),
-                         steps_per_iter, seconds, tracer)
+                         steps_per_iter, seconds, tracer, cell.get("traced_cycle", "whole"))
 
     def reward_fn(rows):
         with annotate("bench/reward_fn"):
@@ -351,11 +423,18 @@ def run_ppo(cell, config, arch, seed, seconds, tracer):
     mark("first_experience_made")
     # evaluation stays outside the window and is kept to nothing: no prompts
     trainer.add_eval_pipeline(PromptPipeline([], trainer.tokenizer, max_prompt_length=trainer.prompt_length))
-    with annotate("bench/learn"):
-        trainer.learn()
+    try:
+        with annotate("bench/learn"):
+            trainer.learn()
+    finally:
+        if stop.step_trace is not None:
+            stop.step_trace.done.set()
+            stop.step_trace.join()
     mark("learn_returned")
     if tracer is not None and tracer.active:
         tracer.stop()
+    if stop.step_trace is not None:
+        stop.step_trace.check()
 
     steps, phases = step_records(stop.metrics_path)
     warm = PPO_WARMUP_ITERATIONS
@@ -377,13 +456,17 @@ def run_ppo(cell, config, arch, seed, seconds, tracer):
         "steps": [r for s, r in sorted(steps.items()) if s > warm * steps_per_iter],
         "all_steps": steps, "steps_per_iter": steps_per_iter,
         "fresh_ratio": [r.get("mean_ratio") for r in first_steps],
-        # train(k) + rollout(k+1) windows the profiler never touched
+        # train(k) + rollout(k+1) windows the profiler never touched: in either
+        # traced cycle it starts inside the third
         "phases": [p for p in phases[1:] if tracer is None or p["step"] < (PPO_TRACE_FROM_CALL - 1) * steps_per_iter],
     }
     traced = None
     if tracer is not None and stop.traced_calls:
         traced = {"iterations": 1, "generated_tokens": config.method.num_rollouts * tp["new_tokens"],
                   "train_steps": steps_per_iter}
+    elif tracer is not None and stop.step_trace is not None:  # no rollout, no scoring in it
+        traced = {"iterations": 1, "generated_tokens": 0,
+                  "train_steps": stop.last_iteration * steps_per_iter - stop.step_trace.first_step + 1}
     return trainer, window, traced
 
 
@@ -442,37 +525,73 @@ def run_ilql(cell, config, arch, seed, seconds, tracer):
 RUNNERS = {"ppo": run_ppo, "ilql": run_ilql}
 
 
-def check_logits(trainer, reference, arch, cell, seed, last=64):
-    """(a): the program's policy forward against the plain reference on a
-    seeded sample: two rows of the cell's sequence length, one full and one
-    left-padded by a third, last `last` positions."""
-    import jax
-    import jax.numpy as jnp
-
-    seq = int(trainer.config.train.seq_length)
-    last = min(last, seq // 2)
+def logits_sample(arch, seq, seed):
+    """Two rows of `seq` tokens from the seed, one full and one left-padded
+    by a third: the sample of check (a)."""
     rng = np.random.default_rng([seed, 3])
     ids = rng.integers(2, arch["vocab_size"], size=(2, seq)).astype(np.int32)
     mask = np.ones((2, seq), np.int32)
     mask[1, : seq // 3] = 0
     ids[1, : seq // 3] = 0
-    ids, mask = jnp.asarray(ids), jnp.asarray(mask)
-    params = trainer.state.params
-    if not arch.get("scale_attn", True):
-        # GPT-Neo's attention is unscaled. A trained checkpoint keeps q.k
-        # moderate; lecun-normal weights give scores of deviation
-        # sqrt(head_dim), a near-one-hot softmax whose winner bf16 rounding
-        # flips: on the chip the bf16 rerun of the plain reference alone moved
-        # the logits by 75% at 512 positions (PERF.md, PR 22). So the sample
-        # weights shrink q by 1/sqrt(head_dim), on both sides alike; the
-        # training run itself keeps the weights as drawn.
-        shrink = (arch["d_model"] // arch["n_head"]) ** -0.5
+    return ids, mask
 
-        def tame(path, leaf):
-            keys = [str(getattr(k, "key", k)) for k in path]
-            return leaf * jnp.asarray(shrink, leaf.dtype) if keys[-2:] == ["q_proj", "kernel"] else leaf
 
-        params = jax.tree_util.tree_map_with_path(tame, params)
+def sample_weights(params, arch):
+    """The weights check (a) is read on: the run's own, but for GPT-Neo's q."""
+    import jax
+    import jax.numpy as jnp
+
+    if arch.get("scale_attn", True):
+        return params
+    # GPT-Neo's attention is unscaled. A trained checkpoint keeps q.k
+    # moderate; lecun-normal weights give scores of deviation
+    # sqrt(head_dim), a near-one-hot softmax whose winner bf16 rounding
+    # flips: on the chip the bf16 rerun of the plain reference alone moved
+    # the logits by 75% at 512 positions (PERF.md, PR 22). So the sample
+    # weights shrink q by 1/sqrt(head_dim), on both sides alike; the
+    # training run itself keeps the weights as drawn.
+    shrink = (arch["d_model"] // arch["n_head"]) ** -0.5
+
+    def tame(path, leaf):
+        keys = [str(getattr(k, "key", k)) for k in path]
+        return leaf * jnp.asarray(shrink, leaf.dtype) if keys[-2:] == ["q_proj", "kernel"] else leaf
+
+    return jax.tree_util.tree_map_with_path(tame, params)
+
+
+def reference_distances(reference, trunk, arch, cell, ids, mask, last, controls=()):
+    """The plain float32 reference's logits on the sample, a function that
+    gives any logits' relative RMS distance from them, and the distances of
+    the reference's own coarser reruns: the cell's yardstick (its
+    `tolerances.logits_yardstick`, a precision of the reference; absent: the
+    one-pass `bfloat16` rerun of PR 22) and whatever `controls` names."""
+    import jax.numpy as jnp
+
+    want = reference.forward(trunk, arch, ids, mask, last)
+    rel = lambda x: float(jnp.sqrt(jnp.mean((x - want) ** 2) / jnp.mean(want**2)))
+    rerun = lambda precision: rel(reference.forward(trunk, arch, ids, mask, last, precision=precision))
+    tol = cell["tolerances"]
+    yardstick = tol.get("logits_yardstick", "bfloat16")
+    result = {"yardstick": yardstick, "bf16_reference_rel_rms": rerun(yardstick),
+              "ref_rms": float(jnp.sqrt(jnp.mean(want**2))),
+              "tol_rel_rms": tol["logits_rel_rms"], "tol_vs_bf16_reference": tol["logits_vs_bf16_reference"]}
+    if controls:
+        result["controls"] = {name: rerun(name) for name in controls}
+    return want, rel, result
+
+
+def check_logits(trainer, reference, arch, cell, seed, last=64, controls=()):
+    """(a): the program's policy forward against the plain reference on a
+    seeded sample: two rows of the cell's sequence length, last `last`
+    positions. `controls` names precisions of the reference to put in the
+    program's place as well (benchmark/control.py)."""
+    import jax
+    import jax.numpy as jnp
+
+    seq = int(trainer.config.train.seq_length)
+    last = min(last, seq // 2)
+    ids, mask = (jnp.asarray(a) for a in logits_sample(arch, seq, seed))
+    params = sample_weights(trainer.state.params, arch)
 
     @jax.jit
     def program_logits(params, ids, mask):
@@ -480,16 +599,18 @@ def check_logits(trainer, reference, arch, cell, seed, last=64):
         return out["logits"][:, -last:].astype(jnp.float32)
 
     got = program_logits(params, ids, mask)
-    want = reference.forward(params["transformer"], arch, ids, mask, last)
-    # The yardstick for "as far as bf16 goes": the same plain reference with
-    # its matmuls at the precision the program computes in.
-    coarse = reference.forward(params["transformer"], arch, ids, mask, last, precision="bfloat16")
-    rel = lambda x: float(jnp.sqrt(jnp.mean((x - want) ** 2) / jnp.mean(want**2)))
-    return {"rel_rms": rel(got), "bf16_reference_rel_rms": rel(coarse),
-            "max_abs": float(jnp.max(jnp.abs(got - want))),
-            "ref_rms": float(jnp.sqrt(jnp.mean(want**2))), "finite": bool(jnp.isfinite(got).all()),
-            "tol_rel_rms": cell["tolerances"]["logits_rel_rms"],
-            "tol_vs_bf16_reference": cell["tolerances"]["logits_vs_bf16_reference"]}
+    want, rel, result = reference_distances(reference, params["transformer"], arch, cell, ids, mask, last, controls)
+    result.update(rel_rms=rel(got), max_abs=float(jnp.max(jnp.abs(got - want))), finite=bool(jnp.isfinite(got).all()))
+    return result
+
+
+def logits_pass(logits, rel_rms=None):
+    """(a)'s rule: finite, and no farther from the float32 reference than the
+    cell's ceiling and its multiple of the reference's own rerun at the
+    cell's yardstick."""
+    rel_rms = logits["rel_rms"] if rel_rms is None else rel_rms
+    return logits.get("finite", True) and rel_rms <= min(
+        logits["tol_rel_rms"], logits["tol_vs_bf16_reference"] * logits["bf16_reference_rel_rms"])
 
 
 def verdict(cell, trainer, window, compiles, kernels, logits):
@@ -501,8 +622,7 @@ def verdict(cell, trainer, window, compiles, kernels, logits):
     ratio_tol = cell["tolerances"].get("mean_ratio")
     ratios = window["fresh_ratio"]
     checks = {
-        "logits": logits["finite"] and logits["rel_rms"] <= min(
-            logits["tol_rel_rms"], logits["tol_vs_bf16_reference"] * logits["bf16_reference_rel_rms"]),
+        "logits": logits_pass(logits),
         "losses_finite": finite and int(trainer.skipped_steps) == 0,
         "fresh_ratio": ratio_tol is None or all(r is not None and abs(r - 1.0) <= ratio_tol for r in ratios),
         "kernels": sorted(kernels) == sorted(cell["expect_kernels"]),

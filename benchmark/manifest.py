@@ -8,6 +8,10 @@ file of its own, found by the name in BENCHMARK.json:
     layer_metrics/<metric>.json  reader kind + name patterns of one metric
     readers/<kind>.py            a reader kind (``read(ctx, spec)``)
     references/<name>.py         a plain reference (``forward(...)``)
+    counts/<name>.py             a configuration's own count of operations and
+                                 bytes (its file's ``"flops": "<name>"``);
+                                 a configuration that names none is counted
+                                 by flops.py, the dense GPT block
 
 A later PR adds a cell, a configuration or a metric by adding files and
 entries; nothing here names one of them. No JAX import in this module.
@@ -15,6 +19,7 @@ entries; nothing here names one of them. No JAX import in this module.
 
 import importlib
 import json
+import math
 import os
 import re
 
@@ -58,6 +63,15 @@ class Manifest:
         for key in ("name", "config", "traffic", "chips"):
             if spec.get(key) != entry[key]:
                 raise ManifestError(f"workloads/{name}.json: {key}={spec.get(key)!r} != BENCHMARK.json {entry[key]!r}")
+        mesh = spec.get("mesh")
+        if mesh is not None and not (
+                isinstance(mesh, list) and len(mesh) == 4 and all(isinstance(n, int) and n > 0 for n in mesh)
+                and math.prod(mesh) == entry["chips"]):
+            raise ManifestError(f"workloads/{name}.json: mesh {mesh!r} is not four sizes (dp, fsdp, tp, sp) "
+                                f"whose product is the cell's {entry['chips']} chip(s)")
+        if spec.get("traced_cycle", "whole") not in ("whole", "train_steps"):
+            raise ManifestError(f"workloads/{name}.json: traced_cycle {spec['traced_cycle']!r} is neither "
+                                "'whole' (reward call to reward call) nor 'train_steps'")
         return spec
 
     def config(self, name):
@@ -87,6 +101,16 @@ class Manifest:
 
     def reference(self, name):
         return self._module("references", name)
+
+    def counts(self, name=None):
+        """The module the readers take operations and bytes from: a
+        configuration's own (`"flops": "<name>"` in its file, counts/<name>.py)
+        or, where it names none, flops.py. Either exposes what the readers
+        call: `ppo_train_step_flops`, `ilql_train_step_flops`,
+        `layer_windows`, `flash_call`, `logprob_head_call`, `least_seconds`."""
+        if name is None:
+            return importlib.import_module(f"{os.path.basename(self.dir)}.flops")
+        return self._module("counts", name)
 
     def _module(self, package, name):
         if not NAME_RE.match(name) or not os.path.isfile(self.path(package, f"{name}.py")):

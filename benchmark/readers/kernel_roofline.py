@@ -11,7 +11,9 @@ bf16[batch*heads, positions, head_dim]; forward returns (o, lse), dkv returns
 [vocab, d]; forward returns three [rows, 1] columns, dw a vocab-sized
 result, dx the rest). Which layers are windowed cannot be read from a call,
 so a flash call's floor is the mean over the configuration's layer kinds.
-Returns nothing where the trace shows no such call.
+On several planes a row's seconds are the mean over the chips and its calls
+the sum, so the calls are divided by the planes too. Returns nothing where
+the trace shows no such call.
 """
 
 import re
@@ -58,6 +60,6 @@ def read(ctx, spec):
             if parsed:
                 floor = f.least_seconds(*f.logprob_head_call(parsed[0], **parsed[1]), peaks)[0]
         if parsed:
-            least += row["calls"] * floor
+            least += row["calls"] / red["n_devices"] * floor
             spent += row["seconds"]
     return 100.0 * least / spent if spent else None

@@ -1,7 +1,8 @@
 """Model FLOP/s utilization of the train step: the operations forward and
-backward need (benchmark/flops.py; no recomputation, no weight gradients of
-frozen blocks) over the median device time of one execution of the
-train-step program times the chip's bf16 peak."""
+backward need (the configuration's count, `ctx["flops"]`: no recomputation,
+no weight gradients of frozen blocks) for one chip's share of the global
+batch, over the median device time of one execution of the train-step
+program (over every chip's executions) times one chip's bf16 peak."""
 
 
 def read(ctx, spec):
@@ -16,4 +17,4 @@ def read(ctx, spec):
         ops = f.ppo_train_step_flops(ctx["arch"], s["batch"], s["prompt"], s["response"], s["unfrozen"])
     else:
         ops = f.ilql_train_step_flops(ctx["arch"], s["batch"], s["seq"], s["unfrozen"], s["two_qs"])
-    return 100.0 * ops / (seconds * peaks["bf16_flops_per_s"])
+    return 100.0 * (ops / ctx["chips"]) / (seconds * peaks["bf16_flops_per_s"])

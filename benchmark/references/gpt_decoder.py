@@ -39,20 +39,61 @@ def _layer_norm(x, p, eps):
     return (x - mean) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
 
 
+def _to_bf16(z):
+    return z.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+def _keep_bf16(z):
+    """The same rounding as an operation of its own, which no compiler pass
+    may drop as excess precision between two fused element-wise operations."""
+    return jax.lax.reduce_precision(z, exponent_bits=8, mantissa_bits=7)
+
+
+def _to_int8(z):
+    """Per-tensor symmetric int8, as an int8 matmul is fed: the largest
+    magnitude lands on 127."""
+    scale = jnp.maximum(jnp.max(jnp.abs(z)), 1e-30) / 127.0
+    return jnp.clip(jnp.round(z / scale), -127.0, 127.0) * scale
+
+
+def _identity(z):
+    return z
+
+
+# precision -> (r, ra, s): `r` rounds what a weight matmul reads, `ra` what
+# attention's two matmuls read, `s` what an operation hands to the next one
+# (the activations and the residual stream). Accumulation inside an operation
+# (a matmul's sums, a LayerNorm's moments, the softmax over the scores) is
+# float32 in every one of them.
+#   highest          the reference.
+#   bfloat16         one bf16 pass of the matrix unit and nothing else: the
+#                    yardstick of the 8- and 24-layer cells (PR 22).
+#   bfloat16_stream  what a configuration with `dtype: bfloat16` states:
+#                    activations and residual stream are bf16 as well. The
+#                    yardstick where depth makes the stream's rounding the
+#                    larger part (28 layers, PR 25).
+#   int8             the control of the logits check (benchmark/control.py),
+#                    never a yardstick: `bfloat16_stream` with every matmul
+#                    fed int8, scaled per tensor; `int8_dense` feeds only the
+#                    weight matmuls so and leaves attention's two in bf16.
+PRECISIONS = {
+    "highest": (_identity, _identity, _identity),
+    "bfloat16": (_to_bf16, _to_bf16, _identity),
+    "bfloat16_stream": (_keep_bf16, _keep_bf16, _keep_bf16),
+    "int8": (_to_int8, _to_int8, _keep_bf16),
+    "int8_dense": (_to_int8, _keep_bf16, _keep_bf16),
+}
+
+
 def _rounding(precision):
-    """Identity for the reference ("highest"); for "bfloat16", round a
-    matmul's operands to bf16 and keep float32 accumulation: what one bf16
-    pass of the chip's matrix unit computes, on any backend."""
-    if precision == "highest":
-        return lambda z: z
-    if precision == "bfloat16":
-        return lambda z: z.astype(jnp.bfloat16).astype(jnp.float32)
-    raise ValueError(f"precision {precision!r}")
+    if precision not in PRECISIONS:
+        raise ValueError(f"precision {precision!r}")
+    return PRECISIONS[precision]
 
 
-def _dense(x, p, r):
+def _dense(x, p, r, s):
     y = r(x) @ r(p["kernel"])
-    return y + p["bias"] if "bias" in p else y
+    return s(y + p["bias"] if "bias" in p else y)
 
 
 def _act(x, name):
@@ -98,50 +139,51 @@ def _block(x, p, attention_mask, positions, *, arch, window, precision):
     b, t, d = x.shape
     h = a["n_head"]
     hd = d // h
-    r = _rounding(precision)
+    r, ra, s = _rounding(precision)
     with jax.default_matmul_precision("highest"):
-        ln1 = _layer_norm(x, p["ln_1"], a["ln_eps"])
+        ln1 = s(_layer_norm(x, p["ln_1"], a["ln_eps"]))
         at = p["attn"]
         if "c_qkv" in at:
-            q, k, v = jnp.split(_dense(ln1, at["c_qkv"], r), 3, axis=-1)
+            q, k, v = jnp.split(_dense(ln1, at["c_qkv"], r, s), 3, axis=-1)
         else:
-            q, k, v = (_dense(ln1, at[n], r) for n in ("q_proj", "k_proj", "v_proj"))
+            q, k, v = (_dense(ln1, at[n], r, s) for n in ("q_proj", "k_proj", "v_proj"))
         q, k, v = (z.reshape(b, t, h, hd) for z in (q, k, v))
         if a["pos_type"] == "rotary":
             rd = a["rotary_dim"] or hd
-            q, k = (_rotary(z, positions, rd, a["neox_rotary"]) for z in (q, k))
-        scores = jnp.einsum("bqhd,bkhd->bhqk", r(q), r(k))
+            q, k = (s(_rotary(z, positions, rd, a["neox_rotary"])) for z in (q, k))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", ra(q), ra(k))
         if a["scale_attn"]:
             scores = scores / math.sqrt(hd)
-        probs = jax.nn.softmax(scores + _mask_bias(attention_mask, window), axis=-1)
-        attn = _dense(jnp.einsum("bhqk,bkhd->bqhd", r(probs), r(v)).reshape(b, t, d), at["c_proj"], r)
+        probs = s(jax.nn.softmax(scores + _mask_bias(attention_mask, window), axis=-1))
+        mixed = s(jnp.einsum("bhqk,bkhd->bqhd", ra(probs), ra(v))).reshape(b, t, d)
+        attn = _dense(mixed, at["c_proj"], r, s)
 
         def mlp(z):
-            return _dense(_act(_dense(z, p["mlp"]["c_fc"], r), a["activation"]), p["mlp"]["c_proj"], r)
+            return _dense(s(_act(_dense(z, p["mlp"]["c_fc"], r, s), a["activation"])), p["mlp"]["c_proj"], r, s)
 
         if a["parallel_residual"]:
-            mlp_in = _layer_norm(x, p["ln_2"], a["ln_eps"]) if a["use_parallel_ln"] else ln1
-            return x + attn + mlp(mlp_in)
-        x = x + attn
-        return x + mlp(_layer_norm(x, p["ln_2"], a["ln_eps"]))
+            mlp_in = s(_layer_norm(x, p["ln_2"], a["ln_eps"])) if a["use_parallel_ln"] else ln1
+            return s(x + attn + mlp(mlp_in))
+        x = s(x + attn)
+        return s(x + mlp(s(_layer_norm(x, p["ln_2"], a["ln_eps"]))))
 
 
-@functools.partial(jax.jit, static_argnames=("learned",))
-def _embed(t, input_ids, positions, *, learned):
+@functools.partial(jax.jit, static_argnames=("learned", "precision"))
+def _embed(t, input_ids, positions, *, learned, precision):
     x = t["wte"]["embedding"][input_ids].astype(jnp.float32)
     if learned:
         x = x + t["wpe"]["embedding"][positions].astype(jnp.float32)
-    return x
+    return _rounding(precision)[2](x)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "tied", "precision"))
 def _head(x, ln_f, head, *, eps, tied, precision):
-    r = _rounding(precision)
+    r, _, s = _rounding(precision)
     with jax.default_matmul_precision("highest"):
-        x = _layer_norm(x, _f32(ln_f), eps)
+        x = s(_layer_norm(x, _f32(ln_f), eps))
         if tied:
-            return r(x) @ r(head["embedding"].astype(jnp.float32)).T
-        return _dense(x, _f32(head), r)
+            return s(r(x) @ r(head["embedding"].astype(jnp.float32)).T)
+        return _dense(x, _f32(head), r, s)
 
 
 def arch_key(model_arch):
@@ -164,13 +206,14 @@ def forward(trunk, model_arch, input_ids, attention_mask, last, precision="highe
     """float32 logits [b, last, vocab] of the final `last` positions.
 
     `trunk` is the program's ``params["transformer"]`` subtree (any dtype).
-    `precision` is the matmul precision: "highest" is the reference;
-    "bfloat16" reruns it with every matmul operand rounded to bf16, which
-    measures how far bf16 arithmetic alone moves this architecture's logits."""
+    `precision` names a row of PRECISIONS: "highest" is the reference; the
+    others rerun it coarser, which measures how far that arithmetic alone
+    moves this architecture's logits."""
     key = arch_key(model_arch)
     positions = jnp.maximum(jnp.cumsum(attention_mask, axis=-1) - 1, 0)
     kinds = model_arch.get("attention_layers") or ["global"] * model_arch["n_layer"]
-    x = _embed(trunk, input_ids, positions, learned=model_arch.get("pos_type", "learned") == "learned")
+    x = _embed(trunk, input_ids, positions, learned=model_arch.get("pos_type", "learned") == "learned",
+               precision=precision)
     for i, kind in enumerate(kinds):
         window = int(model_arch.get("window_size", 0)) if kind == "local" else 0
         x = _block(x, trunk[f"h_{i}"], attention_mask, positions, arch=key, window=window, precision=precision)

@@ -74,9 +74,7 @@ def main(argv=None):
         print(f"benchmark: cell wants {cell['chips']} chip(s), JAX shows {device['count']}", file=sys.stderr)
         return 2
 
-    from trlx_tpu.utils.compile_cache import setup_compile_cache
-
-    cache_dir = setup_compile_cache()
+    cache_dir = harness.setup_cache()
     compiles = harness.CompileLog().install()
     out_dir = os.path.join(ROOT, "benchmark_out", cell["name"])
     shutil.rmtree(out_dir, ignore_errors=True)  # metrics.jsonl is appended to, and read below
@@ -128,7 +126,7 @@ def main(argv=None):
     }
 
     if args.trace:
-        from benchmark import flops, trace
+        from benchmark import trace
 
         reduction = None
         xplane = tracer.xplane()
@@ -147,7 +145,7 @@ def main(argv=None):
         tp = cell["traffic_params"]
         ctx = {
             "cell": cell, "arch": arch, "reduction": reduction, "traced": traced, "window": window,
-            "compile": setup, "flops": flops, "trace": trace,
+            "compile": setup, "flops": manifest.counts(config_spec.get("flops")), "trace": trace, "chips": chips,
             "peaks": None if args.rehearsal else manifest.peaks(device["kind"]),
             "shapes": {
                 "batch": config.train.batch_size, "seq": config.train.seq_length,

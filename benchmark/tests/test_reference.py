@@ -47,3 +47,31 @@ def test_reference_matches_the_program(arch):
     if arch.get("window_size"):
         wide = gpt_decoder.forward(params, dict(arch, window_size=0), ids, mask, last=24)
         assert float(jnp.max(jnp.abs(wide - want))) > 1e-2
+
+
+@pytest.mark.parametrize("arch", [GPTJ, NEOX], ids=["gptj", "neox"])  # scaled attention: GPT-Neo's is ill-conditioned on fresh weights
+def test_the_coarser_reruns_stand_in_their_order(arch):
+    """`bfloat16` rounds what the matmuls read and nothing else (the yardstick
+    of PR 22, arithmetic unchanged); `bfloat16_stream` rounds what every
+    operation hands on as well; the controls feed the matmuls int8 on top of
+    that, `int8_dense` all but attention's two. Each step down moves the
+    logits farther from the float32 reference."""
+    model = TransformerLM(LMConfig.from_dict({**arch, "dtype": "float32", "param_dtype": "float32", "attn_impl": "xla"}))
+    ids = jnp.asarray(np.random.default_rng(0).integers(2, arch["vocab_size"], size=(2, 48)), jnp.int32)
+    mask = jnp.ones((2, 48), jnp.int32)
+    params = model.init(jax.random.PRNGKey(1), ids, mask)["params"]
+    want = gpt_decoder.forward(params, arch, ids, mask, last=24)
+    far = {name: float(jnp.sqrt(jnp.mean((gpt_decoder.forward(params, arch, ids, mask, 24, precision=name) - want) ** 2)))
+           for name in gpt_decoder.PRECISIONS}
+    assert far["highest"] == 0.0
+    assert 0 < far["bfloat16"] < far["bfloat16_stream"] < min(far["int8_dense"], far["int8"]), far
+    assert far["int8"] > 3 * far["bfloat16_stream"], far
+    # one bf16 pass, written out: operands rounded, float32 sums, nothing else touched
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(8, 64)), jnp.float32)
+    p = {"kernel": jnp.asarray(np.random.default_rng(2).normal(size=(64, 32)), jnp.float32)}
+    r, _, s = gpt_decoder.PRECISIONS["bfloat16"]
+    with jax.default_matmul_precision("highest"):
+        by_hand = x.astype(jnp.bfloat16).astype(jnp.float32) @ p["kernel"].astype(jnp.bfloat16).astype(jnp.float32)
+        np.testing.assert_array_equal(np.asarray(gpt_decoder._dense(x, p, r, s)), np.asarray(by_hand))
+    with pytest.raises(ValueError, match="precision"):
+        gpt_decoder.forward(params, arch, ids, mask, 24, precision="float8")

@@ -2,7 +2,10 @@
 program's step records, the run ended on the iteration that fills --seconds."""
 
 import json
+import time
 import types
+
+import pytest
 
 from benchmark import harness
 
@@ -42,3 +45,74 @@ def test_run_ends_on_the_iteration_that_fills_the_seconds(tmp_path):
     assert [c[1:] for c in stop.calls] == [(24, 8)] * 5
     stop.on_rollout(rows)  # learn()'s closing evaluate() is not a rollout
     assert len(stop.calls) == 5
+
+
+class FakeTracer:
+    def __init__(self):
+        self.t_start = self.t_stop = None
+        self.started_at_step = None
+        self.trainer = None
+
+    def start(self):
+        self.started_at_step, self.t_start = self.trainer.iter_count, 1.0
+
+    def stop(self):
+        self.t_stop = 2.0
+
+
+def test_a_whole_traced_cycle_runs_from_reward_call_four_to_five(tmp_path):
+    tracer = FakeTracer()
+    stop = harness.IterationStop(str(tmp_path / "metrics.jsonl"), SPI, seconds=35.0, tracer=tracer)
+    stop.trainer = tracer.trainer = types.SimpleNamespace(total_steps=10**9, iter_count=0)
+    started = []
+    for i in range(1, 6):
+        stop.on_rollout([[1, 2, 3]] * 8)
+        started.append(tracer.t_start is not None)
+    assert started == [False, False, False, True, True] and tracer.t_stop is not None
+    assert stop.traced_calls == (4, 5) and stop.step_trace is None and stop.trainer.total_steps == 5 * SPI
+
+
+def test_a_train_steps_cycle_traces_the_last_steps_of_the_first_measured_iteration(tmp_path):
+    """`traced_cycle: train_steps`: at reward call 3 the run is set to end with
+    iteration 3; the profiler starts from beside `learn()` when the first of
+    that iteration's last steps (eight at most, never its first) is
+    dispatched, not inside the reward call (whose seconds the program books on
+    the phase record before), and no later call touches it."""
+    tracer = FakeTracer()
+    stop = harness.IterationStop(str(tmp_path / "metrics.jsonl"), SPI, 35.0, tracer, traced_cycle="train_steps")
+    trainer = stop.trainer = tracer.trainer = types.SimpleNamespace(total_steps=10**9, iter_count=0)
+    for i in (1, 2):
+        stop.on_rollout([[1, 2, 3]] * 8)
+        trainer.iter_count = i * SPI
+    assert stop.step_trace is None and trainer.total_steps == 10**9
+    stop.on_rollout([[1, 2, 3]] * 8)
+    assert trainer.total_steps == 3 * SPI and stop.last_iteration == 3 and stop.traced_calls is None
+    time.sleep(0.05)
+    assert tracer.t_start is None  # still at the reward call: step 8 of 12
+    trainer.iter_count = 2 * SPI + 1
+    time.sleep(0.05)
+    assert tracer.t_start is None  # an iteration of four steps: the last three
+    trainer.iter_count = 2 * SPI + 2
+    stop.step_trace.join(timeout=5)
+    assert tracer.started_at_step == 2 * SPI + 2 and not stop.step_trace.is_alive()
+    stop.step_trace.check()
+    stop.on_rollout([[1, 2, 3]] * 8)  # learn()'s closing evaluate()
+    assert len(stop.calls) == 3
+    long = harness.IterationStop(str(tmp_path / "metrics.jsonl"), 16, 35.0, FakeTracer(), traced_cycle="train_steps")
+    long.trainer = long.tracer.trainer = types.SimpleNamespace(total_steps=10**9, iter_count=0)
+    for _ in range(3):
+        long.on_rollout([[1, 2, 3]] * 8)
+    long.step_trace.done.set()
+    assert long.step_trace.first_step == 41 and long.trainer.total_steps == 48  # steps 41..48 of 33..48
+
+
+def test_a_train_steps_cycle_that_traced_nothing_fails(tmp_path):
+    tracer = FakeTracer()
+    stop = harness.IterationStop(str(tmp_path / "metrics.jsonl"), SPI, 35.0, tracer, traced_cycle="train_steps")
+    stop.trainer = tracer.trainer = types.SimpleNamespace(total_steps=10**9, iter_count=0)
+    for _ in range(3):
+        stop.on_rollout([[1, 2, 3]] * 8)
+    stop.step_trace.done.set()
+    stop.step_trace.join(timeout=5)
+    with pytest.raises(harness.BenchFailure, match="nothing was traced"):
+        stop.step_trace.check()

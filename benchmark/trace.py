@@ -29,6 +29,8 @@ from collections import defaultdict
 MIN_GAP_NS = 1000  # shorter pauses between two operations are the device's own, not the host's
 LAYOUT = re.compile(r"\{[^{}]*\}")
 OP_TEXT = re.compile(r"^%(?P<base>[^\s=]+?)(?:\.\d+)? = (?P<result>.*?) (?P<opcode>[\w\-]+)\(")
+CALLS = re.compile(r", calls=%[\w\.\-]+")  # what a fusion calls: a v5e writes a reduce-scatter so, after the operand list
+TEXT_KEPT = 1200
 
 
 def describe_op(text, mosaic_pattern):
@@ -42,6 +44,15 @@ def describe_op(text, mosaic_pattern):
     result = LAYOUT.sub("", m.group("result"))
     kind = "mosaic-kernel" if mosaic else m.group("opcode")
     return f"{m.group('base')} {kind} -> {result[:90]}", mosaic
+
+
+def kept_text(text):
+    """The part of an operation's HLO text a row keeps for the metric files'
+    patterns: its first `TEXT_KEPT` characters without layouts and, where a
+    long operand list pushed it beyond them, what the operation calls."""
+    text = LAYOUT.sub("", text)
+    called = CALLS.search(text)
+    return text[:TEXT_KEPT] + (called.group(0) if called and called.end() > TEXT_KEPT else "")
 
 
 def _events(plane, line_pattern):
@@ -90,7 +101,7 @@ def reduce_planes(planes, patterns, window_ns=None):
             row = ops.get((program, short))
             if row is None:
                 label, mosaic = describe_op(text, patterns["mosaic_op"])
-                row = ops[(program, short)] = {"seconds": 0.0, "calls": 0, "text": LAYOUT.sub("", text)[:1200], "label": label,
+                row = ops[(program, short)] = {"seconds": 0.0, "calls": 0, "text": kept_text(text), "label": label,
                                                "mosaic": mosaic, "container": False}
             row["seconds"] += (e - s) / 1e9 / n
             row["calls"] += 1
