@@ -39,3 +39,20 @@ def pytest_configure(config):
         "markers",
         "network: needs internet + HF checkpoint downloads; skipped unless TRLX_TPU_NETWORK=1 (see RUNBOOK.md)",
     )
+
+
+import pytest
+
+
+@pytest.fixture(autouse=True)
+def _no_leftover_process_mesh():
+    """`parallel/mesh.py` keeps ONE process-global mesh, and what a program
+    traces to depends on it (a mesh of more than one device keeps the full
+    cache read, ops/kv_read.py; the kernel gates close). A test that builds
+    a trainer or a mesh leaves it behind for whichever test its xdist worker
+    runs next, so each test starts and ends with none."""
+    from trlx_tpu.parallel import mesh
+
+    mesh._GLOBAL_MESH = None
+    yield
+    mesh._GLOBAL_MESH = None

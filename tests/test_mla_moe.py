@@ -1,0 +1,383 @@
+"""The latent-attention, sparse-expert block family (models/lm.py
+`LatentAttention`, models/moe.py) against the plain reference
+`benchmark/references/mla_moe_decoder.py`: seeded random weights, tiny sizes,
+float32, CPU. The letters are ISSUE 26's.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.references import mla_moe_decoder as reference
+from trlx_tpu.models import moe
+from trlx_tpu.models.heads import LMWithValueHead, extract_branch_params, trainable_mask
+from trlx_tpu.models.lm import LMConfig, TransformerLM, cache_bytes_per_token, init_cache
+from trlx_tpu.ops.generate import generate
+from trlx_tpu.ops.sampling import GenerateConfig
+
+YARN = {"type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
+        "original_max_position_embeddings": 4096, "mscale": 1, "mscale_all_dim": 1}
+# One dense layer, then two expert layers; 16 experts of which [4, 8) are held, 2 a token.
+ARCH = dict(
+    vocab_size=96, n_layer=3, n_head=4, d_model=32, d_ff=64, max_position=256, eos_token_id=0, pos_type="rotary",
+    norm="rmsnorm", mlp="gated", attention="mla", activation="silu", tie_word_embeddings=False,
+    ffn_layers=["dense", "experts", "experts"], rope_theta=50000, rope_scaling=YARN,
+    q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+    n_experts=16, experts_per_token=2, expert_d_ff=16, n_shared_experts=1, routed_scaling_factor=2.827,
+    experts_held=[4, 4],
+)
+F32 = dict(dtype="float32", param_dtype="float32", attn_impl="xla")
+B, T = 2, 24
+
+
+def _model(arch=ARCH, seed=0):
+    cfg = LMConfig.from_dict({**arch, **F32})
+    model = TransformerLM(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(seed + 1), (B, T), 2, cfg.vocab_size)
+    mask = jnp.ones((B, T), jnp.int32).at[1, :5].set(0)  # row 1 is left-padded
+    params = model.init(jax.random.PRNGKey(seed), ids, mask)["params"]
+    return cfg, model, params, ids * mask, mask
+
+
+@pytest.mark.parametrize("row_group", [8, 1], ids=["rows at once", "a row group at a time"])
+def test_logits_match_the_reference_padded_and_unpadded_rows(monkeypatch, row_group):
+    """(a) also with the unabsorbed path's rows going through in groups
+    (`MLA_ROW_GROUP`: a scoring pass over a whole rollout chunk)."""
+    import trlx_tpu.models.lm as lm
+
+    monkeypatch.setattr(lm, "MLA_ROW_GROUP", row_group)
+    monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0 if row_group == 1 else 2048)  # and the large call's sorted path
+    cfg, model, params, ids, mask = _model()
+    got = model.apply({"params": params}, ids, mask)["logits"]
+    want = reference.forward(params, ARCH, ids, mask, T)
+    assert float(jnp.abs(want).max()) > 0.5
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5, rtol=1e-4)  # no padding
+    np.testing.assert_allclose(got[1, 5:], want[1, 5:], atol=2e-5, rtol=1e-4)  # its real positions
+
+
+@pytest.mark.parametrize("small_call_slots", [2048, 0], ids=["small call: experts over tokens", "large call: sorted, grouped products"])
+def test_gradients_of_an_unfrozen_expert_block_match_the_reference(monkeypatch, small_call_slots):
+    """(b) the gradient of a mean target log-prob, every trainable leaf of the
+    top block, through either way the held experts' part is computed."""
+    monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", small_call_slots)
+    cfg, model, params, ids, mask = _model()
+    labels = jnp.roll(ids, -1, axis=1)
+
+    def target(logits):
+        lp = jnp.take_along_axis(jax.nn.log_softmax(logits[:, :-1]), labels[:, :-1, None], axis=-1)[..., 0]
+        return jnp.sum(lp * mask[:, 1:]) / jnp.sum(mask[:, 1:])
+
+    top = "h_2"
+    program = jax.grad(lambda p: target(model.apply({"params": {**params, top: p}}, ids, mask)["logits"]))(params[top])
+    plain = jax.grad(lambda p: target(reference.forward({**params, top: p}, ARCH, ids, mask, T)))(params[top])
+    train = trainable_mask({"transformer": params}, cfg, 1)["transformer"][top]
+    leaves = jax.tree_util.tree_leaves_with_path(program)
+    assert len(leaves) == 17
+    for (path, got), want, trains in zip(leaves, jax.tree_util.tree_leaves(plain), jax.tree_util.tree_leaves(train)):
+        name = jax.tree_util.keystr(path)
+        if not trains:  # the correction bias: a buffer, no gradient moves it
+            assert moe.BIAS_NAME in name and not np.any(got)
+            continue
+        assert float(jnp.abs(want).max()) > 0, name
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-4, err_msg=name)
+
+
+def _decode_through_cache(model, params, ids, mask, prefill):
+    """Prefill `prefill` tokens, then one step a token, teacher-forced:
+    ([b, T - prefill, V] logits, the expert counts of every step)."""
+    cfg = model.cfg
+    cache_mask = jnp.concatenate([mask[:, :prefill], jnp.zeros((B, T - prefill), jnp.int32)], axis=1)
+    out = model.apply({"params": params}, ids[:, :prefill], mask[:, :prefill], cache=init_cache(cfg, B, T),
+                      cache_index=0, cache_mask=cache_mask)
+    step = jax.jit(lambda cache, index, cache_mask, token: model.apply(
+        {"params": params}, token, jnp.ones((B, 1), jnp.int32), cache=cache, cache_index=index, cache_mask=cache_mask))
+    cache, logits, counts = out["cache"], [out["logits"][:, -1]], []
+    for i in range(prefill, T):
+        cache_mask = cache_mask.at[:, i].set(1)
+        out = step(cache, jnp.int32(i), cache_mask, ids[:, i:i + 1])
+        cache = out["cache"]
+        logits.append(out["logits"][:, 0])
+        counts.append(out["expert_counts"])
+    return jnp.stack(logits[:-1], axis=1), jnp.stack(logits[1:], axis=1), counts
+
+
+def test_prefill_then_absorbed_decode_matches_the_full_forward():
+    """(c) logits and the chosen experts, through the latent cache."""
+    cfg, model, params, ids, mask = _model()
+    prefill = 9
+    want = reference.forward(params, ARCH, ids, mask, T)
+    last_of_prefill, decoded, counts = _decode_through_cache(model, params, ids, mask, prefill)
+    np.testing.assert_allclose(last_of_prefill[:, 0], want[:, prefill - 1], atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(decoded, want[:, prefill:], atol=2e-5, rtol=1e-4)
+    assert [leaf.shape for leaf in jax.tree_util.tree_leaves(init_cache(cfg, B, T))] == [(B, T, 16), (B, T, 8)] * 3
+    assert cache_bytes_per_token(cfg) == (16 + 8) * 4 * 3  # float32 here
+    # The chosen experts: what each step's router picked is what the full forward's router picks there.
+    assert model.apply({"params": params}, ids, mask, stop_layer=1)["expert_counts"] is None  # the dense layer
+    per_position = _held_counts_per_position(params, ids, mask)
+    for offset, step_counts in enumerate(counts):
+        np.testing.assert_array_equal(step_counts, per_position[:, :, prefill + offset].sum(axis=1))
+
+
+def _held_counts_per_position(params, ids, mask):
+    """[expert layers, b, T, held]: whether each position chose each held
+    expert, from the reference's own router on the full forward."""
+    a = ARCH
+    first, held = a["experts_held"]
+    x = reference._embed(params["wte"]["embedding"], ids, precision="highest")
+    positions = jnp.maximum(jnp.cumsum(mask, axis=-1) - 1, 0)
+    key, out = reference.arch_key(a), []
+    for i, kind in enumerate(a["ffn_layers"]):
+        p = params[f"h_{i}"]
+        x = reference._attention(x, p["ln_1"], p["attn"], mask, positions, arch=key, precision="highest")
+        y = reference._normed(x, p["ln_2"], eps=1e-5, precision="highest")
+        if kind == "experts":
+            chosen, _ = reference._route(y, p["moe"]["router"], p["moe"]["e_score_correction_bias"], k=2,
+                                         scaling=2.827, precision="highest")
+            out.append((chosen[..., None] == first + jnp.arange(held)).sum(axis=2))
+            x = x + reference._expert_ffn(y, p["moe"], a, "highest")
+        else:
+            x = x + reference._gated_mlp(y, p["mlp"], precision="highest")
+    return np.asarray(jnp.stack(out))
+
+
+@pytest.mark.parametrize("capacity", ["small call", "worst-case buffer", "sized buffer", "overflow: dense path", "token chunks"])
+def test_the_shares_add_up(monkeypatch, capacity):
+    """(d) 16 experts as 4 shares of 4: the routed parts summed and the shared
+    expert counted once equal the uncut reference layer, on every path of
+    `held_experts_ffn`."""
+    if capacity != "small call":
+        monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)  # 96 token-slots: the sorted path of a large call
+    if capacity == "sized buffer":
+        # 96 slots, some 24 of them held in a share: a buffer of 64 rows holds them, under the `cond`
+        monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held: 64)
+    elif capacity == "token chunks":
+        monkeypatch.setattr(moe, "TOKEN_CHUNK", 12)  # 48 tokens: four passes
+    elif capacity == "overflow: dense path":
+        monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held: 8)
+    one = {**ARCH, "n_layer": 2, "ffn_layers": ["dense", "experts"], "experts_held": [0, 16]}
+    cfg, model, params, ids, mask = _model(one)
+    whole = params["h_1"]["moe"]
+    y = reference._normed(jax.random.normal(jax.random.PRNGKey(7), (B, T, 32)), params["h_1"]["ln_2"], eps=1e-5,
+                          precision="highest")
+    want = reference._expert_ffn(y, whole, one, "highest")
+
+    def share(first, count, n_shared):
+        part = {**whole, **{f"experts_{m}": whole[f"experts_{m}"][first:first + count] for m in ("gate", "up", "down")}}
+        layer = moe.ExpertLayer(cfg.replace(experts_held=(first, count), n_shared_experts=n_shared))
+        out, counts = layer.apply({"params": part}, y)
+        assert counts.shape == (count,)
+        return out, counts
+
+    shared_once = share(0, 4, 1)[0] - share(0, 4, 0)[0]
+    parts = [share(first, 4, 0) for first in (0, 4, 8, 12)]
+    assert int(sum(counts.sum() for _, counts in parts)) == B * T * 2  # every slot lands in exactly one share
+    np.testing.assert_allclose(sum(out for out, _ in parts) + shared_once, want, atol=2e-5, rtol=1e-4)
+    # a held range off by one, or the shared expert in every share, does not add up
+    off_by_one = sum(share(first, 4, 0)[0] for first in (1, 5, 9)) + share(13, 3, 0)[0] + shared_once
+    assert float(jnp.abs(off_by_one - want).max()) > 1e-2
+    counted_per_share = sum(share(first, 4, 1)[0] for first in (0, 4, 8, 12))
+    assert float(jnp.abs(counted_per_share - want).max()) > 1e-2
+
+
+GPT_DICTS = {
+    "gptj": dict(vocab_size=64, n_layer=2, n_head=2, d_model=32, d_ff=0, max_position=64, eos_token_id=0, pos_type="rotary",
+                 rotary_dim=8, parallel_residual=True, use_parallel_ln=False, fused_qkv=False, qkv_bias=False,
+                 out_bias=False, scale_attn=True, tie_word_embeddings=False, activation="gelu_new", ln_eps=1e-5,
+                 extra={"lm_head_bias": True}),
+    "gptneo": dict(vocab_size=64, n_layer=2, n_head=2, d_model=32, max_position=64, eos_token_id=0, pos_type="learned",
+                   fused_qkv=False, qkv_bias=False, scale_attn=False, attention_layers=["global", "local"], window_size=8),
+}
+
+
+@pytest.mark.parametrize("family", sorted(GPT_DICTS))
+def test_gpt_dicts_still_load(family):
+    """(e)"""
+    cfg = LMConfig.from_dict(GPT_DICTS[family])
+    assert (cfg.norm, cfg.mlp, cfg.attention, cfg.ffn_layers) == ("layernorm", "dense", "mha", ())
+
+
+def test_benchmark_configurations_load():
+    """(e) every `model_arch` and `rehearsal_arch` the benchmark has."""
+    import glob
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = sorted(glob.glob(os.path.join(root, "benchmark", "configs", "*.json")))
+    assert len(files) >= 4
+    for path in files:
+        spec = json.load(open(path))
+        for key in ("model_arch", "rehearsal_arch"):
+            LMConfig.from_dict(spec[key])
+
+
+@pytest.mark.parametrize("bad, message", [
+    ({"sliding_window": 128}, "unknown architecture key"),
+    ({"num_experts": 8}, "unknown architecture key"),
+    ({"attention": "gqa"}, "unknown attention kind"),
+    ({"norm": "layer_norm"}, "unknown norm kind"),
+    ({"activation": "swiglu"}, "unknown activation"),
+    ({"ffn_layers": ["dense", "experts"]}, "ffn_layers"),
+    ({"experts_held": [12, 8]}, "experts_held"),
+    ({"kv_cache_quant": True}, "kv_cache_quant"),
+    ({"rope_scaling": {"type": "linear", "factor": 2}}, "rope_scaling type"),
+])
+def test_from_dict_raises_on_what_the_program_lacks(bad, message):
+    """(e) a `model_arch` that names a mechanism the program lacks fails at construction."""
+    with pytest.raises(ValueError, match=message):
+        LMConfig.from_dict({**ARCH, **bad})
+
+
+def test_mask_and_branch_on_the_mixed_stack():
+    """(f)"""
+    cfg = LMConfig.from_dict({**ARCH, **F32})
+    model = LMWithValueHead(cfg, branch_layer=2)
+    ids = jnp.ones((1, 4), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids, jnp.ones_like(ids))["params"]
+    mask = trainable_mask(params, cfg, 1)
+    flat = {jax.tree_util.keystr(p): v for p, v in jax.tree_util.tree_leaves_with_path(mask)}
+    trains = lambda fragment: {v for k, v in flat.items() if fragment in k}
+    assert trains("'h_0'") == {False} and trains("'h_1'") == {False}  # a dense block and an expert block, frozen
+    assert trains("'h_2']['attn'") == {True} and trains("'h_2']['moe']['experts_") == {True}
+    assert trains("'h_2']['moe']['router'") == {True} and trains("'h_2']['moe']['shared'") == {True}
+    assert trains(moe.BIAS_NAME) == {False}  # a buffer in every block, unfrozen or not
+    assert trains("'wte'") == trains("'ln_f'") == trains("'lm_head'") == trains("'v_head'") == {True}
+    everything = trainable_mask(params, cfg, 0)
+    assert {v for k, v in jax.tree_util.tree_leaves_with_path(everything) if moe.BIAS_NAME not in jax.tree_util.keystr(k)} == {True}
+    branch = extract_branch_params(params, cfg, 2)["transformer"]
+    assert sorted(branch) == ["h_2", "lm_head", "ln_f"]
+    assert branch["h_2"]["moe"]["experts_gate"].shape == (4, 32, 16)
+    assert branch["h_2"]["moe"]["experts_gate"] is not params["transformer"]["h_2"]["moe"]["experts_gate"]
+    # the replay of the branch from the hidden state entering it gives the policy's own logits
+    out = model.apply({"params": params}, ids, jnp.ones_like(ids), collect_branch_hidden=True)
+    replay = model.apply({"params": {"transformer": branch}}, out["branch_hidden"], jnp.ones_like(ids),
+                         method="forward_branch")
+    np.testing.assert_allclose(replay, out["logits"], atol=1e-5)
+
+
+def test_counters_by_hand():
+    """(g) three tokens, two choices each, experts [4, 8) of 16 held."""
+    ids = jnp.array([[4, 9], [5, 4], [15, 0]], jnp.int32)
+    counts = moe.held_counts(ids, 4, 4)
+    np.testing.assert_array_equal(counts, [2, 1, 0, 0])
+    share, load = moe.expert_load_stats(jnp.stack([counts, jnp.array([1, 1, 1, 1])]), n_tokens=3, k=2)
+    assert float(share) == pytest.approx((3 + 4) / (2 * 3 * 2))  # held slots over slots, both layers
+    assert float(load) == pytest.approx(2 / (7 / 8))  # the fullest expert over the mean of the held ones
+    # the router by hand: sigmoid scores, the bias moves the choice and not the weight
+    x = jnp.eye(2, dtype=jnp.float32)
+    router = jnp.array([[2.0, 0.0, -2.0], [0.0, 0.0, 0.0]])
+    chosen, weights = moe.route(x, router, jnp.array([0.0, 0.0, 5.0]), 2, 3.0)
+    # expert 2 wins by its bias alone; token 1's three scores tie, the lower index takes the second place
+    np.testing.assert_array_equal(np.sort(np.asarray(chosen), axis=-1), [[0, 2], [0, 2]])
+    sig = 1 / (1 + np.exp(-np.array([2.0, -2.0])))
+    order = np.argsort(np.asarray(chosen[0]))
+    np.testing.assert_allclose(np.asarray(weights[0])[order], sig / sig.sum() * 3.0, rtol=1e-6)
+    np.testing.assert_allclose(weights[1], [1.5, 1.5], rtol=1e-6)
+    assert 32 * 8 <= moe.SMALL_CALL_SLOTS < 4096 * 8  # a decode step is a small call, a train step is not
+    assert moe.slot_capacity(4096, 8, 8) == 8192  # two slots a token; the worst case is 32768
+    assert moe.slot_capacity(48, 2, 4) == 96  # the worst case where that is no larger
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_a_decode_step_runs_every_held_expert_whatever_the_routing():
+    """A small call's work does not follow the routing: no branch on the
+    counts (a rollout's seconds followed the experts a seed made popular,
+    PERF.md PR 26), and an expert no token chose adds exactly nothing."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (6, 8), jnp.float32)
+    gate, up = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 8, 16), jnp.float32)
+    down = jax.random.normal(jax.random.PRNGKey(2), (4, 16, 8), jnp.float32)
+    ids = jnp.array([[4, 9], [9, 4], [0, 1], [6, 4], [4, 6], [1, 0]], jnp.int32)  # of the held [4, 8): 5 and 7 untouched
+    weights = jax.random.uniform(jax.random.PRNGKey(3), (6, 2), jnp.float32)
+    call = lambda x: moe.held_experts_ffn(x, ids, weights, 4, gate, up, down, jax.nn.silu)
+    assert "cond" not in set(_primitives(jax.make_jaxpr(call)(x).jaxpr))
+    y, counts = call(x)
+    np.testing.assert_array_equal(counts, [4, 0, 2, 0])
+    want = sum(jnp.where((ids == e)[..., None], weights[..., None], 0.0).sum(1)
+               * ((jax.nn.silu(x @ gate[e - 4]) * (x @ up[e - 4])) @ down[e - 4]) for e in (4, 6))
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+
+
+def test_generate_carries_experts_touched_and_stays_one_loop():
+    """(g) `rollout/experts_touched`'s source, against the counts of single steps."""
+    cfg, model, params, ids, mask = _model()
+    gcfg = GenerateConfig(max_new_tokens=6, do_sample=False, eos_token_id=None)
+    fn = lambda p, i, m: generate(p, i, m, jax.random.PRNGKey(2), model=model, gcfg=gcfg,
+                                  step_stats_fn=lambda tok, s: {"token": tok.astype(jnp.float32)})
+    tokens, out_mask, stats = jax.jit(fn)({"params": params}, ids[:, :9], mask[:, :9])
+    per_position = _held_counts_per_position(params, tokens, out_mask)
+    touched = [(per_position[:, :, 9 + step].sum(axis=1) > 0).sum(axis=-1).mean() for step in range(6)]
+    assert float(stats["experts_touched_per_step"]) == pytest.approx(float(np.mean(touched)), abs=1e-6)
+    jaxpr = jax.make_jaxpr(fn)({"params": params}, ids[:, :9], mask[:, :9]).jaxpr
+    assert [e.primitive.name for e in jaxpr.eqns].count("while") == 1  # ops/generate.py stays one loop
+
+
+def test_partition_rules_name_every_new_parameter():
+    """No parameter of the new block family falls to the replicating fallback,
+    and on an fsdp x tp mesh none of the large ones stays whole."""
+    from jax.sharding import PartitionSpec
+
+    from trlx_tpu.parallel import make_mesh
+    from trlx_tpu.parallel.sharding import lm_partition_rules, match_partition_rules, sanitize_specs
+
+    cfg, model, params, ids, mask = _model()
+    rules = lm_partition_rules()
+    assert rules[-1][0] == ".*"
+    specs = match_partition_rules(rules[:-1] + [(".*", "fallback")], {"transformer": params})
+    assert "fallback" not in jax.tree_util.tree_leaves(specs, is_leaf=lambda s: isinstance(s, (str, PartitionSpec)))
+    mesh = make_mesh((2, 2, 2, 1))
+    import warnings
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # sanitize_specs warns where it falls back to replication
+        specs = sanitize_specs(mesh, params, match_partition_rules(rules, params))
+    for path, spec in jax.tree_util.tree_leaves_with_path(specs, is_leaf=lambda s: isinstance(s, PartitionSpec)):
+        name = jax.tree_util.keystr(path)
+        if any(part in name for part in ("kernel", "experts_", "router", "embedding")):
+            assert any(axis is not None for axis in spec), name
+
+
+def test_ppo_two_iterations_on_the_normal_path(tmp_path):
+    """(h) `trlx_tpu.train` -> orchestrator -> ops/generate.py -> make_experience -> learn():
+    the fresh-step PPO ratio compares the absorbed decode path (the sampler's
+    own log-probs, fused rollout stats) with the unabsorbed train forward."""
+    import json
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "examples"))
+    import trlx_tpu
+    from randomwalks import base_config
+
+    config = base_config("ppo", ARCH["vocab_size"], 16)
+    config.model.model_arch = dict(ARCH)
+    config.model.num_layers_unfrozen = 1
+    config.train.batch_size, config.train.total_steps, config.train.epochs = 8, 4, 4  # dp 8 over the test devices
+    config.train.eval_interval, config.train.log_interval = 100, 1
+    config.train.checkpoint_dir = str(tmp_path)
+    config.method.num_rollouts = config.method.chunk_size = 8
+    config.method.ppo_epochs = 2
+    config.method.gen_kwargs = {"prompt_length": 4, "max_new_tokens": 12, "min_new_tokens": 12, "do_sample": True,
+                                "top_k": 0, "top_p": 1.0}
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(2, ARCH["vocab_size"], size=int(n)).tolist() for n in rng.integers(2, 5, size=8)]
+    trainer = trlx_tpu.train(reward_fn=lambda rows: [float(np.mean(r)) / 96 for r in rows], prompts=prompts,
+                             eval_prompts=[[2, 3]], config=config)
+    assert trainer.fused_rollout and trainer.model.cfg.attention == "mla"
+    records = [json.loads(line) for line in open(os.path.join(str(tmp_path), "metrics.jsonl"))]
+    steps = {r["step"]: r for r in records if "step_time" in r}
+    assert sorted(steps) == [1, 2, 3, 4]
+    for first in (1, 3):  # the first step of each iteration: the policy has not moved since it sampled
+        assert abs(steps[first]["mean_ratio"] - 1.0) < 1e-3, steps[first]["mean_ratio"]
+    for r in steps.values():
+        assert np.isfinite(r["loss"]) if "loss" in r else True
+        assert 0.0 < r["moe/held_slot_share"] < 1.0 and r["moe/max_expert_load"] >= 1.0
+    phases = [r for r in records if "time/window_wall_s" in r]
+    assert phases and all(0.0 <= p["rollout/experts_touched"] <= 4.0 for p in phases)
+    assert all(p["rollout/cache_bytes_per_token"] == (16 + 8) * 4 * 3 for p in phases)

@@ -103,6 +103,7 @@ class LMWithValueHead(nn.Module):
             "hidden": out["hidden"],
             "branch_hidden": out["branch_hidden"],
             "cache": out["cache"],
+            "expert_counts": out["expert_counts"],
             "logprobs": out["logprobs"],
             "lse": out["lse"],
             "entropy": out["entropy"],
@@ -255,14 +256,19 @@ def trainable_mask(params: dict, cfg: LMConfig, num_layers_unfrozen: int) -> dic
     (reference: trlx/model/accelerate_base_model.py:49-64): with
     num_layers_unfrozen = k > 0 the bottom N-k blocks are frozen. Embeddings
     and ln_f stay trainable, exactly like the reference (which freezes only
-    entries of `hidden_layers`). k <= 0 → everything trains.
+    entries of `hidden_layers`). k <= 0 → everything trains. Blocks are
+    found by name (`h_<i>`), whatever kind each is; an expert layer's router
+    correction bias is a buffer and never trains (models/moe.py).
     """
-    if num_layers_unfrozen <= 0:
-        return jax.tree_util.tree_map(lambda _: True, params)
-    frozen_blocks = {f"h_{i}" for i in range(cfg.n_layer - num_layers_unfrozen)}
+    from trlx_tpu.models.moe import BIAS_NAME
+
+    k = num_layers_unfrozen if num_layers_unfrozen > 0 else cfg.n_layer
+    frozen_blocks = {f"h_{i}" for i in range(cfg.n_layer - k)}
 
     def mask(path, _leaf):
         keys = [str(getattr(k, "key", k)) for k in path]
+        if keys[-1] == BIAS_NAME:
+            return False
         if "transformer" in keys and any(fb in keys for fb in frozen_blocks):
             return False
         return True

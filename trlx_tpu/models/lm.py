@@ -32,9 +32,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from trlx_tpu.observability import numerics as obs_numerics
-from trlx_tpu.ops.kv_read import attend, ranged_read
+from trlx_tpu.ops.kv_read import attend, attend_latent, attend_latent_range, ranged_read
 
 Dtype = Any
+
+ACTIVATIONS = {
+    "gelu_new": lambda h: nn.gelu(h, approximate=True),
+    "gelu": lambda h: nn.gelu(h, approximate=False),
+    "relu": nn.relu,
+    "silu": nn.silu,
+}
+# Keys of a `model_arch` dict that are not LMConfig's: the trainers read them
+# from the dict (`eos_token_id`: trainer/ppo.py, trainer/ilql.py).
+ARCH_KEYS_READ_ELSEWHERE = frozenset({"eos_token_id"})
 
 
 @dataclass(frozen=True)
@@ -61,6 +71,13 @@ class LMConfig:
     attention_layers: Tuple[str, ...] = ()
     window_size: int = 0
     tie_word_embeddings: bool = True
+    # Deviation the token embedding is DRAWN with (initialisation only; a
+    # checkpoint brings its own). 0 -> flax's default, 1/sqrt(d_model): rows so
+    # small that after the first attention every position's hidden state is
+    # the attention's running mean, not its token. That is harmless to a GPT
+    # block and ruinous to a router over random weights, which then sends every
+    # token to the same few experts (PERF.md, PR 26); 1.0 is torch's default.
+    embed_init_std: float = 0.0
     activation: str = "gelu_new"
     ln_eps: float = 1e-5
     embd_pdrop: float = 0.0  # dropout unused in RL fine-tuning; kept for parity
@@ -102,6 +119,41 @@ class LMConfig:
     # results survive, attention scores recompute) — more memory, less
     # backward recompute. Only read when remat=True.
     remat_policy: str = "full"
+    # What a block is made of, by kind. The defaults are the GPT block every
+    # field above describes; a kind the program lacks is an error here, at
+    # construction, never a quietly different model.
+    norm: str = "layernorm"  # "layernorm" | "rmsnorm" (scale only, no mean)
+    mlp: str = "dense"  # "dense": c_proj(act(c_fc x)) | "gated": down(act(gate x) * up x), no biases
+    attention: str = "mha"  # "mha": per-head K and V in the cache | "mla": latent attention (below)
+    # Per-layer feed-forward kind ("dense" | "experts"); empty -> all dense.
+    # An "experts" layer is trlx_tpu/models/moe.py: sigmoid router over all
+    # n_experts, experts_per_token chosen, a shared expert, and the routed
+    # experts THIS program holds.
+    ffn_layers: Tuple[str, ...] = ()
+    # Rotary base and its scaling ({"type": "yarn", "factor", "beta_fast",
+    # "beta_slow", "original_max_position_embeddings", "mscale",
+    # "mscale_all_dim"}; None = none). Scaling is built for "mla" only.
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[Dict[str, Any]] = None
+    # Latent attention (MLA): queries through a q_lora_rank bottleneck, keys
+    # and values through ONE kv_lora_rank latent a token plus ONE
+    # qk_rope_head_dim rotary key shared by all heads; those two are the cache.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Expert layers: the router is n_experts wide whatever is held here.
+    # experts_held = (first, count): the routed experts [first, first+count)
+    # this program holds, one chip's share of an expert-parallel deployment;
+    # what the absent experts would add is left out (no stand-in). Empty ->
+    # all of them.
+    n_experts: int = 0
+    experts_per_token: int = 0
+    expert_d_ff: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: Tuple[int, ...] = ()
     extra: Dict[str, Any] = field(default_factory=dict)
 
     def __post_init__(self):
@@ -111,6 +163,40 @@ class LMConfig:
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r} (expected 'full' or 'dots')"
             )
+        for name, kinds in (("norm", ("layernorm", "rmsnorm")), ("mlp", ("dense", "gated")),
+                            ("attention", ("mha", "mla"))):
+            if getattr(self, name) not in kinds:
+                raise ValueError(f"unknown {name} kind {getattr(self, name)!r} (expected one of {kinds})")
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"unknown activation {self.activation!r} (expected one of {sorted(ACTIVATIONS)})")
+        if self.ffn_layers and (len(self.ffn_layers) != self.n_layer
+                                or set(self.ffn_layers) - {"dense", "experts"}):
+            raise ValueError(f"ffn_layers must name 'dense' or 'experts' for each of {self.n_layer} layers: {self.ffn_layers!r}")
+        if self.attention == "mla":
+            sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+            if min(sizes) <= 0 or self.pos_type != "rotary" or self.qk_rope_head_dim % 2:
+                raise ValueError(f"attention 'mla' needs pos_type 'rotary' and its five sizes, got {sizes}")
+            if self.kv_cache_quant or self.attention_layers or self.n_soft_tokens:
+                raise ValueError("attention 'mla' is not built with kv_cache_quant, windowed layers or soft prompts")
+        elif self.rope_scaling:
+            raise ValueError("rope_scaling is built for attention 'mla' only")
+        if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
+            raise ValueError(f"rope_scaling type {self.rope_scaling.get('type')!r} is not built (only 'yarn')")
+        if "experts" in self.ffn_layers:
+            first, count = self.held_experts
+            if (self.mlp != "gated" or self.expert_d_ff <= 0 or not 0 < self.experts_per_token <= self.n_experts
+                    or first < 0 or count <= 0 or first + count > self.n_experts):
+                raise ValueError(
+                    f"expert layers need mlp 'gated', expert_d_ff, 0 < experts_per_token <= n_experts "
+                    f"and experts_held inside [0, n_experts): {self.experts_held!r} of {self.n_experts}")
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        if not self.experts_held:
+            return 0, self.n_experts
+        first, count = self.experts_held
+        return int(first), int(count)
 
     @property
     def head_dim(self) -> int:
@@ -133,9 +219,16 @@ class LMConfig:
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]):
+        """An unknown key is an error: a `model_arch` that names a mechanism
+        the program lacks must not build some other model and run. Keys other
+        modules read from the same dict themselves pass (`ARCH_KEYS_READ_ELSEWHERE`)."""
+        unknown = sorted(set(d) - set(cls.__dataclass_fields__) - ARCH_KEYS_READ_ELSEWHERE)
+        if unknown:
+            raise ValueError(f"LMConfig: unknown architecture key(s) {unknown}")
         known = {k: v for k, v in d.items() if k in cls.__dataclass_fields__}
-        if "attention_layers" in known:
-            known["attention_layers"] = tuple(known["attention_layers"])
+        for key in ("attention_layers", "ffn_layers", "experts_held"):
+            if key in known:
+                known[key] = tuple(known[key])
         return cls(**known)
 
 
@@ -144,9 +237,32 @@ class LMConfig:
 # ---------------------------------------------------------------------------
 
 
-def rotary_sincos(positions: jnp.ndarray, rotary_dim: int, base: float = 10000.0):
+def yarn_inv_freq(rotary_dim: int, base: float, scaling: Dict[str, Any]) -> np.ndarray:
+    """YaRN-corrected rotary frequencies: dimensions that turn more than
+    `beta_fast` times over the original context keep their frequency, those
+    that turn less than `beta_slow` times are interpolated by `factor`, a
+    linear ramp between."""
+    exponent = np.arange(0, rotary_dim, 2) / rotary_dim
+    extrapolated, interpolated = 1.0 / base**exponent, 1.0 / (scaling["factor"] * base**exponent)
+    original = scaling["original_max_position_embeddings"]
+
+    def correction_dim(turns):
+        return rotary_dim * np.log(original / (turns * 2 * np.pi)) / (2 * np.log(base))
+
+    low = max(int(np.floor(correction_dim(scaling["beta_fast"]))), 0)
+    high = min(int(np.ceil(correction_dim(scaling["beta_slow"]))), rotary_dim - 1)
+    ramp = np.clip((np.arange(rotary_dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return interpolated * ramp + extrapolated * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def rotary_sincos(positions: jnp.ndarray, rotary_dim: int, base: float = 10000.0, inv_freq=None):
     """sin/cos tables for rotary positions. positions: [b, t] → [b, t, rd/2]."""
-    inv_freq = 1.0 / (base ** (np.arange(0, rotary_dim, 2) / rotary_dim))
+    if inv_freq is None:
+        inv_freq = 1.0 / (base ** (np.arange(0, rotary_dim, 2) / rotary_dim))
     freqs = positions[..., None].astype(jnp.float32) * inv_freq[None, None, :]
     return jnp.sin(freqs), jnp.cos(freqs)
 
@@ -334,6 +450,20 @@ def quantize_weights(params, probe=None):
     return walk(params)
 
 
+def write_cache(buf, upd, cache_index):
+    """Write `upd` [b, q, ...] into a fixed cache buffer [b, T, ...] at
+    `cache_index`. Scalar offset: one dynamic_update_slice covers the batch.
+    Vector offset [b] (slot decode): every row writes at its own slot length
+    — a vmap'd per-row update (lowers to scatter)."""
+    upd = upd.astype(buf.dtype)
+    zeros = (0,) * (buf.ndim - 2)
+    if not isinstance(cache_index, (int, np.integer)) and jnp.ndim(cache_index) == 1:
+        return jax.vmap(
+            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i,) + zeros)
+        )(buf, upd, cache_index)
+    return jax.lax.dynamic_update_slice(buf, upd, (0, cache_index) + zeros)
+
+
 class Attention(nn.Module):
     """Multi-head causal attention with functional KV cache.
 
@@ -373,7 +503,7 @@ class Attention(nn.Module):
 
         if cfg.pos_type == "rotary":
             rd = cfg.rotary_dim or hd
-            sin, cos = rotary_sincos(positions, rd)
+            sin, cos = rotary_sincos(positions, rd, cfg.rope_theta)
             neox = cfg.extra.get("neox_rotary", False)
             q = apply_rotary(q, sin, cos, rd, neox)
             k = apply_rotary(k, sin, cos, rd, neox)
@@ -445,17 +575,7 @@ class Attention(nn.Module):
             else:
 
                 def cache_write(buf, upd):
-                    # Scalar offset: one dynamic_update_slice covers the batch.
-                    # Vector offset [b] (slot decode): every row writes at its own
-                    # slot length — a vmap'd per-row update (lowers to scatter).
-                    upd = upd.astype(buf.dtype)
-                    if vector_index:
-                        zeros = (0,) * (buf.ndim - 2)
-                        return jax.vmap(
-                            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i,) + zeros)
-                        )(buf, upd, cache_index)
-                    start = (0, cache_index) + (0,) * (buf.ndim - 2)
-                    return jax.lax.dynamic_update_slice(buf, upd, start)
+                    return write_cache(buf, upd, cache_index)
 
                 def gather_virt(buf):
                     # Legacy per-slot buffers ARE the virtual cache.
@@ -571,26 +691,36 @@ class Attention(nn.Module):
         return out, new_cache
 
 
-class MLP(nn.Module):
-    cfg: LMConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        h = QDense(cfg.ff_dim, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="c_fc")(x)
-        if cfg.activation == "gelu_new":
-            h = nn.gelu(h, approximate=True)
-        elif cfg.activation == "gelu":
-            h = nn.gelu(h, approximate=False)
-        elif cfg.activation == "relu":
-            h = nn.relu(h)
-        else:
-            raise ValueError(f"unknown activation {cfg.activation}")
-        return QDense(cfg.d_model, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="c_proj")(h)
+# Rows whose per-head queries, keys and values the unabsorbed path holds at
+# once (a train batch is at most this; a scoring pass over 32 rows is four groups).
+MLA_ROW_GROUP = 8
 
 
-class Block(nn.Module):
-    """One transformer block; sequential (gpt2) or parallel (gptj/neox) residual."""
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA), with its two read paths.
+
+        c_q = RMSNorm(x W_qa);  q = c_q W_qb -> n_head x (nope | rope)
+        [c_kv | k_r] = x W_kva; c_kv = RMSNorm(c_kv); k_rope = RoPE(k_r), one for all heads
+        [k_nope | v] = c_kv W_kvb -> n_head x (nope | v)
+        scores = (q_nope . k_nope + RoPE(q_rope) . k_rope) * s, causal, softmax in float32
+
+    The cache is `(c_kv [b, T, kv_lora_rank], k_rope [b, T, qk_rope_head_dim])`:
+    the latent after its norm and the rotated shared key, nothing per head.
+
+    *Unabsorbed* (training, scoring, a prefill at write offset 0): k_nope and
+    v are built from the block's own latents and attention runs over the
+    block, through the flash kernel where the trunk chose it. The kernel
+    takes one head width, so q and k (nope + rope wide) and v are padded with
+    zeros to the next multiple of 128: zeros add nothing to q.k, the padded
+    columns of the output are dropped, and the scale stays `s`; at 192/128
+    that is the 256-wide shape the kernel is tuned for, in place of a second
+    kernel of two widths.
+    *Absorbed* (every other read of the cache: the decode step): with W_UK,
+    W_UV the two halves of W_kvb, viewed not copied,
+    q_lat = q_nope W_UK^T, scores = q_lat . c_kv + q_rope . k_rope,
+    o = (softmax . c_kv) W_UV: the same function, contracted in another
+    order, over the cache's 576 numbers a token (ops/kv_read.py).
+    """
 
     cfg: LMConfig
 
@@ -598,18 +728,159 @@ class Block(nn.Module):
     def __call__(self, x, attn_bias, positions, cache=None, cache_index=None,
                  flash_mask=None, window=0, use_ring=False, block_tables=None):
         cfg = self.cfg
-        ln = lambda name: nn.LayerNorm(epsilon=cfg.ln_eps, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name=name)
-        attn = Attention(cfg, name="attn")
+        if use_ring or block_tables is not None or window:
+            raise NotImplementedError("attention 'mla' is not built for the sp ring, paged caches or windows")
+        dtype = cfg.compute_dtype
+        b, q_len, _ = x.shape
+        h, dn, dr, dv, rank = cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+        dense = lambda feats, name: QDense(feats, dtype=dtype, param_dtype=cfg.params_dtype, use_bias=False, name=name)
+        norm = lambda name: nn.RMSNorm(epsilon=cfg.ln_eps, dtype=dtype, param_dtype=cfg.params_dtype, name=name)
+
+        c_q = norm("q_a_norm")(dense(cfg.q_lora_rank, "q_a_proj")(x))
+        kv_a = dense(rank + dr, "kv_a_proj")(x)
+        c_kv = norm("kv_a_norm")(kv_a[..., :rank])
+        scaling = cfg.rope_scaling
+        sin, cos = rotary_sincos(positions, dr, cfg.rope_theta,
+                                 inv_freq=yarn_inv_freq(dr, cfg.rope_theta, scaling) if scaling else None)
+        softmax_scale = (dn + dr) ** -0.5
+        if scaling:
+            # YaRN's attention temperature: the published code multiplies the
+            # softmax scale by mscale(factor, mscale_all_dim)^2 and the sin/cos
+            # tables by mscale(factor, mscale) / mscale(factor, mscale_all_dim).
+            all_dim = yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0) or 0)
+            softmax_scale *= all_dim**2
+            table = yarn_mscale(scaling["factor"], scaling.get("mscale", 1)) / all_dim
+            if table != 1.0:
+                sin, cos = sin * table, cos * table
+        # Interleaved pairs (0,1), (2,3), ... as the published code rotates them.
+        k_rope = apply_rotary(kv_a[:, :, None, rank:], sin, cos, dr)[:, :, 0]
+        params = lambda feats, name, fan_in: HeadParams(
+            feats, param_dtype=cfg.params_dtype, use_bias=False, name=name)(fan_in)[0].astype(dtype)
+        w_qb = params(h * (dn + dr), "q_b_proj", cfg.q_lora_rank).reshape(cfg.q_lora_rank, h, dn + dr)
+        w_kvb = params(h * (dn + dv), "kv_b_proj", rank).reshape(rank, h, dn + dv)
+
+        def queries(c_q, sin, cos):
+            q = jnp.einsum("btc,chn->bthn", c_q, w_qb)
+            return q[..., :dn], apply_rotary(q[..., dn:], sin, cos, dr)
+
+        new_cache = None
+        if cache is not None:
+            new_cache = (write_cache(cache[0], c_kv, cache_index), write_cache(cache[1], k_rope, cache_index))
+        # Before the first decode step every cache slot beyond the block is
+        # invalid, so a prefill at (static) offset 0 attends over its own block.
+        at_zero = isinstance(cache_index, (int, np.integer)) and int(cache_index) == 0
+        if cache is None or at_zero:
+
+            def unabsorbed(c_q, c_kv, k_rope, sin, cos, mask_or_bias):
+                """[rows, q_len, h * dv] from the rows' latents: everything per
+                head (q, k, v, the padded copies) lives inside."""
+                rows = c_q.shape[0]
+                q = jnp.concatenate(queries(c_q, sin, cos), axis=-1)
+                kv = jnp.einsum("btc,chn->bthn", c_kv, w_kvb)
+                k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope[:, :, None, :], (rows, q_len, h, dr))], axis=-1)
+                v = kv[..., dn:]
+                if flash_mask is None:
+                    return attend(q, k, v, mask_or_bias[..., :q_len], softmax_scale, dtype).reshape(rows, q_len, h * dv)
+                from trlx_tpu.ops.flash_attention import flash_attention, pick_block
+
+                wide = -(-(dn + dr) // 128) * 128
+                pad = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, wide - a.shape[-1])))
+                blk = pick_block(q_len)
+                out = flash_attention(
+                    pad(q), pad(k), pad(v), mask_or_bias, scale=softmax_scale, causal=True,
+                    block_q=blk, block_k=blk,
+                )
+                return out[..., :dv].astype(dtype).reshape(rows, q_len, h * dv)
+
+            with jax.named_scope("mla_unabsorbed"):
+                operands = (c_q, c_kv, k_rope, sin, cos, flash_mask if flash_mask is not None else attn_bias)
+                group = MLA_ROW_GROUP
+                if b > group and b % group == 0:
+                    # A scoring pass over a whole rollout chunk: per-head keys
+                    # and values of 32 rows x 1024 tokens x 64 heads are 1 GB a
+                    # tensor beside a resident train state. The rows are
+                    # independent, so they go through a group at a time.
+                    split = lambda a: a.reshape((b // group, group) + a.shape[1:])
+                    out = jax.lax.map(lambda args: unabsorbed(*args), tuple(split(a) for a in operands))
+                    out = out.reshape(b, q_len, h * dv)
+                else:
+                    out = unabsorbed(*operands)
+        else:
+            with jax.named_scope("mla_absorbed"):
+                q_nope, q_rope = queries(c_q, sin, cos)
+                q_lat = jnp.einsum("bqhn,chn->bqhc", q_nope, w_kvb[..., :dn], preferred_element_type=jnp.float32)
+                read = ranged_read(int(cache[0].shape[1]), q_len, cache_index,
+                                   attend_range=attend_latent_range, slot_major=False)
+                if read is not None:
+                    o_lat = read((q_lat, q_rope), new_cache, attn_bias, softmax_scale, dtype)
+                else:
+                    o_lat = attend_latent(q_lat, q_rope, *new_cache, attn_bias, softmax_scale, dtype)
+                out = jnp.einsum("bqhc,chv->bqhv", o_lat, w_kvb[..., dn:]).reshape(b, q_len, h * dv)
+        return dense(cfg.d_model, "c_proj")(out), new_cache
+
+
+class MLP(nn.Module):
+    """The feed-forward of a "dense" layer: c_proj(act(c_fc x)), or with
+    `cfg.mlp == "gated"` down(act(gate x) * up x) without biases. `width`
+    overrides the hidden width (an expert layer's shared expert)."""
+
+    cfg: LMConfig
+    width: int = 0
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        act = ACTIVATIONS[cfg.activation]
+        width = self.width or cfg.ff_dim
+        dense = lambda feats, name, bias: QDense(
+            feats, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, use_bias=bias, name=name)
+        if cfg.mlp == "gated":
+            return dense(cfg.d_model, "down_proj", False)(
+                act(dense(width, "gate_proj", False)(x)) * dense(width, "up_proj", False)(x))
+        return dense(cfg.d_model, "c_proj", True)(act(dense(width, "c_fc", True)(x)))
+
+
+def make_norm(cfg: LMConfig, name: str):
+    kind = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+    return kind(epsilon=cfg.ln_eps, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name=name)
+
+
+class Block(nn.Module):
+    """One transformer block; sequential (gpt2) or parallel (gptj/neox)
+    residual. `ffn` is this layer's feed-forward kind ("dense" | "experts").
+    Returns (x, cache, expert_counts): the tokens each held expert took in
+    this block, None for a dense one."""
+
+    cfg: LMConfig
+    ffn: str = "dense"
+
+    @nn.compact
+    def __call__(self, x, attn_bias, positions, cache=None, cache_index=None,
+                 flash_mask=None, window=0, use_ring=False, block_tables=None):
+        cfg = self.cfg
+        ln = lambda name: make_norm(cfg, name)
+        attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
+        counts = None
+
+        def feed_forward(h):
+            nonlocal counts
+            if self.ffn == "experts":
+                from trlx_tpu.models.moe import ExpertLayer
+
+                h, counts = ExpertLayer(cfg, name="moe")(h)
+                return h
+            return MLP(cfg, name="mlp")(h)
+
         if cfg.parallel_residual:
             h = ln("ln_1")(x)
             attn_out, new_cache = attn(h, attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
             mlp_in = ln("ln_2")(x) if cfg.use_parallel_ln else h
-            x = x + attn_out + MLP(cfg, name="mlp")(mlp_in)
+            x = x + attn_out + feed_forward(mlp_in)
         else:
             attn_out, new_cache = attn(ln("ln_1")(x), attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
             x = x + attn_out
-            x = x + MLP(cfg, name="mlp")(ln("ln_2")(x))
-        return x, new_cache
+            x = x + feed_forward(ln("ln_2")(x))
+        return x, new_cache, counts
 
 
 def make_attn_bias(
@@ -713,6 +984,8 @@ class TransformerLM(nn.Module):
           auto|force|off) and by the exact materializing log_softmax chain
           otherwise. ``labels_mask`` zeros masked rows on either path.
           ``logits`` is None in this mode: not existing is the point.
+        - ``expert_counts`` [expert layers run, experts held] int32: how many
+          tokens each held expert took in this call; None without expert layers.
         - `segment_ids` [b, q_len] (packed train batches; full-sequence
           passes only) makes attention block-diagonal per packed segment —
           the einsum bias path is forced, since the flash/ring kernels'
@@ -724,8 +997,9 @@ class TransformerLM(nn.Module):
             "segment packing is a train-batch construct; decode caches are unpacked"
         )
 
+        drawn = {"embedding_init": nn.initializers.normal(cfg.embed_init_std)} if cfg.embed_init_std else {}
         wte = nn.Embed(
-            cfg.vocab_size, cfg.d_model, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="wte"
+            cfg.vocab_size, cfg.d_model, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="wte", **drawn
         )
         if inputs_embeds is None:
             if cfg.onehot_embed and cache is None:
@@ -856,10 +1130,11 @@ class TransformerLM(nn.Module):
 
         branch_hidden = None
         new_cache = [] if cache is not None else None
+        expert_counts = []
         for i in range(cfg.n_layer):
             # All blocks are *defined* every call so the param structure is
             # identical regardless of start/stop — only [start, stop) execute.
-            block = block_cls(cfg, name=f"h_{i}")
+            block = block_cls(cfg, cfg.ffn_layers[i] if cfg.ffn_layers else "dense", name=f"h_{i}")
             if i < start_layer or i >= stop_layer:
                 continue
             if collect_hidden_at is not None and i == collect_hidden_at:
@@ -868,15 +1143,17 @@ class TransformerLM(nn.Module):
             is_local = bool(cfg.attention_layers) and cfg.attention_layers[i] == "local"
             layer_bias = local_bias if is_local else attn_bias
             layer_window = cfg.window_size if is_local else 0
-            x, layer_new_cache = block(
+            x, layer_new_cache, layer_counts = block(
                 x, layer_bias, position_ids, layer_cache, cache_index,
                 flash_mask, layer_window, use_ring, block_tables,
             )
             x = obs_numerics.probe_tap(f"block_{i}", x)
             if cache is not None:
                 new_cache.append(layer_new_cache)
+            if layer_counts is not None:
+                expert_counts.append(layer_counts)
 
-        x = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="ln_f")(x)
+        x = make_norm(cfg, "ln_f")(x)
         x = obs_numerics.probe_tap("ln_f", x)
         if collect_hidden_at is not None and collect_hidden_at == cfg.n_layer:
             branch_hidden = x
@@ -943,6 +1220,9 @@ class TransformerLM(nn.Module):
             "hidden": x,
             "branch_hidden": branch_hidden,
             "cache": tuple(new_cache) if new_cache is not None else None,
+            # [expert layers run, experts held]: tokens each held expert took
+            # in this call (models/moe.py); None for a model without them.
+            "expert_counts": jnp.stack(expert_counts) if expert_counts else None,
             "logprobs": logprobs,
             "lse": lse,
             "entropy": entropy,
@@ -972,8 +1252,16 @@ def quantize_kv(x: jnp.ndarray, probe=None, probe_class: str = "kv"):
 
 
 def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
-    """Allocate an empty KV cache pytree: per-layer (k, v) [b, T, n_head, hd],
-    or (k_i8, v_i8, k_scale, v_scale) with kv_cache_quant."""
+    """Allocate an empty KV cache pytree, as the attention kind keeps it:
+    "mha": per-layer (k, v) [b, T, n_head, hd], or (k_i8, v_i8, k_scale,
+    v_scale) with kv_cache_quant; "mla": per-layer (c_kv [b, T, kv_lora_rank],
+    k_rope [b, T, qk_rope_head_dim]), shared by all heads."""
+    if cfg.attention == "mla":
+        dtype = dtype or cfg.compute_dtype
+        return tuple(
+            (jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype), jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype))
+            for _ in range(cfg.n_layer)
+        )
     shape = (batch, max_len, cfg.n_head, cfg.head_dim)
     if cfg.kv_cache_quant:
         assert dtype is None, "kv_cache_quant caches are int8; dtype not honored"
@@ -1000,6 +1288,8 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
     reserved by the engine pool) absorbs dead rows' clamped writes — masked
     reads weight stale content by an exact softmax zero, which only stays
     zero if the content (values AND scales) is finite."""
+    if cfg.attention != "mha":
+        raise NotImplementedError(f"the paged pool is not built for attention {cfg.attention!r}")
     shape = (n_blocks, block_size, cfg.n_head, cfg.head_dim)
     if cfg.kv_cache_quant:
         assert dtype is None, "kv_cache_quant caches are int8; dtype not honored"
@@ -1016,3 +1306,25 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
     dtype = dtype or cfg.compute_dtype
     zero = lambda: jnp.zeros(shape, dtype=dtype)
     return tuple((zero(), zero()) for _ in range(cfg.n_layer))
+
+
+def cache_partition_spec(cfg: LMConfig, leaf_ndim: int):
+    """PartitionSpec of one leaf of `init_cache`'s pytree: batch over the data
+    axes, heads over tp. An "mla" cache has no head axis: one latent a token
+    serves every head, so it is whole on every tp shard."""
+    from jax.sharding import PartitionSpec
+
+    from trlx_tpu.parallel.mesh import AXIS_TP, DATA_AXES
+
+    if cfg.attention == "mla":
+        return PartitionSpec(DATA_AXES, None, None)
+    # 4-D leaves are k/v ([b, T, h, d]); 3-D leaves are the int8 cache's
+    # per-slot scales ([b, T, h]).
+    return PartitionSpec(DATA_AXES, None, AXIS_TP, None) if leaf_ndim == 4 else PartitionSpec(DATA_AXES, None, AXIS_TP)
+
+
+def cache_bytes_per_token(cfg: LMConfig) -> int:
+    """Bytes the cache holds a token, all layers: the counter
+    `rollout/cache_bytes_per_token`, from `init_cache`'s own shapes."""
+    leaves = jax.tree_util.tree_leaves(jax.eval_shape(lambda: init_cache(cfg, 1, 1)))
+    return int(sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves))

@@ -1,0 +1,198 @@
+"""The sparse expert feed-forward layer, as ONE chip of an expert-parallel
+deployment holds it.
+
+    sigma = sigmoid(x . W_g)                       over ALL n_experts, product in float32
+    chosen = the experts_per_token largest of sigma + b     (b: the correction bias, a buffer)
+    w_e = sigma_e / sum_chosen sigma * routed_scaling_factor
+    y = sum over (chosen and held) of w_e Expert_e(x) + Shared(x)
+
+The layer is told which routed experts it holds (`LMConfig.experts_held`,
+`[first, first + count)`), routes over all of them at the published width,
+and computes its own experts' part of the result. What the absent experts
+would add is left out; nothing here stands in for the other chips or their
+tokens, and on one chip the layer runs without its exchange. No token is
+dropped: there is no capacity limit. The balance of a trained router is the
+bias `b`, which no gradient moves (`models/heads.py` `trainable_mask`); no
+balance term joins a loss.
+
+How the held experts' part is computed (`held_experts_ffn`), by the size of
+the call, both exact for any routing:
+
+A small call (a decode step: at most `SMALL_CALL_SLOTS` token-slots) runs EVERY
+held expert over every token of the call, weighted by zero where a token did
+not choose it: at 32 tokens the arithmetic is nothing and the step is the held
+experts' weights read once, whatever the routing (0.98 ms a layer of 8 held
+experts on the chip, 88% of the weight-read floor; PERF.md, PR 26). It does
+not skip an expert that no token of the call chose: on one chip's 32 tokens
+about half of the held experts go untouched a step and a `lax.cond` an expert
+would save their weights' read, but a rollout's seconds then follow the
+routing (which experts the seed made popular, how peaked the policy has
+become: 1% of an iteration between seeds), and in the deployment this layer
+is a share of, a chip's experts take the tokens of all the chips that share
+the layer and none goes untouched.
+
+A large call goes through in chunks of at most `TOKEN_CHUNK` tokens; a chunk's
+(token, choice) slots that chose a held expert are sorted by expert into a
+buffer of `slot_capacity(...)` rows and go through three grouped products
+(`jax.lax.ragged_dot`, one group an expert). The buffer holds two slots a
+token: room for two of the held experts to be chosen by EVERY token, a
+quarter of the worst case at 8 held and 8 a token (0.9 GB a buffer at 8,192
+tokens of width 7,168). A router over random weights is far from even, and a
+first buffer of four times the EVEN share overflowed in a quarter of the
+train steps. A chunk whose held slots exceed the buffer all the same takes
+`dense_held_ffn`: every held expert over every token, in token chunks
+recomputed in the backward pass.
+"""
+
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from trlx_tpu.models.lm import ACTIVATIONS, MLP, LMConfig
+
+BIAS_NAME = "e_score_correction_bias"
+SMALL_CALL_SLOTS = 2048  # token-slots (tokens x experts_per_token) up to which a call is "small": a decode step
+SLOTS_PER_TOKEN = 2  # the sorted buffer's rows a token, in a large call
+TOKEN_CHUNK = 4096  # tokens a pass: bounds the buffers of a scoring pass over a whole rollout chunk
+
+
+def route(x, router, bias, k: int, scaling: float):
+    """(ids [n, k] int32, weights [n, k] float32) of tokens `x` [n, d]: the
+    router's product and everything after it in float32, as published."""
+    with jax.named_scope("moe_router"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST))
+        _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        chosen = jnp.take_along_axis(scores, ids, axis=-1)
+        weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scaling
+    return ids.astype(jnp.int32), weights
+
+
+def slot_capacity(n_tokens: int, k: int, held: int) -> int:
+    """Rows of the sorted slot buffer for a pass over `n_tokens` tokens."""
+    return min(n_tokens * min(k, held), SLOTS_PER_TOKEN * n_tokens)
+
+
+def held_counts(ids, first: int, held: int):
+    """[held] int32: the tokens each held expert was chosen by."""
+    local = ids.reshape(-1) - first
+    return jnp.sum(local[:, None] == jnp.arange(held, dtype=ids.dtype)[None, :], axis=0, dtype=jnp.int32)
+
+
+DENSE_CHUNK = 1024
+
+
+def experts_over_tokens(x, ids, weights, first: int, gate, up, down, act):
+    """sum over the held experts of w_e Expert_e(x), each expert over EVERY
+    token with weight zero where the token did not choose it."""
+    total = jnp.zeros_like(x)
+    for e in range(gate.shape[0]):
+        w_e = jnp.sum(jnp.where(ids == first + e, weights, 0.0), axis=-1)[:, None].astype(x.dtype)
+        total = total + jnp.dot(act(jnp.dot(x, gate[e])) * jnp.dot(x, up[e]), down[e]) * w_e
+    return total
+
+
+def dense_held_ffn(x, ids, weights, first: int, gate, up, down, act):
+    """`experts_over_tokens` in chunks of `DENSE_CHUNK` tokens, each
+    recomputed in the backward pass, so that what a differentiated `cond`
+    keeps of this branch (and fills with zeros when the other one runs) is
+    its inputs and not one [tokens, d] product an expert."""
+    n, d = x.shape
+    chunk = min(DENSE_CHUNK, n)
+    pad = -n % chunk
+    split = lambda a: jnp.pad(a, ((0, pad), (0, 0))).reshape((n + pad) // chunk, chunk, a.shape[-1])
+    one_chunk = jax.checkpoint(lambda _, args: (None, experts_over_tokens(*args, first, gate, up, down, act)))
+    return jax.lax.scan(one_chunk, None, (split(x), split(ids), split(weights)))[1].reshape(n + pad, d)[:n]
+
+
+def sorted_held_ffn(x, ids, weights, counts, first: int, capacity: int, gate, up, down, act):
+    """The held experts' part through grouped products: slots sorted by
+    expert, held ones first; rows past the held slots carry weight zero."""
+    held, k = gate.shape[0], ids.shape[-1]
+    local = ids.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)[:capacity]
+    token = order // k
+    live = key[order] < held
+    w = jnp.where(live, weights.reshape(-1)[order], 0.0).astype(x.dtype)
+
+    # A grouped product writes only the rows of its groups: what it leaves in
+    # the others is not defined (on the chip: whatever was there), in its
+    # result and in the lhs-gradient its transpose computes alike; 0 x NaN is
+    # NaN, and the first train steps on the chip were all non-finite. So every
+    # operand a grouped product reads, and the last result, pass the mask:
+    # its forward keeps dead rows out of a sum or a scatter, its backward
+    # zeroes what the transposed product left in them.
+    alive = lambda a: jnp.where(live[:, None], a, 0)
+    xs = alive(x[token])
+    with jax.named_scope("moe_grouped_ffn"):
+        hidden = alive(act(jax.lax.ragged_dot(xs, gate, counts)) * jax.lax.ragged_dot(xs, up, counts))
+        ys = alive(jax.lax.ragged_dot(hidden, down, counts))
+    return jnp.zeros_like(x).at[token].add(ys * w[:, None])
+
+
+def held_experts_ffn(x, ids, weights, first: int, gate, up, down, act):
+    """(y [n, d], counts [held]): sum over the held experts a token chose of
+    w_e Expert_e(x); `gate`/`up` [held, d, f], `down` [held, f, d]."""
+    n, k, held = x.shape[0], ids.shape[-1], gate.shape[0]
+    if n * k <= SMALL_CALL_SLOTS:
+        return experts_over_tokens(x, ids, weights, first, gate, up, down, act), held_counts(ids, first, held)
+    if n > TOKEN_CHUNK and n % TOKEN_CHUNK == 0:
+        split = lambda a: a.reshape((n // TOKEN_CHUNK, TOKEN_CHUNK) + a.shape[1:])
+        y, counts = jax.lax.map(lambda args: held_experts_ffn(*args, first, gate, up, down, act),
+                                (split(x), split(ids), split(weights)))
+        return y.reshape(x.shape), jnp.sum(counts, axis=0)
+    counts = held_counts(ids, first, held)
+    capacity = slot_capacity(n, k, held)
+    sorted_ffn = lambda: sorted_held_ffn(x, ids, weights, counts, first, capacity, gate, up, down, act)
+    if capacity >= n * min(k, held):
+        return sorted_ffn(), counts
+    y = jax.lax.cond(jnp.sum(counts) <= capacity, sorted_ffn,
+                     jax.checkpoint(lambda: dense_held_ffn(x, ids, weights, first, gate, up, down, act)))
+    return y, counts
+
+
+class ExpertLayer(nn.Module):
+    """The feed-forward of an "experts" layer. Returns (y, counts [held])."""
+
+    cfg: LMConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        dtype, d, f = cfg.compute_dtype, cfg.d_model, cfg.expert_d_ff
+        first, held = cfg.held_experts
+        b, t, _ = x.shape
+        router = self.param("router", nn.initializers.lecun_normal(), (d, cfg.n_experts), cfg.params_dtype)
+        # Drawn from the seed, small: a trained router's bias is not zero, and
+        # a zero one would let a program that forgot it pass every comparison;
+        # but the bias exists to even the load out, and a random one of the
+        # scores' own size (deviation 0.1 against the sigmoid's 0.2) skews it:
+        # the chip read a fullest expert at 6-15 times the mean (PERF.md, PR 26).
+        bias = self.param(BIAS_NAME, nn.initializers.normal(stddev=0.01), (cfg.n_experts,), jnp.float32)
+        stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        gate = self.param("experts_gate", stacked, (held, d, f), cfg.params_dtype).astype(dtype)
+        up = self.param("experts_up", stacked, (held, d, f), cfg.params_dtype).astype(dtype)
+        down = self.param("experts_down", stacked, (held, f, d), cfg.params_dtype).astype(dtype)
+
+        flat = x.reshape(b * t, d).astype(dtype)
+        ids, weights = route(flat, router, bias, cfg.experts_per_token, cfg.routed_scaling_factor)
+        with jax.named_scope("moe_experts"):
+            y, counts = held_experts_ffn(flat, ids, weights, first, gate, up, down, ACTIVATIONS[cfg.activation])
+        y = y.reshape(b, t, d)
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe_shared"):
+                y = y + MLP(cfg, width=cfg.n_shared_experts * f, name="shared")(x)
+        return y, counts
+
+
+def expert_load_stats(counts, n_tokens: int, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(held_slot_share, max_expert_load) of `counts` [expert layers, held]
+    from a call of `n_tokens` tokens: the token-slots that chose a held expert
+    over all token-slots (held / n_experts where routing is even), and the
+    fullest held expert's tokens over the mean of the held experts'."""
+    counts = counts.astype(jnp.float32)
+    share = jnp.sum(counts) / (counts.shape[0] * n_tokens * k)
+    return share, jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9)

@@ -90,16 +90,19 @@ def _smem_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _compiler_params(interpret, semantics=("parallel", "parallel", "arbitrary")):
+def _compiler_params(interpret, semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=None):
     """Mark the (bh, outer-block) grid dims parallel so Mosaic pipelines
     across grid steps instead of serializing them; only the innermost dim
     (the online-softmax / accumulation walk) is order-dependent. Without
     this the kernel is grid-step-latency-bound: at [8,1024,16,256] the
     forward drops from ~18ms to ~3ms on a v5e. The decode and fused-logprob
-    kernels pass their own two-dim semantics."""
+    kernels pass their own two-dim semantics. `vmem_limit_bytes` lifts the
+    compiler's scoped-VMEM limit (16 MiB) for a kernel whose blocks need more;
+    None leaves the default."""
     if interpret:
         return {}
-    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=semantics)}
+    extra = {} if vmem_limit_bytes is None else {"vmem_limit_bytes": int(vmem_limit_bytes)}
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=semantics, **extra)}
 
 
 # ---------------------------------------------------------------------------

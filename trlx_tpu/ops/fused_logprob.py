@@ -77,11 +77,21 @@ def pick_v_block(V: int, block_v: int = BLOCK_V) -> int:
     return V if V <= block_v else block_v
 
 
-# N-blocks are independent; the V walk is the online accumulation order and
-# must stay sequential.
-_compiler_params = functools.partial(
-    _grid_compiler_params, semantics=("parallel", "arbitrary")
-)
+# The model axis the tile widths above were set for: every block carries the
+# whole [D], and at D = 4096 the three kernels fit the 16 MiB of scoped VMEM
+# the compiler allows by default. A wider model keeps the tiles and asks for
+# the limit in proportion (D = 7168: the forward's double-buffered [D, 512]
+# weight block alone is 14.7 MB, the compiler refused the kernel at 18.25 MB;
+# a v5e has 128 MiB of VMEM).
+BLOCK_D = 4096
+DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+
+
+def _compiler_params(interpret, D=BLOCK_D):
+    """N-blocks are independent; the V walk is the online accumulation order
+    and must stay sequential."""
+    limit = None if D <= BLOCK_D else DEFAULT_SCOPED_VMEM * -(-D // BLOCK_D)
+    return _grid_compiler_params(interpret, semantics=("parallel", "arbitrary"), vmem_limit_bytes=limit)
 
 
 def _tile_scores(x_ref, w_ref, b_ref, j, *, V, bv, tied):
@@ -316,7 +326,7 @@ def _fwd_call(x, w, bias, labels, tied, bn, bv, interpret):
         out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.float32)] * 3,
         scratch_shapes=[_scratch((bn, 128)) for _ in range(4)],
         interpret=interpret,
-        **_compiler_params(interpret),
+        **_compiler_params(interpret, D),
     )(*operands)
     return tuple(out)
 
@@ -341,7 +351,7 @@ def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv, inte
         out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
         scratch_shapes=[_scratch((bn, D))],
         interpret=interpret,
-        **_compiler_params(interpret),
+        **_compiler_params(interpret, D),
     )(*operands)
 
     # dW (+db): V-blocks parallel, N innermost accumulating [D, bv] / [bv, D].
@@ -364,7 +374,7 @@ def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv, inte
             out_shape=[dw_shape, jax.ShapeDtypeStruct(bias.shape, bias.dtype)],
             scratch_shapes=[_scratch(acc_shape), _scratch((1, bv))],
             interpret=interpret,
-            **_compiler_params(interpret),
+            **_compiler_params(interpret, D),
         )(*operands)
         dw, db = out
     else:
@@ -377,7 +387,7 @@ def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv, inte
             out_shape=dw_shape,
             scratch_shapes=[_scratch(acc_shape)],
             interpret=interpret,
-            **_compiler_params(interpret),
+            **_compiler_params(interpret, D),
         )(*operands)
         db = None
     return dx, dw, db

@@ -22,7 +22,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.models.lm import init_cache
+from trlx_tpu.models.lm import cache_partition_spec, init_cache
 from trlx_tpu.ops.sampling import GenerateConfig, process_logits_default
 
 
@@ -55,7 +55,9 @@ def generate(
     the in-loop state to per-step values — scalars (e.g. Q(s, tok), V(s), the
     sampled token's raw logprob) or vectors (e.g. the branch-point hidden
     state) — collected into [b, max_new_tokens, ...] buffers and returned as
-    a third output. This makes decode-side rollout statistics FREE: no extra
+    a third output (a model with expert layers adds the scalar
+    ``experts_touched_per_step`` to it: held experts with at least one token,
+    mean over decode steps and expert layers). This makes decode-side rollout statistics FREE: no extra
     forward pass after generation (validity = the returned mask's response
     region). Scalar stats are stored fp32; vector stats keep their dtype.
     When set, the return is (tokens, mask, stats).
@@ -102,18 +104,14 @@ def generate(
 
     mesh = mesh_mod.peek_mesh()
     if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec as PSpec
+        from jax.sharding import NamedSharding
 
         data = int(mesh.shape[mesh_mod.AXIS_DP]) * int(mesh.shape[mesh_mod.AXIS_FSDP])
         tp = int(mesh.shape[mesh_mod.AXIS_TP])
         if B % data == 0 and cfg.n_head % tp == 0:
-            # 4-D leaves are k/v ([b, T, h, d]); 3-D leaves are the int8
-            # cache's per-slot scales ([b, T, h]).
-            spec4 = NamedSharding(mesh, PSpec(mesh_mod.DATA_AXES, None, mesh_mod.AXIS_TP, None))
-            spec3 = NamedSharding(mesh, PSpec(mesh_mod.DATA_AXES, None, mesh_mod.AXIS_TP))
             cache = jax.tree_util.tree_map(
                 lambda x: jax.lax.with_sharding_constraint(
-                    x, spec4 if x.ndim == 4 else spec3
+                    x, NamedSharding(mesh, cache_partition_spec(cfg, x.ndim))
                 ),
                 cache,
             )
@@ -155,6 +153,12 @@ def generate(
         "last_hidden": out["hidden"][:, -1],
         "carry": {k: last_pos(out[k]) for k in carry_keys},
     }
+    counts_experts = out.get("expert_counts") is not None
+    if counts_experts:
+        # Held experts a decode step gave at least one token, mean over the
+        # expert layers, summed over the steps: carried here and read once a
+        # rollout (`rollout/experts_touched`), no sync a step.
+        state["experts_touched"] = jnp.zeros((), jnp.float32)
     if step_stats_fn is not None:
         # eval_shape: discover the stat names/shapes without executing the fn.
         probe = jax.eval_shape(
@@ -219,6 +223,9 @@ def generate(
             "last_hidden": step_out["hidden"][:, 0],
             "carry": {k: last_pos(step_out[k]) for k in carry_keys},
         }
+        if counts_experts:
+            touched = jnp.mean(jnp.sum(step_out["expert_counts"] > 0, axis=-1).astype(jnp.float32))
+            new_s["experts_touched"] = s["experts_touched"] + touched
         if step_stats_fn is not None:
             # Stats read the PRE-step state: Q/V at the position that
             # produced `tok` (state-before-token, matching rollout scoring).
@@ -244,6 +251,8 @@ def generate(
 
     with jax.named_scope("decode_loop"):
         final = jax.lax.while_loop(cond, body, state)
+    if step_stats_fn is not None and counts_experts:
+        final["stats"]["experts_touched_per_step"] = final["experts_touched"] / jnp.maximum(final["step"], 1)
     if step_stats_fn is not None and prefill_collect:
         return final["tokens"], final["mask"], final["stats"], prefill_extras
     if step_stats_fn is not None:

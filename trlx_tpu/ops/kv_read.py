@@ -89,6 +89,26 @@ def attend_range(q, cache, attn_bias, lo: int, hi: int, scale, dtype):
     return attend(q, k, v, jax.lax.slice_in_dim(attn_bias, lo, hi, axis=3), scale, dtype)
 
 
+def attend_latent(q_lat, q_rope, c_kv, k_rope, attn_bias, scale, dtype):
+    """Latent attention's absorbed read (models/lm.py `LatentAttention`):
+    `q_lat` [b, q, h, rank] is the query with the key up-projection absorbed,
+    `q_rope` [b, q, h, r] its rotary part; the keys are the cache itself,
+    `c_kv` [b, kv, rank] and `k_rope` [b, kv, r], one row a token for all
+    heads. Returns softmax . c_kv, [b, q, h, rank]: the value up-projection is
+    the caller's."""
+    scores = jnp.einsum("bqhc,bkc->bhqk", q_lat.astype(jnp.float32), c_kv.astype(jnp.float32))
+    scores = scores + jnp.einsum("bqhr,bkr->bhqk", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
+    probs = jax.nn.softmax(scores * scale + attn_bias, axis=-1).astype(dtype)
+    return jnp.einsum("bhqk,bkc->bqhc", probs, c_kv.astype(dtype))
+
+
+def attend_latent_range(q, cache, attn_bias, lo: int, hi: int, scale, dtype):
+    """`attend_latent` over cache slots `[lo, hi)`; `q` is `(q_lat, q_rope)`,
+    `cache` is `(c_kv, k_rope)`, each whole."""
+    cut = lambda a: jax.lax.slice_in_dim(a, lo, hi, axis=1)
+    return attend_latent(*q, cut(cache[0]), cut(cache[1]), jax.lax.slice_in_dim(attn_bias, lo, hi, axis=3), scale, dtype)
+
+
 def _partitioned() -> bool:
     """A mesh of more than one device keeps the full read: the layout request
     in `ranged_read` is a custom call, and GSPMD replicates what it cannot
@@ -97,26 +117,32 @@ def _partitioned() -> bool:
     return mesh is not None and mesh.size > 1
 
 
-def _read_branch(q, cache, attn_bias, *, lo, hi, scale, dtype):
+def _read_branch(q, cache, attn_bias, *, lo, hi, scale, dtype, attend_range, slot_major):
     # A conditional's operands take the default, batch-major layout, and with
     # them the cache the loop carries. Outside a conditional XLA keeps it
     # slot-major ([T, h, b, d]: a slice of slots is one contiguous block, a
     # [b, d] tile is full), and batch-major the GPT-Neo decode loop took 5.71 s
-    # against 3.56 s (PERF.md, PR 24): ask for slot-major.
-    cache = tuple(
-        with_layout_constraint(a, Layout(major_to_minor=(1, 2, 0, 3)[: a.ndim])) for a in cache
-    )
+    # against 3.56 s (PERF.md, PR 24): ask for slot-major. A latent cache
+    # ([b, T, rank], no head axis) keeps the default: a row's slice of slots
+    # is one contiguous block as it is.
+    if slot_major:
+        cache = tuple(
+            with_layout_constraint(a, Layout(major_to_minor=(1, 2, 0, 3)[: a.ndim])) for a in cache
+        )
     return attend_range(q, cache, attn_bias, lo, hi, scale, dtype)
 
 
-def ranged_read(cache_len: int, q_len: int, cache_index, window: int = 0) -> Optional[Callable]:
+def ranged_read(cache_len: int, q_len: int, cache_index, window: int = 0, *,
+                attend_range: Callable = attend_range, slot_major: bool = True) -> Optional[Callable]:
     """The read for one decode step on a fixed cache with ONE traced write
     offset for the whole batch, as `read(q, cache, attn_bias, scale, dtype)`:
     a `lax.switch` over `kv_read_ranges`, around the read only. Its operands
     are read-only and its result is `[b, 1, h, d]`, so no branch returns (or
     copies) a cache. None where the caller keeps its full read: more than one
     query token, a per-row (vector) offset, a cache of a single branch, or a
-    mesh of more than one device.
+    mesh of more than one device. `attend_range` is the read of one branch
+    (`attend_range`: per-head K and V; `attend_latent_range`: the latent
+    cache), `slot_major` whether the branch asks for the slot-major layout.
     """
     scalar = not isinstance(cache_index, (int, np.integer)) and jnp.ndim(cache_index) == 0
     ranges = kv_read_ranges(cache_len, window)
@@ -126,7 +152,9 @@ def ranged_read(cache_len: int, q_len: int, cache_index, window: int = 0) -> Opt
 
     def read(q, cache, attn_bias, scale, dtype):
         branches = [
-            partial(_read_branch, lo=lo, hi=hi, scale=scale, dtype=dtype) for lo, hi in ranges
+            partial(_read_branch, lo=lo, hi=hi, scale=scale, dtype=dtype,
+                    attend_range=attend_range, slot_major=slot_major)
+            for lo, hi in ranges
         ]
         return jax.lax.switch(cache_index // bucket, branches, q, tuple(cache), attn_bias)
 

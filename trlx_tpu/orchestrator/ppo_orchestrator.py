@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
+from trlx_tpu.models.lm import cache_bytes_per_token
 from trlx_tpu.ops.kv_read import kv_keys_read
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
 from trlx_tpu.pipeline.overlap import ScoreWorker
@@ -265,6 +266,7 @@ class PPOOrchestrator(Orchestrator):
             for kind in lm_cfg.attention_layers or ("global",) * lm_cfg.n_layer
         ]
         kv_keys = np.zeros(2, dtype=np.int64)
+        experts_touched = []  # a model with expert layers: one reading a chunk
         # Final-chunk stats for logging; placeholders are never logged (the
         # aborted path returns before the tracker call).
         last_scores = np.zeros((1,), dtype=np.float32)
@@ -409,6 +411,10 @@ class PPOOrchestrator(Orchestrator):
                 ))
                 episode_steps.extend(int(v) for v in ds["episode_steps"])
                 step_budget = ds["decode_step_budget"]
+                if gen_aux is not None and "experts_touched_per_step" in gen_aux[0]:
+                    # The loop's own counter (ops/generate.py), read after
+                    # the rollout's grids: the program has finished.
+                    experts_touched.append(float(gen_aux[0]["experts_touched_per_step"]))
 
                 if getattr(rl, "has_reward_model", False):
                     # On-device learned RM: the whole scoring pass (policy
@@ -519,7 +525,10 @@ class PPOOrchestrator(Orchestrator):
             "exp_per_sec": stats["exp_per_sec"],
             "rollout/decode_steps": stats["exp_decode_dispatches"],
             "rollout/kv_read_share": float(kv_keys[0] / kv_keys[1]) if kv_keys[1] else 1.0,
+            "rollout/cache_bytes_per_token": float(cache_bytes_per_token(lm_cfg)),
         }
+        if experts_touched:
+            rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
         rl.tracker.log(stats, step=iter_count)
 
     def _make_experience_engine(

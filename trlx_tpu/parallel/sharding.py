@@ -43,6 +43,20 @@ def lm_partition_rules() -> List[Tuple[str, P]]:
         # attention output [d, d_model] row-parallel
         (r"attn/c_proj/kernel$", P(AXIS_TP, AXIS_FSDP)),
         (r"attn/c_proj/bias$", P(None)),
+        # latent attention (MLA): the two bottleneck projections keep their
+        # narrow output whole, the per-head up-projections are column-parallel
+        (r"attn/(q_a_proj|kv_a_proj)/kernel$", P(AXIS_FSDP, None)),
+        (r"attn/(q_b_proj|kv_b_proj)/kernel$", P(AXIS_FSDP, AXIS_TP)),
+        # gated feed-forward (a dense layer's, an expert layer's shared expert)
+        (r"(mlp|shared)/(gate_proj|up_proj)/kernel$", P(AXIS_FSDP, AXIS_TP)),
+        (r"(mlp|shared)/down_proj/kernel$", P(AXIS_TP, AXIS_FSDP)),
+        # expert layer: router [d_model, n_experts]; the held experts stacked
+        # [held, d_model, f] / [held, f, d_model]. No expert axis: the leading
+        # axis stays whole and each expert is sharded like a dense MLP
+        (r"moe/router$", P(AXIS_FSDP, None)),
+        (r"moe/e_score_correction_bias$", P()),
+        (r"moe/experts_(gate|up)$", P(None, AXIS_FSDP, AXIS_TP)),
+        (r"moe/experts_down$", P(None, AXIS_TP, AXIS_FSDP)),
         # MLP up [d_model, d_ff] column-parallel
         (r"mlp/c_fc/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"mlp/c_fc/bias$", P(AXIS_TP)),
@@ -53,7 +67,7 @@ def lm_partition_rules() -> List[Tuple[str, P]]:
         (r"lm_head/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"lm_head/bias$", P(AXIS_TP)),
         # layer norms / scalars — replicated
-        (r"(ln_1|ln_2|ln_f|layernorm.*)/(scale|bias)$", P()),
+        (r"(ln_1|ln_2|ln_f|q_a_norm|kv_a_norm|layernorm.*)/(scale|bias)$", P()),
         # value / Q heads (2-layer MLPs, small) — shard the wide hidden dim
         (r"(v_head|q1_head|q2_head|target_q1_head|target_q2_head)/layers_0/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"(v_head|q1_head|q2_head|target_q1_head|target_q2_head)/layers_0/bias$", P(AXIS_TP)),

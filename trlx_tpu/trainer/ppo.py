@@ -226,6 +226,14 @@ class PPOTrainer(JaxBaseTrainer):
         if getattr(config.model, "decode_weight_quant", False):
             from trlx_tpu.models.lm import quantize_weights
 
+            lm_cfg = self.model.cfg
+            if lm_cfg.attention != "mha" or lm_cfg.mlp != "dense" or "experts" in lm_cfg.ffn_layers:
+                raise ValueError(
+                    "model.decode_weight_quant covers the GPT block's kernels only "
+                    "(models/lm.py QUANT_KERNEL_NAMES): it is not built for attention "
+                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r} or expert layers"
+                )
+
             self._quantize_fn = self._wrap_monitored(
                 "rollout/quantize", jax.jit(quantize_weights), phase="rollout"
             )
@@ -1077,6 +1085,17 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         vf_coef=m.vf_coef,
     )
 
+    def with_expert_stats(result, out, n_tokens):
+        """A model with expert layers: the step's routing counters beside the
+        loss's own stats (`moe/held_slot_share`, `moe/max_expert_load`)."""
+        if out["expert_counts"] is None:
+            return result
+        from trlx_tpu.models.moe import expert_load_stats
+
+        share, load = expert_load_stats(out["expert_counts"], n_tokens, model.cfg.experts_per_token)
+        loss, stats = result
+        return loss, {**stats, "moe/held_slot_share": share, "moe/max_expert_load": load}
+
     def dense_loss_fn(params, batch: PPORLBatch):
         params = detach_frozen(params)
         all_ids = jnp.concatenate([batch.query_tensors, batch.response_tensors], axis=1)
@@ -1085,10 +1104,10 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         logits = out["logits"].astype(jnp.float32)
         lp = logprobs_from_logits(logits[:, :-1], all_ids[:, P:])
         vpred = out["values"].astype(jnp.float32)[:, P - 1 : -1]
-        return ppo_loss(
+        return with_expert_stats(ppo_loss(
             lp, vpred, batch.logprobs, batch.values, batch.rewards,
             batch.response_mask, **loss_kwargs,
-        )
+        ), out, all_ids.size)
 
     def fused_loss_fn(params, batch: PPORLBatch):
         # Same update, fused head: the policy's per-label logprobs come out
@@ -1103,10 +1122,10 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
             labels=all_ids[:, P:], labels_mask=batch.response_mask,
         )
         vpred = out["values"].astype(jnp.float32)[:, P - 1 : -1]
-        return ppo_loss(
+        return with_expert_stats(ppo_loss(
             out["logprobs"], vpred, batch.logprobs, batch.values, batch.rewards,
             batch.response_mask, **loss_kwargs,
-        )
+        ), out, all_ids.size)
 
     def packed_loss_fn(params, batch: PackedPPOBatch):
         # Packed layout: episodes live as segments inside dense rows
@@ -1121,12 +1140,12 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
             labels=batch.labels, labels_mask=batch.loss_mask,
         )
         vpred = out["values"].astype(jnp.float32)
-        return ppo_loss(
+        return with_expert_stats(ppo_loss(
             out["logprobs"], vpred, batch.old_logprobs, batch.old_values,
             batch.rewards, batch.loss_mask,
             segment_ids=batch.segment_ids, n_seqs=config.train.batch_size,
             **loss_kwargs,
-        )
+        ), out, batch.input_ids.size)
 
     if packed:
         return packed_loss_fn
