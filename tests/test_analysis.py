@@ -343,7 +343,7 @@ def test_gl006_clean_with_tiling_provenance(tmp_path):
     from trlx_tpu.ops.tiling import check_layout, flash_block_layout
 
     def kernel(x, bq, bk):
-        check_layout(flash_block_layout(8, 128, 64, bq, bk))
+        check_layout(flash_block_layout(8, 128, 64, (bq, 128, bk)))
         spec = pl.BlockSpec((bq, 64), lambda i: (i, 0))
         return spec
     """

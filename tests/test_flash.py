@@ -183,8 +183,8 @@ def test_auto_routing_thresholds(monkeypatch):
     with pytest.raises(ValueError):
         flash_eligible(LMConfig(attn_impl="pallas"), 512, has_cache=False)
 
-    from trlx_tpu.ops.flash_attention import pick_block
+    from trlx_tpu.ops.flash_attention import FlashBlocks, pick_block
 
-    assert pick_block(2048) == 512
-    assert pick_block(768) == 256
-    assert pick_block(48) == 48
+    # sizes follow the call's length (tests/test_flash_blocks.py has the rule's tests)
+    assert pick_block(2048) == FlashBlocks(512, 2048, 512) and pick_block(768).chunk == 256
+    assert pick_block(48) == FlashBlocks(48, 48, 48)

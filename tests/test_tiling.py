@@ -90,12 +90,24 @@ def test_decode_specs_legal_for_test_model_shapes():
     check_layout(decode_block_layout(2, 17, 2, 16, True))
 
 
-def test_flash_specs_legal_at_bench_shape():
+@pytest.mark.parametrize(
+    "BH, T, D",
+    [
+        (128, 1024, 256),  # gptj6b-l8.ppo-768x256 and gptj6b-l8.ppo-128x896: the train step
+        (512, 768, 256),  # the 768-token prefill
+        (256, 512, 128),  # gptneo1.3b.ppo-256x256, global and local layers
+        (128, 256, 128),  # gptneo1.3b.ilql-256
+        (256, 1024, 256),  # kimik2.5-l5.ppo-128x896, heads padded to 256
+        (32, 8192, 256),  # too long to be resident: major pieces
+    ],
+)
+def test_flash_specs_legal_at_bench_shape(BH, T, D):
     from trlx_tpu.ops.flash_attention import pick_block
 
-    T = 1024
-    blk = pick_block(T)
-    check_layout(flash_block_layout(BENCH_B * BENCH_H, T, BENCH_D, blk, blk))
+    blocks = pick_block(T)
+    check_layout(flash_block_layout(BH, T, D, blocks))
+    # what the kernels slice inside a step: 128-row chunks of the resident side
+    assert blocks.chunk % 128 == 0 and blocks.block % 128 == 0 and blocks.major % blocks.chunk == 0
 
 
 def test_routing_probe_refuses_illegal_layout(monkeypatch):

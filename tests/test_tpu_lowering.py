@@ -49,6 +49,26 @@ def _flash(q, k, v, m):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
+def _flash_windowed(q, k, v, m):
+    """GPT-Neo's calls: 128-wide heads, unscaled, a local layer's window
+    (the loop's lower bound and the edge chunks below the mask-free run)."""
+    loss = lambda q, k, v: flash_attention(
+        q, k, v, m, scale=1.0, causal=True, window=256, interpret=False
+    ).astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _flash_ring_chunk(q, k, v, m, offset):
+    """A sequence too long to be resident (major pieces, the state carried in
+    scratch) called the way the ring path calls it: a traced offset (the
+    loop bounds are traced scalars), lse returned and differentiated."""
+    def loss(q, k, v):
+        o, lse = flash_attention(q, k, v, m, scale=SCALE, causal=True, offset=offset, return_lse=True, interpret=False)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
 def _fused(x, w, y, b):
     """value_and_grad: the forward kernel and both backward kernels."""
     loss = lambda x, w, b: sum(
@@ -72,6 +92,9 @@ def _cases(s):
     head = (s((N, DM), bf16), s((DM, V), bf16), s((N,), i32), s((V,), bf16))
     cases = [
         ("flash fwd+bwd", _flash, (qkv, qkv, qkv, s((B, T), f32))),
+        ("flash fwd+bwd windowed d128", _flash_windowed, (s((16, 512, H, 128), bf16),) * 3 + (s((16, 512), f32),)),
+        ("flash fwd+bwd major pieces, traced offset", _flash_ring_chunk,
+         (s((1, 8192, H, D), bf16),) * 3 + (s((1, 8192), f32), s((), f32))),
         ("fused_logprob fwd+bwd", _fused, head),
     ]
     for quant in (False, True):

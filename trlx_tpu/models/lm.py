@@ -338,6 +338,30 @@ def flash_eligible(cfg: LMConfig, q_len: int, has_cache: bool, prefill_at_zero: 
     return True
 
 
+def layer_window(cfg: LMConfig, layer: int) -> int:
+    """The trailing window of a layer's attention: `window_size` on a local
+    layer (gpt-neo's alternating pattern), 0 on a global one."""
+    return cfg.window_size if cfg.attention_layers and cfg.attention_layers[layer] == "local" else 0
+
+
+def flash_kept_pair_share(cfg: LMConfig, q_len: int) -> Optional[float]:
+    """Pairs the mask keeps over pairs the flash kernels' live chunks compute,
+    mean over the layers' attention calls of one full-sequence pass at
+    `q_len` (a train step's); None where that pass takes no flash kernel (or
+    the ring path, whose calls are per chunk). Reads the routing the trunk
+    reads (`ring_eligible`, `flash_eligible`, `layer_window`) and the sizes
+    the call takes (`pick_block`). A host float from shapes: the counter
+    `flash/kept_pair_share` of a trainer's step records. A description of the
+    sizes, not a score: a wider chunk lowers it and is as fast or faster
+    (PERF.md §6, PR 27)."""
+    if ring_eligible(cfg, q_len, False) or not flash_eligible(cfg, q_len, has_cache=False):
+        return None
+    from trlx_tpu.ops.flash_attention import kept_pair_share, pick_block
+
+    blocks = pick_block(q_len)
+    return sum(kept_pair_share(q_len, blocks, True, layer_window(cfg, i)) for i in range(cfg.n_layer)) / cfg.n_layer
+
+
 class QDense(nn.Module):
     """`nn.Dense` drop-in whose weights can be OVERRIDDEN by an int8
     weight-only copy passed as the ``qw`` variable collection (decode-time
@@ -655,13 +679,9 @@ class Attention(nn.Module):
                     q, k, v, flash_mask, scale=scale, causal=True, window=window
                 ).astype(dtype)
             else:
-                from trlx_tpu.ops.flash_attention import flash_attention, pick_block
+                from trlx_tpu.ops.flash_attention import flash_attention
 
-                blk = pick_block(q_len)
-                out = flash_attention(
-                    q, k, v, flash_mask, scale=scale, causal=True, window=window,
-                    block_q=blk, block_k=blk,
-                ).astype(dtype)
+                out = flash_attention(q, k, v, flash_mask, scale=scale, causal=True, window=window).astype(dtype)
         elif decode_kernel_kv is not None:
             from trlx_tpu.ops.decode_attention import (
                 decode_attention,
@@ -781,15 +801,11 @@ class LatentAttention(nn.Module):
                 v = kv[..., dn:]
                 if flash_mask is None:
                     return attend(q, k, v, mask_or_bias[..., :q_len], softmax_scale, dtype).reshape(rows, q_len, h * dv)
-                from trlx_tpu.ops.flash_attention import flash_attention, pick_block
+                from trlx_tpu.ops.flash_attention import flash_attention
 
                 wide = -(-(dn + dr) // 128) * 128
                 pad = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, wide - a.shape[-1])))
-                blk = pick_block(q_len)
-                out = flash_attention(
-                    pad(q), pad(k), pad(v), mask_or_bias, scale=softmax_scale, causal=True,
-                    block_q=blk, block_k=blk,
-                )
+                out = flash_attention(pad(q), pad(k), pad(v), mask_or_bias, scale=softmax_scale, causal=True)
                 return out[..., :dv].astype(dtype).reshape(rows, q_len, h * dv)
 
             with jax.named_scope("mla_unabsorbed"):
@@ -1140,12 +1156,11 @@ class TransformerLM(nn.Module):
             if collect_hidden_at is not None and i == collect_hidden_at:
                 branch_hidden = x
             layer_cache = cache[i] if cache is not None else None
-            is_local = bool(cfg.attention_layers) and cfg.attention_layers[i] == "local"
-            layer_bias = local_bias if is_local else attn_bias
-            layer_window = cfg.window_size if is_local else 0
+            window = layer_window(cfg, i)
+            layer_bias = local_bias if window else attn_bias
             x, layer_new_cache, layer_counts = block(
                 x, layer_bias, position_ids, layer_cache, cache_index,
-                flash_mask, layer_window, use_ring, block_tables,
+                flash_mask, window, use_ring, block_tables,
             )
             x = obs_numerics.probe_tap(f"block_{i}", x)
             if cache is not None:

@@ -2,30 +2,40 @@
 
 The hot op of every forward/rollout/train step. The reference leans on
 torch/HF SDPA CUDA kernels (reference: trlx/model/nn/ppo_models.py:171-189
-replays HF GPT-2 blocks); here the kernel is ours, built for the MXU:
+replays HF GPT-2 blocks); here the kernel is ours, built for the MXU. One
+algorithm in three kernels (forward; dq; dk/dv), each doing only the work the
+mask leaves:
 
-- grid (batch*heads, q_blocks, k_blocks) with the k dimension innermost, so
-  the softmax runs online in VMEM scratch (m/l running max/sum) and the
-  [T, T] score matrix never exists in HBM;
-- causal + left-padding key-validity + gpt-neo local-window masking fused
-  into the score block (the XLA path materializes an additive [b,1,T,T]
-  bias — see trlx_tpu.models.lm.make_attn_bias);
-- fully-masked upper-diagonal k blocks are skipped (`pl.when`), recovering
-  the ~2x causal FLOP saving;
-- custom VJP with two backward kernels (dq; dk/dv) that recompute P from the
-  saved log-sum-exp instead of storing probabilities.
+- a grid step owns one `block` of rows (queries in the forward and dq, keys
+  in dk/dv) and keeps the other side RESIDENT in VMEM, `major` rows of it
+  (the whole sequence wherever it fits, so the grid has one step per block and
+  K/V are fetched once per head; a longer sequence walks `major`-sized pieces
+  in the innermost grid dimension, carrying the softmax state in scratch);
+- inside the step a loop walks the resident side in `chunk`-row pieces, and
+  only over the LIVE ones: the loop's bounds come from the mask (`live_chunks`:
+  the causal diagonal, the gpt-neo local window, the ring path's traced
+  offset), so a dead chunk costs neither a fetch nor a step;
+- every live chunk takes the one body: the band's compare-and-select on the
+  f32 scores (a second, mask-free body for the chunks the mask keeps whole
+  measured SLOWER: three loops a step for one, PERF.md §6, PR 27), `scale` on
+  the f32 scores, key
+  validity (left padding in PPO, right padding in ILQL) as one additive f32
+  row per chunk;
+- custom VJP with two backward kernels that recompute P from the saved
+  log-sum-exp instead of storing probabilities; dk/dv works on the TRANSPOSED
+  score tile (keys down the sublanes), so its products need no transposed
+  operand and the per-query log-sum-exp is a row.
 
-All matmuls ACCUMULATE in fp32 via preferred_element_type (multiplies run at
-the MXU's native bf16 granularity, same precision class as XLA's default
-einsum path on TPU); inputs may be bf16. Interpret mode (CPU) is
-auto-selected off-TPU so the same code path is unit-testable in CI; measured
-on a v5e, the kernel matches the XLA einsum path within mutual bf16 noise
-(~1e-2 at T=1024 fp32 inputs) and the parallel grid dimension_semantics are
-bit-identical to sequential execution.
+The sizes follow the call's length (`pick_block`; the measurements behind its
+rule are in PERF.md §6, PR 27). All matmuls ACCUMULATE in fp32 via
+preferred_element_type (multiplies run at the MXU's native bf16 granularity,
+same precision class as XLA's default einsum path on TPU); inputs may be
+bf16. Interpret mode (CPU) is auto-selected off-TPU so the same code path is
+unit-testable in CI (tests/test_flash_blocks.py).
 """
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 M_INIT = -1e30  # running-max init (finite: fully-masked rows degrade to
 # uniform attention exactly like the XLA path's -1e9 bias)
 MASK_VAL = -1e9
+RESIDENT_ROWS = 2048  # rows of the resident side of a grid step (K, V; Q, dO): 1 MiB an operand at 256-wide bf16 heads
 
 
 def _interpret_default() -> bool:
@@ -58,14 +69,29 @@ def one_device_tpu() -> bool:
     return mesh is None or mesh.size == 1
 
 
-def pick_block(q_len: int) -> int:
-    """Largest well-measured block that divides q_len: 512x512 measured best
-    on v5e (7.7ms vs einsum 10.7ms at b=4,T=2048,h=16,d=64), falling to 256/
-    128, else one whole-length block."""
-    for blk in (512, 256, 128):
-        if q_len % blk == 0:
-            return blk
-    return q_len
+class FlashBlocks(NamedTuple):
+    """The three sizes of a call. `block`: rows one grid step owns (queries in
+    the forward and dq, keys in dk/dv). `major`: rows of the other side a
+    step holds resident. `chunk`: rows of it one loop step computes."""
+
+    block: int
+    major: int
+    chunk: int
+
+
+def pick_block(q_len: int) -> FlashBlocks:
+    """The sizes of a call, from its length alone. A length that 128 does not
+    divide keeps one whole-length chunk. Otherwise block = chunk = the widest
+    of 512/256/128 that divides the length, and the other side resident up to
+    RESIDENT_ROWS. Measured (PERF.md §6, PR 27): a tile's time is mostly its
+    two row reductions and the latency around them, not its products, so a
+    wide chunk is as fast as a narrow one or faster even where it computes
+    more pairs above the diagonal."""
+    if q_len % 128:
+        return FlashBlocks(q_len, q_len, q_len)
+    size = next(s for s in (512, 256, 128) if q_len % s == 0)
+    major = max(m for m in range(size, min(RESIDENT_ROWS, q_len) + 1, size) if q_len % m == 0)
+    return FlashBlocks(size, major, size)
 
 
 def auto_flash_ok(q_len: int) -> bool:
@@ -75,6 +101,72 @@ def auto_flash_ok(q_len: int) -> bool:
     ring-attention per-chunk path so the eligibility rule and the block
     choice cannot drift apart."""
     return jax.default_backend() == "tpu" and q_len >= 256 and q_len % 128 == 0
+
+
+# ---------------------------------------------------------------------------
+# Liveness: which chunks a block's loop visits
+# ---------------------------------------------------------------------------
+
+
+def mask_band(doff, causal: bool, window: int, keys_own_block: bool = False):
+    """The mask as a band: a pair is kept iff lower <= c - r <= upper, with r
+    the row of the side that owns the grid step's block and c the row of the
+    side the loop chunks; None is unbounded. `doff` shifts key positions into
+    the query frame (k_global = k + doff): zero for ordinary self-attention,
+    the chunk displacement for ring-attention blocks, then a traced scalar.
+    Forward and dq own queries (c a key: c + doff <= r, c + doff > r -
+    window); dk/dv owns keys (c a query)."""
+    if keys_own_block:
+        return (doff if causal else None), (doff + window - 1 if window > 0 else None)
+    return (1 - window - doff if window > 0 else None), (-doff if causal else None)
+
+
+def _max(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.maximum(a, b)
+
+
+def _min(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.minimum(a, b)
+
+
+def _div0(x, d: int, ceil: bool = False):
+    """floor or ceil of max(x, 0) / d, for a python int or a traced scalar."""
+    x = _max(x, 0) + (d - 1 if ceil else 0)
+    return x // d if isinstance(x, int) else jax.lax.div(x, jnp.int32(d))
+
+
+def live_chunks(start, band, *, block: int, chunk: int, count: int):
+    """(lo, hi) for the block whose first row is `start`, counted from the
+    first row of chunk 0: of the chunks [0, count) exactly those in [lo, hi)
+    hold a pair the band keeps. Exact: c - r takes every value between the
+    rectangle's corners. The one rule the kernels' loop bounds,
+    `kept_pair_share` and the tests share."""
+    lower, upper = band
+    lo, hi = 0, count
+    if upper is not None:
+        hi = _min(_div0(start + block + upper, chunk, ceil=True), hi)
+    if lower is not None:
+        lo = _min(_div0(start + lower, chunk), hi)
+    return lo, hi
+
+
+def kept_pair_share(q_len: int, blocks: FlashBlocks, causal: bool = True, window: int = 0) -> float:
+    """Pairs the mask keeps over pairs the live chunks compute, for one call
+    at offset 0 (padding is data, so it does not count). By symmetry the
+    same for the three kernels. A host float from shapes."""
+    band = mask_band(0, causal, window)
+    computed = 0
+    for start in range(0, q_len, blocks.block):
+        lo, hi = live_chunks(start, band, block=blocks.block, chunk=blocks.chunk, count=q_len // blocks.chunk)
+        computed += blocks.block * (hi - lo) * blocks.chunk
+    span = window if 0 < window < q_len else q_len  # keys the last query sees
+    kept = (span * (span + 1) // 2 + (q_len - span) * span) if causal else q_len * q_len
+    return kept / computed
+
+
+# ---------------------------------------------------------------------------
+# Pallas plumbing
+# ---------------------------------------------------------------------------
 
 
 def _vmem_spec(shape, index_map):
@@ -91,14 +183,13 @@ def _smem_spec():
 
 
 def _compiler_params(interpret, semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=None):
-    """Mark the (bh, outer-block) grid dims parallel so Mosaic pipelines
-    across grid steps instead of serializing them; only the innermost dim
-    (the online-softmax / accumulation walk) is order-dependent. Without
-    this the kernel is grid-step-latency-bound: at [8,1024,16,256] the
-    forward drops from ~18ms to ~3ms on a v5e. The decode and fused-logprob
-    kernels pass their own two-dim semantics. `vmem_limit_bytes` lifts the
-    compiler's scoped-VMEM limit (16 MiB) for a kernel whose blocks need more;
-    None leaves the default."""
+    """Mark the (bh, block) grid dims parallel so Mosaic pipelines across
+    grid steps instead of serializing them; only the innermost dim (the walk
+    over `major` pieces, one step where the sequence is resident) is
+    order-dependent. The decode and fused-logprob kernels pass their own
+    two-dim semantics. `vmem_limit_bytes` lifts the compiler's scoped-VMEM
+    limit (16 MiB) for a kernel whose blocks need more; None leaves the
+    default."""
     if interpret:
         return {}
     extra = {} if vmem_limit_bytes is None else {"vmem_limit_bytes": int(vmem_limit_bytes)}
@@ -106,45 +197,83 @@ def _compiler_params(interpret, semantics=("parallel", "parallel", "arbitrary"),
 
 
 # ---------------------------------------------------------------------------
-# Shared score block
+# What the three kernels share
 # ---------------------------------------------------------------------------
 
-def _masked_scores(q_ref, k_ref, kmask_ref, q_start, k_start, doff, *, scale,
-                   causal, window, bq, bk):
-    """q@k^T (native dtype, fp32 accumulate) + causal/validity/window mask —
-    shared by the forward and both backward kernels so their masking can never
-    desynchronize. `doff` shifts key positions into the query frame
-    (k_global = k_idx + doff); zero for ordinary self-attention, the chunk
-    displacement for ring-attention blocks."""
-    s = jax.lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    q_idx = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_idx = doff + k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = (kmask_ref[0, 0] > 0.5)[None, :]
-    if causal:
-        mask = mask & (k_idx <= q_idx)
-    if window > 0:
-        mask = mask & (k_idx > q_idx - window)
-    return jnp.where(mask, s, MASK_VAL)
+
+def _mm(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _run_if_live(compute, q_start, k_start, doff, *, bq, bk, causal, window):
-    """Skip k blocks that the mask would zero out entirely: above the causal
-    diagonal (in the offset frame), or (local attention) wholly below the
-    trailing window."""
-    conds = []
-    if causal:
-        conds.append(k_start + doff <= q_start + bq - 1)
-    if window > 0:
-        conds.append(k_start + bk - 1 + doff > q_start - window)
-    if not conds:
-        compute()
+def _mm_nt(a, b):
+    """a @ b.T: both operands contract their head dimension."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _band_setup(off_ref, i_block, i_major, *, scale, causal, window, blocks, keys_own_block):
+    """The block's live chunks [lo, hi) within this step's `major` piece, and
+    `scores(x_block, x_chunk, bias, j)`: the [block, chunk] f32 scores of
+    local chunk j, scaled, plus the additive key-validity bias (a [1, chunk]
+    row where the chunk side is keys, a [block, chunk] tile where the block
+    side is), with the pairs outside the band at MASK_VAL — shared by the
+    three kernels so their masking can never desynchronize."""
+    block, major, chunk = blocks
+    doff = off_ref[0, 0].astype(jnp.int32)
+    lower, upper = band = mask_band(doff, causal, window, keys_own_block)
+    # the block's first row, counted from the first row of this major piece
+    start = i_block * block - i_major * major
+    bounds = live_chunks(start, band, block=block, chunk=chunk, count=major // chunk)
+    # c - r of a tile is this constant difference of iotas plus a scalar
+    rel = jax.lax.broadcasted_iota(jnp.int32, (block, chunk), 1) - jax.lax.broadcasted_iota(
+        jnp.int32, (block, chunk), 0
+    )
+
+    def scores(x_block, x_chunk, bias, j):
+        s = _mm_nt(x_block, x_chunk) * scale + bias
+        shift = j * chunk - start
+        if lower is not None:
+            s = jnp.where(rel >= lower - shift, s, MASK_VAL)
+        if upper is not None:
+            s = jnp.where(rel <= upper - shift, s, MASK_VAL)
+        return s
+
+    return bounds, scores
+
+
+def _across_major(i_major, n_major, scratch, fresh, run, finish):
+    """Carry `run`'s state over the `major` pieces of a long sequence in
+    scratch; a resident sequence (one piece) needs none."""
+    if n_major == 1:
+        finish(run(fresh))
         return
-    pred = conds[0]
-    for c in conds[1:]:
-        pred = pred & c
-    pl.when(pred)(compute)
+
+    @pl.when(i_major == 0)
+    def _():
+        for ref, val in zip(scratch, fresh):
+            ref[...] = val
+
+    out = run(tuple(ref[...] for ref in scratch))
+    for ref, val in zip(scratch, out):
+        ref[...] = val
+    pl.when(i_major == n_major - 1)(lambda: finish(out))
+
+
+# Index maps over the grid (bh, block, major piece): the side that owns the
+# step's block, the resident side, and the block side's [BH, 1, T] row vectors.
+def _own(bh, i, im):
+    return bh, i, 0
+
+
+def _resident(bh, i, im):
+    return bh, im, 0
+
+
+def _own_row(bh, i, im):
+    return bh, 0, i
+
+
+def _rows(j, chunk):
+    return pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
 
 
 # ---------------------------------------------------------------------------
@@ -152,93 +281,103 @@ def _run_if_live(compute, q_start, k_start, doff, *, bq, bk, causal, window):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(off_ref, kmask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr,
-                *, scale, causal, window, bq, bk):
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-    q_start = iq * bq
-    k_start = ik * bk
-    doff = off_ref[0, 0].astype(jnp.int32)
+def _fwd_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                scale, causal, window, blocks, n_major):
+    block, major, chunk = blocks
+    i_major = pl.program_id(2)
+    bounds, scores = _band_setup(
+        off_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
+        keys_own_block=False,
+    )
+    q = q_ref[0]
 
-    @pl.when(ik == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, M_INIT)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc[:] = jnp.zeros_like(acc)
-
-    def compute():
-        s = _masked_scores(q_ref, k_ref, kmask_ref, q_start, k_start, doff,
-                           scale=scale, causal=causal, window=window, bq=bq, bk=bk)
-        m_prev = m_scr[:, :1]
+    def step(j, carry):
+        m_prev, l_prev, acc = carry
+        rows = _rows(j, chunk)
+        s = scores(q, k_ref[0, rows, :], kbias_ref[0, j], j)
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_cur)
         alpha = jnp.exp(m_prev - m_cur)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        l_cur = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        return m_cur, l_cur, acc * alpha + _mm(p.astype(v_ref.dtype), v_ref[0, rows, :])
 
-    _run_if_live(compute, q_start, k_start, doff, bq=bq, bk=bk, causal=causal, window=window)
-
-    @pl.when(ik == nk - 1)
-    def _():
-        # Rows whose every k block was skipped (an entirely-future ring
-        # chunk) have l == 0: emit zeros with lse = M_INIT so the chunk
-        # vanishes from any log-sum-exp combination instead of NaN-ing.
-        l = l_scr[:, :1]
+    def finish(carry):
+        # Rows whose every chunk was dead (an entirely-future ring chunk)
+        # have l == 0: emit zeros with lse = M_INIT so the chunk vanishes
+        # from any log-sum-exp combination instead of NaN-ing.
+        m, l, acc = carry
         l_safe = jnp.maximum(l, 1e-30)
-        o_ref[0] = (acc[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(
-            l[:, 0] > 0, m_scr[:, 0] + jnp.log(l_safe[:, 0]), M_INIT
-        )
+        o_ref[0] = (acc * (1.0 / l_safe)).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.where(l[:, 0] > 0, m[:, 0] + jnp.log(l_safe[:, 0]), M_INIT)
+
+    fresh = (
+        jnp.full((block, 1), M_INIT, jnp.float32),
+        jnp.zeros((block, 1), jnp.float32),
+        jnp.zeros((block, q_ref.shape[-1]), jnp.float32),
+    )
+    _across_major(i_major, n_major, scratch, fresh, lambda c: jax.lax.fori_loop(*bounds, step, c), finish)
 
 
-def _fwd(q, k, v, kmask, off, scale, causal, window, bq, bk, interpret):
-    BH, T, D = q.shape
-    nq, nk = T // bq, T // bk
-    H = BH // kmask.shape[0]
+def _geometry(T, blocks):
+    block, major, chunk = blocks
+    if T % block or T % major or major % chunk:
+        raise ValueError(f"seq len {T} not divisible by blocks {tuple(blocks)}")
+    return T // block, T // major, major // chunk
+
+
+def _key_bias(kmask):
+    """[b, 1, T] f32: 0 at a valid key, MASK_VAL at padding."""
+    return jnp.where(kmask > 0.5, 0.0, MASK_VAL).astype(jnp.float32)
+
+
+def _by_chunk(x, chunk):
+    """[n, 1, T] rows as [n, T // chunk, 1, chunk]: a loop step's row is
+    `ref[0, j]`, an index on an untiled dimension."""
+    return x.reshape(x.shape[0], x.shape[-1] // chunk, 1, chunk)
+
+
+def _check_layout(BH, T, D, blocks, interpret):
     if not interpret:
-        # GL006 provenance: the _vmem_spec shapes below must agree with the
-        # canonical tiling.flash_block_layout description — validating the
-        # layout before compiling keeps wrapper and validator from drifting
-        # (the PR 3 Mosaic tile-rule crash class). Interpret mode has no
-        # Mosaic tile constraints, so tiny CPU test shapes stay legal.
+        # GL006 provenance: the _vmem_spec shapes of the three kernels must
+        # agree with the canonical tiling.flash_block_layout description —
+        # validating the layout before compiling keeps wrapper and validator
+        # from drifting (the PR 3 Mosaic tile-rule crash class). Interpret
+        # mode has no Mosaic tile constraints, so tiny CPU test shapes stay
+        # legal.
         from trlx_tpu.ops.tiling import check_layout, flash_block_layout
 
-        check_layout(flash_block_layout(BH, T, D, bq, bk))
+        check_layout(flash_block_layout(BH, T, D, blocks))
+
+
+def _fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret):
+    BH, T, D = q.shape
+    block, major, chunk = blocks
+    n_block, n_major, per_major = _geometry(T, blocks)
+    H = BH // kmask.shape[0]
+    _check_layout(BH, T, D, blocks, interpret)
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, window=window, bq=bq, bk=bk
+        _fwd_kernel, scale=scale, causal=causal, window=window, blocks=blocks, n_major=n_major
     )
     o, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",  # how a device trace names the call
-        grid=(BH, nq, nk),
+        grid=(BH, n_block, n_major),
         in_specs=[
             _smem_spec(),
-            _vmem_spec((1, 1, bk), lambda bh, iq, ik: (bh // H, 0, ik)),
-            _vmem_spec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            _vmem_spec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
-            _vmem_spec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
+            _vmem_spec((1, per_major, 1, chunk), lambda bh, i, im: (bh // H, im, 0, 0)),
+            _vmem_spec((1, block, D), _own),
+            _vmem_spec((1, major, D), _resident),
+            _vmem_spec((1, major, D), _resident),
         ],
-        out_specs=[
-            _vmem_spec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            _vmem_spec((1, 1, bq), lambda bh, iq, ik: (bh, 0, iq)),
-        ],
+        out_specs=[_vmem_spec((1, block, D), _own), _vmem_spec((1, 1, block), _own_row)],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
         ],
-        scratch_shapes=[
-            _scratch((bq, D)),
-            _scratch((bq, 128)),
-            _scratch((bq, 128)),
-        ],
+        scratch_shapes=[_scratch((block, 1)), _scratch((block, 1)), _scratch((block, D))] if n_major > 1 else [],
         interpret=interpret,
         **_compiler_params(interpret),
-    )(off, kmask, q, k, v)
+    )(off, _by_chunk(_key_bias(kmask), chunk), q, k, v)
     return o, lse
 
 
@@ -247,74 +386,65 @@ def _fwd(q, k, v, kmask, off, scale, causal, window, bq, bk, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(off_ref, kmask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc, *, scale, causal, window, bq, bk):
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-    q_start, k_start = iq * bq, ik * bk
-    doff = off_ref[0, 0].astype(jnp.int32)
+def _bwd_dq_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, *scratch, scale, causal, window, blocks, n_major):
+    block, major, chunk = blocks
+    i_major = pl.program_id(2)
+    bounds, scores = _band_setup(
+        off_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
+        keys_own_block=False,
+    )
+    q = q_ref[0]
+    do = do_ref[0]
+    lse = lse_ref[0, 0][:, None]
+    delta = delta_ref[0, 0][:, None]
 
-    @pl.when(ik == 0)
-    def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+    def step(j, carry):
+        rows = _rows(j, chunk)
+        k = k_ref[0, rows, :]
+        s = scores(q, k, kbias_ref[0, j], j)
+        p = jnp.exp(s - lse)
+        dp = _mm_nt(do, v_ref[0, rows, :])
+        ds = p * (dp - delta)
+        return (carry[0] + _mm(ds.astype(k.dtype), k),)
 
-    def compute():
-        s = _masked_scores(q_ref, k_ref, kmask_ref, q_start, k_start, doff,
-                           scale=scale, causal=causal, window=window, bq=bq, bk=bk)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+    def finish(carry):
+        dq_ref[0] = (carry[0] * scale).astype(dq_ref.dtype)
 
-    _run_if_live(compute, q_start, k_start, doff, bq=bq, bk=bk, causal=causal, window=window)
-
-    @pl.when(ik == nk - 1)
-    def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+    fresh = (jnp.zeros((block, q_ref.shape[-1]), jnp.float32),)
+    _across_major(i_major, n_major, scratch, fresh, lambda c: jax.lax.fori_loop(*bounds, step, c), finish)
 
 
-def _bwd_dkv_kernel(off_ref, kmask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, window, bq, bk):
-    ik, iq = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
-    q_start, k_start = iq * bq, ik * bk
-    doff = off_ref[0, 0].astype(jnp.int32)
+def _bwd_dkv_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, *scratch, scale, causal, window, blocks, n_major):
+    """The mirror image: a key block resident, a loop over the live query
+    chunks, on the transposed score tile [keys, queries]."""
+    block, major, chunk = blocks
+    i_major = pl.program_id(2)
+    bounds, scores = _band_setup(
+        off_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
+        keys_own_block=True,
+    )
+    k = k_ref[0]
+    v = v_ref[0]
+    kbias = jnp.broadcast_to(kbias_ref[0, 0][:, None], (block, chunk))
 
-    @pl.when(iq == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    def step(j, carry):
+        dk_acc, dv_acc = carry
+        rows = _rows(j, chunk)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        s = scores(k, q, kbias, j)  # [keys, queries]
+        p = jnp.exp(s - lse_ref[0, j])
+        dp = _mm_nt(v, do)
+        ds = p * (dp - delta_ref[0, j])
+        return dk_acc + _mm(ds.astype(q.dtype), q), dv_acc + _mm(p.astype(do.dtype), do)
 
-    def compute():
-        s = _masked_scores(q_ref, k_ref, kmask_ref, q_start, k_start, doff,
-                           scale=scale, causal=causal, window=window, bq=bq, bk=bk)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])  # [bq, bk]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+    def finish(carry):
+        dk_ref[0] = (carry[0] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = carry[1].astype(dv_ref.dtype)
 
-    _run_if_live(compute, q_start, k_start, doff, bq=bq, bk=bk, causal=causal, window=window)
-
-    @pl.when(iq == nq - 1)
-    def _():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    fresh = (jnp.zeros((block, k_ref.shape[-1]), jnp.float32),) * 2
+    _across_major(i_major, n_major, scratch, fresh, lambda c: jax.lax.fori_loop(*bounds, step, c), finish)
 
 
 # ---------------------------------------------------------------------------
@@ -322,92 +452,85 @@ def _bwd_dkv_kernel(off_ref, kmask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, de
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_lse(q, k, v, kmask, off, scale, causal, window, bq, bk, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_lse(q, k, v, kmask, off, scale, causal, window, blocks, interpret):
     """Fused attention returning (o, lse). Exposing lse makes per-chunk calls
     exactly combinable (ring attention): downstream use of lse feeds a dlse
     cotangent which the backward folds into delta."""
-    return _fwd(q, k, v, kmask, off, scale, causal, window, bq, bk, interpret)
+    return _fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret)
 
 
-def _flash_lse_fwd(q, k, v, kmask, off, scale, causal, window, bq, bk, interpret):
-    o, lse = _fwd(q, k, v, kmask, off, scale, causal, window, bq, bk, interpret)
+def _flash_lse_fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret):
+    o, lse = _fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret)
     return (o, lse), (q, k, v, kmask, off, o, lse)
 
 
-def _flash_lse_bwd(scale, causal, window, bq, bk, interpret, res, cts):
+def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
     do, dlse = cts
     q, k, v, kmask, off, o, lse = res
     BH, T, D = q.shape
     H = BH // kmask.shape[0]
+    block, major, chunk = blocks
+    n_block, n_major, per_major = _geometry(T, blocks)
     # d s_ij = p_ij (dp_ij - delta_i); with lse also an output,
     # d lse / d s_ij = p_ij, so delta picks up an extra -dlse_i term.
     delta = (
         jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, None, :]
         - dlse.astype(jnp.float32)
     )  # [BH, 1, T]
-    nq, nk = T // bq, T // bk
+    _check_layout(BH, T, D, blocks, interpret)
 
-    if not interpret:
-        # GL006 provenance: the backward kernels tile the same (block, array)
-        # families as the forward (q/k/v blocks plus the [BH,1,T] row
-        # vectors), so the forward layout is the legality contract here too.
-        from trlx_tpu.ops.tiling import check_layout, flash_block_layout
-
-        check_layout(flash_block_layout(BH, T, D, bq, bk))
-
-    common = dict(scale=scale, causal=causal, window=window, bq=bq, bk=bk)
-    in_arrays = (off, kmask, q, k, v, do, lse, delta)
+    common = dict(scale=scale, causal=causal, window=window, blocks=blocks, n_major=n_major)
+    grid = (BH, n_block, n_major)
+    scratch = lambda n: [_scratch((block, D))] * n if n_major > 1 else []
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         name="flash_bwd_dq",
-        grid=(BH, nq, nk),
+        grid=grid,
         in_specs=[
             _smem_spec(),
-            _vmem_spec((1, 1, bk), lambda bh, iq, ik: (bh // H, 0, ik)),
-            _vmem_spec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            _vmem_spec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
-            _vmem_spec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
-            _vmem_spec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            _vmem_spec((1, 1, bq), lambda bh, iq, ik: (bh, 0, iq)),
-            _vmem_spec((1, 1, bq), lambda bh, iq, ik: (bh, 0, iq)),
+            _vmem_spec((1, per_major, 1, chunk), lambda bh, i, im: (bh // H, im, 0, 0)),
+            _vmem_spec((1, block, D), _own),
+            _vmem_spec((1, major, D), _resident),
+            _vmem_spec((1, major, D), _resident),
+            _vmem_spec((1, block, D), _own),
+            _vmem_spec((1, 1, block), _own_row),
+            _vmem_spec((1, 1, block), _own_row),
         ],
-        out_specs=[_vmem_spec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0))],
+        out_specs=[_vmem_spec((1, block, D), _own)],
         out_shape=[jax.ShapeDtypeStruct((BH, T, D), q.dtype)],
-        scratch_shapes=[_scratch((bq, D))],
+        scratch_shapes=scratch(1),
         interpret=interpret,
         **_compiler_params(interpret),
-    )(*in_arrays)[0]
+    )(off, _by_chunk(_key_bias(kmask), chunk), q, k, v, do, lse, delta)[0]
 
-    # k-side: grid walks (bh, k_block, q_block) — q innermost so dk/dv
-    # accumulate in VMEM scratch across the whole q range.
+    # k-side: a step owns a key block; queries, dO and their row vectors
+    # (one [1, chunk] row a loop step: `ref[0, j]`) are the resident side.
+    row_spec = _vmem_spec((1, per_major, 1, chunk), lambda bh, i, im: (bh, im, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
         name="flash_bwd_dkv",
-        grid=(BH, nk, nq),
+        grid=grid,
         in_specs=[
             _smem_spec(),
-            _vmem_spec((1, 1, bk), lambda bh, ik, iq: (bh // H, 0, ik)),
-            _vmem_spec((1, bq, D), lambda bh, ik, iq: (bh, iq, 0)),
-            _vmem_spec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
-            _vmem_spec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
-            _vmem_spec((1, bq, D), lambda bh, ik, iq: (bh, iq, 0)),
-            _vmem_spec((1, 1, bq), lambda bh, ik, iq: (bh, 0, iq)),
-            _vmem_spec((1, 1, bq), lambda bh, ik, iq: (bh, 0, iq)),
+            _vmem_spec((1, 1, block), lambda bh, i, im: (bh // H, 0, i)),
+            _vmem_spec((1, major, D), _resident),
+            _vmem_spec((1, block, D), _own),
+            _vmem_spec((1, block, D), _own),
+            _vmem_spec((1, major, D), _resident),
+            row_spec,
+            row_spec,
         ],
-        out_specs=[
-            _vmem_spec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
-            _vmem_spec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
-        ],
+        out_specs=[_vmem_spec((1, block, D), _own), _vmem_spec((1, block, D), _own)],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, D), k.dtype),
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
-        scratch_shapes=[_scratch((bk, D)), _scratch((bk, D))],
+        scratch_shapes=scratch(2),
         interpret=interpret,
         **_compiler_params(interpret),
-    )(*in_arrays)
+    )(off, _key_bias(kmask), q, k, v, do, _by_chunk(lse, chunk), _by_chunk(delta, chunk))
 
     return dq, dk, dv, jnp.zeros_like(kmask), jnp.zeros_like(off)
 
@@ -425,8 +548,7 @@ def flash_attention(
     causal: bool = True,
     window: int = 0,
     offset=None,
-    block_q: int = 512,
-    block_k: int = 512,
+    blocks: Optional[FlashBlocks] = None,
     interpret: Optional[bool] = None,
     return_lse: bool = False,
 ):
@@ -437,14 +559,12 @@ def flash_attention(
     (python int or traced scalar) shifts key positions into the query frame
     — ring attention passes the visiting chunk's displacement. With
     `return_lse` the per-row log-sum-exp comes back as [b, h, T] for exact
-    cross-chunk combination. Sequence length must divide block_q/block_k
-    (the model layer guarantees this by routing unaligned shapes to the XLA
-    einsum path).
+    cross-chunk combination. `blocks` is `pick_block`'s choice unless a test
+    or a microbench passes its own; the sequence length must divide by its
+    block and major, and major by chunk.
     """
     b, T, h, d = q.shape
-    bq, bk = min(block_q, T), min(block_k, T)
-    if T % bq or T % bk:
-        raise ValueError(f"seq len {T} not divisible by blocks ({bq}, {bk})")
+    blocks = pick_block(T) if blocks is None else FlashBlocks(*blocks)
     if interpret is None:
         interpret = _interpret_default()
     # float32 deliberately: `off` is a differentiable custom_vjp operand
@@ -457,7 +577,7 @@ def flash_attention(
 
     o, lse = _flash_lse(
         to_bh(q), to_bh(k), to_bh(v), kv_mask.astype(jnp.float32)[:, None, :],
-        off, float(scale), bool(causal), int(window), bq, bk, bool(interpret),
+        off, float(scale), bool(causal), int(window), blocks, bool(interpret),
     )
     o = o.reshape(b, h, T, d).transpose(0, 2, 1, 3)
     if return_lse:

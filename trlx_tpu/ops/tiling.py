@@ -278,16 +278,23 @@ def paged_decode_layout(
     return layouts
 
 
-def flash_block_layout(BH: int, T: int, D: int, bq: int, bk: int) -> list:
-    """The flash-attention forward kernel's block layouts (see
-    trlx_tpu.ops.flash_attention._fwd)."""
+def flash_block_layout(BH: int, T: int, D: int, blocks) -> list:
+    """The flash-attention kernels' block layouts (see
+    trlx_tpu.ops.flash_attention `_fwd` and `_flash_lse_bwd`; `blocks` is its
+    FlashBlocks: block, major, chunk). A grid step owns a `block` of rows of
+    one side (q, o, dq in the forward and dq; k, v, dk, dv in dk/dv) and
+    holds `major` rows of the other side resident; both sides are [BH, T, D],
+    so the two shapes cover the three kernels. The block side's row vectors
+    (lse, delta, the key bias of dk/dv) are [BH, 1, T] in `block`-wide tiles;
+    the resident side's (the key bias of the forward and dq; lse and delta of
+    dk/dv) are [BH, T / chunk, 1, chunk], so a loop step's row is an index on
+    an untiled dimension and the last two block dims are always full."""
+    block, major, chunk = blocks
     return [
-        BlockLayout("kmask", (1, 1, bk), (BH, 1, T)),
-        BlockLayout("q", (1, bq, D), (BH, T, D)),
-        BlockLayout("k", (1, bk, D), (BH, T, D)),
-        BlockLayout("v", (1, bk, D), (BH, T, D)),
-        BlockLayout("o", (1, bq, D), (BH, T, D)),
-        BlockLayout("lse", (1, 1, bq), (BH, 1, T)),
+        BlockLayout("block side", (1, block, D), (BH, T, D)),
+        BlockLayout("resident side", (1, major, D), (BH, T, D)),
+        BlockLayout("block rows", (1, 1, block), (BH, 1, T)),
+        BlockLayout("resident rows", (1, major // chunk, 1, chunk), (BH, T // chunk, 1, chunk)),
     ]
 
 

@@ -139,10 +139,9 @@ def _ring_flash(q, k, v, kv_mask, *, axis_name: str, n_ring: int, scale: float,
     causality) cost nothing: every k block fails the kernel's offset-aware
     liveness test. Gradients flow through the combine into dlse, which the
     kernel backward folds into its delta term."""
-    from trlx_tpu.ops.flash_attention import flash_attention, pick_block
+    from trlx_tpu.ops.flash_attention import flash_attention
 
     b, t, h, d = q.shape
-    blk = pick_block(t)
     idx = jax.lax.axis_index(axis_name)
     perm = [(j, (j + 1) % n_ring) for j in range(n_ring)]
 
@@ -154,7 +153,7 @@ def _ring_flash(q, k, v, kv_mask, *, axis_name: str, n_ring: int, scale: float,
         offset = ((src - idx) * t).astype(jnp.float32)
         o_c, lse_c = flash_attention(
             q, k_c, v_c, mask_c, scale=scale, causal=causal, window=window,
-            offset=offset, return_lse=True, block_q=blk, block_k=blk,
+            offset=offset, return_lse=True,
         )
         lse_new = jnp.logaddexp(lse, lse_c)
         w_old = jnp.exp(lse - lse_new).transpose(0, 2, 1)[..., None]
@@ -226,9 +225,7 @@ def ring_attention_zigzag(q, k, v, kv_mask, *, axis_name: str, n_ring: int,
     idx = jax.lax.axis_index(axis_name)
     flash_engine = _flash_in_ring_ok(c, use_flash)
     if flash_engine:
-        from trlx_tpu.ops.flash_attention import flash_attention, pick_block
-
-        blk = pick_block(c)
+        from trlx_tpu.ops.flash_attention import flash_attention
 
     cqs = (idx, 2 * n_ring - 1 - idx)  # chunk ids of the local q halves
     q_halves = (q[:, :c], q[:, c:])
@@ -246,7 +243,7 @@ def ring_attention_zigzag(q, k, v, kv_mask, *, axis_name: str, n_ring: int,
                 o_c, lse_c = flash_attention(
                     q_half, k_half, v_half, mask_half, scale=scale, causal=True,
                     window=window, offset=((ck - cq) * c).astype(jnp.float32),
-                    return_lse=True, block_q=blk, block_k=blk,
+                    return_lse=True,
                 )
                 o_c = o_c.astype(jnp.float32).transpose(0, 2, 1, 3)  # → [b,h,c,d]
             else:

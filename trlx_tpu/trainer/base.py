@@ -37,6 +37,7 @@ from transformers import AutoTokenizer
 
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.heads import trainable_mask
+from trlx_tpu.models.lm import flash_kept_pair_share
 from trlx_tpu import observability as obs
 from trlx_tpu.observability import fleet as obs_fleet
 from trlx_tpu.observability import graftscope as obs_graftscope
@@ -193,6 +194,9 @@ class JaxBaseTrainer(BaseRLTrainer):
         self._res_pending = []  # buffered per-step device scalars (no sync)
         self._host_t0 = None  # where time/step_host_ms counts from (learn loop)
         self._step_wait_s = 0.0  # device waits inside a step, outside its stats read
+        # `flash/kept_pair_share` of every step record: a host float from the
+        # train step's shapes, None where its attention takes no flash kernel
+        self._flash_kept_share = flash_kept_pair_share(self.model.cfg, config.train.seq_length)
         # Parallel host-side batch refs for the graftnum nonfinite census:
         # populated ONLY when incident capture is armed (None placeholders
         # otherwise), so default runs keep zero extra references alive.
@@ -1372,6 +1376,8 @@ class JaxBaseTrainer(BaseRLTrainer):
         stats_host["time/step_host_ms"] = max(0.0, read.end_s - self._host_t0 - waited) * 1e3
         self._host_t0, self._step_wait_s = read.end_s, 0.0
         stats_host["obs/compiles"] = obs_spans.take_compiles()
+        if self._flash_kept_share is not None:
+            stats_host["flash/kept_pair_share"] = self._flash_kept_share
         if self._anomaly is not None and self._anomaly.observe(
             stats_host["step_time"]
         ):
