@@ -22,8 +22,7 @@ test-shard1:
 	$(TEST_ENV) python -m pytest tests/ -q -m "not slow" \
 	    && $(TEST_ENV) python -m pytest -q -m slow \
 	        tests/test_flash.py tests/test_ring_attention.py tests/test_generate.py \
-	        tests/test_weight_quant.py tests/test_hf_stream.py \
-	        tests/test_decode_attention.py
+	        tests/test_weight_quant.py tests/test_hf_stream.py
 
 test-shard2:
 	$(TEST_ENV) python -m pytest -q -m slow \

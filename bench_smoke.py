@@ -2,38 +2,32 @@
 
 Probes covering exactly what BENCH_r05 showed CPU CI was blind to:
 
-1. kernel — the flash-decode Pallas kernel runs in INTERPRET mode at the
-   flagship head layout (h=16, d=256) over an int8 KV cache with a ragged
-   cache length, and must match the model layer's dequantize+einsum fallback.
-   Plus the static tile-legality check at the full bench shape (B=32, T=832),
-   which is the part of the Mosaic lowering that CAN be enforced off-TPU.
-
-2. rollout — a tiny bucketed rollout: PromptPipeline with bucket widths
+1. rollout — a tiny bucketed rollout: PromptPipeline with bucket widths
    feeding make_generate_fn, asserting the compiled-program count stays
    <= n_buckets (the trace-count hook) and the decode metrics helper returns
    sane numbers.
 
-3. overlap — a tiny bucketed PPO run with the rollout/train pipeline on
+2. overlap — a tiny bucketed PPO run with the rollout/train pipeline on
    (method.max_staleness=1): the phase windows in metrics.jsonl must carry
    time/overlap_fraction, the stored samples must carry the staleness
    column, and the producer/score-worker threads must be joined by the time
    train() returns.
 
-4. fused_loss — the streaming logprob head: static tile legality at the
+3. fused_loss — the streaming logprob head: static tile legality at the
    FULL bench head shape (N=6656, d=4096, V=50400), interpret-mode parity
    vs the materialized log_softmax chain at the flagship head/vocab layout
    (d=4096, V=50400, N scaled down), gradient parity at a reduced width,
    and a tiny PPO train run with method.pack_train_batch=true whose
    metrics must carry train_tokens_per_s / train_batch_fill.
 
-5. decode_engine — the continuous-batching rollout engine (trlx_tpu/engine)
+4. decode_engine — the continuous-batching rollout engine (trlx_tpu/engine)
    on a mixed-response-length CPU workload where every static chunk carries
    one full-budget straggler: slot decode must match the whole-batch decode
    token for token, keep slot occupancy > 85%, and deliver HIGHER decode
    tokens/s than the static-batch path (the straggler steps the slot refill
    reclaims). Both rates land in BENCH_SMOKE.json.
 
-6. paged_kv — the paged KV cache + prefix caching path (trlx_tpu/engine,
+5. paged_kv — the paged KV cache + prefix caching path (trlx_tpu/engine,
    RUNBOOK §20) on a mixed-length workload whose prompts all open with the
    same 64-token template: the paged engine must match the fixed-slot
    engine token for token (int8 KV on and off), run >= 1.5x the slot count
@@ -41,7 +35,7 @@ Probes covering exactly what BENCH_r05 showed CPU CI was blind to:
    cache_len), and skip the template's prefill on every admission after
    the first (prefix hits + tokens-saved land in BENCH_SMOKE.json).
 
-7. fleet_elastic — elastic N-worker fleet transport throughput
+6. fleet_elastic — elastic N-worker fleet transport throughput
    (trlx_tpu/fleet, RUNBOOK §18): threaded workers with a fixed synthetic
    produce cost drive the real lease ledger + per-worker stream indexes +
    exactly-once intake at 1 worker then 2. Intake must stay exactly-once
@@ -63,49 +57,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(REPO, "BENCH_SMOKE.json")
-
-
-def kernel_probe():
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-
-    from trlx_tpu.models.lm import quantize_kv
-    from trlx_tpu.ops.decode_attention import decode_attention
-    from trlx_tpu.ops.tiling import check_layout, decode_block_layout
-
-    # Static legality at the REAL flagship decode shape (the lowering rule
-    # that used to only fire on device).
-    check_layout(decode_block_layout(32, 832, 16, 256, True))
-    check_layout(decode_block_layout(32, 832, 16, 256, False))
-
-    # Interpret-mode parity at the flagship head layout, batch scaled down
-    # (interpret mode is a Python loop; B=32 would take minutes for no
-    # additional coverage).
-    B, T, h, d = 2, 300, 16, 256  # ragged: T % 128 != 0
-    rng = np.random.default_rng(0)
-    q = rng.normal(size=(B, h, d)).astype(np.float32)
-    k = rng.normal(size=(B, T, h, d)).astype(np.float32)
-    v = rng.normal(size=(B, T, h, d)).astype(np.float32)
-    valid = np.ones((B, T), dtype=bool)
-    valid[0, :7] = False  # left padding
-    bias = np.where(valid, 0.0, -1e9).astype(np.float32)
-
-    kq, ks = quantize_kv(jnp.asarray(k))
-    vq, vs = quantize_kv(jnp.asarray(v))
-    t0 = time.time()
-    out = decode_attention(
-        jnp.asarray(q), kq, vq, ks, vs, jnp.asarray(bias), scale=d ** -0.5, interpret=True
-    )
-    kernel_s = time.time() - t0
-
-    k_dq = kq.astype(jnp.float32) * ks[..., None].astype(jnp.float32)
-    v_dq = vq.astype(jnp.float32) * vs[..., None].astype(jnp.float32)
-    scores = jnp.einsum("bhd,bkhd->bhk", jnp.asarray(q), k_dq) * d ** -0.5 + bias[:, None, :]
-    ref = jnp.einsum("bhk,bkhd->bhd", jax.nn.softmax(scores, axis=-1), v_dq)
-    err = float(jnp.max(jnp.abs(out[:, 0] - ref)))
-    assert err < 2e-4, f"kernel parity failed: maxerr={err}"
-    return {"shape": [B, T, h, d], "maxerr": err, "seconds": round(kernel_s, 2)}
 
 
 def rollout_probe():
@@ -875,7 +826,6 @@ def main():
     )
     result = {}
     for name, probe in (
-        ("kernel", kernel_probe),
         ("rollout", rollout_probe),
         ("overlap", overlap_probe),
         ("fused_loss", fused_loss_probe),

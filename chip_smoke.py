@@ -16,6 +16,9 @@ One process, no network, seeded random weights, no tokenizer. It
    ``trlx_tpu/ops/`` at the GPT-J-6B shapes, and prints one line per kernel:
    compiled, max abs error against the tolerance, the route the model layer
    takes for that shape, and the kernel's and the reference's time per call;
+   then times a decode step's einsum read of an int8 cache inside a loop,
+   whole cache beside the ranged read (the baseline a decode kernel would
+   have to beat: ops/kv_read.py);
 3. runs PPO through ``trlx_tpu.train(reward_fn=..., prompts=<token ids>,
    config=...)`` — default orchestrator, static rollout, fused rollout
    stats — at GPT-J-6B's published widths (d4096, 16 heads x 256, V50400,
@@ -52,12 +55,12 @@ import numpy as np
 FLAGSHIP = dict(
     d_model=4096, n_head=16, vocab=50400, n_layer=8, rotary_dim=64,
     prompt=768, new_tokens=256, chunk=32, batch=8, ppo_epochs=4, unfrozen=2,
-    paged_block=128, n_prompts=64,
+    n_prompts=64,
 )
 REHEARSAL = dict(
     d_model=256, n_head=2, vocab=640, n_layer=2, rotary_dim=32,
     prompt=24, new_tokens=8, chunk=8, batch=4, ppo_epochs=2, unfrozen=1,
-    paged_block=8, n_prompts=16,
+    n_prompts=16,
 )
 SEED = 0
 # Kernel-vs-reference bound, set beforehand from the dtype: both sides read
@@ -224,7 +227,7 @@ def _masks(rng, batch, length):
 
 
 def _time_ranged_read(q, cache, bias, T, scale, steps=64):
-    """What a decode kernel has to beat now: XLA's read inside a loop that
+    """What a decode kernel would have to beat: XLA's read inside a loop that
     carries the int8 cache, as the generate program's does (alone, the same
     read compiles to another program and takes two to three times as long).
     Each step writes the frontier's slot and reads: the whole cache, as before
@@ -270,7 +273,6 @@ def kernel_phase(size, interpret):
     import jax.numpy as jnp
 
     from trlx_tpu.models.lm import LMConfig, flash_eligible, make_attn_bias, quantize_kv
-    from trlx_tpu.ops import decode_attention as da
     from trlx_tpu.ops import fused_logprob as fl
     from trlx_tpu.ops.flash_attention import flash_attention
 
@@ -354,60 +356,11 @@ def kernel_phase(size, interpret):
         BWD_TOL, fused_route))
     del x, w, b, g
 
-    # ---- decode attention: fixed cache and paged pool, plain and int8 ----
-    qd = normal(C, h, d)
-    kc, vc = normal(C, T, h, d), normal(C, T, h, d)
-    bias = jnp.asarray(np.where(_masks(rng, C, T), 0.0, -1e9), jnp.float32)
-    bs = size["paged_block"]
-    bps = T // bs
-    tables = jnp.asarray(rng.permutation(C * bps).reshape(C, bps), jnp.int32)
-    # the same keys and values, scattered into the shared physical pool
-    pool = lambda cache: jnp.zeros((C * bps, bs) + cache.shape[2:], cache.dtype).at[
-        tables.reshape(-1)
-    ].set(cache.reshape((C * bps, bs) + cache.shape[2:]))
-
-    def einsum_decode(q, k, v, ks, vs, bias):
-        if ks is not None:
-            k = k.astype(jnp.float32) * ks[..., None]
-            v = v.astype(jnp.float32) * vs[..., None]
-        s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32), k.astype(jnp.float32))
-        p = jax.nn.softmax(s * scale + bias[:, None, :], axis=-1)
-        return jnp.einsum("bhk,bkhd->bhd", p, v.astype(jnp.float32))[:, None]
-
-    def decode_route(eligible):
-        if eligible:
-            return "pallas decode"
-        return "xla einsum (rule: " + (
-            "DECODE_KERNEL_ROUTED is False" if not da.DECODE_KERNEL_ROUTED else "eligibility"
-        ) + ")"
-
-    for quant in (False, True):
-        if quant:
-            (kq, ks), (vq, vs) = quantize_kv(kc), quantize_kv(vc)
-        else:
-            kq, vq, ks, vs = kc, vc, None, None
-        kind = "int8" if quant else "bf16"
-        verdicts.append(check(
-            f"decode_attention {kind} q [{C},{h},{d}] cache [{C},{T},{h},{d}]",
-            lambda q, k, v, ks, vs, bias: da.decode_attention(
-                q, k, v, ks, vs, bias, scale=scale, interpret=interpret),
-            einsum_decode, (qd, kq, vq, ks, vs, bias), FWD_TOL,
-            decode_route(da.decode_attn_eligible(h, d, T, quant)),
-        ))
-        if quant and not interpret:
-            verdicts.append(_time_ranged_read(qd, (kq, vq, ks, vs), bias, T, scale))
-        pools = tuple(None if a is None else pool(a) for a in (kq, vq, ks, vs))
-        verdicts.append(check(
-            f"paged_decode_attention {kind} {C * bps} blocks x {bs}, {bps} per slot",
-            lambda q, k, v, ks, vs, bias: da.paged_decode_attention(
-                q, k, v, ks, vs, tables, bias, scale=scale, interpret=interpret),
-            lambda q, k, v, ks, vs, bias: einsum_decode(
-                q, *(None if a is None else a[tables].reshape((C, T) + a.shape[2:])
-                     for a in (k, v, ks, vs)), bias),
-            (qd,) + pools + (bias,), FWD_TOL,
-            decode_route(da.paged_decode_eligible(h, d, bs, bps, quant)),
-        ))
-        del pools
+    # ---- a decode step's read: XLA's einsum, whole cache and ranged ------
+    if not interpret:
+        (kq, ks), (vq, vs) = quantize_kv(normal(C, T, h, d)), quantize_kv(normal(C, T, h, d))
+        bias = jnp.asarray(np.where(_masks(rng, C, T), 0.0, -1e9), jnp.float32)
+        verdicts.append(_time_ranged_read(normal(C, h, d), (kq, vq, ks, vs), bias, T, scale))
     return verdicts
 
 
@@ -580,7 +533,7 @@ def route_check(verdicts, traced, size):
     """The kernels inside the real programs are the routes the kernel phase
     printed: flash in the train step (its backward kernels, at the train
     batch) and in prefill (forward at the prompt length over the chunk),
-    the fused log-prob head in the loss, and decode as routed."""
+    the fused log-prob head in the loss."""
     P, T = size["prompt"], size["prompt"] + size["new_tokens"]
     routed = lambda name: any(v["kernel"].startswith(name) and v["route"].startswith("pallas")
                               for v in verdicts)
@@ -589,13 +542,11 @@ def route_check(verdicts, traced, size):
         "flash in train step": has("flash_attention._bwd_dq_kernel", lambda s: s[1] == T),
         "flash in prefill": has("flash_attention._fwd_kernel", lambda s: s[1] == P),
         "fused log-prob in loss": has("fused_logprob._bwd_dx_kernel"),
-        "decode kernel": has("decode_attention._decode_kernel"),
     }
     want = {
         "flash in train step": routed("flash_attention"),
         "flash in prefill": routed("flash_attention"),
         "fused log-prob in loss": routed("fused_logprob"),
-        "decode kernel": routed("decode_attention") or routed("paged_decode_attention"),
     }
     if found != want:
         raise SmokeFailure(f"routes in the real programs {found} != routes printed {want}")

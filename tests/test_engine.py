@@ -729,34 +729,6 @@ def test_slow_step_with_engine_completes_and_captures(task, tmp_path, monkeypatc
     assert not any(t.name.startswith("trlx-") for t in threading.enumerate())
 
 
-# ----------------------------------------------------- slot attention (kernel)
-
-
-@pytest.mark.slow
-def test_slot_decode_attention_interpret_matches_einsum():
-    """slot_decode_attention: the slot-mask → bias-row shim over the
-    flash-decode kernel handles per-slot ragged lengths (interpret mode)."""
-    from trlx_tpu.ops.decode_attention import slot_decode_attention
-
-    rng = np.random.default_rng(0)
-    B, T, h, d = 2, 64, 2, 128
-    q = rng.normal(size=(B, h, d)).astype(np.float32)
-    k = rng.normal(size=(B, T, h, d)).astype(np.float32)
-    v = rng.normal(size=(B, T, h, d)).astype(np.float32)
-    slot_mask = np.zeros((B, T), np.int32)
-    slot_mask[0, :10] = 1  # slot 0: 10 valid positions
-    slot_mask[1, :37] = 1  # slot 1: 37 — ragged vs any block size
-    out = slot_decode_attention(
-        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), None, None,
-        jnp.asarray(slot_mask), scale=0.125, interpret=True,
-    )
-    bias = np.where(slot_mask.astype(bool), 0.0, -1e9).astype(np.float32)
-    scores = np.einsum("bhd,bkhd->bhk", q, k) * 0.125 + bias[:, None, :]
-    probs = jax.nn.softmax(jnp.asarray(scores), axis=-1)
-    ref = np.einsum("bhk,bkhd->bhd", np.asarray(probs), v)
-    np.testing.assert_allclose(np.asarray(out[:, 0]), ref, rtol=2e-5, atol=2e-5)
-
-
 # ------------------------------------------------- slot timeline (graftscope)
 
 

@@ -203,3 +203,105 @@ def test_a_partitioned_mesh_keeps_the_full_read():
         assert kv_keys_read(P + N, P, N, [0, WINDOW]) == (2 * (P + N) * N,) * 2  # and the counter says so
     finally:
         set_mesh(prior)
+
+
+# ----- one decode step against a reference of its own -------------------
+# Cache 300 (128 does not divide it): branches [0,128) [0,256) [0,300), and
+# with the window of 100 the last one starts at 128. Rows are left-padded by
+# different amounts, so their valid lengths are ragged at every frontier.
+STEP_T, STEP_BLOCK, STEP_PAD = 300, 20, (0, 17, 3)
+STEP_FRONTIERS = {"first-bucket": 70, "last-bucket": 290}
+
+
+def _dense_attention_f32(q, k, v, admitted, scale):
+    """Softmax attention in numpy float32 over the admitted keys only:
+    q [b, h, d], k/v [b, T, h, d], admitted [b, T] bool -> [b, h, d]."""
+    out = np.zeros_like(q)
+    for b in range(q.shape[0]):
+        keys = np.flatnonzero(admitted[b])
+        s = np.einsum("hd,khd->hk", q[b], k[b, keys]) * np.float32(scale)
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        out[b] = np.einsum("hk,khd->hd", p / p.sum(axis=-1, keepdims=True), v[b, keys])
+    return out
+
+
+@pytest.mark.parametrize("window", [0, WINDOW], ids=["global", "local"])
+@pytest.mark.parametrize("addressing", ["one-traced-index", "per-row-index", "block-table"])
+@pytest.mark.parametrize("quant", [False, True], ids=["plain-cache", "int8-cache"])
+def test_decode_step_read_matches_dense_float32_attention(quant, addressing, window):
+    """One `Attention` decode step, write then read, by each way a step
+    addresses its cache (the generate loop's one traced index, which takes
+    the ranged read; the engine's per-row index; the engine's block table,
+    with a dead row parked on the trash block), against a float32 softmax
+    attention in numpy over the dequantized keys the bias admits. The frontier
+    sits in the first and in the last bucket of the read."""
+    rng = np.random.default_rng(3)
+    cfg = LMConfig(vocab_size=8, n_layer=1, n_head=2, d_model=32, pos_type="learned", fused_qkv=False,
+                   qkv_bias=False, out_bias=True, kv_cache_quant=quant, dtype="float32")
+    Bs, h, d, T = len(STEP_PAD), cfg.n_head, cfg.head_dim, STEP_T
+    attn = lm.Attention(cfg)
+    x = jnp.asarray(rng.normal(size=(Bs, 1, cfg.d_model)), jnp.float32)
+    f32 = lambda a: np.asarray(a, np.float32)
+
+    # what the cache holds before the step, by virtual slot: [Bs, T, ...]
+    old = [rng.normal(size=(Bs, T, h, d)).astype(np.float32) for _ in range(2)]
+    if quant:
+        old = [np.asarray(a) for kv in old for a in lm.quantize_kv(jnp.asarray(kv))]
+        old = [old[0], old[2], old[1], old[3]]  # (k, v, k_scale, v_scale)
+    paged = addressing == "block-table"
+    if paged:
+        bps = T // STEP_BLOCK
+        tables = 1 + rng.permutation(Bs * bps).reshape(Bs, bps)  # block 0 is the trash block
+        tables[2] = 0  # a freed row, parked on it
+        place = lambda a: np.zeros((1 + Bs * bps, STEP_BLOCK) + a.shape[2:], a.dtype)
+        cache = []
+        for a in old:
+            pool = place(a)
+            pool[tables[:2].reshape(-1)] = a[:2].reshape((2 * bps, STEP_BLOCK) + a.shape[2:])
+            cache.append(pool)
+        virtual = lambda pool: f32(pool)[tables].reshape((Bs, T) + pool.shape[2:])
+    else:
+        tables, cache, virtual = None, old, f32
+    cache = tuple(jnp.asarray(a) for a in cache)
+    params = attn.init(jax.random.PRNGKey(0), x, jnp.zeros((Bs, 1, 1, 1)), None)
+
+    @jax.jit
+    def step(index, cache_mask):
+        bias = make_attn_bias(cache_mask, 1, index, window=window)
+        return attn.apply(params, x, bias, None, cache=cache, cache_index=index, window=window,
+                          block_tables=None if tables is None else jnp.asarray(tables, jnp.int32))
+
+    w = {name: f32(p["kernel"]) for name, p in params["params"].items()}
+    q_ref, k_new, v_new = (f32(x)[:, 0] @ w[n] for n in ("q_proj", "k_proj", "v_proj"))
+    for frontier in STEP_FRONTIERS.values():
+        # per-row frontiers differ (slot decode); the generate loop has one for all rows
+        at = np.full(Bs, frontier) if addressing == "one-traced-index" else frontier - np.array([0, 9, 40])
+        slots = np.arange(T)[None, :]
+        cache_mask = (slots >= np.array(STEP_PAD)[:, None]) & (slots <= at[:, None])
+        live = np.ones(Bs, bool)
+        if paged:
+            cache_mask[2], live[2] = False, False  # the freed row holds nothing
+        index = jnp.int32(frontier) if addressing == "one-traced-index" else jnp.asarray(at, jnp.int32)
+        assert (ranged_read(T, 1, index, window) is not None) == (addressing == "one-traced-index")
+        out, new_cache = step(index, jnp.asarray(cache_mask, jnp.int32))
+
+        # the write: the step's key and value at each live row's frontier, nothing else moved
+        got = [virtual(a) for a in new_cache]
+        k_all, v_all = (got[0], got[1]) if not quant else (got[0] * got[2][..., None], got[1] * got[3][..., None])
+        before = [virtual(a) for a in cache]
+        for b in np.flatnonzero(live):
+            tol = dict(rtol=0, atol=np.abs(k_new).max() / 127) if quant else dict(rtol=1e-6, atol=1e-6)
+            np.testing.assert_allclose(k_all[b, at[b]], k_new[b].reshape(h, d), **tol)
+            np.testing.assert_allclose(v_all[b, at[b]], v_new[b].reshape(h, d), **tol)
+            untouched = np.arange(T) != at[b]
+            for a, a0 in zip(got, before):
+                np.testing.assert_array_equal(a[b, untouched], a0[b, untouched])
+
+        # the read: softmax over the keys the mask, causality and the window admit
+        admitted = cache_mask & (slots > at[:, None] - window if window else True)
+        assert admitted[live].any(axis=1).all() and len(set(cache_mask[live].sum(axis=1))) > 1  # ragged lengths
+        ref = _dense_attention_f32(q_ref.reshape(Bs, h, d)[live], k_all[live], v_all[live], admitted[live],
+                                   1.0 / np.sqrt(d))
+        ref = ref.reshape(-1, cfg.d_model) @ w["c_proj"] + f32(params["params"]["c_proj"]["bias"])
+        np.testing.assert_allclose(f32(out)[live, 0], ref, rtol=2e-5, atol=2e-5)
+        assert np.isfinite(f32(out)).all()

@@ -1,11 +1,10 @@
 """Tier-1 (fast, CPU) static tile-legality tests for the Pallas kernels.
 
 The Mosaic last-two-dims (8, 128)-or-full rule only bites at lowering time
-for a TPU — exactly how the old decode-attention kernel's (1, 1, d)
-blocks survived CPU CI and then crashed BENCH_r05 mid-bench. These tests
-run the rule statically at the REAL bench shapes (B=32, h=16, d=256,
-T=832), so an illegal block mapping in ops/ fails the fast tier without
-any TPU. The rule is the first of Mosaic's checks, not the last:
+for a TPU — exactly how a kernel's (1, 1, d) blocks once survived CPU CI and
+then crashed a bench run mid-way. These tests run the rule statically at the
+REAL bench shapes, so an illegal block mapping in ops/ fails the fast tier
+without any TPU. The rule is the first of Mosaic's checks, not the last:
 tests/test_tpu_lowering.py lowers and compiles the same kernels for a TPU
 target."""
 
@@ -16,14 +15,10 @@ from trlx_tpu.ops.tiling import (
     TileError,
     block_tile_issues,
     check_layout,
-    decode_block_layout,
     flash_block_layout,
-    is_tile_legal,
 )
 
-# The flagship bench decode shape (gptj-l8-d4096-2.0B: chunk 32 rows/host,
-# 16 heads x 256 head_dim, prompt 768 + 64 decoded = 832 cache slots).
-BENCH_B, BENCH_H, BENCH_D, BENCH_T = 32, 16, 256, 832
+BENCH_T = 832  # prompt 768 + 64 new tokens: the sequence of the flagship head shape below
 
 
 def test_rule_basics():
@@ -44,52 +39,6 @@ def test_rule_basics():
     assert not block_tile_issues((7,), (9,))
 
 
-def test_old_decode_specs_are_rejected():
-    """The exact block shapes of the pre-rewrite kernel at the BENCH_r05
-    crash shape — the validator must reject every one of them."""
-    old = [
-        BlockLayout("q", (1, 1, BENCH_D), (BENCH_B, BENCH_H, BENCH_D)),
-        BlockLayout("k_cache", (1, BENCH_T, 1, BENCH_D), (BENCH_B, BENCH_T, BENCH_H, BENCH_D)),
-        BlockLayout("k_scale", (1, BENCH_T, 1), (BENCH_B, BENCH_T, BENCH_H)),
-        BlockLayout("bias", (1, BENCH_T), (BENCH_B, BENCH_T)),
-    ]
-    assert not is_tile_legal(old)
-    # and each operand individually carries a violation the error names
-    for lay in old:
-        issues = block_tile_issues(lay.block_shape, lay.array_shape, lay.name)
-        assert issues, f"{lay.name} should be illegal"
-        assert lay.name in issues[0]
-    with pytest.raises(TileError):
-        check_layout(old)
-
-
-@pytest.mark.parametrize("quant", (True, False))
-def test_new_decode_specs_are_legal_at_bench_shape(quant):
-    layouts = decode_block_layout(BENCH_B, BENCH_T, BENCH_H, BENCH_D, quant)
-    check_layout(layouts)  # raises on violation
-    # the cache streams in lane-dense [bt, n_head * head_dim] blocks and the
-    # q/out rows carry every head
-    by_name = {l.name: l for l in layouts}
-    assert by_name["k_cache"].block_shape == (1, 128, BENCH_H * BENCH_D)
-    assert by_name["q"].block_shape == (1, 1, BENCH_H * BENCH_D)
-    assert by_name["out"].block_shape == (1, 1, BENCH_H * BENCH_D)
-
-
-@pytest.mark.parametrize(
-    "T", (64, 100, 128, 200, 832, 833, 4096)
-)
-def test_decode_specs_legal_for_ragged_cache_lengths(T):
-    """The masked tail removed the cache-length alignment restriction: the
-    layout must stay tile-legal for ANY cache length, aligned or not."""
-    check_layout(decode_block_layout(BENCH_B, T, BENCH_H, BENCH_D, True))
-    check_layout(decode_block_layout(BENCH_B, T, BENCH_H, BENCH_D, False))
-
-
-def test_decode_specs_legal_for_test_model_shapes():
-    """Tiny shapes (CPU test models) are legal too — full blocks everywhere."""
-    check_layout(decode_block_layout(2, 17, 2, 16, True))
-
-
 @pytest.mark.parametrize(
     "BH, T, D",
     [
@@ -108,33 +57,6 @@ def test_flash_specs_legal_at_bench_shape(BH, T, D):
     check_layout(flash_block_layout(BH, T, D, blocks))
     # what the kernels slice inside a step: 128-row chunks of the resident side
     assert blocks.chunk % 128 == 0 and blocks.block % 128 == 0 and blocks.major % blocks.chunk == 0
-
-
-def test_routing_probe_refuses_illegal_layout(monkeypatch):
-    """decode_attn_supported answers False (with a warning, once) when the
-    static layout check fails — a stated CPU-side rule the model layer's
-    einsum route keys off. (What passes the rule must lower on a TPU
-    backend: tests/test_tpu_lowering.py.)"""
-    import warnings
-
-    from trlx_tpu.ops import decode_attention as da
-    from trlx_tpu.ops import tiling
-
-    def bad_layout(B, T, h, d, quant, block_t=None):
-        return [BlockLayout("q", (1, 1, d), (B, h, d))]
-
-    da._PROBE_CACHE.clear()
-    monkeypatch.setattr(tiling, "decode_block_layout", bad_layout)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert not da.decode_attn_supported(4, 64, 4, 128, True)
-        assert any("falling back to the einsum" in str(x.message) for x in w)
-    # cached: the next call must not warn again
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert not da.decode_attn_supported(4, 64, 4, 128, True)
-        assert not w
-    da._PROBE_CACHE.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -199,6 +121,35 @@ def test_fused_probe_refuses_illegal_layout(monkeypatch):
         assert not fl.fused_logprob_supported(256, 128, 1024, False, False)
         assert not w
     fl._PROBE_CACHE.clear()
+
+
+@pytest.mark.parametrize("lowers", [False, True], ids=["does-not-lower", "lowers"])
+def test_fused_probe_on_a_tpu_backend_must_lower(monkeypatch, lowers):
+    """No quiet fallback: on a TPU backend a shape that passes the tile rule
+    is lowered, forward and backward, before the verdict is given. A failure
+    raises KernelLoweringError naming the kernel and the shape, and caches
+    nothing (the next call raises again); a success is cached and not probed
+    twice. The backend here is the CPU under the name "tpu": a Mosaic call
+    cannot lower for it, which is the failure."""
+    import jax
+
+    from trlx_tpu.ops import fused_logprob as fl
+    from trlx_tpu.ops import tiling
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fl, "_PROBE_CACHE", {})
+    probed = []
+    if lowers:
+        monkeypatch.setattr(tiling, "require_lowering", lambda kernel, shape, fn, *args: probed.append((kernel, shape, len(args))))
+        assert fl.fused_logprob_supported(256, 128, 1024, False, True) is True
+        assert fl.fused_logprob_supported(256, 128, 1024, False, True) is True
+        assert probed == [("fused-logprob", "[N=256, D=128, V=1024, tied=False, bias=True]", 4)]
+        assert list(fl._PROBE_CACHE.values()) == [True]
+    else:
+        for _ in range(2):
+            with pytest.raises(tiling.KernelLoweringError, match=r"fused-logprob kernel is eligible for shape \[N=256, D=128, V=1024"):
+                fl.fused_logprob_supported(256, 128, 1024, False, False)
+        assert not fl._PROBE_CACHE
 
 
 def test_fused_logprob_eligibility_is_static():

@@ -3,9 +3,9 @@
 The static tile rule (tests/test_tiling.py) is only the first of Mosaic's
 checks. Twice a kernel passed it, passed its interpret-mode parity tests and
 could not run on the chip: a block mapping the lowering rejected, then a
-batched matrix-vector `dot_general` Mosaic cannot express (the decode
-kernels, three PRs deep, hidden by a probe that caught the exception and
-routed to einsum). Neither needs a chip to find:
+batched matrix-vector `dot_general` Mosaic cannot express (hidden, three PRs
+deep, by a probe that caught the exception and routed to einsum). Neither
+needs a chip to find:
 
 - `jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))` runs the
   jaxpr → Mosaic lowering for a TPU target in seconds on a CPU;
@@ -15,27 +15,26 @@ routed to einsum). Neither needs a chip to find:
   device attached.
 
 Shapes are the GPT-J-6B ones chip_smoke.py runs (flagship train batch,
-decode chunk, logprob head). Run this before spending chip time on a kernel.
+logprob head). Run this before spending chip time on a kernel. The last test
+holds the other end: a kernel in ops/ that no benchmark cell expects is a
+kernel no program runs.
 """
+
+import ast
+import functools
+import glob
+import json
+import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from trlx_tpu.ops.decode_attention import (
-    DECODE_KERNEL_ROUTED,
-    decode_attention,
-    decode_attn_eligible,
-    paged_decode_attention,
-    paged_decode_eligible,
-)
 from trlx_tpu.ops.flash_attention import flash_attention
 from trlx_tpu.ops.fused_logprob import fused_logprob
 
 B, T, H, D = 8, 1024, 16, 256  # train batch, seq, heads x head_dim
-C = 32  # rollout chunk (decode batch)
 N, DM, V = 2056, 4096, 50400  # logprob head: 8 x 257 rows, d_model, vocab
-BLOCKS, BS, BPS = 256, 128, 8  # paged pool
 SCALE = 1.0 / 16.0
 
 
@@ -77,43 +76,18 @@ def _fused(x, w, y, b):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w, b)
 
 
-def _decode(q, k, v, ks, vs, bias):
-    return decode_attention(q, k, v, ks, vs, bias, scale=SCALE, interpret=False)
-
-
-def _paged(q, k, v, ks, vs, tbl, bias):
-    return paged_decode_attention(q, k, v, ks, vs, tbl, bias, scale=SCALE, interpret=False)
-
-
 def _cases(s):
     """(name, fn, abstract args) with `s(shape, dtype)` building each arg."""
-    bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
     qkv = s((B, T, H, D), bf16)
     head = (s((N, DM), bf16), s((DM, V), bf16), s((N,), i32), s((V,), bf16))
-    cases = [
+    return [
         ("flash fwd+bwd", _flash, (qkv, qkv, qkv, s((B, T), f32))),
         ("flash fwd+bwd windowed d128", _flash_windowed, (s((16, 512, H, 128), bf16),) * 3 + (s((16, 512), f32),)),
         ("flash fwd+bwd major pieces, traced offset", _flash_ring_chunk,
          (s((1, 8192, H, D), bf16),) * 3 + (s((1, 8192), f32), s((), f32))),
         ("fused_logprob fwd+bwd", _fused, head),
     ]
-    for quant in (False, True):
-        kind = "int8" if quant else "bf16"
-        # the int8 case at a ragged cache length: the masked tail block
-        Tc = T - 191 if quant else T
-        kv = s((C, Tc, H, D), i8 if quant else bf16)
-        sc = s((C, Tc, H), f32) if quant else None
-        cases.append(
-            (f"decode {kind} T={Tc}", _decode,
-             (s((C, H, D), bf16), kv, kv, sc, sc, s((C, Tc), f32)))
-        )
-        pool = s((BLOCKS, BS, H, D), i8 if quant else bf16)
-        psc = s((BLOCKS, BS, H), f32) if quant else None
-        cases.append(
-            (f"paged decode {kind}", _paged,
-             (s((C, H, D), bf16), pool, pool, psc, psc, s((C, BPS), i32), s((C, BPS * BS), f32)))
-        )
-    return cases
 
 
 CASE_NAMES = [name for name, _, _ in _cases(jax.ShapeDtypeStruct)]
@@ -166,15 +140,6 @@ def test_mosaic_kernels_refuse_a_multi_device_jit(v5e_sharding):
         jax.jit(_flash_fwd).lower(qkv, qkv, qkv, mask)
 
 
-def test_decode_kernels_are_ineligible_by_the_measured_rule():
-    """The decode kernels lower, compile and match einsum, and lose to it on
-    the chip (PERF.md): no shape is routed to them, on any backend."""
-    assert DECODE_KERNEL_ROUTED is False
-    assert not decode_attn_eligible(H, D, T, True)
-    assert not decode_attn_eligible(H, D, T, False)
-    assert not paged_decode_eligible(H, D, BS, BPS, True)
-
-
 def test_one_device_rule(monkeypatch):
     """On a TPU backend the gates open on a one-device mesh and close on a
     larger one; off TPU they are closed."""
@@ -190,3 +155,51 @@ def test_one_device_rule(monkeypatch):
     assert fa.one_device_tpu()
     monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", mesh_mod.make_mesh([4, 1, 1, 1], jax.devices()[:4]))
     assert not fa.one_device_tpu() and not fused_logprob_eligible(DM, V)
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@functools.lru_cache(maxsize=None)
+def _pallas_calls_in_ops():
+    """{"<module>.<kernel function>": [(line, has a name=), ...]} of every
+    `pallas_call` under trlx_tpu/ops/, read from the source: the key is the
+    one the benchmark records at trace time (benchmark/harness.py
+    `record_pallas_calls`) and a cell's `expect_kernels` lists."""
+    calls = {}
+    for path in sorted(glob.glob(os.path.join(REPO, "trlx_tpu", "ops", "*.py"))):
+        module = os.path.basename(path)[:-3]
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        functions = {n.name for n in tree.body if isinstance(n, ast.FunctionDef)}
+        assigned = {n.targets[0].id: n.value for n in ast.walk(tree)
+                    if isinstance(n, ast.Assign) and isinstance(n.targets[0], ast.Name)}
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call) and getattr(node.func, "attr", None) == "pallas_call"):
+                continue
+            kernel = node.args[0]
+            if isinstance(kernel, ast.Name) and kernel.id not in functions:
+                kernel = assigned.get(kernel.id, kernel)  # `kernel = functools.partial(_fwd_kernel, ...)`
+            if isinstance(kernel, ast.Call) and getattr(kernel.func, "attr", "") == "partial":
+                kernel = kernel.args[0]
+            # a kernel this cannot name is a case of its own, and fails below
+            fn = kernel.id if isinstance(kernel, ast.Name) and kernel.id in functions else f"<line {node.lineno}>"
+            named = any(kw.arg == "name" for kw in node.keywords)
+            calls.setdefault(f"{module}.{fn}", []).append((node.lineno, named))
+    return calls
+
+
+@pytest.mark.parametrize("kernel", sorted(_pallas_calls_in_ops()))
+def test_every_pallas_kernel_in_ops_is_expected_by_a_cell(kernel):
+    """A kernel stays in ops/ while a benchmark cell runs it. Every
+    `pallas_call` carries a `name=` (how a device trace finds it), and its
+    kernel is in `expect_kernels` of at least one cell, so the chip run of
+    that cell fails when the route to it closes. A kernel that loses its
+    measurement goes, with its gates; it does not stay switched off."""
+    expected = set()
+    for path in glob.glob(os.path.join(REPO, "benchmark", "workloads", "*.json")):
+        with open(path) as f:
+            expected |= set(json.load(f)["expect_kernels"])
+    unnamed = [line for line, named in _pallas_calls_in_ops()[kernel] if not named]
+    assert not unnamed, f"{kernel}: pallas_call without name= at line(s) {unnamed}"
+    assert kernel in expected, f"{kernel} is in no cell's expect_kernels: {sorted(expected)}"

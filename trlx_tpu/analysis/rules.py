@@ -489,8 +489,7 @@ BASELINE_TRUTHY_FIELDS = frozenset(
         "betas", "two_qs", "n_soft_tokens", "initialize_from_vocab",
         # kv_block_size is a PARAMETER of the paged-KV feature, not its
         # toggle: it is only read when paged_kv (default False) is on, so
-        # the serial path stays byte-identical with it truthy. 128 is the
-        # TPU lane width the paged decode kernel wants (RUNBOOK §20).
+        # the serial path stays byte-identical with it truthy.
         "kv_block_size",
     }
 )
@@ -675,15 +674,10 @@ def check_gl005(module: Module) -> Iterator[Finding]:
 
 TILING_HOME = "ops/tiling.py"
 TILING_FACTORIES = {
-    "decode_block_layout",
-    "slot_decode_layout",
-    "spec_verify_layout",
-    "paged_decode_layout",
     "flash_block_layout",
     "fused_logprob_block_layout",
     "check_layout",
     "block_tile_issues",
-    "is_tile_legal",
 }
 
 

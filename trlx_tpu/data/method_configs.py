@@ -160,11 +160,8 @@ class PPOConfig(MethodConfig):
     # scratch tail lives in each slot's last block). Requires rollout_engine
     # and no soft prompts. Off (default) keeps the engine byte-identical.
     paged_kv: bool = False
-    # kv_block_size: tokens per physical KV block. The TPU flash decode
-    # kernel needs block_size % 128 == 0 (the bias tile constraint,
-    # ops/tiling.py:paged_decode_layout) unless a slot fits in one block;
-    # off-kernel (CPU tests, interpret) any size >= 1 works. 128 keeps the
-    # kernel path on real workloads.
+    # kv_block_size: tokens per physical KV block; any size >= 1 works.
+    # 128 is one lane tile of the bias row and of the scales.
     kv_block_size: int = 128
     # kv_pool_blocks: physical blocks in the shared pool (incl. the reserved
     # trash block 0). 0 = auto: 1 + engine_slots * ceil(cache_len /

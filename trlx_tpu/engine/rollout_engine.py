@@ -11,8 +11,7 @@ is that loop for the rollout side of PPO:
   ``decode_step`` program. Per-slot lengths are pure data: every slot carries
   its own write offset (``write_pos``) and cache-validity row, the model's
   vector ``cache_index`` path scatters each slot's KV at its own offset, and
-  the attention bias/flash-decode kernel already handle ragged cache lengths
-  per row (ops/tiling.slot_decode_layout is the layout contract).
+  the attention bias already handles ragged cache lengths per row.
 - A host-side slot manager admits prompts from a width-grouped queue
   (pipeline.PromptSlotQueue — PR 4's bucketing becomes slot admission) into
   free slots via a batched, jitted prefill (one compiled program per
@@ -255,32 +254,12 @@ class RolloutEngine:
             )
         if spec:
             from trlx_tpu.engine.drafters import make_drafter
-            from trlx_tpu.ops.decode_attention import spec_verify_supported
 
             self.drafter = (
                 drafter
                 if drafter is not None
                 else make_drafter(spec, gen_cfg.pad_token_id)
             )
-            # Layout blessing at arm time (CPU-checkable): the verify
-            # window's block layouts must tile so a future multi-token
-            # kernel port inherits a legal shape — see spec_verify_layout.
-            cfg = model.cfg
-            if not spec_verify_supported(
-                self.n_slots,
-                self.cache_len,
-                cfg.n_head,
-                cfg.d_model // cfg.n_head,
-                self.spec_k,
-                bool(cfg.kv_cache_quant),
-            ):
-                import warnings
-
-                warnings.warn(
-                    f"spec verify layout is not tile-legal at [S={self.n_slots}, "
-                    f"T={self.cache_len}, k={self.spec_k}] — the einsum verify "
-                    "path still runs, but a kernel port would need a new layout"
-                )
             # Host frontier token per slot (the drafter's chaining basis) —
             # refreshed at admit and after every verify sync.
             self._spec_last_tok = np.zeros((self.n_slots,), dtype=np.int64)

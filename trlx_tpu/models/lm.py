@@ -533,30 +533,16 @@ class Attention(nn.Module):
             k = apply_rotary(k, sin, cos, rd, neox)
 
         new_cache = None
-        decode_kernel_kv = None  # set → route this step through the fused
-        # pallas decode-attention kernel (single-token, cache-resident)
         read = None  # set → the einsum read covers a slice of the cache
         if cache is not None:
-            from trlx_tpu.ops.decode_attention import (
-                decode_attn_eligible,
-                decode_attn_supported,
-                paged_decode_eligible,
-                paged_decode_supported,
-            )
-
-            single_step = q_len == 1 and attn_bias is not None
-            vector_index = (
-                cache_index is not None
-                and not isinstance(cache_index, (int, np.integer))
-                and jnp.ndim(cache_index) == 1
-            )
-            # Vector cache_index composes with q_len > 1 (the speculative
-            # verify window): the vmap'd cache_write scatters a [b, k, ...]
-            # update at each row's own frontier, and make_attn_bias builds the
-            # per-row ragged causal bias. Rows whose frontier would run past
-            # the buffer end get their start clamped by dynamic_update_slice —
-            # callers must size the cache with a k-1 scratch tail so live rows
-            # never clamp (see RolloutEngine.cache_len).
+            # WRITE. A per-row (vector) cache_index composes with q_len > 1
+            # (the speculative verify window): the vmap'd write scatters a
+            # [b, k, ...] update at each row's own frontier, and
+            # make_attn_bias builds the per-row ragged causal bias. Rows whose
+            # frontier would run past the buffer end get their start clamped
+            # by dynamic_update_slice — callers must size the cache with a
+            # k-1 scratch tail so live rows never clamp (see
+            # RolloutEngine.cache_len).
             paged = block_tables is not None
             if paged:
                 # Paged KV: the per-layer cache operand is ONE shared block
@@ -567,17 +553,15 @@ class Attention(nn.Module):
                 # t_virt = blocks_per_slot * block_size exactly as over the
                 # fixed buffer — only the physical placement is indirect, so
                 # the write is one advanced-index scatter at (physical block,
-                # in-block offset) and the einsum read gathers the virtual
-                # view back. q_len covers decode (1), spec verify windows
+                # in-block offset) and the read gathers the virtual view
+                # back. q_len covers decode (1), spec verify windows
                 # (spec_k), and suffix prefill (W - hit) uniformly.
-                n_blocks_p = int(cache[0].shape[0])
                 blk = int(cache[0].shape[1])
-                bps = int(block_tables.shape[1])
-                t_virt = bps * blk
+                t_virt = int(block_tables.shape[1]) * blk
                 tbl = block_tables.astype(jnp.int32)
                 base = (
                     cache_index.astype(jnp.int32)[:, None]
-                    if vector_index
+                    if not isinstance(cache_index, (int, np.integer)) and jnp.ndim(cache_index) == 1
                     else jnp.full((b, 1), cache_index, dtype=jnp.int32)
                 )
                 voff = base + jnp.arange(q_len, dtype=jnp.int32)[None, :]
@@ -593,7 +577,7 @@ class Attention(nn.Module):
                     return pool.at[phys, off].set(upd.astype(pool.dtype))
 
                 def gather_virt(pool):
-                    # Virtual-cache view for the einsum path: [b, t_virt, ...].
+                    # The row's virtual cache: [b, t_virt, ...].
                     return pool[tbl].reshape((b, t_virt) + pool.shape[2:])
 
             else:
@@ -602,73 +586,36 @@ class Attention(nn.Module):
                     return write_cache(buf, upd, cache_index)
 
                 def gather_virt(buf):
-                    # Legacy per-slot buffers ARE the virtual cache.
+                    # Per-slot buffers ARE the virtual cache.
                     return buf
 
-            # One traced write offset for the whole batch on a fixed buffer
-            # (a decode step of the static generate path): the einsum read
-            # covers only the slots the bias can admit (ops/kv_read.py). A
-            # per-row index or a block table keeps the full read below.
-            if flash_mask is None and not paged:
-                read = ranged_read(int(cache[0].shape[1]), q_len, cache_index, window)
-
-            def kernel_ok(quant):
-                # Two gates, both static at trace time: the eligibility rule,
-                # then the cached tile check. On a TPU backend a shape that
-                # passes both and still does not lower is an error there,
-                # not an einsum fallback.
-                if paged:
-                    return paged_decode_eligible(
-                        cfg.n_head, hd, blk, bps, quant
-                    ) and paged_decode_supported(
-                        b, n_blocks_p, blk, bps, cfg.n_head, hd, quant, dtype
-                    )
-                return decode_attn_eligible(
-                    cfg.n_head, hd, int(cache[0].shape[1]), quant
-                ) and decode_attn_supported(
-                    int(cache[0].shape[0]),
-                    int(cache[0].shape[1]),
-                    cfg.n_head,
-                    hd,
-                    quant,
-                    dtype,
-                )
-
             if cfg.kv_cache_quant:
-                k_cache, v_cache, ks_cache, vs_cache = cache
-                kq, ks = quantize_kv(k)
-                vq, vs = quantize_kv(v)
-                k_cache = cache_write(k_cache, kq)
-                v_cache = cache_write(v_cache, vq)
-                ks_cache = cache_write(ks_cache, ks)
-                vs_cache = cache_write(vs_cache, vs)
-                new_cache = (k_cache, v_cache, ks_cache, vs_cache)
-                if flash_mask is None:
-                    if single_step and kernel_ok(True):
-                        # Kernel reads the int8 cache directly (dequant is
-                        # folded into the attention algebra) — HBM traffic
-                        # is exactly the int8 bytes.
-                        decode_kernel_kv = (k_cache, v_cache, ks_cache, vs_cache)
-                    elif read is None:
-                        # Dequantize on read for the einsum path (paged:
-                        # gather the virtual view first).
-                        k = gather_virt(k_cache).astype(dtype) * gather_virt(ks_cache)[..., None].astype(dtype)
-                        v = gather_virt(v_cache).astype(dtype) * gather_virt(vs_cache)[..., None].astype(dtype)
+                (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
+                new_cache = tuple(cache_write(c, u) for c, u in zip(cache, (kq, vq, ks, vs)))
             else:
-                k_cache, v_cache = cache
-                k_cache = cache_write(k_cache, k)
-                v_cache = cache_write(v_cache, v)
-                new_cache = (k_cache, v_cache)
-                # Flash prefill attends over the LOCAL block only (cache
-                # slots beyond the prompt are invalid until decode) — k/v
-                # stay local. The einsum paths (decode steps, unaligned
-                # prefill) attend over the cache buffers with the
-                # cache-validity bias.
-                if flash_mask is None:
-                    if single_step and kernel_ok(False):
-                        decode_kernel_kv = (k_cache, v_cache, None, None)
-                    elif read is None:
-                        k, v = gather_virt(k_cache), gather_virt(v_cache)
+                new_cache = tuple(cache_write(c, u) for c, u in zip(cache, (k, v)))
+
+            # READ. Flash prefill attends over the LOCAL block only (cache
+            # slots beyond the prompt are invalid until decode): k, v stay
+            # the block's own. One traced write offset for the whole batch on
+            # a fixed buffer (a decode step of the static generate path): the
+            # ranged read covers only the slots the bias can admit and reads
+            # new_cache itself (ops/kv_read.py). Everything else (a per-row
+            # index, a block table, an unaligned prefill) attends over the
+            # whole virtual cache with the cache-validity bias, dequantized
+            # on read.
+            if flash_mask is None:
+                if not paged:
+                    read = ranged_read(int(cache[0].shape[1]), q_len, cache_index, window)
+                if read is None:
+
+                    def view(i):
+                        x = gather_virt(new_cache[i])
+                        if cfg.kv_cache_quant:
+                            x = x.astype(dtype) * gather_virt(new_cache[i + 2])[..., None].astype(dtype)
+                        return x
+
+                    k, v = view(0), view(1)
 
         scale = 1.0 / np.sqrt(hd) if cfg.scale_attn else 1.0
         if flash_mask is not None:
@@ -682,26 +629,6 @@ class Attention(nn.Module):
                 from trlx_tpu.ops.flash_attention import flash_attention
 
                 out = flash_attention(q, k, v, flash_mask, scale=scale, causal=True, window=window).astype(dtype)
-        elif decode_kernel_kv is not None:
-            from trlx_tpu.ops.decode_attention import (
-                decode_attention,
-                paged_decode_attention,
-            )
-
-            kc, vc, ksc, vsc = decode_kernel_kv
-            # attn_bias is [b, 1, 1, kv] on a single-token step; the kernel
-            # takes the one bias row (causality + validity + local window
-            # are all already encoded in it).
-            if block_tables is not None:
-                out = paged_decode_attention(
-                    q[:, 0], kc, vc, ksc, vsc,
-                    block_tables.astype(jnp.int32), attn_bias[:, 0, 0, :],
-                    scale=scale,
-                ).astype(dtype)
-            else:
-                out = decode_attention(
-                    q[:, 0], kc, vc, ksc, vsc, attn_bias[:, 0, 0, :], scale=scale
-                ).astype(dtype)
         elif read is not None:
             out = read(q, new_cache, attn_bias, scale, dtype)
         else:

@@ -30,11 +30,10 @@ Capture cost and safety:
   closures keep their public surface (``make_generate_fn``'s ``num_traces``
   / ``traced_shapes`` counters remain visible through the proxy).
 
-Routing gauges (``kernel_routing_gauges``) read the Pallas kernels' probe
-caches (ops/decode_attention.py, ops/fused_logprob.py): a probe entry that
-is False means the kernel was ELIGIBLE but its lowering failed — the silent
-einsum/log_softmax fallback this PR makes visible in metrics.jsonl within
-one window instead of only as a one-time stderr warning.
+Routing gauges (``kernel_routing_gauges``) read the fused log-prob head's
+probe cache (ops/fused_logprob.py): an entry that is False means an ELIGIBLE
+shape was refused by the static tile check and took log_softmax — visible in
+metrics.jsonl within one window, not only as a one-time stderr warning.
 """
 
 import json
@@ -297,30 +296,19 @@ class DeviceMonitor:
 
 
 def kernel_routing_gauges() -> dict:
-    """Live kernel-routing state from the Pallas probe caches.
+    """Live kernel-routing state from the fused log-prob head's probe cache.
 
     - ``*_active``: 1.0 when at least one shape probed OK (the kernel is
       actually serving dispatches);
-    - ``*_fallback``: 1.0 when at least one ELIGIBLE shape failed its
-      lowering probe — the silent-fallback condition that used to be one
-      stderr warning, now a gauge a dashboard can alarm on."""
-    from trlx_tpu.ops import decode_attention as da
+    - ``*_fallback``: 1.0 when at least one ELIGIBLE shape was refused by
+      its probe — the silent-fallback condition that used to be one stderr
+      warning, now a gauge a dashboard can alarm on."""
     from trlx_tpu.ops import fused_logprob as fl
 
-    def pair(cache):
-        values = list(cache.values())
-        return (
-            1.0 if any(values) else 0.0,
-            1.0 if any(not ok for ok in values) else 0.0,
-        )
-
-    da_active, da_fallback = pair(da._PROBE_CACHE)
-    fl_active, fl_fallback = pair(fl._PROBE_CACHE)
+    values = list(fl._PROBE_CACHE.values())
     return {
-        "obs/decode_attn_active": da_active,
-        "obs/decode_attn_fallback": da_fallback,
-        "obs/fused_logprob_active": fl_active,
-        "obs/fused_logprob_fallback": fl_fallback,
+        "obs/fused_logprob_active": 1.0 if any(values) else 0.0,
+        "obs/fused_logprob_fallback": 1.0 if any(not ok for ok in values) else 0.0,
     }
 
 

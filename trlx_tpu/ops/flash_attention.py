@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from trlx_tpu.parallel.mesh import partitioned
+
 M_INIT = -1e30  # running-max init (finite: fully-masked rows degrade to
 # uniform attention exactly like the XLA path's -1e9 bias)
 MASK_VAL = -1e9
@@ -54,19 +56,14 @@ def _interpret_default() -> bool:
 
 def one_device_tpu() -> bool:
     """The rule every model-layer kernel gate in ops/ shares: a TPU backend
-    and a one-device mesh. No pallas_call here is under shard_map, and a jit
-    over more than one device refuses to lower one ("Mosaic kernels cannot
-    be automatically partitioned. Please wrap the call in a shard_map",
-    jax 0.9) — so on a mesh larger than one the XLA paths stand until the
+    and a mesh that is not `partitioned()`. No pallas_call here is under
+    shard_map, and a jit over more than one device refuses to lower one
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap the
+    call in a shard_map", jax 0.9) — so there the XLA paths stand until the
     kernels are wrapped (ROADMAP A6). Ring attention calls the flash kernel
     per shard inside its own shard_map and gates on ``auto_flash_ok``
     alone."""
-    if jax.default_backend() != "tpu":
-        return False
-    from trlx_tpu.parallel.mesh import peek_mesh
-
-    mesh = peek_mesh()
-    return mesh is None or mesh.size == 1
+    return jax.default_backend() == "tpu" and not partitioned()
 
 
 class FlashBlocks(NamedTuple):
@@ -186,8 +183,8 @@ def _compiler_params(interpret, semantics=("parallel", "parallel", "arbitrary"),
     """Mark the (bh, block) grid dims parallel so Mosaic pipelines across
     grid steps instead of serializing them; only the innermost dim (the walk
     over `major` pieces, one step where the sequence is resident) is
-    order-dependent. The decode and fused-logprob kernels pass their own
-    two-dim semantics. `vmem_limit_bytes` lifts the compiler's scoped-VMEM
+    order-dependent. The fused-logprob kernels pass their own two-dim
+    semantics. `vmem_limit_bytes` lifts the compiler's scoped-VMEM
     limit (16 MiB) for a kernel whose blocks need more; None leaves the
     default."""
     if interpret:

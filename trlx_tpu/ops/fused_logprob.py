@@ -30,7 +30,7 @@ the N axis innermost), so the backward never materializes [N, V] either.
 Grid (N-blocks, V-blocks) with the V walk sequential ("arbitrary" — it is
 the online-softmax accumulation order); the weight streams in bv-wide tiles
 (128-divisible, so ragged GPT-2/J vocab sizes get a partial tail block that
-is masked in-kernel, exactly like the flash-decode T tail). Block layouts
+is masked in-kernel). Block layouts
 live in tiling.fused_logprob_block_layout — the validator and this wrapper
 read the SAME description, and the routing gate (fused_logprob_supported)
 re-checks it before the model layer ever traces the kernel: a tile-illegal
@@ -38,7 +38,7 @@ shape takes the materialized log_softmax path by that stated rule, and a
 shape that passes must lower on a TPU backend or the run stops with an
 error naming the kernel and the shape.
 
-Engagement mirrors flash/decode attention: real TPU backend (or explicit
+Engagement mirrors flash attention: real TPU backend (or explicit
 interpret mode for CPU CI parity tests, tests/test_losses.py); tiny test
 models stay on the einsum fallback where they are faster.
 """
@@ -203,8 +203,8 @@ def _bwd_dx_kernel(*refs, V, bv, tied, has_bias):
                   dlp_ref, dlse_ref, dent_ref, j, V=V, bv=bv, tied=tied)
     # The dx contraction runs over the vocab tile axis, so the tail block's
     # padding columns are contracted INTO the result: ds is 0 there, but the
-    # weight padding is undefined memory (0 · NaN poisons the accumulator —
-    # same hazard as the decode kernel's tail v rows). Zero them explicitly.
+    # weight padding is undefined memory (0 · NaN poisons the accumulator).
+    # Zero them explicitly.
     w = w_ref[...]
     vocab_axis = 0 if tied else 1
     tail_valid = (
@@ -493,13 +493,32 @@ _PROBE_CACHE = {}
 
 def fused_logprob_supported(N: int, D: int, V: int, tied: bool,
                             has_bias: bool, dtype=jnp.bfloat16) -> bool:
-    """Cached verdict for a call-site shape (tiling.routing_verdict, the
-    same two stages as decode_attn_supported): the CPU-runnable static tile
-    check over the real block layouts may refuse the shape; a shape that
-    passes it must, on a TPU backend, lower forward AND backward."""
-    from trlx_tpu.ops.tiling import fused_logprob_block_layout, routing_verdict
+    """Cached verdict for a call-site shape whose static eligibility rule
+    already passed (`_PROBE_CACHE`; devicemon's routing gauges read it). The
+    CPU-runnable tile check over the kernel's real block layouts may refuse
+    the shape: a stated rule, warned once, answered False, and the caller
+    takes log_softmax. A shape that passes must, on a TPU backend, lower
+    forward AND backward, or `require_lowering` raises naming it."""
+    import warnings
 
-    def lower():
+    from trlx_tpu.ops import tiling
+
+    key = (N, D, V, bool(tied), bool(has_bias), jnp.dtype(dtype).name, jax.default_backend())
+    hit = _PROBE_CACHE.get(key)
+    if hit is not None:
+        return hit
+    shape = f"[N={N}, D={D}, V={V}, tied={tied}, bias={has_bias}]"
+    Np = -(-N // BLOCK_N) * BLOCK_N
+    issues = tiling.layout_issues(
+        tiling.fused_logprob_block_layout(Np, D, V, BLOCK_N, pick_v_block(V), tied, has_bias)
+    )
+    if issues:
+        warnings.warn(
+            f"fused-logprob kernel refused for shape {shape} by the static tile "
+            f"check — falling back to the log_softmax path "
+            f"({'; '.join(issues)[:300]})"
+        )
+    elif jax.default_backend() == "tpu":
         s = jax.ShapeDtypeStruct
         args = [s((N, D), dtype), s((V, D) if tied else (D, V), dtype),
                 s((N,), jnp.int32)]
@@ -515,19 +534,9 @@ def fused_logprob_supported(N: int, D: int, V: int, tied: bool,
 
             return jax.grad(f, argnums=tuple(range(2 + len(b))))(x, w, *b)
 
-        return (probe, *args)
-
-    Np = -(-N // BLOCK_N) * BLOCK_N
-    return routing_verdict(
-        _PROBE_CACHE,
-        (N, D, V, bool(tied), bool(has_bias), jnp.dtype(dtype).name,
-         jax.default_backend()),
-        "fused-logprob",
-        f"[N={N}, D={D}, V={V}, tied={tied}, bias={has_bias}]",
-        fused_logprob_block_layout(Np, D, V, BLOCK_N, pick_v_block(V), tied, has_bias),
-        "log_softmax",
-        lower,
-    )
+        tiling.require_lowering("fused-logprob", shape, probe, *args)
+    _PROBE_CACHE[key] = not issues
+    return _PROBE_CACHE[key]
 
 
 def routed_logprob(x, w, labels, bias=None, *, tied=False, mode="auto", mask=None):

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from trlx_tpu.parallel.mesh import peek_mesh
+from trlx_tpu.parallel.mesh import partitioned
 
 # The bucket is a function of the cache length alone: 128 keys (one lane tile
 # of the bias row and of the scales, so every slice edge is tile-aligned),
@@ -109,14 +109,6 @@ def attend_latent_range(q, cache, attn_bias, lo: int, hi: int, scale, dtype):
     return attend_latent(*q, cut(cache[0]), cut(cache[1]), jax.lax.slice_in_dim(attn_bias, lo, hi, axis=3), scale, dtype)
 
 
-def _partitioned() -> bool:
-    """A mesh of more than one device keeps the full read: the layout request
-    in `ranged_read` is a custom call, and GSPMD replicates what it cannot
-    partition, so every branch would all-gather the cache."""
-    mesh = peek_mesh()
-    return mesh is not None and mesh.size > 1
-
-
 def _read_branch(q, cache, attn_bias, *, lo, hi, scale, dtype, attend_range, slot_major):
     # A conditional's operands take the default, batch-major layout, and with
     # them the cache the loop carries. Outside a conditional XLA keeps it
@@ -140,13 +132,15 @@ def ranged_read(cache_len: int, q_len: int, cache_index, window: int = 0, *,
     are read-only and its result is `[b, 1, h, d]`, so no branch returns (or
     copies) a cache. None where the caller keeps its full read: more than one
     query token, a per-row (vector) offset, a cache of a single branch, or a
-    mesh of more than one device. `attend_range` is the read of one branch
+    `partitioned()` mesh (the layout request in `_read_branch` is a custom
+    call, and GSPMD replicates what it cannot partition, so every branch
+    would all-gather the cache). `attend_range` is the read of one branch
     (`attend_range`: per-head K and V; `attend_latent_range`: the latent
     cache), `slot_major` whether the branch asks for the slot-major layout.
     """
     scalar = not isinstance(cache_index, (int, np.integer)) and jnp.ndim(cache_index) == 0
     ranges = kv_read_ranges(cache_len, window)
-    if q_len != 1 or not scalar or len(ranges) == 1 or _partitioned():
+    if q_len != 1 or not scalar or len(ranges) == 1 or partitioned():
         return None
     bucket = kv_read_bucket(cache_len)
 
@@ -170,7 +164,7 @@ def kv_keys_read(
     `first_index + 1`, ... Counted on the host from shapes alone; their ratio
     over a rollout phase is the counter `rollout/kv_read_share`."""
     full = cache_len * steps * len(windows)
-    if _partitioned():
+    if partitioned():
         return full, full
     branch = (first_index + np.arange(steps)) // kv_read_bucket(cache_len)
     read = 0

@@ -116,6 +116,15 @@ def peek_mesh() -> Optional[Mesh]:
     return _GLOBAL_MESH
 
 
+def partitioned() -> bool:
+    """A process mesh of more than one device: the one rule by which the
+    routes in ops/ that cannot be partitioned stand back (the Pallas kernels,
+    `flash_attention.one_device_tpu`; the ranged cache read,
+    `kv_read.ranged_read`). It reads the process-global mesh, not the arrays
+    a call is given (ROADMAP: routing reads a module global)."""
+    return _GLOBAL_MESH is not None and _GLOBAL_MESH.size > 1
+
+
 def get_mesh(shape: Sequence[int] = (-1, 1, 1, 1)) -> Mesh:
     """Return the process-global mesh, creating it on first use."""
     global _GLOBAL_MESH
