@@ -2,6 +2,9 @@
 reference cannot test at all (its distributed path is exercised only by
 manual `accelerate launch`, SURVEY.md §4)."""
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -281,3 +284,242 @@ def test_tiny_ppo_on_fsdp4_matches_one_device(fsdp4_and_one_device, what):
         assert one["primitives"]["train_step"]["pallas_call"] > 0
         assert mesh["primitives"] == {"generate": {"pallas_call": 0, "cond": 0},
                                       "train_step": {"pallas_call": 0, "cond": 0}}
+
+
+# ----- the ZeRO-3 schedule the program states on a partitioned mesh
+# (trlx_tpu/parallel/schedule.py): a pass over many tokens gathers each
+# kernel at its point of use and leaves the rows where the batch split put
+# them; a decode step keeps the shards. Read off the compiled HLO of tiny
+# models on forced CPU devices, whose SPMD partitioner is the chip's.
+ZERO3_B, ZERO3_P, ZERO3_R = 8, 16, 16  # global batch 8: no other dimension of these models is 8
+_ZERO3_COMMON = {"vocab_size": 512, "n_layer": 3, "n_head": 2, "d_model": 128, "max_position": 64, "eos_token_id": 0}
+ZERO3_ARCHS = {
+    "gptj": {**_ZERO3_COMMON, "pos_type": "rotary", "rotary_dim": 32, "parallel_residual": True,
+             "fused_qkv": False, "qkv_bias": False, "out_bias": False, "tie_word_embeddings": False,
+             "extra": {"lm_head_bias": True}},
+    "gptneo": {**_ZERO3_COMMON, "pos_type": "learned", "fused_qkv": False, "qkv_bias": False, "scale_attn": False,
+               "attention_layers": ["global", "local", "global"], "window_size": 12, "tie_word_embeddings": True},
+    "mla-experts": {**_ZERO3_COMMON, "pos_type": "rotary", "norm": "rmsnorm", "mlp": "gated", "attention": "mla",
+                    "activation": "silu", "tie_word_embeddings": False, "d_ff": 256,
+                    "ffn_layers": ["dense", "experts", "experts"], "q_lora_rank": 64, "kv_lora_rank": 64,
+                    "qk_nope_head_dim": 32, "qk_rope_head_dim": 16, "v_head_dim": 32, "n_experts": 16,
+                    "experts_per_token": 2, "expert_d_ff": 128, "n_shared_experts": 1, "experts_held": [0, 4]},
+}
+
+
+def _hlo_shapes(text, op):
+    """Result shapes (as [dims] tuples) of every `op` instruction of an HLO
+    text, the elements of a tuple result each."""
+    shapes = []
+    for line in text.splitlines():
+        head, sep, _ = line.partition(f" {op}(")
+        if not sep:
+            head, sep, _ = line.partition(f" {op}-start(")
+        if sep and " = " in head:
+            shapes += [tuple(int(d) for d in dims.split(",") if d)
+                       for dims in re.findall(r"\w+\[([\d,]*)\]", head.split(" = ", 1)[1])]
+    return shapes
+
+
+@functools.lru_cache(maxsize=None)
+def _zero3_programs(arch_name, mesh_shape):
+    """The compiled train step and generate program of a tiny PPO model over
+    `mesh_shape`, from abstract parameters (nothing runs): their HLO texts,
+    what `use_weight` counted while each was traced, and the kernels' shapes."""
+    from functools import partial
+
+    from jax.sharding import NamedSharding
+
+    from trlx_tpu.data import PPORLBatch
+    from trlx_tpu.models.heads import extract_branch_params, trainable_mask
+    from trlx_tpu.models.hf_import import build_lm_config
+    from trlx_tpu.ops.generate import generate
+    from trlx_tpu.ops.sampling import GenerateConfig
+    from trlx_tpu.parallel.mesh import DATA_AXES, peek_mesh, set_mesh
+    from trlx_tpu.parallel.schedule import count_weight_gathers, use_spec
+    from trlx_tpu.parallel.sharding import sanitize_specs, specs_to_shardings
+    from trlx_tpu.trainer.api import default_config
+    from trlx_tpu.trainer.base import TrainState, build_optimizer
+    from trlx_tpu.trainer.ppo import make_ppo_train_step
+
+    B, P_, R = ZERO3_B, ZERO3_P, ZERO3_R
+    config = default_config("ppo")
+    config.model.model_path = config.model.tokenizer_path = ""
+    config.model.num_layers_unfrozen, config.model.dtype, config.model.remat = 1, "float32", True
+    config.model.model_arch = dict(ZERO3_ARCHS[arch_name])
+    config.train.mesh, config.train.batch_size, config.train.seq_length = mesh_shape, B, P_ + R
+    prior = peek_mesh()
+    mesh = make_mesh(mesh_shape, devices=jax.devices()[: int(np.prod(mesh_shape))])
+    set_mesh(mesh)
+    try:
+        lm_cfg = build_lm_config(config).replace(onehot_embed=True)  # as the trainer does on a mesh
+        model = LMWithValueHead(lm_cfg, branch_layer=lm_cfg.n_layer - 1)
+        one = jnp.zeros((1, 2), jnp.int32)
+        params = jax.eval_shape(lambda r: model.init(r, one, jnp.ones_like(one))["params"], jax.random.PRNGKey(0))
+        trainable = trainable_mask(params, lm_cfg, 1)
+        optimizer, schedule = build_optimizer(config.train, trainable)
+        detach = lambda p: jax.tree_util.tree_map(lambda x, t: x if t else jax.lax.stop_gradient(x), p, trainable)
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+        state = TrainState(step=i32(), params=params, opt_state=jax.eval_shape(optimizer.init, params),
+                           extras=jax.eval_shape(lambda p: extract_branch_params(p, lm_cfg, model.branch_layer), params),
+                           bad_steps=i32())
+        batch = PPORLBatch(query_tensors=i32(B, P_), query_mask=i32(B, P_), response_tensors=i32(B, R),
+                           response_mask=i32(B, R), logprobs=f32(B, R), values=f32(B, R), rewards=f32(B, R))
+        placed = lambda tree, shardings: jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), tree, shardings)
+        by_rules = lambda tree: specs_to_shardings(
+            mesh, sanitize_specs(mesh, tree, match_partition_rules(lm_partition_rules(), tree)))
+        rows = NamedSharding(mesh, P(DATA_AXES, None))
+        out = {"train_tally": {}, "generate_tally": {}}
+        with count_weight_gathers(out["train_tally"]):
+            step = make_ppo_train_step(model, optimizer, config, P_, schedule, detach)
+            out["train"] = step.lower(
+                placed(state, by_rules(state)), placed(batch, jax.tree_util.tree_map(lambda _: rows, batch))
+            ).compile().as_text()
+        gcfg = GenerateConfig(max_new_tokens=R, do_sample=False, eos_token_id=None, pad_token_id=0)
+        with count_weight_gathers(out["generate_tally"]):
+            out["generate"] = jax.jit(partial(generate, model=model, gcfg=gcfg)).lower(
+                {"params": placed(params, by_rules(params))}, placed(i32(B, P_), rows), placed(i32(B, P_), rows),
+                jax.ShapeDtypeStruct((2,), jnp.uint32),
+            ).compile().as_text()
+        # every leaf the partition rules split over fsdp: its full shape, and
+        # whether it trains
+        flat = jax.tree_util.tree_flatten_with_path(params)[0]
+        masks = jax.tree_util.tree_leaves(trainable)
+        out["split"] = {}
+        for (path, leaf), trains in zip(flat, masks):
+            name = "/".join(str(k.key) for k in path)
+            stored, at_use = use_spec(name, leaf.shape)
+            if stored != at_use:
+                out["split"][name] = (tuple(leaf.shape), bool(trains), at_use)
+        return out
+    finally:
+        set_mesh(prior)
+
+
+def _computation(text, name):
+    """The lines of the HLO computation `name`."""
+    lines = text.splitlines()
+    start = next(i for i, l in enumerate(lines) if l.lstrip("ENTRY ").startswith(f"%{name} ") and l.rstrip().endswith("{"))
+    return lines[start: next(i for i in range(start, len(lines)) if lines[i].startswith("}"))]
+
+
+@pytest.mark.parametrize("what", ["rows-stay", "every-kernel-gathers", "only-trainable-gradients-reduce"])
+@pytest.mark.parametrize("arch", sorted(ZERO3_ARCHS))
+def test_zero3_train_step_on_fsdp4(arch, what):
+    """Over fsdp 4 the train step of a GPT-J-shaped, a GPT-Neo-shaped and an
+    MLA + expert model moves weights and never rows: no all-to-all, no
+    collective-permute, no array whose leading dimensions are the GLOBAL
+    batch and a sequence; an all-gather for every kernel the rules split over
+    fsdp (and one more each in the remat'd backward); weight gradients
+    reduced for the leaves that train and no others."""
+    from trlx_tpu.parallel.schedule import weight_gather_share
+
+    got = _zero3_programs(arch, (1, 4, 1, 1))
+    text, split = got["train"], got["split"]
+    if what == "rows-stay":
+        assert not _hlo_shapes(text, "all-to-all") and not _hlo_shapes(text, "collective-permute")
+        global_rows = [s for s in re.findall(r"\w+\[([\d,]+)\]", text)
+                       if s.startswith(f"{ZERO3_B},") and int(s.split(",")[1]) >= ZERO3_R - 1]
+        assert not global_rows, sorted(set(global_rows))
+    elif what == "every-kernel-gathers":
+        assert weight_gather_share(got["train_tally"]) == 1.0
+        assert {path for path, _ in got["train_tally"]} == set(split)
+        gathered = _hlo_shapes(text, "all-gather")
+        for name, (shape, _, _) in split.items():
+            assert shape in gathered or shape[::-1] in gathered, (name, shape)
+        assert len(gathered) >= len(split)
+    else:
+        # By elements, not by instruction: the partitioner reduces an expert
+        # stack an expert at a time and a latent projection in its per-head
+        # shape. The CPU backend writes a reduce-scatter as all-reduce + slice.
+        big = lambda shape: int(np.prod(shape)) >= 64 * 64  # a kernel, not a bias, a norm or a loss scalar
+        reduced = sum(int(np.prod(s)) for s in _hlo_shapes(text, "all-reduce") if big(s))
+        reduced += sum(4 * int(np.prod(s)) for s in _hlo_shapes(text, "reduce-scatter") if big(s))
+        expected = sum(int(np.prod(shape)) for shape, trains, _ in split.values() if trains and big(shape))
+        if ZERO3_ARCHS[arch]["tie_word_embeddings"]:
+            expected += int(np.prod(split["transformer/wte/embedding"][0]))  # used twice (lookup, head): reduced twice
+        assert reduced == expected
+
+
+@pytest.mark.parametrize("arch", sorted(ZERO3_ARCHS))
+def test_zero3_decode_step_keeps_its_shards(arch):
+    """The generate program over fsdp 4: the prefill (128 tokens) gathers,
+    the decode loop (8 tokens a step) gathers no kernel, so the program's
+    share of gathered weight bytes is the prefill's part only."""
+    from trlx_tpu.parallel.schedule import weight_gather_share
+
+    got = _zero3_programs(arch, (1, 4, 1, 1))
+    text = got["generate"]
+    kept = {path for (path, gathered) in got["generate_tally"] if not gathered}
+    assert kept and 0.0 < weight_gather_share(got["generate_tally"]) < 1.0
+    kernel_shapes = {s for shape, _, _ in got["split"].values() for s in (shape, shape[::-1])}
+    body = re.search(r" while\(.*body=%([\w.\-]+)", text).group(1)
+    assert not set(_hlo_shapes("\n".join(_computation(text, body)), "all-gather")) & kernel_shapes
+    assert set(_hlo_shapes(text, "all-gather")) & kernel_shapes  # the prefill's
+
+
+def test_zero3_keeps_the_tp_split_at_use():
+    """fsdp 2 x tp 2: only the fsdp axis is dropped at a kernel's point of
+    use. Every split kernel's spec at use still names tp, and no all-gather
+    of the train step produces a whole kernel."""
+    got = _zero3_programs("gptj", (1, 2, 2, 1))
+    on_tp = {name: at_use for name, (_, _, at_use) in got["split"].items() if "tp" in jax.tree_util.tree_leaves(tuple(at_use))}
+    assert len(on_tp) == len(got["split"]) and all("fsdp" not in str(spec) for spec in on_tp.values())
+    # the 512-wide kernels (c_fc, mlp c_proj, the embedding, the head) arrive
+    # 256 wide: no all-gather of the train step produces one whole
+    gathered = set(_hlo_shapes(got["train"], "all-gather"))
+    assert {(128, 256), (256, 128)} <= gathered and not any(512 in s for s in gathered)
+    assert not _hlo_shapes(got["train"], "all-to-all")
+
+
+def test_the_size_rule_of_a_weight_gather():
+    """`weights_travel` at GPT-J's widths over fsdp 4: break-even at 2,048
+    tokens for a square projection and 3,277 for the MLP's; the cell's train
+    step, prefill and scoring pass gather, its decode step does not; nothing
+    travels without an fsdp axis."""
+    from trlx_tpu.parallel.mesh import peek_mesh, set_mesh
+    from trlx_tpu.parallel.schedule import weights_travel
+
+    prior = peek_mesh()
+    try:
+        set_mesh(make_mesh((1, 4, 1, 1), devices=jax.devices()[:4]))
+        assert not weights_travel(2048, (4096, 4096)) and weights_travel(2049, (4096, 4096))
+        assert not weights_travel(3276, (4096, 16384)) and weights_travel(3277, (4096, 16384))
+        for tokens, travels in ((8 * 1024, True), (32 * 768, True), (32 * 1024, True), (32, False), (32 * 4, False)):
+            assert all(weights_travel(tokens, s) == travels for s in ((4096, 4096), (4096, 16384), (16384, 4096), (4096, 50400)))
+        assert weights_travel(8192, (8, 7168, 2048)) and not weights_travel(32, (8, 7168, 2048))  # an expert stack: by one expert's widths
+        set_mesh(make_mesh((4, 1, 1, 1), devices=jax.devices()[:4]))
+        assert not weights_travel(8192, (4096, 4096))
+        set_mesh(None)
+        assert not weights_travel(8192, (4096, 4096))
+    finally:
+        set_mesh(prior)
+
+
+def test_schedule_helpers_return_their_argument_without_a_partitioned_mesh():
+    """No mesh, or a mesh of one device: `use_weight` and `hold_rows` are the
+    identity and the traced program holds no constraint and no barrier; over
+    fsdp 4 the same function traces to both."""
+    from trlx_tpu.parallel.mesh import peek_mesh, set_mesh
+    from trlx_tpu.parallel.schedule import hold_rows, use_weight
+
+    cfg = LMConfig(vocab_size=64, n_layer=2, n_head=2, d_model=64, max_position=32, dtype="float32", remat=True)
+    model = LMWithValueHead(cfg)
+    ids, mask = jnp.zeros((4, 32), jnp.int32), jnp.ones((4, 32), jnp.int32)
+    params = jax.eval_shape(lambda r: model.init(r, ids, mask)["params"], jax.random.PRNGKey(0))
+    loss = lambda p: jnp.sum(model.apply({"params": p}, ids, mask)["logits"])
+    w, x = jnp.ones((64, 256)), jnp.ones((4, 32, 64))
+    prior = peek_mesh()
+    try:
+        for mesh in (None, make_mesh((1, 1, 1, 1), devices=jax.devices()[:1])):
+            set_mesh(mesh)
+            assert use_weight(w, ("h_0", "mlp", "c_fc", "kernel"), 4096) is w and hold_rows(x) is x
+            jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+            assert _count_primitive(jaxpr, "sharding_constraint") == 0
+            assert _count_primitive(jaxpr, "optimization_barrier") == 0
+        set_mesh(make_mesh((1, 4, 1, 1), devices=jax.devices()[:4]))
+        assert _count_primitive(jax.make_jaxpr(jax.grad(loss))(params).jaxpr, "sharding_constraint") > 0
+    finally:
+        set_mesh(prior)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from trlx_tpu.models.lm import LMConfig, TransformerLM
+from trlx_tpu.parallel.schedule import gathering_dot_general
 
 
 class MLPHead(nn.Module):
@@ -29,8 +30,11 @@ class MLPHead(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        # layers_0's kernel is split over fsdp like a trunk kernel and is used
+        # like one (parallel/schedule.py); layers_1's never is
         h = nn.Dense(
-            self.cfg.d_model * 2, dtype=self.cfg.compute_dtype, param_dtype=self.cfg.params_dtype, name="layers_0"
+            self.cfg.d_model * 2, dtype=self.cfg.compute_dtype, param_dtype=self.cfg.params_dtype, name="layers_0",
+            dot_general=gathering_dot_general(self.path + ("layers_0", "kernel")),
         )(x)
         h = nn.relu(h)
         # Head output in fp32: value/Q targets are small-magnitude scalars and

@@ -23,6 +23,7 @@ reference: trlx/model/nn/ppo_models.py:35-413):
 - **Static shapes everywhere**: padding + masks, no ragged tensors.
 """
 
+import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional, Tuple
 
@@ -33,6 +34,8 @@ import numpy as np
 
 from trlx_tpu.observability import numerics as obs_numerics
 from trlx_tpu.ops.kv_read import attend, attend_latent, attend_latent_range, ranged_read
+from trlx_tpu.parallel.mesh import partitioned
+from trlx_tpu.parallel.schedule import hold_rows, use_weight
 
 Dtype = Any
 
@@ -377,6 +380,9 @@ class QDense(nn.Module):
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     use_bias: bool = True
+    # the rows of the pass this product belongs to, where they are more than
+    # x's own (the head over the response positions of a whole-sequence pass)
+    pass_tokens: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -396,7 +402,11 @@ class QDense(nn.Module):
             scale = self.get_variable("qw", "scale")
             y = jnp.dot(x.astype(self.dtype), kq.astype(self.dtype)) * scale.astype(self.dtype)
         else:
-            y = jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype))
+            # on a partitioned mesh a pass over many tokens gathers the kernel
+            # here, at its point of use (parallel/schedule.py)
+            tokens = self.pass_tokens or math.prod(x.shape[:-1])
+            kernel = use_weight(kernel.astype(self.dtype), self.path + ("kernel",), tokens)
+            y = jnp.dot(x.astype(self.dtype), kernel)
         if bias is not None:
             y = y + bias.astype(self.dtype)
         return y
@@ -415,13 +425,15 @@ class HeadParams(nn.Module):
     use_bias: bool = True
 
     @nn.compact
-    def __call__(self, in_features: int):
+    def __call__(self, in_features: int, tokens: int):
         kernel = self.param(
             "kernel",
             nn.initializers.lecun_normal(),
             (in_features, self.features),
             self.param_dtype,
         )
+        # `tokens`: the rows of the product the caller makes with the kernel
+        kernel = use_weight(kernel, self.path + ("kernel",), tokens)
         bias = (
             self.param("bias", nn.initializers.zeros_init(), (self.features,), self.param_dtype)
             if self.use_bias
@@ -702,7 +714,7 @@ class LatentAttention(nn.Module):
         # Interleaved pairs (0,1), (2,3), ... as the published code rotates them.
         k_rope = apply_rotary(kv_a[:, :, None, rank:], sin, cos, dr)[:, :, 0]
         params = lambda feats, name, fan_in: HeadParams(
-            feats, param_dtype=cfg.params_dtype, use_bias=False, name=name)(fan_in)[0].astype(dtype)
+            feats, param_dtype=cfg.params_dtype, use_bias=False, name=name)(fan_in, b * q_len)[0].astype(dtype)
         w_qb = params(h * (dn + dr), "q_b_proj", cfg.q_lora_rank).reshape(cfg.q_lora_rank, h, dn + dr)
         w_kvb = params(h * (dn + dv), "kv_b_proj", rank).reshape(rank, h, dn + dv)
 
@@ -804,6 +816,11 @@ class Block(nn.Module):
         ln = lambda name: make_norm(cfg, name)
         attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
         counts = None
+        # On a partitioned mesh a pass over many tokens keeps its rows where
+        # the batch split put them, at both edges of the block (inside, so
+        # that a remat'd backward holds them too), and every product gathers
+        # its weight (parallel/schedule.py).
+        x = hold_rows(x)
 
         def feed_forward(h):
             nonlocal counts
@@ -823,7 +840,7 @@ class Block(nn.Module):
             attn_out, new_cache = attn(ln("ln_1")(x), attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
             x = x + attn_out
             x = x + feed_forward(ln("ln_2")(x))
-        return x, new_cache, counts
+        return hold_rows(x), new_cache, counts
 
 
 def make_attn_bias(
@@ -944,14 +961,21 @@ class TransformerLM(nn.Module):
         wte = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="wte", **drawn
         )
+
+        def lookup(embed, ids):
+            """`embed(ids)` (`nn.Embed.__call__`), with the table as this
+            lookup uses it (parallel/schedule.py)."""
+            (table,) = embed.promote_dtype(embed.embedding, dtype=embed.dtype, inexact=False)
+            return jnp.take(use_weight(table, embed.path + ("embedding",), ids.size, lookup=True), ids, axis=0)
+
         if inputs_embeds is None:
             if cfg.onehot_embed and cache is None:
                 # Training/scoring forward on a sharded mesh: one-hot matmul
                 # (see LMConfig.onehot_embed). Decode keeps the gather.
                 onehot = jax.nn.one_hot(input_ids, cfg.vocab_size, dtype=cfg.compute_dtype)
-                x = onehot @ wte.embedding.astype(cfg.compute_dtype)
+                x = onehot @ use_weight(wte.embedding.astype(cfg.compute_dtype), wte.path + ("embedding",), input_ids.size)
             else:
-                x = wte(input_ids)
+                x = lookup(wte, input_ids)
         else:
             x = inputs_embeds.astype(cfg.compute_dtype)
 
@@ -1019,8 +1043,8 @@ class TransformerLM(nn.Module):
         if start_layer == 0 and cfg.pos_type == "learned":
             wpe = nn.Embed(
                 cfg.max_position, cfg.d_model, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="wpe"
-            )(position_ids)
-            x = x + wpe
+            )
+            x = x + lookup(wpe, position_ids)
         if start_layer == 0:
             # graftnum probe tap (observability/numerics.py): identity unless
             # the NaN-provenance bisector's EAGER re-forward is live — inside
@@ -1067,8 +1091,12 @@ class TransformerLM(nn.Module):
             policy = None
             if cfg.remat_policy == "dots":  # validated in LMConfig.__post_init__
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+            # On a partitioned mesh the recomputed block gathers its weights
+            # again (ZeRO-3): left to common-subexpression elimination, the
+            # forward's gathers of every layer stay alive for the backward,
+            # 11 GB at GPT-J-6B (PERF.md §6, PR 29).
             block_cls = nn.remat(
-                Block, prevent_cse=False, static_argnums=(7, 8), policy=policy
+                Block, prevent_cse=partitioned(), static_argnums=(7, 8), policy=policy
             )
 
         branch_hidden = None
@@ -1111,6 +1139,8 @@ class TransformerLM(nn.Module):
 
         logits = None
         logprobs = lse = entropy = None
+        # The head's weight moves as the trunk's do: by the tokens of the pass
+        # (the rows `hold_rows` kept in place), not the positions it evaluates.
         if labels is not None:
             # Fused head mode: the [b, S, V] logits are never materialized —
             # the vocab projection streams through the Pallas kernel (or the
@@ -1123,14 +1153,14 @@ class TransformerLM(nn.Module):
             x_head = x[:, logits_start:] if logits_start else x
             x_head = x_head[:, :S]
             if cfg.tie_word_embeddings:
-                w_head, b_head, tied = wte.embedding, None, True
+                w_head, b_head, tied = use_weight(wte.embedding, wte.path + ("embedding",), b * q_len), None, True
             else:
                 w_head, b_head = HeadParams(
                     cfg.vocab_size,
                     param_dtype=cfg.params_dtype,
                     use_bias=cfg.extra.get("lm_head_bias", False),
                     name="lm_head",
-                )(x_head.shape[-1])
+                )(x_head.shape[-1], b * q_len)
                 tied = False
             logprobs, lse, entropy = routed_logprob(
                 x_head,
@@ -1147,13 +1177,16 @@ class TransformerLM(nn.Module):
             # vocab-projection FLOPs and the fp32 logit memory.
             x_head = x[:, logits_start:] if logits_start else x
             if cfg.tie_word_embeddings:
-                logits = wte.attend(x_head)
+                # `wte.attend(x_head)`, with the table as this product uses it
+                query, table = wte.promote_dtype(x_head, wte.embedding, dtype=wte.dtype)
+                logits = jnp.dot(query, use_weight(table, wte.path + ("embedding",), b * q_len).T)
             else:
                 logits = QDense(
                     cfg.vocab_size,
                     dtype=cfg.compute_dtype,
                     param_dtype=cfg.params_dtype,
                     use_bias=cfg.extra.get("lm_head_bias", False),
+                    pass_tokens=b * q_len,
                     name="lm_head",
                 )(x_head)
 

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from trlx_tpu.models.lm import ACTIVATIONS, MLP, LMConfig
+from trlx_tpu.parallel.schedule import use_weight
 
 BIAS_NAME = "e_score_correction_bias"
 SMALL_CALL_SLOTS = 2048  # token-slots (tokens x experts_per_token) up to which a call is "small": a decode step
@@ -173,9 +174,13 @@ class ExpertLayer(nn.Module):
         # the chip read a fullest expert at 6-15 times the mean (PERF.md, PR 26).
         bias = self.param(BIAS_NAME, nn.initializers.normal(stddev=0.01), (cfg.n_experts,), jnp.float32)
         stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
-        gate = self.param("experts_gate", stacked, (held, d, f), cfg.params_dtype).astype(dtype)
-        up = self.param("experts_up", stacked, (held, d, f), cfg.params_dtype).astype(dtype)
-        down = self.param("experts_down", stacked, (held, f, d), cfg.params_dtype).astype(dtype)
+        # on a partitioned mesh a call of many tokens gathers the router and the
+        # stacks here, as a dense layer gathers its kernels (parallel/schedule.py)
+        at_use = lambda w, name: use_weight(w, self.path + (name,), b * t)
+        router = at_use(router, "router")
+        gate = at_use(self.param("experts_gate", stacked, (held, d, f), cfg.params_dtype).astype(dtype), "experts_gate")
+        up = at_use(self.param("experts_up", stacked, (held, d, f), cfg.params_dtype).astype(dtype), "experts_up")
+        down = at_use(self.param("experts_down", stacked, (held, f, d), cfg.params_dtype).astype(dtype), "experts_down")
 
         flat = x.reshape(b * t, d).astype(dtype)
         ids, weights = route(flat, router, bias, cfg.experts_per_token, cfg.routed_scaling_factor)

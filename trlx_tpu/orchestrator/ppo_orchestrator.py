@@ -29,6 +29,7 @@ import numpy as np
 from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.models.lm import cache_bytes_per_token
 from trlx_tpu.ops.kv_read import kv_keys_read
+from trlx_tpu.parallel.schedule import weight_gather_share
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
 from trlx_tpu.pipeline.overlap import ScoreWorker
 from trlx_tpu.resilience.faults import FaultInjected
@@ -529,6 +530,11 @@ class PPOOrchestrator(Orchestrator):
         }
         if experts_touched:
             rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
+        gather_share = weight_gather_share(rl._weight_gathers["generate"])
+        if gather_share is not None:
+            # the generate program on a partitioned mesh: the prefill gathers
+            # its weights, the decode loop keeps the shards
+            rl._last_exp_stats["parallel/weight_gather_share"] = gather_share
         rl.tracker.log(stats, step=iter_count)
 
     def _make_experience_engine(

@@ -46,6 +46,7 @@ from trlx_tpu.observability import spans as obs_spans
 from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.parallel import make_mesh, set_mesh, shard_pytree
 from trlx_tpu.parallel.mesh import DATA_AXES, barrier, init_distributed, is_main_process
+from trlx_tpu.parallel.schedule import count_weight_gathers, weight_gather_share
 from trlx_tpu.resilience import (
     CheckpointError,
     DivergenceWatchdog,
@@ -197,6 +198,10 @@ class JaxBaseTrainer(BaseRLTrainer):
         # `flash/kept_pair_share` of every step record: a host float from the
         # train step's shapes, None where its attention takes no flash kernel
         self._flash_kept_share = flash_kept_pair_share(self.model.cfg, config.train.seq_length)
+        # `parallel/weight_gather_share`: what the traced train step and
+        # generate program did with each kernel split over fsdp, filled while
+        # they are traced (parallel/schedule.py); empty without such a mesh
+        self._weight_gathers = {"train": {}, "generate": {}}
         # Parallel host-side batch refs for the graftnum nonfinite census:
         # populated ONLY when incident capture is armed (None placeholders
         # otherwise), so default runs keep zero extra references alive.
@@ -1231,7 +1236,7 @@ class JaxBaseTrainer(BaseRLTrainer):
                 n_layer = int(self.model.cfg.n_layer)
                 tap = f"block_{min(self.iter_count + 1, n_layer - 1)}"
                 obs_numerics.latch_injection(tap)
-            with trace_span("train/dispatch"), self._dispatch_lock:
+            with trace_span("train/dispatch"), self._dispatch_lock, count_weight_gathers(self._weight_gathers["train"]):
                 prev_state = self.state
                 self.state, stats = self.train_step(self.state, step_batch)
             # Donation handoff: train_step donates the old state
@@ -1378,6 +1383,9 @@ class JaxBaseTrainer(BaseRLTrainer):
         stats_host["obs/compiles"] = obs_spans.take_compiles()
         if self._flash_kept_share is not None:
             stats_host["flash/kept_pair_share"] = self._flash_kept_share
+        gather_share = weight_gather_share(self._weight_gathers["train"])
+        if gather_share is not None:
+            stats_host["parallel/weight_gather_share"] = gather_share
         if self._anomaly is not None and self._anomaly.observe(
             stats_host["step_time"]
         ):

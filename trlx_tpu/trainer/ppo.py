@@ -28,6 +28,7 @@ from trlx_tpu.observability import spans as obs_spans
 from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.ops.sampling import GenerateConfig
 from trlx_tpu.parallel.mesh import DATA_AXES
+from trlx_tpu.parallel.schedule import count_weight_gathers
 from trlx_tpu.pipeline.overlap import PhaseTimer, RolloutProducer
 from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage
 from trlx_tpu.resilience.guard import guarded_update
@@ -610,7 +611,7 @@ class PPOTrainer(JaxBaseTrainer):
         # _dispatch_lock: generation runs on the producer thread at
         # max_staleness > 0 while the main thread dispatches train steps —
         # see JaxBaseTrainer.__init__ for the rendezvous hazard.
-        with self._dispatch_lock:
+        with self._dispatch_lock, count_weight_gathers(self._weight_gathers["generate"]):
             return self._generate_fn(
                 self._decode_variables(snapshot), batch["i"], batch["m"], rng
             )
@@ -623,7 +624,7 @@ class PPOTrainer(JaxBaseTrainer):
         batch = self.put_batch({"i": input_ids, "m": attention_mask})
         if rng is None:
             rng = self.next_rng()
-        with self._dispatch_lock:
+        with self._dispatch_lock, count_weight_gathers(self._weight_gathers["generate"]):
             return self._generate_fused_fn(
                 self._decode_variables(snapshot), batch["i"], batch["m"], rng
             )
