@@ -35,13 +35,27 @@ def test_resolve_mesh_shape():
 
 
 @slow
-def test_partition_rules_megatron_layout():
-    cfg = LMConfig(vocab_size=32, n_layer=2, n_head=4, d_model=64, dtype="float32")
+@pytest.mark.parametrize("kind", ["gpt block", "grouped keys with qk-norm"])
+def test_partition_rules_megatron_layout(kind):
+    grouped = kind != "gpt block"
+    extra = dict(n_kv_head=2, head_width=32, fused_qkv=False, qk_norm=True) if grouped else {}
+    cfg = LMConfig(vocab_size=32, n_layer=2, n_head=4, d_model=64, dtype="float32", **extra)
     model = LMWithValueHead(cfg)
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32), jnp.ones((1, 4), jnp.int32))["params"]
     specs = match_partition_rules(lm_partition_rules(), params)
     t = specs["transformer"]
-    assert t["h_0"]["attn"]["c_qkv"]["kernel"] == P("fsdp", "tp")
+    if grouped:
+        # K and V at their 2 heads of 32 (64 columns where q_proj has 128): column-parallel like q_proj;
+        # the two head norms' scales are head_width numbers for every head: whole
+        attn = params["transformer"]["h_0"]["attn"]
+        assert attn["q_proj"]["kernel"].shape == (64, 128) and attn["k_proj"]["kernel"].shape == (64, 64)
+        for name in ("q_proj", "k_proj", "v_proj"):
+            assert t["h_0"]["attn"][name]["kernel"] == P("fsdp", "tp")
+        assert t["h_0"]["attn"]["q_norm"]["scale"] == t["h_0"]["attn"]["k_norm"]["scale"] == P()
+        unruled = match_partition_rules(lm_partition_rules()[:-1] + [(".*", "fallback")], {"attn": attn})  # every leaf has its rule
+        assert "fallback" not in jax.tree_util.tree_leaves(unruled, is_leaf=lambda s: isinstance(s, (str, P)))
+    else:
+        assert t["h_0"]["attn"]["c_qkv"]["kernel"] == P("fsdp", "tp")
     assert t["h_0"]["attn"]["c_proj"]["kernel"] == P("tp", "fsdp")
     assert t["h_0"]["mlp"]["c_fc"]["kernel"] == P("fsdp", "tp")
     assert t["h_0"]["mlp"]["c_proj"]["kernel"] == P("tp", "fsdp")

@@ -57,6 +57,15 @@ def _flash_windowed(q, k, v, m):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
+def _flash_grouped(q, k, v, m):
+    """Grouped keys (64 query heads over 8 K/V heads of 128) under a 128-key
+    window: K and V by the index map, dk/dv summed over a group in scratch."""
+    loss = lambda q, k, v: flash_attention(
+        q, k, v, m, scale=128**-0.5, causal=True, window=128, interpret=False
+    ).astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
 def _flash_ring_chunk(q, k, v, m, offset):
     """A sequence too long to be resident (major pieces, the state carried in
     scratch) called the way the ring path calls it: a traced offset (the
@@ -84,6 +93,8 @@ def _cases(s):
     return [
         ("flash fwd+bwd", _flash, (qkv, qkv, qkv, s((B, T), f32))),
         ("flash fwd+bwd windowed d128", _flash_windowed, (s((16, 512, H, 128), bf16),) * 3 + (s((16, 512), f32),)),
+        ("flash fwd+bwd grouped keys windowed d128", _flash_grouped,
+         (s((4, 1024, 64, 128), bf16), s((4, 1024, 8, 128), bf16), s((4, 1024, 8, 128), bf16), s((4, 1024), f32))),
         ("flash fwd+bwd major pieces, traced offset", _flash_ring_chunk,
          (s((1, 8192, H, D), bf16),) * 3 + (s((1, 8192), f32), s((), f32))),
         ("fused_logprob fwd+bwd", _fused, head),

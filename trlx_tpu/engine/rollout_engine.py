@@ -132,6 +132,10 @@ class RolloutEngine:
     ):
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
+        if model.cfg.window_cache == "ring":
+            raise NotImplementedError(
+                "the rollout engine (per-slot write offsets, suffix prefill, spec verify windows) is not built "
+                "for window_cache 'ring': a ring takes one write offset for the whole batch (ops/generate.py)")
         self.model = model
         self.gcfg = gen_cfg
         self.processor = processor

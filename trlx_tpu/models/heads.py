@@ -17,7 +17,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.models.lm import LMConfig, TransformerLM
+from trlx_tpu.models.lm import LMConfig, TransformerLM, drawn_in
 from trlx_tpu.parallel.schedule import gathering_dot_general
 
 
@@ -32,15 +32,17 @@ class MLPHead(nn.Module):
     def __call__(self, x):
         # layers_0's kernel is split over fsdp like a trunk kernel and is used
         # like one (parallel/schedule.py); layers_1's never is
+        # nn.Dense's own default initializer, drawn as LMConfig.draw_dtype says
+        kernel_init = drawn_in(self.cfg.draw_dtype, nn.initializers.lecun_normal())
         h = nn.Dense(
             self.cfg.d_model * 2, dtype=self.cfg.compute_dtype, param_dtype=self.cfg.params_dtype, name="layers_0",
-            dot_general=gathering_dot_general(self.path + ("layers_0", "kernel")),
+            dot_general=gathering_dot_general(self.path + ("layers_0", "kernel")), kernel_init=kernel_init,
         )(x)
         h = nn.relu(h)
         # Head output in fp32: value/Q targets are small-magnitude scalars and
         # bf16 rounding hurts GAE/TD numerics.
         return nn.Dense(
-            self.out_features, dtype=jnp.float32, param_dtype=self.cfg.params_dtype, name="layers_1"
+            self.out_features, dtype=jnp.float32, param_dtype=self.cfg.params_dtype, name="layers_1", kernel_init=kernel_init
         )(h)
 
 

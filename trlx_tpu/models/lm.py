@@ -81,6 +81,17 @@ class LMConfig:
     # block and ruinous to a router over random weights, which then sends every
     # token to the same few experts (PERF.md, PR 26); 1.0 is torch's default.
     embed_init_std: float = 0.0
+    # The dtype every weight is DRAWN in (initialisation only), cast to
+    # param_dtype afterwards; "" -> param_dtype itself, as ever. A draw in
+    # bfloat16 is biased: jax.random's normal has mean -0.012 deviations there
+    # and the truncated normal of lecun_normal -0.018 (a coarse uniform under
+    # the inverse error function), the same on every entry, so each kernel
+    # carries a rank-one part along the all-ones direction that a 6,144-wide
+    # sum amplifies 1.4 times a product: every hidden state shares that
+    # direction, and a router over such weights sends 87% of the tokens to
+    # one expert by the fourth expert layer (PERF.md §6, PR 30). "float32"
+    # draws without the bias.
+    draw_dtype: str = ""
     activation: str = "gelu_new"
     ln_eps: float = 1e-5
     embd_pdrop: float = 0.0  # dropout unused in RL fine-tuning; kept for parity
@@ -128,6 +139,27 @@ class LMConfig:
     norm: str = "layernorm"  # "layernorm" | "rmsnorm" (scale only, no mean)
     mlp: str = "dense"  # "dense": c_proj(act(c_fc x)) | "gated": down(act(gate x) * up x), no biases
     attention: str = "mha"  # "mha": per-head K and V in the cache | "mla": latent attention (below)
+    # Grouped keys ("mha" only): K and V are projected to n_kv_head heads and
+    # each serves n_head // n_kv_head query heads, in the cache and in every
+    # read; 0 -> n_head (one K and V a query head).
+    n_kv_head: int = 0
+    # Width of one head where it is not d_model // n_head ("mha" with separate
+    # projections only): q_proj is n_head * head_width wide, k_proj and v_proj
+    # kv_heads * head_width, c_proj takes n_head * head_width back to d_model.
+    head_width: int = 0
+    # RMSNorm over each query head and each key head (one scale of head_dim for
+    # all the query heads, one for the key heads), in float32, before rotary.
+    qk_norm: bool = False
+    # Which layers rotate q and k (pos_type "rotary"): "all", or "local": the
+    # window layers of `attention_layers` only, a global layer has no position
+    # signal at all.
+    rotary_layers: str = "all"
+    # What a window layer keeps in the cache: "span": every slot like a global
+    # layer, the window by the bias (gpt-neo); "ring": window_size slots,
+    # position p written at slot p mod window_size. The ring is built for the
+    # static generate path (one write offset a batch: a prefill at 0, then one
+    # token a step); the engine, the paged pool, spec decode and sp refuse it.
+    window_cache: str = "span"
     # Per-layer feed-forward kind ("dense" | "experts"); empty -> all dense.
     # An "experts" layer is trlx_tpu/models/moe.py: sigmoid router over all
     # n_experts, experts_per_token chosen, a shared expert, and the routed
@@ -167,9 +199,12 @@ class LMConfig:
                 f"unknown remat_policy {self.remat_policy!r} (expected 'full' or 'dots')"
             )
         for name, kinds in (("norm", ("layernorm", "rmsnorm")), ("mlp", ("dense", "gated")),
-                            ("attention", ("mha", "mla"))):
+                            ("attention", ("mha", "mla")), ("rotary_layers", ("all", "local")),
+                            ("window_cache", ("span", "ring"))):
             if getattr(self, name) not in kinds:
                 raise ValueError(f"unknown {name} kind {getattr(self, name)!r} (expected one of {kinds})")
+        if self.draw_dtype not in ("", "float32"):
+            raise ValueError(f"unknown draw_dtype {self.draw_dtype!r} (expected '' or 'float32')")
         if self.activation not in ACTIVATIONS:
             raise ValueError(f"unknown activation {self.activation!r} (expected one of {sorted(ACTIVATIONS)})")
         if self.ffn_layers and (len(self.ffn_layers) != self.n_layer
@@ -185,6 +220,22 @@ class LMConfig:
             raise ValueError("rope_scaling is built for attention 'mla' only")
         if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"rope_scaling type {self.rope_scaling.get('type')!r} is not built (only 'yarn')")
+        if self.n_kv_head and self.n_kv_head != self.n_head:
+            if self.n_kv_head < 0 or self.n_head % self.n_kv_head:
+                raise ValueError(f"n_kv_head {self.n_kv_head} does not divide n_head {self.n_head}")
+            if self.attention != "mha" or self.fused_qkv or self.sp_size > 1:
+                raise ValueError("grouped keys (n_kv_head < n_head) are built for attention 'mha' with separate "
+                                 "q/k/v projections (fused_qkv false), and not for the sp ring")
+        if (self.qk_norm or self.head_width) and (self.attention != "mha" or self.fused_qkv):
+            raise ValueError("qk_norm and head_width are built for attention 'mha' with separate q/k/v projections "
+                             "(fused_qkv false)")
+        if self.rotary_layers == "local" and (self.pos_type != "rotary" or "local" not in self.attention_layers):
+            raise ValueError("rotary_layers 'local' needs pos_type 'rotary' and a 'local' layer in attention_layers")
+        if self.window_cache == "ring":
+            if "local" not in self.attention_layers or self.window_size <= 0:
+                raise ValueError("window_cache 'ring' needs a 'local' layer in attention_layers and a window_size")
+            if self.n_soft_tokens or self.sp_size > 1:
+                raise ValueError("window_cache 'ring' is not built with soft prompts or the sp ring")
         if "experts" in self.ffn_layers:
             first, count = self.held_experts
             if (self.mlp != "gated" or self.expert_d_ff <= 0 or not 0 < self.experts_per_token <= self.n_experts
@@ -203,7 +254,12 @@ class LMConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_head
+        return self.head_width or self.d_model // self.n_head
+
+    @property
+    def kv_heads(self) -> int:
+        """K and V heads: n_kv_head, or n_head where keys are not grouped."""
+        return self.n_kv_head or self.n_head
 
     @property
     def ff_dim(self) -> int:
@@ -347,6 +403,13 @@ def layer_window(cfg: LMConfig, layer: int) -> int:
     return cfg.window_size if cfg.attention_layers and cfg.attention_layers[layer] == "local" else 0
 
 
+def ring_slots(cfg: LMConfig, layer: int, max_len: int) -> int:
+    """Slots of a layer's ring in a cache of `max_len` positions: the layer's
+    window (the whole span where that is shorter) under window_cache "ring",
+    0 for a layer that keeps the full span."""
+    return min(layer_window(cfg, layer), max_len) if cfg.window_cache == "ring" else 0
+
+
 def flash_kept_pair_share(cfg: LMConfig, q_len: int) -> Optional[float]:
     """Pairs the mask keeps over pairs the flash kernels' live chunks compute,
     mean over the layers' attention calls of one full-sequence pass at
@@ -363,6 +426,14 @@ def flash_kept_pair_share(cfg: LMConfig, q_len: int) -> Optional[float]:
 
     blocks = pick_block(q_len)
     return sum(kept_pair_share(q_len, blocks, True, layer_window(cfg, i)) for i in range(cfg.n_layer)) / cfg.n_layer
+
+
+def drawn_in(draw_dtype: str, init):
+    """`init`, drawing in `draw_dtype` and casting to the parameter's dtype
+    (`LMConfig.draw_dtype`); `init` itself where none is named."""
+    if not draw_dtype:
+        return init
+    return lambda key, shape, dtype=jnp.float32: init(key, shape, jnp.dtype(draw_dtype)).astype(dtype)
 
 
 class QDense(nn.Module):
@@ -383,12 +454,13 @@ class QDense(nn.Module):
     # the rows of the pass this product belongs to, where they are more than
     # x's own (the head over the response positions of a whole-sequence pass)
     pass_tokens: int = 0
+    draw_dtype: str = ""  # LMConfig.draw_dtype
 
     @nn.compact
     def __call__(self, x):
         kernel = self.param(
             "kernel",
-            nn.initializers.lecun_normal(),
+            drawn_in(self.draw_dtype, nn.initializers.lecun_normal()),
             (x.shape[-1], self.features),
             self.param_dtype,
         )
@@ -423,12 +495,13 @@ class HeadParams(nn.Module):
     features: int
     param_dtype: Any = jnp.float32
     use_bias: bool = True
+    draw_dtype: str = ""  # LMConfig.draw_dtype
 
     @nn.compact
     def __call__(self, in_features: int, tokens: int):
         kernel = self.param(
             "kernel",
-            nn.initializers.lecun_normal(),
+            drawn_in(self.draw_dtype, nn.initializers.lecun_normal()),
             (in_features, self.features),
             self.param_dtype,
         )
@@ -500,12 +573,38 @@ def write_cache(buf, upd, cache_index):
     return jax.lax.dynamic_update_slice(buf, upd, (0, cache_index) + zeros)
 
 
+def write_ring(buf, upd, cache_index):
+    """Write `upd` [b, q, ...] into a ring buffer [b, W, ...], position p at
+    slot p mod W. One token (a decode step) goes to slot `cache_index` mod W;
+    a block written at offset 0 (the prefill) leaves its last W positions."""
+    slots, q_len = buf.shape[1], upd.shape[1]
+    if q_len == 1:
+        return write_cache(buf, upd, cache_index % slots)
+    if q_len <= slots:
+        return write_cache(buf, upd, 0)
+    first = q_len - slots  # the oldest position kept
+    return jnp.roll(upd[:, first:], first % slots, axis=1).astype(buf.dtype)
+
+
+def ring_bias(cache_mask, cache_index, slots: int):
+    """The additive bias [b, 1, 1, slots] of a decode step's read of a ring of
+    `slots` slots, the step writing position `cache_index`: slot s holds the
+    newest position p <= cache_index with p = s mod slots, which the window
+    admits by construction; it is valid where that position exists (p >= 0)
+    and `cache_mask` [b, T] marks it (left padding, a finished row)."""
+    s = jnp.arange(slots, dtype=jnp.int32)
+    pos = cache_index - (cache_index - s) % slots
+    valid = (pos >= 0)[None, :] & jnp.take(cache_mask, jnp.maximum(pos, 0), axis=1).astype(bool)
+    return jnp.where(valid, 0.0, -1e9).astype(jnp.float32)[:, None, None, :]
+
+
 class Attention(nn.Module):
     """Multi-head causal attention with functional KV cache.
 
     Layout: qkv projections are column-parallel over tp (see
     trlx_tpu/parallel/sharding.py), output projection row-parallel. Softmax in
-    fp32. The cache is `(k, v)` of shape [b, cache_len, n_head, head_dim]
+    fp32. The cache is `(k, v)` of shape [b, cache_len, kv_heads, head_dim]
+    (a window layer under window_cache "ring": cache_len = window_size slots)
     written at `cache_index` with dynamic_update_slice. When `flash_mask` is
     given (and attn_bias is None) the score/softmax/value contraction runs in
     the fused pallas kernel instead of einsum.
@@ -522,22 +621,33 @@ class Attention(nn.Module):
         hd = cfg.head_dim
 
         dense = lambda feats, name, use_bias: QDense(
-            feats, dtype=dtype, param_dtype=cfg.params_dtype, use_bias=use_bias, name=name
+            feats, dtype=dtype, param_dtype=cfg.params_dtype, use_bias=use_bias, draw_dtype=cfg.draw_dtype, name=name
         )
 
+        kvh = cfg.kv_heads
         if cfg.fused_qkv:
             qkv = dense(3 * cfg.d_model, "c_qkv", cfg.qkv_bias)(x)
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
-            q = dense(cfg.d_model, "q_proj", cfg.qkv_bias)(x)
-            k = dense(cfg.d_model, "k_proj", cfg.qkv_bias)(x)
-            v = dense(cfg.d_model, "v_proj", cfg.qkv_bias)(x)
+            q = dense(cfg.n_head * hd, "q_proj", cfg.qkv_bias)(x)
+            k = dense(kvh * hd, "k_proj", cfg.qkv_bias)(x)
+            v = dense(kvh * hd, "v_proj", cfg.qkv_bias)(x)
 
+        # Grouped keys: K and V keep their kvh heads from here to the cache and
+        # through every read; a read serves n_head // kvh query heads from one
+        # K/V head by a reshape of the QUERY heads (ops/kv_read.py `attend`,
+        # ops/flash_attention.py), never by a repeated copy of K or V.
         q = q.reshape(b, q_len, cfg.n_head, hd)
-        k = k.reshape(b, q_len, cfg.n_head, hd)
-        v = v.reshape(b, q_len, cfg.n_head, hd)
+        k = k.reshape(b, q_len, kvh, hd)
+        v = v.reshape(b, q_len, kvh, hd)
 
-        if cfg.pos_type == "rotary":
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                head_norm = lambda name: nn.RMSNorm(
+                    epsilon=cfg.ln_eps, dtype=jnp.float32, param_dtype=cfg.params_dtype, name=name)
+                q, k = head_norm("q_norm")(q).astype(dtype), head_norm("k_norm")(k).astype(dtype)
+
+        if cfg.pos_type == "rotary" and (cfg.rotary_layers == "all" or window):
             rd = cfg.rotary_dim or hd
             sin, cos = rotary_sincos(positions, rd, cfg.rope_theta)
             neox = cfg.extra.get("neox_rotary", False)
@@ -556,6 +666,13 @@ class Attention(nn.Module):
             # k-1 scratch tail so live rows never clamp (see
             # RolloutEngine.cache_len).
             paged = block_tables is not None
+            # A window layer under window_cache "ring" keeps window_size
+            # slots. The trunk admits two calls and hands each its bias: one
+            # token at one offset for the batch (a decode step: the bias over
+            # the ring's slots), or a block at write offset 0 (the prefill:
+            # the bias cut to the block). Told apart by q_len: under remat the
+            # offset is a tracer here.
+            ring = cfg.window_cache == "ring" and window > 0
             if paged:
                 # Paged KV: the per-layer cache operand is ONE shared block
                 # pool [n_blocks, block_size, h, d] and each row addresses it
@@ -595,7 +712,7 @@ class Attention(nn.Module):
             else:
 
                 def cache_write(buf, upd):
-                    return write_cache(buf, upd, cache_index)
+                    return (write_ring if ring else write_cache)(buf, upd, cache_index)
 
                 def gather_virt(buf):
                     # Per-slot buffers ARE the virtual cache.
@@ -616,8 +733,12 @@ class Attention(nn.Module):
             # index, a block table, an unaligned prefill) attends over the
             # whole virtual cache with the cache-validity bias, dequantized
             # on read.
-            if flash_mask is None:
-                if not paged:
+            # A ring layer: the prefill block likewise attends over itself;
+            # a decode step reads all of the ring's slots, valid by the
+            # position each holds (the trunk's `ring_bias`): one branch, no
+            # switch.
+            if flash_mask is None and not (ring and q_len > 1):
+                if not (paged or ring):
                     read = ranged_read(int(cache[0].shape[1]), q_len, cache_index, window)
                 if read is None:
 
@@ -630,22 +751,23 @@ class Attention(nn.Module):
                     k, v = view(0), view(1)
 
         scale = 1.0 / np.sqrt(hd) if cfg.scale_attn else 1.0
-        if flash_mask is not None:
-            if use_ring:
-                from trlx_tpu.parallel.ring_attention import ring_attention_sharded
+        with jax.named_scope("attn_window" if window else "attn_full"):
+            if flash_mask is not None:
+                if use_ring:
+                    from trlx_tpu.parallel.ring_attention import ring_attention_sharded
 
-                out = ring_attention_sharded(
-                    q, k, v, flash_mask, scale=scale, causal=True, window=window
-                ).astype(dtype)
+                    out = ring_attention_sharded(
+                        q, k, v, flash_mask, scale=scale, causal=True, window=window
+                    ).astype(dtype)
+                else:
+                    from trlx_tpu.ops.flash_attention import flash_attention
+
+                    out = flash_attention(q, k, v, flash_mask, scale=scale, causal=True, window=window).astype(dtype)
+            elif read is not None:
+                out = read(q, new_cache, attn_bias, scale, dtype)
             else:
-                from trlx_tpu.ops.flash_attention import flash_attention
-
-                out = flash_attention(q, k, v, flash_mask, scale=scale, causal=True, window=window).astype(dtype)
-        elif read is not None:
-            out = read(q, new_cache, attn_bias, scale, dtype)
-        else:
-            out = attend(q, k, v, attn_bias, scale, dtype)
-        out = out.reshape(b, q_len, cfg.d_model)
+                out = attend(q, k, v, attn_bias, scale, dtype)
+        out = out.reshape(b, q_len, cfg.n_head * hd)
         out = dense(cfg.d_model, "c_proj", cfg.out_bias)(out)
         return out, new_cache
 
@@ -692,7 +814,8 @@ class LatentAttention(nn.Module):
         dtype = cfg.compute_dtype
         b, q_len, _ = x.shape
         h, dn, dr, dv, rank = cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
-        dense = lambda feats, name: QDense(feats, dtype=dtype, param_dtype=cfg.params_dtype, use_bias=False, name=name)
+        dense = lambda feats, name: QDense(feats, dtype=dtype, param_dtype=cfg.params_dtype, use_bias=False,
+                                           draw_dtype=cfg.draw_dtype, name=name)
         norm = lambda name: nn.RMSNorm(epsilon=cfg.ln_eps, dtype=dtype, param_dtype=cfg.params_dtype, name=name)
 
         c_q = norm("q_a_norm")(dense(cfg.q_lora_rank, "q_a_proj")(x))
@@ -714,7 +837,7 @@ class LatentAttention(nn.Module):
         # Interleaved pairs (0,1), (2,3), ... as the published code rotates them.
         k_rope = apply_rotary(kv_a[:, :, None, rank:], sin, cos, dr)[:, :, 0]
         params = lambda feats, name, fan_in: HeadParams(
-            feats, param_dtype=cfg.params_dtype, use_bias=False, name=name)(fan_in, b * q_len)[0].astype(dtype)
+            feats, param_dtype=cfg.params_dtype, use_bias=False, draw_dtype=cfg.draw_dtype, name=name)(fan_in, b * q_len)[0].astype(dtype)
         w_qb = params(h * (dn + dr), "q_b_proj", cfg.q_lora_rank).reshape(cfg.q_lora_rank, h, dn + dr)
         w_kvb = params(h * (dn + dv), "kv_b_proj", rank).reshape(rank, h, dn + dv)
 
@@ -788,7 +911,7 @@ class MLP(nn.Module):
         act = ACTIVATIONS[cfg.activation]
         width = self.width or cfg.ff_dim
         dense = lambda feats, name, bias: QDense(
-            feats, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, use_bias=bias, name=name)
+            feats, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, use_bias=bias, draw_dtype=cfg.draw_dtype, name=name)
         if cfg.mlp == "gated":
             return dense(cfg.d_model, "down_proj", False)(
                 act(dense(width, "gate_proj", False)(x)) * dense(width, "up_proj", False)(x))
@@ -957,7 +1080,7 @@ class TransformerLM(nn.Module):
             "segment packing is a train-batch construct; decode caches are unpacked"
         )
 
-        drawn = {"embedding_init": nn.initializers.normal(cfg.embed_init_std)} if cfg.embed_init_std else {}
+        drawn = {"embedding_init": drawn_in(cfg.draw_dtype, nn.initializers.normal(cfg.embed_init_std))} if cfg.embed_init_std else {}
         wte = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="wte", **drawn
         )
@@ -1061,6 +1184,11 @@ class TransformerLM(nn.Module):
             and int(cache_index) == 0
         )
         use_flash = use_ring or flash_eligible(cfg, q_len, cache is not None, prefill_at_zero)
+        ring_cache = cfg.window_cache == "ring" and cache is not None
+        if ring_cache and (block_tables is not None or jnp.ndim(cache_index) != 0 or (q_len > 1 and not prefill_at_zero)):
+            raise NotImplementedError(
+                "a ring cache takes a prefill at write offset 0 or one token a step at one offset for the "
+                "whole batch (the static generate path): no block table, per-row offset or verify window")
         if segment_ids is not None:
             # Packed segments need a block-diagonal mask; the flash/ring
             # kernels' (causal × key-validity) masks cannot express that.
@@ -1077,10 +1205,17 @@ class TransformerLM(nn.Module):
                 bias_mask, bias_offset = attention_mask, 0
             attn_bias = make_attn_bias(bias_mask, q_len, bias_offset, segment_ids=segment_ids)
             local_bias = None
-            if any(t == "local" for t in cfg.attention_layers):
+            if ring_cache and q_len == 1:
+                # a decode step over ring caches: the window layers' bias is
+                # over their ring's slots
+                (slots,) = {int(cache[i][0].shape[1]) for i in range(cfg.n_layer) if layer_window(cfg, i)}
+                local_bias = ring_bias(kv_mask, cache_index, slots)
+            elif any(t == "local" for t in cfg.attention_layers):
                 local_bias = make_attn_bias(
                     bias_mask, q_len, bias_offset, window=cfg.window_size, segment_ids=segment_ids
                 )
+                if ring_cache:  # the prefill: a ring layer attends over its own block
+                    local_bias = local_bias[..., :q_len]
 
         block_cls = Block
         if cfg.remat:
@@ -1159,6 +1294,7 @@ class TransformerLM(nn.Module):
                     cfg.vocab_size,
                     param_dtype=cfg.params_dtype,
                     use_bias=cfg.extra.get("lm_head_bias", False),
+                    draw_dtype=cfg.draw_dtype,
                     name="lm_head",
                 )(x_head.shape[-1], b * q_len)
                 tied = False
@@ -1187,6 +1323,7 @@ class TransformerLM(nn.Module):
                     param_dtype=cfg.params_dtype,
                     use_bias=cfg.extra.get("lm_head_bias", False),
                     pass_tokens=b * q_len,
+                    draw_dtype=cfg.draw_dtype,
                     name="lm_head",
                 )(x_head)
 
@@ -1227,9 +1364,11 @@ def quantize_kv(x: jnp.ndarray, probe=None, probe_class: str = "kv"):
 
 
 def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
-    """Allocate an empty KV cache pytree, as the attention kind keeps it:
-    "mha": per-layer (k, v) [b, T, n_head, hd], or (k_i8, v_i8, k_scale,
-    v_scale) with kv_cache_quant; "mla": per-layer (c_kv [b, T, kv_lora_rank],
+    """Allocate an empty KV cache pytree, as the attention kind and the
+    layer's kind keep it: "mha": per-layer (k, v) [b, T, kv_heads, hd], or
+    (k_i8, v_i8, k_scale, v_scale) with kv_cache_quant (scales [b, T,
+    kv_heads]), T = `max_len`, but min(window_size, max_len) on a window layer
+    under window_cache "ring"; "mla": per-layer (c_kv [b, T, kv_lora_rank],
     k_rope [b, T, qk_rope_head_dim]), shared by all heads."""
     if cfg.attention == "mla":
         dtype = dtype or cfg.compute_dtype
@@ -1237,22 +1376,23 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
             (jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype), jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype))
             for _ in range(cfg.n_layer)
         )
-    shape = (batch, max_len, cfg.n_head, cfg.head_dim)
     if cfg.kv_cache_quant:
         assert dtype is None, "kv_cache_quant caches are int8; dtype not honored"
-        sshape = (batch, max_len, cfg.n_head)
-        return tuple(
-            (
+    dtype = dtype or cfg.compute_dtype
+
+    def layer(i):
+        sshape = (batch, ring_slots(cfg, i, max_len) or max_len, cfg.kv_heads)
+        shape = sshape + (cfg.head_dim,)
+        if cfg.kv_cache_quant:
+            return (
                 jnp.zeros(shape, dtype=jnp.int8),
                 jnp.zeros(shape, dtype=jnp.int8),
                 jnp.ones(sshape, dtype=jnp.float32),
                 jnp.ones(sshape, dtype=jnp.float32),
             )
-            for _ in range(cfg.n_layer)
-        )
-    dtype = dtype or cfg.compute_dtype
-    zero = lambda: jnp.zeros(shape, dtype=dtype)
-    return tuple((zero(), zero()) for _ in range(cfg.n_layer))
+        return jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype)
+
+    return tuple(layer(i) for i in range(cfg.n_layer))
 
 
 def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
@@ -1263,12 +1403,13 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
     reserved by the engine pool) absorbs dead rows' clamped writes — masked
     reads weight stale content by an exact softmax zero, which only stays
     zero if the content (values AND scales) is finite."""
-    if cfg.attention != "mha":
-        raise NotImplementedError(f"the paged pool is not built for attention {cfg.attention!r}")
-    shape = (n_blocks, block_size, cfg.n_head, cfg.head_dim)
+    if cfg.attention != "mha" or cfg.window_cache != "span":
+        raise NotImplementedError(
+            f"the paged pool is not built for attention {cfg.attention!r} or window_cache {cfg.window_cache!r}")
+    shape = (n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_cache_quant:
         assert dtype is None, "kv_cache_quant caches are int8; dtype not honored"
-        sshape = (n_blocks, block_size, cfg.n_head)
+        sshape = (n_blocks, block_size, cfg.kv_heads)
         return tuple(
             (
                 jnp.zeros(shape, dtype=jnp.int8),
@@ -1285,8 +1426,9 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
 
 def cache_partition_spec(cfg: LMConfig, leaf_ndim: int):
     """PartitionSpec of one leaf of `init_cache`'s pytree: batch over the data
-    axes, heads over tp. An "mla" cache has no head axis: one latent a token
-    serves every head, so it is whole on every tp shard."""
+    axes, heads over tp (grouped keys: the kv_heads; a ring layer's leaves
+    have the same axes, fewer slots). An "mla" cache has no head axis: one
+    latent a token serves every head, so it is whole on every tp shard."""
     from jax.sharding import PartitionSpec
 
     from trlx_tpu.parallel.mesh import AXIS_TP, DATA_AXES
@@ -1298,8 +1440,18 @@ def cache_partition_spec(cfg: LMConfig, leaf_ndim: int):
     return PartitionSpec(DATA_AXES, None, AXIS_TP, None) if leaf_ndim == 4 else PartitionSpec(DATA_AXES, None, AXIS_TP)
 
 
+def cache_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
+    """Bytes of the cache `init_cache(cfg, batch, max_len)` allocates, all
+    layers, from its own shapes: the counter `rollout/cache_bytes` at the
+    generate program's batch and length."""
+    leaves = jax.tree_util.tree_leaves(jax.eval_shape(lambda: init_cache(cfg, batch, max_len)))
+    return int(sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves))
+
+
 def cache_bytes_per_token(cfg: LMConfig) -> int:
     """Bytes the cache holds a token, all layers: the counter
-    `rollout/cache_bytes_per_token`, from `init_cache`'s own shapes."""
-    leaves = jax.tree_util.tree_leaves(jax.eval_shape(lambda: init_cache(cfg, 1, 1)))
-    return int(sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves))
+    `rollout/cache_bytes_per_token`, from `init_cache`'s own shapes at one
+    row of one token. A ring layer counts its ring once: one slot, like a
+    full-span layer's, though past window_size tokens it grows no further
+    (`rollout/cache_bytes` is the whole allocation)."""
+    return cache_bytes(cfg, 1, 1)

@@ -50,7 +50,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.models.lm import ACTIVATIONS, MLP, LMConfig
+from trlx_tpu.models.lm import ACTIVATIONS, MLP, LMConfig, drawn_in
 from trlx_tpu.parallel.schedule import use_weight
 
 BIAS_NAME = "e_score_correction_bias"
@@ -166,14 +166,14 @@ class ExpertLayer(nn.Module):
         dtype, d, f = cfg.compute_dtype, cfg.d_model, cfg.expert_d_ff
         first, held = cfg.held_experts
         b, t, _ = x.shape
-        router = self.param("router", nn.initializers.lecun_normal(), (d, cfg.n_experts), cfg.params_dtype)
+        router = self.param("router", drawn_in(cfg.draw_dtype, nn.initializers.lecun_normal()), (d, cfg.n_experts), cfg.params_dtype)
         # Drawn from the seed, small: a trained router's bias is not zero, and
         # a zero one would let a program that forgot it pass every comparison;
         # but the bias exists to even the load out, and a random one of the
         # scores' own size (deviation 0.1 against the sigmoid's 0.2) skews it:
         # the chip read a fullest expert at 6-15 times the mean (PERF.md, PR 26).
         bias = self.param(BIAS_NAME, nn.initializers.normal(stddev=0.01), (cfg.n_experts,), jnp.float32)
-        stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        stacked = drawn_in(cfg.draw_dtype, nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,)))
         # on a partitioned mesh a call of many tokens gathers the router and the
         # stacks here, as a dense layer gathers its kernels (parallel/schedule.py)
         at_use = lambda w, name: use_weight(w, self.path + (name,), b * t)

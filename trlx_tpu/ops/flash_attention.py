@@ -269,6 +269,13 @@ def _own_row(bh, i, im):
     return bh, 0, i
 
 
+def _resident_kv(group: int):
+    """K and V as the forward and dq hold them resident: query head `bh` reads
+    K/V head `bh // group`. Consecutive grid steps of one group name the same
+    block, so it is fetched once a group. A group of 1 is `_resident` itself."""
+    return _resident if group == 1 else (lambda bh, i, im: (bh // group, im, 0))
+
+
 def _rows(j, chunk):
     return pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
 
@@ -351,6 +358,7 @@ def _fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret):
     block, major, chunk = blocks
     n_block, n_major, per_major = _geometry(T, blocks)
     H = BH // kmask.shape[0]
+    kv = _resident_kv(BH // k.shape[0])
     _check_layout(BH, T, D, blocks, interpret)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window, blocks=blocks, n_major=n_major
@@ -363,8 +371,8 @@ def _fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret):
             _smem_spec(),
             _vmem_spec((1, per_major, 1, chunk), lambda bh, i, im: (bh // H, im, 0, 0)),
             _vmem_spec((1, block, D), _own),
-            _vmem_spec((1, major, D), _resident),
-            _vmem_spec((1, major, D), _resident),
+            _vmem_spec((1, major, D), kv),
+            _vmem_spec((1, major, D), kv),
         ],
         out_specs=[_vmem_spec((1, block, D), _own), _vmem_spec((1, 1, block), _own_row)],
         out_shape=[
@@ -413,11 +421,14 @@ def _bwd_dq_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, del
 
 
 def _bwd_dkv_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *scratch, scale, causal, window, blocks, n_major):
+                    dk_ref, dv_ref, *scratch, scale, causal, window, blocks, n_major, group):
     """The mirror image: a key block resident, a loop over the live query
-    chunks, on the transposed score tile [keys, queries]."""
+    chunks, on the transposed score tile [keys, queries]. Grouped keys: the
+    innermost grid dimension walks the group's query heads (and within each
+    its major pieces), and dk, dv sum over them in the same scratch."""
     block, major, chunk = blocks
-    i_major = pl.program_id(2)
+    i_inner = pl.program_id(2)
+    i_major = i_inner if group == 1 else 0 if n_major == 1 else jax.lax.rem(i_inner, jnp.int32(n_major))
     bounds, scores = _band_setup(
         off_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
         keys_own_block=True,
@@ -441,7 +452,7 @@ def _bwd_dkv_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, de
         dv_ref[0] = carry[1].astype(dv_ref.dtype)
 
     fresh = (jnp.zeros((block, k_ref.shape[-1]), jnp.float32),) * 2
-    _across_major(i_major, n_major, scratch, fresh, lambda c: jax.lax.fori_loop(*bounds, step, c), finish)
+    _across_major(i_inner, group * n_major, scratch, fresh, lambda c: jax.lax.fori_loop(*bounds, step, c), finish)
 
 
 # ---------------------------------------------------------------------------
@@ -467,6 +478,8 @@ def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
     q, k, v, kmask, off, o, lse = res
     BH, T, D = q.shape
     H = BH // kmask.shape[0]
+    group = BH // k.shape[0]
+    kv = _resident_kv(group)
     block, major, chunk = blocks
     n_block, n_major, per_major = _geometry(T, blocks)
     # d s_ij = p_ij (dp_ij - delta_i); with lse also an output,
@@ -479,7 +492,7 @@ def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
 
     common = dict(scale=scale, causal=causal, window=window, blocks=blocks, n_major=n_major)
     grid = (BH, n_block, n_major)
-    scratch = lambda n: [_scratch((block, D))] * n if n_major > 1 else []
+    scratch = lambda n, pieces=n_major: [_scratch((block, D))] * n if pieces > 1 else []
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
@@ -489,8 +502,8 @@ def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
             _smem_spec(),
             _vmem_spec((1, per_major, 1, chunk), lambda bh, i, im: (bh // H, im, 0, 0)),
             _vmem_spec((1, block, D), _own),
-            _vmem_spec((1, major, D), _resident),
-            _vmem_spec((1, major, D), _resident),
+            _vmem_spec((1, major, D), kv),
+            _vmem_spec((1, major, D), kv),
             _vmem_spec((1, block, D), _own),
             _vmem_spec((1, 1, block), _own_row),
             _vmem_spec((1, 1, block), _own_row),
@@ -504,27 +517,36 @@ def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
 
     # k-side: a step owns a key block; queries, dO and their row vectors
     # (one [1, chunk] row a loop step: `ref[0, j]`) are the resident side.
-    row_spec = _vmem_spec((1, per_major, 1, chunk), lambda bh, i, im: (bh, im, 0, 0))
+    # Grouped keys: the grid's first dimension is the K/V heads, and the
+    # innermost walks the `group` query heads of each (times the major pieces),
+    # summing dk and dv in scratch: the result is [b * kv heads, T, D].
+    if group == 1:
+        resident, rows, key_heads = _resident, (lambda bh, i, im: (bh, im, 0, 0)), H
+    else:
+        resident = lambda bh, i, im: (bh * group + im // n_major, im % n_major, 0)
+        rows = lambda bh, i, im: (bh * group + im // n_major, im % n_major, 0, 0)
+        key_heads = H // group
+    row_spec = _vmem_spec((1, per_major, 1, chunk), rows)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **common),
+        functools.partial(_bwd_dkv_kernel, group=group, **common),
         name="flash_bwd_dkv",
-        grid=grid,
+        grid=(k.shape[0], n_block, group * n_major),
         in_specs=[
             _smem_spec(),
-            _vmem_spec((1, 1, block), lambda bh, i, im: (bh // H, 0, i)),
-            _vmem_spec((1, major, D), _resident),
+            _vmem_spec((1, 1, block), lambda bh, i, im: (bh // key_heads, 0, i)),
+            _vmem_spec((1, major, D), resident),
             _vmem_spec((1, block, D), _own),
             _vmem_spec((1, block, D), _own),
-            _vmem_spec((1, major, D), _resident),
+            _vmem_spec((1, major, D), resident),
             row_spec,
             row_spec,
         ],
         out_specs=[_vmem_spec((1, block, D), _own), _vmem_spec((1, block, D), _own)],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, T, D), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        scratch_shapes=scratch(2),
+        scratch_shapes=scratch(2, group * n_major),
         interpret=interpret,
         **_compiler_params(interpret),
     )(off, _key_bias(kmask), q, k, v, do, _by_chunk(lse, chunk), _by_chunk(delta, chunk))
@@ -549,7 +571,11 @@ def flash_attention(
     interpret: Optional[bool] = None,
     return_lse: bool = False,
 ):
-    """Fused causal attention over [b, T, n_head, head_dim] inputs.
+    """Fused causal attention over q [b, T, n_head, head_dim] and k, v
+    [b, T, kv heads, head_dim]. Grouped keys (kv heads < n_head, dividing it):
+    K/V head j serves query heads [j * g, (j + 1) * g) through the kernels'
+    index maps, K and V are never repeated, and dk, dv come back at the kv
+    heads, summed over each group inside the dk/dv kernel.
 
     kv_mask: [b, T] key-slot validity (0 at left-padding). `window > 0`
     restricts keys to the trailing window (gpt-neo local layers). `offset`
@@ -569,8 +595,11 @@ def flash_attention(
     # are exact in float32 far beyond any real sequence length (2^24).
     off = jnp.asarray(0.0 if offset is None else offset, jnp.float32).reshape(1, 1)
 
+    if h % k.shape[2] or v.shape[2] != k.shape[2]:
+        raise ValueError(f"{k.shape[2]} key / {v.shape[2]} value heads do not group {h} query heads")
+
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, T, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], T, d)
 
     o, lse = _flash_lse(
         to_bh(q), to_bh(k), to_bh(v), kv_mask.astype(jnp.float32)[:, None, :],

@@ -108,7 +108,7 @@ def generate(
 
         data = int(mesh.shape[mesh_mod.AXIS_DP]) * int(mesh.shape[mesh_mod.AXIS_FSDP])
         tp = int(mesh.shape[mesh_mod.AXIS_TP])
-        if B % data == 0 and cfg.n_head % tp == 0:
+        if B % data == 0 and cfg.kv_heads % tp == 0:
             cache = jax.tree_util.tree_map(
                 lambda x: jax.lax.with_sharding_constraint(
                     x, NamedSharding(mesh, cache_partition_spec(cfg, x.ndim))
@@ -120,7 +120,7 @@ def generate(
 
             warnings.warn(
                 f"decode KV cache left to XLA propagation: batch {B} or "
-                f"n_head {cfg.n_head} does not divide the mesh "
+                f"{cfg.kv_heads} cache heads do not divide the mesh "
                 f"(data={data}, tp={tp}) — at large scale this can "
                 "replicate the cache per device"
             )

@@ -69,7 +69,17 @@ def kv_read_ranges(cache_len: int, window: int = 0) -> Tuple[Tuple[int, int], ..
 
 
 def attend(q, k, v, attn_bias, scale, dtype):
-    """Softmax attention of `q` [b, q, h, d] over `k`/`v` [b, kv, h, d]."""
+    """Softmax attention of `q` [b, q, h, d] over `k`/`v` [b, kv, h_kv, d].
+    Grouped keys (h_kv < h): K/V head j serves query heads [j * g, (j + 1) * g),
+    g = h // h_kv, by a reshape of the query heads; K and V are read as they
+    are, never repeated."""
+    h, h_kv = q.shape[2], k.shape[2]
+    if h != h_kv:
+        b, q_len, _, d = q.shape
+        grouped = q.astype(jnp.float32).reshape(b, q_len, h_kv, h // h_kv, d)
+        scores = jnp.einsum("bqhgd,bkhd->bhgqk", grouped, k.astype(jnp.float32)) * scale
+        probs = jax.nn.softmax(scores + attn_bias[:, :, None], axis=-1).astype(dtype)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(dtype)).reshape(b, q_len, h, d)
     # [b, n_head, q, kv] scores in fp32 for a stable softmax.
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
     scores = scores * scale
@@ -156,19 +166,25 @@ def ranged_read(cache_len: int, q_len: int, cache_index, window: int = 0, *,
 
 
 def kv_keys_read(
-    cache_len: int, first_index: int, steps: int, windows: Sequence[int]
+    cache_len: int, first_index: int, steps: int, windows: Sequence[int], rings: Sequence[int] = ()
 ) -> Tuple[int, int]:
-    """(keys read, keys a full-cache read would have touched) by the decode
+    """(keys read, keys a full-span read would have touched) by the decode
     steps of one rollout, summed over layers (`windows`: each layer's window,
-    0 = global) and over `steps` steps writing slots `first_index`,
-    `first_index + 1`, ... Counted on the host from shapes alone; their ratio
-    over a rollout phase is the counter `rollout/kv_read_share`."""
+    0 = global; `rings`: each layer's ring slots, 0 or absent = a full-span
+    cache) and over `steps` steps writing slots `first_index`,
+    `first_index + 1`, ... A ring layer reads its ring, every step, on any
+    mesh. Counted on the host from shapes alone; their ratio over a rollout
+    phase is the counter `rollout/kv_read_share`."""
+    rings = tuple(rings) or (0,) * len(windows)
     full = cache_len * steps * len(windows)
-    if partitioned():
-        return full, full
     branch = (first_index + np.arange(steps)) // kv_read_bucket(cache_len)
     read = 0
-    for window in windows:
-        width = np.array([hi - lo for lo, hi in kv_read_ranges(cache_len, window)])
-        read += int(width[branch].sum())
+    for window, ring in zip(windows, rings):
+        if ring:
+            read += ring * steps
+        elif partitioned():
+            read += cache_len * steps
+        else:
+            width = np.array([hi - lo for lo, hi in kv_read_ranges(cache_len, window)])
+            read += int(width[branch].sum())
     return read, full

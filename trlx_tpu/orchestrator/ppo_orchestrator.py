@@ -27,7 +27,7 @@ import jax
 import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
-from trlx_tpu.models.lm import cache_bytes_per_token
+from trlx_tpu.models.lm import cache_bytes, cache_bytes_per_token, layer_window, ring_slots
 from trlx_tpu.ops.kv_read import kv_keys_read
 from trlx_tpu.parallel.schedule import weight_gather_share
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
@@ -262,10 +262,8 @@ class PPOOrchestrator(Orchestrator):
         # would have touched (ops/kv_read.py): from shapes and step counts.
         lm_cfg = rl.model.cfg
         n_soft = lm_cfg.n_soft_tokens
-        layer_windows = [
-            lm_cfg.window_size if kind == "local" else 0
-            for kind in lm_cfg.attention_layers or ("global",) * lm_cfg.n_layer
-        ]
+        layer_windows = [layer_window(lm_cfg, i) for i in range(lm_cfg.n_layer)]
+        cache_alloc = 0  # bytes of the cache the generate program allocated (the last chunk's)
         kv_keys = np.zeros(2, dtype=np.int64)
         experts_touched = []  # a model with expert layers: one reading a chunk
         # Final-chunk stats for logging; placeholders are never logged (the
@@ -407,9 +405,12 @@ class PPOOrchestrator(Orchestrator):
                 ds = rl.rollout_decode_stats(mask_h, P)
                 gen_tokens += ds["gen_tokens"]
                 decode_steps.append(ds["decode_steps"])
+                cache_len = mask_h.shape[1] + n_soft
                 kv_keys += np.array(kv_keys_read(
-                    mask_h.shape[1] + n_soft, P + n_soft, ds["decode_steps"], layer_windows
+                    cache_len, P + n_soft, ds["decode_steps"], layer_windows,
+                    [ring_slots(lm_cfg, i, cache_len) for i in range(lm_cfg.n_layer)],
                 ))
+                cache_alloc = cache_bytes(lm_cfg, mask_h.shape[0], cache_len)
                 episode_steps.extend(int(v) for v in ds["episode_steps"])
                 step_budget = ds["decode_step_budget"]
                 if gen_aux is not None and "experts_touched_per_step" in gen_aux[0]:
@@ -527,6 +528,7 @@ class PPOOrchestrator(Orchestrator):
             "rollout/decode_steps": stats["exp_decode_dispatches"],
             "rollout/kv_read_share": float(kv_keys[0] / kv_keys[1]) if kv_keys[1] else 1.0,
             "rollout/cache_bytes_per_token": float(cache_bytes_per_token(lm_cfg)),
+            "rollout/cache_bytes": float(cache_alloc),
         }
         if experts_touched:
             rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))

@@ -38,6 +38,9 @@ def lm_partition_rules() -> List[Tuple[str, P]]:
         # attention: fused qkv [d_model, 3*d] column-parallel
         (r"attn/c_qkv/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"attn/c_qkv/bias$", P(AXIS_TP)),
+        # (grouped keys: k_proj / v_proj are kv_heads * head_dim columns, split
+        # over tp like q_proj's; a tp that does not divide them leaves them
+        # whole, `sanitize_specs`)
         (r"attn/(q_proj|k_proj|v_proj)/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"attn/(q_proj|k_proj|v_proj)/bias$", P(AXIS_TP)),
         # attention output [d, d_model] row-parallel
@@ -67,7 +70,9 @@ def lm_partition_rules() -> List[Tuple[str, P]]:
         (r"lm_head/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"lm_head/bias$", P(AXIS_TP)),
         # layer norms / scalars — replicated
-        (r"(ln_1|ln_2|ln_f|q_a_norm|kv_a_norm|layernorm.*)/(scale|bias)$", P()),
+        # (qk-norm's two scales are head_dim numbers shared by every head:
+        # nothing to split)
+        (r"(ln_1|ln_2|ln_f|q_a_norm|kv_a_norm|q_norm|k_norm|layernorm.*)/(scale|bias)$", P()),
         # value / Q heads (2-layer MLPs, small) — shard the wide hidden dim
         (r"(v_head|q1_head|q2_head|target_q1_head|target_q2_head)/layers_0/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"(v_head|q1_head|q2_head|target_q1_head|target_q2_head)/layers_0/bias$", P(AXIS_TP)),
