@@ -167,9 +167,26 @@ def test_flash_with_a_group_of_8_matches_the_einsum(window, blocks):
         np.testing.assert_allclose(g, w, atol=5e-5)
 
 
-def test_the_expert_shares_add_up_to_the_uncut_layer():
+LARGE_CALL_PATHS = {  # 48 tokens, 2 a token, a share of 4 of 16 experts: some 24 held slots of 96
+    "small call": None,
+    "large call, the buffer from the shapes": "shapes",
+    "large call, a buffer that holds the slots": 64,
+    "large call, in token chunks": "chunks",
+    "large call, every held expert over every token": 8,
+}
+
+
+@pytest.mark.parametrize("path", LARGE_CALL_PATHS)
+def test_the_expert_shares_add_up_to_the_uncut_layer(monkeypatch, path):
     """The parts all four shares of 4 experts give, the shared expert counted
-    once, add up to the layer that holds all 16."""
+    once, add up to the layer that holds all 16, on every path of
+    `held_experts_ffn` (the uncut layer takes the same path)."""
+    if LARGE_CALL_PATHS[path] is not None:
+        monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)
+    if isinstance(LARGE_CALL_PATHS[path], int):
+        monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: LARGE_CALL_PATHS[path])
+    if LARGE_CALL_PATHS[path] == "chunks":
+        monkeypatch.setattr(moe, "TOKEN_CHUNK", 12)  # 48 tokens: four passes
     whole_cfg = LMConfig.from_dict({**ARCH, **F32, "experts_held": []})
     x = jax.random.normal(jax.random.PRNGKey(5), (B, T, whole_cfg.d_model))
     layer = moe.ExpertLayer(whole_cfg)
@@ -183,6 +200,25 @@ def test_the_expert_shares_add_up_to_the_uncut_layer():
         part = {**whole, **{name: whole[name][first:first + 4] for name in ("experts_gate", "experts_up", "experts_down")}}
         total = total + moe.ExpertLayer(cfg).apply({"params": part}, x)[0] - shared
     np.testing.assert_allclose(total, want, atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("n_experts, first_rows", [(128, 4096), (384, 1536)], ids=["K-EXAONE: 8 of 128", "Kimi: 8 of 384"])
+def test_the_first_buffer_at_the_cells_train_shape(n_experts, first_rows):
+    """A train step, a scoring chunk and the prefill are calls of 4,096 tokens,
+    8 a token, 8 held: the slot buffer is twice the even share in whole tiles
+    of 512 rows, of the 8,192 it was before PR 31; the counter reads 1.0 at the
+    held share the chip read (ledger, PR 30: 0.0622 and 0.0202) and at one and
+    a half times it, and says which layers passed the first buffer."""
+    assert moe.slot_capacity(4096, 8, 8, n_experts) == first_rows < moe.SLOTS_PER_TOKEN * 4096
+    assert first_rows % moe.ROW_TILE == 0 and first_rows >= 2 * 4096 * 8 * 8 / n_experts > first_rows - moe.ROW_TILE
+    even = 4096 * 8 * 8 // n_experts
+    counts = jnp.full((4, 8), even // 8, jnp.int32)
+    assert float(moe.first_buffer_share(counts, 4096, 8, n_experts)) == 1.0
+    assert float(moe.first_buffer_share(counts * 3 // 2, 4096, 8, n_experts)) == 1.0
+    over = counts.at[2, 0].add(first_rows - int(counts[2].sum()) + 1)  # one slot more than the buffer's rows
+    assert float(moe.first_buffer_share(over, 4096, 8, n_experts)) == 0.75
+    # a scoring pass over a rollout chunk: 8 chunks of 4,096 tokens, each with a first buffer of its own
+    assert float(moe.first_buffer_share(counts * 8, 32768, 8, n_experts)) == 1.0
 
 
 @pytest.mark.parametrize("bad, message", [
@@ -344,6 +380,7 @@ def test_ppo_two_iterations_on_the_normal_path(tmp_path):
         assert abs(steps[first]["mean_ratio"] - 1.0) < 1e-3, steps[first]["mean_ratio"]
     for r in steps.values():
         assert 0.0 < r["moe/held_slot_share"] < 1.0 and r["moe/max_expert_load"] >= 1.0
+        assert r["moe/first_buffer_share"] == 1.0  # a tiny model's train step is a small call: no buffer to overflow
     phases = [r for r in records if "time/window_wall_s" in r]
     itemsize = trainer.model.cfg.compute_dtype.itemsize
     assert phases and all(p["rollout/cache_bytes"] == 8 * (4 * 8 + 24) * 2 * 2 * 16 * itemsize for p in phases)

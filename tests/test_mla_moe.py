@@ -47,7 +47,7 @@ def test_logits_match_the_reference_padded_and_unpadded_rows(monkeypatch, row_gr
     import trlx_tpu.models.lm as lm
 
     monkeypatch.setattr(lm, "MLA_ROW_GROUP", row_group)
-    monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0 if row_group == 1 else 2048)  # and the large call's sorted path
+    monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0 if row_group == 1 else 2048)  # and the large call's grouped path
     cfg, model, params, ids, mask = _model()
     got = model.apply({"params": params}, ids, mask)["logits"]
     want = reference.forward(params, ARCH, ids, mask, T)
@@ -141,20 +141,23 @@ def _held_counts_per_position(params, ids, mask):
     return np.asarray(jnp.stack(out))
 
 
-@pytest.mark.parametrize("capacity", ["small call", "worst-case buffer", "sized buffer", "overflow: dense path", "token chunks"])
+@pytest.mark.parametrize("capacity", ["small call", "worst-case buffer", "sized buffer", "twice the even share",
+                                      "overflow: dense path", "token chunks"])
 def test_the_shares_add_up(monkeypatch, capacity):
     """(d) 16 experts as 4 shares of 4: the routed parts summed and the shared
     expert counted once equal the uncut reference layer, on every path of
     `held_experts_ffn`."""
     if capacity != "small call":
-        monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)  # 96 token-slots: the sorted path of a large call
+        monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)  # 96 token-slots: the grouped path of a large call
     if capacity == "sized buffer":
         # 96 slots, some 24 of them held in a share: a buffer of 64 rows holds them, under the `cond`
-        monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held: 64)
+        monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: 64)
     elif capacity == "token chunks":
         monkeypatch.setattr(moe, "TOKEN_CHUNK", 12)  # 48 tokens: four passes
+    elif capacity == "twice the even share":
+        monkeypatch.setattr(moe, "ROW_TILE", 8)  # `slot_capacity`'s own rule: 2 x 24 slots a share = 48 rows of 96
     elif capacity == "overflow: dense path":
-        monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held: 8)
+        monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: 8)
     one = {**ARCH, "n_layer": 2, "ffn_layers": ["dense", "experts"], "experts_held": [0, 16]}
     cfg, model, params, ids, mask = _model(one)
     whole = params["h_1"]["moe"]
@@ -275,8 +278,11 @@ def test_counters_by_hand():
     np.testing.assert_allclose(np.asarray(weights[0])[order], sig / sig.sum() * 3.0, rtol=1e-6)
     np.testing.assert_allclose(weights[1], [1.5, 1.5], rtol=1e-6)
     assert 32 * 8 <= moe.SMALL_CALL_SLOTS < 4096 * 8  # a decode step is a small call, a train step is not
-    assert moe.slot_capacity(4096, 8, 8) == 8192  # two slots a token; the worst case is 32768
-    assert moe.slot_capacity(48, 2, 4) == 96  # the worst case where that is no larger
+    # twice the even share in whole tiles of 512 rows; the worst case is 32768
+    assert moe.slot_capacity(4096, 8, 8, 384) == 1536  # Kimi's train step: 2 x 682.7 slots
+    assert moe.slot_capacity(4096, 8, 8, 128) == 4096  # K-EXAONE's: 2 x 2048
+    assert moe.slot_capacity(4096, 8, 8, 32) == 8192  # never more than two rows a token
+    assert moe.slot_capacity(48, 2, 4, 16) == 96  # nor than the worst case, where that is no larger
 
 
 def _primitives(jaxpr):
@@ -295,13 +301,196 @@ def test_a_decode_step_runs_every_held_expert_whatever_the_routing():
     down = jax.random.normal(jax.random.PRNGKey(2), (4, 16, 8), jnp.float32)
     ids = jnp.array([[4, 9], [9, 4], [0, 1], [6, 4], [4, 6], [1, 0]], jnp.int32)  # of the held [4, 8): 5 and 7 untouched
     weights = jax.random.uniform(jax.random.PRNGKey(3), (6, 2), jnp.float32)
-    call = lambda x: moe.held_experts_ffn(x, ids, weights, 4, gate, up, down, jax.nn.silu)
-    assert "cond" not in set(_primitives(jax.make_jaxpr(call)(x).jaxpr))
+    call = lambda x: moe.held_experts_ffn(x, ids, weights, 4, 16, gate, up, down, jax.nn.silu)
+    assert not {"cond", "sort", "scatter", "scatter-add", "ragged_dot_general"} & set(_primitives(jax.make_jaxpr(call)(x).jaxpr))
     y, counts = call(x)
     np.testing.assert_array_equal(counts, [4, 0, 2, 0])
     want = sum(jnp.where((ids == e)[..., None], weights[..., None], 0.0).sum(1)
                * ((jax.nn.silu(x @ gate[e - 4]) * (x @ up[e - 4])) @ down[e - 4]) for e in (4, 6))
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+
+
+def _routing(case, n=64, k=2, n_experts=16, first=4, held=4):
+    """ids [n, k] int32 of a large call, the held experts `[first, first + held)`."""
+    if case == "no token chooses a held expert":
+        return jnp.tile(jnp.array([[0, 15]], jnp.int32), (n, 1))
+    if case == "every token chooses the same one":
+        return jnp.tile(jnp.array([[first + 2, 0]], jnp.int32), (n, 1))
+    if case == "every token chooses two held ones":
+        return jnp.tile(jnp.array([[first + 3, first]], jnp.int32), (n, 1))
+    _, ids = jax.lax.top_k(jax.random.uniform(jax.random.PRNGKey(int(case.split()[-1])), (n, n_experts)), k)
+    return ids.astype(jnp.int32)
+
+
+def _sorted_placement(ids, first, held, capacity):
+    """The placement the stable `argsort` of PR 26 gave: (slot, live)."""
+    local = ids.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)[:capacity]
+    return order, key[order] < held
+
+
+@pytest.mark.parametrize("capacity", [24, 32, 128], ids=lambda rows: f"{rows} rows")
+@pytest.mark.parametrize("case", ["seed 0", "seed 1", "seed 2", "no token chooses a held expert",
+                                  "every token chooses the same one", "every token chooses two held ones"])
+def test_the_counted_placement_is_the_stable_sort_row_for_row(case, capacity):
+    """`place_slots` puts every held slot where the stable sort by expert put
+    it, so the grouped products see the same groups in the same order; rows
+    past the held slots are dead; a buffer too small holds the first rows."""
+    ids = _routing(case)
+    counts = moe.held_counts(ids, 4, 4)
+    slot, live = moe.place_slots(ids, counts, 4, capacity)
+    want_slot, want_live = _sorted_placement(ids, 4, 4, capacity)
+    placed = min(int(counts.sum()), capacity)
+    assert slot.shape == live.shape == (capacity,) and slot.dtype == jnp.int32
+    np.testing.assert_array_equal(slot[:placed], want_slot[:placed])
+    if int(counts.sum()) <= capacity:
+        np.testing.assert_array_equal(live, want_live)
+        assert int(live.sum()) == placed and not np.any(slot[placed:])  # dead rows name slot 0
+    # a live row's expert, read back from its slot, is its group's
+    expert = ids.reshape(-1)[slot[:placed]] - 4
+    np.testing.assert_array_equal(expert, np.repeat(np.arange(4), np.asarray(counts))[:placed])
+
+
+def _expert_call(seed=0, n=64, d=8, f=16, held=4):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(keys[0], (n, d), jnp.float32)
+    weights = jax.random.uniform(keys[1], (n, 2), jnp.float32, 0.2, 1.0)
+    gate, up = jax.random.normal(keys[2], (2, held, d, f), jnp.float32) * 0.5
+    down = jax.random.normal(keys[3], (held, f, d), jnp.float32) * 0.5
+    return x, weights, gate, up, down
+
+
+RUNGS = {  # rows of the slot buffer; the held slots of `_routing("seed 0")`: 33
+    "the buffer holds the slots with room": 40,
+    "held slots exactly the buffer's rows": 33,
+    "one more than the buffer's rows: every held expert over every token": 32,
+    "far past the buffer": 8,
+    "the worst case: no cond": 128,
+    "twice the even share in tiles of 8 rows": None,  # `slot_capacity`'s own rule: 64 rows
+}
+
+
+@pytest.mark.parametrize("rung", RUNGS)
+def test_each_rung_matches_every_held_expert_over_every_token(monkeypatch, rung):
+    """The large call is exact for any routing, whichever path the held slots
+    select: forward and the gradient of every input a training run moves
+    (tokens, slot weights, the three stacks) equal `dense_held_ffn`'s in
+    float32; and the grouped path is taken exactly where the buffer holds
+    the slots."""
+    monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)
+    if RUNGS[rung] is None:
+        monkeypatch.setattr(moe, "ROW_TILE", 8)
+        assert moe.slot_capacity(64, 2, 4, 16) == 64
+    else:
+        monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: RUNGS[rung])
+    ids = _routing("seed 0")
+    assert int(moe.held_counts(ids, 4, 4).sum()) == 33
+    args = _expert_call()
+    large = lambda x, w, gate, up, down: moe.held_experts_ffn(x, ids, w, 4, 16, gate, up, down, jax.nn.silu)[0]
+    dense = lambda x, w, gate, up, down: moe.dense_held_ffn(x, ids, w, 4, gate, up, down, jax.nn.silu)
+    np.testing.assert_allclose(large(*args), dense(*args), atol=1e-5, rtol=1e-5)
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))
+    got = jax.grad(loss(large), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, g, w in zip(("x", "weights", "gate", "up", "down"), got, want):
+        assert float(jnp.abs(w).max()) > 1e-3, name
+        np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-4, err_msg=name)
+
+    # which path ran: each stands in for itself with a constant
+    monkeypatch.setattr(moe, "grouped_held_ffn", lambda x, ids, weights, counts, first, capacity, *rest: jnp.full_like(x, capacity))
+    monkeypatch.setattr(moe, "dense_held_ffn", lambda x, *rest: jnp.full_like(x, -1.0))
+    taken = float(moe.held_experts_ffn(args[0], ids, args[1], 4, 16, *args[2:], jax.nn.silu)[0][0, 0])
+    rows = moe.slot_capacity(64, 2, 4, 16)
+    assert taken == (rows if 33 <= rows else -1.0)
+
+
+@pytest.mark.parametrize("rung", ["the buffer holds the slots with room", "the worst case: no cond"])
+def test_what_a_grouped_product_leaves_in_dead_rows_reaches_nothing(monkeypatch, rung):
+    """A grouped product leaves the rows outside its groups unwritten, in its
+    result and in its lhs-gradient (on the chip: whatever was there, PERF.md
+    PR 26). Non-finite values planted in both reach neither the result nor a
+    gradient: every operand of a product and its last result pass the mask."""
+    plain = jax.lax.ragged_dot
+
+    @jax.custom_vjp
+    def planted(lhs, rhs, group_sizes):
+        dead = jnp.arange(lhs.shape[0])[:, None] >= jnp.sum(group_sizes)
+        return jnp.where(dead, jnp.nan, plain(lhs, rhs, group_sizes))
+
+    def planted_fwd(lhs, rhs, group_sizes):
+        return planted(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def planted_bwd(res, g):
+        lhs, rhs, group_sizes = res
+        dead = jnp.arange(lhs.shape[0])[:, None] >= jnp.sum(group_sizes)
+        # the transposes read the rows of the groups only, and leave the dead rows of the lhs-gradient unwritten
+        d_lhs, d_rhs = jax.vjp(lambda l, r: plain(l, r, group_sizes), lhs, rhs)[1](jnp.where(dead, 0, g))
+        return jnp.where(dead, jnp.inf, d_lhs), d_rhs, None
+
+    planted.defvjp(planted_fwd, planted_bwd)
+    monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)
+    monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: RUNGS[rung])
+    ids = _routing("seed 0")
+    args = _expert_call()
+    large = lambda x, w, gate, up, down: moe.held_experts_ffn(x, ids, w, 4, 16, gate, up, down, jax.nn.silu)[0]
+    loss = lambda *a: jnp.sum(jnp.sin(large(*a)))
+    want_y, want_g = large(*args), jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+    monkeypatch.setattr(jax.lax, "ragged_dot", planted)
+    got_y, got_g = large(*args), jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+    assert bool(jnp.all(jnp.isfinite(got_y)))
+    np.testing.assert_allclose(got_y, want_y, atol=1e-6)
+    for name, g, w in zip(("x", "weights", "gate", "up", "down"), got_g, want_g):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        np.testing.assert_allclose(g, w, atol=1e-6, rtol=1e-5, err_msg=name)
+
+
+def test_put_rows_is_the_transpose_of_take_rows():
+    """The two maps between tokens and buffer rows, by hand: a dead row takes
+    nothing and gives nothing, a token two rows name gets their sum, and each
+    is the other's gradient."""
+    x = jnp.arange(12, dtype=jnp.float32).reshape(4, 3) + 1
+    token, live = jnp.array([2, 0, 2, 0, 0], jnp.int32), jnp.array([True, True, True, False, False])
+    rows = moe.take_rows(4, x, token, live)
+    np.testing.assert_array_equal(rows, [x[2], x[0], x[2], [0, 0, 0], [0, 0, 0]])
+    v = jnp.arange(15, dtype=jnp.float32).reshape(5, 3) * 1.25 + 0.1
+    back = moe.put_rows(4, v, token, live)
+    np.testing.assert_array_equal(back, [v[1], [0, 0, 0], v[0] + v[2], [0, 0, 0]])
+    np.testing.assert_array_equal(back, jnp.zeros_like(x).at[token].add(jnp.where(live[:, None], v, 0)))
+    np.testing.assert_array_equal(jax.grad(lambda x: jnp.sum(moe.take_rows(4, x, token, live) * v))(x), back)
+    np.testing.assert_array_equal(jax.grad(lambda v: jnp.sum(moe.put_rows(4, v, token, live) * x))(v), rows)
+
+
+def test_first_buffer_share_by_hand(monkeypatch):
+    """`moe/first_buffer_share`: the expert layers of a step whose held slots
+    the first buffer holds, over the expert layers."""
+    counts = jnp.array([[700, 300, 300, 200], [800, 300, 300, 137], [0, 0, 0, 0], [1536, 1, 0, 0]], jnp.int32)
+    # 4,096 tokens, 8 a token, 4 held of 192: the first buffer is 2 x 682.7 slots in whole tiles = 1,536 rows
+    assert moe.slot_capacity(4096, 8, 4, 192) == 1536
+    assert float(moe.first_buffer_share(counts, 4096, 8, 192)) == 0.5  # 1500 fits, 1537 does not, 0 fits, 1537 does not
+    assert float(moe.first_buffer_share(counts[:1], 4096, 8, 192)) == 1.0
+    assert float(moe.first_buffer_share(counts[1:2], 4096, 8, 192)) == 0.0
+    # a call in token chunks is held to its chunks' buffers together; a small call has no buffer to overflow
+    assert float(moe.first_buffer_share(counts, 8192, 8, 192)) == 1.0
+    assert float(moe.first_buffer_share(counts // 16, 128, 8, 192)) == 1.0
+    # and the train step's record carries it beside the two counters of PR 26 (the PPO test below reads it)
+
+
+@pytest.mark.parametrize("call", ["large call", "large call in token chunks", "small call"])
+def test_no_call_sorts_and_the_small_call_neither_scatters_nor_groups(monkeypatch, call):
+    """The large call places its slots by counting: its jaxpr, forward and
+    backward, holds no `sort`; the small call's holds no `sort`, no scatter
+    and no grouped product, as before."""
+    n = {"large call": 2048, "large call in token chunks": 8192, "small call": 128}[call]
+    args = (jax.ShapeDtypeStruct((n, 8), jnp.float32), jax.ShapeDtypeStruct((n, 8), jnp.int32), jax.ShapeDtypeStruct((n, 8), jnp.float32),
+            jax.ShapeDtypeStruct((8, 8, 16), jnp.float32), jax.ShapeDtypeStruct((8, 8, 16), jnp.float32), jax.ShapeDtypeStruct((8, 16, 8), jnp.float32))
+    fn = lambda x, ids, w, gate, up, down: jnp.sum(moe.held_experts_ffn(x, ids, w, 0, 384, gate, up, down, jax.nn.silu)[0])
+    names = set(_primitives(jax.make_jaxpr(jax.value_and_grad(fn, argnums=(0, 2, 3, 4, 5)))(*args).jaxpr))
+    assert "sort" not in names
+    if call == "small call":
+        assert not {"scatter", "scatter-add", "ragged_dot_general", "cond"} & names
+    else:
+        assert {"ragged_dot_general", "cond", "cumsum"} <= names
 
 
 def test_generate_carries_experts_touched_and_stays_one_loop():
@@ -378,6 +567,7 @@ def test_ppo_two_iterations_on_the_normal_path(tmp_path):
     for r in steps.values():
         assert np.isfinite(r["loss"]) if "loss" in r else True
         assert 0.0 < r["moe/held_slot_share"] < 1.0 and r["moe/max_expert_load"] >= 1.0
+        assert r["moe/first_buffer_share"] == 1.0  # a tiny model's train step is a small call: no buffer to overflow
     phases = [r for r in records if "time/window_wall_s" in r]
     assert phases and all(0.0 <= p["rollout/experts_touched"] <= 4.0 for p in phases)
     assert all(p["rollout/cache_bytes_per_token"] == (16 + 8) * 4 * 3 for p in phases)

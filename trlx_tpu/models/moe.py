@@ -31,19 +31,33 @@ become: 1% of an iteration between seeds), and in the deployment this layer
 is a share of, a chip's experts take the tokens of all the chips that share
 the layer and none goes untouched.
 
-A large call goes through in chunks of at most `TOKEN_CHUNK` tokens; a chunk's
-(token, choice) slots that chose a held expert are sorted by expert into a
-buffer of `slot_capacity(...)` rows and go through three grouped products
-(`jax.lax.ragged_dot`, one group an expert). The buffer holds two slots a
-token: room for two of the held experts to be chosen by EVERY token, a
-quarter of the worst case at 8 held and 8 a token (0.9 GB a buffer at 8,192
-tokens of width 7,168). A router over random weights is far from even, and a
-first buffer of four times the EVEN share overflowed in a quarter of the
-train steps. A chunk whose held slots exceed the buffer all the same takes
-`dense_held_ffn`: every held expert over every token, in token chunks
-recomputed in the backward pass.
+A large call goes through in chunks of at most `TOKEN_CHUNK` tokens. A chunk's
+(token, choice) slots that chose a held expert are placed in a buffer, one
+group an expert, and go through three grouped products (`jax.lax.ragged_dot`).
+Where a slot goes is counted, not sorted (`place_slots`): a token chooses an
+expert at most once, so a slot's row is its expert's offset plus the tokens
+before its own that chose the same expert, which is the row a stable sort by
+expert gives it. The rows are gathered from the tokens (`take_rows`) and summed
+back onto them (`put_rows`) as one product with the placement's 0/1 matrix:
+XLA's scatter-add cost more than the three products and did not fall with the
+rows (PERF.md, PR 31).
+
+The buffer is sized to the layer's share of the experts, from the call's
+shapes alone (`slot_capacity`): twice the even share of the held experts,
+`2 n k held / n_experts` slots in whole tiles of `ROW_TILE` rows (1,536 rows
+where 8 of 384 experts are held and 4,096 tokens choose 8 each, 4,096 where 8
+of 128 are), never more than `SLOTS_PER_TOKEN` rows a token. A call whose held
+slots pass it takes `dense_held_ffn`, every held expert over every token in
+token chunks, recomputed in the backward pass: a `lax.cond` on the call's held
+slots, exact for any routing. That path is for safety, not a ladder for speed:
+a step's time must not follow the routing from step to step, and at twice the
+even share the chip never left the buffer (`moe/first_buffer_share`, the share
+of a step's calls it served: 1.0). A second buffer of two rows a token between
+the two was built and taken out: a branch nobody took, it set the train step's
+temporaries (0.63 GB more on the chip at 8 of 384, PERF.md, PR 31).
 """
 
+import functools
 from typing import Tuple
 
 import flax.linen as nn
@@ -55,7 +69,8 @@ from trlx_tpu.parallel.schedule import use_weight
 
 BIAS_NAME = "e_score_correction_bias"
 SMALL_CALL_SLOTS = 2048  # token-slots (tokens x experts_per_token) up to which a call is "small": a decode step
-SLOTS_PER_TOKEN = 2  # the sorted buffer's rows a token, in a large call
+SLOTS_PER_TOKEN = 2  # the most rows a token the slot buffer of a large call takes
+ROW_TILE = 512  # a slot buffer is whole row tiles of the grouped product
 TOKEN_CHUNK = 4096  # tokens a pass: bounds the buffers of a scoring pass over a whole rollout chunk
 
 
@@ -71,9 +86,19 @@ def route(x, router, bias, k: int, scaling: float):
     return ids.astype(jnp.int32), weights
 
 
-def slot_capacity(n_tokens: int, k: int, held: int) -> int:
-    """Rows of the sorted slot buffer for a pass over `n_tokens` tokens."""
-    return min(n_tokens * min(k, held), SLOTS_PER_TOKEN * n_tokens)
+def slot_capacity(n_tokens: int, k: int, held: int, n_experts: int) -> int:
+    """Rows of the slot buffer of a large call over `n_tokens` tokens, from
+    shapes only: twice the even share of the held experts (`n_tokens * k *
+    held / n_experts` slots) in whole row tiles; never more than
+    `SLOTS_PER_TOKEN` rows a token, nor than the worst case, every token
+    choosing as many held experts as it can."""
+    even_twice = -(-2 * n_tokens * k * held // n_experts)
+    return min(-(-even_twice // ROW_TILE) * ROW_TILE, SLOTS_PER_TOKEN * n_tokens, n_tokens * min(k, held))
+
+
+def token_chunks(n_tokens: int) -> int:
+    """The passes of at most `TOKEN_CHUNK` tokens a call of `n_tokens` goes through in."""
+    return n_tokens // TOKEN_CHUNK if n_tokens > TOKEN_CHUNK and n_tokens % TOKEN_CHUNK == 0 else 1
 
 
 def held_counts(ids, first: int, held: int):
@@ -108,49 +133,93 @@ def dense_held_ffn(x, ids, weights, first: int, gate, up, down, act):
     return jax.lax.scan(one_chunk, None, (split(x), split(ids), split(weights)))[1].reshape(n + pad, d)[:n]
 
 
-def sorted_held_ffn(x, ids, weights, counts, first: int, capacity: int, gate, up, down, act):
-    """The held experts' part through grouped products: slots sorted by
-    expert, held ones first; rows past the held slots carry weight zero."""
-    held, k = gate.shape[0], ids.shape[-1]
-    local = ids.reshape(-1) - first
-    key = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(key, stable=True)[:capacity]
-    token = order // k
-    live = key[order] < held
-    w = jnp.where(live, weights.reshape(-1)[order], 0.0).astype(x.dtype)
+def place_slots(ids, counts, first: int, capacity: int):
+    """(slot [capacity] int32, live [capacity] bool): the (token, choice) slot
+    `token * k + choice` that each row of a slot buffer holds: the held experts
+    in order and an expert's tokens in theirs, which is where a stable sort of
+    the slots by expert puts them. By counting: a token chooses an expert at
+    most once, so a slot's row is its expert's offset (the counts before it)
+    plus the tokens before its own that chose the same expert. Rows past the
+    held slots are not live and name slot 0."""
+    n, k = ids.shape
+    held = counts.shape[0]
+    hit = ids.T[None] == first + jnp.arange(held, dtype=ids.dtype)[:, None, None]  # [held, k, n]: tokens along the lanes
+    chose = jnp.any(hit, axis=1)
+    rank = jnp.cumsum(chose, axis=1, dtype=jnp.int32) - chose
+    row = jnp.where(chose, (jnp.cumsum(counts) - counts)[:, None] + rank, capacity)
+    slot = jnp.arange(n, dtype=jnp.int32)[None] * k + jnp.argmax(hit, axis=1).astype(jnp.int32)
+    slot = jnp.zeros(capacity, jnp.int32).at[row.reshape(-1)].set(slot.reshape(-1), mode="drop")
+    return slot, jnp.arange(capacity) < jnp.sum(counts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def take_rows(n: int, x, token, live):
+    """[rows, d]: row r is `x[token[r]]` of `x` [n, d], zero where not live."""
+    return jnp.where(live[:, None], x[token], 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def put_rows(n: int, v, token, live):
+    """[n, d]: the transpose of `take_rows`, token t the sum of the live rows
+    of `v` [rows, d] that name it, as ONE product with the 0/1 matrix
+    `[n, rows]` of the placement: exact (a row is taken whole, the sum is in
+    float32). XLA's scatter-add does not fall with the rows: on the chip 3.21
+    ms at 1,536 rows of 7,168 where this takes 0.49, 1.63 at 4,096 rows of
+    6,144 where this takes 1.17 (PERF.md, PR 31). A dead row has to be
+    finite: 0 x NaN is NaN here too."""
+    takes = (token[None, :] == jnp.arange(n, dtype=token.dtype)[:, None]) & live[None, :]
+    return jnp.dot(takes.astype(v.dtype), v, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32).astype(v.dtype)
+
+
+take_rows.defvjp(lambda n, x, token, live: (take_rows(n, x, token, live), (token, live)),
+                 lambda n, placed, g: (put_rows(n, g, *placed), None, None))
+put_rows.defvjp(lambda n, v, token, live: (put_rows(n, v, token, live), (token, live)),
+                lambda n, placed, g: (take_rows(n, g, *placed), None, None))
+
+
+def grouped_held_ffn(x, ids, weights, counts, first: int, capacity: int, gate, up, down, act):
+    """The held experts' part through grouped products over a buffer of
+    `capacity` rows, which has to hold every held slot: gathered by
+    `place_slots`, one group an expert, summed back onto the tokens."""
+    n = x.shape[0]
+    slot, live = place_slots(ids, counts, first, capacity)
+    token = slot // ids.shape[-1]
+    w = jnp.where(live, weights.reshape(-1)[slot], 0.0).astype(x.dtype)
 
     # A grouped product writes only the rows of its groups: what it leaves in
     # the others is not defined (on the chip: whatever was there), in its
     # result and in the lhs-gradient its transpose computes alike; 0 x NaN is
     # NaN, and the first train steps on the chip were all non-finite. So every
     # operand a grouped product reads, and the last result, pass the mask:
-    # its forward keeps dead rows out of a sum or a scatter, its backward
-    # zeroes what the transposed product left in them.
+    # its forward keeps dead rows out of a sum, its backward zeroes what the
+    # transposed product left in them.
     alive = lambda a: jnp.where(live[:, None], a, 0)
-    xs = alive(x[token])
+    xs = alive(take_rows(n, x, token, live))
     with jax.named_scope("moe_grouped_ffn"):
         hidden = alive(act(jax.lax.ragged_dot(xs, gate, counts)) * jax.lax.ragged_dot(xs, up, counts))
         ys = alive(jax.lax.ragged_dot(hidden, down, counts))
-    return jnp.zeros_like(x).at[token].add(ys * w[:, None])
+    return put_rows(n, ys * w[:, None], token, live)
 
 
-def held_experts_ffn(x, ids, weights, first: int, gate, up, down, act):
+def held_experts_ffn(x, ids, weights, first: int, n_experts: int, gate, up, down, act):
     """(y [n, d], counts [held]): sum over the held experts a token chose of
-    w_e Expert_e(x); `gate`/`up` [held, d, f], `down` [held, f, d]."""
+    w_e Expert_e(x); `gate`/`up` [held, d, f], `down` [held, f, d], the held
+    experts `[first, first + held)` of `n_experts`."""
     n, k, held = x.shape[0], ids.shape[-1], gate.shape[0]
     if n * k <= SMALL_CALL_SLOTS:
         return experts_over_tokens(x, ids, weights, first, gate, up, down, act), held_counts(ids, first, held)
-    if n > TOKEN_CHUNK and n % TOKEN_CHUNK == 0:
+    if token_chunks(n) > 1:
         split = lambda a: a.reshape((n // TOKEN_CHUNK, TOKEN_CHUNK) + a.shape[1:])
-        y, counts = jax.lax.map(lambda args: held_experts_ffn(*args, first, gate, up, down, act),
+        y, counts = jax.lax.map(lambda args: held_experts_ffn(*args, first, n_experts, gate, up, down, act),
                                 (split(x), split(ids), split(weights)))
         return y.reshape(x.shape), jnp.sum(counts, axis=0)
     counts = held_counts(ids, first, held)
-    capacity = slot_capacity(n, k, held)
-    sorted_ffn = lambda: sorted_held_ffn(x, ids, weights, counts, first, capacity, gate, up, down, act)
+    capacity = slot_capacity(n, k, held, n_experts)
+    grouped_ffn = lambda: grouped_held_ffn(x, ids, weights, counts, first, capacity, gate, up, down, act)
     if capacity >= n * min(k, held):
-        return sorted_ffn(), counts
-    y = jax.lax.cond(jnp.sum(counts) <= capacity, sorted_ffn,
+        return grouped_ffn(), counts
+    y = jax.lax.cond(jnp.sum(counts) <= capacity, grouped_ffn,
                      jax.checkpoint(lambda: dense_held_ffn(x, ids, weights, first, gate, up, down, act)))
     return y, counts
 
@@ -185,7 +254,8 @@ class ExpertLayer(nn.Module):
         flat = x.reshape(b * t, d).astype(dtype)
         ids, weights = route(flat, router, bias, cfg.experts_per_token, cfg.routed_scaling_factor)
         with jax.named_scope("moe_experts"):
-            y, counts = held_experts_ffn(flat, ids, weights, first, gate, up, down, ACTIVATIONS[cfg.activation])
+            y, counts = held_experts_ffn(flat, ids, weights, first, cfg.n_experts, gate, up, down,
+                                         ACTIVATIONS[cfg.activation])
         y = y.reshape(b, t, d)
         if cfg.n_shared_experts:
             with jax.named_scope("moe_shared"):
@@ -201,3 +271,15 @@ def expert_load_stats(counts, n_tokens: int, k: int) -> Tuple[jnp.ndarray, jnp.n
     counts = counts.astype(jnp.float32)
     share = jnp.sum(counts) / (counts.shape[0] * n_tokens * k)
     return share, jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9)
+
+
+def first_buffer_share(counts, n_tokens: int, k: int, n_experts: int):
+    """The share of the expert layers' calls (`counts` [expert layers, held],
+    each layer one call of `n_tokens` tokens) whose held slots fit the slot
+    buffer of `held_experts_ffn`: 1.0 says the buffer sized from the shapes
+    served every call. A small call has no buffer to overflow and counts as
+    served; a call in token chunks is held to its chunks' buffers together."""
+    chunks, held = token_chunks(n_tokens), counts.shape[-1]
+    small = n_tokens * k <= SMALL_CALL_SLOTS
+    rows = n_tokens * min(k, held) if small else chunks * slot_capacity(n_tokens // chunks, k, held, n_experts)
+    return jnp.mean((jnp.sum(counts, axis=-1) <= rows).astype(jnp.float32))

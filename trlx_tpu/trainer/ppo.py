@@ -1088,14 +1088,17 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
 
     def with_expert_stats(result, out, n_tokens):
         """A model with expert layers: the step's routing counters beside the
-        loss's own stats (`moe/held_slot_share`, `moe/max_expert_load`)."""
+        loss's own stats (`moe/held_slot_share`, `moe/max_expert_load`,
+        `moe/first_buffer_share`)."""
         if out["expert_counts"] is None:
             return result
-        from trlx_tpu.models.moe import expert_load_stats
+        from trlx_tpu.models.moe import expert_load_stats, first_buffer_share
 
-        share, load = expert_load_stats(out["expert_counts"], n_tokens, model.cfg.experts_per_token)
+        k = model.cfg.experts_per_token
+        share, load = expert_load_stats(out["expert_counts"], n_tokens, k)
+        fit = first_buffer_share(out["expert_counts"], n_tokens, k, model.cfg.n_experts)
         loss, stats = result
-        return loss, {**stats, "moe/held_slot_share": share, "moe/max_expert_load": load}
+        return loss, {**stats, "moe/held_slot_share": share, "moe/max_expert_load": load, "moe/first_buffer_share": fit}
 
     def dense_loss_fn(params, batch: PPORLBatch):
         params = detach_frozen(params)
