@@ -66,6 +66,16 @@ def _flash_grouped(q, k, v, m):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
+def _flash_narrow_heads(q, k, v, m):
+    """Heads 64 wide (granite-4.0-h-micro's attention layers: 32 query heads
+    over 8), padded with zeros to the kernels' 128 as `Attention` pads them."""
+    widen = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, 64)))
+    loss = lambda q, k, v: flash_attention(
+        widen(q), widen(k), widen(v), m, scale=1 / 64, causal=True, interpret=False
+    )[..., :64].astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
 def _flash_ring_chunk(q, k, v, m, offset):
     """A sequence too long to be resident (major pieces, the state carried in
     scratch) called the way the ring path calls it: a traced offset (the
@@ -85,6 +95,13 @@ def _fused(x, w, y, b):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w, b)
 
 
+def _fused_tied(x, w, y):
+    """The tied head at the vocabulary the hybrid state-space cell runs
+    (granite-4.0-h-micro: W is the embedding table [V, D], V = 100,352)."""
+    loss = lambda x, w: sum(o.sum() for o in fused_logprob(x, w, y, None, tied=True, interpret=False))
+    return jax.value_and_grad(loss, argnums=(0, 1))(x, w)
+
+
 def _cases(s):
     """(name, fn, abstract args) with `s(shape, dtype)` building each arg."""
     bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -95,9 +112,13 @@ def _cases(s):
         ("flash fwd+bwd windowed d128", _flash_windowed, (s((16, 512, H, 128), bf16),) * 3 + (s((16, 512), f32),)),
         ("flash fwd+bwd grouped keys windowed d128", _flash_grouped,
          (s((4, 1024, 64, 128), bf16), s((4, 1024, 8, 128), bf16), s((4, 1024, 8, 128), bf16), s((4, 1024), f32))),
+        ("flash fwd+bwd grouped keys 32 over 8, d64 padded to 128", _flash_narrow_heads,
+         (s((8, 1024, 32, 64), bf16), s((8, 1024, 8, 64), bf16), s((8, 1024, 8, 64), bf16), s((8, 1024), f32))),
         ("flash fwd+bwd major pieces, traced offset", _flash_ring_chunk,
          (s((1, 8192, H, D), bf16),) * 3 + (s((1, 8192), f32), s((), f32))),
         ("fused_logprob fwd+bwd", _fused, head),
+        ("fused_logprob tied fwd+bwd V100352 d2048", _fused_tied,
+         (s((8 * 896, 2048), bf16), s((100352, 2048), bf16), s((8 * 896,), i32))),
     ]
 
 
@@ -134,6 +155,36 @@ def test_kernel_compiles_for_v5e(name, v5e_sharding):
     s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
     (fn, args), = [(f, a) for n, f, a in _cases(s) if n == name]
     jax.jit(fn).lower(*args).compile()
+
+
+def test_both_forms_of_the_state_space_mixer_compile_for_v5e(v5e_sharding):
+    """models/ssm.py at granite-4.0-h-micro's widths, bf16, no Pallas kernel in
+    it: the chunked form forward and backward over [1, 512] (two chunks of
+    256) and the recurrent step over 32 rows; the state stays float32."""
+    import json
+    import os
+
+    from trlx_tpu.models import ssm
+    from trlx_tpu.models.lm import LMConfig
+
+    spec = json.load(open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "granite-4.0-h-micro.json")))
+    cfg = LMConfig.from_dict({**spec["model_arch"], "dtype": "bfloat16", "param_dtype": "bfloat16"})
+    mixer = ssm.SSMMixer(cfg)
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+    x = jnp.zeros((1, 4, cfg.d_model), jnp.bfloat16)
+    params = jax.eval_shape(lambda: mixer.init(jax.random.PRNGKey(0), x, jnp.ones((1, 4), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), params)
+
+    def train(p, x, m):
+        return jax.value_and_grad(lambda p, x: mixer.apply({"params": p}, x, m)[0].astype(jnp.float32).sum(), argnums=(0, 1))(p, x)
+
+    jax.jit(train).lower(params, s((1, 512, 2048), jnp.bfloat16), s((1, 512), jnp.int32)).compile()
+    step = lambda p, x, m, c: mixer.apply({"params": p}, x, m, c)
+    args = (params, s((32, 1, 2048), jnp.bfloat16), s((32, 1), jnp.int32),
+            tuple(s(shape, dtype) for shape, dtype in ssm.cache_shapes(cfg, 32)))
+    jax.jit(step).lower(*args).compile()
+    _, (conv, state) = jax.eval_shape(step, *args)
+    assert state.dtype == jnp.float32 and state.shape == (32, 64, 64, 128) and conv.shape == (32, 3, 4352)
 
 
 def test_mosaic_kernels_refuse_a_multi_device_jit(v5e_sharding):

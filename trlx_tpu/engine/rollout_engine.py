@@ -136,6 +136,11 @@ class RolloutEngine:
             raise NotImplementedError(
                 "the rollout engine (per-slot write offsets, suffix prefill, spec verify windows) is not built "
                 "for window_cache 'ring': a ring takes one write offset for the whole batch (ops/generate.py)")
+        if model.cfg.has_ssm:
+            raise NotImplementedError(
+                "the rollout engine (and with it the paged pool and spec decode) is not built for a state-space "
+                "layer: a slot's state is not carried through admission, a block table has nothing to page and a "
+                "rejected draft needs a snapshot of the state to roll back to")
         self.model = model
         self.gcfg = gen_cfg
         self.processor = processor

@@ -18,7 +18,8 @@ SOURCE and the load is TPU-native streaming:
 
 Legacy pytorch_model.bin checkpoints fall back to the full torch load.
 Supported families match the reference's (reference: README.md:6): gpt2,
-gpt-j, gpt-neo, gpt-neox. With no checkpoint (or `model_arch` given) params
+gpt-j, gpt-neo, gpt-neox; and granitemoehybrid without experts (state-space
+and attention layers, models/ssm.py). With no checkpoint (or `model_arch` given) params
 initialize from scratch — the randomwalks path
 (reference: examples/randomwalks.py:99-101).
 """
@@ -129,6 +130,52 @@ def lm_config_from_hf(hf, **overrides) -> LMConfig:
             activation="gelu",
             ln_eps=hf.layer_norm_eps,
             extra={"neox_rotary": True},
+        )
+    elif t == "granitemoehybrid":
+        # The dense members of the family (granite-4.0-h-micro): state-space
+        # (Mamba-2) and grouped-key attention layers, no position signal, the
+        # four multipliers. What the program lacks raises here.
+        kinds = list(hf.layers_block_type)
+        unbuilt = [name for name, on in (
+            ("num_local_experts > 0 (the family's expert members)", getattr(hf, "num_local_experts", 0) > 0),
+            ("position_embedding_type other than 'nope'", getattr(hf, "position_embedding_type", "nope") not in ("nope", None)),
+            ("mamba_n_groups other than 1", hf.mamba_n_groups != 1),
+            ("mamba_expand * hidden_size != mamba_n_heads * mamba_d_head",
+             int(hf.mamba_expand * hf.hidden_size) != hf.mamba_n_heads * hf.mamba_d_head),
+            ("mamba_proj_bias / attention_bias", hf.mamba_proj_bias or hf.attention_bias),
+            ("mamba_conv_bias false", not hf.mamba_conv_bias),
+            ("hidden_act other than silu", hf.hidden_act != "silu")) if on]
+        if unbuilt:
+            raise ValueError(f"granitemoehybrid: not built: {'; '.join(unbuilt)}")
+        d = dict(
+            vocab_size=hf.vocab_size,
+            n_layer=hf.num_hidden_layers,
+            n_head=hf.num_attention_heads,
+            n_kv_head=hf.num_key_value_heads,
+            head_width=hf.hidden_size // hf.num_attention_heads,
+            d_model=hf.hidden_size,
+            d_ff=hf.shared_intermediate_size,
+            max_position=hf.max_position_embeddings,
+            pos_type="none",
+            norm="rmsnorm",
+            mlp="gated",
+            activation="silu",
+            ln_eps=hf.rms_norm_eps,
+            parallel_residual=False,
+            fused_qkv=False,
+            qkv_bias=False,
+            out_bias=False,
+            tie_word_embeddings=hf.tie_word_embeddings,
+            mixer_layers=tuple("mamba" if k == "mamba" else "attention" for k in kinds),
+            ssm_heads=hf.mamba_n_heads,
+            ssm_head_dim=hf.mamba_d_head,
+            ssm_state=hf.mamba_d_state,
+            ssm_conv=hf.mamba_d_conv,
+            ssm_chunk=hf.mamba_chunk_size,
+            embedding_multiplier=float(hf.embedding_multiplier),
+            attention_multiplier=float(hf.attention_multiplier),
+            residual_multiplier=float(hf.residual_multiplier),
+            logits_scaling=float(hf.logits_scaling),
         )
     else:
         raise ValueError(f"unsupported HF model_type for conversion: {t}")
@@ -305,6 +352,8 @@ def load_hf_trunk(model_path: str, cfg: LMConfig, put=None) -> Dict[str, Any]:
 
 
 def _detect_family(sd) -> str:
+    if any(".mamba.in_proj." in k for k in sd):
+        return "granitemoehybrid"
     if any(k.startswith("transformer.h.") and ".attn.c_attn." in k for k in sd):
         return "gpt2"
     if any(".attn.attention.q_proj." in k for k in sd):
@@ -360,6 +409,8 @@ def trunk_spec(family: str, cfg: LMConfig) -> Dict[str, Any]:
         return _spec_gpt_neo(cfg)
     if family == "gpt_neox":
         return _spec_neox(cfg)
+    if family == "granitemoehybrid":
+        return _spec_granite_hybrid(cfg)
     raise ValueError(f"unsupported family: {family}")
 
 
@@ -501,6 +552,61 @@ def _spec_neox(cfg: LMConfig) -> Dict[str, Any]:
                 },
             },
         }
+    return p
+
+
+def _spec_granite_hybrid(cfg: LMConfig) -> Dict[str, Any]:
+    """granitemoehybrid without experts: nn.Linear weights transposed; the
+    depthwise Conv1d's [channels, 1, K] to [K, channels]; the gated MLP's
+    fused `input_linear` [2 f, d] split into gate (first f rows) and up."""
+    f = cfg.ff_dim
+
+    def half(key, first):
+        def thunk(sd):
+            w = np.asarray(sd[key])
+            return (w[:f] if first else w[f:]).T
+
+        return thunk
+
+    def depthwise(key):
+        return lambda sd: np.asarray(sd[key])[:, 0, :].T
+
+    scale = lambda key: {"scale": _id(key)}
+    p: Dict[str, Any] = {"wte": {"embedding": _id("model.embed_tokens.weight")}, "ln_f": scale("model.norm.weight")}
+    if not cfg.tie_word_embeddings:
+        p["lm_head"] = {"kernel": _t("lm_head.weight")}
+    for i in range(cfg.n_layer):
+        h = f"model.layers.{i}"
+        block = {
+            "ln_1": scale(f"{h}.input_layernorm.weight"),
+            "ln_2": scale(f"{h}.post_attention_layernorm.weight"),
+            "mlp": {
+                "gate_proj": {"kernel": half(f"{h}.shared_mlp.input_linear.weight", True)},
+                "up_proj": {"kernel": half(f"{h}.shared_mlp.input_linear.weight", False)},
+                "down_proj": {"kernel": _t(f"{h}.shared_mlp.output_linear.weight")},
+            },
+        }
+        if cfg.mixer(i) == "mamba":
+            m = f"{h}.mamba"
+            block["mamba"] = {
+                "in_proj": {"kernel": _t(f"{m}.in_proj.weight")},
+                "out_proj": {"kernel": _t(f"{m}.out_proj.weight")},
+                "conv_kernel": depthwise(f"{m}.conv1d.weight"),
+                "conv_bias": _id(f"{m}.conv1d.bias"),
+                "dt_bias": _id(f"{m}.dt_bias"),
+                "A_log": _id(f"{m}.A_log"),
+                "D": _id(f"{m}.D"),
+                "norm_scale": _id(f"{m}.norm.weight"),
+            }
+        else:
+            a = f"{h}.self_attn"
+            block["attn"] = {
+                "q_proj": {"kernel": _t(f"{a}.q_proj.weight")},
+                "k_proj": {"kernel": _t(f"{a}.k_proj.weight")},
+                "v_proj": {"kernel": _t(f"{a}.v_proj.weight")},
+                "c_proj": {"kernel": _t(f"{a}.o_proj.weight")},
+            }
+        p[f"h_{i}"] = block
     return p
 
 

@@ -36,6 +36,7 @@ from trlx_tpu.observability import numerics as obs_numerics
 from trlx_tpu.ops.kv_read import attend, attend_latent, attend_latent_range, ranged_read
 from trlx_tpu.parallel.mesh import partitioned
 from trlx_tpu.parallel.schedule import hold_rows, use_weight
+from trlx_tpu.utils import tree_size_bytes
 
 Dtype = Any
 
@@ -60,7 +61,7 @@ class LMConfig:
     d_model: int = 768
     d_ff: int = 0  # 0 → 4*d_model
     max_position: int = 1024
-    pos_type: str = "learned"  # "learned" | "rotary"
+    pos_type: str = "learned"  # "learned" | "rotary" | "none" (no position signal anywhere)
     rotary_dim: int = 0  # 0 w/ rotary → full head dim
     parallel_residual: bool = False  # gptj/neox style
     use_parallel_ln: bool = False  # neox: separate ln for mlp in parallel block
@@ -189,6 +190,28 @@ class LMConfig:
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     experts_held: Tuple[int, ...] = ()
+    # Per-layer mixer kind ("attention" | "mamba"); empty -> all attention.
+    # A "mamba" layer is trlx_tpu/models/ssm.py: the Mamba-2 state-space mixer
+    # (ssm_heads x ssm_head_dim channels, one B/C group of ssm_state numbers, a
+    # depthwise causal convolution ssm_conv wide, the scan in chunks of
+    # ssm_chunk). Its cache is a fixed float32 state a row, no slot axis. Built
+    # for the static generate path, scoring and the train step; the engine,
+    # the paged pool, spec decode, the sp ring, kv_cache_quant,
+    # decode_weight_quant and soft prompts refuse it.
+    mixer_layers: Tuple[str, ...] = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # The four scalars of the granite family: on the token embedding, on the
+    # attention scores (0 -> 1/sqrt(head_dim), or 1 without scale_attn), on
+    # both residual branches of a block, and dividing the logits (in the fused
+    # log-prob head, the sampler and scoring alike).
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     extra: Dict[str, Any] = field(default_factory=dict)
 
     def __post_init__(self):
@@ -200,7 +223,7 @@ class LMConfig:
             )
         for name, kinds in (("norm", ("layernorm", "rmsnorm")), ("mlp", ("dense", "gated")),
                             ("attention", ("mha", "mla")), ("rotary_layers", ("all", "local")),
-                            ("window_cache", ("span", "ring"))):
+                            ("window_cache", ("span", "ring")), ("pos_type", ("learned", "rotary", "none"))):
             if getattr(self, name) not in kinds:
                 raise ValueError(f"unknown {name} kind {getattr(self, name)!r} (expected one of {kinds})")
         if self.draw_dtype not in ("", "float32"):
@@ -244,6 +267,34 @@ class LMConfig:
                     f"expert layers need mlp 'gated', expert_d_ff, 0 < experts_per_token <= n_experts "
                     f"and experts_held inside [0, n_experts): {self.experts_held!r} of {self.n_experts}")
 
+        if self.mixer_layers and (len(self.mixer_layers) != self.n_layer
+                                  or set(self.mixer_layers) - {"attention", "mamba"}):
+            raise ValueError(f"mixer_layers must name 'attention' or 'mamba' for each of {self.n_layer} layers: {self.mixer_layers!r}")
+        if self.has_ssm:
+            sizes = (self.ssm_heads, self.ssm_head_dim, self.ssm_state, self.ssm_conv - 1, self.ssm_chunk)
+            if min(sizes) <= 0:
+                raise ValueError(f"a 'mamba' layer needs ssm_heads, ssm_head_dim, ssm_state, ssm_conv >= 2 and ssm_chunk, got {sizes}")
+            unbuilt = [name for name, on in (
+                ("attention 'mla'", self.attention == "mla"), ("kv_cache_quant", self.kv_cache_quant),
+                ("soft prompts", self.n_soft_tokens > 0), ("the sp ring (sp_size > 1)", self.sp_size > 1),
+                ("windowed attention_layers", "local" in self.attention_layers),
+                ("expert layers", "experts" in self.ffn_layers), ("parallel_residual", self.parallel_residual)) if on]
+            if unbuilt:
+                raise ValueError(f"a 'mamba' layer (mixer_layers) is not built with {', '.join(unbuilt)}")
+        if self.logits_scaling != 1.0 and self.extra.get("lm_head_bias", False):
+            raise ValueError("logits_scaling is not built with a head bias (extra.lm_head_bias)")
+        if min(self.embedding_multiplier, self.residual_multiplier, self.logits_scaling) <= 0 or self.attention_multiplier < 0:
+            raise ValueError("embedding_multiplier, residual_multiplier and logits_scaling are positive, attention_multiplier "
+                             "is 0 (unset) or positive")
+
+    @property
+    def has_ssm(self) -> bool:
+        """Whether any layer is a state-space ("mamba") mixer."""
+        return "mamba" in self.mixer_layers
+
+    def mixer(self, layer: int) -> str:
+        return self.mixer_layers[layer] if self.mixer_layers else "attention"
+
     @property
     def held_experts(self) -> Tuple[int, int]:
         """(first, count) of the routed experts held here."""
@@ -285,7 +336,7 @@ class LMConfig:
         if unknown:
             raise ValueError(f"LMConfig: unknown architecture key(s) {unknown}")
         known = {k: v for k, v in d.items() if k in cls.__dataclass_fields__}
-        for key in ("attention_layers", "ffn_layers", "experts_held"):
+        for key in ("attention_layers", "ffn_layers", "experts_held", "mixer_layers"):
             if key in known:
                 known[key] = tuple(known[key])
         return cls(**known)
@@ -750,7 +801,7 @@ class Attention(nn.Module):
 
                     k, v = view(0), view(1)
 
-        scale = 1.0 / np.sqrt(hd) if cfg.scale_attn else 1.0
+        scale = cfg.attention_multiplier or (1.0 / np.sqrt(hd) if cfg.scale_attn else 1.0)
         with jax.named_scope("attn_window" if window else "attn_full"):
             if flash_mask is not None:
                 if use_ring:
@@ -762,7 +813,19 @@ class Attention(nn.Module):
                 else:
                     from trlx_tpu.ops.flash_attention import flash_attention
 
-                    out = flash_attention(q, k, v, flash_mask, scale=scale, causal=True, window=window).astype(dtype)
+                    # The kernels are tuned and tested at head widths that
+                    # fill their 128 lanes; a narrower head (64: 13.8 ms on
+                    # XLA, 5.3 ms here, forward and backward at [8, 1024], 32
+                    # over 8; PERF.md §6, PR 32) is padded with zeros, which
+                    # add nothing to q.k, and the output's padded columns are
+                    # dropped, as the latent path pads 192/128 to 256. The pad
+                    # buys test coverage, not speed: unpadded at 64 the kernels
+                    # read 5.2 ms in the same run, and no lowering or parity
+                    # test holds them at that width (PERF.md §7, PR 32 e).
+                    pad = -hd % 128
+                    widen = (lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, pad)))) if pad else (lambda a: a)
+                    out = flash_attention(widen(q), widen(k), widen(v), flash_mask, scale=scale, causal=True, window=window)
+                    out = (out[..., :hd] if pad else out).astype(dtype)
             elif read is not None:
                 out = read(q, new_cache, attn_bias, scale, dtype)
             else:
@@ -925,25 +988,35 @@ def make_norm(cfg: LMConfig, name: str):
 
 class Block(nn.Module):
     """One transformer block; sequential (gpt2) or parallel (gptj/neox)
-    residual. `ffn` is this layer's feed-forward kind ("dense" | "experts").
-    Returns (x, cache, expert_counts): the tokens each held expert took in
-    this block, None for a dense one."""
+    residual. `ffn` is this layer's feed-forward kind ("dense" | "experts"),
+    `mixer` its mixer kind ("attention" | "mamba": models/ssm.py, whose cache
+    is a state and which reads `token_mask` [b, q_len], the real tokens of
+    `x`, in place of a bias). Returns (x, cache, expert_counts): the tokens
+    each held expert took in this block, None for a dense one."""
 
     cfg: LMConfig
     ffn: str = "dense"
+    mixer: str = "attention"
 
     @nn.compact
     def __call__(self, x, attn_bias, positions, cache=None, cache_index=None,
-                 flash_mask=None, window=0, use_ring=False, block_tables=None):
+                 flash_mask=None, window=0, use_ring=False, block_tables=None, token_mask=None):
         cfg = self.cfg
         ln = lambda name: make_norm(cfg, name)
-        attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
         counts = None
         # On a partitioned mesh a pass over many tokens keeps its rows where
         # the batch split put them, at both edges of the block (inside, so
         # that a remat'd backward holds them too), and every product gathers
         # its weight (parallel/schedule.py).
         x = hold_rows(x)
+
+        def mix(h):
+            if self.mixer == "mamba":
+                from trlx_tpu.models.ssm import SSMMixer
+
+                return SSMMixer(cfg, name="mamba")(h, token_mask, cache)
+            attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
+            return attn(h, attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
 
         def feed_forward(h):
             nonlocal counts
@@ -954,15 +1027,18 @@ class Block(nn.Module):
                 return h
             return MLP(cfg, name="mlp")(h)
 
+        # both residual branches of a block take `residual_multiplier`
+        branch = (lambda y: y) if cfg.residual_multiplier == 1.0 else (
+            lambda y: y * jnp.asarray(cfg.residual_multiplier, y.dtype))
         if cfg.parallel_residual:
             h = ln("ln_1")(x)
-            attn_out, new_cache = attn(h, attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
+            attn_out, new_cache = mix(h)
             mlp_in = ln("ln_2")(x) if cfg.use_parallel_ln else h
-            x = x + attn_out + feed_forward(mlp_in)
+            x = x + branch(attn_out) + branch(feed_forward(mlp_in))
         else:
-            attn_out, new_cache = attn(ln("ln_1")(x), attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
-            x = x + attn_out
-            x = x + feed_forward(ln("ln_2")(x))
+            attn_out, new_cache = mix(ln("ln_1")(x))
+            x = x + branch(attn_out)
+            x = x + branch(feed_forward(ln("ln_2")(x)))
         return hold_rows(x), new_cache, counts
 
 
@@ -1099,6 +1175,8 @@ class TransformerLM(nn.Module):
                 x = onehot @ use_weight(wte.embedding.astype(cfg.compute_dtype), wte.path + ("embedding",), input_ids.size)
             else:
                 x = lookup(wte, input_ids)
+            if cfg.embedding_multiplier != 1.0:
+                x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         else:
             x = inputs_embeds.astype(cfg.compute_dtype)
 
@@ -1189,6 +1267,12 @@ class TransformerLM(nn.Module):
             raise NotImplementedError(
                 "a ring cache takes a prefill at write offset 0 or one token a step at one offset for the "
                 "whole batch (the static generate path): no block table, per-row offset or verify window")
+        if cfg.has_ssm and (segment_ids is not None or (cache is not None and (
+                block_tables is not None or jnp.ndim(cache_index) != 0 or (q_len > 1 and not prefill_at_zero)))):
+            raise NotImplementedError(
+                "a state-space layer takes a pass with no cache, a prefill at write offset 0 or one token a step "
+                "for the whole batch (the static generate path): no block table, per-row offset, verify window "
+                "or packed segments")
         if segment_ids is not None:
             # Packed segments need a block-diagonal mask; the flash/ring
             # kernels' (causal × key-validity) masks cannot express that.
@@ -1230,8 +1314,12 @@ class TransformerLM(nn.Module):
             # again (ZeRO-3): left to common-subexpression elimination, the
             # forward's gathers of every layer stay alive for the backward,
             # 11 GB at GPT-J-6B (PERF.md §6, PR 29).
+            # With state-space layers likewise, on one chip too: merged with
+            # the forward, the recomputation keeps every layer's projections
+            # and scan products alive, 34.7 GB for a 16 GB chip at 40 layers
+            # (PERF.md §6, PR 32).
             block_cls = nn.remat(
-                Block, prevent_cse=partitioned(), static_argnums=(7, 8), policy=policy
+                Block, prevent_cse=partitioned() or cfg.has_ssm, static_argnums=(7, 8), policy=policy
             )
 
         branch_hidden = None
@@ -1240,7 +1328,7 @@ class TransformerLM(nn.Module):
         for i in range(cfg.n_layer):
             # All blocks are *defined* every call so the param structure is
             # identical regardless of start/stop — only [start, stop) execute.
-            block = block_cls(cfg, cfg.ffn_layers[i] if cfg.ffn_layers else "dense", name=f"h_{i}")
+            block = block_cls(cfg, cfg.ffn_layers[i] if cfg.ffn_layers else "dense", cfg.mixer(i), name=f"h_{i}")
             if i < start_layer or i >= stop_layer:
                 continue
             if collect_hidden_at is not None and i == collect_hidden_at:
@@ -1248,9 +1336,11 @@ class TransformerLM(nn.Module):
             layer_cache = cache[i] if cache is not None else None
             window = layer_window(cfg, i)
             layer_bias = local_bias if window else attn_bias
+            # a state-space layer reads the tokens' mask itself, in place of a bias
+            token_mask = (attention_mask,) if cfg.mixer(i) == "mamba" else ()
             x, layer_new_cache, layer_counts = block(
                 x, layer_bias, position_ids, layer_cache, cache_index,
-                flash_mask, window, use_ring, block_tables,
+                flash_mask, window, use_ring, block_tables, *token_mask,
             )
             x = obs_numerics.probe_tap(f"block_{i}", x)
             if cache is not None:
@@ -1274,6 +1364,10 @@ class TransformerLM(nn.Module):
 
         logits = None
         logprobs = lse = entropy = None
+        # logits / logits_scaling, in every head alike: the head is linear and
+        # has no bias where a family scales, so its input is divided instead
+        unscaled = (lambda h: h) if cfg.logits_scaling == 1.0 else (
+            lambda h: h * jnp.asarray(1.0 / cfg.logits_scaling, h.dtype))
         # The head's weight moves as the trunk's do: by the tokens of the pass
         # (the rows `hold_rows` kept in place), not the positions it evaluates.
         if labels is not None:
@@ -1286,7 +1380,7 @@ class TransformerLM(nn.Module):
 
             S = labels.shape[1]
             x_head = x[:, logits_start:] if logits_start else x
-            x_head = x_head[:, :S]
+            x_head = unscaled(x_head[:, :S])
             if cfg.tie_word_embeddings:
                 w_head, b_head, tied = use_weight(wte.embedding, wte.path + ("embedding",), b * q_len), None, True
             else:
@@ -1311,7 +1405,7 @@ class TransformerLM(nn.Module):
             # RL losses/scoring only need logits from the first response
             # position on — slicing before the head skips ~P/T of the
             # vocab-projection FLOPs and the fp32 logit memory.
-            x_head = x[:, logits_start:] if logits_start else x
+            x_head = unscaled(x[:, logits_start:] if logits_start else x)
             if cfg.tie_word_embeddings:
                 # `wte.attend(x_head)`, with the table as this product uses it
                 query, table = wte.promote_dtype(x_head, wte.embedding, dtype=wte.dtype)
@@ -1369,7 +1463,9 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
     (k_i8, v_i8, k_scale, v_scale) with kv_cache_quant (scales [b, T,
     kv_heads]), T = `max_len`, but min(window_size, max_len) on a window layer
     under window_cache "ring"; "mla": per-layer (c_kv [b, T, kv_lora_rank],
-    k_rope [b, T, qk_rope_head_dim]), shared by all heads."""
+    k_rope [b, T, qk_rope_head_dim]), shared by all heads; a "mamba" layer
+    (models/ssm.py): (conv [b, ssm_conv - 1, channels], state [b, ssm_heads,
+    ssm_head_dim, ssm_state] float32), no slot axis whatever `max_len`."""
     if cfg.attention == "mla":
         dtype = dtype or cfg.compute_dtype
         return tuple(
@@ -1381,6 +1477,10 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
     dtype = dtype or cfg.compute_dtype
 
     def layer(i):
+        if cfg.mixer(i) == "mamba":
+            from trlx_tpu.models.ssm import cache_shapes
+
+            return tuple(jnp.zeros(shape, leaf_dtype) for shape, leaf_dtype in cache_shapes(cfg, batch))
         sshape = (batch, ring_slots(cfg, i, max_len) or max_len, cfg.kv_heads)
         shape = sshape + (cfg.head_dim,)
         if cfg.kv_cache_quant:
@@ -1403,9 +1503,10 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
     reserved by the engine pool) absorbs dead rows' clamped writes — masked
     reads weight stale content by an exact softmax zero, which only stays
     zero if the content (values AND scales) is finite."""
-    if cfg.attention != "mha" or cfg.window_cache != "span":
+    if cfg.attention != "mha" or cfg.window_cache != "span" or cfg.has_ssm:
         raise NotImplementedError(
-            f"the paged pool is not built for attention {cfg.attention!r} or window_cache {cfg.window_cache!r}")
+            f"the paged pool is not built for attention {cfg.attention!r}, window_cache {cfg.window_cache!r} or a "
+            "state-space layer (a state has no slots to page)")
     shape = (n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_cache_quant:
         assert dtype is None, "kv_cache_quant caches are int8; dtype not honored"
@@ -1424,15 +1525,19 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
     return tuple((zero(), zero()) for _ in range(cfg.n_layer))
 
 
-def cache_partition_spec(cfg: LMConfig, leaf_ndim: int):
-    """PartitionSpec of one leaf of `init_cache`'s pytree: batch over the data
-    axes, heads over tp (grouped keys: the kv_heads; a ring layer's leaves
-    have the same axes, fewer slots). An "mla" cache has no head axis: one
-    latent a token serves every head, so it is whole on every tp shard."""
+def cache_partition_spec(cfg: LMConfig, leaf_ndim: int, layer: int = 0):
+    """PartitionSpec of one leaf of `init_cache`'s pytree, in layer `layer`:
+    batch over the data axes, heads over tp (grouped keys: the kv_heads; a
+    ring layer's leaves have the same axes, fewer slots). An "mla" cache has
+    no head axis: one latent a token serves every head, so it is whole on
+    every tp shard. A "mamba" layer: the state's rows over the data axes and
+    its heads over tp, the convolution's window whole on every tp shard."""
     from jax.sharding import PartitionSpec
 
     from trlx_tpu.parallel.mesh import AXIS_TP, DATA_AXES
 
+    if cfg.mixer(layer) == "mamba":
+        return PartitionSpec(DATA_AXES, AXIS_TP, None, None) if leaf_ndim == 4 else PartitionSpec(DATA_AXES, None, None)
     if cfg.attention == "mla":
         return PartitionSpec(DATA_AXES, None, None)
     # 4-D leaves are k/v ([b, T, h, d]); 3-D leaves are the int8 cache's
@@ -1444,8 +1549,15 @@ def cache_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
     """Bytes of the cache `init_cache(cfg, batch, max_len)` allocates, all
     layers, from its own shapes: the counter `rollout/cache_bytes` at the
     generate program's batch and length."""
-    leaves = jax.tree_util.tree_leaves(jax.eval_shape(lambda: init_cache(cfg, batch, max_len)))
-    return int(sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves))
+    return tree_size_bytes(jax.eval_shape(lambda: init_cache(cfg, batch, max_len)))
+
+
+def state_bytes(cfg: LMConfig, batch: int) -> int:
+    """The part of `cache_bytes` that the state-space layers hold (state and
+    convolution window, whatever the length): the counter
+    `rollout/state_bytes`, from `init_cache`'s own shapes."""
+    cache = jax.eval_shape(lambda: init_cache(cfg, batch, 1))
+    return tree_size_bytes([cache[i] for i in range(cfg.n_layer) if cfg.mixer(i) == "mamba"])
 
 
 def cache_bytes_per_token(cfg: LMConfig) -> int:
@@ -1453,5 +1565,18 @@ def cache_bytes_per_token(cfg: LMConfig) -> int:
     `rollout/cache_bytes_per_token`, from `init_cache`'s own shapes at one
     row of one token. A ring layer counts its ring once: one slot, like a
     full-span layer's, though past window_size tokens it grows no further
-    (`rollout/cache_bytes` is the whole allocation)."""
-    return cache_bytes(cfg, 1, 1)
+    (`rollout/cache_bytes` is the whole allocation). A state-space layer
+    holds nothing a token: its state is `rollout/state_bytes_per_row`."""
+    return cache_bytes(cfg, 1, 1) - state_bytes(cfg, 1)
+
+
+def decode_step_bytes(cfg: LMConfig, batch: int, keys_read: float, weight_bytes: int) -> Tuple[int, int]:
+    """(bytes a decode step of the static generate path must move, the
+    state's part of them), from shapes: the weights read once
+    (`weight_bytes`), the state-space layers' state and window read AND
+    written, and `keys_read` cache slots of every attention layer's K and V
+    (what the ranged read takes at that step). The counters
+    `rollout/step_bytes_needed` and `ssm/state_rw_share`."""
+    state = 2 * state_bytes(cfg, batch)
+    keys = int(keys_read * batch * cache_bytes_per_token(cfg))
+    return weight_bytes + state + keys, state

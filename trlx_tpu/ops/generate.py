@@ -108,12 +108,15 @@ def generate(
 
         data = int(mesh.shape[mesh_mod.AXIS_DP]) * int(mesh.shape[mesh_mod.AXIS_FSDP])
         tp = int(mesh.shape[mesh_mod.AXIS_TP])
-        if B % data == 0 and cfg.kv_heads % tp == 0:
-            cache = jax.tree_util.tree_map(
-                lambda x: jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, cache_partition_spec(cfg, x.ndim))
-                ),
-                cache,
+        if B % data == 0 and cfg.kv_heads % tp == 0 and (not cfg.has_ssm or cfg.ssm_heads % tp == 0):
+            cache = tuple(
+                jax.tree_util.tree_map(
+                    lambda x, i=i: jax.lax.with_sharding_constraint(
+                        x, NamedSharding(mesh, cache_partition_spec(cfg, x.ndim, i))
+                    ),
+                    layer_cache,
+                )
+                for i, layer_cache in enumerate(cache)
             )
         elif mesh.size > 1:
             import warnings

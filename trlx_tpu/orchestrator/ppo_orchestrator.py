@@ -27,14 +27,14 @@ import jax
 import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
-from trlx_tpu.models.lm import cache_bytes, cache_bytes_per_token, layer_window, ring_slots
+from trlx_tpu.models.lm import cache_bytes, cache_bytes_per_token, decode_step_bytes, layer_window, ring_slots, state_bytes
 from trlx_tpu.ops.kv_read import kv_keys_read
 from trlx_tpu.parallel.schedule import weight_gather_share
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
 from trlx_tpu.pipeline.overlap import ScoreWorker
 from trlx_tpu.resilience.faults import FaultInjected
 from trlx_tpu.resilience.retry import call_with_retries
-from trlx_tpu.utils import Clock
+from trlx_tpu.utils import Clock, tree_size_bytes
 
 
 @register_orchestrator
@@ -262,8 +262,10 @@ class PPOOrchestrator(Orchestrator):
         # would have touched (ops/kv_read.py): from shapes and step counts.
         lm_cfg = rl.model.cfg
         n_soft = lm_cfg.n_soft_tokens
-        layer_windows = [layer_window(lm_cfg, i) for i in range(lm_cfg.n_layer)]
-        cache_alloc = 0  # bytes of the cache the generate program allocated (the last chunk's)
+        # the layers that keep keys: a state-space layer reads none
+        key_layers = [i for i in range(lm_cfg.n_layer) if lm_cfg.mixer(i) == "attention"]
+        layer_windows = [layer_window(lm_cfg, i) for i in key_layers]
+        cache_alloc = gen_rows = 0  # bytes of the cache the generate program allocated (the last chunk's), its rows
         kv_keys = np.zeros(2, dtype=np.int64)
         experts_touched = []  # a model with expert layers: one reading a chunk
         # Final-chunk stats for logging; placeholders are never logged (the
@@ -408,9 +410,10 @@ class PPOOrchestrator(Orchestrator):
                 cache_len = mask_h.shape[1] + n_soft
                 kv_keys += np.array(kv_keys_read(
                     cache_len, P + n_soft, ds["decode_steps"], layer_windows,
-                    [ring_slots(lm_cfg, i, cache_len) for i in range(lm_cfg.n_layer)],
+                    [ring_slots(lm_cfg, i, cache_len) for i in key_layers],
                 ))
                 cache_alloc = cache_bytes(lm_cfg, mask_h.shape[0], cache_len)
+                gen_rows = mask_h.shape[0]
                 episode_steps.extend(int(v) for v in ds["episode_steps"])
                 step_budget = ds["decode_step_budget"]
                 if gen_aux is not None and "experts_touched_per_step" in gen_aux[0]:
@@ -532,6 +535,20 @@ class PPOOrchestrator(Orchestrator):
         }
         if experts_touched:
             rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
+        if lm_cfg.has_ssm and cache_alloc:
+            # What a decode step must move, from shapes: the weights once, the
+            # state read and written, the keys the ranged read took (the mean
+            # over the rollout's steps).
+            steps = max(1, int(np.sum(decode_steps)))
+            keys_a_step = kv_keys[0] / steps / max(1, len(key_layers))
+            needed, state_rw = decode_step_bytes(
+                lm_cfg, gen_rows, keys_a_step, tree_size_bytes(rl.state.params["transformer"]))
+            rl._last_exp_stats.update({
+                "rollout/state_bytes": float(state_bytes(lm_cfg, gen_rows)),
+                "rollout/state_bytes_per_row": float(state_bytes(lm_cfg, 1)),
+                "rollout/step_bytes_needed": float(needed),
+                "ssm/state_rw_share": float(state_rw / needed),
+            })
         gather_share = weight_gather_share(rl._weight_gathers["generate"])
         if gather_share is not None:
             # the generate program on a partitioned mesh: the prefill gathers

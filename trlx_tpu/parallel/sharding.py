@@ -60,6 +60,11 @@ def lm_partition_rules() -> List[Tuple[str, P]]:
         (r"moe/e_score_correction_bias$", P()),
         (r"moe/experts_(gate|up)$", P(None, AXIS_FSDP, AXIS_TP)),
         (r"moe/experts_down$", P(None, AXIS_TP, AXIS_FSDP)),
+        # state-space mixer (models/ssm.py): the two projections as the dense
+        # kernels they are; the convolution and the per-head vectors whole
+        (r"mamba/in_proj/kernel$", P(AXIS_FSDP, AXIS_TP)),
+        (r"mamba/out_proj/kernel$", P(AXIS_TP, AXIS_FSDP)),
+        (r"mamba/(conv_kernel|conv_bias|dt_bias|A_log|D|norm_scale)$", P()),
         # MLP up [d_model, d_ff] column-parallel
         (r"mlp/c_fc/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"mlp/c_fc/bias$", P(AXIS_TP)),

@@ -113,6 +113,7 @@ class PPOTrainer(JaxBaseTrainer):
         self._pack_rows_multiple = int(np.prod([self.mesh.shape[a] for a in DATA_AXES]))
         self._window_tokens = []
         self._window_fill = []
+        self._window_pad = []  # state-space layers: padding positions of each train batch (`ssm/pad_share`)
         # Multi-host overlap (max_staleness > 0 at process_count() > 1) used
         # to raise here: two threads dispatching device programs concurrently
         # cannot GUARANTEE the same collective launch order on every host —
@@ -228,11 +229,11 @@ class PPOTrainer(JaxBaseTrainer):
             from trlx_tpu.models.lm import quantize_weights
 
             lm_cfg = self.model.cfg
-            if lm_cfg.attention != "mha" or lm_cfg.mlp != "dense" or "experts" in lm_cfg.ffn_layers:
+            if lm_cfg.attention != "mha" or lm_cfg.mlp != "dense" or "experts" in lm_cfg.ffn_layers or lm_cfg.has_ssm:
                 raise ValueError(
                     "model.decode_weight_quant covers the GPT block's kernels only "
                     "(models/lm.py QUANT_KERNEL_NAMES): it is not built for attention "
-                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r} or expert layers"
+                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r}, expert layers or state-space layers"
                 )
 
             self._quantize_fn = self._wrap_monitored(
@@ -907,6 +908,11 @@ class PPOTrainer(JaxBaseTrainer):
             tokens = batch.query_tensors.shape[0] * (
                 batch.query_tensors.shape[1] + batch.response_tensors.shape[1]
             )
+            if self.model.cfg.has_ssm:
+                # the host batch, before it is put: no transfer. The scan
+                # walks every position, padding too.
+                pad = sum(int(np.sum(np.asarray(t) == self.pad_token_id)) for t in (batch.query_tensors, batch.response_tensors))
+                self._window_pad.append(pad / tokens)
         # The same device batch feeds every PPO inner epoch.
         self._window_tokens.append(tokens * max(1, getattr(self, "n_updates_per_batch", 1)))
         return super()._prepare_batch(batch)
@@ -936,6 +942,11 @@ class PPOTrainer(JaxBaseTrainer):
             stats["train_tokens_per_s"] = float(sum(window_tokens)) / train_s
         if window_fill:
             stats["train_batch_fill"] = float(np.mean(window_fill))
+        window_pad, self._window_pad = self._window_pad, []
+        if window_pad:
+            lm_cfg = self.model.cfg
+            stats["ssm/pad_share"] = float(np.mean(window_pad))
+            stats["ssm/chunks_per_pass"] = float(-(-int(self.config.train.seq_length) // lm_cfg.ssm_chunk))
         if self._last_exp_stats:
             stats.update(self._last_exp_stats)
         # Device telemetry flushes on the SAME cadence as the phase window —
