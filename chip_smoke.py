@@ -17,8 +17,9 @@ One process, no network, seeded random weights, no tokenizer. It
    compiled, max abs error against the tolerance, the route the model layer
    takes for that shape, and the kernel's and the reference's time per call;
    then times a decode step's einsum read of an int8 cache inside a loop,
-   whole cache beside the ranged read (the baseline a decode kernel would
-   have to beat: ops/kv_read.py);
+   whole cache beside the ranged read, at the benchmark's two rollout shapes,
+   each with the bytes it must move and their share of the chip's memory rate
+   (the baseline a decode kernel would have to beat: ops/kv_read.py);
 3. runs PPO through ``trlx_tpu.train(reward_fn=..., prompts=<token ids>,
    config=...)`` — default orchestrator, static rollout, fused rollout
    stats — at GPT-J-6B's published widths (d4096, 16 heads x 256, V50400,
@@ -62,6 +63,9 @@ REHEARSAL = dict(
     prompt=24, new_tokens=8, chunk=8, batch=4, ppo_epochs=2, unfrozen=1,
     n_prompts=16,
 )
+# The decode read's second timed shape (rows, slots, head width; 16 heads as
+# above): the `gptneo1.3b.ppo-256x256` cell's int8 cache.
+READ_SHAPE_NARROW_HEADS = (64, 512, 128)
 SEED = 0
 # Kernel-vs-reference bound, set beforehand from the dtype: both sides read
 # the same bf16 operands and accumulate in fp32, so what separates them is
@@ -232,10 +236,14 @@ def _time_ranged_read(q, cache, bias, T, scale, steps=64):
     read compiles to another program and takes two to three times as long).
     Each step writes the frontier's slot and reads: the whole cache, as before
     PR 24, beside the program's own read (ops/kv_read.py) with the frontier in
-    the middle of the cache."""
+    the middle of the cache. Beside each time: the bytes that read must move
+    (the int8 K and V and their scales over its keys) and the share of the
+    chip's memory rate they amount to at that time (the step's write and
+    softmax are in the time and not in the bytes)."""
     import jax
     import jax.numpy as jnp
 
+    from trlx_tpu.observability.devicemon import chip_peaks
     from trlx_tpu.ops.kv_read import attend_range, kv_read_bucket, kv_read_ranges, ranged_read
 
     def loop(ranged):
@@ -255,14 +263,22 @@ def _time_ranged_read(q, cache, bias, T, scale, steps=64):
     per_step = lambda ranged: round(_time_us(loop(ranged), q[:, None], cache, iters=3) / steps)
     bias = bias[:, None, None, :]
     lo, hi = kv_read_ranges(T)[(T // 2) // kv_read_bucket(T)]
+    hbm_bytes_per_us = chip_peaks(jax.devices()[0].device_kind)[1] * 1e3
+    key_bytes = sum(a.nbytes for a in cache) // T  # one slot of every row: K, V, both scales
     verdict = {
         "kernel": f"xla read in a loop, int8 cache [{','.join(map(str, cache[0].shape))}]",
         "compiled": True, "within_tol": True, "route": "xla einsum (ranged read)",
         "whole_cache_us": per_step(False), "range": [lo, hi], "range_us": per_step(True),
     }
+    for name, keys in (("whole_cache", T), ("range", hi - lo)):
+        verdict[f"{name}_bytes"] = keys * key_bytes
+        verdict[f"{name}_hbm_share_pct"] = round(100 * keys * key_bytes / hbm_bytes_per_us / verdict[f"{name}_us"], 1)
     print(
         f"[kernel] {verdict['kernel']}: route={verdict['route']} info: a step reading the whole cache "
-        f"{verdict['whole_cache_us']} us, the ranged read at frontier {T // 2} ([{lo},{hi})) {verdict['range_us']} us",
+        f"{verdict['whole_cache_us']} us for {verdict['whole_cache_bytes'] / 1e6:.1f} MB "
+        f"({verdict['whole_cache_hbm_share_pct']}% of {hbm_bytes_per_us / 1e3:.0f} GB/s), the ranged read at frontier "
+        f"{T // 2} ([{lo},{hi})) {verdict['range_us']} us for {verdict['range_bytes'] / 1e6:.1f} MB "
+        f"({verdict['range_hbm_share_pct']}%)",
         flush=True,
     )
     return verdict
@@ -357,10 +373,13 @@ def kernel_phase(size, interpret):
     del x, w, b, g
 
     # ---- a decode step's read: XLA's einsum, whole cache and ranged ------
-    if not interpret:
-        (kq, ks), (vq, vs) = quantize_kv(normal(C, T, h, d)), quantize_kv(normal(C, T, h, d))
-        bias = jnp.asarray(np.where(_masks(rng, C, T), 0.0, -1e9), jnp.float32)
-        verdicts.append(_time_ranged_read(normal(C, h, d), (kq, vq, ks, vs), bias, T, scale))
+    # at the benchmark's two rollout shapes: this size's rows (GPT-J's heads,
+    # 256 wide), then GPT-Neo's (64 rows of 512 slots, heads 128 wide)
+    for rows, slots, width in [] if interpret else [(C, T, d), READ_SHAPE_NARROW_HEADS]:
+        (kq, ks), (vq, vs) = (quantize_kv(normal(rows, slots, h, width)) for _ in range(2))
+        bias = jnp.asarray(np.where(_masks(rng, rows, slots), 0.0, -1e9), jnp.float32)
+        verdicts.append(_time_ranged_read(
+            normal(rows, h, width), (kq, vq, ks, vs), bias, slots, 1.0 / math.sqrt(width)))
     return verdicts
 
 

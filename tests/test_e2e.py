@@ -48,6 +48,8 @@ def test_ppo_e2e_smoke(task, tmp_path):
     )
     assert model.iter_count >= 6
     assert len(model.store) > 0
+    # the int8 cache's reads scale a key's score and its probability, once each (ops/kv_read.py)
+    assert model._last_exp_stats["rollout/kv_scale_mults_per_key"] == 2.0
 
 
 def test_ppo_e2e_bucketed_prompts(task, tmp_path):

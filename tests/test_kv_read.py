@@ -5,10 +5,13 @@ slice of the KV cache that holds every key the bias can admit, chosen by a
 `lax.switch` on the write frontier. These tests pin: the same logits and
 tokens as the full-cache read, the ranges as a pure function, that the
 engine's per-row and paged steps do not take it, that the decode loop stays
-one `while`, and the host counter `rollout/kv_read_share`.
+one `while`, the host counter `rollout/kv_read_share`, and how a read of an
+int8 cache states its two contractions (`attend_quantized`: a key's scale once
+a key, the int8 values converted and nothing else).
 """
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,12 +19,17 @@ import pytest
 import trlx_tpu.models.lm as lm
 from trlx_tpu.models.lm import LMConfig, TransformerLM, init_cache, init_paged_cache, make_attn_bias
 from trlx_tpu.ops.generate import generate
+from trlx_tpu.ops import kv_read
 from trlx_tpu.ops.kv_read import (
     KV_READ_BUCKET,
     KV_READ_MAX_BRANCHES,
+    attend,
+    attend_quantized,
+    attend_range,
     kv_keys_read,
     kv_read_bucket,
     kv_read_ranges,
+    kv_scale_mults_per_key,
     ranged_read,
 )
 from trlx_tpu.ops.sampling import GenerateConfig
@@ -98,14 +106,17 @@ def test_ranges_hold_every_admitted_key(cache_len, window):
         assert ranges[k][0] <= admitted.min() and admitted.max() < ranges[k][1], (c, ranges[k])
 
 
+def _eqns(jaxpr):
+    """Every equation, through every sub-jaxpr."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
 def _count(jaxpr, name):
     """Equations of primitive `name`, through every sub-jaxpr."""
-    n = 0
-    for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == name
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += _count(sub, name)
-    return n
+    return sum(eqn.primitive.name == name for eqn in _eqns(jaxpr))
 
 
 @pytest.mark.parametrize("path", ["vector-index", "spec-verify", "paged", "paged-scalar-index", "prefill"])
@@ -305,3 +316,211 @@ def test_decode_step_read_matches_dense_float32_attention(quant, addressing, win
         ref = ref.reshape(-1, cfg.d_model) @ w["c_proj"] + f32(params["params"]["c_proj"]["bias"])
         np.testing.assert_allclose(f32(out)[live, 0], ref, rtol=2e-5, atol=2e-5)
         assert np.isfinite(f32(out)).all()
+
+
+# ----- the read of an int8 cache: a key's scale once a key ----------------
+
+
+def _dequantizing_attend_range(q, cache, attn_bias, lo, hi, scale, dtype):
+    """`attend_range` as it stood before `attend_quantized`: an int8 cache is
+    dequantized element by element, in `dtype`, ahead of both contractions.
+    Kept here as the comparison (and as the form an unquantized read must
+    still trace to), nowhere in the package."""
+    cut = lambda a: jax.lax.slice_in_dim(a, lo, hi, axis=1)
+    k, v = cut(cache[0]), cut(cache[1])
+    if len(cache) == 4:
+        k = k.astype(dtype) * cut(cache[2])[..., None].astype(dtype)
+        v = v.astype(dtype) * cut(cache[3])[..., None].astype(dtype)
+    return attend(q, k, v, jax.lax.slice_in_dim(attn_bias, lo, hi, axis=3), scale, dtype)
+
+
+def _attention_f64(q, k, v, bias, scale):
+    """Softmax attention in numpy float64; q [b, q, h, d], k/v [b, kv, h_kv, d],
+    K/V head j serving query heads [j * g, (j + 1) * g); bias [b, 1, q, kv]."""
+    g = q.shape[2] // k.shape[2]
+    k, v = np.repeat(k, g, axis=2), np.repeat(v, g, axis=2)
+    s = np.einsum("bqhd,bkhd->bhqk", q, k) * scale + bias
+    p = np.exp(s - s.max(axis=-1, keepdims=True))
+    return np.einsum("bhqk,bkhd->bqhd", p / p.sum(axis=-1, keepdims=True), v)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("span", [(0, 160), (32, 128)], ids=["whole", "lo-above-0"])
+@pytest.mark.parametrize("heads", [(4, 4), (8, 2)], ids=["plain", "grouped"])
+@pytest.mark.parametrize("q_len", [1, 8])
+def test_quantized_read_matches_float64_attention(q_len, heads, span, dtype):
+    """`attend_quantized` (through `attend_range`, so a slice too) against a
+    float64 softmax attention over k_i8 x k_scale, v_i8 x v_scale. In float32
+    it agrees to float32 rounding; in bf16 its error is no larger than that
+    of the read that dequantized K and V in bf16 first, on the same inputs."""
+    (h, h_kv), (lo, hi), b, T, d = heads, span, 3, 160, 32
+    rng = np.random.default_rng(11)
+    dtype = jnp.dtype(dtype)
+    q = jnp.asarray(rng.normal(size=(b, q_len, h, d)), dtype)
+    (k8, ks), (v8, vs) = (lm.quantize_kv(jnp.asarray(rng.normal(size=(b, T, h_kv, d)), jnp.float32)) for _ in range(2))
+    # ragged validity (left padding), and causal among the q_len queries at the end of the range
+    valid = np.arange(T)[None, None, None, :] >= np.array([0, 7, 40])[:, None, None, None]
+    causal = np.arange(T)[None, None, None, :] <= hi - q_len + np.arange(q_len)[None, None, :, None]
+    bias = np.where(valid & causal, 0.0, -1e9).astype(np.float32)
+    scale = 1.0 / np.sqrt(d)
+
+    f64 = lambda a: np.asarray(a.astype(jnp.float32), np.float64)
+    want = _attention_f64(f64(q), (f64(k8) * f64(ks)[..., None])[:, lo:hi], (f64(v8) * f64(vs)[..., None])[:, lo:hi],
+                          bias[..., lo:hi].astype(np.float64), scale)
+    cache = (k8, v8, ks, vs)
+    err = lambda read: float(np.sqrt(np.mean((f64(read(q, cache, jnp.asarray(bias), lo, hi, scale, dtype)) - want) ** 2)))
+    new, old = err(attend_range), err(_dequantizing_attend_range)
+    rms = float(np.sqrt(np.mean(want**2)))
+    if dtype == jnp.float32:
+        assert new <= 2e-6 * rms
+    else:
+        assert new <= old, (new, old)
+        assert new <= 2.0**-8 * rms  # the output's own rounding to bf16, and the weights': nothing else
+
+
+def test_grouped_quantized_read_never_repeats_keys_or_values():
+    """h_kv < h: K and V reach their contractions at their own h_kv heads; no
+    value of the program is as large as a K or V repeated over the group."""
+    b, q_len, h, h_kv, T, d = 2, 1, 8, 2, 64, 16
+    S = jax.ShapeDtypeStruct
+    args = (S((b, q_len, h, d), jnp.bfloat16), S((b, T, h_kv, d), jnp.int8), S((b, T, h_kv, d), jnp.int8),
+            S((b, T, h_kv), jnp.float32), S((b, T, h_kv), jnp.float32), S((b, 1, q_len, T), jnp.float32))
+    jaxpr = jax.make_jaxpr(lambda *a: attend_quantized(*a, 0.25, jnp.bfloat16))(*args).jaxpr
+    dots = [e for e in _eqns(jaxpr) if e.primitive.name == "dot_general"]
+    assert len(dots) == 2 and all((b, T, h_kv, d) in [v.aval.shape for v in e.invars] for e in dots)
+    assert max(int(np.prod(v.aval.shape)) for e in _eqns(jaxpr) for v in e.outvars) <= b * T * h_kv * d
+
+
+# What may stand between an int8 cache leaf and its contraction: the write,
+# a slice, the layout request, the paged pool's gather, a convert.
+_CARRIES_THE_CACHE = {"dynamic_update_slice", "slice", "dynamic_slice", "layout_constraint", "gather", "reshape",
+                      "convert_element_type", "scatter", "squeeze", "broadcast_in_dim"}
+
+
+def _int8_cache_consumers(jaxpr, tainted):
+    """Primitives that consume a value derived from an int8 cache leaf
+    (`tainted`: a set of the jaxpr's variables) by anything in
+    `_CARRIES_THE_CACHE`; returns (consumers, tainted outvars)."""
+    consumers = set()
+    tainted = set(tainted)
+    for eqn in jaxpr.eqns:
+        hit = [i for i, v in enumerate(eqn.invars) if isinstance(v, jax.extend.core.Var) and v in tainted]
+        if not hit:
+            continue
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if subs:
+            # a conditional's branches take the operands after the index; a jitted call all of them
+            offset = len(eqn.invars) - len(subs[0].invars)
+            for sub in subs:
+                inner, out = _int8_cache_consumers(sub, {sub.invars[i - offset] for i in hit if i >= offset})
+                consumers |= inner
+                tainted |= {eqn.outvars[j] for j, v in enumerate(sub.outvars) if v in out}
+        elif eqn.primitive.name in _CARRIES_THE_CACHE:
+            tainted |= set(eqn.outvars)
+        else:
+            consumers.add(eqn.primitive.name)
+    return consumers, tainted
+
+
+@pytest.mark.parametrize("addressing", ["one-traced-index", "per-row-index", "block-table", "prefill"])
+def test_int8_cache_reaches_its_contractions_through_converts_only(addressing):
+    """A decode step (ranged: one traced index; whole: a per-row index, a block
+    table) and a multi-token read over an int8 cache: the int8 leaves reach a
+    `dot_general` through the write, slices, gathers and converts only, and no
+    `mul` has an operand of the extent of the cache or of a range of it."""
+    cfg, model, params, _, _ = _tiny(quant=True, local=True, soft=False)
+    T, q_len = 320, 4 if addressing == "prefill" else 1
+    if addressing == "block-table":
+        cache, tables = init_paged_cache(cfg, 20, 32), jnp.arange(20, dtype=jnp.int32).reshape(B, 10)
+    else:
+        cache, tables = init_cache(cfg, B, T), None
+    index = {"one-traced-index": jnp.int32(290), "prefill": 0}.get(addressing, jnp.array([5, 290], jnp.int32))
+
+    def step(p, cache, index):
+        return model.apply(
+            p, input_ids=jnp.ones((B, q_len), jnp.int32), attention_mask=jnp.ones((B, q_len), jnp.int32),
+            cache=cache, cache_index=index, cache_mask=jnp.ones((B, T), jnp.int32), block_tables=tables)["logits"]
+
+    jaxpr = jax.make_jaxpr(step)(params, cache, index).jaxpr
+    assert _count(jaxpr, "cond") == (cfg.n_layer if addressing == "one-traced-index" else 0)
+    int8_leaves = {v for v in jaxpr.invars if v.aval.dtype == jnp.int8}
+    assert len(int8_leaves) == 2 * cfg.n_layer
+    consumers, _ = _int8_cache_consumers(jaxpr, int8_leaves)
+    assert consumers == {"dot_general"}
+    h, d = cfg.kv_heads, cfg.head_dim
+    extents = {(B, hi - lo, h, d) for w in (0, WINDOW) for lo, hi in kv_read_ranges(T, w)} | {tuple(cache[0][0].shape)}
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "mul":
+            assert not extents & {tuple(v.aval.shape) for v in eqn.invars}, eqn
+
+
+_PLAIN = dict(vocab_size=64, n_layer=2, n_head=4, d_model=32, max_position=512, pos_type="rotary", rotary_dim=8,
+              dtype="float32")
+_FAMILIES = {
+    # the GPT block, learned positions, alternating windows in a full-span cache
+    "plain": dict(_PLAIN, pos_type="learned", attention_layers=("global", "local"), window_size=WINDOW),
+    # grouped keys, qk-norm, a ring cache in the window layer (K-EXAONE's kinds)
+    "grouped-ring": dict(_PLAIN, n_kv_head=2, head_width=16, fused_qkv=False, qk_norm=True, norm="rmsnorm", mlp="gated",
+                         rotary_layers="local", attention_layers=("local", "global"), window_size=8, window_cache="ring"),
+    # a state-space layer beside a grouped attention layer with no position signal (granite's kinds)
+    "state-space": dict(_PLAIN, n_kv_head=2, head_width=16, fused_qkv=False, pos_type="none", norm="rmsnorm",
+                        mlp="gated", tie_word_embeddings=True, mixer_layers=("mamba", "attention"), ssm_heads=4,
+                        ssm_head_dim=16, ssm_state=16, ssm_conv=4, ssm_chunk=8),
+    # latent attention (Kimi's kind): its read is `attend_latent_range`
+    "latent": dict(_PLAIN, attention="mla", norm="rmsnorm", mlp="gated", tie_word_embeddings=False, q_lora_rank=24,
+                   kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8),
+}
+
+
+@pytest.mark.parametrize("q_len", [1, 4], ids=["decode-step", "prefill"])
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_unquantized_reads_trace_to_the_dequantizing_form(monkeypatch, family, q_len):
+    """A cache that is not int8 (`len(cache) == 2`: the three families whose
+    cells set `kv_cache_quant` off, and the GPT block without it) reads as it
+    did: the jaxpr text of a ranged decode step and of a multi-token read is
+    the one the old `attend_range` and a plain `attend` over the view give."""
+    cfg = LMConfig.from_dict(_FAMILIES[family])
+    model, T = TransformerLM(cfg), 320
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.ones((B, 4), jnp.int32), jnp.ones((B, 4), jnp.int32)))
+    cache = jax.eval_shape(lambda: init_cache(cfg, B, T))
+
+    def step(p, cache, index):
+        return model.apply(
+            p, input_ids=jnp.ones((B, q_len), jnp.int32), attention_mask=jnp.ones((B, q_len), jnp.int32),
+            cache=cache, cache_index=index if q_len == 1 else 0, cache_mask=jnp.ones((B, T), jnp.int32))["logits"]
+
+    index = jax.ShapeDtypeStruct((), jnp.int32)
+    text = str(jax.make_jaxpr(step)(params, cache, index))
+    assert ("cond[" in text) == (q_len == 1)
+    monkeypatch.setattr(lm, "attend_cache", lambda q, kv, *rest: attend(q, *kv, *rest))
+    monkeypatch.setattr(lm, "ranged_read", lambda *a, **k: ranged_read(*a, **{"attend_range": _dequantizing_attend_range, **k}))
+    assert text == str(jax.make_jaxpr(lambda *a: step(*a))(params, cache, index))
+
+
+def test_a_partitioned_mesh_keeps_the_dequantizing_read():
+    """On a mesh of more than one device a read of an int8 cache traces to the
+    text it had before `attend_quantized` (restated, the four-chip cell's
+    generate program reserved more memory than its bound allows), and the
+    counter `rollout/kv_scale_mults_per_key` says which read ran: two for a
+    key read and query head served, or 2 x head_dim (512 at GPT-J's heads)."""
+    from trlx_tpu.parallel import make_mesh
+    from trlx_tpu.parallel.mesh import peek_mesh, set_mesh
+
+    assert kv_scale_mults_per_key(16, 16, 256) == 2.0 and kv_scale_mults_per_key(64, 8, 128) == 16.0
+    S = jax.ShapeDtypeStruct
+    b, T, h, d = 2, 64, 4, 16
+    for q_len in (1, 8):
+        args = (S((b, q_len, h, d), jnp.bfloat16), (S((b, T, h, d), jnp.int8), S((b, T, h, d), jnp.int8),
+                S((b, T, h), jnp.float32), S((b, T, h), jnp.float32)), S((b, 1, q_len, T), jnp.float32))
+        bf16 = jnp.bfloat16
+        new = lambda q, cache, bias: kv_read.attend_cache(q, cache, bias, 0.25, bf16)
+        old = lambda q, c, bias: attend(q, c[0].astype(bf16) * c[2][..., None].astype(bf16),
+                                        c[1].astype(bf16) * c[3][..., None].astype(bf16), bias, 0.25, bf16)
+        assert str(jax.make_jaxpr(new)(*args)) != str(jax.make_jaxpr(old)(*args))
+        prior = peek_mesh()
+        set_mesh(make_mesh((1, 2, 4, 1)))
+        try:
+            assert str(jax.make_jaxpr(lambda *a: new(*a))(*args)) == str(jax.make_jaxpr(lambda *a: old(*a))(*args))
+            assert kv_scale_mults_per_key(16, 16, 256) == 512.0
+        finally:
+            set_mesh(prior)

@@ -681,6 +681,7 @@ def test_e2e_main_path_writes_span_keys_and_leaves_little_unspanned(task, tmp_pa
             assert w[key] >= 0.0, key
         assert w["rollout/decode_steps"] == 7.0  # max_length 8, one-token prompts
         assert w["rollout/kv_read_share"] == 1.0  # a cache of 8 slots is one branch: read whole
+        assert "rollout/kv_scale_mults_per_key" not in w  # the cache is not int8: nothing to scale
         assert w["time/unspanned_s"] < 0.05 * w["time/window_wall_s"]
         # the old keys keep their meaning: rollout = generate + device scoring + push
         assert w["time/rollout_s"] == pytest.approx(

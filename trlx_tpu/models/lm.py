@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trlx_tpu.observability import numerics as obs_numerics
-from trlx_tpu.ops.kv_read import attend, attend_latent, attend_latent_range, ranged_read
+from trlx_tpu.ops.kv_read import attend, attend_cache, attend_latent, attend_latent_range, ranged_read
 from trlx_tpu.parallel.mesh import partitioned
 from trlx_tpu.parallel.schedule import hold_rows, use_weight
 from trlx_tpu.utils import tree_size_bytes
@@ -123,8 +123,11 @@ class LMConfig:
     # and halves cache memory (longer sequences / larger rollout chunks per
     # chip). Only cache READS see quantization error: decode steps always,
     # and prefill only when it takes the einsum-over-cache path (flash
-    # prefill attends over the unquantized local block). Scoring/training
-    # passes have no cache and always run full precision.
+    # prefill attends over the unquantized local block). On one device a
+    # read never dequantizes: a key's scale multiplies its score after q.K
+    # and its probability before probs.V, once a key (ops/kv_read.py
+    # `attend_quantized`; a partitioned mesh keeps the dequantizing read).
+    # Scoring/training passes have no cache and always run full precision.
     kv_cache_quant: bool = False
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
@@ -707,6 +710,7 @@ class Attention(nn.Module):
 
         new_cache = None
         read = None  # set → the einsum read covers a slice of the cache
+        kv = (k, v)  # what the einsum read attends over otherwise: the block's own, or the whole cache
         if cache is not None:
             # WRITE. A per-row (vector) cache_index composes with q_len > 1
             # (the speculative verify window): the vmap'd write scatters a
@@ -782,8 +786,8 @@ class Attention(nn.Module):
             # ranged read covers only the slots the bias can admit and reads
             # new_cache itself (ops/kv_read.py). Everything else (a per-row
             # index, a block table, an unaligned prefill) attends over the
-            # whole virtual cache with the cache-validity bias, dequantized
-            # on read.
+            # whole virtual cache with the cache-validity bias; an int8 one
+            # as it is stored, its scales beside it (`attend_quantized`).
             # A ring layer: the prefill block likewise attends over itself;
             # a decode step reads all of the ring's slots, valid by the
             # position each holds (the trunk's `ring_bias`): one branch, no
@@ -792,14 +796,7 @@ class Attention(nn.Module):
                 if not (paged or ring):
                     read = ranged_read(int(cache[0].shape[1]), q_len, cache_index, window)
                 if read is None:
-
-                    def view(i):
-                        x = gather_virt(new_cache[i])
-                        if cfg.kv_cache_quant:
-                            x = x.astype(dtype) * gather_virt(new_cache[i + 2])[..., None].astype(dtype)
-                        return x
-
-                    k, v = view(0), view(1)
+                    kv = tuple(gather_virt(c) for c in new_cache)
 
         scale = cfg.attention_multiplier or (1.0 / np.sqrt(hd) if cfg.scale_attn else 1.0)
         with jax.named_scope("attn_window" if window else "attn_full"):
@@ -829,7 +826,7 @@ class Attention(nn.Module):
             elif read is not None:
                 out = read(q, new_cache, attn_bias, scale, dtype)
             else:
-                out = attend(q, k, v, attn_bias, scale, dtype)
+                out = attend_cache(q, kv, attn_bias, scale, dtype)
         out = out.reshape(b, q_len, cfg.n_head * hd)
         out = dense(cfg.d_model, "c_proj", cfg.out_bias)(out)
         return out, new_cache

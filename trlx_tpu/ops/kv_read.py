@@ -3,7 +3,7 @@
 On the static generate path (`ops/generate.py`: prompts are left-padded, so
 every row writes slot `P + step`) the additive bias admits key `j` for the
 query at slot `c` only if `j <= c` (and `j > c - window` on a local layer).
-The full-cache read dequantizes and contracts every slot and lets the bias
+The full-cache read contracts every slot and lets the bias
 zero the rest: a masked key contributes `exp(-1e9 - max)`, which is exactly 0
 in float32, so leaving it out of the read is the same mathematics on fewer
 bytes.
@@ -13,8 +13,15 @@ Two pieces, both pure:
 - `kv_read_ranges(cache_len, window)`: the static `[lo, hi)` of each branch;
   branch `k` serves every `cache_index` in `[k * bucket, (k + 1) * bucket)`.
 - `attend_range(q, cache, attn_bias, lo, hi, ...)`: slice K, V, their scales
-  and the bias to the range BEFORE the dequantize and the two contractions,
-  so XLA fuses the slice into the operand load as it fuses the int8 convert.
+  and the bias to the range BEFORE the two contractions, so XLA fuses the
+  slice into the operand load as it fuses the int8 convert.
+
+On one device an int8 cache is never dequantized, ranged or whole
+(`attend_quantized`): a key's scale is a constant of the key, so it multiplies
+the key's score after q.K and the key's probability before probs.V, once a
+key, and both contractions take the int8 values converted and nothing else.
+On a `partitioned()` mesh the read dequantizes as it did (the one place the
+expression is written).
 
 `ranged_read` is the rule `Attention.__call__` asks: which of its calls take
 the ranged read at all. The cache WRITE is not this module's business: it
@@ -88,15 +95,49 @@ def attend(q, k, v, attn_bias, scale, dtype):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(dtype))
 
 
+def attend_quantized(q, k_i8, v_i8, k_scale, v_scale, attn_bias, scale, dtype):
+    """`attend` over an int8 cache: `k_i8`/`v_i8` [b, kv, h_kv, d] hold
+    key = k_i8 x k_scale and value = v_i8 x v_scale, the scales [b, kv, h_kv]
+    one a key and head (models/lm.py `quantize_kv`). A key's scale leaves its
+    sum over `d`: sum_d q_d (s k_d) = s sum_d q_d k_d, and sum_k p_k (s_k v_kd)
+    = sum_k (p_k s_k) v_kd. So q contracts with the int8 K as it is and the
+    scale multiplies the [.., q, kv] scores; the probabilities take V's scale,
+    in float32, before they contract with the int8 V as it is. An int8 value
+    is exact in `dtype` and both products accumulate in float32: the sums are
+    those of float32 operands, and no dequantized K or V is ever rounded.
+    Grouped keys as in `attend`: one form, g = 1 where h_kv = h. One rule
+    stands before it, the one `ranged_read` asks: a `partitioned()` mesh."""
+    if partitioned():
+        # On a mesh the read still dequantizes, element by element in `dtype`:
+        # restated, the four-chip cell's generate program ran 13% faster and
+        # reserved 0.2 GB more a chip, past the bound on its peak (PERF.md
+        # section 6, PR 33; section 7 has what a cure must keep).
+        k = k_i8.astype(dtype) * k_scale[..., None].astype(dtype)
+        v = v_i8.astype(dtype) * v_scale[..., None].astype(dtype)
+        return attend(q, k, v, attn_bias, scale, dtype)
+    b, q_len, h, d = q.shape
+    h_kv = k_i8.shape[2]
+    # [b, kv, h_kv] -> [b, h_kv, 1, 1, kv]: one factor a key, for its g query heads
+    per_key = lambda s: jnp.transpose(s.astype(jnp.float32), (0, 2, 1))[:, :, None, None, :]
+    grouped = q.astype(dtype).reshape(b, q_len, h_kv, h // h_kv, d)
+    scores = jnp.einsum("bkhd,bqhgd->bhgqk", k_i8.astype(dtype), grouped, preferred_element_type=jnp.float32)
+    scores = scores * (per_key(k_scale) * scale) + attn_bias[:, :, None]
+    weights = (jax.nn.softmax(scores, axis=-1) * per_key(v_scale)).astype(dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", weights, v_i8.astype(dtype), preferred_element_type=jnp.float32)
+    return out.astype(dtype).reshape(b, q_len, h, d)
+
+
+def attend_cache(q, cache, attn_bias, scale, dtype):
+    """Softmax attention of `q` over a whole cache view: `(k, v)`, or the int8
+    `(k, v, k_scale, v_scale)` (what the code sees in its input, no setting)."""
+    return (attend_quantized if len(cache) == 4 else attend)(q, *cache, attn_bias, scale, dtype)
+
+
 def attend_range(q, cache, attn_bias, lo: int, hi: int, scale, dtype):
-    """`attend` over cache slots `[lo, hi)`; `cache` is `(k, v)` or the int8
-    `(k, v, k_scale, v_scale)`, each whole."""
+    """`attend_cache` over cache slots `[lo, hi)`; `cache` is `(k, v)` or the
+    int8 `(k, v, k_scale, v_scale)`, each whole."""
     cut = lambda a: jax.lax.slice_in_dim(a, lo, hi, axis=1)
-    k, v = cut(cache[0]), cut(cache[1])
-    if len(cache) == 4:
-        k = k.astype(dtype) * cut(cache[2])[..., None].astype(dtype)
-        v = v.astype(dtype) * cut(cache[3])[..., None].astype(dtype)
-    return attend(q, k, v, jax.lax.slice_in_dim(attn_bias, lo, hi, axis=3), scale, dtype)
+    return attend_cache(q, tuple(cut(a) for a in cache), jax.lax.slice_in_dim(attn_bias, lo, hi, axis=3), scale, dtype)
 
 
 def attend_latent(q_lat, q_rope, c_kv, k_rope, attn_bias, scale, dtype):
@@ -163,6 +204,17 @@ def ranged_read(cache_len: int, q_len: int, cache_index, window: int = 0, *,
         return jax.lax.switch(cache_index // bucket, branches, q, tuple(cache), attn_bias)
 
     return read
+
+
+def kv_scale_mults_per_key(n_head: int, kv_heads: int, head_dim: int) -> float:
+    """Scale multiplications a decode step's read of an int8 cache performs
+    for each key it reads (one slot of one K/V head of one row): one on the
+    key's score and one on its probability, for each of the `n_head //
+    kv_heads` query heads the key serves (`attend_quantized`); the read that
+    dequantizes K and V, which a `partitioned()` mesh keeps, `2 x head_dim`.
+    The counter `rollout/kv_scale_mults_per_key`, absent where the cache is
+    not int8."""
+    return 2.0 * (head_dim if partitioned() else n_head // kv_heads)
 
 
 def kv_keys_read(

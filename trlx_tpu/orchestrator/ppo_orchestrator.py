@@ -28,7 +28,7 @@ import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.models.lm import cache_bytes, cache_bytes_per_token, decode_step_bytes, layer_window, ring_slots, state_bytes
-from trlx_tpu.ops.kv_read import kv_keys_read
+from trlx_tpu.ops.kv_read import kv_keys_read, kv_scale_mults_per_key
 from trlx_tpu.parallel.schedule import weight_gather_share
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
 from trlx_tpu.pipeline.overlap import ScoreWorker
@@ -533,6 +533,10 @@ class PPOOrchestrator(Orchestrator):
             "rollout/cache_bytes_per_token": float(cache_bytes_per_token(lm_cfg)),
             "rollout/cache_bytes": float(cache_alloc),
         }
+        if lm_cfg.kv_cache_quant:
+            # an int8 cache: a read applies a key's scale once a key, not once an element (a mesh: once an element)
+            rl._last_exp_stats["rollout/kv_scale_mults_per_key"] = kv_scale_mults_per_key(
+                lm_cfg.n_head, lm_cfg.kv_heads, lm_cfg.head_dim)
         if experts_touched:
             rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
         if lm_cfg.has_ssm and cache_alloc:
