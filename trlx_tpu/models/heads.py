@@ -224,9 +224,10 @@ class LMWithILQLHeads(nn.Module):
     def compute_qs(self, hidden) -> Tuple[jnp.ndarray, ...]:
         """Q head application; also the target-Q entry point (apply with the
         target params subtree swapped into 'q1_head'/'q2_head')."""
-        qs = (self.q1_head(hidden),)
-        if self.two_qs:
-            qs = qs + (self.q2_head(hidden),)
+        with jax.named_scope("lm_head"):
+            qs = (self.q1_head(hidden),)
+            if self.two_qs:
+                qs = qs + (self.q2_head(hidden),)
         return qs
 
 

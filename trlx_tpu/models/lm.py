@@ -821,10 +821,14 @@ class Attention(nn.Module):
                     # test holds them at that width (PERF.md §7, PR 32 e).
                     pad = -hd % 128
                     widen = (lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, pad)))) if pad else (lambda a: a)
-                    out = flash_attention(widen(q), widen(k), widen(v), flash_mask, scale=scale, causal=True, window=window)
-                    out = (out[..., :hd] if pad else out).astype(dtype)
+                    with jax.named_scope("flash_attn"):  # what the call site costs: pad, relayouts, kernels
+                        out = flash_attention(widen(q), widen(k), widen(v), flash_mask, scale=scale, causal=True, window=window)
+                        out = (out[..., :hd] if pad else out).astype(dtype)
             elif read is not None:
                 out = read(q, new_cache, attn_bias, scale, dtype)
+            elif cache is not None:
+                with jax.named_scope("kv_read"):  # the whole cache, where `ranged_read` gave none
+                    out = attend_cache(q, kv, attn_bias, scale, dtype)
             else:
                 out = attend_cache(q, kv, attn_bias, scale, dtype)
         out = out.reshape(b, q_len, cfg.n_head * hd)
@@ -927,8 +931,9 @@ class LatentAttention(nn.Module):
 
                 wide = -(-(dn + dr) // 128) * 128
                 pad = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, wide - a.shape[-1])))
-                out = flash_attention(pad(q), pad(k), pad(v), mask_or_bias, scale=softmax_scale, causal=True)
-                return out[..., :dv].astype(dtype).reshape(rows, q_len, h * dv)
+                with jax.named_scope("flash_attn"):
+                    out = flash_attention(pad(q), pad(k), pad(v), mask_or_bias, scale=softmax_scale, causal=True)
+                    return out[..., :dv].astype(dtype).reshape(rows, q_len, h * dv)
 
             with jax.named_scope("mla_unabsorbed"):
                 operands = (c_q, c_kv, k_rope, sin, cos, flash_mask if flash_mask is not None else attn_bias)
@@ -952,7 +957,8 @@ class LatentAttention(nn.Module):
                 if read is not None:
                     o_lat = read((q_lat, q_rope), new_cache, attn_bias, softmax_scale, dtype)
                 else:
-                    o_lat = attend_latent(q_lat, q_rope, *new_cache, attn_bias, softmax_scale, dtype)
+                    with jax.named_scope("kv_read"):
+                        o_lat = attend_latent(q_lat, q_rope, *new_cache, attn_bias, softmax_scale, dtype)
                 out = jnp.einsum("bqhc,chv->bqhv", o_lat, w_kvb[..., dn:]).reshape(b, q_len, h * dv)
         return dense(cfg.d_model, "c_proj")(out), new_cache
 
@@ -1164,18 +1170,19 @@ class TransformerLM(nn.Module):
             (table,) = embed.promote_dtype(embed.embedding, dtype=embed.dtype, inexact=False)
             return jnp.take(use_weight(table, embed.path + ("embedding",), ids.size, lookup=True), ids, axis=0)
 
-        if inputs_embeds is None:
-            if cfg.onehot_embed and cache is None:
-                # Training/scoring forward on a sharded mesh: one-hot matmul
-                # (see LMConfig.onehot_embed). Decode keeps the gather.
-                onehot = jax.nn.one_hot(input_ids, cfg.vocab_size, dtype=cfg.compute_dtype)
-                x = onehot @ use_weight(wte.embedding.astype(cfg.compute_dtype), wte.path + ("embedding",), input_ids.size)
+        with jax.named_scope("embed"):
+            if inputs_embeds is None:
+                if cfg.onehot_embed and cache is None:
+                    # Training/scoring forward on a sharded mesh: one-hot matmul
+                    # (see LMConfig.onehot_embed). Decode keeps the gather.
+                    onehot = jax.nn.one_hot(input_ids, cfg.vocab_size, dtype=cfg.compute_dtype)
+                    x = onehot @ use_weight(wte.embedding.astype(cfg.compute_dtype), wte.path + ("embedding",), input_ids.size)
+                else:
+                    x = lookup(wte, input_ids)
+                if cfg.embedding_multiplier != 1.0:
+                    x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
             else:
-                x = lookup(wte, input_ids)
-            if cfg.embedding_multiplier != 1.0:
-                x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
-        else:
-            x = inputs_embeds.astype(cfg.compute_dtype)
+                x = inputs_embeds.astype(cfg.compute_dtype)
 
         b, q_len = x.shape[:2]
         if attention_mask is None:
@@ -1242,7 +1249,8 @@ class TransformerLM(nn.Module):
             wpe = nn.Embed(
                 cfg.max_position, cfg.d_model, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name="wpe"
             )
-            x = x + lookup(wpe, position_ids)
+            with jax.named_scope("embed"):
+                x = x + lookup(wpe, position_ids)
         if start_layer == 0:
             # graftnum probe tap (observability/numerics.py): identity unless
             # the NaN-provenance bisector's EAGER re-forward is live — inside
@@ -1367,56 +1375,57 @@ class TransformerLM(nn.Module):
             lambda h: h * jnp.asarray(1.0 / cfg.logits_scaling, h.dtype))
         # The head's weight moves as the trunk's do: by the tokens of the pass
         # (the rows `hold_rows` kept in place), not the positions it evaluates.
-        if labels is not None:
-            # Fused head mode: the [b, S, V] logits are never materialized —
-            # the vocab projection streams through the Pallas kernel (or the
-            # exact log_softmax chain when ineligible). The label length S
-            # selects how many head positions are evaluated: callers that
-            # previously computed logits[:, :-1] simply pass S = len-1 labels.
-            from trlx_tpu.ops.fused_logprob import routed_logprob
+        with jax.named_scope("lm_head"):
+            if labels is not None:
+                # Fused head mode: the [b, S, V] logits are never materialized —
+                # the vocab projection streams through the Pallas kernel (or the
+                # exact log_softmax chain when ineligible). The label length S
+                # selects how many head positions are evaluated: callers that
+                # previously computed logits[:, :-1] simply pass S = len-1 labels.
+                from trlx_tpu.ops.fused_logprob import routed_logprob
 
-            S = labels.shape[1]
-            x_head = x[:, logits_start:] if logits_start else x
-            x_head = unscaled(x_head[:, :S])
-            if cfg.tie_word_embeddings:
-                w_head, b_head, tied = use_weight(wte.embedding, wte.path + ("embedding",), b * q_len), None, True
-            else:
-                w_head, b_head = HeadParams(
-                    cfg.vocab_size,
-                    param_dtype=cfg.params_dtype,
-                    use_bias=cfg.extra.get("lm_head_bias", False),
-                    draw_dtype=cfg.draw_dtype,
-                    name="lm_head",
-                )(x_head.shape[-1], b * q_len)
-                tied = False
-            logprobs, lse, entropy = routed_logprob(
-                x_head,
-                w_head,
-                labels,
-                b_head,
-                tied=tied,
-                mode=cfg.extra.get("fused_logprob", "auto"),
-                mask=labels_mask,
-            )
-        elif compute_logits:
-            # RL losses/scoring only need logits from the first response
-            # position on — slicing before the head skips ~P/T of the
-            # vocab-projection FLOPs and the fp32 logit memory.
-            x_head = unscaled(x[:, logits_start:] if logits_start else x)
-            if cfg.tie_word_embeddings:
-                # `wte.attend(x_head)`, with the table as this product uses it
-                query, table = wte.promote_dtype(x_head, wte.embedding, dtype=wte.dtype)
-                logits = jnp.dot(query, use_weight(table, wte.path + ("embedding",), b * q_len).T)
-            else:
-                logits = QDense(
-                    cfg.vocab_size,
-                    dtype=cfg.compute_dtype,
-                    param_dtype=cfg.params_dtype,
-                    use_bias=cfg.extra.get("lm_head_bias", False),
-                    pass_tokens=b * q_len,
-                    draw_dtype=cfg.draw_dtype,
-                    name="lm_head",
-                )(x_head)
+                S = labels.shape[1]
+                x_head = x[:, logits_start:] if logits_start else x
+                x_head = unscaled(x_head[:, :S])
+                if cfg.tie_word_embeddings:
+                    w_head, b_head, tied = use_weight(wte.embedding, wte.path + ("embedding",), b * q_len), None, True
+                else:
+                    w_head, b_head = HeadParams(
+                        cfg.vocab_size,
+                        param_dtype=cfg.params_dtype,
+                        use_bias=cfg.extra.get("lm_head_bias", False),
+                        draw_dtype=cfg.draw_dtype,
+                        name="lm_head",
+                    )(x_head.shape[-1], b * q_len)
+                    tied = False
+                logprobs, lse, entropy = routed_logprob(
+                    x_head,
+                    w_head,
+                    labels,
+                    b_head,
+                    tied=tied,
+                    mode=cfg.extra.get("fused_logprob", "auto"),
+                    mask=labels_mask,
+                )
+            elif compute_logits:
+                # RL losses/scoring only need logits from the first response
+                # position on — slicing before the head skips ~P/T of the
+                # vocab-projection FLOPs and the fp32 logit memory.
+                x_head = unscaled(x[:, logits_start:] if logits_start else x)
+                if cfg.tie_word_embeddings:
+                    # `wte.attend(x_head)`, with the table as this product uses it
+                    query, table = wte.promote_dtype(x_head, wte.embedding, dtype=wte.dtype)
+                    logits = jnp.dot(query, use_weight(table, wte.path + ("embedding",), b * q_len).T)
+                else:
+                    logits = QDense(
+                        cfg.vocab_size,
+                        dtype=cfg.compute_dtype,
+                        param_dtype=cfg.params_dtype,
+                        use_bias=cfg.extra.get("lm_head_bias", False),
+                        pass_tokens=b * q_len,
+                        draw_dtype=cfg.draw_dtype,
+                        name="lm_head",
+                    )(x_head)
 
         return {
             "logits": logits,

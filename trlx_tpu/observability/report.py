@@ -19,8 +19,11 @@ boundary so metrics.jsonl carries fleet-mean/max gauges, not just rank 0's.
 """
 
 import argparse
+import bisect
+import gzip
 import json
 import os
+import re
 import warnings
 from collections import defaultdict
 
@@ -454,6 +457,57 @@ def _numerics_section(checkpoint_dir, scalars):
 # ----------------------------------------------------------------- report
 
 
+def _device_scopes_section(checkpoint_dir, xplane):
+    """Device time by scope: the profiler's trace (`xplane`, an `.xplane.pb`,
+    gzipped or not) joined with the run's `device_scopes.json` by
+    `<program>/<instruction>`, as benchmark/readers/scope_time.py joins them.
+    A loop, conditional or call (an event that contains the next one) is left
+    out: its body's events are counted."""
+    from jax.profiler import ProfileData
+
+    from trlx_tpu.observability.device_scopes import SCOPES_FILENAME
+
+    lines = ["## Device time by scope", ""]
+    try:
+        with open(os.path.join(checkpoint_dir, SCOPES_FILENAME)) as f:
+            tables = json.load(f)["programs"]
+    except (OSError, ValueError):
+        return lines + [f"No `{SCOPES_FILENAME}`: written at the first iteration boundary after a profiler session "
+                        "closed (`train.profile_dir`, RUNBOOK §8).", ""]
+    scopes = {}
+    for table in tables:
+        for name, entry in table["ops"].items():
+            scopes.setdefault((table["module"], name), entry)
+    with (gzip.open if xplane.endswith(".gz") else open)(xplane, "rb") as f:
+        planes = ProfileData.from_serialized_xspace(f.read()).planes
+    seconds = defaultdict(lambda: defaultdict(float))  # program -> (innermost scope, pass) -> device seconds
+    for plane in planes:
+        by_name = {line.name: line for line in plane.lines}
+        if "XLA Ops" not in by_name or "XLA Modules" not in by_name:
+            continue
+        modules = sorted((e.start_ns, e.start_ns + e.duration_ns, re.sub(r"\(\d+\)$", "", e.name))
+                         for e in by_name["XLA Modules"].events)
+        starts = [m[0] for m in modules]
+        events = sorted(((e.start_ns, e.start_ns + e.duration_ns, e.name) for e in by_name["XLA Ops"].events
+                         if e.duration_ns > 0), key=lambda t: (t[0], -t[1]))
+        for k, (s, e, text) in enumerate(events):
+            if k + 1 < len(events) and events[k + 1][0] < e:
+                continue
+            i = bisect.bisect_right(starts, s) - 1
+            program = modules[i][2] if i >= 0 and s < modules[i][1] else "?"
+            chain, which = scopes.get((program, text.split(" = ", 1)[0].lstrip("%")), ("", "-"))
+            seconds[program][(chain.rsplit("/", 1)[-1] or "(no scope)", which)] += (e - s) / 1e9
+    if not seconds:
+        return lines + [f"`{xplane}` holds no device plane with `XLA Ops`.", ""]
+    lines += ["| program | scope | pass | device ms | share of the program's busy time |", "|---|---|---|---|---|"]
+    for program, rows in sorted(seconds.items(), key=lambda kv: -sum(kv[1].values())):
+        busy = sum(rows.values())
+        for (scope, which), s in sorted(rows.items(), key=lambda kv: -kv[1])[:12]:
+            lines.append(f"| {program} | {scope} | {which} | {_fmt(1e3 * s, 3)} | {_fmt(100 * s / busy, 1)}% |")
+    return lines + ["", "Milliseconds are summed over the device planes of the trace; a scope is the innermost "
+                    "`device_scopes.SCOPES` name on the operation's `op_name` path.", ""]
+
+
 def build_report(checkpoint_dir: str) -> str:
     checkpoint_dir = os.path.abspath(checkpoint_dir)
     metrics = _load_jsonl(os.path.join(checkpoint_dir, "metrics.jsonl"))
@@ -709,9 +763,16 @@ def main(argv=None):
         default=None,
         help="also write spans.jsonl as a {'traceEvents': [...]} JSON for chrome://tracing",
     )
+    parser.add_argument(
+        "--xplane",
+        default=None,
+        help="a profiler trace (.xplane.pb[.gz]) of this run: adds 'Device time by scope' from device_scopes.json",
+    )
     args = parser.parse_args(argv)
 
     report = build_report(args.checkpoint_dir)
+    if args.xplane:
+        report += "\n" + "\n".join(_device_scopes_section(os.path.abspath(args.checkpoint_dir), args.xplane))
     if args.out:
         with open(args.out, "w") as f:
             f.write(report)

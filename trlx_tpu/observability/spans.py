@@ -61,6 +61,7 @@ import warnings
 
 import jax
 
+from trlx_tpu.observability import device_scopes
 from trlx_tpu.utils import jsonl
 
 __all__ = [
@@ -220,6 +221,7 @@ def configure(path=None, process_index=0):
     ``process_index`` becomes the trace's ``pid`` lane group: pass
     ``jax.process_index()`` so multi-host runs sharing a checkpoint dir get
     one lane group per host."""
+    device_scopes.flush(closing=True)  # a traced run's table, if no boundary wrote it
     old, _STATE["tracer"] = _STATE["tracer"], None
     if old is not None:
         old.close()
@@ -237,10 +239,13 @@ def enabled() -> bool:
 
 def flush():
     """Write the spans kept since the last flush (armed only). The loops call
-    this at their iteration boundary, never from inside a step."""
+    this at their iteration boundary, never from inside a step. Armed or not,
+    the boundary after a profiler session has closed also writes
+    ``device_scopes.json`` (device_scopes.py)."""
     tracer = _STATE["tracer"]
     if tracer is not None:
         tracer.flush()
+    device_scopes.flush()
 
 
 def set_iteration(n: int):

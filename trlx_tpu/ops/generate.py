@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from trlx_tpu.models.lm import cache_partition_spec, init_cache
+from trlx_tpu.observability import device_scopes
 from trlx_tpu.ops.sampling import GenerateConfig, process_logits_default
 
 
@@ -181,20 +182,21 @@ def generate(
     def body(s):
         step = s["step"]
         last_token = jax.lax.dynamic_slice_in_dim(s["tokens"], P - 1 + step, 1, axis=1)[:, 0]
-        if processor is not None:
-            logits = processor(
-                s["last_logits"],
-                {"last_token": last_token, "hidden": s["last_hidden"], "step": step, "carry": s["carry"]},
-            )
-        else:
-            logits = process_logits_default(s["last_logits"], gcfg, step)
+        with jax.named_scope("sample"):
+            if processor is not None:
+                logits = processor(
+                    s["last_logits"],
+                    {"last_token": last_token, "hidden": s["last_hidden"], "step": step, "carry": s["carry"]},
+                )
+            else:
+                logits = process_logits_default(s["last_logits"], gcfg, step)
 
-        rng, sub = jax.random.split(s["rng"])
-        if gcfg.do_sample:
-            tok = jax.random.categorical(sub, logits, axis=-1)
-        else:
-            tok = jnp.argmax(logits, axis=-1)
-        tok = tok.astype(s["tokens"].dtype)
+            rng, sub = jax.random.split(s["rng"])
+            if gcfg.do_sample:
+                tok = jax.random.categorical(sub, logits, axis=-1)
+            else:
+                tok = jnp.argmax(logits, axis=-1)
+            tok = tok.astype(s["tokens"].dtype)
 
         was_finished = s["finished"]
         tok = jnp.where(was_finished, gcfg.pad_token_id, tok)
@@ -306,7 +308,7 @@ def make_generate_fn(model, gcfg: GenerateConfig, processor: Optional[Callable] 
         _traces["shapes"].append(tuple(prompt_ids.shape))
         return fn(variables, prompt_ids, prompt_mask, rng)
 
-    jitted = jax.jit(traced)
+    jitted = device_scopes.wrap(jax.jit(traced))
     if monitor is not None:
         jitted = monitor.wrap(monitor_name, jitted, phase="rollout")
 

@@ -33,6 +33,7 @@ def action_tokens(input_ids: jnp.ndarray, actions_ixs: jnp.ndarray) -> jnp.ndarr
     return jnp.take_along_axis(input_ids[:, 1:], actions_ixs, axis=1)
 
 
+@jax.named_scope("loss")
 def ilql_loss_terms(
     Qs: Sequence[jnp.ndarray],        # each [b, A] fp32: online Q at dataset action
     targetQs: Sequence[jnp.ndarray],  # each [b, A] fp32: target Q at dataset action
@@ -91,6 +92,7 @@ def ilql_loss_terms(
     return loss, stats
 
 
+@jax.named_scope("loss")
 def ilql_loss(
     logits: jnp.ndarray,       # [b, T, V]
     qs: Tuple[jnp.ndarray, ...],        # each [b, A, V] (online heads)

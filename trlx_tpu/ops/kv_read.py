@@ -201,7 +201,8 @@ def ranged_read(cache_len: int, q_len: int, cache_index, window: int = 0, *,
                     attend_range=attend_range, slot_major=slot_major)
             for lo, hi in ranges
         ]
-        return jax.lax.switch(cache_index // bucket, branches, q, tuple(cache), attn_bias)
+        with jax.named_scope("kv_read"):
+            return jax.lax.switch(cache_index // bucket, branches, q, tuple(cache), attn_bias)
 
     return read
 

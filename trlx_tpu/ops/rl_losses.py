@@ -84,6 +84,7 @@ def gae_advantages(
     return advantages, returns
 
 
+@jax.named_scope("loss")
 def ppo_loss(
     logprobs: jnp.ndarray,
     vpred: jnp.ndarray,

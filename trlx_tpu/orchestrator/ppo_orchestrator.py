@@ -491,74 +491,78 @@ class PPOOrchestrator(Orchestrator):
                 timer.add("rollout", gen_s + score_s + push_s)
                 timer.add("score", reward_s)
 
-        exp_time = clock.tick()
-        # Process-local statistics of the final chunk (logging only).
-        stats = {
-            "exp_time": exp_time,
-            "exp_gen_s": gen_s,
-            "exp_reward_s": reward_s,
-            "exp_score_s": score_s,
-            "exp_push_s": push_s,
-            # Decode-loop observability: generated tokens per second of
-            # generate-BLOCKED wall time (pipelining hides device time
-            # behind host work, so this is a lower bound on the device
-            # rate), and the per-chunk while_loop steps actually executed
-            # vs the max_new_tokens budget (early-exit savings).
-            "exp_decode_tokens_per_s": gen_tokens / max(gen_s, 1e-9),
-            "exp_decode_steps": float(np.mean(decode_steps)),
-            # Dispatch/token split (same keys as the engine path): the
-            # static-batch loop advances every row one token per step, so
-            # dispatches = total while-loop steps and tokens = the unpadded
-            # generated-token count.
-            "exp_decode_dispatches": float(np.sum(decode_steps)),
-            "exp_decode_tokens": float(gen_tokens),
-            "exp_decode_step_budget": float(step_budget),
-            # Per-EPISODE decode steps vs the per-chunk max above: their gap
-            # is the straggler overhead the static batch pays (see
-            # rollout_decode_stats; the engine path logs the same key).
-            "exp_decode_steps_per_episode": (
-                float(np.mean(episode_steps)) if episode_steps else 0.0
-            ),
-            "rollout_mean_score": float(np.mean(last_scores)),
-            "rollout_mean_kl": float(np.mean(np.asarray(last_kl).sum(-1))),
-            "exp_per_sec": num_rollouts / max(exp_time, 1e-9),
-        }
-        if record_staleness:
-            stats["exp_staleness"] = float(staleness)
-        # Surfaced by progress_line at the next log boundary.
-        rl._last_exp_stats = {
-            "exp_per_sec": stats["exp_per_sec"],
-            "rollout/decode_steps": stats["exp_decode_dispatches"],
-            "rollout/kv_read_share": float(kv_keys[0] / kv_keys[1]) if kv_keys[1] else 1.0,
-            "rollout/cache_bytes_per_token": float(cache_bytes_per_token(lm_cfg)),
-            "rollout/cache_bytes": float(cache_alloc),
-        }
-        if lm_cfg.kv_cache_quant:
-            # an int8 cache: a read applies a key's scale once a key, not once an element (a mesh: once an element)
-            rl._last_exp_stats["rollout/kv_scale_mults_per_key"] = kv_scale_mults_per_key(
-                lm_cfg.n_head, lm_cfg.kv_heads, lm_cfg.head_dim)
-        if experts_touched:
-            rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
-        if lm_cfg.has_ssm and cache_alloc:
-            # What a decode step must move, from shapes: the weights once, the
-            # state read and written, the keys the ranged read took (the mean
-            # over the rollout's steps).
-            steps = max(1, int(np.sum(decode_steps)))
-            keys_a_step = kv_keys[0] / steps / max(1, len(key_layers))
-            needed, state_rw = decode_step_bytes(
-                lm_cfg, gen_rows, keys_a_step, tree_size_bytes(rl.state.params["transformer"]))
-            rl._last_exp_stats.update({
-                "rollout/state_bytes": float(state_bytes(lm_cfg, gen_rows)),
-                "rollout/state_bytes_per_row": float(state_bytes(lm_cfg, 1)),
-                "rollout/step_bytes_needed": float(needed),
-                "ssm/state_rw_share": float(state_rw / needed),
-            })
-        gather_share = weight_gather_share(rl._weight_gathers["generate"])
-        if gather_share is not None:
-            # the generate program on a partitioned mesh: the prefill gathers
-            # its weights, the decode loop keeps the shards
-            rl._last_exp_stats["parallel/weight_gather_share"] = gather_share
-        rl.tracker.log(stats, step=iter_count)
+        # The rollout's own counters and its record: host work after the last
+        # push, with the device idle (three `eval_shape` of the cache: 36 ms a
+        # cycle at 24 layers; PERF.md section 6, PR 35).
+        with trace_span("rollout/stats"):
+            exp_time = clock.tick()
+            # Process-local statistics of the final chunk (logging only).
+            stats = {
+                "exp_time": exp_time,
+                "exp_gen_s": gen_s,
+                "exp_reward_s": reward_s,
+                "exp_score_s": score_s,
+                "exp_push_s": push_s,
+                # Decode-loop observability: generated tokens per second of
+                # generate-BLOCKED wall time (pipelining hides device time
+                # behind host work, so this is a lower bound on the device
+                # rate), and the per-chunk while_loop steps actually executed
+                # vs the max_new_tokens budget (early-exit savings).
+                "exp_decode_tokens_per_s": gen_tokens / max(gen_s, 1e-9),
+                "exp_decode_steps": float(np.mean(decode_steps)),
+                # Dispatch/token split (same keys as the engine path): the
+                # static-batch loop advances every row one token per step, so
+                # dispatches = total while-loop steps and tokens = the unpadded
+                # generated-token count.
+                "exp_decode_dispatches": float(np.sum(decode_steps)),
+                "exp_decode_tokens": float(gen_tokens),
+                "exp_decode_step_budget": float(step_budget),
+                # Per-EPISODE decode steps vs the per-chunk max above: their gap
+                # is the straggler overhead the static batch pays (see
+                # rollout_decode_stats; the engine path logs the same key).
+                "exp_decode_steps_per_episode": (
+                    float(np.mean(episode_steps)) if episode_steps else 0.0
+                ),
+                "rollout_mean_score": float(np.mean(last_scores)),
+                "rollout_mean_kl": float(np.mean(np.asarray(last_kl).sum(-1))),
+                "exp_per_sec": num_rollouts / max(exp_time, 1e-9),
+            }
+            if record_staleness:
+                stats["exp_staleness"] = float(staleness)
+            # Surfaced by progress_line at the next log boundary.
+            rl._last_exp_stats = {
+                "exp_per_sec": stats["exp_per_sec"],
+                "rollout/decode_steps": stats["exp_decode_dispatches"],
+                "rollout/kv_read_share": float(kv_keys[0] / kv_keys[1]) if kv_keys[1] else 1.0,
+                "rollout/cache_bytes_per_token": float(cache_bytes_per_token(lm_cfg)),
+                "rollout/cache_bytes": float(cache_alloc),
+            }
+            if lm_cfg.kv_cache_quant:
+                # an int8 cache: a read applies a key's scale once a key, not once an element (a mesh: once an element)
+                rl._last_exp_stats["rollout/kv_scale_mults_per_key"] = kv_scale_mults_per_key(
+                    lm_cfg.n_head, lm_cfg.kv_heads, lm_cfg.head_dim)
+            if experts_touched:
+                rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
+            if lm_cfg.has_ssm and cache_alloc:
+                # What a decode step must move, from shapes: the weights once, the
+                # state read and written, the keys the ranged read took (the mean
+                # over the rollout's steps).
+                steps = max(1, int(np.sum(decode_steps)))
+                keys_a_step = kv_keys[0] / steps / max(1, len(key_layers))
+                needed, state_rw = decode_step_bytes(
+                    lm_cfg, gen_rows, keys_a_step, tree_size_bytes(rl.state.params["transformer"]))
+                rl._last_exp_stats.update({
+                    "rollout/state_bytes": float(state_bytes(lm_cfg, gen_rows)),
+                    "rollout/state_bytes_per_row": float(state_bytes(lm_cfg, 1)),
+                    "rollout/step_bytes_needed": float(needed),
+                    "ssm/state_rw_share": float(state_rw / needed),
+                })
+            gather_share = weight_gather_share(rl._weight_gathers["generate"])
+            if gather_share is not None:
+                # the generate program on a partitioned mesh: the prefill gathers
+                # its weights, the decode loop keeps the shards
+                rl._last_exp_stats["parallel/weight_gather_share"] = gather_share
+            rl.tracker.log(stats, step=iter_count)
 
     def _make_experience_engine(
         self,

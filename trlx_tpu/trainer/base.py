@@ -39,6 +39,7 @@ from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.heads import trainable_mask
 from trlx_tpu.models.lm import flash_kept_pair_share
 from trlx_tpu import observability as obs
+from trlx_tpu.observability import device_scopes
 from trlx_tpu.observability import fleet as obs_fleet
 from trlx_tpu.observability import graftscope as obs_graftscope
 from trlx_tpu.observability import numerics as obs_numerics
@@ -268,6 +269,7 @@ class JaxBaseTrainer(BaseRLTrainer):
             # trainer in this process (tests build several) must not keep
             # appending this run's thread spans to its old file.
             obs_spans.shutdown()
+        device_scopes.configure(ckpt_dir)
         obs_spans.install_compile_listener()
         self._devicemon = None
         if (
@@ -452,13 +454,15 @@ class JaxBaseTrainer(BaseRLTrainer):
             self.train_step = self._wrap_monitored("train/step", self.build_train_step())
 
     def _wrap_monitored(self, name: str, fn, phase: str = "train"):
-        """Route a jitted fn through the device-telemetry monitor — identity
-        when telemetry is off, so call sites stay unconditional. getattr:
+        """Route a jitted fn through the device-telemetry monitor (when
+        telemetry is on; call sites stay unconditional). getattr:
         subclass __init__ code may build programs before the base bootstrap
         has armed the monitor. Every registered jitted program funnels
         through here, so this is also where the dispatch sanitizer hooks in
-        (identity unless TRLX_TPU_SANITIZE=dispatch)."""
-        fn = sanitize.wrap_dispatch(name, fn, getattr(self, "_dispatch_lock", None))
+        (identity unless TRLX_TPU_SANITIZE=dispatch), and where a program
+        dispatched under an open profiler session notes itself for the
+        scope table (observability/device_scopes.py), telemetry on or off."""
+        fn = sanitize.wrap_dispatch(name, device_scopes.wrap(fn), getattr(self, "_dispatch_lock", None))
         monitor = getattr(self, "_devicemon", None)
         if monitor is None:
             return fn
@@ -1044,6 +1048,9 @@ class JaxBaseTrainer(BaseRLTrainer):
             self._close_batch_feed()
             self._shutdown_experience_pipeline()
             self.end_progress()
+            if self._profiling:  # before the flush: a closed session's scope table is written by it
+                jax.profiler.stop_trace()
+                self._profiling = False
             obs_spans.flush()
             # An async interval save may still be in flight — its sidecars
             # (manifest, latest.txt) only land at finalize, so the exit path
@@ -1081,8 +1088,6 @@ class JaxBaseTrainer(BaseRLTrainer):
                 # the final gauge state right up to teardown.
                 self._metrics_exporter.close()
                 self._metrics_exporter = None
-            if self._profiling:
-                jax.profiler.stop_trace()
             if handler_installed:
                 # old_handler may be None (disposition installed outside
                 # Python) — restore to default in that case rather than

@@ -237,21 +237,22 @@ class ILQLTrainer(JaxBaseTrainer):
             hs_actions = jnp.take_along_axis(out["hidden"], batch.actions_ixs[..., None], axis=1)
             actions = action_tokens(batch.input_ids, batch.actions_ixs)
             head_names = ["q1_head"] + (["q2_head"] if m.two_qs else [])
-            Qs, cql_nlls = [], []
-            for name in head_names:
-                head = params[name]
-                lp, lse, _ = routed_logprob(
-                    mlp_hidden(head, hs_actions).astype(jnp.float32),
-                    head["layers_1"]["kernel"],
-                    actions,
-                    head["layers_1"]["bias"],
-                    tied=False,
-                    mode=fused_mode,
-                )
-                # gathered Q at the action = label logit = logprob + logsumexp
-                Qs.append(lp + lse)
-                cql_nlls.append(-lp)
-            targetQs = [gathered_head_logit(extras[name], hs_actions, actions) for name in head_names]
+            with jax.named_scope("lm_head"):  # the Q heads: vocabulary-wide, as the LM head is
+                Qs, cql_nlls = [], []
+                for name in head_names:
+                    head = params[name]
+                    lp, lse, _ = routed_logprob(
+                        mlp_hidden(head, hs_actions).astype(jnp.float32),
+                        head["layers_1"]["kernel"],
+                        actions,
+                        head["layers_1"]["bias"],
+                        tied=False,
+                        mode=fused_mode,
+                    )
+                    # gathered Q at the action = label logit = logprob + logsumexp
+                    Qs.append(lp + lse)
+                    cql_nlls.append(-lp)
+                targetQs = [gathered_head_logit(extras[name], hs_actions, actions) for name in head_names]
             return ilql_loss_terms(
                 Qs,
                 targetQs,
@@ -308,20 +309,21 @@ class ILQLTrainer(JaxBaseTrainer):
         def train_step(state, batch: ILQLBatch):
             (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params, state.extras, batch)
             stats = dict(stats)
-            if self.config.train.nonfinite_guard:
-                bad0 = state.bad_steps
-                if bad0 is None:
-                    bad0 = jnp.zeros((), dtype=jnp.int32)
-                params, opt_state, bad, finite = guarded_update(
-                    optimizer, grads, loss, state.params, state.opt_state, bad0
-                )
-                stats["resilience/nonfinite"] = 1.0 - finite.astype(jnp.float32)
-                stats["resilience/bad_steps"] = bad.astype(jnp.float32)
-            else:
-                updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-                params = optax.apply_updates(state.params, updates)
-                bad = state.bad_steps
-            stats["grad_norm"] = optax.global_norm(grads)
+            with jax.named_scope("optimizer"):
+                if self.config.train.nonfinite_guard:
+                    bad0 = state.bad_steps
+                    if bad0 is None:
+                        bad0 = jnp.zeros((), dtype=jnp.int32)
+                    params, opt_state, bad, finite = guarded_update(
+                        optimizer, grads, loss, state.params, state.opt_state, bad0
+                    )
+                    stats["resilience/nonfinite"] = 1.0 - finite.astype(jnp.float32)
+                    stats["resilience/bad_steps"] = bad.astype(jnp.float32)
+                else:
+                    updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
+                    params = optax.apply_updates(state.params, updates)
+                    bad = state.bad_steps
+                stats["grad_norm"] = optax.global_norm(grads)
             if self.config.train.watch_interval:
                 for group, sub in grads.items():
                     stats[f"watch/grad_norm/{group}"] = optax.global_norm(sub)

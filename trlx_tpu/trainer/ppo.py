@@ -1185,23 +1185,24 @@ def make_ppo_train_step(model, optimizer, config, prompt_length, schedule, detac
     def train_step(state, batch: PPORLBatch):
         (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(state.params, batch)
         stats = dict(stats)
-        if config.train.nonfinite_guard:
-            # Abstract states built before the bad_steps field existed
-            # (tests/test_scale_compile.py hand-constructs them) default it
-            # to None — materialize the counter in-trace.
-            bad0 = state.bad_steps
-            if bad0 is None:
-                bad0 = jnp.zeros((), dtype=jnp.int32)
-            params, opt_state, bad, finite = guarded_update(
-                optimizer, grads, loss, state.params, state.opt_state, bad0
-            )
-            stats["resilience/nonfinite"] = 1.0 - finite.astype(jnp.float32)
-            stats["resilience/bad_steps"] = bad.astype(jnp.float32)
-        else:
-            updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            bad = state.bad_steps
-        stats["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            if config.train.nonfinite_guard:
+                # Abstract states built before the bad_steps field existed
+                # (tests/test_scale_compile.py hand-constructs them) default it
+                # to None — materialize the counter in-trace.
+                bad0 = state.bad_steps
+                if bad0 is None:
+                    bad0 = jnp.zeros((), dtype=jnp.int32)
+                params, opt_state, bad, finite = guarded_update(
+                    optimizer, grads, loss, state.params, state.opt_state, bad0
+                )
+                stats["resilience/nonfinite"] = 1.0 - finite.astype(jnp.float32)
+                stats["resilience/bad_steps"] = bad.astype(jnp.float32)
+            else:
+                updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
+                bad = state.bad_steps
+            stats["grad_norm"] = optax.global_norm(grads)
         if config.train.watch_interval:
             # per-group grad norms for the wandb.watch-equivalent; device
             # scalars, fetched only at log boundaries with the rest
