@@ -147,7 +147,22 @@ def test_topk_mask():
 
 import pytest
 
-from trlx_tpu.ops.fused_logprob import fused_logprob, naive_logprob, routed_logprob
+from trlx_tpu.ops.fused_logprob import (
+    ROW_TILE_FLOOR,
+    ROW_TILES,
+    HeadTiles,
+    fused_logprob,
+    head_tiles,
+    naive_logprob,
+    routed_logprob,
+)
+
+
+def uniform_tiles(bn, bv, V):
+    """One row tile and one vocabulary tile for all three kernels, the
+    backward's narrowed to 256 as `head_tiles` narrows it."""
+    bwd = (bn, min(bv, 256) if V > 256 else bv)
+    return HeadTiles((bn, bv), bwd, bwd)
 
 
 def _head_case(rng, B, T, D, V, dtype, tied, bias):
@@ -169,7 +184,7 @@ def test_fused_logprob_matches_naive(tied, bias, dtype):
     rng = np.random.default_rng(0)
     x, w, b, y = _head_case(rng, 2, 19, 64, 300, dtype, tied, bias)
     lp_k, lse_k, ent_k = fused_logprob(
-        x, w, y, b, tied=tied, interpret=True, block_v=128
+        x, w, y, b, tied=tied, interpret=True, tiles=uniform_tiles(128, 128, 300)
     )
     lp_n, lse_n, ent_n = naive_logprob(x, w, y, b, tied=tied)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
@@ -193,7 +208,7 @@ def test_fused_logprob_grads_match_naive(tied, bias):
         return f
 
     fused = lambda x_, w_, y_, b_, tied: fused_logprob(
-        x_, w_, y_, b_, tied=tied, interpret=True, block_v=128
+        x_, w_, y_, b_, tied=tied, interpret=True, tiles=uniform_tiles(128, 128, 300)
     )
     args = (x, w, b)
     argnums = (0, 1, 2) if bias else (0, 1)
@@ -201,6 +216,118 @@ def test_fused_logprob_grads_match_naive(tied, bias):
     g_n = jax.grad(scalar(naive_logprob), argnums=argnums)(*args)
     for a, bb in zip(g_k, g_n):
         np.testing.assert_allclose(np.asarray(a), np.asarray(bb), rtol=1e-4, atol=1e-5)
+
+
+# Every row tile the rule can return, and one call whose three kernels differ.
+_TILE_CASES = {
+    **{f"rows{bn}": uniform_tiles(bn, 128, 300) for bn in ROW_TILES},
+    "mixed": HeadTiles(fwd=(512, 256), dx=(256, 128), dw=(128, 256)),
+}
+_OPERAND_CASES = {  # tied, bias, rows' dtype, weight's dtype
+    "untied": (False, False, jnp.float32, jnp.float32),
+    "tied": (True, False, jnp.float32, jnp.float32),
+    "biased": (False, True, jnp.float32, jnp.float32),
+    "f32_rows_bf16_weight": (False, True, jnp.float32, jnp.bfloat16),  # ILQL's Q heads
+}
+
+
+@pytest.mark.parametrize("operands", sorted(_OPERAND_CASES))
+@pytest.mark.parametrize("tiles", sorted(_TILE_CASES))
+def test_fused_logprob_parity_at_every_row_tile(tiles, operands):
+    """Forward and all three gradients against `naive_logprob` at each tile
+    `head_tiles` can choose: 2 x 333 = 666 rows pad to 768 (128, 256) or
+    1,024 (512), V = 300 leaves a partial vocabulary tail tile."""
+    tied, bias, x_dtype, w_dtype = _OPERAND_CASES[operands]
+    rng = np.random.default_rng(5)
+    x, w, b, y = _head_case(rng, 2, 333, 64, 300, x_dtype, tied, bias)
+    w = w.astype(w_dtype)
+
+    def scalar(fn):
+        def f(x, w, b):
+            lp, lse, ent = fn(x, w, y, b, tied=tied)
+            return jnp.sum(lp) + 0.5 * jnp.sum(lse) - 0.25 * jnp.sum(ent), (lp, lse, ent)
+
+        return f
+
+    fused = lambda *a, **k: fused_logprob(*a, interpret=True, tiles=_TILE_CASES[tiles], **k)
+    argnums = (0, 1, 2) if bias else (0, 1)
+    (_, out_k), g_k = jax.value_and_grad(scalar(fused), argnums=argnums, has_aux=True)(x, w, b)
+    (_, out_n), g_n = jax.value_and_grad(scalar(naive_logprob), argnums=argnums, has_aux=True)(x, w, b)
+    for a, bb in zip(out_k, out_n):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(bb), rtol=1e-5, atol=1e-5)
+    for a, bb in zip(g_k, g_n):
+        assert a.dtype == bb.dtype and a.shape == bb.shape
+        tol = 2e-2 if a.dtype == jnp.bfloat16 else 1e-4
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(bb, np.float32), rtol=tol, atol=tol * 0.1)
+
+
+# (N, D, V, rows' itemsize, weight's itemsize, bias) of the claimed cells' head calls
+_CLAIMED_HEAD_CALLS = {
+    "gptj6b-l8.ppo-128x896 train": (8 * 896, 4096, 50400, 2, 2, True),
+    "gptj6b-l8.ppo-128x896 scoring": (32 * 896, 4096, 50400, 2, 2, True),
+    "gptneo1.3b.ilql-256 Q head": (8 * 255, 4096, 50257, 4, 2, True),
+    "gptneo1.3b.ilql-256 LM head": (8 * 255, 2048, 50257, 2, 2, False),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_CLAIMED_HEAD_CALLS))
+def test_head_tiles_takes_512_rows_at_the_claimed_cells(call):
+    N, D, V, xi, wi, bias = _CLAIMED_HEAD_CALLS[call]
+    tiles = head_tiles(N, D, V, xi, wi, bias)
+    assert tiles.fwd[0] == 512
+    assert tiles.weight_passes(N) == -(-N // 512)
+    assert all(bn in ROW_TILES and bv % 128 == 0 for bn, bv in tiles)
+
+
+@pytest.mark.parametrize("N,rows", [(1, 128), (38, 128), (255, 128), (256, 256), (500, 256), (512, 512),
+                                    (513, 128), (577, 128), (897, 512), (1025, 128), (1200, 256), (7168, 512)])
+def test_head_tiles_rule_by_row_count(N, rows):
+    """Fewer rows than a tile keep the floor; a tile is refused where padding
+    up to it would waste an eighth of the rows or more (513 rows at 512: half)."""
+    tiles = head_tiles(N, 4096, 50400)
+    assert tiles.fwd[0] == rows
+    padded = -(-N // tiles.rows) * tiles.rows
+    assert rows == ROW_TILE_FLOOR or (padded - N) * 8 < padded
+
+
+def test_head_tiles_shrink_to_the_budget():
+    """A budget too small for 512 rows gives the next tile; one too small for
+    any keeps the floor with the widest vocabulary tile (the tiles every
+    call had before the rule), whatever they need."""
+    from trlx_tpu.ops.tiling import fused_logprob_vmem_bytes as need
+
+    args = (7168, 4096, 50400, 2, 2, True)
+    full = head_tiles(*args)
+    assert need("fwd", 4096, *full.fwd, 2, 2, True) <= 64 * 2**20
+    tight = head_tiles(*args, budget=need("fwd", 4096, 512, 256, 2, 2, True) - 1)
+    assert tight.fwd[0] == 256
+    assert head_tiles(*args, budget=1) == HeadTiles((128, 512), (128, 256), (128, 256))
+
+
+def test_routed_logprob_hands_a_vocabulary_major_weight_over_transposed(monkeypatch):
+    """Where the device holds an untied [D, V] weight as the rows of [V, D]
+    (`held_vocab_major`), the kernels get its transpose as a tied weight:
+    same results, and the gradient comes back in the weight's own shape."""
+    from trlx_tpu.ops import fused_logprob as fl
+
+    rng = np.random.default_rng(6)
+    x, w, b, y = _head_case(rng, 2, 70, 64, 300, jnp.float32, False, True)
+    seen = []
+    real = fl.fused_logprob
+    monkeypatch.setattr(fl, "fused_logprob", lambda x, w, *a, **k: seen.append((w.shape, k["tied"])) or real(x, w, *a, **k))
+
+    def loss(x, w, b):
+        lp, lse, ent = routed_logprob(x, w, y, b, tied=False, mode="force")
+        return jnp.sum(lp) + 0.5 * jnp.sum(lse) - 0.25 * jnp.sum(ent)
+
+    assert not fl.held_vocab_major(w)  # the CPU client holds arrays row-major
+    plain = jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w, b)
+    monkeypatch.setattr(fl, "held_vocab_major", lambda w: True)
+    held = jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w, b)
+    assert seen == [((64, 300), False), ((300, 64), True)]
+    assert held[1][1].shape == w.shape
+    for a, bb in zip(jax.tree_util.tree_leaves(held), jax.tree_util.tree_leaves(plain)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(bb), rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("mode", ["force", "off"])

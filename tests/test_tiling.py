@@ -163,3 +163,43 @@ def test_fused_logprob_eligibility_is_static():
     # sub-block vocabs and unaligned d_model never qualify
     assert not fused_logprob_eligible(HEAD_D, BLOCK_V - 1)
     assert not fused_logprob_eligible(HEAD_D + 1, HEAD_V)
+
+
+# The seven one-chip cells' head calls (benchmark/configs, PERF.md §4):
+# (rows of a train step, rows of the scoring chunk, D, V, rows' itemsize, tied, bias).
+CELL_HEADS = {
+    "gptj6b-l8.ppo-768x256": (8 * 256, 32 * 256, 4096, 50400, 2, False, True),
+    "gptj6b-l8.ppo-128x896": (8 * 896, 32 * 896, 4096, 50400, 2, False, True),
+    "gptneo1.3b.ppo-256x256": (16 * 256, 64 * 256, 2048, 50257, 2, True, False),
+    "gptneo1.3b.ilql-256 LM head": (8 * 255, None, 2048, 50257, 2, True, False),
+    "gptneo1.3b.ilql-256 Q heads": (8 * 255, None, 4096, 50257, 4, False, True),
+    "kimik2.5-l5.ppo-128x896": (4 * 896, 32 * 896, 7168, 20480, 2, False, False),
+    "kexaone-l5.ppo-128x896": (4 * 896, 32 * 896, 6144, 19200, 2, False, False),
+    "granite4hmicro.ppo-128x896": (8 * 896, 32 * 896, 2048, 100352, 2, True, False),
+}
+_CELL_CALLS = [(cell, which) for cell, head in CELL_HEADS.items() for which in ("train", "scoring") if head[which == "scoring"]]
+
+
+@pytest.mark.parametrize("cell,which", _CELL_CALLS, ids=[f"{c}-{w}" for c, w in _CELL_CALLS])
+def test_fused_logprob_tiles_legal_and_inside_their_vmem_limit_at_every_cell(cell, which):
+    """At each cell's real head shape the rule's tiles are tile-legal in all
+    three kernels, in both orientations an untied weight can be handed over
+    in (as stored, or transposed where the chip holds it vocabulary-major),
+    above the 128-row floor, inside the rule's budget, and each kernel's
+    scoped-VMEM request covers its estimate and stays under a v5e's 128 MiB."""
+    from trlx_tpu.ops import fused_logprob as fl
+    from trlx_tpu.ops.tiling import fused_logprob_vmem_bytes
+
+    train, scoring, D, V, x_itemsize, tied, bias = CELL_HEADS[cell]
+    N = scoring if which == "scoring" else train
+    tiles = fl.head_tiles(N, D, V, x_itemsize, 2, bias)
+    Np = tiles.padded(N)
+    assert Np - N < 128 and tiles.weight_passes(N) == Np // tiles.fwd[0]
+    for kind, (bn, bv) in zip(("fwd", "dx", "dw"), tiles):
+        for orientation in {tied, True}:
+            check_layout(fused_logprob_block_layout(Np, D, V, bn, bv, orientation, bias))
+        assert bn > fl.ROW_TILE_FLOOR and Np % bn == 0
+        need = fused_logprob_vmem_bytes(kind, D, bn, bv, x_itemsize, 2, bias)
+        limit = fl._compiler_params(False, need)["compiler_params"].vmem_limit_bytes
+        assert need <= fl.VMEM_BUDGET and need <= limit <= 100 * 2**20
+    assert tiles.fwd[0] == 512  # every cell's forward streams its weight once per 512 rows

@@ -102,6 +102,19 @@ def _fused_tied(x, w, y):
     return jax.value_and_grad(loss, argnums=(0, 1))(x, w)
 
 
+def _fused_held(x, w, y, b):
+    """An untied head as `routed_logprob` hands it over where the chip holds
+    `[D, V]` vocabulary-major (V not a multiple of 128): the transpose, to
+    the tied kernels, with the bias. ILQL's Q heads: float32 rows."""
+    loss = lambda x, w, b: sum(o.sum() for o in fused_logprob(x, w, y, b, tied=True, interpret=False))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w, b)
+
+
+def _fused_scoring(x, w, y, b):
+    """The scoring program's call: the forward kernel alone over the rollout chunk."""
+    return fused_logprob(x, w, y, b, tied=True, interpret=False)[0]
+
+
 def _cases(s):
     """(name, fn, abstract args) with `s(shape, dtype)` building each arg."""
     bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -119,6 +132,16 @@ def _cases(s):
         ("fused_logprob fwd+bwd", _fused, head),
         ("fused_logprob tied fwd+bwd V100352 d2048", _fused_tied,
          (s((8 * 896, 2048), bf16), s((100352, 2048), bf16), s((8 * 896,), i32))),
+        # the tiles `head_tiles` gives the cells' calls (512 rows; 256 in dx at the widest models)
+        ("fused_logprob fwd+bwd 7168 rows", _fused, (s((8 * 896, DM), bf16),) + head[1:2] + (s((8 * 896,), i32), head[3])),
+        ("fused_logprob held [V, D] fwd+bwd 7168 rows", _fused_held,
+         (s((8 * 896, DM), bf16), s((V, DM), bf16), s((8 * 896,), i32), s((V,), bf16))),
+        ("fused_logprob held [V, D] fwd+bwd float32 rows (ILQL Q head)", _fused_held,
+         (s((8 * 255, DM), f32), s((50257, DM), bf16), s((8 * 255,), i32), s((50257,), bf16))),
+        ("fused_logprob tied fwd+bwd d7168 V20480", _fused_tied,
+         (s((4 * 896, 7168), bf16), s((20480, 7168), bf16), s((4 * 896,), i32))),
+        ("fused_logprob scoring fwd 28672 rows", _fused_scoring,
+         (s((32 * 896, DM), bf16), s((V, DM), bf16), s((32 * 896,), i32), s((V,), bf16))),
     ]
 
 
@@ -155,6 +178,22 @@ def test_kernel_compiles_for_v5e(name, v5e_sharding):
     s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
     (fn, args), = [(f, a) for n, f, a in _cases(s) if n == name]
     jax.jit(fn).lower(*args).compile()
+
+
+def test_a_v5e_holds_an_untied_head_vocabulary_major_where_the_vocabulary_is_ragged(v5e_sharding, monkeypatch):
+    """`held_vocab_major` asks the client for the default layout of the
+    weight's shape: a TPU puts the axis that pads less to its (8, 128) tile
+    minor, so GPT-J's and GPT-Neo's [4096, V] heads (V not a multiple of 128)
+    are held as the rows of [V, 4096] and the kernels take the transpose."""
+    from trlx_tpu.ops import fused_logprob as fl
+
+    device = next(iter(v5e_sharding.device_set))
+    monkeypatch.setattr(fl, "_layout_device", lambda: device)
+    w = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert fl.held_vocab_major(w(4096, 50400)) and fl.held_vocab_major(w(4096, 50257))
+    assert not fl.held_vocab_major(w(4096, 50304))  # a multiple of 128: row-major
+    assert not fl.held_vocab_major(w(7168, 20480)) and not fl.held_vocab_major(w(6144, 19200))
+    assert not fl.held_vocab_major(w(50257, 2048)) and not fl.held_vocab_major(w(100352, 2048))  # the tied tables
 
 
 def test_both_forms_of_the_state_space_mixer_compile_for_v5e(v5e_sharding):

@@ -30,13 +30,40 @@ the N axis innermost), so the backward never materializes [N, V] either.
 Grid (N-blocks, V-blocks) with the V walk sequential ("arbitrary" — it is
 the online-softmax accumulation order); the weight streams in bv-wide tiles
 (128-divisible, so ragged GPT-2/J vocab sizes get a partial tail block that
-is masked in-kernel). Block layouts
+is masked in-kernel).
+
+**The tiles follow the call** (`head_tiles`). A grid step holds one operand
+resident and streams the other past it, so the resident tile sets how many
+FLOPs each streamed byte feeds, and the chip wants 240 of them a byte
+(197e12 / 819e9 on a v5e) before its MXU stops waiting for HBM:
+
+    forward  x[bn, D] resident, W streamed once per row block: 2·bn / itemsize(W)
+    dx       the same walk, two products a tile:               4·bn / itemsize(W)
+    dw       W[D, bv] resident, x streamed once per vocab tile: 4·bv / itemsize(x)
+
+At 128 rows a bf16 weight byte fed 128 FLOPs: the forward over 7,168 rows
+streamed GPT-J's 413 MB head 56 times (23 GB, 28 ms) for 15 ms of products,
+and took 31 ms. The rule takes the largest row tile of 512 / 256 / 128 that
+(a) keeps every block of the kernel, double-buffered, with its scratch and
+the score tile's temporaries inside `VMEM_BUDGET`, shrinking the vocabulary
+tile to make room (weight traffic depends on the row tile alone: x's block
+stays put through the vocabulary walk), (b) wastes under an eighth of the
+rows in padding, and (c) is no larger than the call: fewer rows than a tile
+keep the 128-row floor. It reads N, D, V, the operands' itemsizes and
+`has_bias`, nothing else: no option, no environment variable. Measured on a
+v5e at the benchmark's seven head shapes (PERF.md §6, PR 36): the forward
+31 → 18 ms at 7,168 x 4096 x 50,400, within a fifth of its products' time;
+256 rows get nearly all of it, 1,024 no more (and lose at D 7168); a
+256-wide vocabulary tile costs the forward 14%, so the forward narrows it
+last; dx and dw were near their products' time already and gain 4 to 18%.
+The scoped-VMEM limit each kernel asks the compiler for is its own blocks'
+estimate (`tiling.fused_logprob_vmem_bytes`) with headroom. Block layouts
 live in tiling.fused_logprob_block_layout — the validator and this wrapper
 read the SAME description, and the routing gate (fused_logprob_supported)
-re-checks it before the model layer ever traces the kernel: a tile-illegal
-shape takes the materialized log_softmax path by that stated rule, and a
-shape that passes must lower on a TPU backend or the run stops with an
-error naming the kernel and the shape.
+re-checks it at the SAME tiles before the model layer ever traces the
+kernel: a tile-illegal shape takes the materialized log_softmax path by
+that stated rule, and a shape that passes must lower on a TPU backend or
+the run stops with an error naming the kernel and the shape.
 
 Engagement mirrors flash attention: real TPU backend (or explicit
 interpret mode for CPU CI parity tests, tests/test_losses.py); tiny test
@@ -44,6 +71,9 @@ models stay on the einsum fallback where they are faster.
 """
 
 import functools
+import threading
+from contextlib import contextmanager
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,14 +90,23 @@ from trlx_tpu.ops.flash_attention import (
 )
 from trlx_tpu.ops.flash_attention import _compiler_params as _grid_compiler_params
 from trlx_tpu.ops.flash_attention import _vmem_spec as _vmem
+from trlx_tpu.ops.tiling import fused_logprob_vmem_bytes
 
-# Forward vocab tile: 512 columns/tile keeps the [D, bv] weight block at
-# 4 MB (bf16, D=4096) — comfortable VMEM with double buffering. The
-# backward kernels re-stream the weight AND carry a [D, bv] fp32 dW (or
-# [bn, D] dx) accumulator, so they halve the tile.
-BLOCK_N = 128
+# The tiles a call may take. Rows: the largest of ROW_TILES the rule admits,
+# ROW_TILE_FLOOR when none is (every call took the floor before the rule).
+# Vocabulary: the widest of a kernel's candidates that fits beside the row
+# tile; the backward kernels carry a [D, bv] fp32 dW (or [bn, D] dx)
+# accumulator beside the streamed weight, so they start narrower.
+ROW_TILES = (512, 256, 128)
+ROW_TILE_FLOOR = ROW_TILES[-1]
 BLOCK_V = 512
 BLOCK_V_BWD = 256
+V_TILES = {"fwd": (BLOCK_V, BLOCK_V_BWD), "dx": (BLOCK_V_BWD,), "dw": (BLOCK_V_BWD,)}
+
+# What the rule lets one kernel's blocks, scratch and temporaries take of a
+# v5e's 128 MiB of VMEM, and what the compiler grants a kernel unasked.
+VMEM_BUDGET = 64 * 1024 * 1024
+DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
 
 
 def pick_v_block(V: int, block_v: int = BLOCK_V) -> int:
@@ -77,21 +116,95 @@ def pick_v_block(V: int, block_v: int = BLOCK_V) -> int:
     return V if V <= block_v else block_v
 
 
-# The model axis the tile widths above were set for: every block carries the
-# whole [D], and at D = 4096 the three kernels fit the 16 MiB of scoped VMEM
-# the compiler allows by default. A wider model keeps the tiles and asks for
-# the limit in proportion (D = 7168: the forward's double-buffered [D, 512]
-# weight block alone is 14.7 MB, the compiler refused the kernel at 18.25 MB;
-# a v5e has 128 MiB of VMEM).
-BLOCK_D = 4096
-DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+class HeadTiles(NamedTuple):
+    """(row tile, vocabulary tile) of each of the three kernels."""
+
+    fwd: Tuple[int, int]
+    dx: Tuple[int, int]
+    dw: Tuple[int, int]
+
+    @property
+    def rows(self) -> int:
+        """What the call's rows are padded to a multiple of (the tiles are
+        128 times a power of two, so the largest is every one's multiple)."""
+        return max(self.fwd[0], self.dx[0], self.dw[0])
+
+    def padded(self, N: int) -> int:
+        """`N` rows padded up to a multiple of every kernel's row tile."""
+        return -(-N // self.rows) * self.rows
+
+    def weight_passes(self, N: int) -> int:
+        """How many times the forward streams the whole weight from HBM."""
+        return self.padded(N) // self.fwd[0]
 
 
-def _compiler_params(interpret, D=BLOCK_D):
+def head_tiles(N: int, D: int, V: int, x_itemsize: int = 2, w_itemsize: int = 2,
+               has_bias: bool = False, budget: int = VMEM_BUDGET) -> HeadTiles:
+    """The rule (module docstring): per kernel the largest row tile that is
+    no larger than the call, pads it by under an eighth, and fits `budget`
+    with some vocabulary tile of the kernel's candidates, widest first. A
+    call no tile above the floor admits keeps the floor and the widest
+    vocabulary tile, whatever they need: what every call ran before."""
+
+    def pick(kind):
+        for bn in ROW_TILES[:-1]:
+            padded = -(-N // bn) * bn
+            if bn > N or (padded - N) * 8 >= padded:
+                continue
+            for bv in (pick_v_block(V, v) for v in V_TILES[kind]):
+                if fused_logprob_vmem_bytes(kind, D, bn, bv, x_itemsize, w_itemsize, has_bias) <= budget:
+                    return bn, bv
+        return ROW_TILE_FLOOR, pick_v_block(V, V_TILES[kind][0])
+
+    return HeadTiles(pick("fwd"), pick("dx"), pick("dw"))
+
+
+def _compiler_params(interpret, vmem_bytes):
     """N-blocks are independent; the V walk is the online accumulation order
-    and must stay sequential."""
-    limit = None if D <= BLOCK_D else DEFAULT_SCOPED_VMEM * -(-D // BLOCK_D)
+    and must stay sequential. The scoped-VMEM limit is the kernel's own
+    estimate (tiling.fused_logprob_vmem_bytes) and a quarter of headroom,
+    never under what the compiler grants unasked."""
+    limit = max(DEFAULT_SCOPED_VMEM, -(-vmem_bytes * 5 // 4 // 2**20) * 2**20)
     return _grid_compiler_params(interpret, semantics=("parallel", "arbitrary"), vmem_limit_bytes=limit)
+
+
+# What the traced programs' head calls chose, for the `head/*` counters: the
+# dict a caller arms is filled while its program is traced and left alone by
+# a dispatch that traces nothing, as parallel/schedule.count_weight_gathers.
+_armed = threading.local()
+
+
+@contextmanager
+def count_head_calls(tally: dict):
+    """While a program is traced inside, every `fused_logprob` call records
+    into `tally[site]` its padded rows, the forward's tiles and
+    `weight_passes`, the times the forward streams the whole weight."""
+    prior = getattr(_armed, "tally", None)
+    _armed.tally = tally
+    try:
+        yield tally
+    finally:
+        _armed.tally = prior
+
+
+def _note_head_call(site, N, tiles):
+    tally = getattr(_armed, "tally", None)
+    if tally is not None:
+        tally[site] = {
+            "padded_rows": tiles.padded(N),
+            "row_tile": tiles.fwd[0],
+            "vocab_tile": tiles.fwd[1],
+            "weight_passes": tiles.weight_passes(N),
+        }
+
+
+def take_head_call_scalars(tally: dict, program: str) -> dict:
+    """`head/<program>/<site>/{weight_passes,row_tile,vocab_tile,padded_rows}`
+    of the sites traced since the last take, emptying `tally`: a compiled
+    program shows in the tracker's scalars once."""
+    out = {f"head/{program}/{site}/{k}": float(v) for site, call in tally.items() for k, v in call.items()}
+    tally.clear()
+    return out
 
 
 def _tile_scores(x_ref, w_ref, b_ref, j, *, V, bv, tied):
@@ -309,9 +422,16 @@ def _operand_specs(N, D, V, bn, bv, tied, has_bias, grid_nv_outer=False):
     return x_spec, w_spec, b_spec, row_spec
 
 
-def _fwd_call(x, w, bias, labels, tied, bn, bv, interpret):
+def _vmem_bytes(kind, x, w, bias, bn, bv):
+    return fused_logprob_vmem_bytes(
+        kind, x.shape[1], bn, bv, x.dtype.itemsize, w.dtype.itemsize, bias is not None
+    )
+
+
+def _fwd_call(x, w, bias, labels, tied, tile, interpret):
     N, D = x.shape
     V = w.shape[0] if tied else w.shape[1]
+    bn, bv = tile
     grid = (N // bn, -(-V // bv))
     has_bias = bias is not None
     x_spec, w_spec, b_spec, row_spec = _operand_specs(N, D, V, bn, bv, tied, has_bias)
@@ -326,35 +446,38 @@ def _fwd_call(x, w, bias, labels, tied, bn, bv, interpret):
         out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.float32)] * 3,
         scratch_shapes=[_scratch((bn, 128)) for _ in range(4)],
         interpret=interpret,
-        **_compiler_params(interpret, D),
+        **_compiler_params(interpret, _vmem_bytes("fwd", x, w, bias, bn, bv)),
     )(*operands)
     return tuple(out)
 
 
-def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv, interpret):
+def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, tiles, interpret):
     N, D = x.shape
     V = w.shape[0] if tied else w.shape[1]
-    nv = -(-V // bv)
     has_bias = bias is not None
     row_operands = [labels, lse, ent, dlp, dlse, dent]
 
     # dx: N-blocks parallel, V innermost accumulating into a [bn, D] scratch.
+    bn, bv = tiles.dx
     x_spec, w_spec, b_spec, row_spec = _operand_specs(N, D, V, bn, bv, tied, has_bias)
     in_specs = [x_spec, w_spec] + ([b_spec] if has_bias else []) + [row_spec] * 6
     operands = [x, w] + ([bias] if has_bias else []) + row_operands
     dx = pl.pallas_call(
         functools.partial(_bwd_dx_kernel, V=V, bv=bv, tied=tied, has_bias=has_bias),
         name="logprob_head_bwd_dx",
-        grid=(N // bn, nv),
+        grid=(N // bn, -(-V // bv)),
         in_specs=in_specs,
         out_specs=_vmem((bn, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
         scratch_shapes=[_scratch((bn, D))],
         interpret=interpret,
-        **_compiler_params(interpret, D),
+        **_compiler_params(interpret, _vmem_bytes("dx", x, w, bias, bn, bv)),
     )(*operands)
 
     # dW (+db): V-blocks parallel, N innermost accumulating [D, bv] / [bv, D].
+    bn, bv = tiles.dw
+    grid = (-(-V // bv), N // bn)
+    params = _compiler_params(interpret, _vmem_bytes("dw", x, w, bias, bn, bv))
     x_spec, w_spec, b_spec, row_spec = _operand_specs(
         N, D, V, bn, bv, tied, has_bias, grid_nv_outer=True
     )
@@ -368,46 +491,46 @@ def _bwd_calls(x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv, inte
         out = pl.pallas_call(
             functools.partial(_bwd_dw_kernel, V=V, bv=bv, tied=tied, has_bias=True),
             name="logprob_head_bwd_dw",
-            grid=(nv, N // bn),
+            grid=grid,
             in_specs=in_specs,
             out_specs=[dw_spec, _vmem((1, bv), lambda j, i: (0, j))],
             out_shape=[dw_shape, jax.ShapeDtypeStruct(bias.shape, bias.dtype)],
             scratch_shapes=[_scratch(acc_shape), _scratch((1, bv))],
             interpret=interpret,
-            **_compiler_params(interpret, D),
+            **params,
         )(*operands)
         dw, db = out
     else:
         dw = pl.pallas_call(
             functools.partial(_bwd_dw_kernel, V=V, bv=bv, tied=tied, has_bias=False),
             name="logprob_head_bwd_dw",
-            grid=(nv, N // bn),
+            grid=grid,
             in_specs=in_specs,
             out_specs=dw_spec,
             out_shape=dw_shape,
             scratch_shapes=[_scratch(acc_shape)],
             interpret=interpret,
-            **_compiler_params(interpret, D),
+            **params,
         )(*operands)
         db = None
     return dx, dw, db
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _fused_core(x, w, bias, labels, tied, bn, bv_fwd, bv_bwd, interpret):
-    return _fwd_call(x, w, bias, labels, tied, bn, bv_fwd, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _fused_core(x, w, bias, labels, tied, tiles, interpret):
+    return _fwd_call(x, w, bias, labels, tied, tiles.fwd, interpret)
 
 
-def _fused_core_fwd(x, w, bias, labels, tied, bn, bv_fwd, bv_bwd, interpret):
-    lp, lse, ent = _fwd_call(x, w, bias, labels, tied, bn, bv_fwd, interpret)
+def _fused_core_fwd(x, w, bias, labels, tied, tiles, interpret):
+    lp, lse, ent = _fwd_call(x, w, bias, labels, tied, tiles.fwd, interpret)
     return (lp, lse, ent), (x, w, bias, labels, lse, ent)
 
 
-def _fused_core_bwd(tied, bn, bv_fwd, bv_bwd, interpret, res, g):
+def _fused_core_bwd(tied, tiles, interpret, res, g):
     x, w, bias, labels, lse, ent = res
     dlp, dlse, dent = g
     dx, dw, db = _bwd_calls(
-        x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, bn, bv_bwd, interpret
+        x, w, bias, labels, lse, ent, dlp, dlse, dent, tied, tiles, interpret
     )
     dlabels = np.zeros(labels.shape, dtype=jax.dtypes.float0)
     return dx, dw, db, dlabels
@@ -417,7 +540,7 @@ _fused_core.defvjp(_fused_core_fwd, _fused_core_bwd)
 
 
 def fused_logprob(x, w, labels, bias=None, *, tied=False, interpret=None,
-                  block_n=None, block_v=None):
+                  tiles=None, site="lm_head"):
     """Fused head projection + per-token (logprob, logsumexp, entropy).
 
     x: [..., D] hidden states (any leading shape). w: lm_head kernel [D, V]
@@ -425,17 +548,19 @@ def fused_logprob(x, w, labels, bias=None, *, tied=False, interpret=None,
     bias: optional [V]. Returns fp32 (logprob, lse, entropy), each shaped
     like labels; the [..., V] logits never exist outside one VMEM tile,
     forward or backward. Differentiable in x / w / bias via the custom VJP.
+    `tiles` (a HeadTiles) stands in for the rule's choice in tests and
+    probes; `site` names the call in the counters of `count_head_calls`.
     """
     interpret = _interpret_default() if interpret is None else interpret
     lead = x.shape[:-1]
     D = x.shape[-1]
     V = w.shape[0] if tied else w.shape[1]
     N = int(np.prod(lead)) if lead else 1
-    bn = BLOCK_N if block_n is None else block_n
-    bv = pick_v_block(V) if block_v is None else block_v
-    bv_bwd = min(bv, BLOCK_V_BWD) if V > BLOCK_V_BWD else bv
+    if tiles is None:
+        tiles = head_tiles(N, D, V, x.dtype.itemsize, w.dtype.itemsize, bias is not None)
+    _note_head_call(site, N, tiles)
 
-    Np = -(-N // bn) * bn
+    Np = tiles.padded(N)
     x2 = x.reshape(N, D)
     y2 = labels.reshape(N, 1).astype(jnp.int32)
     if Np != N:
@@ -446,7 +571,7 @@ def fused_logprob(x, w, labels, bias=None, *, tied=False, interpret=None,
         y2 = jnp.pad(y2, ((0, Np - N), (0, 0)))
     b2 = None if bias is None else bias.reshape(1, V)
 
-    lp, lse, ent = _fused_core(x2, w, b2, y2, tied, bn, bv, bv_bwd, interpret)
+    lp, lse, ent = _fused_core(x2, w, b2, y2, tied, tiles, interpret)
     return tuple(v[:N, 0].reshape(lead) for v in (lp, lse, ent))
 
 
@@ -492,26 +617,31 @@ _PROBE_CACHE = {}
 
 
 def fused_logprob_supported(N: int, D: int, V: int, tied: bool,
-                            has_bias: bool, dtype=jnp.bfloat16) -> bool:
+                            has_bias: bool, dtype=jnp.bfloat16, w_dtype=None) -> bool:
     """Cached verdict for a call-site shape whose static eligibility rule
     already passed (`_PROBE_CACHE`; devicemon's routing gauges read it). The
-    CPU-runnable tile check over the kernel's real block layouts may refuse
-    the shape: a stated rule, warned once, answered False, and the caller
-    takes log_softmax. A shape that passes must, on a TPU backend, lower
-    forward AND backward, or `require_lowering` raises naming it."""
+    CPU-runnable tile check over the kernels' real block layouts, at the
+    tiles `head_tiles` gives the call, may refuse the shape: a stated rule,
+    warned once, answered False, and the caller takes log_softmax. A shape
+    that passes must, on a TPU backend, lower forward AND backward, or
+    `require_lowering` raises naming it."""
     import warnings
 
     from trlx_tpu.ops import tiling
 
-    key = (N, D, V, bool(tied), bool(has_bias), jnp.dtype(dtype).name, jax.default_backend())
+    dtype, w_dtype = jnp.dtype(dtype), jnp.dtype(dtype if w_dtype is None else w_dtype)
+    key = (N, D, V, bool(tied), bool(has_bias), dtype.name, w_dtype.name, jax.default_backend())
     hit = _PROBE_CACHE.get(key)
     if hit is not None:
         return hit
     shape = f"[N={N}, D={D}, V={V}, tied={tied}, bias={has_bias}]"
-    Np = -(-N // BLOCK_N) * BLOCK_N
-    issues = tiling.layout_issues(
-        tiling.fused_logprob_block_layout(Np, D, V, BLOCK_N, pick_v_block(V), tied, has_bias)
-    )
+    tiles = head_tiles(N, D, V, dtype.itemsize, w_dtype.itemsize, has_bias)
+    Np = tiles.padded(N)
+    issues = [
+        issue
+        for bn, bv in dict.fromkeys(tiles)
+        for issue in tiling.layout_issues(tiling.fused_logprob_block_layout(Np, D, V, bn, bv, tied, has_bias))
+    ]
     if issues:
         warnings.warn(
             f"fused-logprob kernel refused for shape {shape} by the static tile "
@@ -520,7 +650,7 @@ def fused_logprob_supported(N: int, D: int, V: int, tied: bool,
         )
     elif jax.default_backend() == "tpu":
         s = jax.ShapeDtypeStruct
-        args = [s((N, D), dtype), s((V, D) if tied else (D, V), dtype),
+        args = [s((N, D), dtype), s((V, D) if tied else (D, V), w_dtype),
                 s((N,), jnp.int32)]
         if has_bias:
             args.append(s((V,), jnp.float32))
@@ -539,25 +669,52 @@ def fused_logprob_supported(N: int, D: int, V: int, tied: bool,
     return _PROBE_CACHE[key]
 
 
-def routed_logprob(x, w, labels, bias=None, *, tied=False, mode="auto", mask=None):
+def _layout_device():
+    return jax.devices()[0]
+
+
+def held_vocab_major(w) -> bool:
+    """Whether the device holds an untied head weight `[D, V]` with the
+    vocabulary as its major axis, i.e. as the rows of `[V, D]`: the layout a
+    TPU gives a two-dimensional array by default puts the axis that pads
+    less to its (8, 128) tile minor, so `[4096, 50257]` and `[4096, 50400]`
+    (V not a multiple of 128) are held as `[V, 4096]`, with their optimizer
+    moments. A Mosaic call takes its operands row-major, so the untied
+    kernels made the compiler copy such a weight whole before the forward
+    and copy the new weight and both moments back after the update: six
+    copies of 413 MB a GPT-J step, twelve an ILQL step. Asked of the client
+    at trace time, from the shape and dtype alone; row-major where the
+    client cannot say."""
+    device = _layout_device()
+    try:
+        layout = device.client.get_default_layout(jnp.dtype(w.dtype), tuple(w.shape), device)
+        return tuple(layout._xla_layout().minor_to_major()) == (0, 1)
+    except (AttributeError, TypeError, NotImplementedError, jax.errors.JaxRuntimeError):
+        return False
+
+
+def routed_logprob(x, w, labels, bias=None, *, tied=False, mode="auto", mask=None, site="lm_head"):
     """The model layer's entry point: kernel when forced or (eligible +
     probe-supported), else the materializing naive path. `mode` is
     LMConfig.extra['fused_logprob']: 'auto' (default), 'force' (kernel
     unconditionally — interpret mode off-TPU, for CPU parity tests), or
     'off' (always the naive path). `mask` zeros masked rows on both paths
     (the kernel computes them — they are uniform work on the grid — and
-    the fallback skips them in the softmax)."""
-    use_kernel = mode == "force"
-    if not use_kernel and mode != "off":
-        lead = x.shape[:-1]
-        N = int(np.prod(lead)) if lead else 1
-        D = x.shape[-1]
-        V = w.shape[0] if tied else w.shape[1]
-        use_kernel = fused_logprob_eligible(D, V) and fused_logprob_supported(
-            N, D, V, tied, bias is not None, x.dtype
-        )
+    the fallback skips them in the softmax). `site` names the call site in
+    the `head/*` counters (`count_head_calls`)."""
+    lead = x.shape[:-1]
+    N = int(np.prod(lead)) if lead else 1
+    D = x.shape[-1]
+    V = w.shape[0] if tied else w.shape[1]
+    use_kernel = mode == "force" or (mode != "off" and fused_logprob_eligible(D, V))
+    if use_kernel and not tied and held_vocab_major(w):
+        # the weight as it is held: its transpose is the same bytes, read by
+        # the tied kernels; dW comes back in the moments' layout
+        w, tied = w.T, True
+    if use_kernel and mode != "force":
+        use_kernel = fused_logprob_supported(N, D, V, tied, bias is not None, x.dtype, w.dtype)
     if use_kernel:
-        lp, lse, ent = fused_logprob(x, w, labels, bias, tied=tied)
+        lp, lse, ent = fused_logprob(x, w, labels, bias, tied=tied, site=site)
         if mask is not None:
             m = mask.astype(jnp.float32)
             lp, lse, ent = lp * m, lse * m, ent * m

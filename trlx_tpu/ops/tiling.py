@@ -166,3 +166,37 @@ def fused_logprob_block_layout(
     if has_bias:
         layouts.insert(2, BlockLayout("bias", (1, bv), (1, V)))
     return layouts
+
+
+def fused_logprob_vmem_bytes(
+    kind: str, D: int, bn: int, bv: int, x_itemsize: int, w_itemsize: int, has_bias: bool
+) -> int:
+    """VMEM one grid step of the fused log-prob kernel `kind` ("fwd", "dx",
+    "dw") holds at row tile `bn` and vocabulary tile `bv`: every pipelined
+    block twice (the pipeline's double buffer), the scratch accumulators
+    once, and the compiler's temporaries: the [bn, bv] float32 score tile and
+    its elementwise companions (iota, mask, p, p·s, the cotangent), the weight
+    tile cast to the rows' dtype where they differ, the product's float32
+    result. An [n, 1] column occupies [n, 128] lanes and a [1, bv] bias 8
+    sublanes. `head_tiles` picks tiles against this and the kernels ask the
+    compiler for it as their scoped-VMEM limit, so it errs high."""
+    column = bn * LANE * 4
+    x_block, w_block = bn * D * x_itemsize, D * bv * w_itemsize
+    bias = SUBLANE * bv * 4 if has_bias else 0
+    score = bn * bv * 4
+    cast = D * bv * x_itemsize if x_itemsize != w_itemsize else 0
+    if kind == "fwd":  # x, w, bias, labels in; three columns out; m, l, r, label score
+        piped = x_block + w_block + bias + 4 * column
+        scratch = 4 * column
+        temps = 6 * score + cast
+    elif kind == "dx":  # + five more columns in, the dx block out; the tail-masked weight, its iota
+        piped = 2 * x_block + w_block + bias + 6 * column
+        scratch = bn * D * 4
+        temps = 8 * score + cast + D * bv * (w_itemsize + 4) + bn * D * 4
+    elif kind == "dw":  # the dW tile (and db) out
+        piped = x_block + 2 * (w_block + bias) + 6 * column
+        scratch = D * bv * 4 + bias
+        temps = 8 * score + cast + D * bv * 4
+    else:
+        raise ValueError(f"unknown fused log-prob kernel {kind!r}")
+    return 2 * piped + scratch + temps

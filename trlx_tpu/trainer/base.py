@@ -45,6 +45,7 @@ from trlx_tpu.observability import graftscope as obs_graftscope
 from trlx_tpu.observability import numerics as obs_numerics
 from trlx_tpu.observability import spans as obs_spans
 from trlx_tpu.observability.spans import trace_span
+from trlx_tpu.ops.fused_logprob import count_head_calls, take_head_call_scalars
 from trlx_tpu.parallel import make_mesh, set_mesh, shard_pytree
 from trlx_tpu.parallel.mesh import DATA_AXES, barrier, init_distributed, is_main_process
 from trlx_tpu.parallel.schedule import count_weight_gathers, weight_gather_share
@@ -203,6 +204,11 @@ class JaxBaseTrainer(BaseRLTrainer):
         # generate program did with each kernel split over fsdp, filled while
         # they are traced (parallel/schedule.py); empty without such a mesh
         self._weight_gathers = {"train": {}, "generate": {}}
+        # `head/<program>/<site>/weight_passes` and the tiles beside it: what
+        # the fused log-prob head chose at each call site of the train step
+        # and the scoring program, filled while they are traced
+        # (ops/fused_logprob.py) and logged once a compiled program
+        self._head_calls = {"train": {}, "score": {}}
         # Parallel host-side batch refs for the graftnum nonfinite census:
         # populated ONLY when incident capture is armed (None placeholders
         # otherwise), so default runs keep zero extra references alive.
@@ -1241,7 +1247,9 @@ class JaxBaseTrainer(BaseRLTrainer):
                 n_layer = int(self.model.cfg.n_layer)
                 tap = f"block_{min(self.iter_count + 1, n_layer - 1)}"
                 obs_numerics.latch_injection(tap)
-            with trace_span("train/dispatch"), self._dispatch_lock, count_weight_gathers(self._weight_gathers["train"]):
+            with trace_span("train/dispatch"), self._dispatch_lock, count_weight_gathers(
+                self._weight_gathers["train"]
+            ), count_head_calls(self._head_calls["train"]):
                 prev_state = self.state
                 self.state, stats = self.train_step(self.state, step_batch)
             # Donation handoff: train_step donates the old state
@@ -1391,6 +1399,7 @@ class JaxBaseTrainer(BaseRLTrainer):
         gather_share = weight_gather_share(self._weight_gathers["train"])
         if gather_share is not None:
             stats_host["parallel/weight_gather_share"] = gather_share
+        stats_host.update(take_head_call_scalars(self._head_calls["train"], "train"))
         if self._anomaly is not None and self._anomaly.observe(
             stats_host["step_time"]
         ):

@@ -248,6 +248,7 @@ class ILQLTrainer(JaxBaseTrainer):
                         head["layers_1"]["bias"],
                         tied=False,
                         mode=fused_mode,
+                        site=name,
                     )
                     # gathered Q at the action = label logit = logprob + logsumexp
                     Qs.append(lp + lse)

@@ -19,7 +19,7 @@ from trlx_tpu.data import PackedPPOBatch, PPORLBatch
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.fleet import FleetDegradedExit, validate_fleet_config
 from trlx_tpu.models.heads import LMWithValueHead, extract_branch_params
-from trlx_tpu.ops.fused_logprob import fused_logprob_eligible
+from trlx_tpu.ops.fused_logprob import count_head_calls, fused_logprob_eligible, take_head_call_scalars
 from trlx_tpu.ops.generate import make_generate_fn
 from trlx_tpu.ops.modeling import logprobs_from_logits
 from trlx_tpu.ops.rl_losses import kl_penalty_rewards, ppo_loss
@@ -666,7 +666,7 @@ class PPOTrainer(JaxBaseTrainer):
         stats, prefill_extras = gen_aux
         extras = self.state.extras if snapshot is None else snapshot["extras"]
         scores = self.put_batch(np.asarray(scores, dtype=np.float32))
-        with self._dispatch_lock:
+        with self._dispatch_lock, count_head_calls(self._head_calls["score"]):
             return self._score_fused_fn_for(self._batch_prompt_length(tokens))(
                 extras,
                 tokens,
@@ -733,7 +733,7 @@ class PPOTrainer(JaxBaseTrainer):
         params = self.state.params if snapshot is None else snapshot["params"]
         extras = self.state.extras if snapshot is None else snapshot["extras"]
         scores = self.put_batch(np.asarray(scores, dtype=np.float32))
-        with self._dispatch_lock:
+        with self._dispatch_lock, count_head_calls(self._head_calls["score"]):
             return self._score_fn_for(self._batch_prompt_length(tokens))(
                 params,
                 extras,
@@ -949,6 +949,7 @@ class PPOTrainer(JaxBaseTrainer):
             stats["ssm/chunks_per_pass"] = float(-(-int(self.config.train.seq_length) // lm_cfg.ssm_chunk))
         if self._last_exp_stats:
             stats.update(self._last_exp_stats)
+        stats.update(take_head_call_scalars(self._head_calls["score"], "score"))
         # Device telemetry flushes on the SAME cadence as the phase window —
         # its per-phase FLOP accumulators divide by exactly these seconds, so
         # obs/train_mfu_pct is the window's true utilization, not a smoothed
