@@ -141,6 +141,11 @@ class RolloutEngine:
                 "the rollout engine (and with it the paged pool and spec decode) is not built for a state-space "
                 "layer: a slot's state is not carried through admission, a block table has nothing to page and a "
                 "rejected draft needs a snapshot of the state to roll back to")
+        if model.cfg.n_loops > 1:
+            raise NotImplementedError(
+                "the rollout engine (and with it the paged pool and spec decode) is not built for a looped stack "
+                "(n_loops > 1): its slots, block tables and verify windows address one cache entry a layer, where a "
+                "looped stack keeps keys a (loop, layer) pair")
         self.model = model
         self.gcfg = gen_cfg
         self.processor = processor

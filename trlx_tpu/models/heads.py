@@ -110,6 +110,7 @@ class LMWithValueHead(nn.Module):
             "branch_hidden": out["branch_hidden"],
             "cache": out["cache"],
             "expert_counts": out["expert_counts"],
+            "exit_probs": out["exit_probs"],
             "logprobs": out["logprobs"],
             "lse": out["lse"],
             "entropy": out["entropy"],
@@ -118,7 +119,9 @@ class LMWithValueHead(nn.Module):
     def forward_branch(self, branch_hidden, attention_mask=None, position_ids=None, logits_start: int = 0,
                        labels=None, labels_mask=None, segment_ids=None):
         """Replay blocks [branch_layer..N) + ln_f + lm head from the
-        branch-point hidden states. Called via
+        branch-point hidden states (a looped stack: from the FIRST loop's
+        block branch_layer through every later loop whole, over
+        `branch_replay_params`). Called via
         ``model.apply({'params': ref_branch_params}, ..., method='forward_branch')``
         — the functional `forward_hydra`
         (reference: trlx/model/nn/ppo_models.py:351-368). With ``labels``
@@ -256,6 +259,22 @@ def extract_branch_params(params: dict, cfg: LMConfig, branch_layer: int) -> dic
     return jax.tree_util.tree_map(jnp.copy, {"transformer": branch})
 
 
+def branch_replay_params(params: dict, branch: dict, cfg: LMConfig, branch_layer: int) -> dict:
+    """The tree `forward_branch` replays over. One pass through the stack:
+    `branch` itself, the frozen copies. A looped stack shares its weights
+    between the loops, so the trained top blocks act in loop 1 and feed every
+    later loop: the hidden state entering block `branch_layer` in the LAST
+    loop already depends on trained weights, and a replay from there would
+    score a reference that drifts with the policy. The replay starts at (loop
+    1, block branch_layer) and runs everything after it, so it needs the
+    bottom blocks too, in loops 2..R: the live ones, which are frozen and so
+    identical to the initial ones (no second copy is kept)."""
+    if cfg.n_loops == 1:
+        return branch
+    live = params["transformer"]
+    return {"transformer": {**{f"h_{i}": live[f"h_{i}"] for i in range(branch_layer)}, **branch["transformer"]}}
+
+
 def trainable_mask(params: dict, cfg: LMConfig, num_layers_unfrozen: int) -> dict:
     """Boolean pytree: True where the param trains.
 
@@ -265,7 +284,9 @@ def trainable_mask(params: dict, cfg: LMConfig, num_layers_unfrozen: int) -> dic
     and ln_f stay trainable, exactly like the reference (which freezes only
     entries of `hidden_layers`). k <= 0 → everything trains. Blocks are
     found by name (`h_<i>`), whatever kind each is; an expert layer's router
-    correction bias is a buffer and never trains (models/moe.py).
+    correction bias is a buffer and never trains (models/moe.py). A looped
+    stack's exit gate never trains either: at exit_threshold 1 it decides
+    nothing and has no gradient, and AdamW's decay alone would shrink it.
     """
     from trlx_tpu.models.moe import BIAS_NAME
 
@@ -274,7 +295,7 @@ def trainable_mask(params: dict, cfg: LMConfig, num_layers_unfrozen: int) -> dic
 
     def mask(path, _leaf):
         keys = [str(getattr(k, "key", k)) for k in path]
-        if keys[-1] == BIAS_NAME:
+        if keys[-1] == BIAS_NAME or "exit_gate" in keys:
             return False
         if "transformer" in keys and any(fb in keys for fb in frozen_blocks):
             return False

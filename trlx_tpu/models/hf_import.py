@@ -19,7 +19,8 @@ SOURCE and the load is TPU-native streaming:
 Legacy pytorch_model.bin checkpoints fall back to the full torch load.
 Supported families match the reference's (reference: README.md:6): gpt2,
 gpt-j, gpt-neo, gpt-neox; and granitemoehybrid without experts (state-space
-and attention layers, models/ssm.py). With no checkpoint (or `model_arch` given) params
+and attention layers, models/ssm.py). `ouro` (the looped family) maps its
+config only: its weights are not imported (`load_hf_trunk` raises). With no checkpoint (or `model_arch` given) params
 initialize from scratch — the randomwalks path
 (reference: examples/randomwalks.py:99-101).
 """
@@ -177,10 +178,72 @@ def lm_config_from_hf(hf, **overrides) -> LMConfig:
             residual_multiplier=float(hf.residual_multiplier),
             logits_scaling=float(hf.logits_scaling),
         )
+    elif t == "ouro":
+        # The looped family (Ouro-1.4B / 2.6B): a Llama-shaped block (rotary
+        # over the whole head in the rotate-half layout, RMSNorm, gated SiLU,
+        # no bias, untied head) run `total_ut_steps` times a token. The
+        # sandwich norms, the final norm at the end of every loop and the exit
+        # gate are the family's description, not keys of its config.json. A key
+        # this branch does not know is an error, as in LMConfig.from_dict.
+        given = hf.to_dict() if hasattr(hf, "to_dict") else dict(vars(hf))
+        generic = set()
+        if hasattr(hf, "to_dict"):
+            from transformers import PretrainedConfig
+
+            generic = set(PretrainedConfig().to_dict())
+        unknown = sorted(set(given) - OURO_KEYS - generic)
+        if unknown:
+            raise ValueError(f"ouro: unknown config key(s) {unknown}")
+        head_dim = given.get("head_dim") or hf.hidden_size // hf.num_attention_heads
+        unbuilt = [name for name, on in (
+            ("layer_types other than full_attention", set(given.get("layer_types") or ["full_attention"]) != {"full_attention"}),
+            ("use_sliding_window / sliding_window", bool(given.get("use_sliding_window")) or given.get("sliding_window") is not None),
+            ("rope_scaling", given.get("rope_scaling") is not None),
+            ("hidden_act other than silu", hf.hidden_act != "silu"),
+            ("layer_types of another length than num_hidden_layers",
+             given.get("layer_types") is not None and len(given["layer_types"]) != hf.num_hidden_layers)) if on]
+        if unbuilt:
+            raise ValueError(f"ouro: not built: {'; '.join(unbuilt)}")
+        d = dict(
+            vocab_size=hf.vocab_size,
+            n_layer=hf.num_hidden_layers,
+            n_head=hf.num_attention_heads,
+            n_kv_head=0 if hf.num_key_value_heads == hf.num_attention_heads else hf.num_key_value_heads,
+            head_width=0 if head_dim * hf.num_attention_heads == hf.hidden_size else head_dim,
+            d_model=hf.hidden_size,
+            d_ff=hf.intermediate_size,
+            max_position=hf.max_position_embeddings,
+            pos_type="rotary",
+            rope_theta=float(hf.rope_theta),
+            norm="rmsnorm",
+            mlp="gated",
+            activation="silu",
+            ln_eps=hf.rms_norm_eps,
+            parallel_residual=False,
+            fused_qkv=False,
+            qkv_bias=False,
+            out_bias=False,
+            tie_word_embeddings=hf.tie_word_embeddings,
+            n_loops=int(hf.total_ut_steps),
+            sandwich_norm=True,
+            exit_gate=int(hf.total_ut_steps) > 1,
+            exit_threshold=float(given.get("early_exit_threshold", 1.0)),
+            extra={"neox_rotary": True},
+        )
     else:
         raise ValueError(f"unsupported HF model_type for conversion: {t}")
     d.update(overrides)
     return LMConfig.from_dict(d)
+
+
+# The keys of the looped family's published config.json (`model_type: ouro`).
+OURO_KEYS = frozenset({
+    "model_type", "head_dim", "hidden_act", "hidden_size", "intermediate_size", "layer_types", "max_position_embeddings",
+    "max_window_layers", "num_attention_heads", "num_hidden_layers", "num_key_value_heads", "rms_norm_eps", "rope_scaling",
+    "rope_theta", "sliding_window", "tie_word_embeddings", "total_ut_steps", "early_exit_threshold", "use_sliding_window",
+    "vocab_size", "attention_dropout", "initializer_range", "use_cache", "torch_dtype", "architectures", "auto_map",
+    "bos_token_id", "eos_token_id", "pad_token_id", "transformers_version",
+})
 
 
 def load_or_init_params(model, config, rng) -> Dict[str, Any]:
@@ -333,6 +396,11 @@ def load_hf_trunk(model_path: str, cfg: LMConfig, put=None) -> Dict[str, Any]:
     each converted tensor immediately — dtype cast + sharded device
     placement); falls back to a full torch load for legacy
     pytorch_model.bin checkpoints."""
+    if cfg.n_loops > 1:
+        raise NotImplementedError(
+            "importing a looped checkpoint's weights is not built: the names of the family's tensors (the two "
+            "sandwich norms a block, the exit gate) could not be read without the network, and a guessed mapping "
+            "would load another model; `model_arch` (weights from the seed) is the path that runs")
     try:
         sd: Any = LazySafetensors(model_path)
     except (FileNotFoundError, NotADirectoryError):

@@ -215,6 +215,29 @@ class LMConfig:
     attention_multiplier: float = 0.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # A looped stack: the n_layer blocks run n_loops times a token with the
+    # SAME parameters (the tree keeps h_0 .. h_{N-1} once), the final norm at
+    # the end of EVERY loop, its output the next loop's input; the cache keeps
+    # keys and values a (loop, layer) pair, entry loop * n_layer + layer
+    # (`cache_entries`), and loop r's layer reads what loop r's layer wrote.
+    # A pass with no cache runs ONE traced stack under a scan over the loops;
+    # through the cache the loops are unrolled (each entry has its own leaves).
+    # Built for the static generate path, scoring and the train step over
+    # "mha" blocks with dense feed-forwards; the engine, the paged pool, spec
+    # decode, the sp ring, decode_weight_quant and packed segments refuse it.
+    n_loops: int = 1
+    # An RMSNorm/LayerNorm on each residual branch's OUTPUT as well as on its
+    # input: x + norm(attn(norm(x))), x + norm(mlp(norm(x))) (sequential
+    # residual only).
+    sandwich_norm: bool = False
+    # The exit gate of a looped stack: lambda_r = sigmoid(w . z_r + b) on each
+    # loop's output z_r, and the exit distribution p_r = lambda_r prod_{j<r}
+    # (1 - lambda_j), the last loop taking what is left (`exit_probs` of a
+    # pass with no cache). At exit_threshold 1 every loop runs for every row
+    # and the gate decides nothing; a threshold under 1 (rows of one batch
+    # leaving the loop at different depths) is not built.
+    exit_gate: bool = False
+    exit_threshold: float = 1.0
     extra: Dict[str, Any] = field(default_factory=dict)
 
     def __post_init__(self):
@@ -284,6 +307,25 @@ class LMConfig:
                 ("expert layers", "experts" in self.ffn_layers), ("parallel_residual", self.parallel_residual)) if on]
             if unbuilt:
                 raise ValueError(f"a 'mamba' layer (mixer_layers) is not built with {', '.join(unbuilt)}")
+        if self.n_loops < 1:
+            raise ValueError(f"n_loops must be at least 1, got {self.n_loops}")
+        if self.n_loops > 1:
+            unbuilt = [name for name, on in (
+                ("attention 'mla'", self.attention == "mla"), ("expert layers", "experts" in self.ffn_layers),
+                ("a 'mamba' layer", self.has_ssm), ("window_cache 'ring'", self.window_cache == "ring"),
+                ("soft prompts", self.n_soft_tokens > 0), ("the sp ring (sp_size > 1)", self.sp_size > 1)) if on]
+            if unbuilt:
+                raise ValueError(f"a looped stack (n_loops > 1) is not built with {', '.join(unbuilt)}")
+        if self.sandwich_norm and self.parallel_residual:
+            raise ValueError("sandwich_norm is built for the sequential residual (parallel_residual false)")
+        if self.exit_gate and self.n_loops == 1:
+            raise ValueError("exit_gate needs a looped stack (n_loops > 1): with one loop there is nothing to leave")
+        if self.exit_threshold != 1.0:
+            if not self.exit_gate or not 0.0 < self.exit_threshold < 1.0:
+                raise ValueError(f"exit_threshold {self.exit_threshold} needs exit_gate and lies in (0, 1]")
+            raise NotImplementedError(
+                f"exit_threshold {self.exit_threshold} < 1 (adaptive depth: rows of one batch leave the loop at "
+                "different depths, so a step no longer costs every row the same) is not built; at 1 every loop runs")
         if self.logits_scaling != 1.0 and self.extra.get("lm_head_bias", False):
             raise ValueError("logits_scaling is not built with a head bias (extra.lm_head_bias)")
         if min(self.embedding_multiplier, self.residual_multiplier, self.logits_scaling) <= 0 or self.attention_multiplier < 0:
@@ -297,6 +339,11 @@ class LMConfig:
 
     def mixer(self, layer: int) -> str:
         return self.mixer_layers[layer] if self.mixer_layers else "attention"
+
+    @property
+    def cache_entries(self) -> int:
+        """Leaf groups of `init_cache`: one a (loop, layer) pair."""
+        return self.n_loops * self.n_layer
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -984,14 +1031,15 @@ class MLP(nn.Module):
         return dense(cfg.d_model, "c_proj", True)(act(dense(width, "c_fc", True)(x)))
 
 
-def make_norm(cfg: LMConfig, name: str):
+def make_norm(cfg: LMConfig, name: str, **kwargs):
     kind = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
-    return kind(epsilon=cfg.ln_eps, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name=name)
+    return kind(epsilon=cfg.ln_eps, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name=name, **kwargs)
 
 
 class Block(nn.Module):
     """One transformer block; sequential (gpt2) or parallel (gptj/neox)
-    residual. `ffn` is this layer's feed-forward kind ("dense" | "experts"),
+    residual; with `cfg.sandwich_norm` a second norm (`ln_1_out`, `ln_2_out`)
+    on each branch's output. `ffn` is this layer's feed-forward kind ("dense" | "experts"),
     `mixer` its mixer kind ("attention" | "mamba": models/ssm.py, whose cache
     is a state and which reads `token_mask` [b, q_len], the real tokens of
     `x`, in place of a bias). Returns (x, cache, expert_counts): the tokens
@@ -1039,9 +1087,11 @@ class Block(nn.Module):
             mlp_in = ln("ln_2")(x) if cfg.use_parallel_ln else h
             x = x + branch(attn_out) + branch(feed_forward(mlp_in))
         else:
+            # sandwich_norm: a norm on each branch's output as well as on its input
+            out_norm = (lambda name, y: ln(name)(y)) if cfg.sandwich_norm else (lambda name, y: y)
             attn_out, new_cache = mix(ln("ln_1")(x))
-            x = x + branch(attn_out)
-            x = x + branch(feed_forward(ln("ln_2")(x)))
+            x = x + branch(out_norm("ln_1_out", attn_out))
+            x = x + branch(out_norm("ln_2_out", feed_forward(ln("ln_2")(x))))
         return hold_rows(x), new_cache, counts
 
 
@@ -1148,6 +1198,8 @@ class TransformerLM(nn.Module):
           ``logits`` is None in this mode: not existing is the point.
         - ``expert_counts`` [expert layers run, experts held] int32: how many
           tokens each held expert took in this call; None without expert layers.
+        - ``exit_probs`` [b, q_len, n_loops] float32 (`exit_gate`, no cache,
+          start_layer 0): the exit distribution over the loops.
         - `segment_ids` [b, q_len] (packed train batches; full-sequence
           passes only) makes attention block-diagonal per packed segment —
           the einsum bias path is forced, since the flash/ring kernels'
@@ -1322,41 +1374,121 @@ class TransformerLM(nn.Module):
             # With state-space layers likewise, on one chip too: merged with
             # the forward, the recomputation keeps every layer's projections
             # and scan products alive, 34.7 GB for a 16 GB chip at 40 layers
-            # (PERF.md §6, PR 32).
+            # (PERF.md §6, PR 32). A looped stack likewise: 48 applications of
+            # 12 blocks kept every gate and up projection alive, 19.5 GB
+            # (PERF.md §6, PR 37).
             block_cls = nn.remat(
-                Block, prevent_cse=partitioned() or cfg.has_ssm, static_argnums=(7, 8), policy=policy
+                Block, prevent_cse=partitioned() or cfg.has_ssm or cfg.n_loops > 1, static_argnums=(7, 8), policy=policy
             )
+
+        looped = cfg.n_loops > 1
+        if looped and (segment_ids is not None or block_tables is not None or stop_layer != cfg.n_layer or (
+                cache is not None and (jnp.ndim(cache_index) != 0 or (q_len > 1 and not prefill_at_zero)))):
+            raise NotImplementedError(
+                "a looped stack (n_loops > 1) takes a pass with no cache, a prefill at write offset 0 or one token a "
+                "step for the whole batch (the static generate path): no block table, per-row offset, verify "
+                "window, packed segments or stop_layer")
 
         branch_hidden = None
         new_cache = [] if cache is not None else None
         expert_counts = []
-        for i in range(cfg.n_layer):
-            # All blocks are *defined* every call so the param structure is
-            # identical regardless of start/stop — only [start, stop) execute.
-            block = block_cls(cfg, cfg.ffn_layers[i] if cfg.ffn_layers else "dense", cfg.mixer(i), name=f"h_{i}")
-            if i < start_layer or i >= stop_layer:
-                continue
-            if collect_hidden_at is not None and i == collect_hidden_at:
-                branch_hidden = x
-            layer_cache = cache[i] if cache is not None else None
-            window = layer_window(cfg, i)
-            layer_bias = local_bias if window else attn_bias
-            # a state-space layer reads the tokens' mask itself, in place of a bias
-            token_mask = (attention_mask,) if cfg.mixer(i) == "mamba" else ()
-            x, layer_new_cache, layer_counts = block(
-                x, layer_bias, position_ids, layer_cache, cache_index,
-                flash_mask, window, use_ring, block_tables, *token_mask,
-            )
-            x = obs_numerics.probe_tap(f"block_{i}", x)
-            if cache is not None:
-                new_cache.append(layer_new_cache)
-            if layer_counts is not None:
-                expert_counts.append(layer_counts)
+        # All blocks are *defined* every call so the param structure is
+        # identical regardless of start/stop — only [start, stop) execute. A
+        # looped stack calls the same N modules n_loops times (the same
+        # parameters; a trained block's gradient is the sum over its uses).
+        def stack_modules(parent):
+            """(blocks, final norm, exit gate) as children of `parent`: this
+            module, or its clone inside the scan over the loops."""
+            blocks = [block_cls(cfg, cfg.ffn_layers[i] if cfg.ffn_layers else "dense", cfg.mixer(i), name=f"h_{i}",
+                                parent=parent) for i in range(cfg.n_layer)]
+            gate = nn.Dense(1, dtype=jnp.float32, param_dtype=cfg.params_dtype, name="exit_gate", parent=parent,
+                            kernel_init=drawn_in(cfg.draw_dtype, nn.initializers.normal(0.02))) if cfg.exit_gate else None
+            return blocks, make_norm(cfg, "ln_f", parent=parent), gate
 
-        x = make_norm(cfg, "ln_f")(x)
+        def one_pass(blocks, x, loop, first):
+            """Blocks [first, stop_layer) once; `loop` is an int where the pass
+            is unrolled (it names the cache entries and the taps), None in the scan."""
+            nonlocal branch_hidden
+            for i, block in enumerate(blocks):
+                if i < first or i >= stop_layer:
+                    continue
+                if collect_hidden_at is not None and i == collect_hidden_at and loop == 0:
+                    branch_hidden = x
+                layer_cache = cache[loop * cfg.n_layer + i] if cache is not None else None
+                window = layer_window(cfg, i)
+                layer_bias = local_bias if window else attn_bias
+                # a state-space layer reads the tokens' mask itself, in place of a bias
+                token_mask = (attention_mask,) if cfg.mixer(i) == "mamba" else ()
+                x, layer_new_cache, layer_counts = block(
+                    x, layer_bias, position_ids, layer_cache, cache_index,
+                    flash_mask, window, use_ring, block_tables, *token_mask,
+                )
+                if loop is not None:
+                    x = obs_numerics.probe_tap(f"block_{i}" if loop == 0 else f"loop_{loop}_block_{i}", x)
+                if cache is not None:
+                    new_cache.append(layer_new_cache)
+                if layer_counts is not None:
+                    expert_counts.append(layer_counts)
+            return x
+
+        def loop_end(ln_f, gate, x):
+            """The ONE final norm, at the end of every loop: its output is the
+            loop's output and the next loop's input; the gate reads it."""
+            with jax.named_scope("loop_norm"):
+                x = ln_f(x)
+            if not gated:
+                return x, None
+            with jax.named_scope("exit_gate"):
+                return x, gate(x)[..., 0]
+
+        blocks, ln_f, gate = stack_modules(self)
+        # the exit gate reads each loop's output but the last; a pass through
+        # the cache (a decode step) and the branch replay leave it out
+        gated = cfg.exit_gate and cache is None and start_layer == 0
+        # Loops unrolled in Python: all of them through the cache, where every
+        # (loop, layer) pair has leaves of its own (and while the parameters
+        # are made). A pass with no cache (train step, scoring, the replay)
+        # runs ONE traced stack under a scan over the loops, the parameters
+        # broadcast to every iteration; `start_layer` (the hydra replay) and
+        # `collect_hidden_at` cut into the FIRST loop only, which is then
+        # unrolled before the scan: every later loop depends on the replayed
+        # blocks and runs whole.
+        if not looped or cache is not None or self.is_initializing():
+            unrolled = cfg.n_loops
+        else:
+            unrolled = int(start_layer > 0 or collect_hidden_at is not None)
+        readings = []  # the gate's logit of each loop's output, [b, t, loops] a piece
+        for loop in range(unrolled):
+            x = one_pass(blocks, x, loop, start_layer if loop == 0 else 0)
+            if looped:
+                x, reading = loop_end(ln_f, gate, x)
+                readings.append(reading[..., None] if gated else None)
+        if unrolled < cfg.n_loops:
+
+            def body(clone, x, _):
+                blocks, ln_f, gate = stack_modules(clone)
+                return loop_end(ln_f, gate, one_pass(blocks, x, None, 0))
+
+            x, scanned = nn.scan(body, variable_broadcast="params", split_rngs={"params": False},
+                                 length=cfg.n_loops - unrolled)(self, x, None)
+            readings.append(jnp.moveaxis(scanned, 0, -1) if gated else None)
+        # [b, t, n_loops - 1]: the last loop's reading decides nothing
+        gate_logits = jnp.concatenate(readings, axis=-1)[..., :-1] if gated else None
+
+        if not looped:
+            x = ln_f(x)
         x = obs_numerics.probe_tap("ln_f", x)
         if collect_hidden_at is not None and collect_hidden_at == cfg.n_layer:
             branch_hidden = x
+
+        exit_probs = None
+        if gate_logits is not None:
+            # p_r = lambda_r prod_{j<r} (1 - lambda_j); the last loop takes what is left
+            with jax.named_scope("exit_gate"):
+                lam = jax.nn.sigmoid(gate_logits)  # [b, t, n_loops - 1]
+                stay = jnp.cumprod(1.0 - lam, axis=-1)
+                before = jnp.concatenate([jnp.ones_like(stay[..., :1]), stay[..., :-1]], axis=-1)
+                exit_probs = jnp.concatenate([lam * before, stay[..., -1:]], axis=-1)  # [b, t, n_loops]
 
         if n_soft:
             # Drop the soft-prefix positions: callers see the original length.
@@ -1435,6 +1567,9 @@ class TransformerLM(nn.Module):
             # [expert layers run, experts held]: tokens each held expert took
             # in this call (models/moe.py); None for a model without them.
             "expert_counts": jnp.stack(expert_counts) if expert_counts else None,
+            # [b, t, n_loops] float32: where the exit gate would leave the loop
+            # (a pass with no cache over a gated looped stack); None otherwise.
+            "exit_probs": exit_probs,
             "logprobs": logprobs,
             "lse": lse,
             "entropy": entropy,
@@ -1471,7 +1606,9 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
     under window_cache "ring"; "mla": per-layer (c_kv [b, T, kv_lora_rank],
     k_rope [b, T, qk_rope_head_dim]), shared by all heads; a "mamba" layer
     (models/ssm.py): (conv [b, ssm_conv - 1, channels], state [b, ssm_heads,
-    ssm_head_dim, ssm_state] float32), no slot axis whatever `max_len`."""
+    ssm_head_dim, ssm_state] float32), no slot axis whatever `max_len`. A
+    looped stack (n_loops > 1) keeps n_loops * n_layer groups, entry
+    loop * n_layer + layer: loop r's layer reads what loop r's layer wrote."""
     if cfg.attention == "mla":
         dtype = dtype or cfg.compute_dtype
         return tuple(
@@ -1498,7 +1635,8 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
             )
         return jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype)
 
-    return tuple(layer(i) for i in range(cfg.n_layer))
+    # a looped stack: one leaf group a (loop, layer) pair, loop-major
+    return tuple(layer(entry % cfg.n_layer) for entry in range(cfg.cache_entries))
 
 
 def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
@@ -1509,10 +1647,11 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
     reserved by the engine pool) absorbs dead rows' clamped writes — masked
     reads weight stale content by an exact softmax zero, which only stays
     zero if the content (values AND scales) is finite."""
-    if cfg.attention != "mha" or cfg.window_cache != "span" or cfg.has_ssm:
+    if cfg.attention != "mha" or cfg.window_cache != "span" or cfg.has_ssm or cfg.n_loops > 1:
         raise NotImplementedError(
-            f"the paged pool is not built for attention {cfg.attention!r}, window_cache {cfg.window_cache!r} or a "
-            "state-space layer (a state has no slots to page)")
+            f"the paged pool is not built for attention {cfg.attention!r}, window_cache {cfg.window_cache!r}, a "
+            "state-space layer (a state has no slots to page) or a looped stack (one table a layer, where a "
+            "looped stack keeps keys a (loop, layer) pair)")
     shape = (n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_cache_quant:
         assert dtype is None, "kv_cache_quant caches are int8; dtype not honored"
@@ -1532,7 +1671,8 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
 
 
 def cache_partition_spec(cfg: LMConfig, leaf_ndim: int, layer: int = 0):
-    """PartitionSpec of one leaf of `init_cache`'s pytree, in layer `layer`:
+    """PartitionSpec of one leaf of `init_cache`'s pytree, in entry `layer`
+    (a looped stack: loop * n_layer + layer, the layer's spec in every loop):
     batch over the data axes, heads over tp (grouped keys: the kv_heads; a
     ring layer's leaves have the same axes, fewer slots). An "mla" cache has
     no head axis: one latent a token serves every head, so it is whole on
@@ -1542,7 +1682,7 @@ def cache_partition_spec(cfg: LMConfig, leaf_ndim: int, layer: int = 0):
 
     from trlx_tpu.parallel.mesh import AXIS_TP, DATA_AXES
 
-    if cfg.mixer(layer) == "mamba":
+    if cfg.mixer(layer % cfg.n_layer) == "mamba":
         return PartitionSpec(DATA_AXES, AXIS_TP, None, None) if leaf_ndim == 4 else PartitionSpec(DATA_AXES, None, None)
     if cfg.attention == "mla":
         return PartitionSpec(DATA_AXES, None, None)
@@ -1567,7 +1707,8 @@ def state_bytes(cfg: LMConfig, batch: int) -> int:
 
 
 def cache_bytes_per_token(cfg: LMConfig) -> int:
-    """Bytes the cache holds a token, all layers: the counter
+    """Bytes the cache holds a token, all layers (a looped stack: all
+    n_loops * n_layer entries): the counter
     `rollout/cache_bytes_per_token`, from `init_cache`'s own shapes at one
     row of one token. A ring layer counts its ring once: one slot, like a
     full-span layer's, though past window_size tokens it grows no further
@@ -1576,13 +1717,18 @@ def cache_bytes_per_token(cfg: LMConfig) -> int:
     return cache_bytes(cfg, 1, 1) - state_bytes(cfg, 1)
 
 
-def decode_step_bytes(cfg: LMConfig, batch: int, keys_read: float, weight_bytes: int) -> Tuple[int, int]:
+def decode_step_bytes(cfg: LMConfig, batch: int, keys_read: float, weight_bytes: int,
+                      stack_bytes: int = 0) -> Tuple[int, int]:
     """(bytes a decode step of the static generate path must move, the
     state's part of them), from shapes: the weights read once
     (`weight_bytes`), the state-space layers' state and window read AND
     written, and `keys_read` cache slots of every attention layer's K and V
-    (what the ranged read takes at that step). The counters
-    `rollout/step_bytes_needed` and `ssm/state_rw_share`."""
+    (what the ranged read takes at that step). A looped stack reads its
+    blocks' weights (`stack_bytes` of `weight_bytes`) once a LOOP: the stack
+    does not stay on chip between loops; its keys are a (loop, layer) pair's,
+    which `cache_bytes_per_token` counts. The counters
+    `rollout/step_bytes_needed`, `ssm/state_rw_share` and
+    `loops/weight_read_share`."""
     state = 2 * state_bytes(cfg, batch)
     keys = int(keys_read * batch * cache_bytes_per_token(cfg))
-    return weight_bytes + state + keys, state
+    return weight_bytes + (cfg.n_loops - 1) * stack_bytes + state + keys, state

@@ -70,6 +70,8 @@ SCOPES = (
     "ssm_scan",  # its chunked scan (or one recurrence step)
     "ssm_gate",  # its gated norm
     "ssm_out",  # its output projection
+    "loop_norm",  # a looped stack: the final norm at the end of every loop, the next loop's input
+    "exit_gate",  # a looped stack: the exit gate on each loop's output and the exit distribution
     "lm_head",  # the vocabulary head in every form: fused log-probs, dense logits, ILQL's Q heads
     "loss",  # the RL loss terms and GAE inside the train step
     "optimizer",  # optax update, gradient norm and clip, the non-finite guard's select

@@ -263,7 +263,8 @@ class PPOOrchestrator(Orchestrator):
         lm_cfg = rl.model.cfg
         n_soft = lm_cfg.n_soft_tokens
         # the layers that keep keys: a state-space layer reads none
-        key_layers = [i for i in range(lm_cfg.n_layer) if lm_cfg.mixer(i) == "attention"]
+        # (a looped stack: every (loop, layer) entry of the cache is read a step)
+        key_layers = [i for i in range(lm_cfg.n_layer) if lm_cfg.mixer(i) == "attention"] * lm_cfg.n_loops
         layer_windows = [layer_window(lm_cfg, i) for i in key_layers]
         cache_alloc = gen_rows = 0  # bytes of the cache the generate program allocated (the last chunk's), its rows
         kv_keys = np.zeros(2, dtype=np.int64)
@@ -543,20 +544,32 @@ class PPOOrchestrator(Orchestrator):
                     lm_cfg.n_head, lm_cfg.kv_heads, lm_cfg.head_dim)
             if experts_touched:
                 rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
-            if lm_cfg.has_ssm and cache_alloc:
-                # What a decode step must move, from shapes: the weights once, the
-                # state read and written, the keys the ranged read took (the mean
-                # over the rollout's steps).
+            if (lm_cfg.has_ssm or lm_cfg.n_loops > 1) and cache_alloc:
+                # What a decode step must move, from shapes: the weights once (a
+                # looped stack's blocks once a loop), the state read and written,
+                # the keys the ranged read took (the mean over the rollout's steps).
                 steps = max(1, int(np.sum(decode_steps)))
                 keys_a_step = kv_keys[0] / steps / max(1, len(key_layers))
-                needed, state_rw = decode_step_bytes(
-                    lm_cfg, gen_rows, keys_a_step, tree_size_bytes(rl.state.params["transformer"]))
-                rl._last_exp_stats.update({
-                    "rollout/state_bytes": float(state_bytes(lm_cfg, gen_rows)),
-                    "rollout/state_bytes_per_row": float(state_bytes(lm_cfg, 1)),
-                    "rollout/step_bytes_needed": float(needed),
-                    "ssm/state_rw_share": float(state_rw / needed),
-                })
+                trunk = rl.state.params["transformer"]
+                stack = tree_size_bytes({k: v for k, v in trunk.items() if k.startswith("h_")})
+                # an untied table is looked up (a row a token), not read (a tied one is the head);
+                # a decode step leaves the exit gate out
+                read_once = tree_size_bytes({k: v for k, v in trunk.items()
+                                             if k != "exit_gate" and (k != "wte" or lm_cfg.tie_word_embeddings)})
+                needed, state_rw = decode_step_bytes(lm_cfg, gen_rows, keys_a_step, read_once, stack)
+                rl._last_exp_stats["rollout/step_bytes_needed"] = float(needed)
+                if lm_cfg.has_ssm:
+                    rl._last_exp_stats.update({
+                        "rollout/state_bytes": float(state_bytes(lm_cfg, gen_rows)),
+                        "rollout/state_bytes_per_row": float(state_bytes(lm_cfg, 1)),
+                        "ssm/state_rw_share": float(state_rw / needed),
+                    })
+                if lm_cfg.n_loops > 1:
+                    rl._last_exp_stats.update({
+                        "loops/n_loops": float(lm_cfg.n_loops),
+                        "loops/block_applications": float(lm_cfg.cache_entries),
+                        "loops/weight_read_share": float(lm_cfg.n_loops * stack / needed),
+                    })
             gather_share = weight_gather_share(rl._weight_gathers["generate"])
             if gather_share is not None:
                 # the generate program on a partitioned mesh: the prefill gathers

@@ -18,7 +18,7 @@ import optax
 from trlx_tpu.data import PackedPPOBatch, PPORLBatch
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.fleet import FleetDegradedExit, validate_fleet_config
-from trlx_tpu.models.heads import LMWithValueHead, extract_branch_params
+from trlx_tpu.models.heads import LMWithValueHead, branch_replay_params, extract_branch_params
 from trlx_tpu.ops.fused_logprob import count_head_calls, fused_logprob_eligible, take_head_call_scalars
 from trlx_tpu.ops.generate import make_generate_fn
 from trlx_tpu.ops.modeling import logprobs_from_logits
@@ -108,6 +108,9 @@ class PPOTrainer(JaxBaseTrainer):
         # train-throughput metering for the phase window (satellite of the
         # fused-logprob head work; see make_ppo_train_step).
         self._pack_train_batch = bool(getattr(m, "pack_train_batch", False))
+        if self._pack_train_batch and self.model.cfg.n_loops > 1:
+            raise NotImplementedError(
+                "method.pack_train_batch (packed segments) is not built for a looped stack (n_loops > 1)")
         # put_batch shards the leading dim over DATA_AXES — packed row-count
         # buckets must round up to a multiple of that axis product.
         self._pack_rows_multiple = int(np.prod([self.mesh.shape[a] for a in DATA_AXES]))
@@ -229,11 +232,13 @@ class PPOTrainer(JaxBaseTrainer):
             from trlx_tpu.models.lm import quantize_weights
 
             lm_cfg = self.model.cfg
-            if lm_cfg.attention != "mha" or lm_cfg.mlp != "dense" or "experts" in lm_cfg.ffn_layers or lm_cfg.has_ssm:
+            if (lm_cfg.attention != "mha" or lm_cfg.mlp != "dense" or "experts" in lm_cfg.ffn_layers or lm_cfg.has_ssm
+                    or lm_cfg.n_loops > 1):
                 raise ValueError(
                     "model.decode_weight_quant covers the GPT block's kernels only "
                     "(models/lm.py QUANT_KERNEL_NAMES): it is not built for attention "
-                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r}, expert layers or state-space layers"
+                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r}, expert layers, state-space layers or a looped "
+                    "stack (n_loops > 1)"
                 )
 
             self._quantize_fn = self._wrap_monitored(
@@ -664,11 +669,13 @@ class PPOTrainer(JaxBaseTrainer):
 
     def rollout_score_fused(self, tokens, mask, scores, gen_aux, snapshot=None):
         stats, prefill_extras = gen_aux
+        params = self.state.params if snapshot is None else snapshot["params"]
         extras = self.state.extras if snapshot is None else snapshot["extras"]
         scores = self.put_batch(np.asarray(scores, dtype=np.float32))
         with self._dispatch_lock, count_head_calls(self._head_calls["score"]):
             return self._score_fused_fn_for(self._batch_prompt_length(tokens))(
-                extras,
+                # a looped stack's replay also runs the live bottom blocks (loops 2..R)
+                branch_replay_params(params, extras, self.model.cfg, self.model.branch_layer),
                 tokens,
                 mask,
                 scores,
@@ -681,6 +688,8 @@ class PPOTrainer(JaxBaseTrainer):
 
     def _rollout_score_impl(self, params, extras, tokens, mask, scores, kl_coef, *, prompt_length: int):
         P = prompt_length
+        if self.model.branch_layer >= 0:
+            extras = branch_replay_params(params, extras, self.model.cfg, self.model.branch_layer)
         # Response region, state-before-token convention [P-1, P+R-1)
         # (reference: trlx/orchestrator/ppo_orchestrator.py:94-98).
         if resolve_fused_head(self.model.cfg):
@@ -1098,10 +1107,18 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         vf_coef=m.vf_coef,
     )
 
-    def with_expert_stats(result, out, n_tokens):
+    def with_trunk_stats(result, out, n_tokens, exit_mask=None):
         """A model with expert layers: the step's routing counters beside the
         loss's own stats (`moe/held_slot_share`, `moe/max_expert_load`,
-        `moe/first_buffer_share`)."""
+        `moe/first_buffer_share`). A gated looped stack: the loop the exit
+        gate would leave at, mean over the response positions `exit_mask`
+        (`policy/expected_exit_loop`, sum over r of r p_r, no gradient)."""
+        if out["exit_probs"] is not None and exit_mask is not None:
+            p = jax.lax.stop_gradient(out["exit_probs"])[:, P - 1 : -1]
+            loops = jnp.sum(p * jnp.arange(1, p.shape[-1] + 1, dtype=p.dtype), axis=-1)
+            weight = exit_mask.astype(p.dtype)
+            loss, stats = result
+            result = loss, {**stats, "policy/expected_exit_loop": jnp.sum(loops * weight) / jnp.maximum(jnp.sum(weight), 1.0)}
         if out["expert_counts"] is None:
             return result
         from trlx_tpu.models.moe import expert_load_stats, first_buffer_share
@@ -1120,10 +1137,10 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         logits = out["logits"].astype(jnp.float32)
         lp = logprobs_from_logits(logits[:, :-1], all_ids[:, P:])
         vpred = out["values"].astype(jnp.float32)[:, P - 1 : -1]
-        return with_expert_stats(ppo_loss(
+        return with_trunk_stats(ppo_loss(
             lp, vpred, batch.logprobs, batch.values, batch.rewards,
             batch.response_mask, **loss_kwargs,
-        ), out, all_ids.size)
+        ), out, all_ids.size, batch.response_mask)
 
     def fused_loss_fn(params, batch: PPORLBatch):
         # Same update, fused head: the policy's per-label logprobs come out
@@ -1138,10 +1155,10 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
             labels=all_ids[:, P:], labels_mask=batch.response_mask,
         )
         vpred = out["values"].astype(jnp.float32)[:, P - 1 : -1]
-        return with_expert_stats(ppo_loss(
+        return with_trunk_stats(ppo_loss(
             out["logprobs"], vpred, batch.logprobs, batch.values, batch.rewards,
             batch.response_mask, **loss_kwargs,
-        ), out, all_ids.size)
+        ), out, all_ids.size, batch.response_mask)
 
     def packed_loss_fn(params, batch: PackedPPOBatch):
         # Packed layout: episodes live as segments inside dense rows
@@ -1156,7 +1173,7 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
             labels=batch.labels, labels_mask=batch.loss_mask,
         )
         vpred = out["values"].astype(jnp.float32)
-        return with_expert_stats(ppo_loss(
+        return with_trunk_stats(ppo_loss(
             out["logprobs"], vpred, batch.old_logprobs, batch.old_values,
             batch.rewards, batch.loss_mask,
             segment_ids=batch.segment_ids, n_seqs=config.train.batch_size,
