@@ -226,6 +226,48 @@ def test_both_forms_of_the_state_space_mixer_compile_for_v5e(v5e_sharding):
     assert state.dtype == jnp.float32 and state.shape == (32, 64, 64, 128) and conv.shape == (32, 3, 4352)
 
 
+def test_the_chunked_scan_relays_no_float32_array_64_lanes_wide(v5e_sharding):
+    """`ssd_chunked` forward and gradient at granite-4.0-h-micro's train
+    shapes ([8, 1024], 64 heads of 64, state 128, chunks of 256, bf16), as the
+    v5e's compiler leaves it. A head 64 wide fills half a 128-lane tile: held
+    P-minor, x and y were float32 arrays of 268 MB for 134, copied three to
+    four times a layer a pass (PERF.md section 6, PR 38). The form holds them
+    positions-minor, so NO float32 result as large as x has a last axis of 64
+    (what is left 64 wide is a head's scalar a position or a chunk, H = 64
+    here: at most b T H elements, and no relayout instruction among those
+    either beyond a chunk's decay), and x crosses HBM in a relayout twice in,
+    twice out: x and its gradient in bf16, y and its cotangent in float32."""
+    import math
+    import re
+
+    from trlx_tpu.models import ssm
+
+    b, T, H, P, N, Q = 8, 1024, 64, 64, 128, 256
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+
+    def scalar(x, dt, a, B, C, wy, wl):  # the mixer's own reshapes in and out
+        y, last = ssm.ssd_chunked(x.reshape(b, T, H, P), dt, a, B, C, Q, jnp.bfloat16)
+        return (y.reshape(b, T, H * P) * wy).sum() + (last * wl).sum()
+
+    text = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(
+        s((b, T, H * P), jnp.bfloat16), s((b, T, H), jnp.float32), s((H,), jnp.float32), s((b, T, N), jnp.bfloat16),
+        s((b, T, N), jnp.bfloat16), s((b, T, H * P), jnp.float32), s((b, H, P, N), jnp.float32)).compile().as_text()
+    relayout, wide, moved = ("copy", "reshape", "transpose"), [], []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        if not m or m.group(2) in ("parameter", "bitcast", "tuple", "get-tuple-element"):
+            continue
+        for dtype, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", m.group(1)):
+            dims = [int(d) for d in dims.split(",")]
+            if dtype == "f32" and dims[-1] == 64:
+                wide.append((m.group(2), math.prod(dims)))
+            if m.group(2) in relayout and math.prod(dims) >= b * T * H * P:
+                moved.append(dtype)
+    assert wide and max(n for _, n in wide) <= b * T * H, sorted(wide, key=lambda w: -w[1])[:5]
+    assert all(n <= b * (T // Q) * H for op, n in wide if op in relayout), [w for w in wide if w[0] in relayout]
+    assert sorted(moved) == ["bf16", "bf16", "f32", "f32"], moved
+
+
 def test_mosaic_kernels_refuse_a_multi_device_jit(v5e_sharding):
     """Why every model-layer gate requires a one-device mesh
     (flash_attention.one_device_tpu): jax will not partition a Mosaic call."""

@@ -25,7 +25,9 @@ the prefill): the same y from cumulative sums of D_t A inside chunks of
 ssm_chunk positions, one decay-masked product C B^T a chunk (shared by the
 heads), each chunk's end state, a recurrence over the chunk end states, and
 C_t applied to the state carried into the chunk. Decays and sums are float32;
-the four products take operands in the compute dtype and accumulate in float32.
+the four products take operands in the compute dtype and accumulate in float32
+(x as it arrives: the step D_t rides on the decay mask, and on the weight of
+each position in its chunk's end state).
 *Recurrent* (`ssd_step`: one token, a decode step): the update above, all in
 float32, on the cache's state.
 
@@ -40,6 +42,7 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from trlx_tpu.models.lm import LMConfig, QDense, drawn_in
 
@@ -74,11 +77,28 @@ def a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32)).astype(dtype)
 
 
+def lane_fill(cfg: LMConfig, tokens: int) -> float:
+    """Share of a 128-lane tile the chunked scan's widest float32 results
+    fill over `tokens` positions: they are held positions-minor (`ssd_chunked`),
+    so a chunk of 256 fills its tiles and a head's width does not matter."""
+    minor = min(cfg.ssm_chunk, tokens)
+    return minor / (-(-minor // 128) * 128)
+
+
 def ssd_chunked(x, dt, a, B, C, chunk: int, dtype):
-    """The scan over [b, T] in chunks. x [b, T, H, P], dt [b, T, H] float32
-    (0 on padding), a [H] float32 (negative), B, C [b, T, N]. Returns
-    (y [b, T, H, P] float32 without the D skip, the state after position
-    T - 1 [b, H, P, N] float32)."""
+    """The scan over [b, T] in chunks. x [b, T, H, P] as it arrives (the
+    compute dtype), dt [b, T, H] float32 (0 on padding), a [H] float32
+    (negative), B, C [b, T, N]. Returns (y [b, T, H, P] float32 without the D
+    skip, the state after position T - 1 [b, H, P, N] float32).
+
+    Everything a head owns is held POSITIONS-minor, [b, c, H, P, Q]: the
+    products want their contraction (the chunk's positions) there, and a head
+    narrower than a 128-lane tile (P 64) would fill half of every tile it is
+    minor in. x is transposed once, as it arrives; y once, as it leaves; both
+    are pinned row-major on the mixer's side of that transpose, or the
+    compiler hands the positions-minor layout on to the projections and pays
+    for it where [b, T] splits into chunks (a relayout and a copy a pass:
+    PERF.md section 6, PR 38)."""
     b, T, H, P = x.shape
     Q = min(chunk, T)
     pad = -T % Q
@@ -86,23 +106,25 @@ def ssd_chunked(x, dt, a, B, C, chunk: int, dtype):
         x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (x, dt, B, C))
     c = (T + pad) // Q
     chunks = lambda t: t.reshape((b, c, Q) + t.shape[2:])
-    x, dt, B, C = chunks(x), chunks(dt), chunks(B), chunks(C)
+    heads_first = lambda t: jnp.swapaxes(chunks(t), 2, 3)
+    rows = lambda t: with_layout_constraint(t, Layout(major_to_minor=tuple(range(t.ndim))))
+    x = heads_first(rows(x.reshape(b, T + pad, H * P))).reshape(b, c, H, P, Q)
+    dt, B, C = heads_first(dt), chunks(B), chunks(C)  # dt [b, c, H, Q]
     f32 = jnp.float32
     prod = lambda spec, *ops: jnp.einsum(spec, *(o.astype(dtype) for o in ops), preferred_element_type=f32)
 
-    cum = jnp.cumsum(dt * a, axis=2)  # [b, c, Q, H], falling from 0
-    xdt = x.astype(f32) * dt[..., None]
-    # inside a chunk: position l reads s <= l through exp(cum_l - cum_s)
-    gap = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [b, c, l, s, H]
-    keep = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
-    decay = jnp.exp(jnp.where(keep, gap, -jnp.inf))
+    cum = jnp.cumsum(dt * a[:, None], axis=3)  # [b, c, H, Q], falling from 0
+    # inside a chunk: position l reads s <= l through exp(cum_l - cum_s), and
+    # the step dt_s rides on the mask (x goes to the products as it is)
+    gap = cum[..., :, None] - cum[..., None, :]  # [b, c, H, l, s]
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), gap, -jnp.inf))
     scores = prod("bcln,bcsn->bcls", C, B)  # one group: shared by the heads
-    y = prod("bclsh,bcshp->bclhp", scores[..., None] * decay, xdt)
+    y = prod("bchls,bchps->bchpl", scores[:, :, None] * decay * dt[..., None, :], x)
     # each chunk's own end state, from zero
-    to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # [b, c, Q, H]
-    ends = prod("bcsh,bcshp,bcsn->bchpn", to_end, xdt, B)
+    to_end = jnp.exp(cum[..., -1:] - cum) * dt  # [b, c, H, Q]
+    ends = prod("bchps,bcsn->bchpn", x.astype(f32) * to_end[:, :, :, None, :], B)
     # the recurrence over chunk end states: the state carried INTO each chunk
-    chunk_decay = jnp.exp(cum[:, :, -1, :])  # [b, c, H]
+    chunk_decay = jnp.exp(cum[..., -1])  # [b, c, H]
 
     def carry(state, chunk_in):
         end, factor = chunk_in
@@ -111,7 +133,8 @@ def ssd_chunked(x, dt, a, B, C, chunk: int, dtype):
     last, into = jax.lax.scan(carry, jnp.zeros((b, H, P, B.shape[-1]), f32),
                               (jnp.moveaxis(ends, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
     into = jnp.moveaxis(into, 0, 1)  # [b, c, H, P, N]
-    y = y + prod("bcln,bchpn->bclhp", C, into) * jnp.exp(cum)[..., None]
+    y = y + prod("bcln,bchpn->bchpl", C, into) * jnp.exp(cum)[:, :, :, None, :]
+    y = rows(jnp.swapaxes(y.reshape(b, c, H * P, Q), 2, 3).reshape(b, c * Q, H * P))
     return y.reshape(b, c * Q, H, P)[:, :T], last
 
 
@@ -167,26 +190,32 @@ class SSMMixer(nn.Module):
             conv = sum(window[:, j:j + T].astype(f32) * w_conv[j] for j in range(K)) + b_conv
             xbc_out = (jax.nn.silu(conv) * m).astype(dtype)
             new_conv = window[:, -(K - 1):]
-        xs = xbc_out[..., :inner].reshape(b, T, H, P)
+        xs = xbc_out[..., :inner]
         B, C = xbc_out[..., inner:inner + N], xbc_out[..., inner + N:]
 
         with jax.named_scope("ssm_scan"):
+            heads = xs.reshape(b, T, H, P)
             if step:
-                y, state = ssd_step(cache[1], xs[:, 0], dt[:, 0], a, B[:, 0], C[:, 0])
-                y = y[:, None]
+                y, state = ssd_step(cache[1], heads[:, 0], dt[:, 0], a, B[:, 0], C[:, 0])
             else:
                 group = max(1, SCAN_TOKENS // T)
                 if b > group and b % group == 0:
                     split = lambda t: t.reshape((b // group, group) + t.shape[1:])
                     y, state = jax.lax.map(lambda ops: ssd_chunked(ops[0], ops[1], a, ops[2], ops[3], cfg.ssm_chunk, dtype),
-                                           tuple(split(t) for t in (xs, dt, B, C)))
-                    y, state = y.reshape((b,) + y.shape[2:]), state.reshape((b,) + state.shape[2:])
+                                           tuple(split(t) for t in (heads, dt, B, C)))
+                    state = state.reshape((b,) + state.shape[2:])
                 else:
-                    y, state = ssd_chunked(xs, dt, a, B, C, cfg.ssm_chunk, dtype)
-            y = y + skip[:, None] * xs.astype(f32)
+                    y, state = ssd_chunked(heads, dt, a, B, C, cfg.ssm_chunk, dtype)
+                if cache is not None:
+                    # the prefill: nothing reads a layer's state before the decode loop, and a
+                    # scheduler that therefore leaves it for last keeps its operands (this
+                    # layer's x, B and projections) alive under every layer after it
+                    y, state = jax.lax.optimization_barrier((y, state))
+            # the D skip where a row is inner wide, every lane of a tile in use
+            y = y.reshape(b, T, inner) + jnp.repeat(skip, P) * xs.astype(f32)
 
         with jax.named_scope("ssm_gate"):
-            g = y.reshape(b, T, inner) * jax.nn.silu(z.astype(f32))
+            g = y * jax.nn.silu(z.astype(f32))
             g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.ln_eps)
             scale = vector("norm_scale", nn.initializers.ones_init(), (inner,))
             gated = (scale * g).astype(dtype)

@@ -19,6 +19,7 @@ from trlx_tpu.data import PackedPPOBatch, PPORLBatch
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.fleet import FleetDegradedExit, validate_fleet_config
 from trlx_tpu.models.heads import LMWithValueHead, branch_replay_params, extract_branch_params
+from trlx_tpu.models.ssm import lane_fill
 from trlx_tpu.ops.fused_logprob import count_head_calls, fused_logprob_eligible, take_head_call_scalars
 from trlx_tpu.ops.generate import make_generate_fn
 from trlx_tpu.ops.modeling import logprobs_from_logits
@@ -956,6 +957,7 @@ class PPOTrainer(JaxBaseTrainer):
             lm_cfg = self.model.cfg
             stats["ssm/pad_share"] = float(np.mean(window_pad))
             stats["ssm/chunks_per_pass"] = float(-(-int(self.config.train.seq_length) // lm_cfg.ssm_chunk))
+            stats["ssm/lane_fill"] = lane_fill(lm_cfg, int(self.config.train.seq_length))
         if self._last_exp_stats:
             stats.update(self._last_exp_stats)
         stats.update(take_head_call_scalars(self._head_calls["score"], "score"))
