@@ -226,6 +226,38 @@ def test_both_forms_of_the_state_space_mixer_compile_for_v5e(v5e_sharding):
     assert state.dtype == jnp.float32 and state.shape == (32, 64, 64, 128) and conv.shape == (32, 3, 4352)
 
 
+def test_both_forms_of_the_kda_mixer_compile_for_v5e(v5e_sharding):
+    """models/kda.py at Kimi-Linear's widths, bf16, no Pallas kernel in it: the
+    chunked form forward and backward over [4, 1024] (four row groups of 16
+    chunks of 64, each recomputed in its own backward pass) and the recurrent
+    step over 32 rows; the state stays float32."""
+    import json
+    import os
+
+    from trlx_tpu.models import kda
+    from trlx_tpu.models.lm import LMConfig
+
+    spec = json.load(open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "kimi-linear-48b-ep32-l13.json")))
+    cfg = LMConfig.from_dict({**spec["model_arch"], "dtype": "bfloat16", "param_dtype": "bfloat16"})
+    mixer = kda.KDAMixer(cfg)
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+    x = jnp.zeros((1, 4, cfg.d_model), jnp.bfloat16)
+    params = jax.eval_shape(lambda: mixer.init(jax.random.PRNGKey(0), x, jnp.ones((1, 4), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), params)
+
+    def train(p, x, m):
+        return jax.value_and_grad(lambda p, x: mixer.apply({"params": p}, x, m)[0].astype(jnp.float32).sum(), argnums=(0, 1))(p, x)
+
+    compiled = jax.jit(train).lower(params, s((4, 1024, 2304), jnp.bfloat16), s((4, 1024), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9  # 4.8 GB a whole train batch without the row groups
+    step = lambda p, x, m, c: mixer.apply({"params": p}, x, m, c)
+    args = (params, s((32, 1, 2304), jnp.bfloat16), s((32, 1), jnp.int32),
+            tuple(s(shape, dtype) for shape, dtype in kda.cache_shapes(cfg, 32)))
+    jax.jit(step).lower(*args).compile()
+    _, (conv, state) = jax.eval_shape(step, *args)
+    assert state.dtype == jnp.float32 and state.shape == (32, 32, 128, 128) and conv.shape == (32, 3, 12288)
+
+
 def test_the_chunked_scan_relays_no_float32_array_64_lanes_wide(v5e_sharding):
     """`ssd_chunked` forward and gradient at granite-4.0-h-micro's train
     shapes ([8, 1024], 64 heads of 64, state 128, chunks of 256, bf16), as the

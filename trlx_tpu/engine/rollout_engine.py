@@ -136,11 +136,12 @@ class RolloutEngine:
             raise NotImplementedError(
                 "the rollout engine (per-slot write offsets, suffix prefill, spec verify windows) is not built "
                 "for window_cache 'ring': a ring takes one write offset for the whole batch (ops/generate.py)")
-        if model.cfg.has_ssm:
+        if model.cfg.has_state:
             raise NotImplementedError(
-                "the rollout engine (and with it the paged pool and spec decode) is not built for a state-space "
-                "layer: a slot's state is not carried through admission, a block table has nothing to page and a "
-                "rejected draft needs a snapshot of the state to roll back to")
+                "the rollout engine (and with it the paged pool and spec decode) is not built for a "
+                f"{'state-space' if model.cfg.has_ssm else 'kda'} layer: a slot's state is not carried through "
+                "admission, a block table has nothing to page and a rejected draft needs a snapshot of the state to "
+                "roll back to")
         if model.cfg.n_loops > 1:
             raise NotImplementedError(
                 "the rollout engine (and with it the paged pool and spec decode) is not built for a looped stack "

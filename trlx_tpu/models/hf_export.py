@@ -36,13 +36,13 @@ def validate_exportable(cfg: LMConfig, family: str):
     """Fail LOUDLY when the LMConfig's semantics can't be represented by the
     target HF family — a silent mismatch would export a checkpoint that
     computes different logits than the trained model."""
-    if cfg.has_ssm or cfg.pos_type == "none" or cfg.n_loops > 1 or cfg.sandwich_norm or (
+    if cfg.has_state or cfg.pos_type == "none" or cfg.n_loops > 1 or cfg.sandwich_norm or (
             cfg.embedding_multiplier, cfg.residual_multiplier, cfg.logits_scaling, cfg.attention_multiplier) != (1.0, 1.0, 1.0, 0.0):
         raise ValueError(
-            "export to an HF checkpoint is not built for a state-space layer (mixer_layers 'mamba'), pos_type "
+            "export to an HF checkpoint is not built for a state-space or kda layer (mixer_layers 'mamba', 'kda'), pos_type "
             "'none', a looped stack (n_loops > 1), sandwich norms "
             f"or the embedding / residual / attention / logits multipliers: HF {family} has none of them, and "
-            "the checkpoint would compute another model (models/hf_import.py reads granitemoehybrid; nothing writes it)")
+            "the checkpoint would compute another model (models/hf_import.py reads granitemoehybrid and kimi_linear; nothing writes them)")
     problems = []
     if family == "gpt_neo":
         if cfg.scale_attn:

@@ -18,8 +18,9 @@ SOURCE and the load is TPU-native streaming:
 
 Legacy pytorch_model.bin checkpoints fall back to the full torch load.
 Supported families match the reference's (reference: README.md:6): gpt2,
-gpt-j, gpt-neo, gpt-neox; and granitemoehybrid without experts (state-space
-and attention layers, models/ssm.py). `ouro` (the looped family) maps its
+gpt-j, gpt-neo, gpt-neox; granitemoehybrid without experts (state-space
+and attention layers, models/ssm.py); and kimi_linear (gated delta-rule and
+unrotated latent-attention layers, sparse experts: models/kda.py, models/moe.py). `ouro` (the looped family) maps its
 config only: its weights are not imported (`load_hf_trunk` raises). With no checkpoint (or `model_arch` given) params
 initialize from scratch — the randomwalks path
 (reference: examples/randomwalks.py:99-101).
@@ -177,6 +178,61 @@ def lm_config_from_hf(hf, **overrides) -> LMConfig:
             attention_multiplier=float(hf.attention_multiplier),
             residual_multiplier=float(hf.residual_multiplier),
             logits_scaling=float(hf.logits_scaling),
+        )
+    elif t == "kimi_linear":
+        # Kimi-Linear: gated delta-rule (KDA) layers beside latent attention
+        # with directly projected queries and (mla_use_nope) no rotation, a
+        # sigmoid router with a correction bias over one group, one shared
+        # expert. `linear_attn_config` names its layers 1-indexed. What the
+        # program lacks raises here.
+        given = hf.to_dict() if hasattr(hf, "to_dict") else dict(vars(hf))
+        linear = dict(given["linear_attn_config"])
+        n_layer = hf.num_hidden_layers
+        kda_layers, full_layers = set(linear["kda_layers"]), set(linear["full_attn_layers"])
+        unbuilt = [name for name, on in (
+            ("linear_attn_config that does not name each of layers 1..num_hidden_layers once",
+             kda_layers & full_layers or (kda_layers | full_layers) != set(range(1, n_layer + 1))),
+            ("moe_router_activation_func other than sigmoid", given.get("moe_router_activation_func") != "sigmoid"),
+            ("moe_renormalize false", not given.get("moe_renormalize")),
+            ("num_expert_group / topk_group other than 1", (given.get("num_expert_group", 1), given.get("topk_group", 1)) != (1, 1)),
+            ("moe_layer_freq other than 1", given.get("moe_layer_freq", 1) != 1),
+            ("num_nextn_predict_layers > 0", given.get("num_nextn_predict_layers", 0) > 0),
+            ("rope_scaling", given.get("rope_scaling") is not None),
+            ("hidden_act other than silu", hf.hidden_act != "silu")) if on]
+        if unbuilt:
+            raise ValueError(f"kimi_linear: not built: {'; '.join(unbuilt)}")
+        dense_first = int(given.get("first_k_dense_replace", 0))
+        d = dict(
+            vocab_size=hf.vocab_size,
+            n_layer=n_layer,
+            n_head=hf.num_attention_heads,
+            d_model=hf.hidden_size,
+            d_ff=hf.intermediate_size,
+            max_position=int(given.get("model_max_length") or given.get("max_position_embeddings") or 2048),
+            pos_type="none" if given.get("mla_use_nope") else "rotary",
+            rope_theta=float(given.get("rope_theta", 10000.0)),
+            norm="rmsnorm",
+            mlp="gated",
+            attention="mla",
+            activation="silu",
+            ln_eps=hf.rms_norm_eps,
+            parallel_residual=False,
+            tie_word_embeddings=bool(given.get("tie_word_embeddings", False)),
+            mixer_layers=tuple("kda" if i + 1 in kda_layers else "attention" for i in range(n_layer)),
+            ffn_layers=tuple("dense" if i < dense_first else "experts" for i in range(n_layer)),
+            kda_heads=linear["num_heads"],
+            kda_head_dim=linear["head_dim"],
+            kda_conv=linear["short_conv_kernel_size"],
+            q_lora_rank=int(given.get("q_lora_rank") or 0),
+            kv_lora_rank=hf.kv_lora_rank,
+            qk_nope_head_dim=hf.qk_nope_head_dim,
+            qk_rope_head_dim=hf.qk_rope_head_dim,
+            v_head_dim=hf.v_head_dim,
+            n_experts=given["num_experts"],
+            experts_per_token=given["num_experts_per_token"],
+            expert_d_ff=hf.moe_intermediate_size,
+            n_shared_experts=given.get("num_shared_experts", 0),
+            routed_scaling_factor=float(given.get("routed_scaling_factor", 1.0)),
         )
     elif t == "ouro":
         # The looped family (Ouro-1.4B / 2.6B): a Llama-shaped block (rotary
@@ -422,6 +478,8 @@ def load_hf_trunk(model_path: str, cfg: LMConfig, put=None) -> Dict[str, Any]:
 def _detect_family(sd) -> str:
     if any(".mamba.in_proj." in k for k in sd):
         return "granitemoehybrid"
+    if any(".self_attn.f_a_proj." in k for k in sd):
+        return "kimi_linear"
     if any(k.startswith("transformer.h.") and ".attn.c_attn." in k for k in sd):
         return "gpt2"
     if any(".attn.attention.q_proj." in k for k in sd):
@@ -479,6 +537,8 @@ def trunk_spec(family: str, cfg: LMConfig) -> Dict[str, Any]:
         return _spec_neox(cfg)
     if family == "granitemoehybrid":
         return _spec_granite_hybrid(cfg)
+    if family == "kimi_linear":
+        return _spec_kimi_linear(cfg)
     raise ValueError(f"unsupported family: {family}")
 
 
@@ -674,6 +734,66 @@ def _spec_granite_hybrid(cfg: LMConfig) -> Dict[str, Any]:
                 "v_proj": {"kernel": _t(f"{a}.v_proj.weight")},
                 "c_proj": {"kernel": _t(f"{a}.o_proj.weight")},
             }
+        p[f"h_{i}"] = block
+    return p
+
+
+def _spec_kimi_linear(cfg: LMConfig) -> Dict[str, Any]:
+    """kimi_linear, by the tensor names of the family's `modeling_kimi.py` as
+    this file's writer knew them (no network here to read them again: a
+    checkpoint that names a tensor otherwise fails with a KeyError on that
+    name, never loads another model): nn.Linear weights transposed; a
+    depthwise Conv1d's [channels, 1, K] to [K, channels]; `A_log`
+    [1, 1, heads, 1] to [heads]; the gate's bias zeros where the checkpoint
+    has none; the router `gate.weight` [experts, d] transposed, its bias as it
+    is; the HELD experts `[first, first + count)` of `experts.{e}.w1 | w3 | w2`
+    (gate | up | down) stacked."""
+    if cfg.q_lora_rank:
+        raise NotImplementedError("kimi_linear import is built for directly projected queries (q_lora_rank null)")
+    first, held = cfg.held_experts
+    inner = cfg.kda_heads * cfg.kda_head_dim
+    scale = lambda key: {"scale": _id(key)}
+    depthwise = lambda key: (lambda sd: np.asarray(sd[key])[:, 0, :].T)
+    stacked = lambda h, w: (lambda sd: np.stack([np.asarray(sd[f"{h}.experts.{first + e}.{w}.weight"]).T for e in range(held)]))
+    gated = lambda h: {name: {"kernel": _t(f"{h}.{name}.weight")} for name in ("gate_proj", "up_proj", "down_proj")}
+    p: Dict[str, Any] = {"wte": {"embedding": _id("model.embed_tokens.weight")}, "ln_f": scale("model.norm.weight")}
+    if not cfg.tie_word_embeddings:
+        p["lm_head"] = {"kernel": _t("lm_head.weight")}
+    for i in range(cfg.n_layer):
+        h = f"model.layers.{i}"
+        a = f"{h}.self_attn"
+        block = {"ln_1": scale(f"{h}.input_layernorm.weight"), "ln_2": scale(f"{h}.post_attention_layernorm.weight")}
+        if cfg.mixer(i) == "kda":
+            bias_key = f"{a}.g_b_proj.bias"
+            block["kda"] = {
+                **{name: {"kernel": _t(f"{a}.{name}.weight")} for name in (
+                    "q_proj", "k_proj", "v_proj", "f_a_proj", "f_b_proj", "b_proj", "g_a_proj", "o_proj")},
+                "g_b_proj": {"kernel": _t(f"{a}.g_b_proj.weight"),
+                             "bias": lambda sd, key=bias_key: np.asarray(sd[key]) if key in sd else np.zeros((inner,), np.float32)},
+                **{name: depthwise(f"{a}.{name}1d.weight") for name in ("q_conv", "k_conv", "v_conv")},
+                "A_log": lambda sd, key=f"{a}.A_log": np.asarray(sd[key]).reshape(-1),
+                "dt_bias": _id(f"{a}.dt_bias"),
+                "o_norm": _id(f"{a}.o_norm.weight"),
+            }
+        else:
+            block["attn"] = {
+                "q_proj": {"kernel": _t(f"{a}.q_proj.weight")},
+                "kv_a_proj": {"kernel": _t(f"{a}.kv_a_proj_with_mqa.weight")},
+                "kv_a_norm": scale(f"{a}.kv_a_layernorm.weight"),
+                "kv_b_proj": {"kernel": _t(f"{a}.kv_b_proj.weight")},
+                "c_proj": {"kernel": _t(f"{a}.o_proj.weight")},
+            }
+        if cfg.ffn_layers and cfg.ffn_layers[i] == "experts":
+            m = f"{h}.block_sparse_moe"
+            block["moe"] = {
+                "router": _t(f"{m}.gate.weight"),
+                "e_score_correction_bias": _id(f"{m}.gate.e_score_correction_bias"),
+                "experts_gate": stacked(m, "w1"), "experts_up": stacked(m, "w3"), "experts_down": stacked(m, "w2"),
+            }
+            if cfg.n_shared_experts:
+                block["moe"]["shared"] = gated(f"{m}.shared_experts")
+        else:
+            block["mlp"] = gated(f"{h}.mlp")
         p[f"h_{i}"] = block
     return p
 

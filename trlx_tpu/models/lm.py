@@ -49,6 +49,8 @@ ACTIVATIONS = {
 # Keys of a `model_arch` dict that are not LMConfig's: the trainers read them
 # from the dict (`eos_token_id`: trainer/ppo.py, trainer/ilql.py).
 ARCH_KEYS_READ_ELSEWHERE = frozenset({"eos_token_id"})
+# models/kda.py names the output of its chunked pass so; a remat'd block keeps what bears the name
+KDA_SCAN_OUT = "kda_scan_out"
 
 
 @dataclass(frozen=True)
@@ -193,7 +195,7 @@ class LMConfig:
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     experts_held: Tuple[int, ...] = ()
-    # Per-layer mixer kind ("attention" | "mamba"); empty -> all attention.
+    # Per-layer mixer kind ("attention" | "mamba" | "kda"); empty -> all attention.
     # A "mamba" layer is trlx_tpu/models/ssm.py: the Mamba-2 state-space mixer
     # (ssm_heads x ssm_head_dim channels, one B/C group of ssm_state numbers, a
     # depthwise causal convolution ssm_conv wide, the scan in chunks of
@@ -207,6 +209,19 @@ class LMConfig:
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # A "kda" layer of `mixer_layers` is trlx_tpu/models/kda.py: the gated
+    # delta-rule mixer (Kimi Delta Attention): kda_heads heads whose keys and
+    # values are kda_head_dim wide, a log-decay a key channel and a correction
+    # strength a head, three depthwise causal convolutions kda_conv wide, the
+    # pass over many tokens in chunks of kda.CHUNK. Its cache is a fixed
+    # float32 state [kda_heads, kda_head_dim, kda_head_dim] a row, no slot
+    # axis. It stands beside attention "mla" and beside expert layers; the
+    # engine, the paged pool, spec decode, the sp ring, kv_cache_quant,
+    # decode_weight_quant, soft prompts, packed segments and a looped stack
+    # refuse it.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
     # The four scalars of the granite family: on the token embedding, on the
     # attention scores (0 -> 1/sqrt(head_dim), or 1 without scale_attn), on
     # both residual branches of a block, and dividing the logits (in the fused
@@ -260,9 +275,11 @@ class LMConfig:
                                 or set(self.ffn_layers) - {"dense", "experts"}):
             raise ValueError(f"ffn_layers must name 'dense' or 'experts' for each of {self.n_layer} layers: {self.ffn_layers!r}")
         if self.attention == "mla":
-            sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
-            if min(sizes) <= 0 or self.pos_type != "rotary" or self.qk_rope_head_dim % 2:
-                raise ValueError(f"attention 'mla' needs pos_type 'rotary' and its five sizes, got {sizes}")
+            sizes = (self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+            if min(sizes) <= 0 or self.q_lora_rank < 0 or self.pos_type == "learned" or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "attention 'mla' needs pos_type 'rotary' (or 'none': the shared key kept and not rotated), its four "
+                    f"sizes and q_lora_rank (0: queries projected directly), got {sizes} and {self.q_lora_rank}")
             if self.kv_cache_quant or self.attention_layers or self.n_soft_tokens:
                 raise ValueError("attention 'mla' is not built with kv_cache_quant, windowed layers or soft prompts")
         elif self.rope_scaling:
@@ -294,8 +311,8 @@ class LMConfig:
                     f"and experts_held inside [0, n_experts): {self.experts_held!r} of {self.n_experts}")
 
         if self.mixer_layers and (len(self.mixer_layers) != self.n_layer
-                                  or set(self.mixer_layers) - {"attention", "mamba"}):
-            raise ValueError(f"mixer_layers must name 'attention' or 'mamba' for each of {self.n_layer} layers: {self.mixer_layers!r}")
+                                  or set(self.mixer_layers) - {"attention", "mamba", "kda"}):
+            raise ValueError(f"mixer_layers must name 'attention', 'mamba' or 'kda' for each of {self.n_layer} layers: {self.mixer_layers!r}")
         if self.has_ssm:
             sizes = (self.ssm_heads, self.ssm_head_dim, self.ssm_state, self.ssm_conv - 1, self.ssm_chunk)
             if min(sizes) <= 0:
@@ -307,12 +324,24 @@ class LMConfig:
                 ("expert layers", "experts" in self.ffn_layers), ("parallel_residual", self.parallel_residual)) if on]
             if unbuilt:
                 raise ValueError(f"a 'mamba' layer (mixer_layers) is not built with {', '.join(unbuilt)}")
+        if self.has_kda:
+            sizes = (self.kda_heads, self.kda_head_dim, self.kda_conv - 1)
+            if min(sizes) <= 0:
+                raise ValueError(f"a 'kda' layer needs kda_heads, kda_head_dim and kda_conv >= 2, got {sizes}")
+            unbuilt = [name for name, on in (
+                ("a 'mamba' layer", self.has_ssm), ("kv_cache_quant", self.kv_cache_quant),
+                ("soft prompts", self.n_soft_tokens > 0), ("the sp ring (sp_size > 1)", self.sp_size > 1),
+                ("windowed attention_layers", "local" in self.attention_layers),
+                ("parallel_residual", self.parallel_residual)) if on]
+            if unbuilt:
+                raise ValueError(f"a 'kda' layer (mixer_layers) is not built with {', '.join(unbuilt)}")
         if self.n_loops < 1:
             raise ValueError(f"n_loops must be at least 1, got {self.n_loops}")
         if self.n_loops > 1:
             unbuilt = [name for name, on in (
                 ("attention 'mla'", self.attention == "mla"), ("expert layers", "experts" in self.ffn_layers),
-                ("a 'mamba' layer", self.has_ssm), ("window_cache 'ring'", self.window_cache == "ring"),
+                ("a 'mamba' layer", self.has_ssm), ("a 'kda' layer", self.has_kda),
+                ("window_cache 'ring'", self.window_cache == "ring"),
                 ("soft prompts", self.n_soft_tokens > 0), ("the sp ring (sp_size > 1)", self.sp_size > 1)) if on]
             if unbuilt:
                 raise ValueError(f"a looped stack (n_loops > 1) is not built with {', '.join(unbuilt)}")
@@ -336,6 +365,16 @@ class LMConfig:
     def has_ssm(self) -> bool:
         """Whether any layer is a state-space ("mamba") mixer."""
         return "mamba" in self.mixer_layers
+
+    @property
+    def has_kda(self) -> bool:
+        """Whether any layer is a gated delta-rule ("kda") mixer."""
+        return "kda" in self.mixer_layers
+
+    @property
+    def has_state(self) -> bool:
+        """Whether any layer keeps a recurrent state in place of keys ("mamba" or "kda")."""
+        return self.has_ssm or self.has_kda
 
     def mixer(self, layer: int) -> str:
         return self.mixer_layers[layer] if self.mixer_layers else "attention"
@@ -891,10 +930,12 @@ MLA_ROW_GROUP = 8
 class LatentAttention(nn.Module):
     """Multi-head latent attention (MLA), with its two read paths.
 
-        c_q = RMSNorm(x W_qa);  q = c_q W_qb -> n_head x (nope | rope)
+        c_q = RMSNorm(x W_qa);  q = c_q W_qb -> n_head x (nope | rope)     (q_lora_rank 0: q = x W_q, no bottleneck)
         [c_kv | k_r] = x W_kva; c_kv = RMSNorm(c_kv); k_rope = RoPE(k_r), one for all heads
         [k_nope | v] = c_kv W_kvb -> n_head x (nope | v)
         scores = (q_nope . k_nope + RoPE(q_rope) . k_rope) * s, causal, softmax in float32
+    With pos_type "none" RoPE is the identity: the "rope" part of q and the
+    shared key are kept, as wide as ever, and carry no position.
 
     The cache is `(c_kv [b, T, kv_lora_rank], k_rope [b, T, qk_rope_head_dim])`:
     the latent after its norm and the rotated shared key, nothing per head.
@@ -929,10 +970,12 @@ class LatentAttention(nn.Module):
                                            draw_dtype=cfg.draw_dtype, name=name)
         norm = lambda name: nn.RMSNorm(epsilon=cfg.ln_eps, dtype=dtype, param_dtype=cfg.params_dtype, name=name)
 
-        c_q = norm("q_a_norm")(dense(cfg.q_lora_rank, "q_a_proj")(x))
+        # the queries' input and its width: the bottleneck's normed output, or x itself
+        c_q, q_in = (norm("q_a_norm")(dense(cfg.q_lora_rank, "q_a_proj")(x)), cfg.q_lora_rank) if cfg.q_lora_rank else (x, cfg.d_model)
         kv_a = dense(rank + dr, "kv_a_proj")(x)
         c_kv = norm("kv_a_norm")(kv_a[..., :rank])
         scaling = cfg.rope_scaling
+        rotate = apply_rotary if cfg.pos_type == "rotary" else (lambda part, sin, cos, dr: part)
         sin, cos = rotary_sincos(positions, dr, cfg.rope_theta,
                                  inv_freq=yarn_inv_freq(dr, cfg.rope_theta, scaling) if scaling else None)
         softmax_scale = (dn + dr) ** -0.5
@@ -946,15 +989,15 @@ class LatentAttention(nn.Module):
             if table != 1.0:
                 sin, cos = sin * table, cos * table
         # Interleaved pairs (0,1), (2,3), ... as the published code rotates them.
-        k_rope = apply_rotary(kv_a[:, :, None, rank:], sin, cos, dr)[:, :, 0]
+        k_rope = rotate(kv_a[:, :, None, rank:], sin, cos, dr)[:, :, 0]
         params = lambda feats, name, fan_in: HeadParams(
             feats, param_dtype=cfg.params_dtype, use_bias=False, draw_dtype=cfg.draw_dtype, name=name)(fan_in, b * q_len)[0].astype(dtype)
-        w_qb = params(h * (dn + dr), "q_b_proj", cfg.q_lora_rank).reshape(cfg.q_lora_rank, h, dn + dr)
+        w_qb = params(h * (dn + dr), "q_b_proj" if cfg.q_lora_rank else "q_proj", q_in).reshape(q_in, h, dn + dr)
         w_kvb = params(h * (dn + dv), "kv_b_proj", rank).reshape(rank, h, dn + dv)
 
         def queries(c_q, sin, cos):
             q = jnp.einsum("btc,chn->bthn", c_q, w_qb)
-            return q[..., :dn], apply_rotary(q[..., dn:], sin, cos, dr)
+            return q[..., :dn], rotate(q[..., dn:], sin, cos, dr)
 
         new_cache = None
         if cache is not None:
@@ -1040,9 +1083,9 @@ class Block(nn.Module):
     """One transformer block; sequential (gpt2) or parallel (gptj/neox)
     residual; with `cfg.sandwich_norm` a second norm (`ln_1_out`, `ln_2_out`)
     on each branch's output. `ffn` is this layer's feed-forward kind ("dense" | "experts"),
-    `mixer` its mixer kind ("attention" | "mamba": models/ssm.py, whose cache
-    is a state and which reads `token_mask` [b, q_len], the real tokens of
-    `x`, in place of a bias). Returns (x, cache, expert_counts): the tokens
+    `mixer` its mixer kind ("attention" | "mamba": models/ssm.py | "kda":
+    models/kda.py; the last two keep a state for a cache and read `token_mask`
+    [b, q_len], the real tokens of `x`, in place of a bias). Returns (x, cache, expert_counts): the tokens
     each held expert took in this block, None for a dense one."""
 
     cfg: LMConfig
@@ -1066,6 +1109,10 @@ class Block(nn.Module):
                 from trlx_tpu.models.ssm import SSMMixer
 
                 return SSMMixer(cfg, name="mamba")(h, token_mask, cache)
+            if self.mixer == "kda":
+                from trlx_tpu.models.kda import KDAMixer
+
+                return KDAMixer(cfg, name="kda")(h, token_mask, cache)
             attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
             return attn(h, attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
 
@@ -1324,12 +1371,12 @@ class TransformerLM(nn.Module):
             raise NotImplementedError(
                 "a ring cache takes a prefill at write offset 0 or one token a step at one offset for the "
                 "whole batch (the static generate path): no block table, per-row offset or verify window")
-        if cfg.has_ssm and (segment_ids is not None or (cache is not None and (
+        if cfg.has_state and (segment_ids is not None or (cache is not None and (
                 block_tables is not None or jnp.ndim(cache_index) != 0 or (q_len > 1 and not prefill_at_zero)))):
             raise NotImplementedError(
-                "a state-space layer takes a pass with no cache, a prefill at write offset 0 or one token a step "
-                "for the whole batch (the static generate path): no block table, per-row offset, verify window "
-                "or packed segments")
+                f"a {'state-space' if cfg.has_ssm else 'kda'} layer takes a pass with no cache, a prefill at write "
+                "offset 0 or one token a step for the whole batch (the static generate path): no block table, "
+                "per-row offset, verify window or packed segments")
         if segment_ids is not None:
             # Packed segments need a block-diagonal mask; the flash/ring
             # kernels' (causal × key-validity) masks cannot express that.
@@ -1374,11 +1421,17 @@ class TransformerLM(nn.Module):
             # With state-space layers likewise, on one chip too: merged with
             # the forward, the recomputation keeps every layer's projections
             # and scan products alive, 34.7 GB for a 16 GB chip at 40 layers
-            # (PERF.md §6, PR 32). A looped stack likewise: 48 applications of
-            # 12 blocks kept every gate and up projection alive, 19.5 GB
-            # (PERF.md §6, PR 37).
+            # (PERF.md §6, PR 32); a gated delta-rule layer is held to the same,
+            # and keeps the output of its chunked pass: the pass is recomputed
+            # in its row groups' own backward (models/kda.py), so the block's
+            # recomputation need not run it a third time.
+            # A looped stack likewise: 48 applications of 12 blocks kept every
+            # gate and up projection alive, 19.5 GB (PERF.md §6, PR 37).
+            if cfg.has_kda:
+                kept = jax.checkpoint_policies.save_only_these_names(KDA_SCAN_OUT)
+                policy = kept if policy is None else jax.checkpoint_policies.save_from_both_policies(policy, kept)
             block_cls = nn.remat(
-                Block, prevent_cse=partitioned() or cfg.has_ssm or cfg.n_loops > 1, static_argnums=(7, 8), policy=policy
+                Block, prevent_cse=partitioned() or cfg.has_state or cfg.n_loops > 1, static_argnums=(7, 8), policy=policy
             )
 
         looped = cfg.n_loops > 1
@@ -1417,8 +1470,8 @@ class TransformerLM(nn.Module):
                 layer_cache = cache[loop * cfg.n_layer + i] if cache is not None else None
                 window = layer_window(cfg, i)
                 layer_bias = local_bias if window else attn_bias
-                # a state-space layer reads the tokens' mask itself, in place of a bias
-                token_mask = (attention_mask,) if cfg.mixer(i) == "mamba" else ()
+                # a layer that keeps a state reads the tokens' mask itself, in place of a bias
+                token_mask = (attention_mask,) if cfg.mixer(i) != "attention" else ()
                 x, layer_new_cache, layer_counts = block(
                     x, layer_bias, position_ids, layer_cache, cache_index,
                     flash_mask, window, use_ring, block_tables, *token_mask,
@@ -1606,24 +1659,24 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
     under window_cache "ring"; "mla": per-layer (c_kv [b, T, kv_lora_rank],
     k_rope [b, T, qk_rope_head_dim]), shared by all heads; a "mamba" layer
     (models/ssm.py): (conv [b, ssm_conv - 1, channels], state [b, ssm_heads,
-    ssm_head_dim, ssm_state] float32), no slot axis whatever `max_len`. A
+    ssm_head_dim, ssm_state] float32), no slot axis whatever `max_len`; a
+    "kda" layer (models/kda.py) likewise: (conv [b, kda_conv - 1, 3 x channels],
+    state [b, kda_heads, kda_head_dim, kda_head_dim] float32), beside the
+    latent leaves of the "mla" layers of the same stack. A
     looped stack (n_loops > 1) keeps n_loops * n_layer groups, entry
     loop * n_layer + layer: loop r's layer reads what loop r's layer wrote."""
-    if cfg.attention == "mla":
-        dtype = dtype or cfg.compute_dtype
-        return tuple(
-            (jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype), jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype))
-            for _ in range(cfg.n_layer)
-        )
     if cfg.kv_cache_quant:
         assert dtype is None, "kv_cache_quant caches are int8; dtype not honored"
     dtype = dtype or cfg.compute_dtype
 
     def layer(i):
-        if cfg.mixer(i) == "mamba":
-            from trlx_tpu.models.ssm import cache_shapes
+        if cfg.mixer(i) != "attention":
+            from trlx_tpu.models import kda, ssm
 
-            return tuple(jnp.zeros(shape, leaf_dtype) for shape, leaf_dtype in cache_shapes(cfg, batch))
+            shapes = (ssm if cfg.mixer(i) == "mamba" else kda).cache_shapes(cfg, batch)
+            return tuple(jnp.zeros(shape, leaf_dtype) for shape, leaf_dtype in shapes)
+        if cfg.attention == "mla":
+            return jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype), jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype)
         sshape = (batch, ring_slots(cfg, i, max_len) or max_len, cfg.kv_heads)
         shape = sshape + (cfg.head_dim,)
         if cfg.kv_cache_quant:
@@ -1647,10 +1700,10 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
     reserved by the engine pool) absorbs dead rows' clamped writes — masked
     reads weight stale content by an exact softmax zero, which only stays
     zero if the content (values AND scales) is finite."""
-    if cfg.attention != "mha" or cfg.window_cache != "span" or cfg.has_ssm or cfg.n_loops > 1:
+    if cfg.attention != "mha" or cfg.window_cache != "span" or cfg.has_state or cfg.n_loops > 1:
         raise NotImplementedError(
             f"the paged pool is not built for attention {cfg.attention!r}, window_cache {cfg.window_cache!r}, a "
-            "state-space layer (a state has no slots to page) or a looped stack (one table a layer, where a "
+            "state-space or kda layer (a state has no slots to page) or a looped stack (one table a layer, where a "
             "looped stack keeps keys a (loop, layer) pair)")
     shape = (n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_cache_quant:
@@ -1676,13 +1729,13 @@ def cache_partition_spec(cfg: LMConfig, leaf_ndim: int, layer: int = 0):
     batch over the data axes, heads over tp (grouped keys: the kv_heads; a
     ring layer's leaves have the same axes, fewer slots). An "mla" cache has
     no head axis: one latent a token serves every head, so it is whole on
-    every tp shard. A "mamba" layer: the state's rows over the data axes and
-    its heads over tp, the convolution's window whole on every tp shard."""
+    every tp shard. A "mamba" or "kda" layer: the state's rows over the data
+    axes and its heads over tp, the convolution's window whole on every tp shard."""
     from jax.sharding import PartitionSpec
 
     from trlx_tpu.parallel.mesh import AXIS_TP, DATA_AXES
 
-    if cfg.mixer(layer % cfg.n_layer) == "mamba":
+    if cfg.mixer(layer % cfg.n_layer) != "attention":
         return PartitionSpec(DATA_AXES, AXIS_TP, None, None) if leaf_ndim == 4 else PartitionSpec(DATA_AXES, None, None)
     if cfg.attention == "mla":
         return PartitionSpec(DATA_AXES, None, None)
@@ -1699,11 +1752,11 @@ def cache_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
 
 
 def state_bytes(cfg: LMConfig, batch: int) -> int:
-    """The part of `cache_bytes` that the state-space layers hold (state and
-    convolution window, whatever the length): the counter
-    `rollout/state_bytes`, from `init_cache`'s own shapes."""
+    """The part of `cache_bytes` that the layers with a recurrent state hold
+    ("mamba", "kda": state and convolution window, whatever the length): the
+    counter `rollout/state_bytes`, from `init_cache`'s own shapes."""
     cache = jax.eval_shape(lambda: init_cache(cfg, batch, 1))
-    return tree_size_bytes([cache[i] for i in range(cfg.n_layer) if cfg.mixer(i) == "mamba"])
+    return tree_size_bytes([cache[i] for i in range(cfg.n_layer) if cfg.mixer(i) != "attention"])
 
 
 def cache_bytes_per_token(cfg: LMConfig) -> int:
@@ -1727,8 +1780,8 @@ def decode_step_bytes(cfg: LMConfig, batch: int, keys_read: float, weight_bytes:
     blocks' weights (`stack_bytes` of `weight_bytes`) once a LOOP: the stack
     does not stay on chip between loops; its keys are a (loop, layer) pair's,
     which `cache_bytes_per_token` counts. The counters
-    `rollout/step_bytes_needed`, `ssm/state_rw_share` and
-    `loops/weight_read_share`."""
+    `rollout/step_bytes_needed`, `ssm/state_rw_share` (a "kda" stack:
+    `kda/state_rw_share`) and `loops/weight_read_share`."""
     state = 2 * state_bytes(cfg, batch)
     keys = int(keys_read * batch * cache_bytes_per_token(cfg))
     return weight_bytes + (cfg.n_loops - 1) * stack_bytes + state + keys, state

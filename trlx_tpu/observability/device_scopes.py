@@ -70,6 +70,11 @@ SCOPES = (
     "ssm_scan",  # its chunked scan (or one recurrence step)
     "ssm_gate",  # its gated norm
     "ssm_out",  # its output projection
+    "kda_in",  # gated delta-rule mixer: the q/k/v, decay, strength and gate projections
+    "kda_conv",  # its three causal convolutions and the L2 norm of q and k
+    "kda_scan",  # its chunked pass (or one recurrence step)
+    "kda_gate",  # its gated per-head norm
+    "kda_out",  # its output projection
     "loop_norm",  # a looped stack: the final norm at the end of every loop, the next loop's input
     "exit_gate",  # a looped stack: the exit gate on each loop's output and the exit distribution
     "lm_head",  # the vocabulary head in every form: fused log-probs, dense logits, ILQL's Q heads

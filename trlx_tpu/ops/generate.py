@@ -109,7 +109,8 @@ def generate(
 
         data = int(mesh.shape[mesh_mod.AXIS_DP]) * int(mesh.shape[mesh_mod.AXIS_FSDP])
         tp = int(mesh.shape[mesh_mod.AXIS_TP])
-        if B % data == 0 and cfg.kv_heads % tp == 0 and (not cfg.has_ssm or cfg.ssm_heads % tp == 0):
+        if (B % data == 0 and cfg.kv_heads % tp == 0 and (not cfg.has_ssm or cfg.ssm_heads % tp == 0)
+                and (not cfg.has_kda or cfg.kda_heads % tp == 0)):
             cache = tuple(
                 jax.tree_util.tree_map(
                     lambda x, i=i: jax.lax.with_sharding_constraint(

@@ -262,7 +262,7 @@ class PPOOrchestrator(Orchestrator):
         # would have touched (ops/kv_read.py): from shapes and step counts.
         lm_cfg = rl.model.cfg
         n_soft = lm_cfg.n_soft_tokens
-        # the layers that keep keys: a state-space layer reads none
+        # the layers that keep keys: a state-space or kda layer reads none
         # (a looped stack: every (loop, layer) entry of the cache is read a step)
         key_layers = [i for i in range(lm_cfg.n_layer) if lm_cfg.mixer(i) == "attention"] * lm_cfg.n_loops
         layer_windows = [layer_window(lm_cfg, i) for i in key_layers]
@@ -544,7 +544,7 @@ class PPOOrchestrator(Orchestrator):
                     lm_cfg.n_head, lm_cfg.kv_heads, lm_cfg.head_dim)
             if experts_touched:
                 rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
-            if (lm_cfg.has_ssm or lm_cfg.n_loops > 1) and cache_alloc:
+            if (lm_cfg.has_state or lm_cfg.n_loops > 1) and cache_alloc:
                 # What a decode step must move, from shapes: the weights once (a
                 # looped stack's blocks once a loop), the state read and written,
                 # the keys the ranged read took (the mean over the rollout's steps).
@@ -558,11 +558,11 @@ class PPOOrchestrator(Orchestrator):
                                              if k != "exit_gate" and (k != "wte" or lm_cfg.tie_word_embeddings)})
                 needed, state_rw = decode_step_bytes(lm_cfg, gen_rows, keys_a_step, read_once, stack)
                 rl._last_exp_stats["rollout/step_bytes_needed"] = float(needed)
-                if lm_cfg.has_ssm:
+                if lm_cfg.has_state:
                     rl._last_exp_stats.update({
                         "rollout/state_bytes": float(state_bytes(lm_cfg, gen_rows)),
                         "rollout/state_bytes_per_row": float(state_bytes(lm_cfg, 1)),
-                        "ssm/state_rw_share": float(state_rw / needed),
+                        ("ssm" if lm_cfg.has_ssm else "kda") + "/state_rw_share": float(state_rw / needed),
                     })
                 if lm_cfg.n_loops > 1:
                     rl._last_exp_stats.update({

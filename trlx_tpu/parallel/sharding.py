@@ -65,6 +65,15 @@ def lm_partition_rules() -> List[Tuple[str, P]]:
         (r"mamba/in_proj/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"mamba/out_proj/kernel$", P(AXIS_TP, AXIS_FSDP)),
         (r"mamba/(conv_kernel|conv_bias|dt_bias|A_log|D|norm_scale)$", P()),
+        # gated delta-rule mixer (models/kda.py): the head-wide projections as
+        # the dense kernels they are; the two bottlenecks' narrow side, the
+        # per-head strength, the convolutions and the vectors whole
+        (r"kda/(q_proj|k_proj|v_proj)/kernel$", P(AXIS_FSDP, AXIS_TP)),
+        (r"kda/(f_b_proj|g_b_proj)/kernel$", P(None, AXIS_TP)),
+        (r"kda/g_b_proj/bias$", P(AXIS_TP)),
+        (r"kda/(f_a_proj|g_a_proj|b_proj)/kernel$", P(AXIS_FSDP, None)),
+        (r"kda/o_proj/kernel$", P(AXIS_TP, AXIS_FSDP)),
+        (r"kda/(q_conv|k_conv|v_conv|dt_bias|A_log|o_norm)$", P()),
         # MLP up [d_model, d_ff] column-parallel
         (r"mlp/c_fc/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"mlp/c_fc/bias$", P(AXIS_TP)),

@@ -18,6 +18,7 @@ import optax
 from trlx_tpu.data import PackedPPOBatch, PPORLBatch
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.fleet import FleetDegradedExit, validate_fleet_config
+from trlx_tpu.models import kda
 from trlx_tpu.models.heads import LMWithValueHead, branch_replay_params, extract_branch_params
 from trlx_tpu.models.ssm import lane_fill
 from trlx_tpu.ops.fused_logprob import count_head_calls, fused_logprob_eligible, take_head_call_scalars
@@ -109,9 +110,10 @@ class PPOTrainer(JaxBaseTrainer):
         # train-throughput metering for the phase window (satellite of the
         # fused-logprob head work; see make_ppo_train_step).
         self._pack_train_batch = bool(getattr(m, "pack_train_batch", False))
-        if self._pack_train_batch and self.model.cfg.n_loops > 1:
+        if self._pack_train_batch and (self.model.cfg.n_loops > 1 or self.model.cfg.has_kda):
             raise NotImplementedError(
-                "method.pack_train_batch (packed segments) is not built for a looped stack (n_loops > 1)")
+                "method.pack_train_batch (packed segments) is not built for a looped stack (n_loops > 1) or a kda "
+                "layer (a state crosses a segment's edge)")
         # put_batch shards the leading dim over DATA_AXES — packed row-count
         # buckets must round up to a multiple of that axis product.
         self._pack_rows_multiple = int(np.prod([self.mesh.shape[a] for a in DATA_AXES]))
@@ -233,13 +235,13 @@ class PPOTrainer(JaxBaseTrainer):
             from trlx_tpu.models.lm import quantize_weights
 
             lm_cfg = self.model.cfg
-            if (lm_cfg.attention != "mha" or lm_cfg.mlp != "dense" or "experts" in lm_cfg.ffn_layers or lm_cfg.has_ssm
+            if (lm_cfg.attention != "mha" or lm_cfg.mlp != "dense" or "experts" in lm_cfg.ffn_layers or lm_cfg.has_state
                     or lm_cfg.n_loops > 1):
                 raise ValueError(
                     "model.decode_weight_quant covers the GPT block's kernels only "
                     "(models/lm.py QUANT_KERNEL_NAMES): it is not built for attention "
-                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r}, expert layers, state-space layers or a looped "
-                    "stack (n_loops > 1)"
+                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r}, expert layers, state-space layers, kda layers or a "
+                    "looped stack (n_loops > 1)"
                 )
 
             self._quantize_fn = self._wrap_monitored(
@@ -958,6 +960,9 @@ class PPOTrainer(JaxBaseTrainer):
             stats["ssm/pad_share"] = float(np.mean(window_pad))
             stats["ssm/chunks_per_pass"] = float(-(-int(self.config.train.seq_length) // lm_cfg.ssm_chunk))
             stats["ssm/lane_fill"] = lane_fill(lm_cfg, int(self.config.train.seq_length))
+        if self.model.cfg.has_kda and window_tokens:
+            # chunks of the delta-rule pass over one row of a train batch
+            stats["kda/chunks_per_pass"] = float(-(-int(self.config.train.seq_length) // kda.CHUNK))
         if self._last_exp_stats:
             stats.update(self._last_exp_stats)
         stats.update(take_head_call_scalars(self._head_calls["score"], "score"))
