@@ -117,12 +117,67 @@ def test_bf16_operands_keep_the_state_and_the_sums_float32():
     assert 1e-5 < float(jnp.abs(o - want).max()) < 0.05 and float(jnp.abs(last - last_want).max()) < 0.05
 
 
-def test_the_unit_lower_inverse():
-    m = 0.2 * jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (2, 3, 64, 64)), -1)  # beta k . k: within (-1, 1)
+def _substituted_inverse(m, sub):
+    """The parent's `unit_lower_inverse` (PR 39), kept as the reference of the hand-written backward:
+    row-by-row substitution in the diagonal blocks, then block elimination, differentiated step by step."""
+    blocks, hi = m.shape[-1] // sub, jax.lax.Precision.HIGHEST
+    diag = jnp.stack([m[..., i * sub:(i + 1) * sub, i * sub:(i + 1) * sub] for i in range(blocks)], axis=-3)
+    eye = jnp.eye(sub, dtype=m.dtype)
+    rows = []
+    for r in range(sub):
+        row = jnp.broadcast_to(eye[r], diag.shape[:-2] + (sub,))
+        if r:
+            row = row - jnp.einsum("...s,...sc->...c", diag[..., r, :r], jnp.stack(rows, axis=-2), precision=hi)
+        rows.append(row)
+    diag_inv = jnp.stack(rows, axis=-2)
+    inv = diag_inv[..., 0, :, :]
+    for i in range(1, blocks):
+        below, own = m[..., i * sub:(i + 1) * sub, :i * sub], diag_inv[..., i, :, :]
+        corner = -jnp.matmul(own, jnp.matmul(below, inv, precision=hi), precision=hi)
+        top = jnp.concatenate([inv, jnp.zeros(inv.shape[:-1] + (sub,), m.dtype)], axis=-1)
+        inv = jnp.concatenate([top, jnp.concatenate([corner, own], axis=-1)], axis=-2)
+    return inv
+
+
+@pytest.mark.parametrize("size, sub", [(16, 16), (32, 16), (48, 16), (64, 16), (8, 8), (64, 32)],
+                         ids=["one block of 16", "two", "three", "a chunk of four", "a chunk shorter than a sub-block", "two of 32"])
+def test_the_unit_lower_inverse(size, sub):
+    """Against float64, exactly lower triangular, and the hand-written backward (two products)
+    against autodiff through the parent's substitution, on the strictly lower part (the only
+    part of `m` either reads)."""
+    m = 0.2 * jnp.tril(jax.random.normal(jax.random.PRNGKey(size + sub), (2, 3, size, size)), -1)  # beta k . k: within (-1, 1)
+    weight = jax.random.normal(jax.random.PRNGKey(1), m.shape)
     with jax.default_matmul_precision("highest"):
-        inv = kda.unit_lower_inverse(m, 16)
-        np.testing.assert_allclose(inv @ (jnp.eye(64) + m), jnp.broadcast_to(jnp.eye(64), m.shape), atol=1e-4)
+        inv = jax.jit(lambda m: kda.unit_lower_inverse(m, sub))(m)
+        np.testing.assert_allclose(inv @ (jnp.eye(size) + m), jnp.broadcast_to(jnp.eye(size), m.shape), atol=1e-4)
+        got = jax.jit(jax.grad(lambda m: jnp.sum(kda.unit_lower_inverse(m, sub) * weight)))(m)
+        want = jax.jit(jax.grad(lambda m: jnp.sum(_substituted_inverse(m, sub) * weight)))(m)
+    want64 = np.linalg.inv(np.eye(size) + np.asarray(m, np.float64))
+    assert float(np.abs(inv - want64).max()) < 1e-5 * float(np.abs(want64).max())
     assert float(jnp.abs(jnp.triu(inv, 1)).max()) == 0.0
+    assert float(jnp.abs(jnp.triu(got)).max()) == 0.0  # nothing reads the rest of m: its gradient is 0, not garbage
+    assert float(jnp.abs(jnp.tril(got - want, -1)).max()) < 1e-5 * float(jnp.abs(want).max())
+
+
+def test_the_unit_lower_inverse_on_keys_that_share_their_direction():
+    """A model that repeats a token hands a chunk keys that are nearly one vector: I + tril(K K^T, -1)
+    is then all ones under its diagonal and a series form of the inverse (the Neumann product
+    (I - N)(I + N^2)(I + N^4)..., exact in exact arithmetic) is wrong by 1e11 of max|T| in float32
+    where substitution and block elimination read 3e-7 (ISSUE 40)."""
+    rng = np.random.default_rng(0)
+    base, noise = rng.standard_normal((2, 4, 1, 128)), rng.standard_normal((2, 4, 64, 128))
+    k = 0.98 * base + 0.02 * noise
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    m = np.tril(k @ np.swapaxes(k, -1, -2), -1)  # beta = 1
+    want = np.linalg.inv(np.eye(64) + m)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda m: kda.unit_lower_inverse(m, 16))(jnp.asarray(m, jnp.float32))
+    assert float(np.abs(got - want).max()) < 1e-5 * float(np.abs(want).max())
+    series, power = np.eye(64, dtype=np.float32) - m.astype(np.float32), m.astype(np.float32)
+    for _ in range(5):  # (I - N)(I + N^2)(I + N^4)...(I + N^32): N^64 = 0
+        power = power @ power
+        series = series @ (np.eye(64, dtype=np.float32) + power)
+    assert float(np.abs(series - want).max()) > 1e3 * float(np.abs(want).max())  # what this case is here to refuse
 
 
 # ---- (b) the whole trunk against the reference, rows left-padded into one batch ----------------
@@ -487,6 +542,9 @@ def test_cache_shapes_and_counters_by_hand():
     needed, rw = decode_step_bytes(cfg, rows, keys_read=10, weight_bytes=1000)
     assert rw == 2 * 4 * (state + conv) and needed == 1000 + rw + 10 * rows * (16 + 8) * 2
     assert cfg.has_kda and cfg.has_state and not cfg.has_ssm
+    # the triangular solve: a chunk's and a sub-block's positions, and the share of a 128-lane tile a row of the substitution fills
+    assert [kda.chunk_sizes(1024), kda.chunk_sizes(45), kda.chunk_sizes(29, 32), kda.chunk_sizes(8)] == [(64, 16), (48, 16), (32, 16), (16, 16)]
+    assert kda.solve_lane_fill(1024) == kda.solve_lane_fill(8) == 16 / 128
     from jax.sharding import PartitionSpec
 
     from trlx_tpu.parallel.mesh import AXIS_TP, DATA_AXES
@@ -593,3 +651,4 @@ def test_ppo_two_iterations_on_the_normal_path(tmp_path):
     assert all(0 < p["kda/state_rw_share"] < 1 and p["rollout/step_bytes_needed"] > 2 * 8 * row for p in phases)
     assert not any("ssm/state_rw_share" in p or "ssm/pad_share" in p for p in phases)
     assert any(p.get("kda/chunks_per_pass") == 1 for p in records)  # 32 positions, under one chunk of 64
+    assert all(p["kda/solve_lane_fill"] == 0.125 for p in records if "kda/chunks_per_pass" in p)  # a row of a 16-wide sub-block

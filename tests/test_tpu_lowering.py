@@ -258,6 +258,34 @@ def test_both_forms_of_the_kda_mixer_compile_for_v5e(v5e_sharding):
     assert state.dtype == jnp.float32 and state.shape == (32, 32, 128, 128) and conv.shape == (32, 3, 12288)
 
 
+def test_the_delta_rule_pass_does_not_hand_its_inverse_to_autodiff(v5e_sharding):
+    """One row of `kda_chunked` at Kimi-Linear's train shapes ([1, 1024], 32
+    heads of 128, bf16 operands, chunks of 64: what one step of the mixer's
+    `lax.map` runs), forward and gradient in all five operands, as the v5e's
+    compiler leaves it. With the parent's `unit_lower_inverse` (PR 39: sixteen
+    substitution steps each against the rows so far stacked, three growing
+    eliminations, all of it differentiated step by step) the gradient's
+    program was 291 fusions and 9.48 GB by the compiler's count of bytes, the
+    forward's 125 fusions. With the backward pass two products and the
+    eliminations two rounds of pairs (PERF.md section 6, PR 40) they are 198
+    fusions, 7.91 GB and 113: an edit that hands the inverse back to autodiff
+    fails here and not in a benchmark. (The counts are the compiler's, not
+    the chip's: a form that read 180 fusions and 7.35 GB here, the systems
+    along the lanes, took twice the parent's time on the chip, same section.)"""
+    from trlx_tpu.models import kda
+
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+    shape = (1, 1024, 32, 128)
+    ops = (s(shape, jnp.bfloat16),) * 3 + (s(shape, jnp.float32), s(shape[:3], jnp.float32))
+    forward = lambda *ops: kda.kda_chunked(*ops, kda.CHUNK, jnp.bfloat16)[0]
+    gradient = lambda w, *ops: jax.grad(lambda *ops: (forward(*ops) * w).sum(), argnums=(0, 1, 2, 3, 4))(*ops)
+    compiled = jax.jit(forward).lower(*ops).compile()
+    assert compiled.as_text().count(" fusion(") < 125
+    compiled = jax.jit(gradient).lower(s(shape, jnp.float32), *ops).compile()
+    assert compiled.as_text().count(" fusion(") < 240
+    assert compiled.cost_analysis()["bytes accessed"] < 8.7e9
+
+
 def test_the_chunked_scan_relays_no_float32_array_64_lanes_wide(v5e_sharding):
     """`ssd_chunked` forward and gradient at granite-4.0-h-micro's train
     shapes ([8, 1024], 64 heads of 64, state 128, chunks of 256, bf16), as the

@@ -39,7 +39,13 @@ the LATER block's start, exp(G_t - start) exp(start - G_s), both halves at or
 below 0, one product a block row; inside a sub-block the [SUB, SUB, D]
 differences are formed and summed as they are. No `exp` of a positive sum is
 ever taken. T comes from forward substitution in the SUB x SUB diagonal blocks
-and block elimination over them, in float32.
+(a row a step, every block of the call at once) and block elimination over
+them in pairs (16 -> 32 -> 64, the pairs of a round at once), in float32 with
+`Precision.HIGHEST` products (`unit_lower_inverse`); no series form: on keys
+that share their direction those are wrong by orders of magnitude. Its
+backward pass is hand-written (`jax.custom_vjp`): dM = -T^T dT T^T on the
+strictly lower triangle, two products on the T the forward pass kept, not
+autodiff through the substitution's steps (PERF.md section 6, PR 40).
 *Recurrent* (`kda_step`: one token, a decode step): the update above, all in
 float32, on the cache's state.
 
@@ -53,6 +59,7 @@ and the state in FLOAT32. No slot axis, no write offset: each step overwrites
 both whole.
 """
 
+import functools
 import math
 
 import flax.linen as nn
@@ -77,6 +84,7 @@ SCAN_TOKENS = 1024
 CHUNK = 64  # positions of a chunk: one triangular solve, one step of the scan that carries the state
 SUB = 16  # positions of a sub-block: where the within-chunk decay is re-based
 NORM_EPS = 1e-6  # of the L2 norm of q and k
+HIGHEST = jax.lax.Precision.HIGHEST  # of every product that builds T or takes its gradient: float32 operands as they are
 
 
 def inner_width(cfg: LMConfig) -> int:
@@ -88,6 +96,24 @@ def cache_shapes(cfg: LMConfig, batch: int):
     """((shape, dtype), (shape, dtype)) of one layer's (conv, state) leaves."""
     return (((batch, cfg.kda_conv - 1, 3 * inner_width(cfg)), cfg.compute_dtype),
             ((batch, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim), jnp.dtype(jnp.float32)))
+
+
+def chunk_sizes(tokens: int, chunk: int = CHUNK):
+    """(positions of a chunk, of a sub-block) over `tokens` positions: a pass
+    shorter than a chunk is one chunk of whole sub-blocks."""
+    size = min(chunk, -(-tokens // SUB) * SUB)
+    return size, math.gcd(size, SUB)
+
+
+def solve_lane_fill(tokens: int) -> float:
+    """Share of a 128-lane tile the row arrays of the triangular solve's
+    substitution fill in a pass over `tokens` positions: a row of a sub-block's
+    inverse is a sub-block wide (`_diagonal_inverses`), 16 / 128 = 0.125. With
+    the systems along the lanes it would be 1.0, and the pass took twice the
+    time on the chip (PERF.md section 6, PR 40): the counter names the form,
+    it is not a score."""
+    sub = chunk_sizes(tokens)[1]
+    return sub / (-(-sub // 128) * 128)
 
 
 def a_log_init(key, shape, dtype=jnp.float32):
@@ -109,30 +135,73 @@ def kda_step(state, q, k, v, g, beta):
     return o, decayed + k[..., None] * u[..., None, :]
 
 
-def unit_lower_inverse(m, sub: int):
-    """(I + m)^-1 of strictly lower triangular `m` [..., C, C] float32, C a
-    multiple of `sub`: forward substitution inside the sub x sub diagonal
-    blocks (every block at once, a row a step), then block elimination:
-    [[L, 0], [m21, L2]]^-1 = [[L^-1, 0], [-L2^-1 m21 L^-1, L2^-1]]."""
-    size = m.shape[-1]
-    blocks = size // sub
-    hi = jax.lax.Precision.HIGHEST
-    diag = jnp.stack([m[..., i * sub:(i + 1) * sub, i * sub:(i + 1) * sub] for i in range(blocks)], axis=-3)
-    eye = jnp.eye(sub, dtype=m.dtype)
-    rows = []
-    for r in range(sub):
-        row = jnp.broadcast_to(eye[r], diag.shape[:-2] + (sub,))
-        if r:
-            row = row - jnp.einsum("...s,...sc->...c", diag[..., r, :r], jnp.stack(rows, axis=-2), precision=hi)
-        rows.append(row)
-    diag_inv = jnp.stack(rows, axis=-2)  # [..., blocks, sub, sub]
-    inv = diag_inv[..., 0, :, :]
-    for i in range(1, blocks):
-        below, own = m[..., i * sub:(i + 1) * sub, :i * sub], diag_inv[..., i, :, :]
-        corner = -jnp.matmul(own, jnp.matmul(below, inv, precision=hi), precision=hi)
-        top = jnp.concatenate([inv, jnp.zeros(inv.shape[:-1] + (sub,), m.dtype)], axis=-1)
-        inv = jnp.concatenate([top, jnp.concatenate([corner, own], axis=-1)], axis=-2)
+def _diagonal_inverses(diag):
+    """(I + diag)^-1 of strictly lower triangular blocks [..., sub, sub] by
+    forward substitution, every block at once, a row a step: row r of the
+    inverse is its unit vector less row r of `diag` times the rows above it,
+    written into its place in the one array the steps share."""
+    sub = diag.shape[-1]
+    inv = jnp.broadcast_to(jnp.eye(sub, dtype=diag.dtype), diag.shape)
+    for r in range(1, sub):
+        above = jnp.einsum("...s,...sc->...c", diag[..., r, :r], inv[..., :r, :], precision=HIGHEST)
+        inv = inv.at[..., r, :].add(-above)
     return inv
+
+
+def _eliminated(first, below, second):
+    """[[F, 0], [b, S]]^-1 = [[F^-1, 0], [-S^-1 b F^-1, S^-1]] from the
+    diagonal blocks' inverses `first` [..., p, p] and `second` [..., q, q]."""
+    corner = -jnp.matmul(second, jnp.matmul(below, first, precision=HIGHEST), precision=HIGHEST)
+    top = jnp.concatenate([first, jnp.zeros(first.shape[:-1] + second.shape[-1:], first.dtype)], axis=-1)
+    return jnp.concatenate([top, jnp.concatenate([corner, second], axis=-1)], axis=-2)
+
+
+def _unit_lower_inverse(m, sub: int):
+    """The forward pass of `unit_lower_inverse`. The diagonal blocks' inverses
+    are merged in rounds of pairs, every pair of a round at once (a chunk of
+    four blocks: 16 -> 32 -> 64); a block without a partner joins the tail,
+    the inverse of m's last rows and columns."""
+    size = m.shape[-1]
+    inv = _diagonal_inverses(jnp.stack([m[..., i:i + sub, i:i + sub] for i in range(0, size, sub)], axis=-3))
+    tail, width = None, sub
+    while True:
+        blocks = inv.shape[-3]
+        if blocks % 2:
+            at, last = (blocks - 1) * width, inv[..., -1, :, :]
+            tail = last if tail is None else _eliminated(last, m[..., at + width:, at:at + width], tail)
+            if blocks == 1:
+                return tail
+            inv = inv[..., :-1, :, :]
+        pairs = range(0, blocks // 2 * 2 * width, 2 * width)
+        below = jnp.stack([m[..., i + width:i + 2 * width, i:i + width] for i in pairs], axis=-3)
+        inv = _eliminated(inv[..., 0::2, :, :], below, inv[..., 1::2, :, :])
+        width *= 2
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def unit_lower_inverse(m, sub: int):
+    """T = (I + m)^-1 of strictly lower triangular `m` [..., C, C] float32, C a
+    multiple of `sub`: forward substitution inside the sub x sub diagonal
+    blocks (`_diagonal_inverses`), then block elimination:
+    [[L, 0], [m21, L2]]^-1 = [[L^-1, 0], [-L2^-1 m21 L^-1, L2^-1]].
+    Its backward pass is written by hand, dm = -T^T dT T^T: two products on
+    the T the forward pass returns, where autodiff would walk back through
+    every substitution step, slice and concatenation. Only the strictly lower
+    part of `m` is read, so only that part has a gradient."""
+    return _unit_lower_inverse(m, sub)
+
+
+def _unit_lower_inverse_fwd(m, sub):
+    inv = _unit_lower_inverse(m, sub)
+    return inv, inv
+
+
+def _unit_lower_inverse_bwd(sub, inv, ct):
+    inv_t = jnp.swapaxes(inv, -1, -2)
+    return (jnp.tril(-jnp.matmul(inv_t, jnp.matmul(ct, inv_t, precision=HIGHEST), precision=HIGHEST), -1),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
 def _diagonal_blocks(q, k, cum):
@@ -156,8 +225,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int, dtype):
     float32)."""
     b, T, H, D = q.shape
     f32 = jnp.float32
-    C = min(chunk, -(-T // SUB) * SUB)
-    sub = math.gcd(C, SUB)
+    C, sub = chunk_sizes(T, chunk)
     pad = -T % C
     if pad:  # g 0, beta 0: the added positions leave the state as it is
         q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (q, k, v, g, beta))

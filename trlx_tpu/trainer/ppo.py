@@ -963,6 +963,7 @@ class PPOTrainer(JaxBaseTrainer):
         if self.model.cfg.has_kda and window_tokens:
             # chunks of the delta-rule pass over one row of a train batch
             stats["kda/chunks_per_pass"] = float(-(-int(self.config.train.seq_length) // kda.CHUNK))
+            stats["kda/solve_lane_fill"] = kda.solve_lane_fill(int(self.config.train.seq_length))
         if self._last_exp_stats:
             stats.update(self._last_exp_stats)
         stats.update(take_head_call_scalars(self._head_calls["score"], "score"))
