@@ -19,6 +19,8 @@ CPU-only `make lint` CI job) can import the package without the accelerator
 stack.
 """
 
+IMPORT_T0 = __import__("time").time()  # where `setup/import_s` counts from (utils/startup.py)
+
 __version__ = "0.1.0"
 
 __all__ = ["train", "__version__"]

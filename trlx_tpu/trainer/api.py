@@ -18,6 +18,7 @@ import trlx_tpu.pipeline.prompt_pipeline  # noqa: F401
 from trlx_tpu.orchestrator import get_orchestrator
 from trlx_tpu.pipeline.prompt_pipeline import PromptPipeline
 from trlx_tpu.trainer import get_model
+from trlx_tpu.utils.startup import mark_imported
 
 _CONFIG_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "configs")
 
@@ -140,3 +141,6 @@ def train(
 
     model.learn()
     return model
+
+
+mark_imported()  # `setup/import_s` ends here: everything a run imports before it builds a trainer

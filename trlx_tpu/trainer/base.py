@@ -26,14 +26,15 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-# Eager, not lazy-in-method: orbax's first import costs ~4 s and transformers'
-# ~5-6 s on one CPU core; paying them at package-import time (the reference
-# also imports transformers at module scope,
-# reference: trlx/model/accelerate_base_model.py:12-20) instead of inside the
-# first checkpoint / tokenizer build keeps those latencies honest.
-import orbax.checkpoint as ocp
+# transformers and orbax.checkpoint are not imported here: together they
+# were two thirds of this module's import (PERF.md section 6, PR 42), and a
+# run built from `model_arch` that schedules no save calls neither. Each
+# loads where it is first called (`_build_tokenizer`, `_checkpointer`) under
+# a `setup/import` span (utils/startup.py): a run that names a tokenizer, a
+# `checkpoint_interval > 0` or a checkpoint to resume pays them in __init__
+# as before, under that name; any other pays orbax's at an explicit `save()`
+# or a preemption save.
 from flax import struct
-from transformers import AutoTokenizer
 
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.heads import trainable_mask
@@ -64,6 +65,7 @@ from trlx_tpu.utils import Clock
 from trlx_tpu.utils import sanitize
 from trlx_tpu.utils.compile_cache import setup_compile_cache
 from trlx_tpu.utils.logging import Tracker
+from trlx_tpu.utils.startup import deferred_import, startup_counters
 
 
 class TrainState(struct.PyTreeNode):
@@ -173,6 +175,42 @@ class JaxBaseTrainer(BaseRLTrainer):
         # (A plain RLock unless TRLX_TPU_SANITIZE=dispatch arms the
         # ownership-asserting variant — utils/sanitize.py.)
         self._dispatch_lock = sanitize.make_dispatch_lock()
+        # ---- observability, the span file (trlx_tpu/observability/): armed
+        # before the tokenizer, the model build and a resume, so that what
+        # they pay (`setup/import`, `ckpt/load`) lands in this run's file.
+        # Env flags override config so a drill can be bolted onto any run
+        # command; everything defaults OFF and the instrumentation stays off
+        # the hot dispatch path.
+        ckpt_dir = os.path.abspath(config.train.checkpoint_dir)
+        # graftscope (attribution ledger + bubble accounting + slot
+        # timeline) needs both the fence hook in DeviceMonitor and the spans
+        # file for its timeline rows, so arming it implies arming those two.
+        graftscope_on = config.train.graftscope or obs.env_flag("TRLX_TPU_GRAFTSCOPE")
+        # graftfleet (cross-host federation) owns the span filename when
+        # armed: each host writes spans.host<k>.jsonl so read_fleet_spans can
+        # merge per-host lanes. Arming it implies span tracing (the merged
+        # trace and the incident span tails are its artifacts).
+        fleet_on = config.train.graftfleet or obs.env_flag("TRLX_TPU_GRAFTFLEET")
+        if (
+            config.train.trace_spans
+            or graftscope_on
+            or fleet_on
+            or obs.env_flag("TRLX_TPU_SPANS")
+        ):
+            obs_spans.configure(
+                os.path.join(
+                    ckpt_dir,
+                    obs_spans.host_spans_filename(jax.process_index())
+                    if fleet_on
+                    else obs_spans.SPANS_FILENAME,
+                ),
+                process_index=jax.process_index(),
+            )
+        else:
+            # Trainer construction owns the process-global tracer: a prior
+            # trainer in this process (tests build several) must not keep
+            # appending this run's thread spans to its old file.
+            obs_spans.shutdown()
         self.tokenizer = self._build_tokenizer(config.model.tokenizer_path)
 
         # Subclass builds the Flax module + initial host params.
@@ -188,7 +226,13 @@ class JaxBaseTrainer(BaseRLTrainer):
         # _maybe_resume — load() finalizes pending saves and restores the
         # resilience host state.
         self.fault_plan = FaultPlan.from_env_or_config(config.train.fault_plan)
-        self._ckptr = ocp.StandardCheckpointer()
+        # The checkpointer exists from its first use on (`_checkpointer`). A
+        # run that schedules saves builds it here, so that orbax's import is
+        # paid in set-up and never inside a save, least of all a preemption
+        # save's grace window; a resume builds it in `load()`, below.
+        self._ckptr = None
+        if config.train.checkpoint_interval > 0:
+            self._checkpointer()
         self._pending_save = None  # at most one async save in flight
         self._save_count = 0
         self._lr_scale = 1.0  # watchdog LR decay multiplier (compounds)
@@ -241,40 +285,8 @@ class JaxBaseTrainer(BaseRLTrainer):
             log_dir=config.train.checkpoint_dir,
         )
 
-        # ---- observability (trlx_tpu/observability/): span tracing, device
-        # telemetry, anomaly capture. Env flags override config so a drill
-        # can be bolted onto any run command; everything defaults OFF and the
-        # instrumentation stays off the hot dispatch path.
-        ckpt_dir = os.path.abspath(config.train.checkpoint_dir)
-        # graftscope (attribution ledger + bubble accounting + slot
-        # timeline) needs both the fence hook in DeviceMonitor and the spans
-        # file for its timeline rows, so arming it implies arming those two.
-        graftscope_on = config.train.graftscope or obs.env_flag("TRLX_TPU_GRAFTSCOPE")
-        # graftfleet (cross-host federation) owns the span filename when
-        # armed: each host writes spans.host<k>.jsonl so read_fleet_spans can
-        # merge per-host lanes. Arming it implies span tracing (the merged
-        # trace and the incident span tails are its artifacts).
-        fleet_on = config.train.graftfleet or obs.env_flag("TRLX_TPU_GRAFTFLEET")
-        if (
-            config.train.trace_spans
-            or graftscope_on
-            or fleet_on
-            or obs.env_flag("TRLX_TPU_SPANS")
-        ):
-            obs_spans.configure(
-                os.path.join(
-                    ckpt_dir,
-                    obs_spans.host_spans_filename(jax.process_index())
-                    if fleet_on
-                    else obs_spans.SPANS_FILENAME,
-                ),
-                process_index=jax.process_index(),
-            )
-        else:
-            # Trainer construction owns the process-global tracer: a prior
-            # trainer in this process (tests build several) must not keep
-            # appending this run's thread spans to its old file.
-            obs_spans.shutdown()
+        # ---- observability, the rest: device telemetry, anomaly capture,
+        # health, fleet, numerics, the metrics endpoint (same rule for flags).
         device_scopes.configure(ckpt_dir)
         obs_spans.install_compile_listener()
         self._devicemon = None
@@ -412,13 +424,21 @@ class JaxBaseTrainer(BaseRLTrainer):
     def _build_tokenizer(self, tokenizer_path: str):
         if not tokenizer_path:
             return None
-        tokenizer = AutoTokenizer.from_pretrained(tokenizer_path)
+        tokenizer = deferred_import("transformers").AutoTokenizer.from_pretrained(tokenizer_path)
         # pad = eos, left padding (reference:
         # trlx/model/accelerate_base_model.py:42-45); padding itself is done
         # by our fixed-shape pipeline, but the ids matter.
         tokenizer.pad_token = tokenizer.eos_token
         tokenizer.padding_side = "left"
         return tokenizer
+
+    def _checkpointer(self):
+        """The orbax checkpointer, built (and orbax imported) at the first
+        call: in `__init__` where the run schedules saves, else at the first
+        `save()` or restore."""
+        if self._ckptr is None:
+            self._ckptr = deferred_import("orbax.checkpoint").StandardCheckpointer()
+        return self._ckptr
 
     def _lr_schedule(self):
         return lr_schedule(self.config.train)
@@ -1394,6 +1414,7 @@ class JaxBaseTrainer(BaseRLTrainer):
         stats_host["time/step_host_ms"] = max(0.0, read.end_s - self._host_t0 - waited) * 1e3
         self._host_t0, self._step_wait_s = read.end_s, 0.0
         stats_host["obs/compiles"] = obs_spans.take_compiles()
+        stats_host.update(startup_counters())
         if self._flash_kept_share is not None:
             stats_host["flash/kept_pair_share"] = self._flash_kept_share
         gather_share = weight_gather_share(self._weight_gathers["train"])
@@ -1799,7 +1820,7 @@ class JaxBaseTrainer(BaseRLTrainer):
                 # coefficient) may have advanced past this checkpoint's step.
                 "host_state": self.host_state_dict(),
             }
-            self._ckptr.save(os.path.join(directory, name), self.state, force=True)
+            self._checkpointer().save(os.path.join(directory, name), self.state, force=True)
             if block:
                 self._finalize_pending_save()
 
@@ -1813,7 +1834,7 @@ class JaxBaseTrainer(BaseRLTrainer):
             return None
         with trace_span("ckpt/finalize", ckpt=pending["name"]):
             directory, name = pending["directory"], pending["name"]
-            self._ckptr.wait_until_finished()
+            self._checkpointer().wait_until_finished()
             if jax.process_count() > 1:
                 # All-hosts-committed barrier: every host's shards are on disk
                 # before rank 0 writes the sidecars and flips latest.txt — the
@@ -1980,7 +2001,7 @@ class JaxBaseTrainer(BaseRLTrainer):
                         attempts.append(f"{name}: {reason}")
                         continue
                     try:
-                        self.state = self._ckptr.restore(path, self.state)
+                        self.state = self._checkpointer().restore(path, self.state)
                     except Exception as e:  # noqa: BLE001 — fall back to older checkpoint
                         attempts.append(f"{name}: orbax restore failed ({type(e).__name__}: {e})")
                         continue

@@ -10,27 +10,41 @@ counterpart of the reference's generic `debug` env switch
 `debug` name is still honored with a deprecation warning for one release.
 """
 
+import importlib.util
 import os
 import sys
 import time
 import warnings
 from typing import Any, Dict, Optional
 
-try:
-    import wandb  # type: ignore
-
-    _HAS_WANDB = True
-except Exception:
-    wandb = None
-    _HAS_WANDB = False
-
 from trlx_tpu.parallel.mesh import is_main_process
 from trlx_tpu.utils import jsonl
+from trlx_tpu.utils.startup import deferred_import
+
+# wandb is looked for here and imported by the first enabled Tracker
+# (`_load_wandb`): a process that builds no tracker never pays its import.
+try:
+    _HAS_WANDB = importlib.util.find_spec("wandb") is not None
+except (ImportError, ValueError):  # ValueError: a stub in sys.modules with no __spec__
+    _HAS_WANDB = False
+wandb = None
 
 # Canonical implementation lives in utils/jsonl (shared with spans/lineage);
 # re-exported here because read_jsonl grew up in this module and external
 # callers import it from here.
 from trlx_tpu.utils.jsonl import read_jsonl  # noqa: F401
+
+
+def _load_wandb():
+    """The wandb module at a tracker's first use, None where it is absent
+    or its import fails (the JSONL fallback then serves alone)."""
+    global wandb, _HAS_WANDB
+    if _HAS_WANDB and wandb is None:
+        try:
+            wandb = deferred_import("wandb")
+        except Exception:
+            _HAS_WANDB = False
+    return wandb if _HAS_WANDB else None
 
 
 def _tracker_disabled() -> bool:
@@ -62,7 +76,7 @@ class Tracker:
         self._stringified_keys = set()  # warned-once registry (log())
         if not self.enabled:
             return
-        if _HAS_WANDB:
+        if _load_wandb() is not None:
             self._wandb = wandb.init(
                 project=project_name, name=run_name, entity=entity_name, config=config
             )
