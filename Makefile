@@ -1,5 +1,5 @@
 # Dev targets (reference: Makefile style/quality; upgraded to ruff).
-.PHONY: test test-fast test-shard1 test-shard2 test-shard3 test-multihost fleet-drill lint typecheck quality style bench bench-reference bench-smoke chip-smoke obs-smoke acceptance-network sanitize-drill
+.PHONY: test test-fast test-shard1 test-shard2 test-shard3 test-multihost fleet-drill lint typecheck quality style chip-smoke acceptance-network sanitize-drill
 
 TEST_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
@@ -75,8 +75,7 @@ SCRIPT_LINT_RULES = GL003,GL004,GL007,GL008,GL009,GL010,GL011
 lint:
 	python -m trlx_tpu.analysis trlx_tpu/
 	python -m trlx_tpu.analysis --select $(SCRIPT_LINT_RULES) \
-	    bench.py bench_smoke.py bench_decode_probe.py bench_reference.py \
-	    bench_trajectory.py chip_smoke.py obs_smoke.py acceptance_network.py
+	    chip_smoke.py acceptance_network.py bench_flash.py bench_kda.py
 
 # graftrace runtime half, fully armed: the thread-heavy suites (resilience
 # fault drills, overlap pipeline, rollout engine) under
@@ -100,15 +99,10 @@ typecheck:
 	fi
 
 quality:
-	ruff check trlx_tpu/ tests/ examples/ bench.py
+	ruff check trlx_tpu/ tests/ examples/
 
 style:
-	ruff format trlx_tpu/ tests/ examples/ bench.py
-
-# On the chip only: bench.py fails without a TPU. Its parent process stays
-# off JAX and runs one child per size, so the children get the chip.
-bench:
-	python bench.py
+	ruff format trlx_tpu/ tests/ examples/
 
 # The quickest proof that the PPO main path still starts on the chip: every
 # Pallas kernel compiled/run/compared at the GPT-J-6B shapes, then two PPO
@@ -117,28 +111,6 @@ bench:
 # `--devices 4` drives a four-chip host). ~5 min cold on a v5e.
 chip-smoke:
 	python chip_smoke.py
-
-# CPU head-to-head vs the reference's own training loop (writes HEADTOHEAD.json).
-bench-reference:
-	python bench_reference.py
-
-# CPU decode-path smoke, ~2 min: interpret-mode flash-decode parity at the
-# flagship head layout + static tile legality at the full bench shape +
-# a tiny bucketed rollout (trace count <= n_buckets) + the decode_engine
-# probe (slot decode parity vs static batch, occupancy > 0.85, engine
-# tokens/s above the static rate) + the fleet_elastic probe (episodes/s
-# through the real lease/stream/intake transports at 1 vs 2 workers,
-# exactly-once asserted, 2-worker speedup > 1.3x). Writes BENCH_SMOKE.json.
-bench-smoke:
-	$(TEST_ENV) python bench_smoke.py
-
-# CPU observability smoke, ~1 min: a short overlapped PPO run with span
-# tracing, device telemetry, the slow_step anomaly drill, the health monitor
-# with the reward_drift drill, and the live /metrics exporter armed (scraped
-# from a background thread mid-run), then the report renderer over the
-# artifacts. Writes OBS_SMOKE.json + OBS_REPORT.md + OBS_METRICS.prom.
-obs-smoke:
-	$(TEST_ENV) python obs_smoke.py
 
 # Network-day acceptance: the four reference acceptance examples + gates in
 # one command, distilled to ACCEPTANCE.json (RUNBOOK.md). Offline it still

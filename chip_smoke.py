@@ -50,8 +50,9 @@ import time
 
 import numpy as np
 
-# GPT-J-6B's published widths, depth cut to 8 of 28 layers (bench.py's
-# "gptj-l8-d4096-2.0B-w8-bf16" row); REHEARSAL is the same program small
+# GPT-J-6B's published widths (EleutherAI/gpt-j-6b's config.json), depth cut
+# to 8 of 28 layers so that the state fits one 16 GB chip: the benchmark's
+# `benchmark/configs/gptj-6b-l8.json`, 2.0B parameters, int8 KV + W8; REHEARSAL is the same program small
 # enough for interpret-mode kernels on a CPU.
 FLAGSHIP = dict(
     d_model=4096, n_head=16, vocab=50400, n_layer=8, rotary_dim=64,

@@ -5,8 +5,7 @@ way the chip needs (CPU, tier-1, seconds):
   outside through JAX_COMPILATION_CACHE_DIR, else one fixed path under the
   checkout — the same from every call and every process;
 - chip_smoke.py without a TPU stops at the device check, before it builds
-  anything, with a non-zero exit code and no result line;
-- bench.py's parent process stays off JAX, so that its children get the chip.
+  anything, with a non-zero exit code and no result line.
 """
 
 import json
@@ -69,28 +68,6 @@ def test_chip_smoke_without_a_tpu_fails_at_the_device_check():
     assert "no TPU" in out.stderr
     # stopped before the kernel phase, a model or a result line
     assert "phase" not in out.stdout and '"ok"' not in out.stdout
-
-
-def test_bench_parent_stays_off_jax():
-    """Importing bench.py and running its parent-side argument handling and
-    helpers (manifest, size tables) imports no jax: on a TPU host the parent
-    would hold the chip its --one children need."""
-    code = """
-import sys
-sys.path.insert(0, %r)
-sys.argv = ["bench.py"]
-import bench
-from trlx_tpu.utils.manifest import RunManifest
-assert bench.fits_hbm(8, 4096, 50400, 2, 16e9)
-assert callable(bench.main) and callable(bench._run_child)
-print("jax" in sys.modules)
-""" % REPO
-    out = _run(code)
-    assert out.returncode == 0, out.stderr
-    assert out.stdout.strip().splitlines()[-1] == "False"
-    src = open(os.path.join(REPO, "bench.py")).read()
-    main_src = src[src.index("def main():"):src.index("def device_sync(")]
-    assert "import jax" not in main_src and "jax." not in main_src
 
 
 def test_chip_smoke_schedules_no_checkpoint(tmp_path):

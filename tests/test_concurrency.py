@@ -405,14 +405,10 @@ def test_scripts_lint_clean_with_script_rule_subset():
     scripts = [
         os.path.join(REPO, name)
         for name in (
-            "bench.py",
-            "bench_smoke.py",
-            "bench_decode_probe.py",
-            "bench_reference.py",
-            "bench_trajectory.py",
             "chip_smoke.py",
-            "obs_smoke.py",
             "acceptance_network.py",
+            "bench_flash.py",
+            "bench_kda.py",
         )
         if os.path.exists(os.path.join(REPO, name))
     ]

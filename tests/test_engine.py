@@ -729,76 +729,6 @@ def test_slow_step_with_engine_completes_and_captures(task, tmp_path, monkeypatc
     assert not any(t.name.startswith("trlx-") for t in threading.enumerate())
 
 
-# ------------------------------------------------- slot timeline (graftscope)
-
-
-def test_engine_slot_timeline_events_ordered_and_rolled_up(tmp_path):
-    """PR 12: with graftscope + spans armed, every slot episode leaves an
-    admit instant and a harvest instant that carries the slot life — strictly
-    alternating admit/harvest per slot — and the scope rolls refill waits and
-    per-slot occupancy up for /metrics and graftscope.json."""
-    from trlx_tpu.observability import graftscope as obs_graftscope
-    from trlx_tpu.observability import spans as obs_spans
-
-    scope = obs_graftscope.configure()
-    spans_path = str(tmp_path / "spans.jsonl")
-    obs_spans.configure(spans_path)
-    try:
-        model, params, _, _ = _tiny_model()
-        (w6, m6), (w4, m4) = _mixed_prompts()
-        gcfg = GenerateConfig(
-            max_new_tokens=6, do_sample=False, eos_token_id=None, pad_token_id=0
-        )
-        engine = RolloutEngine(
-            model, gcfg, n_slots=2, prompt_width=6, prefill_batch=2, steps_per_sync=2
-        )
-        engine.update_weights(params)
-        engine.submit(w6, m6)
-        engine.submit(w4, m4)
-        episodes = []
-        while not engine.idle:
-            episodes.extend(engine.step())
-        engine.shutdown()
-        assert len(episodes) == 6
-
-        gauges = scope.window()
-        samples = scope.drain_samples()
-        snap = scope.snapshot()
-    finally:
-        obs_spans.shutdown()
-        obs_graftscope.shutdown()
-
-    events = obs_spans.read_spans(spans_path)
-    admits = [e for e in events if e["ph"] == "i" and e["name"] == "engine/slot/admit"]
-    harvests = [
-        e for e in events if e["ph"] == "i" and e["name"] == "engine/slot/harvest"
-    ]
-    assert len(admits) == 6 and len(harvests) == 6
-
-    # per-slot lifecycle ordering: admit and harvest strictly alternate
-    slots = {e["args"]["slot"] for e in admits}
-    assert slots == {0, 1}
-    for slot in slots:
-        timeline = sorted(
-            [(e["ts"], "admit") for e in admits if e["args"]["slot"] == slot]
-            + [(e["ts"], "harvest") for e in harvests if e["args"]["slot"] == slot]
-        )
-        kinds = [k for _, k in timeline]
-        assert kinds == ["admit", "harvest"] * (len(kinds) // 2), (slot, kinds)
-    for e in harvests:
-        assert e["args"]["life_s"] >= 0
-        assert e["args"]["steps"] >= 1 and e["args"]["width"] in (4, 6)
-
-    # rollups: 2 first admissions wait for nothing, the 4 refills are timed
-    assert len(samples["refill_wait_ms"]) == 4
-    assert all(w >= 0.0 for w in samples["refill_wait_ms"])
-    assert "engine/refill_wait_ms_p50" in gauges
-    assert set(samples["straggler_steps"]) <= {4, 6}
-    assert sum(row["episodes"] for row in snap["slots"]) == 6
-    assert all(row["busy_s"] >= 0.0 for row in snap["slots"])
-    assert {row["slot"] for row in snap["slots"]} == {0, 1}
-
-
 # --------------------------------------------------------- paged KV cache
 
 

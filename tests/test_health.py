@@ -436,8 +436,8 @@ def test_exporter_update_merges_and_collisions_keep_last_writer():
 
 
 def test_exporter_histogram_exposition_cumulative_with_labels():
-    """graftscope's distribution feeds (lane gaps, refill waits, straggler
-    steps) render as conformant Prometheus histograms: cumulative buckets,
+    """Distribution feeds (graftfleet's collective skews are the live one)
+    render as conformant Prometheus histograms: cumulative buckets,
     an explicit +Inf bucket, _sum/_count, labels splitting series under one
     metric name, NaN samples dropped."""
     ex = MetricsExporter(port=0)

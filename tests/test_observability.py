@@ -4,7 +4,7 @@ telemetry, anomaly-triggered incident capture, and the report renderer.
 Unit tier: SpanTracer lane/metadata semantics (including OS-ident reuse),
 torn-tail + concurrent-writer file contracts, AnomalyDetector baseline math,
 IncidentCapture bundle contents and budget, DeviceMonitor compiled-cost
-capture and the MFU arithmetic cross-check against bench.py's formula.
+capture and the MFU arithmetic cross-check against the formula by hand.
 
 Integration tier (CPU): the acceptance run — a short overlapped PPO run at
 max_staleness=1 with spans + telemetry + anomaly armed and the
@@ -30,7 +30,6 @@ import trlx_tpu  # noqa: E402
 from randomwalks import base_config, generate_random_walks  # noqa: E402
 from trlx_tpu.observability import anomaly as obs_anomaly  # noqa: E402
 from trlx_tpu.observability import devicemon, report  # noqa: E402
-from trlx_tpu.observability import graftscope as obs_graftscope  # noqa: E402
 from trlx_tpu.observability import spans as obs_spans  # noqa: E402
 
 
@@ -41,7 +40,6 @@ def _span_isolation():
     obs_spans.drain()
     yield
     obs_spans.shutdown()
-    obs_graftscope.shutdown()
     obs_anomaly.register_emergency(None)
 
 
@@ -407,7 +405,7 @@ def test_peak_flops_env_override(monkeypatch):
 
 
 def test_device_monitor_capture_dispatch_accounting_and_mfu():
-    """The acceptance arithmetic: the MFU gauge must match bench.py's formula
+    """The acceptance arithmetic: the MFU gauge must match the formula
     (100 * flops / seconds / peak) computed by hand from the SAME captured
     cost_analysis FLOPs, to 2%."""
     import jax
@@ -428,7 +426,7 @@ def test_device_monitor_capture_dispatch_accounting_and_mfu():
 
     train_s = 2.0
     stats = mon.window({"train": train_s, "wall": train_s})
-    expected_mfu = 100.0 * (3 * flops) / train_s / peak  # bench.py arithmetic
+    expected_mfu = 100.0 * (3 * flops) / train_s / peak
     assert stats["obs/train_mfu_pct"] == pytest.approx(expected_mfu, rel=0.02)
     assert stats["obs/iter_mfu_pct"] == pytest.approx(expected_mfu, rel=0.02)
     assert stats["obs/train_tflops_per_chip"] == pytest.approx(3 * flops / train_s / 1e12, rel=0.02)
@@ -687,360 +685,3 @@ def test_e2e_main_path_writes_span_keys_and_leaves_little_unspanned(task, tmp_pa
         assert w["time/rollout_s"] == pytest.approx(
             w["time/generate_s"] + w["time/score_device_s"] + w["time/push_s"], rel=0.02, abs=2e-3)
         assert w["time/score_s"] >= 0.05  # host decode + reward_fn, the sleep inside
-
-
-# ---------------------------------------------------- graftscope ledger (PR 12)
-
-
-def test_graftscope_ledger_conservation_on_synthetic_intervals(monkeypatch):
-    """The conservation identity device + host + bubble == wall must hold
-    exactly on hand-built interval sets covering every clipping case: a
-    fence straddling the window start, one entirely outside it, overlapping
-    programs, and host lanes partially hidden under device time."""
-    import types
-
-    gs = obs_graftscope.GraftScope()
-    now = 1000.0
-    gs._win_t0 = now
-    monkeypatch.setattr(
-        obs_graftscope, "time", types.SimpleNamespace(time=lambda: now + 10.0)
-    )
-    gs._device = [
-        (now - 2.0, now + 0.5, "warmup"),  # straddles the window start
-        (now - 5.0, now - 4.0, "ancient"),  # fully before: clipped away
-        (now + 1.0, now + 3.0, "train/step"),
-        (now + 2.0, now + 4.0, "rollout/generate"),  # overlaps train/step
-    ]
-    gs._host = [
-        (now + 0.0, now + 5.0, "train"),
-        (now + 4.5, now + 6.0, "producer"),
-        (now + 20.0, now + 30.0, "score"),  # fully after: clipped away
-    ]
-    gauges = gs.window()
-
-    assert gauges["obs/ledger_wall_s"] == pytest.approx(10.0)
-    # device union: (now, now+0.5) + (now+1, now+4) = 3.5s
-    assert gauges["obs/ledger_device_busy_s"] == pytest.approx(3.5)
-    # host union (now, now+6) minus the device union = 2.5s
-    assert gauges["obs/ledger_host_s"] == pytest.approx(2.5)
-    assert gauges["obs/ledger_bubble_s"] == pytest.approx(4.0)
-    assert gauges["obs/bubble_fraction"] == pytest.approx(0.4)
-    assert gauges["obs/ledger_error_frac"] <= 1e-9  # identity by construction
-    assert gauges["obs/lane_busy_train_s"] == pytest.approx(5.0)
-    assert gauges["obs/lane_busy_producer_s"] == pytest.approx(1.5)
-    assert gauges["obs/lane_busy_score_s"] == 0.0
-
-    samples = gs.drain_samples()
-    assert samples["lane_gaps"]["train"] == pytest.approx([5.0])  # trailing idle
-    assert samples["lane_gaps"]["producer"] == pytest.approx([4.5, 4.0])
-    assert gs.drain_samples() is None  # consumed once per window
-
-    snap = gs.snapshot()
-    assert snap["totals"]["wall_s"] == pytest.approx(10.0)
-    assert snap["bubble_fraction"] == pytest.approx(0.4)
-    assert dict(snap["windows"][-1]["top_programs"]) == pytest.approx(
-        {"train/step": 2.0, "rollout/generate": 2.0, "warmup": 0.5}
-    )
-
-
-def test_graftscope_fence_drain_and_dropped_fences():
-    """Real dispatch outputs get fenced OFF the dispatch path by the drain
-    thread; a fence that raises (donated buffer consumed by the next step)
-    is counted and dropped, never propagated; close() joins the thread."""
-    import jax.numpy as jnp
-
-    gs = obs_graftscope.GraftScope()
-    out = {"loss": jnp.array(1.0), "big": jnp.ones((64,))}
-    gs.track_dispatch("train/step", "train", out)
-    deadline = time.time() + 10.0
-    while time.time() < deadline:
-        with gs._lock:
-            if gs._device:
-                break
-        time.sleep(0.005)
-    with gs._lock:
-        assert gs._device, "drain thread never fenced the dispatch"
-        t0, t1, name = gs._device[0]
-        assert name == "train/step" and t1 >= t0
-
-    class _DeadLeaf:
-        size = 1
-
-        def block_until_ready(self):
-            raise RuntimeError("buffer donated to the next step")
-
-    gs.track_dispatch("rollout/generate", "rollout", _DeadLeaf())
-    while time.time() < deadline:
-        with gs._lock:
-            if gs._fences_dropped:
-                break
-        time.sleep(0.005)
-    gauges = gs.window()
-    assert gauges["obs/graftscope_fences_dropped_total"] == 1.0
-    assert "rollout/generate" not in dict(
-        gs.snapshot()["windows"][-1]["top_programs"]
-    )
-    gs.close()
-    assert not any(
-        t.name == obs_graftscope.DRAIN_THREAD_NAME for t in threading.enumerate()
-    )
-
-
-def test_e2e_graftscope_armed_run_conserves_ledger(task, tmp_path, monkeypatch):
-    """The PR 12 acceptance bar: an armed overlapped CPU run keeps
-    |device + host + bubble − wall| / wall ≤ 0.05 in EVERY window, writes
-    the graftscope.json snapshot, and the report renders the attribution
-    section with suggested knobs. Armed via the env override (the config
-    knob path is exercised by obs_smoke.py)."""
-    monkeypatch.setenv("TRLX_TPU_GRAFTSCOPE", "1")
-    monkeypatch.setenv("TRLX_TPU_PEAK_TFLOPS", "0.01")
-    _, logit_mask, metric_fn, reward_fn = task
-    config = base_config("ppo", 15, 8)
-    config.train.total_steps = 8
-    config.train.epochs = 4
-    config.train.batch_size = 16
-    config.train.eval_interval = 100
-    config.train.checkpoint_dir = str(tmp_path)
-    config.method.num_rollouts = 16
-    config.method.chunk_size = 16
-    config.method.max_staleness = 1
-    prompts = [[int(np.random.default_rng(i).integers(1, 15))] for i in range(32)]
-    trlx_tpu.train(
-        reward_fn=reward_fn,
-        prompts=prompts,
-        eval_prompts=[[1]],
-        metric_fn=metric_fn,
-        config=config,
-        logit_mask=logit_mask,
-    )
-    assert not any(t.name.startswith("trlx-") for t in threading.enumerate())
-    assert not obs_graftscope.armed()  # learn() tears the global scope down
-
-    with open(os.path.join(str(tmp_path), "metrics.jsonl")) as f:
-        records = [json.loads(line) for line in f]
-    windows = [r for r in records if "obs/ledger_wall_s" in r]
-    assert windows, "armed run produced no ledger windows"
-    for r in windows:
-        wall = r["obs/ledger_wall_s"]
-        err = abs(
-            r["obs/ledger_device_busy_s"]
-            + r["obs/ledger_host_s"]
-            + r["obs/ledger_bubble_s"]
-            - wall
-        ) / max(wall, 1e-9)
-        assert err <= 0.05, (err, r)
-        assert r["obs/ledger_error_frac"] <= 0.05
-        assert 0.0 <= r["obs/bubble_fraction"] <= 1.0
-    assert any(r["obs/ledger_device_busy_s"] > 0 for r in windows)
-    assert any(r["obs/lane_busy_producer_s"] > 0 for r in windows)
-
-    with open(os.path.join(str(tmp_path), obs_graftscope.SNAPSHOT_FILENAME)) as f:
-        snap = json.load(f)
-    assert snap["windows"] and snap["totals"]["wall_s"] > 0
-    assert snap["programs_s"], "no per-program device attribution"
-
-    md = report.build_report(str(tmp_path))
-    assert "## Device-time attribution (graftscope)" in md
-    assert "Top-3 time sinks" in md
-
-
-# ----------------------------------------------------- RunManifest (PR 12)
-
-
-def test_run_manifest_lifecycle_torn_tail_and_idempotent_finish(tmp_path):
-    path = str(tmp_path / "m.jsonl")
-    m = obs_graftscope.RunManifest(path, cmd="bench.py", backend="cpu")
-    m.heartbeat("size_ladder", candidate="a")
-    m.child("a", 0, "")
-    m.partial({"metric": "x", "value": 1.5})
-    m.finish(rc=0, metric="x", value=1.5)
-    m.finish(rc=1, reason="late duplicate")  # idempotent: first end wins
-    with open(path, "ab") as f:
-        f.write(b'{"event": "heartbeat", "pha')  # SIGKILL tears the tail
-    s = obs_graftscope.RunManifest.read(path)
-    assert s["valid"] and s["complete"] and s["rc"] == 0
-    assert s["reason"] == "completed rc=0"
-    assert s["partial"] == {"metric": "x", "value": 1.5}
-    assert s["children"] == [{"label": "a", "rc": 0}]
-    with open(path, "rb") as f:
-        raw = f.read()
-    assert raw.count(b'"event": "end"') == 1  # the duplicate finish was dropped
-
-
-def test_run_manifest_survives_sigkill_and_names_the_phase(tmp_path):
-    """A bench child SIGKILLed mid-ladder (the BENCH_r04/r05 shape) must
-    leave a manifest that says when and during what the run died, including
-    the last child failure's rc and stderr tail."""
-    import signal
-    import subprocess
-
-    path = str(tmp_path / "m.jsonl")
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    child_src = (
-        "import sys, time\n"
-        "sys.path.insert(0, %r)\n"
-        "from trlx_tpu.observability.graftscope import RunManifest\n"
-        "m = RunManifest(%r, cmd='bench.py drill')\n"
-        "m.heartbeat('size_ladder', candidate='big')\n"
-        "m.child('big', 1, 'Traceback...\\nValueError: mosaic lowering failed')\n"
-        "m.heartbeat('size_ladder', candidate='small')\n"
-        "print('ready', flush=True)\n"
-        "time.sleep(60)\n"
-    ) % (repo, path)
-    proc = subprocess.Popen(
-        [sys.executable, "-c", child_src],
-        stdout=subprocess.PIPE,
-        text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    try:
-        assert proc.stdout.readline().strip() == "ready"
-    finally:
-        proc.send_signal(signal.SIGKILL)
-        proc.wait(timeout=30)
-    s = obs_graftscope.RunManifest.read(path)
-    assert s["valid"] and not s["complete"] and s["rc"] is None
-    assert "killed mid-flight during size_ladder" in s["reason"]
-    assert "(candidate small)" in s["reason"]
-    assert "last child failure big rc=1" in s["reason"]
-    assert "ValueError: mosaic lowering failed" in s["reason"]
-
-
-def test_manifest_reader_parity_with_bench_trajectory_mirror(tmp_path):
-    """bench_trajectory.py carries an inline stdlib mirror of
-    RunManifest.read (it must stay import-light for CI) — the two must
-    produce identical summaries for killed AND completed manifests."""
-    import bench_trajectory
-
-    path = str(tmp_path / "m.jsonl")
-    m = obs_graftscope.RunManifest(path, cmd="bench.py")
-    m.heartbeat("size_ladder", candidate="big")
-    m.child("big", 1, "Traceback...\nValueError: mosaic lowering failed")
-    m.heartbeat("size_ladder", candidate="small")
-    m.partial({"metric": "samples/s/chip", "value": 2.0})
-    for stage in ("killed", "completed"):
-        if stage == "completed":
-            m.finish(rc=0)
-        a = obs_graftscope.RunManifest.read(path)
-        b = bench_trajectory._read_manifest(path)
-        for key in ("valid", "complete", "rc", "reason", "partial", "last_heartbeat"):
-            assert a[key] == b[key], (stage, key, a[key], b[key])
-
-
-def test_bench_trajectory_surfaces_manifest_reason_for_no_data_run(
-    tmp_path, monkeypatch
-):
-    """A gap entry (rc=124, empty tail) picks up the per-run manifest's
-    forensic reason instead of the generic artifact-side one."""
-    import bench_trajectory
-
-    monkeypatch.chdir(tmp_path)
-    with open("BENCH_r07.json", "w") as f:
-        json.dump({"rc": 124, "tail": ""}, f)
-    m = obs_graftscope.RunManifest("BENCH_MANIFEST_r07.jsonl", cmd="bench")
-    m.heartbeat("flagship")
-    traj = bench_trajectory.build_trajectory(
-        ["BENCH_r07.json"],
-        smoke_path="missing.json",
-        manifest_path="missing.jsonl",
-    )
-    entry = traj["runs"][0]
-    assert entry["no_data"] and entry["manifest"]
-    assert entry["reason"] == "run killed mid-flight during flagship"
-
-    # a clean-finish manifest can NOT explain a no-data artifact: the
-    # artifact-side reason must survive
-    m.finish(rc=0)
-    traj = bench_trajectory.build_trajectory(
-        ["BENCH_r07.json"], smoke_path="missing.json", manifest_path="missing.jsonl"
-    )
-    assert traj["runs"][0]["reason"] == "bench run exited rc=124"
-    assert "manifest" not in traj["runs"][0]
-
-
-def test_bench_trajectory_parses_spec_decode_smoke_section(tmp_path):
-    """The smoke fold must surface the spec-decode probe's paired numbers
-    (rate + accept rate + speedup) and stay silent when the section is
-    absent (pre-PR-19 artifacts)."""
-    import bench_trajectory
-
-    path = str(tmp_path / "BENCH_SMOKE.json")
-    with open(path, "w") as f:
-        json.dump(
-            {
-                "spec_decode": {
-                    "decode_tokens_per_s": 39793.1,
-                    "accept_rate": 1.0,
-                    "speedup_vs_nonspec": 2.7,
-                }
-            },
-            f,
-        )
-    out = bench_trajectory._parse_smoke(path)
-    assert out["spec_decode_tokens_per_s"] == 39793.1
-    assert out["spec_accept_rate"] == 1.0
-    assert out["spec_speedup_vs_nonspec"] == 2.7
-
-    with open(path, "w") as f:
-        json.dump({"rollout": {"tokens_per_s": 5.0}}, f)
-    out = bench_trajectory._parse_smoke(path)
-    assert "spec_decode_tokens_per_s" not in out
-    assert out["rollout_tokens_per_s"] == 5.0
-
-
-def test_bench_trajectory_parses_and_gates_paged_kv_section(tmp_path):
-    """The paged-KV record's capacity ratio and prefix savings are
-    hardware-independent CONTRACTS: the smoke fold must surface them, and
-    build_trajectory must flip ``regressed`` when either falls below its
-    floor (1.5x slots in the same bytes, >0 prefill reduction) — the one
-    smoke-sourced gate. Pre-PR-20 artifacts (no section) stay silent."""
-    import bench_trajectory
-
-    path = str(tmp_path / "BENCH_SMOKE.json")
-    good = {
-        "paged_kv": {
-            "slot_capacity_ratio": 1.5,
-            "prefill_token_reduction": 0.889,
-            "prefix_hits_total": 12,
-        }
-    }
-    with open(path, "w") as f:
-        json.dump(good, f)
-    out = bench_trajectory._parse_smoke(path)
-    assert out["paged_slot_capacity_ratio"] == 1.5
-    assert out["paged_prefill_token_reduction"] == 0.889
-    assert out["paged_prefix_hits_total"] == 12
-
-    traj = bench_trajectory.build_trajectory(
-        [], smoke_path=path, manifest_path="missing.jsonl"
-    )
-    assert traj["regressed"] is False
-    assert any("paged KV" in v and "ok" in v for v in traj["verdict"])
-
-    # capacity below the floor -> gate trips even with no bench runs
-    good["paged_kv"]["slot_capacity_ratio"] = 1.2
-    with open(path, "w") as f:
-        json.dump(good, f)
-    traj = bench_trajectory.build_trajectory(
-        [], smoke_path=path, manifest_path="missing.jsonl"
-    )
-    assert traj["regressed"] is True
-    assert any("REGRESSION: paged KV" in v for v in traj["verdict"])
-
-    # savings gone -> same trip
-    good["paged_kv"].update(slot_capacity_ratio=1.5, prefill_token_reduction=0.0)
-    with open(path, "w") as f:
-        json.dump(good, f)
-    assert bench_trajectory.build_trajectory(
-        [], smoke_path=path, manifest_path="missing.jsonl"
-    )["regressed"] is True
-
-    # absent section: no paged fields, no paged verdict
-    with open(path, "w") as f:
-        json.dump({"rollout": {"tokens_per_s": 5.0}}, f)
-    traj = bench_trajectory.build_trajectory(
-        [], smoke_path=path, manifest_path="missing.jsonl"
-    )
-    assert "paged_slot_capacity_ratio" not in traj["smoke"]
-    assert traj["regressed"] is False
-    assert not any("paged" in v for v in traj["verdict"])

@@ -97,16 +97,55 @@ def test_watch_interval_logs_grad_norms_and_histograms(tmp_path):
     assert hist_names, "no parameter histograms logged"
 
 
-def test_ppo_headtohead_assets_round_trip(tmp_path):
-    """Guards bench_reference.py's PPO harness against bitrot: the shared
-    init checkpoint + char tokenizer build offline, the tokenizer encodes/
-    decodes the task alphabet, and trlx_tpu's streamed importer loads the
-    checkpoint into a working trainer."""
-    import sys as _sys
+# A local HF checkpoint directory with a tokenizer of its own: a tiny GPT-2
+# (fixed torch seed) and a character-level byte-BPE vocabulary with no merges,
+# saved as the ordinary HF files.
+_CHARS = "abcdefghijklmnopqrstuvwxyz0123456789 .,!?"
+_N_LAYER, _D_MODEL, _N_HEAD, _VOCAB = 4, 144, 4, 42
+_RESPONSE_TOKENS = 24
 
-    _sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from bench_reference import PPO_PROTOCOL, build_ppo_assets, _ppo_prompts, _ppo_reward_fn
 
+def _ppo_reward_fn(texts):
+    return [sum(c == "a" for c in t) / float(_RESPONSE_TOKENS) for t in texts]
+
+
+def _ppo_prompts():
+    rng = np.random.default_rng(0)
+    return ["".join(rng.choice(list("bcdefgh"), size=6)) for _ in range(64)]
+
+
+def build_ppo_assets(assets_dir):
+    import torch
+    import transformers
+    from transformers.models.gpt2.tokenization_gpt2 import bytes_to_unicode
+
+    os.makedirs(assets_dir, exist_ok=True)
+    cfg = transformers.GPT2Config(
+        n_layer=_N_LAYER, n_embd=_D_MODEL, n_head=_N_HEAD,
+        vocab_size=_VOCAB, n_positions=128,
+        bos_token_id=_VOCAB - 1, eos_token_id=_VOCAB - 1,
+    )
+    torch.manual_seed(7)
+    transformers.GPT2LMHeadModel(cfg).save_pretrained(assets_dir, safe_serialization=True)
+    b2u = bytes_to_unicode()
+    vocab = {}
+    for ch in _CHARS:
+        vocab.setdefault("".join(b2u[b] for b in ch.encode("utf-8")), len(vocab))
+    vocab["<|endoftext|>"] = len(vocab)
+    assert len(vocab) == _VOCAB, len(vocab)
+    with open(os.path.join(assets_dir, "vocab.json"), "w") as f:
+        json.dump(vocab, f)
+    with open(os.path.join(assets_dir, "merges.txt"), "w") as f:
+        f.write("#version: 0.2\n")
+    return assets_dir
+
+
+def test_local_hf_checkpoint_and_char_tokenizer_load_into_a_hydra_ppo_trainer(tmp_path):
+    """A local HF checkpoint directory with its own character-level tokenizer
+    goes through `model.model_path` / `tokenizer_path` and the streamed
+    importer into a working PPOTrainer: the tokenizer round-trips the task
+    alphabet one id a character, the hydra branch is engaged, and
+    `rollout_generate` returns prompt + response columns."""
     assets = str(tmp_path / "assets")
     build_ppo_assets(assets)
 
@@ -125,13 +164,12 @@ def test_ppo_headtohead_assets_round_trip(tmp_path):
     from trlx_tpu.data.configs import TRLConfig
     from trlx_tpu.trainer.ppo import PPOTrainer
 
-    p = PPO_PROTOCOL
     config = TRLConfig.from_dict(
         {
             "model": {"model_path": assets, "tokenizer_path": assets, "model_type": "ppo",
-                      "num_layers_unfrozen": p["num_layers_unfrozen"], "dtype": "float32",
+                      "num_layers_unfrozen": 2, "dtype": "float32",
                       "param_dtype": "float32"},
-            "train": {"seq_length": p["seq_length"], "epochs": 1, "total_steps": 1,
+            "train": {"seq_length": 32, "epochs": 1, "total_steps": 1,
                       "batch_size": 8, "lr_ramp_steps": 1, "lr_decay_steps": 10,
                       "weight_decay": 0.0, "learning_rate_init": 1e-3,
                       "learning_rate_target": 1e-4, "checkpoint_dir": str(tmp_path / "ck"),
@@ -141,14 +179,13 @@ def test_ppo_headtohead_assets_round_trip(tmp_path):
         }
     )
     trainer = PPOTrainer(config)
-    assert trainer.model.branch_layer >= 0  # hydra engaged, as in the h2h
+    assert trainer.model.branch_layer >= 0  # hydra engaged
     enc = tok(prompts[:8], padding=False)
-    import numpy as _np
 
-    ids8 = _np.full((8, 8), tok.eos_token_id, dtype=_np.int32)
-    mask8 = _np.zeros((8, 8), dtype=_np.int32)
+    ids8 = np.full((8, 8), tok.eos_token_id, dtype=np.int32)
+    mask8 = np.zeros((8, 8), dtype=np.int32)
     for i, row in enumerate(enc.input_ids):
         ids8[i, -len(row):] = row
         mask8[i, -len(row):] = 1
     tokens, _ = trainer.rollout_generate(ids8, mask8)
-    assert _np.asarray(tokens).shape == (8, 12)
+    assert np.asarray(tokens).shape == (8, 12)

@@ -2,8 +2,7 @@
 
 Every recent layer added another long-lived thread to the trainer process —
 RolloutProducer / ScoreWorker / PrefetchIterator (PR 5), the heartbeat
-writer (PR 2), the MetricsExporter server (PR 9), the graftscope drain
-thread (PR 12) — but graftlint only checked the *dispatch* lock lexically
+writer (PR 2), the MetricsExporter server (PR 9) — but graftlint only checked the *dispatch* lock lexically
 (GL001). These rules check the rest of the shared mutable state:
 
 - GL008 shared-write-without-lock: build the per-class thread-entry-point

@@ -264,12 +264,6 @@ class TrainConfig:
     # Prometheus-text /metrics and JSON /healthz on this port while the run
     # is alive (0 = off). TRLX_TPU_METRICS_PORT overrides.
     metrics_port: int = 0
-    # graftscope (trlx_tpu/observability/graftscope.py): device-time
-    # attribution ledger (device_busy + host + bubble == wall per phase
-    # window, per-program top-K), pipeline-bubble accounting with per-lane
-    # gap histograms, and the engine slot timeline. Implies span tracing +
-    # device telemetry while armed. TRLX_TPU_GRAFTSCOPE=1 overrides.
-    graftscope: bool = False
     # graftfleet (trlx_tpu/observability/fleet.py): cross-host trace
     # federation (per-host spans.host<k>.jsonl + a barrier-based clock-offset
     # estimator so read_fleet_spans merges one aligned Chrome trace),

@@ -12,8 +12,8 @@ statistics into a fresh slot.
 from the admitted prompt and updated online from the ACCEPTED token stream
 (never from rejected drafts — those are exactly the tokens the big model
 disagreed with). A seeded ``transition`` function overrides the learned
-table for workloads whose next-token map is known a priori — bench_smoke's
-forced-bigram probe uses it for the perfect-draft case, since that
+table for workloads whose next-token map is known a priori — a
+forced-bigram probe needs it for the perfect-draft case, since that
 workload's chained pairs never repeat within an episode and an online
 table would score zero accepts.
 

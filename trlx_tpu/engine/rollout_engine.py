@@ -54,9 +54,7 @@ import numpy as np
 
 from trlx_tpu.engine.paged_pool import BlockPool, PoolExhausted
 from trlx_tpu.models.lm import init_cache, init_paged_cache
-from trlx_tpu.observability import graftscope
 from trlx_tpu.observability import numerics as obs_numerics
-from trlx_tpu.observability import spans as obs_spans
 from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.ops.sampling import GenerateConfig, process_logits_default
 from trlx_tpu.pipeline.prompt_pipeline import PromptSlotQueue
@@ -215,11 +213,6 @@ class RolloutEngine:
         self.queue = PromptSlotQueue()
         self._slot_meta = [None] * self.n_slots  # per-occupied-slot host facts
         self._free = list(range(self.n_slots))
-        # graftscope slot timeline: wall clock when each slot was last
-        # harvested (None until then) — the refill-wait numerator. Only
-        # touched when the scope is armed, so the unarmed path stays
-        # byte-identical.
-        self._slot_free_t = [None] * self.n_slots
         self._variables = None
         self.weight_version = None
         # In-flight weight staging (PipelineRL, arxiv 2509.19128): pushes
@@ -467,36 +460,9 @@ class RolloutEngine:
             self._roll_schedule("harvest", *done)
             toks = np.asarray(jax.device_get(self._state["tokens"]), dtype=np.int32)
             R = int(self.gcfg.max_new_tokens)
-            scope = graftscope.scope()
             for i in done:
                 meta, self._slot_meta[i] = self._slot_meta[i], None
                 steps = int(n_gen[i])
-                if scope is not None:
-                    # Slot-timeline harvest (host side only — GL003 keeps
-                    # clock reads out of the traced decode body): a harvest
-                    # instant and the straggler sample (bucket width →
-                    # decode steps) for the ledger.
-                    now = time.time()
-                    self._slot_free_t[i] = now
-                    admit_t = meta.get("admit_t")
-                    width = int(meta.get("width", len(meta["prompt_ids"])))
-                    life_s = (now - admit_t) if admit_t is not None else 0.0
-                    # The admit and harvest instants bracket the episode's
-                    # life in the slot; the harvest carries its length.
-                    obs_spans.instant(
-                        "engine/slot/harvest", slot=i, steps=steps, width=width, life_s=life_s
-                    )
-                    scope.record_harvest(i, width, steps, life_s)
-                    if self.spec_decode:
-                        # Per-episode accept-rate sample (accepted tokens
-                        # over window positions paid) for the /metrics
-                        # histogram, keyed by prompt bucket width like the
-                        # straggler samples.
-                        disp = int(meta.get("dispatches", 0))
-                        if disp > 0:
-                            scope.record_spec_accept(
-                                i, width, steps / float(disp * self.spec_k)
-                            )
                 rmask = np.zeros((R,), dtype=np.int32)
                 rmask[:steps] = 1
                 spans = self._build_spans(meta, steps)
@@ -529,20 +495,6 @@ class RolloutEngine:
                     block_tables=self._state["block_tables"].at[idx].set(0),
                 )
             self._completed += len(done)
-        if self.paged:
-            scope = graftscope.scope()
-            if scope is not None:
-                # Pool occupancy sample per sync boundary — the slot-timeline
-                # pool row (host bookkeeping only, no device read).
-                scope.record_pool(
-                    self.pool.used_blocks(),
-                    self.pool.cached_blocks(),
-                    len(self.pool.free),
-                    self.n_blocks,
-                    self._pool_frag(),
-                    self.pool.hits_total,
-                    self.pool.tokens_saved_total,
-                )
         return episodes
 
     def _step_decode(self, n_live):
@@ -642,7 +594,6 @@ class RolloutEngine:
                 continue
             chain = self.drafter.propose(i, int(self._spec_last_tok[i]), K)
             drafts[i, 1:] = np.asarray(chain[1:], dtype=np.int32)
-            meta["dispatches"] = meta.get("dispatches", 0) + 1
         return drafts
 
     def _observe_accepted(self, acc, window):
@@ -776,7 +727,6 @@ class RolloutEngine:
                 sanitize.mark_donated(prev_state, "engine._prefill(state) [admit]")
                 del prev_state
             self._prefill_wall += time.time() - t0
-            scope = graftscope.scope()
             for row, slot in enumerate(slots):
                 self._slot_meta[int(slot)] = {
                     "prompt_ids": ids[row],
@@ -792,26 +742,6 @@ class RolloutEngine:
                     # statistics.
                     self._spec_last_tok[j] = int(ids[row, -1])
                     self.drafter.reset_slot(j, ids[row][msk[row] > 0].tolist())
-                if scope is not None:
-                    # Slot-timeline admit: t0 (captured before the prefill
-                    # dispatch) ends the slot's refill wait; the episode's
-                    # occupancy span starts here.
-                    j = int(slot)
-                    self._slot_meta[j]["admit_t"] = t0
-                    self._slot_meta[j]["width"] = int(width)
-                    freed = self._slot_free_t[j]
-                    wait_s = (t0 - freed) if freed is not None else None
-                    scope.record_refill(j, int(width), wait_s)
-                    obs_spans.instant(
-                        "engine/slot/admit",
-                        slot=j,
-                        width=int(width),
-                        **(
-                            {"wait_ms": round(wait_s * 1e3, 3)}
-                            if wait_s is not None
-                            else {}
-                        ),
-                    )
             self._prefill_calls += 1
             self._refills += int(ids.shape[0])
             admitted += int(ids.shape[0])
@@ -871,7 +801,6 @@ class RolloutEngine:
             by_hit = {}
             for slot, r, tbl_row, hit in rows:
                 by_hit.setdefault(hit, []).append((slot, r, tbl_row))
-            scope = graftscope.scope()
             for hit, sub in by_hit.items():
                 slots = np.asarray([s for s, _, _ in sub], dtype=np.int32)
                 rr = [r for _, r, _ in sub]
@@ -918,23 +847,6 @@ class RolloutEngine:
                     if self.spec_decode:
                         self._spec_last_tok[j] = int(ids[r, -1])
                         self.drafter.reset_slot(j, ids[r][msk[r] > 0].tolist())
-                    if scope is not None:
-                        self._slot_meta[j]["admit_t"] = t0
-                        self._slot_meta[j]["width"] = int(width)
-                        freed = self._slot_free_t[j]
-                        wait_s = (t0 - freed) if freed is not None else None
-                        scope.record_refill(j, int(width), wait_s)
-                        obs_spans.instant(
-                            "engine/slot/admit",
-                            slot=j,
-                            width=int(width),
-                            hit=int(hit),
-                            **(
-                                {"wait_ms": round(wait_s * 1e3, 3)}
-                                if wait_s is not None
-                                else {}
-                            ),
-                        )
                 self._prefill_calls += 1
             self._refills += len(rows)
             admitted += len(rows)

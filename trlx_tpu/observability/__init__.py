@@ -1,9 +1,8 @@
 """Unified observability layer (PR 8) + training-health monitor (PR 9)
-+ graftscope attribution ledger & run forensics (PR 12)
 + graftfleet cross-host federation (PR 14)
 + graftnum streaming numerics observatory (PR 15).
 
-Nine parts, all off-hot-path and off by default:
+Eight parts, all off-hot-path and off by default:
 
 - ``spans``     — the one way host work is timed: ``with trace_span(name)``
                   is always a profiler annotation and feeds the ``time/*``
@@ -27,12 +26,6 @@ Nine parts, all off-hot-path and off by default:
                   ``TRLX_TPU_METRICS_PORT``);
 - ``report``    — ``python -m trlx_tpu.observability.report <ckpt_dir>``
                   renders everything as one markdown performance report;
-- ``graftscope``— device-time attribution ledger (``device_busy + host +
-                  bubble == wall`` per phase window, per-program top-K),
-                  pipeline-bubble accounting with per-lane gap histograms,
-                  engine slot timeline, and the crash-proof ``RunManifest``
-                  bench forensics (``train.graftscope`` /
-                  ``TRLX_TPU_GRAFTSCOPE=1``);
 - ``fleet``     — graftfleet cross-host federation: per-host span lanes
                   merged under a barrier-estimated clock alignment,
                   per-collective straggler attribution from guarded-
@@ -47,15 +40,13 @@ Nine parts, all off-hot-path and off by default:
                   handoffs, and grad-spike / update-ratio health detectors
                   (``train.graftnum`` / ``TRLX_TPU_GRAFTNUM=1``).
 
-See RUNBOOK.md §8 (performance), §9 (training health), §12 (device-time
-attribution & run forensics), §14 (fleet observability) and §15 (numerics
-observability) for knobs and triage.
+See RUNBOOK.md §8 (performance), §9 (training health), §14 (fleet
+observability) and §15 (numerics observability) for knobs and triage.
 """
 
 import os
 
 from trlx_tpu.observability import fleet  # noqa: F401 — canonical import point
-from trlx_tpu.observability import graftscope  # noqa: F401 — canonical import point
 from trlx_tpu.observability import numerics  # noqa: F401 — canonical import point
 from trlx_tpu.observability import spans  # noqa: F401 — canonical import point
 from trlx_tpu.observability.anomaly import AnomalyDetector, IncidentCapture  # noqa: F401

@@ -1,6 +1,6 @@
 """Device telemetry: compiled-cost capture, real-FLOPs MFU, routing gauges.
 
-bench.py derives MFU from an analytic FLOP model (``lm_flops``) — fine for a
+An analytic FLOP model (counted from a configuration's shapes) is fine for a
 benchmark that knows its own shapes, useless for a live run whose programs
 (fused vs dense head, packed vs padded batches, per-bucket score fns) are
 picked by routing logic at runtime. This module instead asks XLA: every
@@ -15,7 +15,7 @@ follow from bookkeeping the wrapper already does:
 
 ``cost_analysis`` on an SPMD-partitioned program reports the PER-DEVICE
 module cost, so the MFU needs no device-count division — it is directly the
-per-chip utilization bench.py computes as ``train_tflops / peak``.
+per-chip utilization ``train_tflops / peak``.
 
 Capture cost and safety:
 
@@ -54,7 +54,7 @@ __all__ = [
 
 PROGRAMS_FILENAME = "programs.json"
 
-# The one table of chip peaks (bench.py reads it too), keyed by the
+# The one table of chip peaks, keyed by the
 # device_kind JAX reports: (dense bf16 TFLOP/s, HBM GB/s) per chip, from
 # Google Cloud's per-generation TPU documentation. A TPU that is not here is
 # an error, not a default — add its row with its source.
@@ -129,16 +129,7 @@ class _MonitoredFn:
 
     def __call__(self, *args, **kwargs):
         self._monitor._on_dispatch(self._name, self._fn, args, kwargs)
-        out = self._fn(*args, **kwargs)
-        ledger = self._monitor.ledger
-        if ledger is not None:
-            # graftscope device-time attribution: hand the async result to
-            # the ledger, whose drain THREAD takes the completion-fence
-            # timestamp — nothing blocks on the dispatch path.
-            ledger.track_dispatch(
-                self._name, self._monitor.programs[self._name]["phase"], out
-            )
-        return out
+        return self._fn(*args, **kwargs)
 
     def __getattr__(self, item):
         # Only reached for names not on the proxy — live delegation keeps
@@ -164,9 +155,6 @@ class DeviceMonitor:
         self._lock = threading.Lock()
         self._window_flops = {}  # phase -> flops dispatched since last window()
         self._dirty = False
-        # graftscope attribution ledger, attached by the trainer when armed;
-        # None keeps the dispatch path on one attribute load.
-        self.ledger = None
 
     def wrap(self, name, fn, phase: str = "train"):
         with self._lock:

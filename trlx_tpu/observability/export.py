@@ -15,8 +15,8 @@ overrides) and off by default:
 
 Besides gauges, :meth:`MetricsExporter.observe` accumulates cumulative
 Prometheus histograms (``_bucket{le=...}`` / ``_sum`` / ``_count``) with
-optional labels — graftscope feeds per-lane pipeline-gap, engine
-refill-latency, and straggler-by-width distributions through it.
+optional labels — graftfleet feeds the per-collective arrival-skew
+distribution through it.
 
 Multi-host: the trainer rolls the gauges up over the existing
 ``allgather_host`` path (``rollup_window_stats``) BEFORE handing them over,

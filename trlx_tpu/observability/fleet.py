@@ -1,8 +1,8 @@
 """graftfleet: cross-host trace federation, collective straggler attribution,
 and fleet-wide health rollup.
 
-PRs 8/9/12 built single-host observability — spans, MFU telemetry, the
-health monitor, the graftscope ledger — but every artifact is per-process
+PRs 8/9 built single-host observability — spans, MFU telemetry, the
+health monitor — but every artifact is per-process
 with no cross-host story: a multi-host stall yields N disjoint span files
 with unaligned clocks and a CollectiveTimeout that names the slowest host
 from heartbeats alone. Before the ROADMAP's disaggregated actor/learner
@@ -579,7 +579,7 @@ def _tail_whole_lines(path: str, max_bytes: int = _SPAN_TAIL_BYTES) -> bytes:
 
 
 # ----------------------------------------------------------- module arming
-# Same pattern as spans/graftscope: a module global the trainer arms, so the
+# Same pattern as spans: a module global the trainer arms, so the
 # collective_guard hooks (which hold no trainer reference) reach it, and the
 # disarmed path costs one dict load.
 

@@ -5,7 +5,7 @@ The other observability layers watch *around* the model (spans, MFU,
 phase windows, fleet skew); graftnum watches *inside* it. Armed by
 ``train.graftnum`` (or ``TRLX_TPU_GRAFTNUM=1``), off by default, and the
 disarmed hooks are one module-global load — the serial path stays
-byte-identical (same contract as spans/graftscope/graftfleet):
+byte-identical (same contract as spans/graftfleet):
 
 - **Per-subtree training telemetry** — ``train_step_stats`` folds
   per-top-level-param-subtree grad norm, param norm, and update/param

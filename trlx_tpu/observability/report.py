@@ -177,97 +177,6 @@ def _overlap_seconds(spans, lane_a_substr: str, lane_b_substr: str):
     return total / 1e6
 
 
-# ------------------------------------------------------------- graftscope
-
-
-def _sink_knob(name: str) -> str:
-    """Suggested first knob for a named time sink — the triage table of
-    RUNBOOK §12 in code form."""
-    if "bubble" in name:
-        return "raise method.max_staleness / method.score_queue_depth (hide more rollout behind train)"
-    if "refill" in name:
-        return "raise method.prefill_batch or method.engine_slots (slots starve between episodes)"
-    if "score" in name:
-        return "parallelize the reward fn / raise method.score_queue_depth"
-    if "producer" in name or "rollout" in name or "decode" in name or "engine" in name:
-        return "raise method.engine_steps_per_sync / method.chunk_size (amortize decode sync)"
-    if "train" in name:
-        return "raise train.batch_size or relax remat (device train step dominates)"
-    return "profile with spans.jsonl in Perfetto"
-
-
-def _graftscope_section(checkpoint_dir):
-    """Render graftscope.json (if the run was armed) into the ledger table,
-    per-program attribution, slot occupancy rows, and the top-3 time sinks
-    with a suggested knob each."""
-    lines = ["## Device-time attribution (graftscope)", ""]
-    path = os.path.join(checkpoint_dir, "graftscope.json")
-    if not os.path.exists(path):
-        lines.append("No graftscope snapshot (train.graftscope off — set it or TRLX_TPU_GRAFTSCOPE=1).")
-        lines.append("")
-        return lines
-    try:
-        with open(path) as f:
-            snap = json.load(f)
-    except (OSError, json.JSONDecodeError) as e:
-        lines.append(f"graftscope.json unreadable: {e}")
-        lines.append("")
-        return lines
-    totals = snap.get("totals", {})
-    wall = totals.get("wall_s", 0.0) or 0.0
-    lines.append("| wall_s | device_busy_s | host_s | bubble_s | bubble_frac | windows | fences dropped |")
-    lines.append("|---|---|---|---|---|---|---|")
-    lines.append(
-        f"| {_fmt(wall, 2)} | {_fmt(totals.get('device_busy_s', 0.0), 2)} "
-        f"| {_fmt(totals.get('host_s', 0.0), 2)} | {_fmt(totals.get('bubble_s', 0.0), 2)} "
-        f"| {_fmt(snap.get('bubble_fraction', 0.0), 3)} | {len(snap.get('windows', []))} "
-        f"| {snap.get('fences_dropped', 0)} |"
-    )
-    lines.append("")
-    programs = snap.get("programs_s", {})
-    if programs:
-        lines.append("### Per-program device time (top-K, fence-measured)")
-        lines.append("")
-        lines.append("| program | device_s | share of wall |")
-        lines.append("|---|---|---|")
-        for name, sec in sorted(programs.items(), key=lambda kv: -kv[1]):
-            share = sec / wall if wall else 0.0
-            lines.append(f"| {name} | {_fmt(sec, 2)} | {_fmt(100 * share, 1)}% |")
-        lines.append("")
-    slots = snap.get("slots", [])
-    if slots:
-        lines.append("### Engine slot occupancy")
-        lines.append("")
-        lines.append("| slot | busy_s | episodes | last width |")
-        lines.append("|---|---|---|---|")
-        for row in slots:
-            lines.append(
-                f"| {row.get('slot')} | {_fmt(row.get('busy_s', 0.0), 2)} "
-                f"| {row.get('episodes', 0)} | {row.get('last_width', 0)} |"
-            )
-        lines.append(
-            f"\ncumulative refill wait: {_fmt(snap.get('refill_wait_total_ms', 0.0), 1)} ms"
-        )
-        lines.append("")
-    # Top-3 time sinks: the window's non-overlapped seconds, ranked.
-    sinks = [("pipeline bubble", totals.get("bubble_s", 0.0) or 0.0)]
-    sinks += [(f"device: {name}", sec) for name, sec in list(programs.items())[:4]]
-    for lane, sec in (snap.get("lane_busy_s", {}) or {}).items():
-        sinks.append((f"host {lane} lane", sec or 0.0))
-    refill_s = (snap.get("refill_wait_total_ms", 0.0) or 0.0) / 1e3
-    if refill_s > 0:
-        sinks.append(("engine refill wait", refill_s))
-    sinks = sorted(sinks, key=lambda kv: -kv[1])[:3]
-    lines.append("### Top-3 time sinks")
-    lines.append("")
-    lines.append("| sink | seconds | suggested knob |")
-    lines.append("|---|---|---|")
-    for name, sec in sinks:
-        lines.append(f"| {name} | {_fmt(sec, 2)} | {_sink_knob(name)} |")
-    lines.append("")
-    return lines
-
-
 # ----------------------------------------------------------------- fleet
 
 
@@ -652,9 +561,6 @@ def build_report(checkpoint_dir: str) -> str:
     else:
         lines.append("No spans recorded (train.trace_spans off — set it or TRLX_TPU_SPANS=1).")
     lines.append("")
-
-    # --- graftscope: device-time attribution & time sinks -----------------
-    lines += _graftscope_section(checkpoint_dir)
 
     # --- graftfleet: cross-host federation --------------------------------
     lines += _fleet_section(checkpoint_dir)

@@ -45,7 +45,6 @@ import time
 from collections import deque
 from contextlib import contextmanager
 
-from trlx_tpu.observability import graftscope
 from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.utils import sanitize
 
@@ -148,7 +147,6 @@ class ScoreWorker:
                 t1 = time.time()
                 sanitize.race_access(self, "busy_s", write=True)
                 self.busy_s += t1 - t0
-                graftscope.host_interval("score", t0, t1)
 
     def submit(self, item):
         self._in.put(item)
@@ -234,7 +232,7 @@ class PrefetchIterator:
             for item in it:
                 if self._stop.is_set():
                     return
-                with trace_span("prefetch/stage"), graftscope.lane_span("prefetch"):
+                with trace_span("prefetch/stage"):
                     staged = ("ok", self._transform(item))
                 if not self._put(staged):
                     return
@@ -345,7 +343,7 @@ class RolloutProducer:
                 staleness = index - self._consumed
             store = self._new_store()
             try:
-                with trace_span("rollout/produce", index=index, staleness=staleness), graftscope.lane_span("producer"):
+                with trace_span("rollout/produce", index=index, staleness=staleness):
                     self._produce(store, index, snapshot, staleness, self._should_stop)
             except BaseException as e:  # noqa: BLE001 — re-raised in next_store()
                 with self._cv:

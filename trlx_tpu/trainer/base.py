@@ -42,7 +42,6 @@ from trlx_tpu.models.lm import flash_kept_pair_share
 from trlx_tpu import observability as obs
 from trlx_tpu.observability import device_scopes
 from trlx_tpu.observability import fleet as obs_fleet
-from trlx_tpu.observability import graftscope as obs_graftscope
 from trlx_tpu.observability import numerics as obs_numerics
 from trlx_tpu.observability import spans as obs_spans
 from trlx_tpu.observability.spans import trace_span
@@ -182,10 +181,6 @@ class JaxBaseTrainer(BaseRLTrainer):
         # command; everything defaults OFF and the instrumentation stays off
         # the hot dispatch path.
         ckpt_dir = os.path.abspath(config.train.checkpoint_dir)
-        # graftscope (attribution ledger + bubble accounting + slot
-        # timeline) needs both the fence hook in DeviceMonitor and the spans
-        # file for its timeline rows, so arming it implies arming those two.
-        graftscope_on = config.train.graftscope or obs.env_flag("TRLX_TPU_GRAFTSCOPE")
         # graftfleet (cross-host federation) owns the span filename when
         # armed: each host writes spans.host<k>.jsonl so read_fleet_spans can
         # merge per-host lanes. Arming it implies span tracing (the merged
@@ -193,7 +188,6 @@ class JaxBaseTrainer(BaseRLTrainer):
         fleet_on = config.train.graftfleet or obs.env_flag("TRLX_TPU_GRAFTFLEET")
         if (
             config.train.trace_spans
-            or graftscope_on
             or fleet_on
             or obs.env_flag("TRLX_TPU_SPANS")
         ):
@@ -292,7 +286,6 @@ class JaxBaseTrainer(BaseRLTrainer):
         self._devicemon = None
         if (
             config.train.device_telemetry
-            or graftscope_on
             or obs.env_flag("TRLX_TPU_DEVICE_TELEMETRY")
         ):
             self._devicemon = obs.DeviceMonitor(
@@ -300,19 +293,6 @@ class JaxBaseTrainer(BaseRLTrainer):
                     os.path.join(ckpt_dir, "programs.json") if is_main_process() else None
                 )
             )
-        self._graftscope = None
-        if graftscope_on:
-            self._graftscope = obs_graftscope.configure(
-                os.path.join(ckpt_dir, obs_graftscope.SNAPSHOT_FILENAME)
-                if is_main_process()
-                else None
-            )
-            self._devicemon.ledger = self._graftscope
-        else:
-            # Same ownership rule as the span tracer above: a prior armed
-            # trainer in this process must not keep its drain thread and
-            # ledger alive into this run.
-            obs_graftscope.shutdown()
         anomaly_factor = float(
             os.environ.get("TRLX_TPU_ANOMALY_FACTOR", "") or config.train.anomaly_factor
         )
@@ -549,69 +529,7 @@ class JaxBaseTrainer(BaseRLTrainer):
         out = monitor.window(phase_seconds)
         out.update(monitor.kernel_routing_gauges())
         out.update(monitor.device_memory_gauges())
-        gs = getattr(self, "_graftscope", None)
-        if gs is not None:
-            out.update(gs.window())
-            self._flush_graftscope_samples(gs)
-            gs.flush()
         return out
-
-    def _flush_graftscope_samples(self, gs) -> None:
-        """Feed the window's raw graftscope samples (per-lane idle gaps,
-        engine refill waits, straggler steps per bucket width) to the
-        tracker's histogram records and, when serving, the /metrics
-        histograms."""
-        samples = gs.drain_samples()
-        if not samples:
-            return
-        exporter = getattr(self, "_metrics_exporter", None)
-        for lane, gaps in sorted(samples.get("lane_gaps", {}).items()):
-            if not gaps:
-                continue
-            self.tracker.log_histogram(
-                "obs/lane_gap_" + lane + "_s", gaps, step=self.iter_count
-            )
-            if exporter is not None:
-                exporter.observe(
-                    "obs/lane_gap_s",
-                    gaps,
-                    buckets=obs_graftscope.LANE_GAP_S_BUCKETS,
-                    labels={"lane": lane},
-                )
-        waits = samples.get("refill_wait_ms") or []
-        if waits:
-            self.tracker.log_histogram(
-                "engine/refill_wait_ms", waits, step=self.iter_count
-            )
-            if exporter is not None:
-                exporter.observe(
-                    "engine/refill_wait_ms",
-                    waits,
-                    buckets=obs_graftscope.REFILL_WAIT_MS_BUCKETS,
-                )
-        for width, steps in sorted((samples.get("straggler_steps") or {}).items()):
-            if not steps:
-                continue
-            if exporter is not None:
-                exporter.observe(
-                    "engine/straggler_steps",
-                    steps,
-                    buckets=obs_graftscope.STRAGGLER_STEPS_BUCKETS,
-                    labels={"width": str(width)},
-                )
-        for width, rates in sorted((samples.get("spec_accept") or {}).items()):
-            if not rates:
-                continue
-            self.tracker.log_histogram(
-                "engine/spec_accept_rate", rates, step=self.iter_count
-            )
-            if exporter is not None:
-                exporter.observe(
-                    "engine/spec_accept_rate",
-                    rates,
-                    buckets=obs_graftscope.SPEC_ACCEPT_RATE_BUCKETS,
-                    labels={"width": str(width)},
-                )
 
     def build_trainable_mask(self, init_params):
         """Default layer-freezing mask (num_layers_unfrozen); subclasses
@@ -839,8 +757,6 @@ class JaxBaseTrainer(BaseRLTrainer):
                     merged.get("time/overlap_fraction", 0.0),
                 )
             )
-        if "obs/bubble_fraction" in merged:
-            parts.append("bub={:.0%}".format(merged["obs/bubble_fraction"]))
         fl = getattr(self, "_fleet", None)
         if fl is not None and jax.process_count() > 1:
             # Fleet readout: host count + the last window's worst aligned
@@ -1086,12 +1002,6 @@ class JaxBaseTrainer(BaseRLTrainer):
                 # Final registry persist: dispatches since the last window
                 # boundary must still show in programs.json for the report.
                 self._devicemon.flush()
-            if self._graftscope is not None:
-                # Joins the fence-drain thread (obs_smoke asserts no trlx-*
-                # threads survive learn()) and writes the final snapshot.
-                self._devicemon.ledger = None
-                obs_graftscope.shutdown()
-                self._graftscope = None
             if self._fleet is not None:
                 # Closes the arrival-record file (no thread to join); the
                 # fleet artifacts stay on disk for read_fleet_spans and the
@@ -1234,7 +1144,6 @@ class JaxBaseTrainer(BaseRLTrainer):
                 if timer is not None:
                     train_dt = max(0.0, batch_span.seconds - self._phase_exclude_s)
                     timer.add("train", train_dt)
-                    obs_graftscope.host_interval("train", batch_span.start_s, batch_span.start_s + train_dt)
             self._close_batch_feed()
             self.post_epoch_callback()
             self._host_t0 = None
@@ -1441,9 +1350,6 @@ class JaxBaseTrainer(BaseRLTrainer):
             now = read.end_s
             since = now - self._telemetry_t0
             self._telemetry_t0 = now
-            # The whole inter-flush stretch is train-lane
-            # host time for the attribution ledger.
-            obs_graftscope.host_interval("train", now - since, now)
             stats_host.update(
                 self._flush_device_telemetry(
                     {"train": since, "wall": since}

@@ -304,7 +304,7 @@ class ILQLTrainer(JaxBaseTrainer):
         )
         # Arming is resolved when the step is BUILT: a disarmed trainer
         # compiles a jaxpr with no numerics reductions, so the serial path
-        # stays byte-identical (same contract as spans/graftscope).
+        # stays byte-identical (same contract as spans).
         graftnum = obs_numerics.armed(self.config.train)
 
         def train_step(state, batch: ILQLBatch):

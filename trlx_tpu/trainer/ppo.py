@@ -993,7 +993,7 @@ class PPOTrainer(JaxBaseTrainer):
             stats.update(rollup_window_stats(stats))
         self._last_phase_stats = stats
         self.tracker.log(stats, step=self.iter_count)
-        # The phase-window gauges (overlap fraction, MFU, graftscope ledger)
+        # The phase-window gauges (overlap fraction, MFU)
         # belong on /metrics too — the per-step export at the log boundary
         # only ever sees train-step stats. Already rolled up above, so no
         # second collective here (the exporter lives on process 0 only).

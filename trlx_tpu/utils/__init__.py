@@ -12,8 +12,8 @@ from typing import Any, Iterable, List
 import numpy as np
 
 # jax is imported inside the three pytree helpers below, not here: the
-# package must import without the accelerator stack (utils/manifest.py is
-# what bench.py's JAX-free parent process journals with).
+# package must import without the accelerator stack (utils/compile_cache.py
+# is asked where the cache is by processes that never start JAX).
 
 
 def flatten(L: Iterable[Iterable[Any]]) -> List[Any]:

@@ -1,6 +1,6 @@
 """The one place that decides where JAX's persistent compilation cache lives.
 
-Rule (the trainer, bench.py and chip_smoke.py all call ``setup_compile_cache``
+Rule (the trainer, benchmark/harness.py and chip_smoke.py all call ``setup_compile_cache``
 and nothing else in the tree sets a directory):
 
 - ``JAX_COMPILATION_CACHE_DIR`` set → the program sets NO directory in code;

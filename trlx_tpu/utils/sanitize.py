@@ -19,8 +19,8 @@ discipline; this module checks the *dynamic* half at runtime when armed:
 - ``race``: an Eraser-style per-field lockset tracker (the runtime dual of
   GL008). Locks built through :func:`make_lock` / :func:`make_condition` /
   :func:`make_dispatch_lock` register in a thread-local held-lock set;
-  declared hot shared fields (producer flags, engine slot state, graftscope
-  buffers, exporter gauges, heartbeat state) report each access through
+  declared hot shared fields (producer flags, engine slot state,
+  exporter gauges, heartbeat state) report each access through
   :func:`race_access`. Once a field has been touched by two threads with at
   least one write, the intersection of held-lock sets must stay non-empty —
   when it empties, :class:`RaceViolation` names BOTH conflicting sites
@@ -367,8 +367,8 @@ class TrackedCondition:
 
 
 def make_lock(name: str):
-    """Race-mode-aware lock factory for hot shared structures (graftscope
-    buffers, exporter gauges, heartbeat state). Unarmed: plain Lock."""
+    """Race-mode-aware lock factory for hot shared structures
+    (exporter gauges, heartbeat state). Unarmed: plain Lock."""
     refresh()
     if _RACE_ON:
         return TrackedLock(name)
