@@ -119,6 +119,8 @@ CELL_CALLS = {  # the train step's attention calls of the five one-chip cells: p
     "gptneo1.3b.ppo-256x256": (512, (0, 256), 0.4382),
     "gptneo1.3b.ilql-256": (256, (0, 256), 0.5020),
     "kimik2.5-l5.ppo-128x896": (1024, (0,), 0.6673),
+    # 6,144 positions in three major pieces; a window of 4,096 cuts 11% of a window layer's pairs
+    "smallthinker-ep4.ppo-4096x2048": (6144, (0, 4096, 4096, 4096), 0.8976),
 }
 
 
@@ -163,6 +165,7 @@ def test_pick_block_reads_only_the_calls_length():
     assert pick_block(1024) == FlashBlocks(512, 1024, 512)  # every cell shape: the sequence is resident
     assert pick_block(512) == FlashBlocks(512, 512, 512) and pick_block(768) == FlashBlocks(256, 768, 256)
     assert pick_block(8192) == FlashBlocks(512, 2048, 512)  # too long for VMEM: major pieces, the same loop
+    assert pick_block(6144) == pick_block(4096) == FlashBlocks(512, 2048, 512)  # a 6,144-token train row, a 4,096-token prefill
     assert pick_block(48) == FlashBlocks(48, 48, 48)  # a length no chunk divides: one whole-length chunk
     assert pick_block(300) == FlashBlocks(300, 300, 300)
 

@@ -281,7 +281,7 @@ def test_counters_by_hand():
     # twice the even share in whole tiles of 512 rows; the worst case is 32768
     assert moe.slot_capacity(4096, 8, 8, 384) == 1536  # Kimi's train step: 2 x 682.7 slots
     assert moe.slot_capacity(4096, 8, 8, 128) == 4096  # K-EXAONE's: 2 x 2048
-    assert moe.slot_capacity(4096, 8, 8, 32) == 8192  # never more than two rows a token
+    assert moe.slot_capacity(4096, 8, 8, 32) == 12288 == moe.SLOTS_PER_TOKEN * 4096  # never more than three rows a token
     assert moe.slot_capacity(48, 2, 4, 16) == 96  # nor than the worst case, where that is no larger
 
 

@@ -66,6 +66,22 @@ def _flash_grouped(q, k, v, m):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
+def _flash_group_of_7(q, k, v, m):
+    """28 query heads over 4 K/V heads of 128 under a 4,096-key window at a
+    length past it (SmallThinker's train step: T 6,144 in three major pieces,
+    the dk/dv kernel's innermost axis 7 x 3 steps)."""
+    loss = lambda q, k, v: flash_attention(
+        q, k, v, m, scale=128**-0.5, causal=True, window=4096, interpret=False
+    ).astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _flash_prefill_group_of_7(q, k, v, m):
+    """The same heads in the rollout's prefill: the forward kernel alone over
+    4,096 positions, the window as wide as the block (it fills each ring exactly)."""
+    return flash_attention(q, k, v, m, scale=128**-0.5, causal=True, window=4096, interpret=False)
+
+
 def _flash_narrow_heads(q, k, v, m):
     """Heads 64 wide (granite-4.0-h-micro's attention layers: 32 query heads
     over 8), padded with zeros to the kernels' 128 as `Attention` pads them."""
@@ -110,6 +126,12 @@ def _fused_held(x, w, y, b):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, w, b)
 
 
+def _fused_no_bias(x, w, y):
+    """An untied head without a bias (SmallThinker's, V = 37,984: 128 does not divide it)."""
+    loss = lambda x, w: sum(o.sum() for o in fused_logprob(x, w, y, None, tied=False, interpret=False))
+    return jax.value_and_grad(loss, argnums=(0, 1))(x, w)
+
+
 def _fused_scoring(x, w, y, b):
     """The scoring program's call: the forward kernel alone over the rollout chunk."""
     return fused_logprob(x, w, y, b, tied=True, interpret=False)[0]
@@ -127,6 +149,10 @@ def _cases(s):
          (s((4, 1024, 64, 128), bf16), s((4, 1024, 8, 128), bf16), s((4, 1024, 8, 128), bf16), s((4, 1024), f32))),
         ("flash fwd+bwd grouped keys 32 over 8, d64 padded to 128", _flash_narrow_heads,
          (s((8, 1024, 32, 64), bf16), s((8, 1024, 8, 64), bf16), s((8, 1024, 8, 64), bf16), s((8, 1024), f32))),
+        ("flash fwd+bwd grouped keys 28 over 4, window 4096, T 6144", _flash_group_of_7,
+         (s((1, 6144, 28, 128), bf16), s((1, 6144, 4, 128), bf16), s((1, 6144, 4, 128), bf16), s((1, 6144), f32))),
+        ("flash fwd grouped keys 28 over 4, window 4096, the prefill at T 4096", _flash_prefill_group_of_7,
+         (s((16, 4096, 28, 128), bf16), s((16, 4096, 4, 128), bf16), s((16, 4096, 4, 128), bf16), s((16, 4096), f32))),
         ("flash fwd+bwd major pieces, traced offset", _flash_ring_chunk,
          (s((1, 8192, H, D), bf16),) * 3 + (s((1, 8192), f32), s((), f32))),
         ("fused_logprob fwd+bwd", _fused, head),
@@ -140,6 +166,10 @@ def _cases(s):
          (s((8 * 255, DM), f32), s((50257, DM), bf16), s((8 * 255,), i32), s((50257,), bf16))),
         ("fused_logprob tied fwd+bwd d7168 V20480", _fused_tied,
          (s((4 * 896, 7168), bf16), s((20480, 7168), bf16), s((4 * 896,), i32))),
+        ("fused_logprob fwd+bwd no bias d2560 V37984, 2048 rows", _fused_no_bias,
+         (s((2048, 2560), bf16), s((2560, 37984), bf16), s((2048,), i32))),
+        ("fused_logprob held [V, D] fwd+bwd d2560 V37984, 4096 rows", _fused_tied,
+         (s((2 * 2048, 2560), bf16), s((37984, 2560), bf16), s((2 * 2048,), i32))),
         ("fused_logprob scoring fwd 28672 rows", _fused_scoring,
          (s((32 * 896, DM), bf16), s((V, DM), bf16), s((32 * 896,), i32), s((V,), bf16))),
     ]

@@ -21,7 +21,8 @@ Supported families match the reference's (reference: README.md:6): gpt2,
 gpt-j, gpt-neo, gpt-neox; granitemoehybrid without experts (state-space
 and attention layers, models/ssm.py); and kimi_linear (gated delta-rule and
 unrotated latent-attention layers, sparse experts: models/kda.py, models/moe.py). `ouro` (the looped family) maps its
-config only: its weights are not imported (`load_hf_trunk` raises). With no checkpoint (or `model_arch` given) params
+config only: its weights are not imported (`load_hf_trunk` raises); so does `smallthinker` (grouped keys, windows beside
+NoPE layers, a softmax router ahead of attention over ReGLU experts). With no checkpoint (or `model_arch` given) params
 initialize from scratch — the randomwalks path
 (reference: examples/randomwalks.py:99-101).
 """
@@ -58,7 +59,9 @@ def build_lm_config(config) -> LMConfig:
 
 
 def lm_config_from_hf(hf, **overrides) -> LMConfig:
-    t = hf.model_type
+    t = getattr(hf, "model_type", None)
+    if t is None and str(getattr(hf, "model_name", "")).startswith("smallthinker"):
+        t = "smallthinker"  # the family's published config.json carries `model_name`
     if t == "gpt2":
         d = dict(
             vocab_size=hf.vocab_size,
@@ -286,6 +289,59 @@ def lm_config_from_hf(hf, **overrides) -> LMConfig:
             exit_threshold=float(given.get("early_exit_threshold", 1.0)),
             extra={"neox_rotary": True},
         )
+    elif t == "smallthinker":
+        # SmallThinker (21B-A3B / 4B-A0.6B): grouped keys, RMSNorm, no bias;
+        # `sliding_window_layout` marks the window layers and `rope_layout` the
+        # rotated ones, and the program builds the stacks in which the two
+        # agree (a window layer rotates, a full-span layer has no position
+        # signal); every layer an expert layer of ReGLU experts, no shared one;
+        # the router a softmax over the chosen logits, reading the block's
+        # input ahead of attention (`moe_enable_early_router`, true where the
+        # key is absent: the family's description). What the program lacks
+        # raises here, by name.
+        given = hf.to_dict() if hasattr(hf, "to_dict") else dict(vars(hf))
+        n_layer = given["num_hidden_layers"]
+        windows, rotated = list(given["sliding_window_layout"]), list(given["rope_layout"])
+        unbuilt = [name for name, on in (
+            ("rope_layout and sliding_window_layout that disagree", [bool(x) for x in windows] != [bool(x) for x in rotated]),
+            ("layouts of another length than num_hidden_layers", len(windows) != n_layer or len(rotated) != n_layer),
+            ("a dense layer (moe_layer_layout)", not all(given.get("moe_layer_layout") or [1])),
+            ("moe_primary_router_apply_softmax false", not given.get("moe_primary_router_apply_softmax")),
+            ("norm_topk_prob false", not given.get("norm_topk_prob")),
+            ("rope_scaling", given.get("rope_scaling") is not None)) if on]
+        if unbuilt:
+            raise ValueError(f"smallthinker: not built: {'; '.join(unbuilt)}")
+        local = any(windows)
+        d = dict(
+            vocab_size=given["vocab_size"],
+            n_layer=n_layer,
+            n_head=given["num_attention_heads"],
+            n_kv_head=given["num_key_value_heads"],
+            head_width=given["head_dim"],
+            d_model=given["hidden_size"],
+            max_position=given["max_position_embeddings"],
+            pos_type="rotary" if local else "none",
+            rotary_layers="local" if local else "all",
+            rope_theta=float(given["rope_theta"]),
+            extra={"neox_rotary": True},
+            attention_layers=tuple("local" if w else "global" for w in windows) if local else (),
+            window_size=int(given["sliding_window_size"]) if local else 0,
+            norm="rmsnorm",
+            mlp="gated",
+            activation="relu",
+            ln_eps=given["rms_norm_eps"],
+            parallel_residual=False,
+            fused_qkv=False,
+            qkv_bias=False,
+            out_bias=False,
+            tie_word_embeddings=bool(given.get("tie_word_embeddings", False)),
+            ffn_layers=("experts",) * n_layer,
+            n_experts=given["moe_num_primary_experts"],
+            experts_per_token=given["moe_num_active_primary_experts"],
+            expert_d_ff=given["moe_ffn_hidden_size"],
+            router_scoring="softmax",
+            router_input="block" if given.get("moe_enable_early_router", True) else "ffn",
+        )
     else:
         raise ValueError(f"unsupported HF model_type for conversion: {t}")
     d.update(overrides)
@@ -452,6 +508,11 @@ def load_hf_trunk(model_path: str, cfg: LMConfig, put=None) -> Dict[str, Any]:
     each converted tensor immediately — dtype cast + sharded device
     placement); falls back to a full torch load for legacy
     pytorch_model.bin checkpoints."""
+    if cfg.router_scoring == "softmax" or cfg.router_input == "block":  # expert layers only: LMConfig holds them to that
+        raise NotImplementedError(
+            "importing a smallthinker checkpoint's weights is not built: the names of the family's tensors could not be "
+            "read without the network, and a guessed mapping would load another model; `model_arch` (weights from "
+            "the seed) is the path that runs")
     if cfg.n_loops > 1:
         raise NotImplementedError(
             "importing a looped checkpoint's weights is not built: the names of the family's tensors (the two "

@@ -195,6 +195,18 @@ class LMConfig:
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     experts_held: Tuple[int, ...] = ()
+    # How the router scores: "sigmoid": sigmoid scores over all n_experts, the
+    # choice by score + a correction bias (a buffer), the chosen renormalised
+    # and scaled; "softmax": the experts_per_token largest of the raw logits,
+    # a softmax over the chosen (= a softmax over all, renormalised over the
+    # chosen), no bias parameter, no scale.
+    router_scoring: str = "sigmoid"
+    # What the router reads: "ffn": the feed-forward's normed input, after
+    # attention; "block": the block's INPUT, before ln_1 and ahead of
+    # attention, so a layer's expert choice is known before its attention
+    # runs; the experts read ln_2(x + attn) as ever. Built for the sequential
+    # residual over attention mixers.
+    router_input: str = "ffn"
     # Per-layer mixer kind ("attention" | "mamba" | "kda"); empty -> all attention.
     # A "mamba" layer is trlx_tpu/models/ssm.py: the Mamba-2 state-space mixer
     # (ssm_heads x ssm_head_dim channels, one B/C group of ssm_state numbers, a
@@ -264,7 +276,8 @@ class LMConfig:
             )
         for name, kinds in (("norm", ("layernorm", "rmsnorm")), ("mlp", ("dense", "gated")),
                             ("attention", ("mha", "mla")), ("rotary_layers", ("all", "local")),
-                            ("window_cache", ("span", "ring")), ("pos_type", ("learned", "rotary", "none"))):
+                            ("window_cache", ("span", "ring")), ("pos_type", ("learned", "rotary", "none")),
+                            ("router_scoring", ("sigmoid", "softmax")), ("router_input", ("ffn", "block"))):
             if getattr(self, name) not in kinds:
                 raise ValueError(f"unknown {name} kind {getattr(self, name)!r} (expected one of {kinds})")
         if self.draw_dtype not in ("", "float32"):
@@ -309,6 +322,18 @@ class LMConfig:
                 raise ValueError(
                     f"expert layers need mlp 'gated', expert_d_ff, 0 < experts_per_token <= n_experts "
                     f"and experts_held inside [0, n_experts): {self.experts_held!r} of {self.n_experts}")
+            if self.router_scoring == "softmax" and self.routed_scaling_factor != 1.0:
+                raise ValueError("router_scoring 'softmax' (a softmax over the chosen logits) takes no scale: "
+                                 f"routed_scaling_factor must be 1, got {self.routed_scaling_factor}")
+        elif self.router_scoring != "sigmoid" or self.router_input != "ffn":
+            raise ValueError("router_scoring and router_input describe expert layers: ffn_layers names none")
+        if self.router_input == "block":
+            unbuilt = [name for name, on in (
+                ("parallel_residual", self.parallel_residual), ("sandwich_norm", self.sandwich_norm),
+                ("a 'mamba' layer", self.has_ssm), ("a 'kda' layer", self.has_kda),
+                ("a looped stack (n_loops > 1)", self.n_loops > 1)) if on]
+            if unbuilt:
+                raise ValueError(f"router_input 'block' (the router ahead of attention) is not built with {', '.join(unbuilt)}")
 
         if self.mixer_layers and (len(self.mixer_layers) != self.n_layer
                                   or set(self.mixer_layers) - {"attention", "mamba", "kda"}):
@@ -1116,12 +1141,21 @@ class Block(nn.Module):
             attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
             return attn(h, attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
 
+        moe = None
+        if self.ffn == "experts":
+            from trlx_tpu.models.moe import ExpertLayer
+
+            moe = ExpertLayer(cfg, name="moe")
+        # `router_input` "block": the expert choice is made from the block's
+        # input, here, ahead of attention, and carried across it (through the
+        # recomputation of a remat'd block, the frozen branch's replay and a
+        # decode step alike: all of them run this function)
+        routed = moe.routing(x) if moe is not None and cfg.router_input == "block" else None
+
         def feed_forward(h):
             nonlocal counts
-            if self.ffn == "experts":
-                from trlx_tpu.models.moe import ExpertLayer
-
-                h, counts = ExpertLayer(cfg, name="moe")(h)
+            if moe is not None:
+                h, counts = moe(h, routed=routed)
                 return h
             return MLP(cfg, name="mlp")(h)
 
@@ -1749,6 +1783,14 @@ def cache_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
     layers, from its own shapes: the counter `rollout/cache_bytes` at the
     generate program's batch and length."""
     return tree_size_bytes(jax.eval_shape(lambda: init_cache(cfg, batch, max_len)))
+
+
+def ring_cache_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
+    """The part of `cache_bytes` that the window layers' rings hold
+    (window_cache "ring"), from `init_cache`'s own shapes: over
+    `rollout/cache_bytes` it is the counter `rollout/ring_cache_share`."""
+    cache = jax.eval_shape(lambda: init_cache(cfg, batch, max_len))
+    return tree_size_bytes([cache[i] for i in range(cfg.n_layer) if ring_slots(cfg, i, max_len)])
 
 
 def state_bytes(cfg: LMConfig, batch: int) -> int:

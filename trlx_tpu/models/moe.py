@@ -6,6 +6,13 @@ deployment holds it.
     w_e = sigma_e / sum_chosen sigma * routed_scaling_factor
     y = sum over (chosen and held) of w_e Expert_e(x) + Shared(x)
 
+That is `LMConfig.router_scoring` "sigmoid". "softmax" is another family's
+rule (`route`): the experts_per_token largest of the raw logits x . W_g, then
+a softmax over the chosen, no bias buffer and no scale. What the router reads
+is a kind too (`LMConfig.router_input`): the feed-forward's input, or the
+block's own input ahead of attention (`ExpertLayer.routing`, called by
+`models/lm.py Block` before its mixer).
+
 The layer is told which routed experts it holds (`LMConfig.experts_held`,
 `[first, first + count)`), routes over all of them at the published width,
 and computes its own experts' part of the result. What the absent experts
@@ -46,7 +53,11 @@ The buffer is sized to the layer's share of the experts, from the call's
 shapes alone (`slot_capacity`): twice the even share of the held experts,
 `2 n k held / n_experts` slots in whole tiles of `ROW_TILE` rows (1,536 rows
 where 8 of 384 experts are held and 4,096 tokens choose 8 each, 4,096 where 8
-of 128 are), never more than `SLOTS_PER_TOKEN` rows a token. A call whose held
+of 128 are), never more than `SLOTS_PER_TOKEN` rows a token (three: where 16
+of 64 experts are held and a token chooses 6, twice the even share is 12,288
+rows for 4,096 tokens, and at two rows a token, a third above even, a router
+over random weights passed the buffer in one layer of eight on the chip and the
+step's time followed the routing; PERF.md, PR 44). A call whose held
 slots pass it takes `dense_held_ffn`, every held expert over every token in
 token chunks, recomputed in the backward pass: a `lax.cond` on the call's held
 slots, exact for any routing. That path is for safety, not a ladder for speed:
@@ -69,20 +80,28 @@ from trlx_tpu.parallel.schedule import use_weight
 
 BIAS_NAME = "e_score_correction_bias"
 SMALL_CALL_SLOTS = 2048  # token-slots (tokens x experts_per_token) up to which a call is "small": a decode step
-SLOTS_PER_TOKEN = 2  # the most rows a token the slot buffer of a large call takes
+SLOTS_PER_TOKEN = 3  # the most rows a token the slot buffer of a large call takes
 ROW_TILE = 512  # a slot buffer is whole row tiles of the grouped product
 TOKEN_CHUNK = 4096  # tokens a pass: bounds the buffers of a scoring pass over a whole rollout chunk
 
 
-def route(x, router, bias, k: int, scaling: float):
+def route(x, router, bias, k: int, scaling: float, scoring: str = "sigmoid"):
     """(ids [n, k] int32, weights [n, k] float32) of tokens `x` [n, d]: the
-    router's product and everything after it in float32, as published."""
+    router's product and everything after it in float32, as published.
+    `scoring` "sigmoid": the rule of the module docstring. "softmax": the k
+    largest of the raw logits and a softmax over those k, which is a softmax
+    over all n_experts renormalised over the chosen; `bias` is None and
+    `scaling` 1 (`LMConfig.router_scoring`)."""
     with jax.named_scope("moe_router"):
-        scores = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST))
-        _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
-        chosen = jnp.take_along_axis(scores, ids, axis=-1)
-        weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scaling
+        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+        if scoring == "softmax":
+            chosen, ids = jax.lax.top_k(logits, k)
+            weights = jax.nn.softmax(chosen, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+            chosen = jnp.take_along_axis(scores, ids, axis=-1)
+            weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scaling
     return ids.astype(jnp.int32), weights
 
 
@@ -225,41 +244,66 @@ def held_experts_ffn(x, ids, weights, first: int, n_experts: int, gate, up, down
 
 
 class ExpertLayer(nn.Module):
-    """The feed-forward of an "experts" layer. Returns (y, counts [held])."""
+    """The feed-forward of an "experts" layer. `__call__` returns (y, counts
+    [held]). The router reads the feed-forward's input `h`, or `router_input`
+    where the block hands one (`LMConfig.router_input` "block": the block's
+    own input). A block that routes ahead of its attention calls `routing`
+    first and hands the result back as `routed`."""
 
     cfg: LMConfig
 
-    @nn.compact
-    def __call__(self, x):
+    def setup(self):
         cfg = self.cfg
-        dtype, d, f = cfg.compute_dtype, cfg.d_model, cfg.expert_d_ff
-        first, held = cfg.held_experts
-        b, t, _ = x.shape
-        router = self.param("router", drawn_in(cfg.draw_dtype, nn.initializers.lecun_normal()), (d, cfg.n_experts), cfg.params_dtype)
+        d, f = cfg.d_model, cfg.expert_d_ff
+        held = cfg.held_experts[1]
+        self.router = self.param("router", drawn_in(cfg.draw_dtype, nn.initializers.lecun_normal()), (d, cfg.n_experts), cfg.params_dtype)
         # Drawn from the seed, small: a trained router's bias is not zero, and
         # a zero one would let a program that forgot it pass every comparison;
         # but the bias exists to even the load out, and a random one of the
         # scores' own size (deviation 0.1 against the sigmoid's 0.2) skews it:
         # the chip read a fullest expert at 6-15 times the mean (PERF.md, PR 26).
-        bias = self.param(BIAS_NAME, nn.initializers.normal(stddev=0.01), (cfg.n_experts,), jnp.float32)
+        # A softmax router (`router_scoring`) has no such buffer.
+        self.bias = None
+        if cfg.router_scoring == "sigmoid":
+            self.bias = self.param(BIAS_NAME, nn.initializers.normal(stddev=0.01), (cfg.n_experts,), jnp.float32)
         stacked = drawn_in(cfg.draw_dtype, nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,)))
-        # on a partitioned mesh a call of many tokens gathers the router and the
-        # stacks here, as a dense layer gathers its kernels (parallel/schedule.py)
-        at_use = lambda w, name: use_weight(w, self.path + (name,), b * t)
-        router = at_use(router, "router")
-        gate = at_use(self.param("experts_gate", stacked, (held, d, f), cfg.params_dtype).astype(dtype), "experts_gate")
-        up = at_use(self.param("experts_up", stacked, (held, d, f), cfg.params_dtype).astype(dtype), "experts_up")
-        down = at_use(self.param("experts_down", stacked, (held, f, d), cfg.params_dtype).astype(dtype), "experts_down")
+        self.experts_gate = self.param("experts_gate", stacked, (held, d, f), cfg.params_dtype)
+        self.experts_up = self.param("experts_up", stacked, (held, d, f), cfg.params_dtype)
+        self.experts_down = self.param("experts_down", stacked, (held, f, d), cfg.params_dtype)
+        if cfg.n_shared_experts:
+            self.shared = MLP(cfg, width=cfg.n_shared_experts * f)
 
-        flat = x.reshape(b * t, d).astype(dtype)
-        ids, weights = route(flat, router, bias, cfg.experts_per_token, cfg.routed_scaling_factor)
+    def _route(self, flat):
+        """(ids, weights) [n, k] of the tokens `flat` [n, d]."""
+        cfg = self.cfg
+        # on a partitioned mesh a call of many tokens gathers the router and the
+        # stacks at their use, as a dense layer gathers its kernels (parallel/schedule.py)
+        router = use_weight(self.router, self.path + ("router",), flat.shape[0])
+        return route(flat, router, self.bias, cfg.experts_per_token, cfg.routed_scaling_factor, cfg.router_scoring)
+
+    def _flat(self, x):
+        return x.reshape(-1, x.shape[-1]).astype(self.cfg.compute_dtype)
+
+    def routing(self, x):
+        """(ids, weights) [b * t, k] of the tokens `x` [b, t, d]."""
+        return self._route(self._flat(x))
+
+    def __call__(self, h, router_input=None, routed=None):
+        cfg = self.cfg
+        dtype = cfg.compute_dtype
+        b, t, d = h.shape
+        flat = self._flat(h)
+        if routed is None:  # traced in the order it always was: the router, the stacks, the tokens
+            routed = self._route(flat if router_input is None else self._flat(router_input))
+        at_use = lambda w, name: use_weight(w.astype(dtype), self.path + (name,), b * t)
+        gate, up, down = (at_use(getattr(self, name), name) for name in ("experts_gate", "experts_up", "experts_down"))
         with jax.named_scope("moe_experts"):
-            y, counts = held_experts_ffn(flat, ids, weights, first, cfg.n_experts, gate, up, down,
+            y, counts = held_experts_ffn(flat, *routed, cfg.held_experts[0], cfg.n_experts, gate, up, down,
                                          ACTIVATIONS[cfg.activation])
         y = y.reshape(b, t, d)
         if cfg.n_shared_experts:
             with jax.named_scope("moe_shared"):
-                y = y + MLP(cfg, width=cfg.n_shared_experts * f, name="shared")(x)
+                y = y + self.shared(h)
         return y, counts
 
 
@@ -271,6 +315,15 @@ def expert_load_stats(counts, n_tokens: int, k: int) -> Tuple[jnp.ndarray, jnp.n
     counts = counts.astype(jnp.float32)
     share = jnp.sum(counts) / (counts.shape[0] * n_tokens * k)
     return share, jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9)
+
+
+def rows_per_held_expert(held_slot_share: float, n_tokens: int, k: int, held: int) -> float:
+    """Mean rows a held expert takes in ONE grouped call of a pass over
+    `n_tokens` tokens (a call a `TOKEN_CHUNK` pass), from the pass's own
+    `moe/held_slot_share`: the counter `moe/rows_per_held_expert` of a step
+    record. What the grouped products' arithmetic stands against the read of
+    an expert's weights with."""
+    return held_slot_share * n_tokens * k / held / token_chunks(n_tokens)
 
 
 def first_buffer_share(counts, n_tokens: int, k: int, n_experts: int):

@@ -27,7 +27,8 @@ import jax
 import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
-from trlx_tpu.models.lm import cache_bytes, cache_bytes_per_token, decode_step_bytes, layer_window, ring_slots, state_bytes
+from trlx_tpu.models.lm import (cache_bytes, cache_bytes_per_token, decode_step_bytes, layer_window, ring_cache_bytes, ring_slots,
+                                state_bytes)
 from trlx_tpu.ops.kv_read import kv_keys_read, kv_scale_mults_per_key
 from trlx_tpu.parallel.schedule import weight_gather_share
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
@@ -266,7 +267,7 @@ class PPOOrchestrator(Orchestrator):
         # (a looped stack: every (loop, layer) entry of the cache is read a step)
         key_layers = [i for i in range(lm_cfg.n_layer) if lm_cfg.mixer(i) == "attention"] * lm_cfg.n_loops
         layer_windows = [layer_window(lm_cfg, i) for i in key_layers]
-        cache_alloc = gen_rows = 0  # bytes of the cache the generate program allocated (the last chunk's), its rows
+        cache_alloc = gen_rows = gen_len = 0  # bytes of the cache the generate program allocated (the last chunk's), its rows and slots
         kv_keys = np.zeros(2, dtype=np.int64)
         experts_touched = []  # a model with expert layers: one reading a chunk
         # Final-chunk stats for logging; placeholders are never logged (the
@@ -414,7 +415,7 @@ class PPOOrchestrator(Orchestrator):
                     [ring_slots(lm_cfg, i, cache_len) for i in key_layers],
                 ))
                 cache_alloc = cache_bytes(lm_cfg, mask_h.shape[0], cache_len)
-                gen_rows = mask_h.shape[0]
+                gen_rows, gen_len = mask_h.shape[0], cache_len
                 episode_steps.extend(int(v) for v in ds["episode_steps"])
                 step_budget = ds["decode_step_budget"]
                 if gen_aux is not None and "experts_touched_per_step" in gen_aux[0]:
@@ -542,6 +543,8 @@ class PPOOrchestrator(Orchestrator):
                 # an int8 cache: a read applies a key's scale once a key, not once an element (a mesh: once an element)
                 rl._last_exp_stats["rollout/kv_scale_mults_per_key"] = kv_scale_mults_per_key(
                     lm_cfg.n_head, lm_cfg.kv_heads, lm_cfg.head_dim)
+            if lm_cfg.window_cache == "ring" and cache_alloc:
+                rl._last_exp_stats["rollout/ring_cache_share"] = ring_cache_bytes(lm_cfg, gen_rows, gen_len) / cache_alloc
             if experts_touched:
                 rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
             if (lm_cfg.has_state or lm_cfg.n_loops > 1) and cache_alloc:

@@ -1326,6 +1326,12 @@ class JaxBaseTrainer(BaseRLTrainer):
         stats_host.update(startup_counters())
         if self._flash_kept_share is not None:
             stats_host["flash/kept_pair_share"] = self._flash_kept_share
+        if "moe/held_slot_share" in stats_host:
+            from trlx_tpu.models.moe import rows_per_held_expert
+
+            cfg, train = self.model.cfg, self.config.train
+            stats_host["moe/rows_per_held_expert"] = rows_per_held_expert(
+                stats_host["moe/held_slot_share"], train.batch_size * train.seq_length, cfg.experts_per_token, cfg.held_experts[1])
         gather_share = weight_gather_share(self._weight_gathers["train"])
         if gather_share is not None:
             stats_host["parallel/weight_gather_share"] = gather_share
