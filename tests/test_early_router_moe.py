@@ -203,12 +203,20 @@ LARGE_CALL_PATHS = {  # 48 tokens, 3 a token, a share of 4 of 16 experts: some 3
     "large call, a buffer that holds the slots": 96,
     "large call, in token chunks": "chunks",
     "large call, every held expert over every token": 8,
+    "large call, a buffer of 32 rows a choice summed back by a gather": 96,
+    "large call, in token chunks of 12 rows a choice summed back by a gather": "chunks",
+}
+GATHER_FROM = {  # `GATHER_ROWS_PER_CHOICE` for the paths that land on the gather's side of it (PR 45); the others keep the module's
+    "large call, a buffer of 32 rows a choice summed back by a gather": 32,
+    "large call, in token chunks of 12 rows a choice summed back by a gather": 12,
 }
 
 
 def _paths(monkeypatch, path):
     if LARGE_CALL_PATHS[path] is not None:
         monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)
+    if path in GATHER_FROM:
+        monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", GATHER_FROM[path])
     if isinstance(LARGE_CALL_PATHS[path], int):
         monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: LARGE_CALL_PATHS[path])
     if LARGE_CALL_PATHS[path] == "chunks":
@@ -220,8 +228,13 @@ def test_gated_relu_experts_against_a_plain_loop(monkeypatch, path):
     """down(relu(gate h) * up h), weighted and summed over the held experts a
     token chose: the small-call form, the grouped products, the token chunks
     and `dense_held_ffn` against a loop over tokens and choices in numpy, and
-    the gradient of a scalar through each against the small call's."""
+    the gradient of a scalar through each against the small call's. The rows
+    go back onto the tokens by the 0/1 product or by a gather, as the
+    buffer's rows a choice say."""
     _paths(monkeypatch, path)
+    forms = []
+    for form in ("sum_rows_product", "sum_rows_gather"):
+        monkeypatch.setattr(moe, form, lambda *a, form=form, fn=getattr(moe, form): forms.append(form) or fn(*a))
     n, d, f, first, held, k = 48, 64, 32, 4, 4, 3
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
     x = jax.random.normal(keys[0], (n, d))
@@ -248,6 +261,9 @@ def test_gated_relu_experts_against_a_plain_loop(monkeypatch, path):
                      argnums=(0, 1, 2, 3))(x, gate, up, down)
     for got, ref in zip(grads, small):
         np.testing.assert_allclose(got, ref, atol=5e-5, rtol=1e-3)
+    # forward, and as `take_rows`' transpose in the backward pass: one form throughout, none in a small call
+    assert set(forms) == (set() if path == "small call" else {"sum_rows_gather"} if path in GATHER_FROM else {"sum_rows_product"})
+    assert len(forms) != 1
 
 
 @pytest.mark.parametrize("path", ["small call", "large call, the buffer from the shapes", "large call, in token chunks"])
@@ -399,6 +415,9 @@ def test_counters_by_hand():
     assert moe.rows_per_held_expert(16 / 64, 4096, 6, 16) == pytest.approx(384.0)  # one pass, a third of the tokens
     # twice the even share of 6,144 slots, whole tiles: three rows a token (SLOTS_PER_TOKEN; at two the chip passed the buffer, PR 44)
     assert moe.slot_capacity(4096, 6, 16, 64) == 12288 == moe.SLOTS_PER_TOKEN * 4096
+    # 2,048 rows of buffer a choice: the rows are summed back by a gather of a token's own six, in a pass and in a train batch alike (PR 45)
+    assert moe.sums_by_gather(12288, 6) and moe.sum_rows_per_token(4096, 6, 16, 64) == moe.sum_rows_per_token(12288, 6, 16, 64) == 6
+    assert not moe.sums_by_gather(4096, 8) and moe.sum_rows_per_token(65536, 6, 16, 64) == 6  # the prefill of 16 prompts: 16 passes
     even = jnp.full((8, 16), 3 * 384, jnp.int32)  # a train step's counts at an even router: three passes of 384 rows an expert
     assert float(moe.first_buffer_share(even, 12288, 6, 64)) == 1.0 and float(moe.first_buffer_share(even * 2 + 1, 12288, 6, 64)) == 0.0 and float(moe.first_buffer_share(even * 3 // 2, 12288, 6, 64)) == 1.0
     # the cache of the cell's rollout: six rings of 4,096 and two spans of 6,144, K and V, 4 heads of 128, bf16
@@ -479,6 +498,7 @@ def test_ppo_two_iterations_on_the_normal_path(tmp_path):
         assert 0.0 < r["moe/held_slot_share"] < 1.0 and r["moe/first_buffer_share"] == 1.0
         # 8 rows of 28 tokens, 3 a token, 4 held, one pass
         assert r["moe/rows_per_held_expert"] == pytest.approx(r["moe/held_slot_share"] * 8 * 28 * 3 / 4)
+        assert r["moe/sum_rows_per_token"] == 4  # 672 token-slots: a small call, one result a held expert
     phases = [r for r in records if "time/window_wall_s" in r]
     itemsize = cfg.compute_dtype.itemsize
     assert phases and all(p["rollout/cache_bytes"] == 8 * (3 * 8 + 28) * 2 * 2 * 16 * itemsize for p in phases)

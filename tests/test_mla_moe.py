@@ -285,11 +285,15 @@ def test_counters_by_hand():
     assert moe.slot_capacity(48, 2, 4, 16) == 96  # nor than the worst case, where that is no larger
 
 
-def _primitives(jaxpr):
+def _equations(jaxpr):
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _primitives(sub)
+            yield from _equations(sub)
+
+
+def _primitives(jaxpr):
+    return (eqn.primitive.name for eqn in _equations(jaxpr))
 
 
 def test_a_decode_step_runs_every_held_expert_whatever_the_routing():
@@ -339,7 +343,8 @@ def test_the_counted_placement_is_the_stable_sort_row_for_row(case, capacity):
     past the held slots are dead; a buffer too small holds the first rows."""
     ids = _routing(case)
     counts = moe.held_counts(ids, 4, 4)
-    slot, live = moe.place_slots(ids, counts, 4, capacity)
+    slot, live, by_token = moe.place_slots(ids, counts, 4, capacity)
+    assert by_token is None  # 12 to 64 rows a choice: the sum back is the product, and nothing is computed for the gather
     want_slot, want_live = _sorted_placement(ids, 4, 4, capacity)
     placed = min(int(counts.sum()), capacity)
     assert slot.shape == live.shape == (capacity,) and slot.dtype == jnp.int32
@@ -350,6 +355,85 @@ def test_the_counted_placement_is_the_stable_sort_row_for_row(case, capacity):
     # a live row's expert, read back from its slot, is its group's
     expert = ids.reshape(-1)[slot[:placed]] - 4
     np.testing.assert_array_equal(expert, np.repeat(np.arange(4), np.asarray(counts))[:placed])
+
+
+FORMS = {"the 0/1 product": 10 ** 9, "a gather of each token's rows": 1}  # `GATHER_ROWS_PER_CHOICE` that makes every call take the form
+
+
+@pytest.mark.parametrize("held_of", ["more held than a token chooses", "fewer held than a token chooses"])
+@pytest.mark.parametrize("capacity", [24, 32, 128], ids=lambda rows: f"{rows} rows")
+@pytest.mark.parametrize("case", ["seed 0", "seed 1", "seed 2", "no token chooses a held expert",
+                                  "every token chooses the same one", "every token chooses two held ones"])
+def test_the_tokens_side_of_the_placement_is_its_inverse_row_for_row(monkeypatch, case, capacity, held_of):
+    """`rows_of` is `place_slots`' own (slot, live) read from the tokens'
+    side: a held choice names the live row that holds its slot, every live row
+    is named by exactly one choice, and a choice that is not held (or whose
+    row lies past a buffer too small) names row 0 under a False mark. A column
+    a choice where the chip holds as many experts as a token chooses, a column
+    a held expert where it holds fewer."""
+    monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", FORMS["a gather of each token's rows"])
+    ids = _routing(case)
+    first, held = (4, 4) if held_of == "more held than a token chooses" else (4, 1)
+    k = ids.shape[-1]
+    counts = moe.held_counts(ids, first, held)
+    slot, live, (rows_of, held_choice) = moe.place_slots(ids, counts, first, capacity)
+    assert rows_of.shape == held_choice.shape == (64, min(k, held)) and rows_of.dtype == jnp.int32 and held_choice.dtype == jnp.bool_
+    slot, live, rows_of, held_choice = (np.asarray(a) for a in (slot, live, rows_of, held_choice))
+    placed = min(int(counts.sum()), capacity)
+    assert int(held_choice.sum()) == placed and not rows_of[~held_choice].any()
+    named = np.zeros(capacity, np.int32)
+    for token, column in zip(*np.nonzero(held_choice)):
+        row = rows_of[token, column]
+        assert live[row] and slot[row] // k == token
+        if held >= k:
+            assert slot[row] == token * k + column  # the column is the choice
+        else:
+            assert np.asarray(ids)[token, slot[row] % k] == first + column  # the column is the held expert
+        named[row] += 1
+    np.testing.assert_array_equal(named[:placed], 1)
+    assert not named[placed:].any()
+    # and the (slot, live) beside it is what the product's placement is
+    monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", FORMS["the 0/1 product"])
+    want_slot, want_live, none = moe.place_slots(ids, counts, first, capacity)
+    assert none is None
+    np.testing.assert_array_equal(slot, want_slot)
+    np.testing.assert_array_equal(live, want_live)
+
+
+@pytest.mark.parametrize("cell, n, k, held, n_experts, capacity, gather", [
+    ("smallthinker-ep4.ppo-4096x2048", 4096, 6, 16, 64, 12288, True),
+    ("kexaone-l5.ppo-128x896", 4096, 8, 8, 128, 4096, False),
+    ("kimilinear-l13.ppo-128x896", 4096, 8, 8, 256, 2048, False),
+    ("kimik2.5-l5.ppo-128x896", 4096, 8, 8, 384, 1536, False),
+])
+def test_the_shapes_pick_the_form_of_the_sum_back(cell, n, k, held, n_experts, capacity, gather):
+    """The rule at the four expert cells' own call shapes (PERF.md section 6,
+    PR 45: both forms' times on the chip): the gather where the buffer has
+    2,048 rows a choice, the product at 512, 256 and 192; and the counter
+    `moe/sum_rows_per_token` says which, for a pass and for a train batch of
+    three passes alike."""
+    assert moe.slot_capacity(n, k, held, n_experts) == capacity
+    assert moe.sums_by_gather(capacity, min(k, held)) is gather
+    assert moe.sum_rows_per_token(n, k, held, n_experts) == moe.sum_rows_per_token(3 * n, k, held, n_experts) == (min(k, held) if gather else capacity)
+    ids = jax.ShapeDtypeStruct((n, k), jnp.int32), jax.ShapeDtypeStruct((held,), jnp.int32)
+    by_token = jax.eval_shape(lambda ids, counts: moe.place_slots(ids, counts, 0, capacity), *ids)[2]
+    assert (by_token is not None) is gather
+    if gather:
+        assert [a.shape for a in by_token] == [(n, min(k, held))] * 2
+
+
+def test_sum_rows_per_token_by_hand(monkeypatch):
+    """`moe/sum_rows_per_token`: what the sum back reads for one token. A
+    small call has no buffer and sums one result a held expert; a large call
+    the whole buffer of a pass under the product, a row a choice under the
+    gather, whichever `sums_by_gather` says."""
+    assert moe.sum_rows_per_token(32, 8, 8, 384) == 8 and moe.sum_rows_per_token(256, 8, 4, 16) == 4  # small calls
+    assert moe.sum_rows_per_token(4096, 8, 8, 384) == 1536 and moe.sum_rows_per_token(32768, 8, 8, 384) == 1536  # the product, a pass's buffer
+    monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", 192)
+    assert moe.sum_rows_per_token(4096, 8, 8, 384) == 8 and moe.sum_rows_per_token(4096, 8, 4, 192) == 4  # 1,536 rows over 8, and over 4 held
+    monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", 193)
+    assert moe.sum_rows_per_token(4096, 8, 8, 384) == 1536
+    # and the train step's record carries it beside `moe/first_buffer_share` (the PPO test below reads it)
 
 
 def _expert_call(seed=0, n=64, d=8, f=16, held=4):
@@ -371,14 +455,16 @@ RUNGS = {  # rows of the slot buffer; the held slots of `_routing("seed 0")`: 33
 }
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("rung", RUNGS)
-def test_each_rung_matches_every_held_expert_over_every_token(monkeypatch, rung):
+def test_each_rung_matches_every_held_expert_over_every_token(monkeypatch, rung, form):
     """The large call is exact for any routing, whichever path the held slots
-    select: forward and the gradient of every input a training run moves
-    (tokens, slot weights, the three stacks) equal `dense_held_ffn`'s in
-    float32; and the grouped path is taken exactly where the buffer holds
-    the slots."""
+    select and whichever form sums the rows back: forward and the gradient of
+    every input a training run moves (tokens, slot weights, the three stacks)
+    equal `dense_held_ffn`'s in float32; and the grouped path is taken exactly
+    where the buffer holds the slots."""
     monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)
+    monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", FORMS[form])
     if RUNGS[rung] is None:
         monkeypatch.setattr(moe, "ROW_TILE", 8)
         assert moe.slot_capacity(64, 2, 4, 16) == 64
@@ -405,8 +491,9 @@ def test_each_rung_matches_every_held_expert_over_every_token(monkeypatch, rung)
     assert taken == (rows if 33 <= rows else -1.0)
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("rung", ["the buffer holds the slots with room", "the worst case: no cond"])
-def test_what_a_grouped_product_leaves_in_dead_rows_reaches_nothing(monkeypatch, rung):
+def test_what_a_grouped_product_leaves_in_dead_rows_reaches_nothing(monkeypatch, rung, form):
     """A grouped product leaves the rows outside its groups unwritten, in its
     result and in its lhs-gradient (on the chip: whatever was there, PERF.md
     PR 26). Non-finite values planted in both reach neither the result nor a
@@ -430,6 +517,7 @@ def test_what_a_grouped_product_leaves_in_dead_rows_reaches_nothing(monkeypatch,
 
     planted.defvjp(planted_fwd, planted_bwd)
     monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)
+    monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", FORMS[form])
     monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: RUNGS[rung])
     ids = _routing("seed 0")
     args = _expert_call()
@@ -445,20 +533,36 @@ def test_what_a_grouped_product_leaves_in_dead_rows_reaches_nothing(monkeypatch,
         np.testing.assert_allclose(g, w, atol=1e-6, rtol=1e-5, err_msg=name)
 
 
-def test_put_rows_is_the_transpose_of_take_rows():
-    """The two maps between tokens and buffer rows, by hand: a dead row takes
-    nothing and gives nothing, a token two rows name gets their sum, and each
-    is the other's gradient."""
+@pytest.mark.parametrize("form", FORMS)
+def test_put_rows_is_the_transpose_of_take_rows(form):
+    """The two maps between tokens and buffer rows, by hand, in both forms of
+    the sum back: a dead row takes nothing and gives nothing, a token two rows
+    name gets their sum, and each is the other's gradient. Under the gather a
+    dead row need not be finite: it is selected, not multiplied by zero."""
     x = jnp.arange(12, dtype=jnp.float32).reshape(4, 3) + 1
     token, live = jnp.array([2, 0, 2, 0, 0], jnp.int32), jnp.array([True, True, True, False, False])
-    rows = moe.take_rows(4, x, token, live)
+    # the same placement from the tokens' side: token 0 is named by row 1, token 2 by rows 0 and 2, a column a choice
+    rows_of = jnp.array([[0, 1], [0, 0], [0, 2], [0, 0]], jnp.int32)
+    held_choice = jnp.array([[False, True], [False, False], [True, True], [False, False]])
+    placed = (token, live, (rows_of, held_choice) if form == "a gather of each token's rows" else None)
+    rows = moe.take_rows(4, x, placed)
     np.testing.assert_array_equal(rows, [x[2], x[0], x[2], [0, 0, 0], [0, 0, 0]])
     v = jnp.arange(15, dtype=jnp.float32).reshape(5, 3) * 1.25 + 0.1
-    back = moe.put_rows(4, v, token, live)
+    back = moe.put_rows(4, v, placed)
     np.testing.assert_array_equal(back, [v[1], [0, 0, 0], v[0] + v[2], [0, 0, 0]])
     np.testing.assert_array_equal(back, jnp.zeros_like(x).at[token].add(jnp.where(live[:, None], v, 0)))
-    np.testing.assert_array_equal(jax.grad(lambda x: jnp.sum(moe.take_rows(4, x, token, live) * v))(x), back)
-    np.testing.assert_array_equal(jax.grad(lambda v: jnp.sum(moe.put_rows(4, v, token, live) * x))(v), rows)
+    np.testing.assert_array_equal(jax.grad(lambda x: jnp.sum(moe.take_rows(4, x, placed) * v))(x), back)
+    np.testing.assert_array_equal(jax.grad(lambda v: jnp.sum(moe.put_rows(4, v, placed) * x))(v), rows)
+    planted = v.at[3:].set(jnp.array([[jnp.nan, jnp.inf, -jnp.inf]] * 2))
+    if placed[2] is None:
+        assert not bool(jnp.all(jnp.isfinite(moe.put_rows(4, planted, placed))))  # 0 x NaN: the product needs its dead rows finite
+    else:
+        np.testing.assert_array_equal(moe.put_rows(4, planted, placed), back)
+        np.testing.assert_array_equal(jax.grad(lambda x: jnp.sum(moe.take_rows(4, x, placed) * planted))(x), back)
+    # bf16 rows: the sum is in float32 and rounded once, in either form
+    tiny = jnp.array([[256.0], [1.0], [1.0], [0.0], [0.0]], jnp.bfloat16)
+    by_token = None if placed[2] is None else (jnp.array([[0, 1, 2]], jnp.int32), jnp.array([[True, True, True]]))
+    assert float(moe.put_rows(1, tiny, (jnp.zeros(5, jnp.int32), live, by_token))[0, 0]) == 258.0  # 256 + 1 + 1 in bf16 steps would stay 256
 
 
 def test_first_buffer_share_by_hand(monkeypatch):
@@ -476,21 +580,29 @@ def test_first_buffer_share_by_hand(monkeypatch):
     # and the train step's record carries it beside the two counters of PR 26 (the PPO test below reads it)
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("call", ["large call", "large call in token chunks", "small call"])
-def test_no_call_sorts_and_the_small_call_neither_scatters_nor_groups(monkeypatch, call):
-    """The large call places its slots by counting: its jaxpr, forward and
-    backward, holds no `sort`; the small call's holds no `sort`, no scatter
-    and no grouped product, as before."""
+def test_no_call_sorts_and_the_small_call_neither_scatters_nor_groups(monkeypatch, call, form):
+    """The large call places its slots by counting and sums them back by a
+    product or a gather: its jaxpr, forward and backward, holds no `sort` and
+    no `scatter-add` of rows in either form (the pair `take_rows` / `put_rows`
+    is its own transpose; autodiff of a gather of rows would be one: the one
+    scatter-add there is, and was, adds the slot weights' gradients, scalars,
+    into a vector); the small call's holds no `sort`, no scatter and no
+    grouped product, as before."""
+    monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", FORMS[form])
     n = {"large call": 2048, "large call in token chunks": 8192, "small call": 128}[call]
     args = (jax.ShapeDtypeStruct((n, 8), jnp.float32), jax.ShapeDtypeStruct((n, 8), jnp.int32), jax.ShapeDtypeStruct((n, 8), jnp.float32),
             jax.ShapeDtypeStruct((8, 8, 16), jnp.float32), jax.ShapeDtypeStruct((8, 8, 16), jnp.float32), jax.ShapeDtypeStruct((8, 16, 8), jnp.float32))
     fn = lambda x, ids, w, gate, up, down: jnp.sum(moe.held_experts_ffn(x, ids, w, 0, 384, gate, up, down, jax.nn.silu)[0])
-    names = set(_primitives(jax.make_jaxpr(jax.value_and_grad(fn, argnums=(0, 2, 3, 4, 5)))(*args).jaxpr))
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(fn, argnums=(0, 2, 3, 4, 5)))(*args).jaxpr
+    names = set(_primitives(jaxpr))
     assert "sort" not in names
+    assert all(len(eqn.invars[0].aval.shape) == 1 for eqn in _equations(jaxpr) if eqn.primitive.name == "scatter-add")
     if call == "small call":
         assert not {"scatter", "scatter-add", "ragged_dot_general", "cond"} & names
     else:
-        assert {"ragged_dot_general", "cond", "cumsum"} <= names
+        assert {"ragged_dot_general", "cond", "cumsum", "scatter", "gather"} <= names  # the one scatter is `place_slots`' own `.at[row].set`
 
 
 def test_generate_carries_experts_touched_and_stays_one_loop():
@@ -568,6 +680,7 @@ def test_ppo_two_iterations_on_the_normal_path(tmp_path):
         assert np.isfinite(r["loss"]) if "loss" in r else True
         assert 0.0 < r["moe/held_slot_share"] < 1.0 and r["moe/max_expert_load"] >= 1.0
         assert r["moe/first_buffer_share"] == 1.0  # a tiny model's train step is a small call: no buffer to overflow
+        assert r["moe/sum_rows_per_token"] == ARCH["experts_held"][1]  # and sums one result a held expert
     phases = [r for r in records if "time/window_wall_s" in r]
     assert phases and all(0.0 <= p["rollout/experts_touched"] <= 4.0 for p in phases)
     assert all(p["rollout/cache_bytes_per_token"] == (16 + 8) * 4 * 3 for p in phases)

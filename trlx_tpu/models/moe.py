@@ -45,9 +45,18 @@ Where a slot goes is counted, not sorted (`place_slots`): a token chooses an
 expert at most once, so a slot's row is its expert's offset plus the tokens
 before its own that chose the same expert, which is the row a stable sort by
 expert gives it. The rows are gathered from the tokens (`take_rows`) and summed
-back onto them (`put_rows`) as one product with the placement's 0/1 matrix:
-XLA's scatter-add cost more than the three products and did not fall with the
-rows (PERF.md, PR 31).
+back onto them (`put_rows`), never by XLA's scatter-add, which cost more than
+the three products and did not fall with the rows (PERF.md, PR 31). The sum
+has two forms and the call's shapes say which runs (`sums_by_gather`): ONE
+product with the placement's 0/1 matrix `[tokens, rows]`, whose work grows with
+the buffer's rows, or a gather of each token's own rows (`place_slots` hands
+out the inverse placement, the row of each of a token's choices), whose
+traffic grows with the choices a token has, held or not. The product where
+the buffer is narrow (1,536 rows of 7,168 for 4,096 tokens of 8 choices: a
+token holds 0.17 of its choices and the gather reads all 8; 0.47 ms on the
+chip against 1.47), the gather where it is wide (12,288 rows of 2,560 for
+4,096 tokens of 6 choices: the product is 258 GFLOP a call where the three
+grouped products it serves need 72; 1.34 ms against 0.38; PERF.md, PR 45).
 
 The buffer is sized to the layer's share of the experts, from the call's
 shapes alone (`slot_capacity`): twice the even share of the held experts,
@@ -82,6 +91,7 @@ BIAS_NAME = "e_score_correction_bias"
 SMALL_CALL_SLOTS = 2048  # token-slots (tokens x experts_per_token) up to which a call is "small": a decode step
 SLOTS_PER_TOKEN = 3  # the most rows a token the slot buffer of a large call takes
 ROW_TILE = 512  # a slot buffer is whole row tiles of the grouped product
+GATHER_ROWS_PER_CHOICE = 640  # rows of slot buffer a choice of a token from which the sum back onto the tokens is a gather: on the chip the two forms cross at 510-610
 TOKEN_CHUNK = 4096  # tokens a pass: bounds the buffers of a scoring pass over a whole rollout chunk
 
 
@@ -152,14 +162,36 @@ def dense_held_ffn(x, ids, weights, first: int, gate, up, down, act):
     return jax.lax.scan(one_chunk, None, (split(x), split(ids), split(weights)))[1].reshape(n + pad, d)[:n]
 
 
+def sums_by_gather(capacity: int, choices: int) -> bool:
+    """Which form `put_rows` takes over a buffer of `capacity` rows where a
+    token has `choices` rows at most, from those two alone: the 0/1 product
+    does 2 x capacity FLOPs a token a column, the gather moves 2 x choices
+    bytes, so the gather wins from some number of buffer rows a choice on
+    (`GATHER_ROWS_PER_CHOICE`). On the chip, bf16, 4,096 tokens, ms a call,
+    product / gather: 1.34 / 0.38 at 12,288 rows of 2,560 and 6 choices
+    (2,048 rows a choice), 1.06 / 1.27 at 4,096 of 6,144 and 8 (512), 0.20 /
+    0.40 at 2,048 of 2,304 and 8 (256), 0.47 / 1.47 at 1,536 of 7,168 and 8
+    (192): the product runs at 1.04e-14 s a token a row a column at all four,
+    the gather at 5.3-6.3e-12 s a token a choice a column, and they cross at
+    510-610 rows a choice (PERF.md, PR 45)."""
+    return capacity >= GATHER_ROWS_PER_CHOICE * choices
+
+
 def place_slots(ids, counts, first: int, capacity: int):
-    """(slot [capacity] int32, live [capacity] bool): the (token, choice) slot
-    `token * k + choice` that each row of a slot buffer holds: the held experts
-    in order and an expert's tokens in theirs, which is where a stable sort of
-    the slots by expert puts them. By counting: a token chooses an expert at
-    most once, so a slot's row is its expert's offset (the counts before it)
-    plus the tokens before its own that chose the same expert. Rows past the
-    held slots are not live and name slot 0."""
+    """(slot [capacity] int32, live [capacity] bool, by_token): the (token,
+    choice) slot `token * k + choice` that each row of a slot buffer holds:
+    the held experts in order and an expert's tokens in theirs, which is where
+    a stable sort of the slots by expert puts them. By counting: a token
+    chooses an expert at most once, so a slot's row is its expert's offset
+    (the counts before it) plus the tokens before its own that chose the same
+    expert. Rows past the held slots are not live and name slot 0.
+
+    `by_token` is the same placement read from the tokens' side, for the sum
+    back as a gather, and None where the shapes say the sum is the product
+    (`sums_by_gather`): (rows_of [n, s] int32, held_choice [n, s] bool) with
+    s = min(k, held), the buffer row of each of a token's choices (of each
+    held expert where fewer are held than a token chooses), row 0 and False
+    where the choice is not held or its row lies past the buffer."""
     n, k = ids.shape
     held = counts.shape[0]
     hit = ids.T[None] == first + jnp.arange(held, dtype=ids.dtype)[:, None, None]  # [held, k, n]: tokens along the lanes
@@ -168,33 +200,64 @@ def place_slots(ids, counts, first: int, capacity: int):
     row = jnp.where(chose, (jnp.cumsum(counts) - counts)[:, None] + rank, capacity)
     slot = jnp.arange(n, dtype=jnp.int32)[None] * k + jnp.argmax(hit, axis=1).astype(jnp.int32)
     slot = jnp.zeros(capacity, jnp.int32).at[row.reshape(-1)].set(slot.reshape(-1), mode="drop")
-    return slot, jnp.arange(capacity) < jnp.sum(counts)
+    by_token = None
+    if sums_by_gather(capacity, min(k, held)):
+        # a (choice, token) pair is hit by one held expert at most: the least over the experts is its row, or `capacity`
+        rows = row if held < k else jnp.min(jnp.where(hit, row[:, None], capacity), axis=0)
+        in_buffer = rows < capacity
+        by_token = (jnp.where(in_buffer, rows, 0).T, in_buffer.T)
+    return slot, jnp.arange(capacity) < jnp.sum(counts), by_token
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def take_rows(n: int, x, token, live):
-    """[rows, d]: row r is `x[token[r]]` of `x` [n, d], zero where not live."""
+def take_rows(n: int, x, placed):
+    """[rows, d]: row r is `x[token[r]]` of `x` [n, d], zero where not live;
+    `placed` is (token [rows], live [rows], by_token), `place_slots`' own."""
+    token, live, _ = placed
     return jnp.where(live[:, None], x[token], 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def put_rows(n: int, v, token, live):
-    """[n, d]: the transpose of `take_rows`, token t the sum of the live rows
-    of `v` [rows, d] that name it, as ONE product with the 0/1 matrix
-    `[n, rows]` of the placement: exact (a row is taken whole, the sum is in
-    float32). XLA's scatter-add does not fall with the rows: on the chip 3.21
-    ms at 1,536 rows of 7,168 where this takes 0.49, 1.63 at 4,096 rows of
-    6,144 where this takes 1.17 (PERF.md, PR 31). A dead row has to be
-    finite: 0 x NaN is NaN here too."""
+def sum_rows_product(n: int, v, token, live):
+    """`put_rows` as ONE product with the 0/1 matrix `[n, rows]` of the
+    placement: exact (a row is taken whole, the sum is in float32), at the
+    MXU's rate, 2 x rows FLOPs a token a column. A dead row has to be finite:
+    0 x NaN is NaN here too."""
     takes = (token[None, :] == jnp.arange(n, dtype=token.dtype)[:, None]) & live[None, :]
     return jnp.dot(takes.astype(v.dtype), v, precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32).astype(v.dtype)
 
 
-take_rows.defvjp(lambda n, x, token, live: (take_rows(n, x, token, live), (token, live)),
-                 lambda n, placed, g: (put_rows(n, g, *placed), None, None))
-put_rows.defvjp(lambda n, v, token, live: (put_rows(n, v, token, live), (token, live)),
-                lambda n, placed, g: (take_rows(n, g, *placed), None, None))
+def sum_rows_gather(v, rows_of, held_choice):
+    """`put_rows` as a gather: each token reads the row of each of its
+    choices and adds the held ones in float32, one choice at a time into one
+    `[n, d]` sum, so that no `[n, s, d]` float32 array is kept (ONE gather
+    `v[rows_of]` and a reduction took 0.92 ms and 294 MB of temporaries on
+    the chip where this takes 0.38 and 84; PERF.md, PR 45). The same sum of
+    the same rows as the product's, in the order of a token's choices. A
+    `where` selects: what a row no token names holds reaches nothing."""
+    total = jnp.zeros((rows_of.shape[0], v.shape[1]), jnp.float32)
+    for choice in range(rows_of.shape[1]):
+        total = total + jnp.where(held_choice[:, choice, None], v[rows_of[:, choice]], 0).astype(jnp.float32)
+    return total.astype(v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def put_rows(n: int, v, placed):
+    """[n, d]: the transpose of `take_rows`, token t the sum of the live rows
+    of `v` [rows, d] that name it, by the tokens' side of the placement where
+    `place_slots` handed one out (`sum_rows_gather`) and as the 0/1 product
+    where it did not (`sum_rows_product`). Neither is XLA's scatter-add, which
+    does not fall with the rows: on the chip 3.21 ms at 1,536 rows of 7,168
+    where the product takes 0.49, 1.63 at 4,096 rows of 6,144 where it takes
+    1.17 (PERF.md, PR 31), 2.87 at 12,288 rows of 2,560 where the product
+    takes 1.34 and the gather 0.38 (PERF.md, PR 45)."""
+    token, live, by_token = placed
+    return sum_rows_product(n, v, token, live) if by_token is None else sum_rows_gather(v, *by_token)
+
+
+# each is the other's transpose, in whichever form the placement says: autodiff of a gather would be a scatter-add
+take_rows.defvjp(lambda n, x, placed: (take_rows(n, x, placed), placed), lambda n, placed, g: (put_rows(n, g, placed), None))
+put_rows.defvjp(lambda n, v, placed: (put_rows(n, v, placed), placed), lambda n, placed, g: (take_rows(n, g, placed), None))
 
 
 def grouped_held_ffn(x, ids, weights, counts, first: int, capacity: int, gate, up, down, act):
@@ -202,8 +265,8 @@ def grouped_held_ffn(x, ids, weights, counts, first: int, capacity: int, gate, u
     `capacity` rows, which has to hold every held slot: gathered by
     `place_slots`, one group an expert, summed back onto the tokens."""
     n = x.shape[0]
-    slot, live = place_slots(ids, counts, first, capacity)
-    token = slot // ids.shape[-1]
+    slot, live, by_token = place_slots(ids, counts, first, capacity)
+    placed = (slot // ids.shape[-1], live, by_token)
     w = jnp.where(live, weights.reshape(-1)[slot], 0.0).astype(x.dtype)
 
     # A grouped product writes only the rows of its groups: what it leaves in
@@ -214,11 +277,11 @@ def grouped_held_ffn(x, ids, weights, counts, first: int, capacity: int, gate, u
     # its forward keeps dead rows out of a sum, its backward zeroes what the
     # transposed product left in them.
     alive = lambda a: jnp.where(live[:, None], a, 0)
-    xs = alive(take_rows(n, x, token, live))
+    xs = alive(take_rows(n, x, placed))
     with jax.named_scope("moe_grouped_ffn"):
         hidden = alive(act(jax.lax.ragged_dot(xs, gate, counts)) * jax.lax.ragged_dot(xs, up, counts))
         ys = alive(jax.lax.ragged_dot(hidden, down, counts))
-    return put_rows(n, ys * w[:, None], token, live)
+    return put_rows(n, ys * w[:, None], placed)
 
 
 def held_experts_ffn(x, ids, weights, first: int, n_experts: int, gate, up, down, act):
@@ -324,6 +387,19 @@ def rows_per_held_expert(held_slot_share: float, n_tokens: int, k: int, held: in
     record. What the grouped products' arithmetic stands against the read of
     an expert's weights with."""
     return held_slot_share * n_tokens * k / held / token_chunks(n_tokens)
+
+
+def sum_rows_per_token(n_tokens: int, k: int, held: int, n_experts: int) -> int:
+    """Rows the sum back onto the tokens reads for ONE token in a pass over
+    `n_tokens` tokens, from shapes only: the counter `moe/sum_rows_per_token`
+    of a step record, which says what form of `put_rows` ran. The whole slot
+    buffer of a `TOKEN_CHUNK` pass under the 0/1 product, a row a choice under
+    the gather (`sums_by_gather`); a small call has no buffer and sums one
+    result a held expert."""
+    if n_tokens * k <= SMALL_CALL_SLOTS:
+        return held
+    capacity, choices = slot_capacity(n_tokens // token_chunks(n_tokens), k, held, n_experts), min(k, held)
+    return choices if sums_by_gather(capacity, choices) else capacity
 
 
 def first_buffer_share(counts, n_tokens: int, k: int, n_experts: int):

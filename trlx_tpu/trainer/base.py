@@ -1327,11 +1327,12 @@ class JaxBaseTrainer(BaseRLTrainer):
         if self._flash_kept_share is not None:
             stats_host["flash/kept_pair_share"] = self._flash_kept_share
         if "moe/held_slot_share" in stats_host:
-            from trlx_tpu.models.moe import rows_per_held_expert
+            from trlx_tpu.models.moe import rows_per_held_expert, sum_rows_per_token
 
-            cfg, train = self.model.cfg, self.config.train
+            cfg, tokens = self.model.cfg, self.config.train.batch_size * self.config.train.seq_length
             stats_host["moe/rows_per_held_expert"] = rows_per_held_expert(
-                stats_host["moe/held_slot_share"], train.batch_size * train.seq_length, cfg.experts_per_token, cfg.held_experts[1])
+                stats_host["moe/held_slot_share"], tokens, cfg.experts_per_token, cfg.held_experts[1])
+            stats_host["moe/sum_rows_per_token"] = sum_rows_per_token(tokens, cfg.experts_per_token, cfg.held_experts[1], cfg.n_experts)
         gather_share = weight_gather_share(self._weight_gathers["train"])
         if gather_share is not None:
             stats_host["parallel/weight_gather_share"] = gather_share
