@@ -1,8 +1,10 @@
 """The flash kernels' numerics in tier-1 (interpret mode, tiny shapes): the
 loop over live chunks, the band's mask, the additive key bias, the carry
-over `major` pieces — against a dense reference; the
-liveness rule against a brute-force reading of the dense mask; the counter
-`flash/kept_pair_share` against pairs counted by hand; and the kernel names
+over `major` pieces, the chunks a row's padding takes out — against a dense
+reference and, to the bit, against the same kernels with the valid key range
+forced whole; the liveness rule and the range rule against a brute-force
+reading of the dense mask; the counters `flash/kept_pair_share` and
+`flash/pad_dead_chunk_share` against counts by hand; and the kernel names
 the benchmark's recorder compares with a cell's `expect_kernels`."""
 
 import jax
@@ -10,13 +12,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from trlx_tpu.ops import flash_attention as fa
 from trlx_tpu.ops.flash_attention import (
     FlashBlocks,
     flash_attention,
     kept_pair_share,
     live_chunks,
     mask_band,
+    pad_dead_chunks,
     pick_block,
+    valid_block,
+    valid_chunks,
 )
 
 B, T, H, D = 2, 384, 2, 32
@@ -28,7 +34,14 @@ PADDINGS = {
     "left-inside-a-chunk": lambda m: m.at[0, :17].set(0),
     "left-whole-chunks": lambda m: m.at[0, :200].set(0),  # one whole chunk and part of the next
     "right": lambda m: m.at[0, 300:].set(0),
+    # pads that cover whole chunks: the loop's bounds leave the band's
+    "left-one-chunk": lambda m: m.at[0, :128].set(0),
+    "left-two-chunks-and-a-half": lambda m: m.at[0, :320].set(0),
+    "right-one-chunk-and-a-half": lambda m: m.at[0, 192:].set(0),
+    "left-and-right": lambda m: m.at[0, :130].set(0).at[0, 250:].set(0).at[1, :128].set(0),
+    "one-row-all-padding": lambda m: m.at[0, :].set(0),
 }
+WHOLE_CHUNK_PADDINGS = [p for p in PADDINGS if p not in ("no-padding", "left-inside-a-chunk", "right")]
 OFFSETS = {"offset0": None, "traced-offset": 128.0}  # the second: a ring chunk's displacement, with return_lse
 BLOCKS = {
     "block=chunk": FlashBlocks(128, T, CHUNK),
@@ -36,6 +49,7 @@ BLOCKS = {
     "one-chunk": FlashBlocks(T, T, T),
     "major-pieces": FlashBlocks(128, 128, CHUNK),  # three pieces: the state is carried in scratch
 }
+PIECES_OF_CHUNKS = FlashBlocks(128, 192, 64)  # two pieces of three chunks: a pad takes chunks out of a piece
 
 
 def dense_mask(t, window, offset=0, causal=True):
@@ -44,8 +58,10 @@ def dense_mask(t, window, offset=0, causal=True):
     return m & (ki > qi - window) if window else m
 
 
-def ref_attn(q, k, v, kvmask, scale, window, offset):
-    m = jnp.asarray(dense_mask(q.shape[1], window, offset))[None, None] & kvmask[:, None, None, :].astype(bool)
+def ref_attn(q, k, v, kvmask, scale, window, offset, causal=True):
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    m = jnp.asarray(dense_mask(q.shape[1], window, offset, causal))[None, None] & kvmask[:, None, None, :].astype(bool)
     s = jnp.where(m, jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale, -1e9)
     lse = jax.nn.logsumexp(s, -1)
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v), lse
@@ -57,40 +73,89 @@ def qkv():
     return tuple(jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32) for _ in range(3))
 
 
-@pytest.mark.parametrize("blocks", BLOCKS)
-@pytest.mark.parametrize("offset", OFFSETS)
-@pytest.mark.parametrize("padding", PADDINGS)
-@pytest.mark.parametrize("window", WINDOWS)
-def test_forward_dq_dk_dv_match_reference(qkv, window, padding, offset, blocks):
-    window, blocks, off = WINDOWS[window], BLOCKS[blocks], OFFSETS[offset]
-    kvmask = PADDINGS[padding](jnp.ones((B, T), jnp.int32))
+def whole_range(kmask):
+    """`key_range` forced whole: the kernels' bounds are the band's alone."""
+    b, t = kmask.shape[0], kmask.shape[-1]
+    return jnp.stack([jnp.zeros((b,), jnp.int32), jnp.full((b,), t, jnp.int32)])
+
+
+def check_against_reference(monkeypatch, qkv, kvmask, *, window, blocks, off=None, causal=True, to_the_bit=False):
+    """Forward and the three gradients against the dense reference at every
+    row with a key to see; finite at EVERY position; and, `to_the_bit`, equal
+    to the same kernels with the valid key range forced whole."""
+    t = qkv[0].shape[1]
     scale = 0.25 if window == 0 else D**-0.5
     # Rows with no key to see (padding queries, or every key of a displaced
     # chunk in their future) are left out: both sides emit a meaningless mix
     # there, normalized differently, and every loss masks them.
-    seen = (dense_mask(T, window, int(off or 0))[None] & np.asarray(kvmask, bool)[:, None, :]).any(-1)
+    seen = (dense_mask(t, window, int(off or 0), causal)[None] & np.asarray(kvmask, bool)[:, None, :]).any(-1)
     rows = jnp.asarray(seen, jnp.float32)
 
     def flash(q, k, v):
         return flash_attention(
-            q, k, v, kvmask, scale=scale, window=window, blocks=blocks, return_lse=True,
+            q, k, v, kvmask, scale=scale, causal=causal, window=window, blocks=blocks, return_lse=True,
             offset=None if off is None else jnp.float32(off),
         )
 
-    def loss(fn):
-        def f(q, k, v):
+    def run(fn):
+        """(o, lse) and (dq, dk, dv) of the test's loss, in one pass forward and one back."""
+        def loss(q, k, v):
             o, lse = fn(q, k, v)
             use_lse = 0.0 if off is None else 1.0  # the ring path differentiates through lse
-            return jnp.sum(jnp.sin(o) * rows[:, :, None, None]) + use_lse * jnp.sum(jnp.where(rows[:, None, :] > 0, lse, 0.0))
+            total = jnp.sum(jnp.sin(o) * rows[:, :, None, None]) + use_lse * jnp.sum(jnp.where(rows[:, None, :] > 0, lse, 0.0))
+            return total, (o, lse)
 
-        return f
+        (_, outs), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(*qkv)
+        return dict(zip(("o", "lse", "dq", "dk", "dv"), (*outs, *grads)))
 
-    ref = lambda q, k, v: ref_attn(q, k, v, kvmask, scale, window, int(off or 0))
-    (o, lse), (ro, rlse) = flash(*qkv), ref(*qkv)
-    np.testing.assert_allclose(np.asarray((o - ro) * rows[:, :, None, None]), 0.0, atol=2e-5)
-    np.testing.assert_allclose(np.asarray((lse - rlse) * rows[:, None, :]), 0.0, atol=2e-5)
-    for name, got, want in zip(("dq", "dk", "dv"), jax.grad(loss(flash), (0, 1, 2))(*qkv), jax.grad(loss(ref), (0, 1, 2))(*qkv)):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5, err_msg=name)
+    got = run(flash)
+    want = run(lambda q, k, v: ref_attn(q, k, v, kvmask, scale, window, int(off or 0), causal))
+    at = {"o": rows[:, :, None, None], "lse": rows[:, None, :]}
+    for name, atol in (("o", 2e-5), ("lse", 2e-5), ("dq", 5e-5), ("dk", 5e-5), ("dv", 5e-5)):
+        np.testing.assert_allclose(np.asarray((got[name] - want[name]) * at.get(name, 1.0)), 0.0, atol=atol, err_msg=name)
+        assert np.isfinite(np.asarray(got[name])).all(), name
+    if to_the_bit:
+        monkeypatch.setattr(fa, "key_range", whole_range)
+        whole = run(flash)
+        for name in got:
+            keep = np.broadcast_to(np.asarray(at.get(name, 1.0)) > 0, got[name].shape)
+            np.testing.assert_array_equal(np.asarray(got[name])[keep], np.asarray(whole[name])[keep], err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", BLOCKS)
+@pytest.mark.parametrize("offset", OFFSETS)
+@pytest.mark.parametrize("padding", PADDINGS)
+@pytest.mark.parametrize("window", WINDOWS)
+def test_forward_dq_dk_dv_match_reference(monkeypatch, qkv, window, padding, offset, blocks):
+    kvmask = PADDINGS[padding](jnp.ones((B, T), jnp.int32))
+    check_against_reference(
+        monkeypatch, qkv, kvmask, window=WINDOWS[window], blocks=BLOCKS[blocks], off=OFFSETS[offset],
+        to_the_bit=padding in WHOLE_CHUNK_PADDINGS,
+    )
+
+
+VARIANTS = {  # beside the main grid: what else decides the kernels' rectangles
+    "grouped": dict(kv_heads=1, window=300),  # two query heads over one K/V head: dk, dv summed over the group
+    "non-causal": dict(causal=False, window=0),  # a padding query sees the valid keys behind it
+    "non-causal-displaced": dict(causal=False, window=40, off=128.0),
+}
+
+
+@pytest.mark.parametrize("blocks", ["block=chunk", "block>chunk", "pieces-of-chunks"])
+@pytest.mark.parametrize("padding", WHOLE_CHUNK_PADDINGS)
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_whole_chunk_padding_in_the_other_rectangles(monkeypatch, qkv, variant, padding, blocks):
+    """Grouped keys, `causal=False` (alone and displaced with a window: a
+    band with a lower edge only), and pieces of several chunks, under the
+    pads that take whole chunks out."""
+    kw = dict(VARIANTS[variant])
+    q, k, v = qkv
+    kv_heads = kw.pop("kv_heads", H)
+    kvmask = PADDINGS[padding](jnp.ones((B, T), jnp.int32))
+    check_against_reference(
+        monkeypatch, (q, k[:, :, :kv_heads], v[:, :, :kv_heads]), kvmask,
+        blocks=PIECES_OF_CHUNKS if blocks == "pieces-of-chunks" else BLOCKS[blocks], to_the_bit=True, **kw,
+    )
 
 
 @pytest.mark.parametrize("keys_own_block", [False, True], ids=["fwd-dq", "dkv"])
@@ -111,6 +176,43 @@ def test_live_chunks_equal_brute_force(causal, window, offset, keys_own_block):
             tiles = mask[start:start + block].reshape(block, t // chunk, chunk)
             live = [j for j in range(t // chunk) if tiles[:, j].any()]
             assert live == list(range(lo, hi)) or (not live and lo == hi), (block, chunk, start)
+
+
+VALID_RANGES = [(0, 512), (130, 512), (256, 512), (300, 512), (511, 512), (0, 200), (0, 256), (0, 1), (140, 390), (128, 384)]
+
+
+@pytest.mark.parametrize("keys_own_block", [False, True], ids=["fwd-dq", "dkv"])
+@pytest.mark.parametrize("offset", [0, 128, -256, 37])
+@pytest.mark.parametrize("window", [0, 1, 40, 128, 300])
+@pytest.mark.parametrize("causal", [True, False])
+def test_valid_range_bounds_equal_brute_force(causal, window, offset, keys_own_block):
+    """The band's bounds narrowed by a row's valid key range [first, end),
+    per block and per `major` piece: no rectangle with a kept pair on a valid
+    key is outside them. Forward / dq: they are exactly the band's chunks that
+    hold a valid key, or ONE chunk where there is none and the band had some
+    (no new row without a log-sum-exp). dk/dv: the band's, or empty where the
+    key block holds no valid key."""
+    t = 512
+    mask = dense_mask(t, window, offset, causal)  # [query, key]
+    band = mask_band(offset, causal, window, keys_own_block)
+    for first, end in VALID_RANGES:
+        valid = (np.arange(t) >= first) & (np.arange(t) < end)
+        kept = (mask & valid[None, :]).T if keys_own_block else mask & valid[None, :]  # [block side, chunk side]
+        for block, chunk, major in ((128, 128, 512), (256, 128, 512), (128, 256, 512), (512, 64, 512), (128, 64, 256), (128, 128, 128)):
+            for piece in range(0, t, major):
+                for start in range(0, t, block):
+                    lo, hi = live_chunks(start - piece, band, block=block, chunk=chunk, count=major // chunk)
+                    if keys_own_block:
+                        bounds = valid_block((lo, hi), start, first, end, block=block)
+                        assert bounds == ((lo, hi) if valid[start:start + block].any() else (lo, lo))
+                    else:
+                        bounds = valid_chunks((lo, hi), first - piece, end - piece, chunk=chunk)
+                        holds_valid = [j for j in range(lo, hi) if valid[piece + j * chunk:piece + (j + 1) * chunk].any()]
+                        assert list(range(*bounds)) == holds_valid or (not holds_valid and bounds[1] - bounds[0] == (hi > lo))
+                    assert lo <= bounds[0] <= bounds[1] <= hi
+                    tiles = kept[start:start + block, piece:piece + major].reshape(block, major // chunk, chunk)
+                    needed = [j for j in range(major // chunk) if tiles[:, j].any()]
+                    assert all(bounds[0] <= j < bounds[1] for j in needed), (first, end, block, chunk, piece, start)
 
 
 CELL_CALLS = {  # the train step's attention calls of the five one-chip cells: positions, a layer's windows, the share
@@ -155,6 +257,75 @@ def test_trainer_counter_is_the_mean_over_the_layers_calls(monkeypatch):
     assert share == pytest.approx((kept_pair_share(512, blocks, True, 0) + kept_pair_share(512, blocks, True, 256)) / 2)
     gptj = LMConfig(vocab_size=64, n_layer=2, n_head=2, d_model=512, attn_impl="flash")
     assert flash_kept_pair_share(gptj, 1024) == pytest.approx(0.6673, abs=5e-5)
+
+
+def test_pad_dead_chunk_share_is_the_enumeration_over_rows_and_layers():
+    """`flash/pad_dead_chunk_share` of a train step's stats
+    (lm.flash_pad_dead_chunk_share): on a batch shaped like
+    smallthinker-ep4.ppo-4096x2048's (T 6,144, a 4,096-key window in three
+    layers of four, left pads of 1,984 and 64) the live chunks of the forward
+    that hold no valid key, less the one a block keeps, over the live chunks —
+    counted here from the dense mask; 0.0 where every pad is shorter than a
+    chunk; absent where the pass takes no flash kernel."""
+    from trlx_tpu.models import LMConfig
+    from trlx_tpu.models.lm import flash_pad_dead_chunk_share
+
+    t, pads, window = 6144, (1984, 64), 4096
+    cell = dict(vocab_size=64, n_layer=8, n_head=2, d_model=256, attention_layers=("local", "local", "local", "global") * 2, window_size=window)
+    mask = jnp.asarray(np.arange(t)[None, :] >= np.asarray(pads)[:, None], jnp.int32)
+    block, major, chunk = pick_block(t)
+    dead = live = 0
+    for layer_window in (window, window, window, 0) * 2:
+        dense = dense_mask(t, layer_window)
+        for pad in pads:
+            for start in range(0, t, block):
+                for piece in range(0, t, major):
+                    tiles = dense[start:start + block, piece:piece + major].reshape(block, major // chunk, chunk).any((0, 2))
+                    holds_valid = tiles & (piece + (np.arange(major // chunk) + 1) * chunk > pad)
+                    live += tiles.sum()
+                    dead += tiles.sum() - max(holds_valid.sum(), min(tiles.sum(), 1))
+    assert (dead, live) == (2 * 30 + 6 * 24, 2 * (2 * 78 + 6 * 72))  # by hand: rows of 3 and 0 whole chunks of padding
+    share = flash_pad_dead_chunk_share(LMConfig(**cell, attn_impl="flash"), mask)
+    assert float(share) == pytest.approx(dead / live, rel=1e-6) and float(share) == pytest.approx(0.17347, abs=5e-6)
+    assert pad_dead_chunks(1984, t, t, pick_block(t), True, 0) == (30, 78)  # python ints in, python ints out
+    short = jnp.asarray(np.arange(t)[None, :] >= np.asarray((511, 64))[:, None], jnp.int32)
+    assert float(flash_pad_dead_chunk_share(LMConfig(**cell, attn_impl="flash"), short)) == 0.0
+    right = jnp.asarray(np.arange(1024)[None, :] < np.asarray((400, 1024))[:, None], jnp.int32)  # ILQL's side
+    gptj = LMConfig(vocab_size=64, n_layer=2, n_head=2, d_model=512, attn_impl="flash")
+    assert float(flash_pad_dead_chunk_share(gptj, right)) == pytest.approx(1 / 6)  # of 3 live chunks a row, the last block's second
+    # one chunk a piece (T 512: GPT-Neo's cells): 0.0 by the rule, no scalar in the stats, the trainer writes it
+    assert flash_pad_dead_chunk_share(LMConfig(**cell, attn_impl="flash"), mask[:, :512]) is None
+    assert flash_pad_dead_chunk_share(LMConfig(**cell, attn_impl="xla"), mask) is None
+    assert flash_pad_dead_chunk_share(LMConfig(**cell, attn_impl="auto"), mask) is None  # off TPU the auto gate is closed
+
+
+@pytest.mark.parametrize("attn_impl", ["flash", "xla"])
+def test_ppo_step_stats_carry_the_counter_where_the_pass_takes_a_flash_kernel(attn_impl):
+    """The PPO loss's stats (what a step record is written from): one scalar
+    `flash/pad_dead_chunk_share` from the batch's own masks where the train
+    pass takes a flash kernel, no key where it does not."""
+    from trlx_tpu.data import PPORLBatch
+    from trlx_tpu.models import LMConfig
+    from trlx_tpu.models.heads import LMWithValueHead
+    from trlx_tpu.trainer.api import default_config
+    from trlx_tpu.trainer.ppo import make_ppo_loss_fn
+
+    b, prompt, response = 2, 256, 128  # 384 positions: three chunks of 128
+    cfg = LMConfig(vocab_size=64, n_layer=2, n_head=2, d_model=32, max_position=512, dtype="float32", attn_impl=attn_impl)
+    model = LMWithValueHead(cfg, branch_layer=1)
+    rng = np.random.default_rng(0)
+    ids = jnp.asarray(rng.integers(1, 64, (b, prompt + response)), jnp.int32)
+    query_mask = jnp.ones((b, prompt), jnp.int32).at[0, :130].set(0)  # row 0: one whole chunk of left padding
+    params = model.init(jax.random.PRNGKey(0), ids[:, :2], jnp.ones((b, 2), jnp.int32))["params"]
+    floats = lambda: jnp.asarray(rng.standard_normal((b, response)), jnp.float32)
+    batch = PPORLBatch(query_tensors=ids[:, :prompt], query_mask=query_mask, response_tensors=ids[:, prompt:],
+                       response_mask=jnp.ones((b, response), jnp.int32), logprobs=floats(), values=floats(), rewards=floats())
+    loss, stats = make_ppo_loss_fn(model, default_config("ppo"), prompt, lambda p: p)(params, batch)
+    assert np.isfinite(float(loss))
+    if attn_impl == "xla":
+        assert "flash/pad_dead_chunk_share" not in stats
+    else:  # a row's three blocks keep 1 + 2 + 3 chunks live; the pad takes chunk 0 from the second and third
+        assert float(stats["flash/pad_dead_chunk_share"]) == pytest.approx(2 / 12)
 
 
 def test_pick_block_reads_only_the_calls_length():

@@ -66,12 +66,14 @@ def _flash_grouped(q, k, v, m):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-def _flash_group_of_7(q, k, v, m):
+def _flash_group_of_7(q, k, v, m, window=4096):
     """28 query heads over 4 K/V heads of 128 under a 4,096-key window at a
     length past it (SmallThinker's train step: T 6,144 in three major pieces,
-    the dk/dv kernel's innermost axis 7 x 3 steps)."""
+    the dk/dv kernel's innermost axis 7 x 3 steps), and in its full layers
+    (window 0). At the cell's train batch of 2 the rows' valid key ranges are
+    a [2, 2] operand in SMEM, read by `bh // 28` and, in dk/dv, `bh // 4`."""
     loss = lambda q, k, v: flash_attention(
-        q, k, v, m, scale=128**-0.5, causal=True, window=4096, interpret=False
+        q, k, v, m, scale=128**-0.5, causal=True, window=window, interpret=False
     ).astype(jnp.float32).sum()
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
@@ -151,6 +153,11 @@ def _cases(s):
          (s((8, 1024, 32, 64), bf16), s((8, 1024, 8, 64), bf16), s((8, 1024, 8, 64), bf16), s((8, 1024), f32))),
         ("flash fwd+bwd grouped keys 28 over 4, window 4096, T 6144", _flash_group_of_7,
          (s((1, 6144, 28, 128), bf16), s((1, 6144, 4, 128), bf16), s((1, 6144, 4, 128), bf16), s((1, 6144), f32))),
+        ("flash fwd+bwd grouped keys 28 over 4, window 4096, T 6144, the train batch of 2", _flash_group_of_7,
+         (s((2, 6144, 28, 128), bf16), s((2, 6144, 4, 128), bf16), s((2, 6144, 4, 128), bf16), s((2, 6144), f32))),
+        ("flash fwd+bwd grouped keys 28 over 4, a full layer, T 6144, the train batch of 2",
+         functools.partial(_flash_group_of_7, window=0),
+         (s((2, 6144, 28, 128), bf16), s((2, 6144, 4, 128), bf16), s((2, 6144, 4, 128), bf16), s((2, 6144), f32))),
         ("flash fwd grouped keys 28 over 4, window 4096, the prefill at T 4096", _flash_prefill_group_of_7,
          (s((16, 4096, 28, 128), bf16), s((16, 4096, 4, 128), bf16), s((16, 4096, 4, 128), bf16), s((16, 4096), f32))),
         ("flash fwd+bwd major pieces, traced offset", _flash_ring_chunk,

@@ -23,6 +23,7 @@ reference: trlx/model/nn/ppo_models.py:35-413):
 - **Static shapes everywhere**: padding + masks, no ragged tensors.
 """
 
+import collections
 import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional, Tuple
@@ -575,6 +576,14 @@ def ring_slots(cfg: LMConfig, layer: int, max_len: int) -> int:
     return min(layer_window(cfg, layer), max_len) if cfg.window_cache == "ring" else 0
 
 
+def full_pass_takes_flash(cfg: LMConfig, q_len: int) -> bool:
+    """Whether a full-sequence pass at `q_len` (a train step's) calls the
+    flash kernels on the whole row: not the einsum path and not the ring
+    path, whose calls are per chunk. What the two `flash/*` counters of a
+    step record ask."""
+    return not ring_eligible(cfg, q_len, False) and flash_eligible(cfg, q_len, has_cache=False)
+
+
 def flash_kept_pair_share(cfg: LMConfig, q_len: int) -> Optional[float]:
     """Pairs the mask keeps over pairs the flash kernels' live chunks compute,
     mean over the layers' attention calls of one full-sequence pass at
@@ -585,12 +594,41 @@ def flash_kept_pair_share(cfg: LMConfig, q_len: int) -> Optional[float]:
     `flash/kept_pair_share` of a trainer's step records. A description of the
     sizes, not a score: a wider chunk lowers it and is as fast or faster
     (PERF.md §6, PR 27)."""
-    if ring_eligible(cfg, q_len, False) or not flash_eligible(cfg, q_len, has_cache=False):
+    if not full_pass_takes_flash(cfg, q_len):
         return None
     from trlx_tpu.ops.flash_attention import kept_pair_share, pick_block
 
     blocks = pick_block(q_len)
     return sum(kept_pair_share(q_len, blocks, True, layer_window(cfg, i)) for i in range(cfg.n_layer)) / cfg.n_layer
+
+
+def flash_pad_dead_chunk_share(cfg: LMConfig, attention_mask):
+    """Of the key chunks the band keeps live in the flash forward of one
+    full-sequence pass over `attention_mask` [b, T], the share the rows' own
+    padding takes out (`ops/flash_attention.py pad_dead_chunks`: the kernels'
+    rule through `pick_block`'s sizes), over the rows and the trunk's
+    attention layers. One traced scalar from the batch's mask: the counter
+    `flash/pad_dead_chunk_share` of a train step's stats, 0.0 where no row
+    pads a whole chunk. None where that pass takes no flash kernel, as
+    `flash_kept_pair_share`, and where a `major` piece is ONE chunk, which a
+    block always keeps: the share is 0.0 by the rule, the trainer writes that
+    into the step record itself (`trainer/base.py`) and the step's stats pull
+    is spared a transfer (some 1 ms of host time a step, 0.6% of ILQL's)."""
+    b, q_len = attention_mask.shape
+    if not full_pass_takes_flash(cfg, q_len):
+        return None
+    from trlx_tpu.ops.flash_attention import key_range, pad_dead_chunks, pick_block
+
+    blocks, dead, live = pick_block(q_len), 0, 0
+    if blocks.major == blocks.chunk:
+        return None
+    first, end = key_range(attention_mask[:, None, :])
+    windows = collections.Counter(layer_window(cfg, i) for i in range(cfg.n_layer) if cfg.mixer(i) == "attention")
+    for window, layers in windows.items():
+        row_dead, row_live = pad_dead_chunks(first, end, q_len, blocks, True, window)
+        dead = dead + layers * jnp.sum(row_dead)
+        live += layers * row_live * b
+    return dead / jnp.float32(live)
 
 
 def drawn_in(draw_dtype: str, init):

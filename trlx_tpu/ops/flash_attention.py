@@ -14,7 +14,20 @@ mask leaves:
 - inside the step a loop walks the resident side in `chunk`-row pieces, and
   only over the LIVE ones: the loop's bounds come from the mask (`live_chunks`:
   the causal diagonal, the gpt-neo local window, the ring path's traced
-  offset), so a dead chunk costs neither a fetch nor a step;
+  offset) and from the batch row's own padding (`key_range`: its first and
+  one-past-last valid key, two int32 scalars a row in SMEM; `valid_chunks`
+  drops the key chunks of the forward and dq that hold no valid key,
+  `valid_block` empties the loop of a dk/dv block that holds none), so a dead
+  chunk costs neither a fetch nor a step. A query block the band gave chunks
+  keeps at least one, so padding never turns a finite log-sum-exp into
+  M_INIT. EXACT: a skipped chunk held only scores of MASK_VAL, and for a query
+  with a valid key in its band their weight is `exp(-1e9 - m)`, 0 in float32
+  (in the forward `alpha` wipes `l` and `acc` when the first real chunk
+  arrives; in the backward `p = exp(-1e9 - lse)` is 0): output, lse, dq, dk,
+  dv are what the band's bounds alone give, to the bit. A query that sees
+  nothing but padding emitted a meaningless uniform mix before and emits
+  another one (over fewer masked keys); no loss reads it and its cotangents
+  are zero (tests/test_flash_blocks.py holds both claims);
 - every live chunk takes the one body: the band's compare-and-select on the
   f32 scores (a second, mask-free body for the chunks the mask keeps whole
   measured SLOWER: three loops a step for one, PERF.md §6, PR 27), `scale` on
@@ -147,6 +160,70 @@ def live_chunks(start, band, *, block: int, chunk: int, count: int):
     return lo, hi
 
 
+def key_range(kmask):
+    """[2, b] int32 from the key validity [b, 1, T]: each batch row's first
+    valid key and one past its last (left padding in PPO raises the first,
+    right padding in ILQL lowers the second; a hole in between stays the
+    bias's business, so the range is conservative for any mask). A row with
+    no valid key gets the whole [0, T): the band's bounds alone. The one
+    place the kernels' bounds read the mask: the tests force it whole to get
+    the kernels without the skip."""
+    valid = kmask[:, 0, :] > 0.5
+    T = valid.shape[-1]
+    pos = jnp.arange(T, dtype=jnp.int32)
+    first = jnp.min(jnp.where(valid, pos, T), axis=-1)
+    end = jnp.max(jnp.where(valid, pos + 1, 0), axis=-1)
+    return jnp.stack([jnp.where(end > 0, first, 0), jnp.where(end > 0, end, T)]).astype(jnp.int32)
+
+
+def valid_chunks(bounds, first, end, *, chunk: int):
+    """The band's [lo, hi) of a block that owns queries (forward, dq),
+    narrowed to the chunks that hold a valid key: keys [first, end) are the
+    row's valid range, counted like `live_chunks`' start from the first row of
+    chunk 0. `lo` rises to the chunk of the first valid key, `hi` falls to the
+    chunk after the last — but a block the band gave chunks keeps at least
+    one, so no row that had a finite log-sum-exp loses it (a row left with
+    lse = M_INIT would meet exp(s + 1e30) in a backward kernel whose
+    rectangle differs). No chunk with a kept pair on a valid key is ever
+    outside the result."""
+    lo, hi = bounds
+    lo_valid = _min(_max(lo, _div0(first, chunk)), _max(hi - 1, lo))
+    hi_valid = _max(_min(hi, _div0(end, chunk, ceil=True)), _min(lo_valid + 1, hi))
+    return lo_valid, hi_valid
+
+
+def valid_block(bounds, start, first, end, *, block: int):
+    """The band's [lo, hi) of a block that owns keys [start, start + block)
+    (dk/dv), emptied where none of them is in the valid range [first, end):
+    every term of dk and dv there is a product with an exact 0. The loop over
+    the query chunks is otherwise the band's: a padding query still sees
+    valid keys under right padding."""
+    lo, hi = bounds
+    holds_valid = (first < start + block) & (end > start)
+    if isinstance(holds_valid, (bool, int)):
+        return (lo, hi) if holds_valid else (lo, lo)
+    return lo, jnp.where(holds_valid, hi, lo)
+
+
+def pad_dead_chunks(first, end, q_len: int, blocks: FlashBlocks, causal: bool = True, window: int = 0):
+    """(dead, live) for the forward of one call at offset 0: the chunks the
+    band keeps live over the call's blocks and `major` pieces, and how many of
+    them the valid key range [first, end) takes out — by `live_chunks` and
+    `valid_chunks`, as the kernel's loop counts them. `first` and `end` may be
+    python ints or traced [b] vectors (then `dead` is one a row)."""
+    block, major, chunk = blocks
+    band = mask_band(0, causal, window)
+    dead = live = 0
+    for piece in range(0, q_len, major):
+        for start in range(0, q_len, block):
+            lo, hi = live_chunks(start - piece, band, block=block, chunk=chunk, count=major // chunk)
+            if hi > lo:
+                lo_valid, hi_valid = valid_chunks((lo, hi), first - piece, end - piece, chunk=chunk)
+                live += hi - lo
+                dead = dead + (hi - lo) - (hi_valid - lo_valid)
+    return dead, live
+
+
 def kept_pair_share(q_len: int, blocks: FlashBlocks, causal: bool = True, window: int = 0) -> float:
     """Pairs the mask keeps over pairs the live chunks compute, for one call
     at offset 0 (padding is data, so it does not count). By symmetry the
@@ -175,7 +252,8 @@ def _scratch(shape):
 
 
 def _smem_spec():
-    """Whole (1,1) scalar operand in SMEM (the traced ring-chunk offset)."""
+    """A whole small operand in SMEM: the (1, 1) traced ring-chunk offset, the
+    [2, b] valid key range of the batch rows."""
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
@@ -207,8 +285,10 @@ def _mm_nt(a, b):
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _band_setup(off_ref, i_block, i_major, *, scale, causal, window, blocks, keys_own_block):
-    """The block's live chunks [lo, hi) within this step's `major` piece, and
+def _band_setup(off_ref, range_ref, i_block, i_major, *, scale, causal, window, blocks, heads, keys_own_block):
+    """The block's live chunks [lo, hi) within this step's `major` piece (the
+    band's, less what the valid key range of the step's batch row takes out:
+    grid dimension 0 walks `heads` heads a row), and
     `scores(x_block, x_chunk, bias, j)`: the [block, chunk] f32 scores of
     local chunk j, scaled, plus the additive key-validity bias (a [1, chunk]
     row where the chunk side is keys, a [block, chunk] tile where the block
@@ -220,6 +300,12 @@ def _band_setup(off_ref, i_block, i_major, *, scale, causal, window, blocks, key
     # the block's first row, counted from the first row of this major piece
     start = i_block * block - i_major * major
     bounds = live_chunks(start, band, block=block, chunk=chunk, count=major // chunk)
+    row = jax.lax.div(pl.program_id(0), jnp.int32(heads))
+    first, end = range_ref[0, row], range_ref[1, row]
+    if keys_own_block:
+        bounds = valid_block(bounds, i_block * block, first, end, block=block)
+    else:
+        bounds = valid_chunks(bounds, first - i_major * major, end - i_major * major, chunk=chunk)
     # c - r of a tile is this constant difference of iotas plus a scalar
     rel = jax.lax.broadcasted_iota(jnp.int32, (block, chunk), 1) - jax.lax.broadcasted_iota(
         jnp.int32, (block, chunk), 0
@@ -285,13 +371,13 @@ def _rows(j, chunk):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                scale, causal, window, blocks, n_major):
+def _fwd_kernel(off_ref, range_ref, kbias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                scale, causal, window, blocks, n_major, heads):
     block, major, chunk = blocks
     i_major = pl.program_id(2)
     bounds, scores = _band_setup(
-        off_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
-        keys_own_block=False,
+        off_ref, range_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
+        heads=heads, keys_own_block=False,
     )
     q = q_ref[0]
 
@@ -361,13 +447,14 @@ def _fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret):
     kv = _resident_kv(BH // k.shape[0])
     _check_layout(BH, T, D, blocks, interpret)
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, window=window, blocks=blocks, n_major=n_major
+        _fwd_kernel, scale=scale, causal=causal, window=window, blocks=blocks, n_major=n_major, heads=H
     )
     o, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",  # how a device trace names the call
         grid=(BH, n_block, n_major),
         in_specs=[
+            _smem_spec(),
             _smem_spec(),
             _vmem_spec((1, per_major, 1, chunk), lambda bh, i, im: (bh // H, im, 0, 0)),
             _vmem_spec((1, block, D), _own),
@@ -382,7 +469,7 @@ def _fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret):
         scratch_shapes=[_scratch((block, 1)), _scratch((block, 1)), _scratch((block, D))] if n_major > 1 else [],
         interpret=interpret,
         **_compiler_params(interpret),
-    )(off, _by_chunk(_key_bias(kmask), chunk), q, k, v)
+    )(off, key_range(kmask), _by_chunk(_key_bias(kmask), chunk), q, k, v)
     return o, lse
 
 
@@ -391,13 +478,13 @@ def _fwd(q, k, v, kmask, off, scale, causal, window, blocks, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *scratch, scale, causal, window, blocks, n_major):
+def _bwd_dq_kernel(off_ref, range_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, *scratch, scale, causal, window, blocks, n_major, heads):
     block, major, chunk = blocks
     i_major = pl.program_id(2)
     bounds, scores = _band_setup(
-        off_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
-        keys_own_block=False,
+        off_ref, range_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
+        heads=heads, keys_own_block=False,
     )
     q = q_ref[0]
     do = do_ref[0]
@@ -420,8 +507,8 @@ def _bwd_dq_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, del
     _across_major(i_major, n_major, scratch, fresh, lambda c: jax.lax.fori_loop(*bounds, step, c), finish)
 
 
-def _bwd_dkv_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *scratch, scale, causal, window, blocks, n_major, group):
+def _bwd_dkv_kernel(off_ref, range_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, *scratch, scale, causal, window, blocks, n_major, heads, group):
     """The mirror image: a key block resident, a loop over the live query
     chunks, on the transposed score tile [keys, queries]. Grouped keys: the
     innermost grid dimension walks the group's query heads (and within each
@@ -430,8 +517,8 @@ def _bwd_dkv_kernel(off_ref, kbias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, de
     i_inner = pl.program_id(2)
     i_major = i_inner if group == 1 else 0 if n_major == 1 else jax.lax.rem(i_inner, jnp.int32(n_major))
     bounds, scores = _band_setup(
-        off_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
-        keys_own_block=True,
+        off_ref, range_ref, pl.program_id(1), i_major, scale=scale, causal=causal, window=window, blocks=blocks,
+        heads=heads, keys_own_block=True,
     )
     k = k_ref[0]
     v = v_ref[0]
@@ -494,11 +581,13 @@ def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
     grid = (BH, n_block, n_major)
     scratch = lambda n, pieces=n_major: [_scratch((block, D))] * n if pieces > 1 else []
 
+    valid = key_range(kmask)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **common),
+        functools.partial(_bwd_dq_kernel, heads=H, **common),
         name="flash_bwd_dq",
         grid=grid,
         in_specs=[
+            _smem_spec(),
             _smem_spec(),
             _vmem_spec((1, per_major, 1, chunk), lambda bh, i, im: (bh // H, im, 0, 0)),
             _vmem_spec((1, block, D), _own),
@@ -513,7 +602,7 @@ def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
         scratch_shapes=scratch(1),
         interpret=interpret,
         **_compiler_params(interpret),
-    )(off, _by_chunk(_key_bias(kmask), chunk), q, k, v, do, lse, delta)[0]
+    )(off, valid, _by_chunk(_key_bias(kmask), chunk), q, k, v, do, lse, delta)[0]
 
     # k-side: a step owns a key block; queries, dO and their row vectors
     # (one [1, chunk] row a loop step: `ref[0, j]`) are the resident side.
@@ -528,10 +617,11 @@ def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
         key_heads = H // group
     row_spec = _vmem_spec((1, per_major, 1, chunk), rows)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, group=group, **common),
+        functools.partial(_bwd_dkv_kernel, heads=key_heads, group=group, **common),
         name="flash_bwd_dkv",
         grid=(k.shape[0], n_block, group * n_major),
         in_specs=[
+            _smem_spec(),
             _smem_spec(),
             _vmem_spec((1, 1, block), lambda bh, i, im: (bh // key_heads, 0, i)),
             _vmem_spec((1, major, D), resident),
@@ -549,7 +639,7 @@ def _flash_lse_bwd(scale, causal, window, blocks, interpret, res, cts):
         scratch_shapes=scratch(2, group * n_major),
         interpret=interpret,
         **_compiler_params(interpret),
-    )(off, _key_bias(kmask), q, k, v, do, _by_chunk(lse, chunk), _by_chunk(delta, chunk))
+    )(off, valid, _key_bias(kmask), q, k, v, do, _by_chunk(lse, chunk), _by_chunk(delta, chunk))
 
     return dq, dk, dv, jnp.zeros_like(kmask), jnp.zeros_like(off)
 

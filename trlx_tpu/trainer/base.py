@@ -1326,6 +1326,9 @@ class JaxBaseTrainer(BaseRLTrainer):
         stats_host.update(startup_counters())
         if self._flash_kept_share is not None:
             stats_host["flash/kept_pair_share"] = self._flash_kept_share
+            # the step's own stats carry it where padding can take a chunk out
+            # (models/lm.py flash_pad_dead_chunk_share); 0.0 by the rule elsewhere
+            stats_host.setdefault("flash/pad_dead_chunk_share", 0.0)
         if "moe/held_slot_share" in stats_host:
             from trlx_tpu.models.moe import rows_per_held_expert, sum_rows_per_token
 

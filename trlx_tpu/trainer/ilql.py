@@ -22,6 +22,7 @@ import optax
 from trlx_tpu.data import ILQLBatch
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.heads import LMWithILQLHeads
+from trlx_tpu.models.lm import flash_pad_dead_chunk_share
 from trlx_tpu.observability import numerics as obs_numerics
 from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.ops.fused_logprob import fused_logprob_eligible, routed_logprob
@@ -295,7 +296,15 @@ class ILQLTrainer(JaxBaseTrainer):
                 awac_scale=m.awac_scale,
             )
 
-        loss_fn = fused_loss_fn if use_fused else dense_loss_fn
+        head_loss_fn = fused_loss_fn if use_fused else dense_loss_fn
+
+        def loss_fn(params, extras, batch: ILQLBatch):
+            """The loss and its stats, with the live key chunks the batch's own
+            (right) padding takes out of a pass through the flash kernels."""
+            loss, stats = head_loss_fn(params, extras, batch)
+            share = flash_pad_dead_chunk_share(model.cfg, batch.attention_mask)
+            return loss, (stats if share is None else {**stats, "flash/pad_dead_chunk_share": share})
+
         # Incident-path handle for the graftnum NaN census: the same loss,
         # reachable eagerly (the jitted step donates its inputs). Closes over
         # the LIVE extras at call time, matching what the step just consumed.

@@ -20,6 +20,7 @@ from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.fleet import FleetDegradedExit, validate_fleet_config
 from trlx_tpu.models import kda
 from trlx_tpu.models.heads import LMWithValueHead, branch_replay_params, extract_branch_params
+from trlx_tpu.models.lm import flash_pad_dead_chunk_share
 from trlx_tpu.models.ssm import lane_fill
 from trlx_tpu.ops.fused_logprob import count_head_calls, fused_logprob_eligible, take_head_call_scalars
 from trlx_tpu.ops.generate import make_generate_fn
@@ -1115,12 +1116,18 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         vf_coef=m.vf_coef,
     )
 
-    def with_trunk_stats(result, out, n_tokens, exit_mask=None):
+    def with_trunk_stats(result, out, n_tokens, exit_mask=None, attention_mask=None):
         """A model with expert layers: the step's routing counters beside the
         loss's own stats (`moe/held_slot_share`, `moe/max_expert_load`,
         `moe/first_buffer_share`). A gated looped stack: the loop the exit
         gate would leave at, mean over the response positions `exit_mask`
-        (`policy/expected_exit_loop`, sum over r of r p_r, no gradient)."""
+        (`policy/expected_exit_loop`, sum over r of r p_r, no gradient). A
+        pass through the flash kernels: the live key chunks the batch's own
+        padding `attention_mask` takes out (`flash/pad_dead_chunk_share`)."""
+        share = None if attention_mask is None else flash_pad_dead_chunk_share(model.cfg, attention_mask)
+        if share is not None:
+            loss, stats = result
+            result = loss, {**stats, "flash/pad_dead_chunk_share": share}
         if out["exit_probs"] is not None and exit_mask is not None:
             p = jax.lax.stop_gradient(out["exit_probs"])[:, P - 1 : -1]
             loops = jnp.sum(p * jnp.arange(1, p.shape[-1] + 1, dtype=p.dtype), axis=-1)
@@ -1148,7 +1155,7 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         return with_trunk_stats(ppo_loss(
             lp, vpred, batch.logprobs, batch.values, batch.rewards,
             batch.response_mask, **loss_kwargs,
-        ), out, all_ids.size, batch.response_mask)
+        ), out, all_ids.size, batch.response_mask, all_mask)
 
     def fused_loss_fn(params, batch: PPORLBatch):
         # Same update, fused head: the policy's per-label logprobs come out
@@ -1166,7 +1173,7 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         return with_trunk_stats(ppo_loss(
             out["logprobs"], vpred, batch.logprobs, batch.values, batch.rewards,
             batch.response_mask, **loss_kwargs,
-        ), out, all_ids.size, batch.response_mask)
+        ), out, all_ids.size, batch.response_mask, all_mask)
 
     def packed_loss_fn(params, batch: PackedPPOBatch):
         # Packed layout: episodes live as segments inside dense rows
