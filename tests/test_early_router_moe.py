@@ -172,14 +172,14 @@ def test_the_router_reads_the_block_s_input(router_input):
     params = block.init(jax.random.PRNGKey(2), x, bias, positions)["params"]
     assert moe.BIAS_NAME not in params["moe"] and sorted(params["moe"]) == ["experts_down", "experts_gate", "experts_up", "router"]
     run = lambda p, x: block.apply({"params": p}, x, bias, positions)
-    y, _, counts = run(params, x)
+    y, _, counts, _ = run(params, x)
     assert int(counts.sum()) == B * T * 3
     wave = 1.0 + jnp.sin(jnp.arange(cfg.d_model))  # not a uniform factor: RMSNorm_2 would undo one
     scaled = {**params, "ln_1": {"scale": params["ln_1"]["scale"] * wave}}
-    y_scaled, _, counts_scaled = run(scaled, x)
+    y_scaled, _, counts_scaled, _ = run(scaled, x)
     assert float(jnp.abs(y_scaled - y).max()) > 1e-3  # attention did change
     assert bool(jnp.all(counts_scaled == counts)) == (router_input == "block")
-    _, _, counts_moved = run(params, x + 0.5 * jax.random.normal(jax.random.PRNGKey(3), x.shape))
+    _, _, counts_moved, _ = run(params, x + 0.5 * jax.random.normal(jax.random.PRNGKey(3), x.shape))
     assert not bool(jnp.all(counts_moved == counts))
 
 
@@ -321,7 +321,7 @@ def test_flash_with_a_group_of_7_matches_the_einsum(window, blocks):
 
 
 @pytest.mark.parametrize("bad, message", [
-    ({"router_scoring": "softmax_all"}, "unknown router_scoring"),
+    ({"router_scoring": "softmax_some"}, "unknown router_scoring"),
     ({"router_input": "attention"}, "unknown router_input"),
     ({"routed_scaling_factor": 2.5}, "takes no scale"),
     ({"parallel_residual": True}, "router_input 'block'.*parallel_residual"),

@@ -84,6 +84,14 @@ def _flash_prefill_group_of_7(q, k, v, m):
     return flash_attention(q, k, v, m, scale=128**-0.5, causal=True, window=4096, interpret=False)
 
 
+def _flash_group_of_4(q, k, v, m):
+    """8 query heads over 2 K/V heads of 128, full span (ZAYA1's "cca" layers
+    hand the kernels what an "mha" layer of the shape would: T 6,144 in three
+    major pieces in the train step)."""
+    loss = lambda q, k, v: flash_attention(q, k, v, m, scale=128**-0.5, causal=True, interpret=False).astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
 def _flash_narrow_heads(q, k, v, m):
     """Heads 64 wide (granite-4.0-h-micro's attention layers: 32 query heads
     over 8), padded with zeros to the kernels' 128 as `Attention` pads them."""
@@ -160,6 +168,10 @@ def _cases(s):
          (s((2, 6144, 28, 128), bf16), s((2, 6144, 4, 128), bf16), s((2, 6144, 4, 128), bf16), s((2, 6144), f32))),
         ("flash fwd grouped keys 28 over 4, window 4096, the prefill at T 4096", _flash_prefill_group_of_7,
          (s((16, 4096, 28, 128), bf16), s((16, 4096, 4, 128), bf16), s((16, 4096, 4, 128), bf16), s((16, 4096), f32))),
+        ("flash fwd+bwd grouped keys 8 over 2, T 6144, the train batch of 2", _flash_group_of_4,
+         (s((2, 6144, 8, 128), bf16), s((2, 6144, 2, 128), bf16), s((2, 6144, 2, 128), bf16), s((2, 6144), f32))),
+        ("flash fwd grouped keys 8 over 2, the prefill at T 4096", _flash_fwd,
+         (s((16, 4096, 8, 128), bf16), s((16, 4096, 2, 128), bf16), s((16, 4096, 2, 128), bf16), s((16, 4096), f32))),
         ("flash fwd+bwd major pieces, traced offset", _flash_ring_chunk,
          (s((1, 8192, H, D), bf16),) * 3 + (s((1, 8192), f32), s((), f32))),
         ("fused_logprob fwd+bwd", _fused, head),
@@ -177,6 +189,8 @@ def _cases(s):
          (s((2048, 2560), bf16), s((2560, 37984), bf16), s((2048,), i32))),
         ("fused_logprob held [V, D] fwd+bwd d2560 V37984, 4096 rows", _fused_tied,
          (s((2 * 2048, 2560), bf16), s((37984, 2560), bf16), s((2 * 2048,), i32))),
+        ("fused_logprob tied fwd+bwd d2048 V131136, 4096 rows", _fused_tied,
+         (s((2 * 2048, 2048), bf16), s((131136, 2048), bf16), s((2 * 2048,), i32))),
         ("fused_logprob scoring fwd 28672 rows", _fused_scoring,
          (s((32 * 896, DM), bf16), s((V, DM), bf16), s((32 * 896,), i32), s((V,), bf16))),
     ]
@@ -293,6 +307,46 @@ def test_both_forms_of_the_kda_mixer_compile_for_v5e(v5e_sharding):
     jax.jit(step).lower(*args).compile()
     _, (conv, state) = jax.eval_shape(step, *args)
     assert state.dtype == jnp.float32 and state.shape == (32, 32, 128, 128) and conv.shape == (32, 3, 12288)
+
+
+def test_a_cca_layer_s_three_passes_compile_for_v5e(v5e_sharding):
+    """One layer of zaya1-ep2.ppo-4096x2048 at its widths, bf16, under remat:
+    the train pass (forward and backward over the train batch [2, 6144], the
+    attention core through the flash kernels, the MLP router with its carried
+    state, 8 held experts one a token), the prefill of 16 x 4,096 into the
+    four-leaf cache, and the decode step that advances the window and the
+    shifted value through the ranged read."""
+    import json
+    import os
+
+    from trlx_tpu.models import cca
+    from trlx_tpu.models.lm import LMConfig, TransformerLM
+
+    spec = json.load(open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "zaya1-8b-ep2-l8.json")))
+    cfg = LMConfig.from_dict({**spec["model_arch"], "dtype": "bfloat16", "param_dtype": "bfloat16", "remat": True,
+                              "n_layer": 1, "ffn_layers": ["experts"], "attn_impl": "flash"})
+    model = TransformerLM(cfg)
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+    ids = jnp.zeros((1, 4), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids, jnp.ones_like(ids))["params"])
+    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), params)
+
+    def train(p, ids, mask):
+        loss = lambda p: model.apply({"params": p}, ids, mask, compute_logits=False)["hidden"].astype(jnp.float32).sum()
+        return jax.value_and_grad(loss)(p)
+
+    text = jax.jit(train).lower(params, s((2, 6144), jnp.int32), s((2, 6144), jnp.int32)).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd_dkv" in text
+    cache = (tuple(s(shape, dtype) for shape, dtype in cca.cache_shapes(cfg, 16, 6144)),)
+    prefill = lambda p, ids, mask, cache, cache_mask: model.apply(
+        {"params": p}, ids, mask, cache=cache, cache_index=0, cache_mask=cache_mask, logits_start=4095)
+    jax.jit(prefill).lower(params, s((16, 4096), jnp.int32), s((16, 4096), jnp.int32), cache, s((16, 6144), jnp.int32)).compile()
+    step = lambda p, ids, cache, index, cache_mask: model.apply(
+        {"params": p}, ids, jnp.ones((16, 1), jnp.int32), cache=cache, cache_index=index, cache_mask=cache_mask)
+    args = (params, s((16, 1), jnp.int32), cache, s((), jnp.int32), s((16, 6144), jnp.int32))
+    jax.jit(step).lower(*args).compile()
+    (k, v, window, shifted), = jax.eval_shape(step, *args)["cache"]
+    assert k.shape == v.shape == (16, 6144, 2, 128) and window.shape == (16, 2, 1280) and shifted.shape == (16, 1, 128)
 
 
 def test_the_delta_rule_pass_does_not_hand_its_inverse_to_autodiff(v5e_sharding):

@@ -140,6 +140,11 @@ class RolloutEngine:
                 f"{'state-space' if model.cfg.has_ssm else 'kda'} layer: a slot's state is not carried through "
                 "admission, a block table has nothing to page and a rejected draft needs a snapshot of the state to "
                 "roll back to")
+        if model.cfg.attention == "cca":
+            raise NotImplementedError(
+                "the rollout engine (and with it the paged pool and spec decode) is not built for attention 'cca': a "
+                "slot's convolution window and shifted value are not carried through admission or suffix prefill, a "
+                "block table pages slots and they have none, and a rejected draft needs a snapshot of both to roll back to")
         if model.cfg.n_loops > 1:
             raise NotImplementedError(
                 "the rollout engine (and with it the paged pool and spec decode) is not built for a looped stack "

@@ -110,6 +110,8 @@ class LMWithValueHead(nn.Module):
             "branch_hidden": out["branch_hidden"],
             "cache": out["cache"],
             "expert_counts": out["expert_counts"],
+            "branch_router_state": out["branch_router_state"],
+            "router_top_weight": out["router_top_weight"],
             "exit_probs": out["exit_probs"],
             "logprobs": out["logprobs"],
             "lse": out["lse"],
@@ -117,11 +119,13 @@ class LMWithValueHead(nn.Module):
         }
 
     def forward_branch(self, branch_hidden, attention_mask=None, position_ids=None, logits_start: int = 0,
-                       labels=None, labels_mask=None, segment_ids=None):
+                       labels=None, labels_mask=None, segment_ids=None, router_state=None):
         """Replay blocks [branch_layer..N) + ln_f + lm head from the
         branch-point hidden states (a looped stack: from the FIRST loop's
         block branch_layer through every later loop whole, over
-        `branch_replay_params`). Called via
+        `branch_replay_params`; `cfg.router_carry`: and from `router_state`,
+        the router state block branch_layer - 1 handed on, which the policy's
+        pass returns as ``branch_router_state``). Called via
         ``model.apply({'params': ref_branch_params}, ..., method='forward_branch')``
         — the functional `forward_hydra`
         (reference: trlx/model/nn/ppo_models.py:351-368). With ``labels``
@@ -137,6 +141,7 @@ class LMWithValueHead(nn.Module):
             labels=labels,
             labels_mask=labels_mask,
             segment_ids=segment_ids,
+            router_state=router_state,
         )
         if labels is not None:
             return out["logprobs"]

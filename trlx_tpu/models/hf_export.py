@@ -47,6 +47,10 @@ def validate_exportable(cfg: LMConfig, family: str):
         raise ValueError(
             "export to an HF checkpoint is not built for the smallthinker family (a softmax router over the chosen logits, "
             f"router_input 'block'): HF {family} has no such layer, and the family's tensor names are not known here")
+    if cfg.attention == "cca" or cfg.router_kind == "mlp" or cfg.router_carry or cfg.residual_scaling:
+        raise ValueError(
+            "export to an HF checkpoint is not built for the zaya family (attention 'cca', an MLP router with a carried "
+            f"state, residual scaling): HF {family} has no such layer, and the family's tensor names are not known here")
     problems = []
     if family == "gpt_neo":
         if cfg.scale_attn:

@@ -22,7 +22,8 @@ gpt-j, gpt-neo, gpt-neox; granitemoehybrid without experts (state-space
 and attention layers, models/ssm.py); and kimi_linear (gated delta-rule and
 unrotated latent-attention layers, sparse experts: models/kda.py, models/moe.py). `ouro` (the looped family) maps its
 config only: its weights are not imported (`load_hf_trunk` raises); so does `smallthinker` (grouped keys, windows beside
-NoPE layers, a softmax router ahead of attention over ReGLU experts). With no checkpoint (or `model_arch` given) params
+NoPE layers, a softmax router ahead of attention over ReGLU experts) and `zaya` (attention behind two causal convolutions,
+one expert a token by an MLP router with a carried state, learned residual scaling). With no checkpoint (or `model_arch` given) params
 initialize from scratch — the randomwalks path
 (reference: examples/randomwalks.py:99-101).
 """
@@ -342,6 +343,64 @@ def lm_config_from_hf(hf, **overrides) -> LMConfig:
             router_scoring="softmax",
             router_input="block" if given.get("moe_enable_early_router", True) else "ffn",
         )
+    elif t == "zaya":
+        # ZAYA1 (8B-A0.8B): every layer a CCA sub-block (grouped attention at
+        # `head_dim` behind two causal convolutions `cca_time0` and `cca_time1`
+        # wide, rotary on `partial_rotary_factor` of a head) and an expert
+        # sub-block (one expert a token by an MLP router `router_hidden_size`
+        # wide that carries its state across depth), learned residual scaling,
+        # tied head. What config.json does not say (the convolutions' biases,
+        # the value shift, the router's depth, the form of the scaling) is the
+        # program's reading of the family's papers (models/cca.py, models/moe.py
+        # `MLPRouter`). What the program lacks raises here, by name.
+        given = hf.to_dict() if hasattr(hf, "to_dict") else dict(vars(hf))
+        n_layer, kinds = given["num_hidden_layers"], list(given.get("layer_types") or [])
+        rope = (given.get("rope_parameters") or {}).get("hybrid") or {}
+        factor = float(rope.get("partial_rotary_factor", given.get("partial_rotary_factor", 1.0)))
+        unbuilt = [name for name, on in (
+            ("a layer type other than 'hybrid' (a sliding window every fourth layer is the 74B sibling's)", set(kinds) - {"hybrid"}),
+            ("layer_types of another length than num_hidden_layers", kinds and len(kinds) != n_layer),
+            ("sliding_window", given.get("sliding_window") is not None),
+            ("attention_bias", given.get("attention_bias")), ("lm_head_bias", given.get("lm_head_bias")),
+            (f"hidden_act {given.get('hidden_act')!r}", given.get("hidden_act") != "silu"),
+            (f"rope_type {rope.get('rope_type', 'default')!r}", rope.get("rope_type", "default") != "default"),
+            ("a zero-compute 'skip' expert (a router output beyond num_experts)", given.get("zaya_use_mod"))) if on]
+        if unbuilt:
+            raise ValueError(f"zaya: not built: {'; '.join(unbuilt)}")
+        d = dict(
+            vocab_size=given["vocab_size"],
+            n_layer=n_layer,
+            n_head=given["num_attention_heads"],
+            n_kv_head=given["num_key_value_heads"],
+            head_width=given["head_dim"],
+            d_model=given["hidden_size"],
+            max_position=given["max_position_embeddings"],
+            pos_type="rotary",
+            rotary_dim=int(given["head_dim"] * factor),
+            rope_theta=float(rope.get("rope_theta", given.get("rope_theta", 10000.0))),
+            extra={"neox_rotary": True},
+            attention="cca",
+            cca_time0=given["cca_time0"],
+            cca_time1=given["cca_time1"],
+            norm="rmsnorm",
+            mlp="gated",
+            activation="silu",
+            ln_eps=given["rms_norm_eps"],
+            parallel_residual=False,
+            fused_qkv=False,
+            qkv_bias=False,
+            out_bias=False,
+            tie_word_embeddings=bool(given.get("tie_word_embeddings", True)),
+            ffn_layers=("experts",) * n_layer,
+            n_experts=given["num_experts"],
+            experts_per_token=given["num_experts_per_tok"],
+            expert_d_ff=given["moe_intermediate_size"],
+            router_scoring="softmax_all",
+            router_kind="mlp",
+            router_hidden=given["router_hidden_size"],
+            router_carry=True,
+            residual_scaling=True,
+        )
     else:
         raise ValueError(f"unsupported HF model_type for conversion: {t}")
     d.update(overrides)
@@ -513,6 +572,11 @@ def load_hf_trunk(model_path: str, cfg: LMConfig, put=None) -> Dict[str, Any]:
             "importing a smallthinker checkpoint's weights is not built: the names of the family's tensors could not be "
             "read without the network, and a guessed mapping would load another model; `model_arch` (weights from "
             "the seed) is the path that runs")
+    if cfg.attention == "cca" or cfg.router_kind == "mlp":
+        raise NotImplementedError(
+            "importing a zaya checkpoint's weights is not built: the names of the family's tensors could not be read "
+            "without the network, and a guessed mapping would load another model; `model_arch` (weights from the seed) is "
+            "the path that runs")
     if cfg.n_loops > 1:
         raise NotImplementedError(
             "importing a looped checkpoint's weights is not built: the names of the family's tensors (the two "

@@ -145,7 +145,7 @@ class LMConfig:
     # construction, never a quietly different model.
     norm: str = "layernorm"  # "layernorm" | "rmsnorm" (scale only, no mean)
     mlp: str = "dense"  # "dense": c_proj(act(c_fc x)) | "gated": down(act(gate x) * up x), no biases
-    attention: str = "mha"  # "mha": per-head K and V in the cache | "mla": latent attention (below)
+    attention: str = "mha"  # "mha": per-head K and V in the cache | "mla": latent attention (below) | "cca": models/cca.py
     # Grouped keys ("mha" only): K and V are projected to n_kv_head heads and
     # each serves n_head // n_kv_head query heads, in the cache and in every
     # read; 0 -> n_head (one K and V a query head).
@@ -200,7 +200,10 @@ class LMConfig:
     # choice by score + a correction bias (a buffer), the chosen renormalised
     # and scaled; "softmax": the experts_per_token largest of the raw logits,
     # a softmax over the chosen (= a softmax over all, renormalised over the
-    # chosen), no bias parameter, no scale.
+    # chosen), no bias parameter, no scale; "softmax_all": a softmax over all
+    # n_experts, the choice by probability + a balancing bias (a buffer), the
+    # weight the chosen probability itself, NOT renormalised (with one expert
+    # a token a renormalised weight is the constant 1), no scale.
     router_scoring: str = "sigmoid"
     # What the router reads: "ffn": the feed-forward's normed input, after
     # attention; "block": the block's INPUT, before ln_1 and ahead of
@@ -208,6 +211,32 @@ class LMConfig:
     # runs; the experts read ln_2(x + attn) as ever. Built for the sequential
     # residual over attention mixers.
     router_input: str = "ffn"
+    # What the router is: "linear": one product x . W_g; "mlp": a
+    # down-projection to router_hidden, an RMSNorm and two GeLU layers of that
+    # width before the n_experts logits (models/moe.py `MLPRouter`), in
+    # float32. `router_carry`: the down-projection's output takes the layer
+    # below's (after ITS carry, before its norm) times a learned vector, so
+    # the router's state crosses the depth of the stack: `Block` takes it in
+    # and hands it on, through remat, a decode step and the frozen branch's
+    # replay (`forward_branch`'s second input). Every layer is then an expert
+    # layer.
+    router_kind: str = "linear"
+    router_hidden: int = 0
+    router_carry: bool = False
+    # Learned scale and bias on BOTH operands of each residual sum of a block
+    # (sequential residual): x' = (x + b_x) * a_x + (f + b_f) * a_f, four
+    # vectors of d_model a sum.
+    residual_scaling: bool = False
+    # attention "cca" (models/cca.py): queries and keys at n_head and
+    # n_kv_head heads of head_width pass two causal convolutions over time,
+    # cca_time0 wide a channel and cca_time1 wide a head, ahead of the scores;
+    # the cache keeps the convolutions' window and the previous token's value
+    # beside K and V. Built for the static generate path, scoring and the
+    # train step; the engine, the paged pool, spec decode, the sp ring,
+    # kv_cache_quant, decode_weight_quant, soft prompts, packed segments,
+    # windows and a looped stack refuse it.
+    cca_time0: int = 0
+    cca_time1: int = 0
     # Per-layer mixer kind ("attention" | "mamba" | "kda"); empty -> all attention.
     # A "mamba" layer is trlx_tpu/models/ssm.py: the Mamba-2 state-space mixer
     # (ssm_heads x ssm_head_dim channels, one B/C group of ssm_state numbers, a
@@ -276,9 +305,10 @@ class LMConfig:
                 f"unknown remat_policy {self.remat_policy!r} (expected 'full' or 'dots')"
             )
         for name, kinds in (("norm", ("layernorm", "rmsnorm")), ("mlp", ("dense", "gated")),
-                            ("attention", ("mha", "mla")), ("rotary_layers", ("all", "local")),
+                            ("attention", ("mha", "mla", "cca")), ("rotary_layers", ("all", "local")),
                             ("window_cache", ("span", "ring")), ("pos_type", ("learned", "rotary", "none")),
-                            ("router_scoring", ("sigmoid", "softmax")), ("router_input", ("ffn", "block"))):
+                            ("router_scoring", ("sigmoid", "softmax", "softmax_all")), ("router_input", ("ffn", "block")),
+                            ("router_kind", ("linear", "mlp"))):
             if getattr(self, name) not in kinds:
                 raise ValueError(f"unknown {name} kind {getattr(self, name)!r} (expected one of {kinds})")
         if self.draw_dtype not in ("", "float32"):
@@ -303,12 +333,12 @@ class LMConfig:
         if self.n_kv_head and self.n_kv_head != self.n_head:
             if self.n_kv_head < 0 or self.n_head % self.n_kv_head:
                 raise ValueError(f"n_kv_head {self.n_kv_head} does not divide n_head {self.n_head}")
-            if self.attention != "mha" or self.fused_qkv or self.sp_size > 1:
-                raise ValueError("grouped keys (n_kv_head < n_head) are built for attention 'mha' with separate "
+            if self.attention == "mla" or self.fused_qkv or self.sp_size > 1:
+                raise ValueError("grouped keys (n_kv_head < n_head) are built for attention 'mha' and 'cca' with separate "
                                  "q/k/v projections (fused_qkv false), and not for the sp ring")
-        if (self.qk_norm or self.head_width) and (self.attention != "mha" or self.fused_qkv):
-            raise ValueError("qk_norm and head_width are built for attention 'mha' with separate q/k/v projections "
-                             "(fused_qkv false)")
+        if (self.qk_norm or self.head_width) and (self.attention == "mla" or self.fused_qkv):
+            raise ValueError("qk_norm and head_width are built for attention 'mha' (head_width: 'cca' too) with separate "
+                             "q/k/v projections (fused_qkv false)")
         if self.rotary_layers == "local" and (self.pos_type != "rotary" or "local" not in self.attention_layers):
             raise ValueError("rotary_layers 'local' needs pos_type 'rotary' and a 'local' layer in attention_layers")
         if self.window_cache == "ring":
@@ -323,11 +353,47 @@ class LMConfig:
                 raise ValueError(
                     f"expert layers need mlp 'gated', expert_d_ff, 0 < experts_per_token <= n_experts "
                     f"and experts_held inside [0, n_experts): {self.experts_held!r} of {self.n_experts}")
-            if self.router_scoring == "softmax" and self.routed_scaling_factor != 1.0:
-                raise ValueError("router_scoring 'softmax' (a softmax over the chosen logits) takes no scale: "
+            if self.router_scoring != "sigmoid" and self.routed_scaling_factor != 1.0:
+                raise ValueError(f"router_scoring {self.router_scoring!r} (a softmax) takes no scale: "
                                  f"routed_scaling_factor must be 1, got {self.routed_scaling_factor}")
-        elif self.router_scoring != "sigmoid" or self.router_input != "ffn":
-            raise ValueError("router_scoring and router_input describe expert layers: ffn_layers names none")
+            if (self.router_kind == "mlp") != (self.router_hidden > 0) or (self.router_carry and self.router_kind != "mlp"):
+                raise ValueError("router_kind 'mlp' needs router_hidden (and 'linear' takes none); router_carry needs "
+                                 f"router_kind 'mlp': got {self.router_kind!r}, {self.router_hidden}, {self.router_carry}")
+            if self.router_kind == "mlp" and self.router_input != "ffn":
+                raise ValueError("router_kind 'mlp' is built for router_input 'ffn' (it reads the feed-forward's normed input)")
+            if self.router_carry:
+                unbuilt = [name for name, on in (
+                    ("a 'dense' layer in ffn_layers (the state crosses every layer)", "dense" in self.ffn_layers),
+                    ("parallel_residual", self.parallel_residual), ("a 'mamba' layer", self.has_ssm),
+                    ("a 'kda' layer", self.has_kda), ("soft prompts", self.n_soft_tokens > 0)) if on]
+                if unbuilt:
+                    raise ValueError(f"router_carry (a router state carried across depth) is not built with {', '.join(unbuilt)}")
+        elif (self.router_scoring != "sigmoid" or self.router_input != "ffn" or self.router_kind != "linear"
+              or self.router_hidden or self.router_carry):
+            raise ValueError("router_scoring, router_input, router_kind, router_hidden and router_carry describe expert "
+                             "layers: ffn_layers names none")
+        if self.residual_scaling and (self.parallel_residual or self.sandwich_norm or self.residual_multiplier != 1.0):
+            raise ValueError("residual_scaling is built for the plain sequential residual: not with parallel_residual, "
+                             "sandwich_norm or a residual_multiplier")
+        if self.attention == "cca":
+            if (min(self.cca_time0, self.cca_time1) < 1 or self.cca_time0 + self.cca_time1 < 3 or self.kv_heads % 2
+                    or self.n_head % self.kv_heads or self.pos_type == "learned" or self.head_dim % 2):
+                raise ValueError(
+                    "attention 'cca' needs cca_time0 and cca_time1 (a window of at least one position between them), an "
+                    "even n_kv_head that divides n_head (the values' heads split between the token and the one before) and "
+                    f"pos_type 'rotary' or 'none': got {(self.cca_time0, self.cca_time1)}, {self.n_head} over {self.kv_heads}")
+            unbuilt = [name for name, on in (
+                ("fused_qkv", self.fused_qkv), ("qkv_bias or out_bias (it has no such bias)", self.qkv_bias or self.out_bias),
+                ("qk_norm (its scores are L2-normalised by rule)", self.qk_norm), ("kv_cache_quant", self.kv_cache_quant),
+                ("windowed attention_layers", "local" in self.attention_layers), ("soft prompts", self.n_soft_tokens > 0),
+                ("the sp ring (sp_size > 1)", self.sp_size > 1), ("a 'mamba' layer", self.has_ssm),
+                ("a 'kda' layer", self.has_kda), ("a looped stack (n_loops > 1)", self.n_loops > 1),
+                ("an attention_multiplier or scale_attn false (its scale is 1/sqrt(head_dim) by rule)",
+                 self.attention_multiplier != 0 or not self.scale_attn)) if on]
+            if unbuilt:
+                raise ValueError(f"attention 'cca' is not built with {', '.join(unbuilt)}")
+        elif self.cca_time0 or self.cca_time1:
+            raise ValueError("cca_time0 and cca_time1 describe attention 'cca'")
         if self.router_input == "block":
             unbuilt = [name for name, on in (
                 ("parallel_residual", self.parallel_residual), ("sandwich_norm", self.sandwich_norm),
@@ -801,6 +867,27 @@ def ring_bias(cache_mask, cache_index, slots: int):
     return jnp.where(valid, 0.0, -1e9).astype(jnp.float32)[:, None, None, :]
 
 
+def flash_core(q, k, v, flash_mask, scale, dtype, window=0):
+    """The flash kernels over q [b, t, h, hd] and k, v [b, t, kv_heads, hd],
+    causal, under `flash_mask` [b, t].
+
+    The kernels are tuned and tested at head widths that fill their 128 lanes;
+    a narrower head (64: 13.8 ms on XLA, 5.3 ms here, forward and backward at
+    [8, 1024], 32 over 8; PERF.md §6, PR 32) is padded with zeros, which add
+    nothing to q.k, and the output's padded columns are dropped, as the latent
+    path pads 192/128 to 256. The pad buys test coverage, not speed: unpadded
+    at 64 the kernels read 5.2 ms in the same run, and no lowering or parity
+    test holds them at that width (PERF.md §7, PR 32 e)."""
+    from trlx_tpu.ops.flash_attention import flash_attention
+
+    hd = q.shape[-1]
+    pad = -hd % 128
+    widen = (lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, pad)))) if pad else (lambda a: a)
+    with jax.named_scope("flash_attn"):  # what the call site costs: pad, relayouts, kernels
+        out = flash_attention(widen(q), widen(k), widen(v), flash_mask, scale=scale, causal=True, window=window)
+        return (out[..., :hd] if pad else out).astype(dtype)
+
+
 class Attention(nn.Module):
     """Multi-head causal attention with functional KV cache.
 
@@ -957,22 +1044,7 @@ class Attention(nn.Module):
                         q, k, v, flash_mask, scale=scale, causal=True, window=window
                     ).astype(dtype)
                 else:
-                    from trlx_tpu.ops.flash_attention import flash_attention
-
-                    # The kernels are tuned and tested at head widths that
-                    # fill their 128 lanes; a narrower head (64: 13.8 ms on
-                    # XLA, 5.3 ms here, forward and backward at [8, 1024], 32
-                    # over 8; PERF.md §6, PR 32) is padded with zeros, which
-                    # add nothing to q.k, and the output's padded columns are
-                    # dropped, as the latent path pads 192/128 to 256. The pad
-                    # buys test coverage, not speed: unpadded at 64 the kernels
-                    # read 5.2 ms in the same run, and no lowering or parity
-                    # test holds them at that width (PERF.md §7, PR 32 e).
-                    pad = -hd % 128
-                    widen = (lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, pad)))) if pad else (lambda a: a)
-                    with jax.named_scope("flash_attn"):  # what the call site costs: pad, relayouts, kernels
-                        out = flash_attention(widen(q), widen(k), widen(v), flash_mask, scale=scale, causal=True, window=window)
-                        out = (out[..., :hd] if pad else out).astype(dtype)
+                    out = flash_core(q, k, v, flash_mask, scale, dtype, window)
             elif read is not None:
                 out = read(q, new_cache, attn_bias, scale, dtype)
             elif cache is not None:
@@ -1145,11 +1217,18 @@ def make_norm(cfg: LMConfig, name: str, **kwargs):
 class Block(nn.Module):
     """One transformer block; sequential (gpt2) or parallel (gptj/neox)
     residual; with `cfg.sandwich_norm` a second norm (`ln_1_out`, `ln_2_out`)
-    on each branch's output. `ffn` is this layer's feed-forward kind ("dense" | "experts"),
+    on each branch's output; with `cfg.residual_scaling` a learned scale and
+    bias on both operands of each sum. `ffn` is this layer's feed-forward kind ("dense" | "experts"),
     `mixer` its mixer kind ("attention" | "mamba": models/ssm.py | "kda":
     models/kda.py; the last two keep a state for a cache and read `token_mask`
-    [b, q_len], the real tokens of `x`, in place of a bias). Returns (x, cache, expert_counts): the tokens
-    each held expert took in this block, None for a dense one."""
+    [b, q_len], the real tokens of `x`, in place of a bias; attention "cca",
+    models/cca.py, reads it beside the bias). `router_state` [b, q_len,
+    router_hidden]: what the router of the block below handed on
+    (`cfg.router_carry`). Returns (x, cache, expert_counts, routing): the tokens
+    each held expert took in this block, None for a dense one; `routing` is
+    None but under `router_carry` or `router_scoring` "softmax_all": {"state":
+    this block's router state (None without the carry), "top_weight": the mean
+    weight of a token's first choice}."""
 
     cfg: LMConfig
     ffn: str = "dense"
@@ -1157,10 +1236,10 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_bias, positions, cache=None, cache_index=None,
-                 flash_mask=None, window=0, use_ring=False, block_tables=None, token_mask=None):
+                 flash_mask=None, window=0, use_ring=False, block_tables=None, token_mask=None, router_state=None):
         cfg = self.cfg
         ln = lambda name: make_norm(cfg, name)
-        counts = None
+        counts = routing = None
         # On a partitioned mesh a pass over many tokens keeps its rows where
         # the batch split put them, at both edges of the block (inside, so
         # that a remat'd backward holds them too), and every product gathers
@@ -1176,6 +1255,12 @@ class Block(nn.Module):
                 from trlx_tpu.models.kda import KDAMixer
 
                 return KDAMixer(cfg, name="kda")(h, token_mask, cache)
+            if cfg.attention == "cca":
+                from trlx_tpu.models.cca import CCAttention
+
+                if window or use_ring or block_tables is not None:
+                    raise NotImplementedError("attention 'cca' is not built for windows, the sp ring or paged caches")
+                return CCAttention(cfg, name="attn")(h, attn_bias, positions, cache, cache_index, flash_mask, token_mask)
             attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
             return attn(h, attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
 
@@ -1191,8 +1276,13 @@ class Block(nn.Module):
         routed = moe.routing(x) if moe is not None and cfg.router_input == "block" else None
 
         def feed_forward(h):
-            nonlocal counts
+            nonlocal counts, routing, routed
             if moe is not None:
+                if cfg.router_kind == "mlp":
+                    routed, state = moe.routing_with_state(h, router_state if cfg.router_carry else None)
+                    if cfg.router_carry or cfg.router_scoring == "softmax_all":
+                        routing = {"state": state if cfg.router_carry else None,
+                                   "top_weight": jnp.mean(jax.lax.stop_gradient(routed[1][:, 0]))}
                 h, counts = moe(h, routed=routed)
                 return h
             return MLP(cfg, name="mlp")(h)
@@ -1200,18 +1290,34 @@ class Block(nn.Module):
         # both residual branches of a block take `residual_multiplier`
         branch = (lambda y: y) if cfg.residual_multiplier == 1.0 else (
             lambda y: y * jnp.asarray(cfg.residual_multiplier, y.dtype))
+
+        def joined(name, skip, f):
+            """`residual_scaling`: (skip + b_s) * a_s + (f + b_f) * a_f, four learned
+            vectors a sum, drawn from the seed off their neutral values."""
+            near_one = lambda key, shape, dtype=jnp.float32: 1.0 + 0.05 * jax.random.normal(key, shape, dtype)
+            vec = lambda part, init: self.param(f"{name}_{part}", drawn_in(cfg.draw_dtype, init), (cfg.d_model,),
+                                                cfg.params_dtype).astype(skip.dtype)
+            small = nn.initializers.normal(0.02)
+            with jax.named_scope("residual_scaling"):
+                return ((skip + vec("skip_bias", small)) * vec("skip_scale", near_one)
+                        + (f.astype(skip.dtype) + vec("branch_bias", small)) * vec("branch_scale", near_one))
+
         if cfg.parallel_residual:
             h = ln("ln_1")(x)
             attn_out, new_cache = mix(h)
             mlp_in = ln("ln_2")(x) if cfg.use_parallel_ln else h
             x = x + branch(attn_out) + branch(feed_forward(mlp_in))
+        elif cfg.residual_scaling:
+            attn_out, new_cache = mix(ln("ln_1")(x))
+            x = joined("res_1", x, attn_out)
+            x = joined("res_2", x, feed_forward(ln("ln_2")(x)))
         else:
             # sandwich_norm: a norm on each branch's output as well as on its input
             out_norm = (lambda name, y: ln(name)(y)) if cfg.sandwich_norm else (lambda name, y: y)
             attn_out, new_cache = mix(ln("ln_1")(x))
             x = x + branch(out_norm("ln_1_out", attn_out))
             x = x + branch(out_norm("ln_2_out", feed_forward(ln("ln_2")(x))))
-        return hold_rows(x), new_cache, counts
+        return hold_rows(x), new_cache, counts, routing
 
 
 def make_attn_bias(
@@ -1291,6 +1397,7 @@ class TransformerLM(nn.Module):
         labels: Optional[jnp.ndarray] = None,
         labels_mask: Optional[jnp.ndarray] = None,
         segment_ids: Optional[jnp.ndarray] = None,
+        router_state: Optional[jnp.ndarray] = None,
     ):
         """Returns dict(logits, hidden, branch_hidden, cache).
 
@@ -1319,6 +1426,12 @@ class TransformerLM(nn.Module):
           tokens each held expert took in this call; None without expert layers.
         - ``exit_probs`` [b, q_len, n_loops] float32 (`exit_gate`, no cache,
           start_layer 0): the exit distribution over the loops.
+        - `router_state` [b, q_len, router_hidden] (`router_carry`, with
+          `start_layer` > 0): the router state block start_layer - 1 handed
+          on, the replay's second input; `collect_hidden_at=k` returns it as
+          ``branch_router_state`` beside ``branch_hidden``.
+          ``router_top_weight``: the mean weight of a token's first expert
+          choice over the expert layers run (`router_scoring` "softmax_all").
         - `segment_ids` [b, q_len] (packed train batches; full-sequence
           passes only) makes attention block-diagonal per packed segment —
           the einsum bias path is forced, since the flash/ring kernels'
@@ -1449,6 +1562,15 @@ class TransformerLM(nn.Module):
                 f"a {'state-space' if cfg.has_ssm else 'kda'} layer takes a pass with no cache, a prefill at write "
                 "offset 0 or one token a step for the whole batch (the static generate path): no block table, "
                 "per-row offset, verify window or packed segments")
+        if cfg.attention == "cca" and (segment_ids is not None or (cache is not None and (
+                block_tables is not None or jnp.ndim(cache_index) != 0 or (q_len > 1 and not prefill_at_zero)))):
+            raise NotImplementedError(
+                "attention 'cca' takes a pass with no cache, a prefill at write offset 0 or one token a step for the "
+                "whole batch (the static generate path): its convolutions' window and shifted value are one a row, so "
+                "no block table, per-row offset, verify window or packed segments")
+        if cfg.router_carry and (start_layer > 0) != (router_state is not None):
+            raise ValueError("router_carry: a pass from start_layer > 0 (the frozen branch's replay) takes the router "
+                             "state of the block below as `router_state`, a pass from the first block takes none")
         if segment_ids is not None:
             # Packed segments need a block-diagonal mask; the flash/ring
             # kernels' (causal × key-validity) masks cannot express that.
@@ -1514,9 +1636,11 @@ class TransformerLM(nn.Module):
                 "step for the whole batch (the static generate path): no block table, per-row offset, verify "
                 "window, packed segments or stop_layer")
 
-        branch_hidden = None
+        branch_hidden = branch_router_state = None
         new_cache = [] if cache is not None else None
-        expert_counts = []
+        expert_counts, top_weights = [], []
+        if cfg.router_carry and router_state is None:  # nothing lies below the first block
+            router_state = jnp.zeros((b, q_len, cfg.router_hidden), jnp.float32)
         # All blocks are *defined* every call so the param structure is
         # identical regardless of start/stop — only [start, stop) execute. A
         # looped stack calls the same N modules n_loops times (the same
@@ -1533,21 +1657,26 @@ class TransformerLM(nn.Module):
         def one_pass(blocks, x, loop, first):
             """Blocks [first, stop_layer) once; `loop` is an int where the pass
             is unrolled (it names the cache entries and the taps), None in the scan."""
-            nonlocal branch_hidden
+            nonlocal branch_hidden, branch_router_state, router_state
             for i, block in enumerate(blocks):
                 if i < first or i >= stop_layer:
                     continue
                 if collect_hidden_at is not None and i == collect_hidden_at and loop == 0:
-                    branch_hidden = x
+                    branch_hidden, branch_router_state = x, router_state
                 layer_cache = cache[loop * cfg.n_layer + i] if cache is not None else None
                 window = layer_window(cfg, i)
                 layer_bias = local_bias if window else attn_bias
                 # a layer that keeps a state reads the tokens' mask itself, in place of a bias
-                token_mask = (attention_mask,) if cfg.mixer(i) != "attention" else ()
-                x, layer_new_cache, layer_counts = block(
+                token_mask = (attention_mask,) if cfg.mixer(i) != "attention" or cfg.attention == "cca" else ()
+                if cfg.router_carry:  # the block's last operand, after a token mask or None in its place
+                    token_mask = (token_mask or (None,)) + (router_state,)
+                x, layer_new_cache, layer_counts, routing = block(
                     x, layer_bias, position_ids, layer_cache, cache_index,
                     flash_mask, window, use_ring, block_tables, *token_mask,
                 )
+                if routing is not None:
+                    router_state = routing["state"]
+                    top_weights.append(routing["top_weight"])
                 if loop is not None:
                     x = obs_numerics.probe_tap(f"block_{i}" if loop == 0 else f"loop_{loop}_block_{i}", x)
                 if cache is not None:
@@ -1692,6 +1821,9 @@ class TransformerLM(nn.Module):
             # [expert layers run, experts held]: tokens each held expert took
             # in this call (models/moe.py); None for a model without them.
             "expert_counts": jnp.stack(expert_counts) if expert_counts else None,
+            # `router_carry`: the router state entering block `collect_hidden_at`, float32
+            "branch_router_state": branch_router_state,
+            "router_top_weight": jnp.mean(jnp.stack(top_weights)) if top_weights else None,
             # [b, t, n_loops] float32: where the exit gate would leave the loop
             # (a pass with no cache over a gated looped stack); None otherwise.
             "exit_probs": exit_probs,
@@ -1734,7 +1866,9 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
     ssm_head_dim, ssm_state] float32), no slot axis whatever `max_len`; a
     "kda" layer (models/kda.py) likewise: (conv [b, kda_conv - 1, 3 x channels],
     state [b, kda_heads, kda_head_dim, kda_head_dim] float32), beside the
-    latent leaves of the "mla" layers of the same stack. A
+    latent leaves of the "mla" layers of the same stack; "cca" (models/cca.py):
+    (k, v) [b, T, kv_heads, hd] and, with no slot axis, (window [b, cca_time0 +
+    cca_time1 - 2, (n_head + kv_heads) hd], shifted [b, 1, kv_heads / 2 hd]). A
     looped stack (n_loops > 1) keeps n_loops * n_layer groups, entry
     loop * n_layer + layer: loop r's layer reads what loop r's layer wrote."""
     if cfg.kv_cache_quant:
@@ -1749,6 +1883,10 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
             return tuple(jnp.zeros(shape, leaf_dtype) for shape, leaf_dtype in shapes)
         if cfg.attention == "mla":
             return jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype), jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype)
+        if cfg.attention == "cca":
+            from trlx_tpu.models import cca
+
+            return tuple(jnp.zeros(shape, leaf_dtype) for shape, leaf_dtype in cca.cache_shapes(cfg, batch, max_len))
         sshape = (batch, ring_slots(cfg, i, max_len) or max_len, cfg.kv_heads)
         shape = sshape + (cfg.head_dim,)
         if cfg.kv_cache_quant:
@@ -1811,6 +1949,8 @@ def cache_partition_spec(cfg: LMConfig, leaf_ndim: int, layer: int = 0):
         return PartitionSpec(DATA_AXES, AXIS_TP, None, None) if leaf_ndim == 4 else PartitionSpec(DATA_AXES, None, None)
     if cfg.attention == "mla":
         return PartitionSpec(DATA_AXES, None, None)
+    if cfg.attention == "cca" and leaf_ndim == 3:  # the window and the shifted value: whole on every tp shard
+        return PartitionSpec(DATA_AXES, None, None)
     # 4-D leaves are k/v ([b, T, h, d]); 3-D leaves are the int8 cache's
     # per-slot scales ([b, T, h]).
     return PartitionSpec(DATA_AXES, None, AXIS_TP, None) if leaf_ndim == 4 else PartitionSpec(DATA_AXES, None, AXIS_TP)
@@ -1832,11 +1972,22 @@ def ring_cache_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
 
 
 def state_bytes(cfg: LMConfig, batch: int) -> int:
-    """The part of `cache_bytes` that the layers with a recurrent state hold
-    ("mamba", "kda": state and convolution window, whatever the length): the
-    counter `rollout/state_bytes`, from `init_cache`'s own shapes."""
+    """The part of `cache_bytes` that does not grow with the length: what the
+    layers with a recurrent state hold ("mamba", "kda": state and convolution
+    window) and what "cca" layers keep beside their slots (`cca_state_bytes`).
+    The counter `rollout/state_bytes`, from `init_cache`'s own shapes."""
     cache = jax.eval_shape(lambda: init_cache(cfg, batch, 1))
-    return tree_size_bytes([cache[i] for i in range(cfg.n_layer) if cfg.mixer(i) != "attention"])
+    return tree_size_bytes([cache[i] for i in range(cfg.n_layer) if cfg.mixer(i) != "attention"]) + cca_state_bytes(cfg, batch)
+
+
+def cca_state_bytes(cfg: LMConfig, batch: int) -> int:
+    """The part of `cache_bytes` that "cca" layers hold beside keys and values
+    (the convolutions' window and the shifted value, whatever the length): the
+    counter `rollout/cca_state_bytes`, from `init_cache`'s own shapes."""
+    if cfg.attention != "cca":
+        return 0
+    cache = jax.eval_shape(lambda: init_cache(cfg, batch, 1))
+    return tree_size_bytes([cache[i][2:] for i in range(cfg.n_layer) if cfg.mixer(i) == "attention"])
 
 
 def cache_bytes_per_token(cfg: LMConfig) -> int:

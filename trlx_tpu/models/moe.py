@@ -8,7 +8,11 @@ deployment holds it.
 
 That is `LMConfig.router_scoring` "sigmoid". "softmax" is another family's
 rule (`route`): the experts_per_token largest of the raw logits x . W_g, then
-a softmax over the chosen, no bias buffer and no scale. What the router reads
+a softmax over the chosen, no bias buffer and no scale; "softmax_all" a third
+(`choose`): a softmax over all experts, the choice by probability + a
+balancing bias, the weight the chosen probability itself. The logits are one
+product (`router_kind` "linear") or an MLP behind a down-projection whose
+output the layer above carries on (`MLPRouter`, `router_carry`). What the router reads
 is a kind too (`LMConfig.router_input`): the feed-forward's input, or the
 block's own input ahead of attention (`ExpertLayer.routing`, called by
 `models/lm.py Block` before its mixer).
@@ -95,24 +99,75 @@ GATHER_ROWS_PER_CHOICE = 640  # rows of slot buffer a choice of a token from whi
 TOKEN_CHUNK = 4096  # tokens a pass: bounds the buffers of a scoring pass over a whole rollout chunk
 
 
+def choose(logits, bias, k: int, scaling: float, scoring: str):
+    """(ids [n, k] int32, weights [n, k] float32) from the router's float32
+    `logits` [n, n_experts]. `scoring` "sigmoid": the rule of the module
+    docstring. "softmax": the k largest of the raw logits and a softmax over
+    those k, which is a softmax over all n_experts renormalised over the
+    chosen; `bias` is None and `scaling` 1. "softmax_all": a softmax over all
+    n_experts, the k largest of probability + `bias` (a buffer no gradient
+    moves), the weights the chosen probabilities as they are: not
+    renormalised, so that with k = 1 the router still has a gradient
+    (`LMConfig.router_scoring`)."""
+    if scoring == "softmax":
+        chosen, ids = jax.lax.top_k(logits, k)
+        weights = jax.nn.softmax(chosen, axis=-1)
+    elif scoring == "softmax_all":
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, ids = jax.lax.top_k(probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        weights = jnp.take_along_axis(probs, ids, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        chosen = jnp.take_along_axis(scores, ids, axis=-1)
+        weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scaling
+    return ids.astype(jnp.int32), weights
+
+
 def route(x, router, bias, k: int, scaling: float, scoring: str = "sigmoid"):
-    """(ids [n, k] int32, weights [n, k] float32) of tokens `x` [n, d]: the
-    router's product and everything after it in float32, as published.
-    `scoring` "sigmoid": the rule of the module docstring. "softmax": the k
-    largest of the raw logits and a softmax over those k, which is a softmax
-    over all n_experts renormalised over the chosen; `bias` is None and
-    `scaling` 1 (`LMConfig.router_scoring`)."""
+    """`choose` over the linear router's logits of tokens `x` [n, d]: the
+    router's product and everything after it in float32, as published."""
     with jax.named_scope("moe_router"):
         logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
-        if scoring == "softmax":
-            chosen, ids = jax.lax.top_k(logits, k)
-            weights = jax.nn.softmax(chosen, axis=-1)
-        else:
-            scores = jax.nn.sigmoid(logits)
-            _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
-            chosen = jnp.take_along_axis(scores, ids, axis=-1)
-            weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20) * scaling
-    return ids.astype(jnp.int32), weights
+        return choose(logits, bias, k, scaling, scoring)
+
+
+class MLPRouter(nn.Module):
+    """The router of `LMConfig.router_kind` "mlp", all of it in float32:
+
+        s = h W_d + b_d                      [n, router_hidden]
+        s = s + gamma * s_below              `router_carry`: the layer below's s, after ITS carry and before its norm
+        z = RMSNorm(s)
+        logits = gelu(gelu(z W_1 + b_1) W_2 + b_2) W_3          [n, n_experts]
+
+    Returns (logits, s): s is the state the layer above carries on. The first
+    layer is handed zeros (nothing lies below it), so its gamma moves nothing."""
+
+    cfg: LMConfig
+
+    @nn.compact
+    def __call__(self, h, below):
+        cfg, f32 = self.cfg, jnp.float32
+        lecun = nn.initializers.lecun_normal()
+        # The two kernels behind a GeLU are DRAWN with zero column sums (initialisation only): a GeLU's output has
+        # a positive mean, the same on every token, which a plain draw turns into logit offsets every token shares
+        # (deviation 0.37 beside the tokens' own 0.52), and a router over random weights then sends 40-60% of the
+        # tokens to two experts (`moe_max_expert_load` 7-10 on the chip, PERF.md section 6, PR 47). A trained
+        # router's balancing bias evens that out; here the draw does (1.3-2.0).
+        centred = lambda key, shape, dtype=f32: (lambda w: w - jnp.mean(w, axis=0, keepdims=True))(lecun(key, shape, dtype))
+        dense = lambda feats, name, bias=True, init=lecun: nn.Dense(
+            feats, use_bias=bias, dtype=f32, param_dtype=cfg.params_dtype, precision=jax.lax.Precision.HIGHEST, name=name,
+            kernel_init=drawn_in(cfg.draw_dtype, init), bias_init=nn.initializers.normal(0.02))
+        s = dense(cfg.router_hidden, "down")(h.astype(f32))
+        if cfg.router_carry:
+            # drawn from the seed at the state's own size: a zero one would let a program that dropped the carry pass
+            gamma = self.param("carry_scale", nn.initializers.normal(0.5), (cfg.router_hidden,), cfg.params_dtype)
+            if below is not None:  # None: the layer alone, nothing below it
+                s = s + gamma.astype(f32) * below.astype(f32)
+        z = nn.RMSNorm(epsilon=cfg.ln_eps, dtype=f32, param_dtype=cfg.params_dtype, name="norm")(s)
+        gelu = lambda a: nn.gelu(a, approximate=False)
+        hidden = gelu(dense(cfg.router_hidden, "hidden_1", init=centred)(gelu(dense(cfg.router_hidden, "hidden_0")(z))))
+        return dense(cfg.n_experts, "out", False, centred)(hidden), s
 
 
 def slot_capacity(n_tokens: int, k: int, held: int, n_experts: int) -> int:
@@ -319,16 +374,22 @@ class ExpertLayer(nn.Module):
         cfg = self.cfg
         d, f = cfg.d_model, cfg.expert_d_ff
         held = cfg.held_experts[1]
-        self.router = self.param("router", drawn_in(cfg.draw_dtype, nn.initializers.lecun_normal()), (d, cfg.n_experts), cfg.params_dtype)
+        if cfg.router_kind == "mlp":
+            self.router = MLPRouter(cfg)
+        else:
+            self.router = self.param("router", drawn_in(cfg.draw_dtype, nn.initializers.lecun_normal()), (d, cfg.n_experts), cfg.params_dtype)
         # Drawn from the seed, small: a trained router's bias is not zero, and
         # a zero one would let a program that forgot it pass every comparison;
         # but the bias exists to even the load out, and a random one of the
         # scores' own size (deviation 0.1 against the sigmoid's 0.2) skews it:
         # the chip read a fullest expert at 6-15 times the mean (PERF.md, PR 26).
-        # A softmax router (`router_scoring`) has no such buffer.
+        # A softmax router over the chosen (`router_scoring`) has no such
+        # buffer; one over all experts has a balancing bias on probabilities,
+        # which at 16 experts are a sixteenth each: drawn that much smaller.
         self.bias = None
-        if cfg.router_scoring == "sigmoid":
-            self.bias = self.param(BIAS_NAME, nn.initializers.normal(stddev=0.01), (cfg.n_experts,), jnp.float32)
+        if cfg.router_scoring != "softmax":
+            std = 0.01 if cfg.router_scoring == "sigmoid" else 0.005
+            self.bias = self.param(BIAS_NAME, nn.initializers.normal(stddev=std), (cfg.n_experts,), jnp.float32)
         stacked = drawn_in(cfg.draw_dtype, nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,)))
         self.experts_gate = self.param("experts_gate", stacked, (held, d, f), cfg.params_dtype)
         self.experts_up = self.param("experts_up", stacked, (held, d, f), cfg.params_dtype)
@@ -351,11 +412,23 @@ class ExpertLayer(nn.Module):
         """(ids, weights) [b * t, k] of the tokens `x` [b, t, d]."""
         return self._route(self._flat(x))
 
+    def routing_with_state(self, h, below):
+        """((ids, weights) [b * t, k], state [b, t, router_hidden] float32)
+        of the tokens `h` [b, t, d] under `router_kind` "mlp"; `below` is the
+        state the layer below handed on (`router_carry`; None without it)."""
+        cfg = self.cfg
+        with jax.named_scope("moe_router"):
+            logits, state = self.router(self._flat(h), None if below is None else below.reshape(-1, below.shape[-1]))
+            routed = choose(logits, self.bias, cfg.experts_per_token, cfg.routed_scaling_factor, cfg.router_scoring)
+        return routed, state.reshape(h.shape[:-1] + state.shape[-1:])
+
     def __call__(self, h, router_input=None, routed=None):
         cfg = self.cfg
         dtype = cfg.compute_dtype
         b, t, d = h.shape
         flat = self._flat(h)
+        if routed is None and cfg.router_kind == "mlp":
+            routed, _ = self.routing_with_state(h, None)
         if routed is None:  # traced in the order it always was: the router, the stacks, the tokens
             routed = self._route(flat if router_input is None else self._flat(router_input))
         at_use = lambda w, name: use_weight(w.astype(dtype), self.path + (name,), b * t)

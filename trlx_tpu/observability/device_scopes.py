@@ -61,7 +61,9 @@ SCOPES = (
     "kv_read",  # a read of the KV cache: the ranged switch, its branches, the int8 scale work
     "mla_absorbed",  # latent attention's read of the latent cache (decode)
     "mla_unabsorbed",  # latent attention over a block's own latents (train, score, prefill)
-    "moe_router",  # expert layer: router scores, top-k, slot placement
+    "cca_mix",  # attention "cca": projections, both causal convolutions, the q-k mean, the value shift, L2 norm and rotary
+    "residual_scaling",  # a block's residual sums under learned scales and biases on both operands
+    "moe_router",  # expert layer: router scores (a product, or the MLP router and its carried state), top-k, slot placement
     "moe_experts",  # expert layer: everything the held experts do to their tokens
     "moe_grouped_ffn",  # the grouped products of the held experts
     "moe_shared",  # the shared expert

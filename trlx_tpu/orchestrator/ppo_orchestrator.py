@@ -27,8 +27,8 @@ import jax
 import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
-from trlx_tpu.models.lm import (cache_bytes, cache_bytes_per_token, decode_step_bytes, layer_window, ring_cache_bytes, ring_slots,
-                                state_bytes)
+from trlx_tpu.models.lm import (cache_bytes, cache_bytes_per_token, cca_state_bytes, decode_step_bytes, layer_window,
+                                ring_cache_bytes, ring_slots, state_bytes)
 from trlx_tpu.ops.kv_read import kv_keys_read, kv_scale_mults_per_key
 from trlx_tpu.parallel.schedule import weight_gather_share
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
@@ -545,6 +545,12 @@ class PPOOrchestrator(Orchestrator):
                     lm_cfg.n_head, lm_cfg.kv_heads, lm_cfg.head_dim)
             if lm_cfg.window_cache == "ring" and cache_alloc:
                 rl._last_exp_stats["rollout/ring_cache_share"] = ring_cache_bytes(lm_cfg, gen_rows, gen_len) / cache_alloc
+            if lm_cfg.attention == "cca" and cache_alloc:
+                # what the layers keep beside their slots: the convolutions' window and the shifted value
+                rl._last_exp_stats["rollout/cca_state_bytes"] = float(cca_state_bytes(lm_cfg, gen_rows))
+                # the cache over what keys and values 2 x d_model wide a token a layer would take
+                rl._last_exp_stats["rollout/cca_cache_share"] = cache_alloc / (
+                    gen_rows * gen_len * lm_cfg.n_layer * 2 * lm_cfg.d_model * lm_cfg.compute_dtype.itemsize)
             if experts_touched:
                 rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
             if (lm_cfg.has_state or lm_cfg.n_loops > 1) and cache_alloc:

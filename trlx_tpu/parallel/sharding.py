@@ -43,6 +43,11 @@ def lm_partition_rules() -> List[Tuple[str, P]]:
         # whole, `sanitize_specs`)
         (r"attn/(q_proj|k_proj|v_proj)/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"attn/(q_proj|k_proj|v_proj)/bias$", P(AXIS_TP)),
+        # attention "cca" (models/cca.py): its projections as the dense kernels
+        # they are (q_proj, k_proj, v_proj, c_proj by the rules here; the
+        # shifted value's like v_proj); the convolutions, their biases and the
+        # key temperature whole, by the fallback
+        (r"attn/v_shift_proj/kernel$", P(AXIS_FSDP, AXIS_TP)),
         # attention output [d, d_model] row-parallel
         (r"attn/c_proj/kernel$", P(AXIS_TP, AXIS_FSDP)),
         (r"attn/c_proj/bias$", P(None)),

@@ -301,6 +301,8 @@ class PPOTrainer(JaxBaseTrainer):
                 "scoring delta is bounded by the engine+int8 parity test)."
             )
         if self.fused_rollout:
+            # a router state carried across depth: the replay's second input, collected beside the first
+            branch_keys = ("branch_hidden",) + (("branch_router_state",) if self.model.cfg.router_carry else ())
 
             def rollout_stats_fn(tok, s):
                 lp = jax.nn.log_softmax(s["last_logits"], axis=-1)  # fp32 raw
@@ -309,17 +311,17 @@ class PPOTrainer(JaxBaseTrainer):
                         lp, tok[:, None].astype(jnp.int32), axis=-1
                     )[:, 0],
                     "value": s["carry"]["values"],
-                    "branch_hidden": s["carry"]["branch_hidden"],
+                    **{key: s["carry"][key] for key in branch_keys},
                 }
 
             self._generate_fused_fn = make_generate_fn(
                 self.model,
                 self.gen_cfg,
                 processor,
-                carry_keys=("values", "branch_hidden"),
+                carry_keys=("values",) + branch_keys,
                 step_stats_fn=rollout_stats_fn,
                 apply_kwargs={"collect_branch_hidden": True},
-                prefill_collect=("branch_hidden",),
+                prefill_collect=branch_keys,
                 monitor=getattr(self, "_devicemon", None),
                 monitor_name="rollout/generate_fused",
             )
@@ -639,7 +641,8 @@ class PPOTrainer(JaxBaseTrainer):
                 self._decode_variables(snapshot), batch["i"], batch["m"], rng
             )
 
-    def _rollout_score_fused_impl(self, extras, tokens, mask, scores, kl_coef, logprob, value, bh_steps, bh_prefill, *, prompt_length: int):
+    def _rollout_score_fused_impl(self, extras, tokens, mask, scores, kl_coef, logprob, value, bh_steps, bh_prefill,
+                                  rs_steps=None, rs_prefill=None, *, prompt_length: int):
         """Scoring with decode-collected stats: ONLY the frozen ref branch
         replays (for KL); the policy's logprobs/values come from the decode
         loop that produced the tokens (identical parameters, so they ARE the
@@ -652,19 +655,20 @@ class PPOTrainer(JaxBaseTrainer):
         once the last logits row is dropped), the pad only keeps the ring/
         flash sequence shapes identical to the unfused path."""
         P = prompt_length
-        bh = jnp.concatenate(
-            [bh_prefill, bh_steps[:, 1:], jnp.zeros_like(bh_steps[:, :1])], axis=1
-        )  # [b, T, d]
+        joined = lambda prefill, steps: jnp.concatenate([prefill, steps[:, 1:], jnp.zeros_like(steps[:, :1])], axis=1)
+        bh = joined(bh_prefill, bh_steps)  # [b, T, d]
+        # `router_carry`: the router state entering the branch point, assembled the same way
+        second = {} if rs_steps is None else {"router_state": joined(rs_prefill, rs_steps)}
         if resolve_fused_head(self.model.cfg):
             # Streaming head: the ref branch's [b, R, V] logits never land in
             # HBM — forward_branch returns the label logprobs directly.
             rlp = self.model.apply(
                 {"params": extras}, bh, mask, method="forward_branch",
-                logits_start=P - 1, labels=tokens[:, P:], labels_mask=mask[:, P:],
+                logits_start=P - 1, labels=tokens[:, P:], labels_mask=mask[:, P:], **second,
             )
         else:
             ref_logits = self.model.apply(
-                {"params": extras}, bh, mask, method="forward_branch", logits_start=P - 1
+                {"params": extras}, bh, mask, method="forward_branch", logits_start=P - 1, **second
             ).astype(jnp.float32)
             rlp = logprobs_from_logits(ref_logits[:, :-1], tokens[:, P:])
         rmask = mask[:, P:]
@@ -688,7 +692,13 @@ class PPOTrainer(JaxBaseTrainer):
                 stats["value"],
                 stats["branch_hidden"],
                 prefill_extras["branch_hidden"],
+                *((stats["branch_router_state"], prefill_extras["branch_router_state"]) if self.model.cfg.router_carry else ()),
             )
+
+    def _branch_router_state(self, out):
+        """`forward_branch`'s second input from the policy's pass `out`, as keywords: the router state
+        entering the branch point where the model carries one across depth (`router_carry`), else nothing."""
+        return {"router_state": out["branch_router_state"]} if self.model.cfg.router_carry else {}
 
     def _rollout_score_impl(self, params, extras, tokens, mask, scores, kl_coef, *, prompt_length: int):
         P = prompt_length
@@ -710,7 +720,7 @@ class PPOTrainer(JaxBaseTrainer):
                 rlp = self.model.apply(
                     {"params": extras}, out["branch_hidden"], mask,
                     method="forward_branch", logits_start=P - 1,
-                    labels=rlabels, labels_mask=rlmask,
+                    labels=rlabels, labels_mask=rlmask, **self._branch_router_state(out),
                 )
             else:
                 rlp = self.model.apply(
@@ -728,7 +738,7 @@ class PPOTrainer(JaxBaseTrainer):
             if self.model.branch_layer >= 0:
                 ref_logits = self.model.apply(
                     {"params": extras}, out["branch_hidden"], mask,
-                    method="forward_branch", logits_start=P - 1,
+                    method="forward_branch", logits_start=P - 1, **self._branch_router_state(out),
                 ).astype(jnp.float32)
             else:
                 ref_logits = self.model.apply(
@@ -1142,6 +1152,8 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         share, load = expert_load_stats(out["expert_counts"], n_tokens, k)
         fit = first_buffer_share(out["expert_counts"], n_tokens, k, model.cfg.n_experts)
         loss, stats = result
+        if out["router_top_weight"] is not None:  # one expert a token: whether the policy's router has collapsed
+            stats = {**stats, "moe/top1_weight_mean": out["router_top_weight"]}
         return loss, {**stats, "moe/held_slot_share": share, "moe/max_expert_load": load, "moe/first_buffer_share": fit}
 
     def dense_loss_fn(params, batch: PPORLBatch):
