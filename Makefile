@@ -75,7 +75,7 @@ SCRIPT_LINT_RULES = GL003,GL004,GL007,GL008,GL009,GL010,GL011
 lint:
 	python -m trlx_tpu.analysis trlx_tpu/
 	python -m trlx_tpu.analysis --select $(SCRIPT_LINT_RULES) \
-	    chip_smoke.py acceptance_network.py bench_flash.py bench_kda.py
+	    chip_smoke.py acceptance_network.py bench_flash.py bench_kda.py bench_moe.py
 
 # graftrace runtime half, fully armed: the thread-heavy suites (resilience
 # fault drills, overlap pipeline, rollout engine) under

@@ -409,6 +409,7 @@ def test_scripts_lint_clean_with_script_rule_subset():
             "acceptance_network.py",
             "bench_flash.py",
             "bench_kda.py",
+            "bench_moe.py",
         )
         if os.path.exists(os.path.join(REPO, name))
     ]

@@ -155,6 +155,48 @@ ENTRY %main.3 (a: f32[8]) -> f32[8] {
     }}  # no entry for the fused computation's member, for the copy without metadata, nor for the loop without a scope
 
 
+def test_scope_table_gives_a_kernel_without_a_name_stack_the_scope_of_what_it_reads_or_of_what_reads_it():
+    """The grouped products of an expert call that goes in ONE pass: XLA names
+    them `ragged-dot-none` with no name stack and nothing contains them."""
+    kernel = 'custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}'
+    experts = 'metadata={op_name="jit(step)/jvp(T)/h_0/moe/moe_experts/%s"}'
+    text = f"""HloModule jit_step, is_scheduled=true
+
+ENTRY %main.9 (w: bf16[8,4,4], g: bf16[16,4]) -> (bf16[16,4], bf16[8,4,4]) {{
+  %w = bf16[8,4,4]{{2,1,0}} parameter(0), metadata={{op_name="p['experts_up']"}}
+  %g = bf16[16,4]{{1,0}} parameter(1), metadata={{op_name="g"}}
+  %sizes.1 = (s32[9]{{0:T(128)S(1)}}, s32[1]{{0}}) fusion(%g), kind=kLoop, calls=%fc.1, {experts % "cumsum"}
+  %gte.1 = s32[9]{{0:T(128)S(1)}} get-tuple-element(%sizes.1), index=0
+  %ragged-dot-metadata = (s32[9]{{0:T(128)S(1)}}, s32[1]{{0:T(128)}}) custom-call(%gte.1), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-metadata"}}
+  %gte.2 = s32[9]{{0:T(128)S(1)}} get-tuple-element(%ragged-dot-metadata), index=0
+  %rows.2 = bf16[16,4]{{1,0:T(8,128)(2,1)}} fusion(%g), kind=kLoop, calls=%fc.2, {experts % "take_rows"}
+  %copy.3 = bf16[8,4,4]{{1,2,0:T(8,128)(2,1)}} copy(%w), metadata={{op_name="p['experts_up']"}}
+  %bitcast.3 = bf16[8,4,4]{{2,1,0}} bitcast(%copy.3)
+  %ragged-dot-none.2 = bf16[16,4]{{1,0:T(8,128)(2,1)S(1)}} custom-call(%gte.2, /*index=1*/%rows.2, %w), {kernel}
+  %copy-start.4 = (bf16[16,4]{{1,0}}, bf16[16,4]{{1,0}}, u32[]{{:S(2)}}) copy-start(%ragged-dot-none.2)
+  %copy-done.4 = bf16[16,4]{{1,0}} copy-done(%copy-start.4)
+  %ragged-dot-none.1 = bf16[16,4]{{1,0}} custom-call(%gte.2, bf16[16,4]{{1,0}} %copy-done.4, bf16[8,4,4]{{2,1,0}} %bitcast.3), {kernel}
+  %sum.5 = bf16[16,4]{{1,0}} fusion(%ragged-dot-none.1), kind=kLoop, calls=%fc.3, metadata={{op_name="jit(step)/transpose(jvp(T))/h_0/moe/moe_experts/moe_grouped_ffn/add_any"}}
+  %ragged-dot-none = bf16[8,4,4]{{2,1,0:T(8,128)(2,1)}} custom-call(%gte.2, %rows.2, %sum.5), {kernel}
+  %adam.6 = bf16[8,4,4]{{2,1,0}} fusion(%ragged-dot-none, %w), kind=kLoop, calls=%fc.4, metadata={{op_name="jit(step)/optimizer/mul"}}
+  %alone = bf16[16,4]{{1,0}} custom-call(%g), {kernel}
+  ROOT %tuple.7 = (bf16[16,4]{{1,0}}, bf16[8,4,4]{{2,1,0}}) tuple(%sum.5, %adam.6)
+}}
+"""
+    ops = device_scopes.scope_table(text)["ops"]
+    assert {name: entry for name, entry in ops.items() if entry[0]} == {
+        "sizes.1": ["moe_experts", "fwd"], "rows.2": ["moe_experts", "fwd"],
+        "sum.5": ["moe_experts/moe_grouped_ffn", "bwd"], "adam.6": ["optimizer", "fwd"],
+        # an operand's scope first (the weight gradient's user is the optimizer), through a tuple's element
+        "ragged-dot-metadata": ["moe_experts", "fwd"], "ragged-dot-none.2": ["moe_experts", "fwd"],
+        "ragged-dot-none": ["moe_experts", "fwd"],
+        # operands with none behind what has no metadata (the other kernel lends nothing in its round): the user's.
+        # So for the relayout copy of a weight, named after its parameter: who reads it is found behind the bitcast
+        "ragged-dot-none.1": ["moe_experts/moe_grouped_ffn", "fwd"], "copy.3": ["moe_experts/moe_grouped_ffn", "fwd"],
+    }  # each keeps its own pass, as under a container
+    assert ops["alone"] == ["", "fwd"] and ops["w"] == ["", "fwd"] and "gte.2" not in ops and "copy-done.4" not in ops
+
+
 def test_proxy_forwards_attributes_and_donated_arguments_and_notes_only_under_a_session(tmp_path):
     step = jax.jit(lambda s, b: (s + b, s * 2.0), donate_argnums=0)
     proxy = device_scopes.wrap(step)

@@ -220,7 +220,7 @@ def _paths(monkeypatch, path):
     if isinstance(LARGE_CALL_PATHS[path], int):
         monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: LARGE_CALL_PATHS[path])
     if LARGE_CALL_PATHS[path] == "chunks":
-        monkeypatch.setattr(moe, "TOKEN_CHUNK", 12)  # 48 tokens: four passes
+        monkeypatch.setattr(moe, "NARROW_PASS_TOKENS", 12), monkeypatch.setattr(moe, "WIDE_PASS_TOKENS", 12)  # 48 tokens: four passes
 
 
 @pytest.mark.parametrize("path", LARGE_CALL_PATHS)
@@ -264,6 +264,53 @@ def test_gated_relu_experts_against_a_plain_loop(monkeypatch, path):
     # forward, and as `take_rows`' transpose in the backward pass: one form throughout, none in a small call
     assert set(forms) == (set() if path == "small call" else {"sum_rows_gather"} if path in GATHER_FROM else {"sum_rows_product"})
     assert len(forms) != 1
+
+
+ONE_PASS_BUFFERS = {  # rows of the slot buffer of a pass of n tokens (3 choices of 16 experts a token, 4 held: 0.75 n held slots at an even router)
+    "a wide buffer, three rows a token: no overflow branch": lambda n: 3 * n,
+    "a buffer of a row a token, under the cond": lambda n: n,
+    "a buffer the held slots pass: the dense path": lambda n: n // 4,
+}
+
+
+@pytest.mark.parametrize("buffer", ONE_PASS_BUFFERS)
+def test_one_pass_over_a_call_equals_the_same_call_in_three(monkeypatch, buffer):
+    """The expert layer over one call of 48 tokens in ONE pass (what
+    `pass_tokens` gives a train batch where the buffer is wide, PR 48) against
+    the same call forced into three passes of 16: the layer's output, the
+    held experts' counts, and the gradients of the tokens, the router and the
+    three stacks. One grouped contraction over the call's rows where three
+    were summed over the passes: the same rows, the same products."""
+    monkeypatch.setattr(moe, "SMALL_CALL_SLOTS", 0)
+    monkeypatch.setattr(moe, "GATHER_ROWS_PER_CHOICE", 1)  # summed back by the gather, as a wide buffer is
+    monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: ONE_PASS_BUFFERS[buffer](n))
+    placed = []
+    monkeypatch.setattr(moe, "place_slots", lambda ids, *a, fn=moe.place_slots: placed.append(ids.shape[0]) or fn(ids, *a))
+    cfg = LMConfig.from_dict({**SHORT, **F32})
+    h, x = (jax.random.normal(jax.random.PRNGKey(i), (B, T, cfg.d_model)) for i in (5, 9))
+    layer = moe.ExpertLayer(cfg)
+    params = layer.init(jax.random.PRNGKey(6), h, x)["params"]
+    probe = jax.random.normal(jax.random.PRNGKey(7), (B, T, cfg.d_model))
+
+    def run(params, h, x):
+        y, counts = layer.apply({"params": params}, h, x)
+        return jnp.sum(y * probe), (y, counts)
+
+    results = {}
+    for passes in (1, 3):
+        monkeypatch.setattr(moe, "NARROW_PASS_TOKENS", B * T // passes), monkeypatch.setattr(moe, "WIDE_PASS_TOKENS", B * T // passes)
+        del placed[:]
+        (_, (y, counts)), grads = jax.value_and_grad(run, argnums=(0, 1, 2), has_aux=True)(params, h, x)
+        assert set(placed) == {B * T // passes}  # the tokens of every grouped call traced
+        results[passes] = (y, counts, grads)
+    (y, counts, grads), (y3, counts3, grads3) = results[1], results[3]
+    assert int(counts.sum()) > B * T // 4 and float(jnp.abs(y).max()) > 0.1
+    np.testing.assert_array_equal(counts, counts3)
+    np.testing.assert_allclose(y, y3, atol=2e-6, rtol=1e-5)
+    assert set(grads[0]) == {"router", "experts_gate", "experts_up", "experts_down"}
+    for (path, g), g3 in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(grads3)):
+        assert float(jnp.abs(g).max()) > 1e-3, jax.tree_util.keystr(path)  # the block's input reaches the loss through the router alone
+        np.testing.assert_allclose(g, g3, atol=2e-5, rtol=1e-4, err_msg=jax.tree_util.keystr(path))
 
 
 @pytest.mark.parametrize("path", ["small call", "large call, the buffer from the shapes", "large call, in token chunks"])
@@ -409,16 +456,20 @@ def test_what_names_the_bias_buffer_takes_its_absence():
 
 
 def test_counters_by_hand():
-    # a train step of the cell: 12,288 tokens, 6 of 64 a token, 16 held, three passes of 4,096: 384 rows a call at an even router
-    assert moe.token_chunks(12288) == 3
-    assert moe.rows_per_held_expert(16 / 64, 12288, 6, 16) == pytest.approx(384.0)
-    assert moe.rows_per_held_expert(16 / 64, 4096, 6, 16) == pytest.approx(384.0)  # one pass, a third of the tokens
+    # a train step of the cell: 12,288 tokens, 6 of 64 a token, 16 held, ONE pass where the buffer is wide (PR 48; three of 4,096
+    # before): 1,152 rows a call at an even router
+    assert moe.pass_tokens(12288, 6, 16, 64) == 12288
+    assert moe.rows_per_held_expert(16 / 64, 12288, 6, 16, 64) == pytest.approx(1152.0)
+    assert moe.rows_per_held_expert(16 / 64, 4096, 6, 16, 64) == pytest.approx(384.0)  # a third of the tokens
     # twice the even share of 6,144 slots, whole tiles: three rows a token (SLOTS_PER_TOKEN; at two the chip passed the buffer, PR 44)
     assert moe.slot_capacity(4096, 6, 16, 64) == 12288 == moe.SLOTS_PER_TOKEN * 4096
+    assert moe.slot_capacity(12288, 6, 16, 64) == 36864 == moe.SLOTS_PER_TOKEN * 12288  # the train batch's one buffer: the three pooled
     # 2,048 rows of buffer a choice: the rows are summed back by a gather of a token's own six, in a pass and in a train batch alike (PR 45)
     assert moe.sums_by_gather(12288, 6) and moe.sum_rows_per_token(4096, 6, 16, 64) == moe.sum_rows_per_token(12288, 6, 16, 64) == 6
-    assert not moe.sums_by_gather(4096, 8) and moe.sum_rows_per_token(65536, 6, 16, 64) == 6  # the prefill of 16 prompts: 16 passes
-    even = jnp.full((8, 16), 3 * 384, jnp.int32)  # a train step's counts at an even router: three passes of 384 rows an expert
+    # the prefill of 16 prompts: 4 passes of 16,384 (16 of 4,096 before); the scoring pass over 16 rollouts of 6,144: 6 (24)
+    assert not moe.sums_by_gather(4096, 8) and moe.sum_rows_per_token(65536, 6, 16, 64) == 6
+    assert moe.pass_tokens(65536, 6, 16, 64) == moe.pass_tokens(98304, 6, 16, 64) == 16384 == moe.WIDE_PASS_TOKENS
+    even = jnp.full((8, 16), 1152, jnp.int32)  # a train step's counts at an even router: 1,152 rows an expert of a buffer of 36,864
     assert float(moe.first_buffer_share(even, 12288, 6, 64)) == 1.0 and float(moe.first_buffer_share(even * 2 + 1, 12288, 6, 64)) == 0.0 and float(moe.first_buffer_share(even * 3 // 2, 12288, 6, 64)) == 1.0
     # the cache of the cell's rollout: six rings of 4,096 and two spans of 6,144, K and V, 4 heads of 128, bf16
     big = LMConfig.from_dict({**json.load(open(os.path.join(CONFIGS, "smallthinker-21b-ep4.json")))["model_arch"], "dtype": "bfloat16"})
@@ -499,6 +550,7 @@ def test_ppo_two_iterations_on_the_normal_path(tmp_path):
         # 8 rows of 28 tokens, 3 a token, 4 held, one pass
         assert r["moe/rows_per_held_expert"] == pytest.approx(r["moe/held_slot_share"] * 8 * 28 * 3 / 4)
         assert r["moe/sum_rows_per_token"] == 4  # 672 token-slots: a small call, one result a held expert
+        assert r["moe/passes"] == 1
     phases = [r for r in records if "time/window_wall_s" in r]
     itemsize = cfg.compute_dtype.itemsize
     assert phases and all(p["rollout/cache_bytes"] == 8 * (3 * 8 + 28) * 2 * 2 * 16 * itemsize for p in phases)

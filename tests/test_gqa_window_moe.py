@@ -186,7 +186,7 @@ def test_the_expert_shares_add_up_to_the_uncut_layer(monkeypatch, path):
     if isinstance(LARGE_CALL_PATHS[path], int):
         monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: LARGE_CALL_PATHS[path])
     if LARGE_CALL_PATHS[path] == "chunks":
-        monkeypatch.setattr(moe, "TOKEN_CHUNK", 12)  # 48 tokens: four passes
+        monkeypatch.setattr(moe, "NARROW_PASS_TOKENS", 12), monkeypatch.setattr(moe, "WIDE_PASS_TOKENS", 12)  # 48 tokens: four passes
     whole_cfg = LMConfig.from_dict({**ARCH, **F32, "experts_held": []})
     x = jax.random.normal(jax.random.PRNGKey(5), (B, T, whole_cfg.d_model))
     layer = moe.ExpertLayer(whole_cfg)

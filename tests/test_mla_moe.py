@@ -4,6 +4,9 @@
 float32, CPU. The letters are ISSUE 26's.
 """
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +18,8 @@ from trlx_tpu.models.heads import LMWithValueHead, extract_branch_params, traina
 from trlx_tpu.models.lm import LMConfig, TransformerLM, cache_bytes_per_token, init_cache
 from trlx_tpu.ops.generate import generate
 from trlx_tpu.ops.sampling import GenerateConfig
+
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs")
 
 YARN = {"type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
         "original_max_position_embeddings": 4096, "mscale": 1, "mscale_all_dim": 1}
@@ -153,7 +158,7 @@ def test_the_shares_add_up(monkeypatch, capacity):
         # 96 slots, some 24 of them held in a share: a buffer of 64 rows holds them, under the `cond`
         monkeypatch.setattr(moe, "slot_capacity", lambda n, k, held, n_experts: 64)
     elif capacity == "token chunks":
-        monkeypatch.setattr(moe, "TOKEN_CHUNK", 12)  # 48 tokens: four passes
+        monkeypatch.setattr(moe, "NARROW_PASS_TOKENS", 12), monkeypatch.setattr(moe, "WIDE_PASS_TOKENS", 12)  # 48 tokens: four passes
     elif capacity == "twice the even share":
         monkeypatch.setattr(moe, "ROW_TILE", 8)  # `slot_capacity`'s own rule: 2 x 24 slots a share = 48 rows of 96
     elif capacity == "overflow: dense path":
@@ -402,16 +407,18 @@ def test_the_tokens_side_of_the_placement_is_its_inverse_row_for_row(monkeypatch
 
 @pytest.mark.parametrize("cell, n, k, held, n_experts, capacity, gather", [
     ("smallthinker-ep4.ppo-4096x2048", 4096, 6, 16, 64, 12288, True),
+    ("zaya1-ep2.ppo-4096x2048", 4096, 1, 8, 16, 4096, True),
     ("kexaone-l5.ppo-128x896", 4096, 8, 8, 128, 4096, False),
     ("kimilinear-l13.ppo-128x896", 4096, 8, 8, 256, 2048, False),
     ("kimik2.5-l5.ppo-128x896", 4096, 8, 8, 384, 1536, False),
 ])
 def test_the_shapes_pick_the_form_of_the_sum_back(cell, n, k, held, n_experts, capacity, gather):
-    """The rule at the four expert cells' own call shapes (PERF.md section 6,
+    """The rule at the five expert cells' own call shapes (PERF.md section 6,
     PR 45: both forms' times on the chip): the gather where the buffer has
-    2,048 rows a choice, the product at 512, 256 and 192; and the counter
-    `moe/sum_rows_per_token` says which, for a pass and for a train batch of
-    three passes alike."""
+    2,048 and 4,096 rows a choice, the product at 512, 256 and 192; and the
+    counter `moe/sum_rows_per_token` says which, for 4,096 tokens and for a
+    train batch of three times as many alike (three passes under the product,
+    ONE under the gather: `pass_tokens`)."""
     assert moe.slot_capacity(n, k, held, n_experts) == capacity
     assert moe.sums_by_gather(capacity, min(k, held)) is gather
     assert moe.sum_rows_per_token(n, k, held, n_experts) == moe.sum_rows_per_token(3 * n, k, held, n_experts) == (min(k, held) if gather else capacity)
@@ -684,3 +691,77 @@ def test_ppo_two_iterations_on_the_normal_path(tmp_path):
     phases = [r for r in records if "time/window_wall_s" in r]
     assert phases and all(0.0 <= p["rollout/experts_touched"] <= 4.0 for p in phases)
     assert all(p["rollout/cache_bytes_per_token"] == (16 + 8) * 4 * 3 for p in phases)
+
+
+# ---- the length of a pass (PR 48) ----------------------------------------------------------------------
+
+EXPERT_CONFIGS = {  # configuration: (wide buffer, {tokens of a call the cell makes: its passes})
+    "smallthinker-21b-ep4": (True, {4096: 1, 12288: 1, 65536: 4, 98304: 6}),  # a row, the train batch, the prefill, the scoring pass
+    "zaya1-8b-ep2-l8": (True, {4096: 1, 12288: 1, 65536: 4, 98304: 6}),
+    "kimi-k2.5-ep48-l5": (False, {4096: 1, 8192: 2, 32768: 8}),
+    "k-exaone-236b-ep16-l5": (False, {4096: 1, 8192: 2, 32768: 8}),
+    "kimi-linear-48b-ep32-l13": (False, {4096: 1, 8192: 2, 32768: 8}),
+}
+
+
+def _expert_shapes(name):
+    arch = json.load(open(os.path.join(CONFIGS, name + ".json")))["model_arch"]
+    return arch["experts_per_token"], arch["experts_held"][1], arch["n_experts"]
+
+
+@pytest.mark.parametrize("name", EXPERT_CONFIGS)
+def test_pass_tokens_at_the_expert_configurations_own_calls(name):
+    """The length of a pass from the call's shapes, at the five expert
+    configurations' published counts: where the sum back is the gather (the
+    buffer is wide) a pass is the longest divisor not above 16,384 tokens, so
+    the 12,288-token train batch is ONE grouped call; where it is the 0/1
+    product the passes are the 4,096 tokens they were before PR 48, at every
+    length those cells call with. The counters follow the same rule."""
+    k, held, n_experts = _expert_shapes(name)
+    wide, calls = EXPERT_CONFIGS[name]
+    assert moe.sums_by_gather(moe.slot_capacity(moe.NARROW_PASS_TOKENS, k, held, n_experts), min(k, held)) is wide
+    for n, passes in calls.items():
+        tokens = moe.pass_tokens(n, k, held, n_experts)
+        assert (n // tokens, n % tokens) == (passes, 0) and tokens <= (moe.WIDE_PASS_TOKENS if wide else moe.NARROW_PASS_TOKENS)
+        share = held / n_experts  # an even router
+        assert moe.rows_per_held_expert(share, n, k, held, n_experts) == pytest.approx(tokens * k / n_experts)
+        capacity = moe.slot_capacity(tokens, k, held, n_experts)
+        assert moe.sum_rows_per_token(n, k, held, n_experts) == (min(k, held) if wide else capacity)
+        # a call's held slots are held to its passes' buffers together: twice the even share fits, one slot more does not
+        fits = jnp.full((1, held), passes * capacity // held, jnp.int32)
+        assert float(moe.first_buffer_share(fits, n, k, n_experts)) == 1.0
+        assert float(moe.first_buffer_share(fits.at[0, 0].add(passes * capacity % held + 1), n, k, n_experts)) == 0.0
+
+
+@pytest.mark.parametrize("name, n, want", [
+    ("smallthinker-21b-ep4", 4096 * 17, 512 * 17),  # eight passes of 8,704, not one of 69,632
+    ("smallthinker-21b-ep4", 20480, 10240),
+    ("smallthinker-21b-ep4", 16384 + 2, 8193),
+    ("smallthinker-21b-ep4", 16411, None),  # a prime: its longest divisor is 1
+    ("smallthinker-21b-ep4", 2 * 16411, None),  # 16,411 is over the cap, and a pass of 2 tokens is a small call
+    ("smallthinker-21b-ep4", 17 * 1021, 1021),  # seventeen passes: however many the longest divisor makes
+    ("kimi-k2.5-ep48-l5", 6000, 3000),
+    ("kimi-k2.5-ep48-l5", 4099, None),  # a prime just over a pass
+    ("kimi-k2.5-ep48-l5", 7 * 1001, 1001),  # the parent ran it in one pass of 7,007
+    ("kimi-k2.5-ep48-l5", 17 * 257, 257),  # 257 x 8 slots is a large call still
+    ("kimi-k2.5-ep48-l5", 17 * 251, None),  # 251 x 8 is a small call's
+    ("kimi-k2.5-ep48-l5", 3000, 3000),  # under a pass: itself, whatever its divisors
+    ("kimi-k2.5-ep48-l5", 1031, 1031),
+])
+def test_a_pass_is_a_divisor_or_the_call_is_refused(name, n, want):
+    """Never one pass of everything: a length over the cap goes in passes of
+    its longest divisor not above it, however many that makes, and one whose
+    longest is a small call's (`SMALL_CALL_SLOTS`; a prime's is 1) is refused
+    by name (before PR 48 a length 4,096 did not divide went through in ONE
+    pass, whatever its size)."""
+    shapes = _expert_shapes(name)
+    if want is None:
+        with pytest.raises(ValueError, match=f"{n} tokens has no divisor"):
+            moe.pass_tokens(n, *shapes)
+        x = jax.ShapeDtypeStruct((n, 8), jnp.float32)
+        stacks = [jax.ShapeDtypeStruct(s, jnp.float32) for s in ((shapes[1], 8, 4), (shapes[1], 8, 4), (shapes[1], 4, 8))]
+        routed = jax.ShapeDtypeStruct((n, shapes[0]), jnp.int32), jax.ShapeDtypeStruct((n, shapes[0]), jnp.float32)
+        with pytest.raises(ValueError, match="no divisor"):  # and the layer's own call refuses with it
+            jax.eval_shape(lambda x, ids, w, *s: moe.held_experts_ffn(x, ids, w, 0, shapes[2], *s, jax.nn.relu), x, *routed, *stacks)
+    else:
+        assert moe.pass_tokens(n, *shapes) == want

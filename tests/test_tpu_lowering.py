@@ -309,17 +309,13 @@ def test_both_forms_of_the_kda_mixer_compile_for_v5e(v5e_sharding):
     assert state.dtype == jnp.float32 and state.shape == (32, 32, 128, 128) and conv.shape == (32, 3, 12288)
 
 
-def test_a_cca_layer_s_three_passes_compile_for_v5e(v5e_sharding):
-    """One layer of zaya1-ep2.ppo-4096x2048 at its widths, bf16, under remat:
-    the train pass (forward and backward over the train batch [2, 6144], the
-    attention core through the flash kernels, the MLP router with its carried
-    state, 8 held experts one a token), the prefill of 16 x 4,096 into the
-    four-leaf cache, and the decode step that advances the window and the
-    shifted value through the ranged read."""
+def _zaya_layer(v5e_sharding):
+    """One layer of zaya1-ep2.ppo-4096x2048 at its widths, bf16, under remat,
+    on a described v5e: (cfg, model, abstract params, the abstract-array maker,
+    the train pass over [2, 6144])."""
     import json
     import os
 
-    from trlx_tpu.models import cca
     from trlx_tpu.models.lm import LMConfig, TransformerLM
 
     spec = json.load(open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "zaya1-8b-ep2-l8.json")))
@@ -331,11 +327,41 @@ def test_a_cca_layer_s_three_passes_compile_for_v5e(v5e_sharding):
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids, jnp.ones_like(ids))["params"])
     params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), params)
 
-    def train(p, ids, mask):
+    def train_step(p, ids, mask):
         loss = lambda p: model.apply({"params": p}, ids, mask, compute_logits=False)["hidden"].astype(jnp.float32).sum()
         return jax.value_and_grad(loss)(p)
 
-    text = jax.jit(train).lower(params, s((2, 6144), jnp.int32), s((2, 6144), jnp.int32)).compile().as_text()
+    return cfg, model, params, s, jax.jit(train_step).lower(params, s((2, 6144), jnp.int32), s((2, 6144), jnp.int32))
+
+
+def test_a_one_pass_train_step_keeps_its_grouped_products_under_moe_experts(v5e_sharding):
+    """ZAYA's train batch of 12,288 tokens goes through the expert layer in
+    ONE pass, one expert a token: no `while` and no `cond` contains the nine
+    grouped products, XLA names them `ragged-dot-none` with no name stack,
+    and `device_scopes.scope_table` has to find them their scope from what
+    they read (`moe_experts_ms_per_step` and `scope_attributed_pct` read it)."""
+    from trlx_tpu.observability import device_scopes
+
+    text = _zaya_layer(v5e_sharding)[-1].compile().as_text()
+    ops = device_scopes.scope_table(text)["ops"]
+    products = {name: entry for name, entry in ops.items() if name.startswith("ragged-dot")}
+    assert sum(name.startswith("ragged-dot-none") for name in products) == 9  # forward, lhs- and rhs-gradient of gate, up, down
+    assert all(chain.split("/")[0] == "moe_experts" for chain, _ in products.values()), products
+    entry = text[text.index("\nENTRY "):]
+    assert all(f"%{name} = " in entry for name in products)  # none inside a loop or a branch: the rule under test found them
+
+
+def test_a_cca_layer_s_three_passes_compile_for_v5e(v5e_sharding):
+    """One layer of zaya1-ep2.ppo-4096x2048 at its widths, bf16, under remat:
+    the train pass (forward and backward over the train batch [2, 6144], the
+    attention core through the flash kernels, the MLP router with its carried
+    state, 8 held experts one a token), the prefill of 16 x 4,096 into the
+    four-leaf cache, and the decode step that advances the window and the
+    shifted value through the ranged read."""
+    from trlx_tpu.models import cca
+
+    cfg, model, params, s, train = _zaya_layer(v5e_sharding)
+    text = train.compile().as_text()
     assert "flash_fwd" in text and "flash_bwd_dkv" in text
     cache = (tuple(s(shape, dtype) for shape, dtype in cca.cache_shapes(cfg, 16, 6144)),)
     prefill = lambda p, ids, mask, cache, cache_mask: model.apply(

@@ -42,9 +42,21 @@ become: 1% of an iteration between seeds), and in the deployment this layer
 is a share of, a chip's experts take the tokens of all the chips that share
 the layer and none goes untouched.
 
-A large call goes through in chunks of at most `TOKEN_CHUNK` tokens. A chunk's
-(token, choice) slots that chose a held expert are placed in a buffer, one
-group an expert, and go through three grouped products (`jax.lax.ragged_dot`).
+A large call goes through in passes whose length follows the call's shapes
+(`pass_tokens`): the longest divisor of its tokens not above `WIDE_PASS_TOKENS`
+where the slot buffer is wide (the sum back onto the tokens is the gather,
+below), not above `NARROW_PASS_TOKENS` where it is narrow. Every pass reads
+every held expert's weights again and ends every group in a partial row tile
+that the grouped product pays whole (its time follows the tiles its groups
+touch, not its rows), and passes in a loop sum the weight gradients in the
+loop's carry: a train batch goes in ONE pass where it can. Where the sum back
+is the 0/1 product its work grows with the SQUARE of a pass (2 x buffer rows
+FLOPs a token a column), which eats what longer grouped products give back:
+those calls keep 4,096 tokens a pass. The longer cap bounds the buffers of a
+scoring pass over a whole rollout chunk (the readings: PERF.md section 6,
+PR 48; `bench_moe.py`). A pass's (token, choice) slots that chose a held
+expert are placed in a buffer, one group an expert, and go through three
+grouped products (`jax.lax.ragged_dot`).
 Where a slot goes is counted, not sorted (`place_slots`): a token chooses an
 expert at most once, so a slot's row is its expert's offset plus the tokens
 before its own that chose the same expert, which is the row a stable sort by
@@ -96,7 +108,8 @@ SMALL_CALL_SLOTS = 2048  # token-slots (tokens x experts_per_token) up to which 
 SLOTS_PER_TOKEN = 3  # the most rows a token the slot buffer of a large call takes
 ROW_TILE = 512  # a slot buffer is whole row tiles of the grouped product
 GATHER_ROWS_PER_CHOICE = 640  # rows of slot buffer a choice of a token from which the sum back onto the tokens is a gather: on the chip the two forms cross at 510-610
-TOKEN_CHUNK = 4096  # tokens a pass: bounds the buffers of a scoring pass over a whole rollout chunk
+NARROW_PASS_TOKENS = 4096  # the most tokens a pass where the sum back onto the tokens is the 0/1 product, whose work grows with the square of a pass
+WIDE_PASS_TOKENS = 16384  # and where it is the gather: bounds the buffers of a scoring pass over a whole rollout chunk
 
 
 def choose(logits, bias, k: int, scaling: float, scoring: str):
@@ -180,9 +193,27 @@ def slot_capacity(n_tokens: int, k: int, held: int, n_experts: int) -> int:
     return min(-(-even_twice // ROW_TILE) * ROW_TILE, SLOTS_PER_TOKEN * n_tokens, n_tokens * min(k, held))
 
 
-def token_chunks(n_tokens: int) -> int:
-    """The passes of at most `TOKEN_CHUNK` tokens a call of `n_tokens` goes through in."""
-    return n_tokens // TOKEN_CHUNK if n_tokens > TOKEN_CHUNK and n_tokens % TOKEN_CHUNK == 0 else 1
+def pass_tokens(n_tokens: int, k: int, held: int, n_experts: int) -> int:
+    """Tokens of ONE pass of a large call over `n_tokens` tokens, from shapes
+    only: the longest divisor of `n_tokens` not above `WIDE_PASS_TOKENS` where
+    the slot buffer is wide (what `sums_by_gather` says of a
+    `NARROW_PASS_TOKENS` pass's buffer: the sum back is the gather, whose
+    traffic does not grow with a pass), not above `NARROW_PASS_TOKENS` where
+    the sum is the 0/1 product. A divisor, so that every pass is the same
+    program, however many passes that makes; a length whose longest one is a
+    small call's (a prime's is 1) is refused: one pass of everything has no
+    bound, and a small call runs every held expert over every token."""
+    wide = sums_by_gather(slot_capacity(NARROW_PASS_TOKENS, k, held, n_experts), min(k, held))
+    cap = WIDE_PASS_TOKENS if wide else NARROW_PASS_TOKENS
+    if n_tokens <= cap:
+        return n_tokens
+    passes = next(p for p in range(-(-n_tokens // cap), n_tokens + 1) if n_tokens % p == 0)
+    if n_tokens // passes * k <= SMALL_CALL_SLOTS:
+        raise ValueError(
+            f"an expert layer's call of {n_tokens} tokens has no divisor between {SMALL_CALL_SLOTS // k + 1} and {cap} tokens "
+            f"to take as a pass ({k} of {n_experts} experts a token, {held} held): give the batch x length a factor in that range"
+        )
+    return n_tokens // passes
 
 
 def held_counts(ids, first: int, held: int):
@@ -346,8 +377,9 @@ def held_experts_ffn(x, ids, weights, first: int, n_experts: int, gate, up, down
     n, k, held = x.shape[0], ids.shape[-1], gate.shape[0]
     if n * k <= SMALL_CALL_SLOTS:
         return experts_over_tokens(x, ids, weights, first, gate, up, down, act), held_counts(ids, first, held)
-    if token_chunks(n) > 1:
-        split = lambda a: a.reshape((n // TOKEN_CHUNK, TOKEN_CHUNK) + a.shape[1:])
+    tokens = pass_tokens(n, k, held, n_experts)
+    if tokens < n:
+        split = lambda a: a.reshape((n // tokens, tokens) + a.shape[1:])
         y, counts = jax.lax.map(lambda args: held_experts_ffn(*args, first, n_experts, gate, up, down, act),
                                 (split(x), split(ids), split(weights)))
         return y.reshape(x.shape), jnp.sum(counts, axis=0)
@@ -453,25 +485,25 @@ def expert_load_stats(counts, n_tokens: int, k: int) -> Tuple[jnp.ndarray, jnp.n
     return share, jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9)
 
 
-def rows_per_held_expert(held_slot_share: float, n_tokens: int, k: int, held: int) -> float:
+def rows_per_held_expert(held_slot_share: float, n_tokens: int, k: int, held: int, n_experts: int) -> float:
     """Mean rows a held expert takes in ONE grouped call of a pass over
-    `n_tokens` tokens (a call a `TOKEN_CHUNK` pass), from the pass's own
+    `n_tokens` tokens (a call a `pass_tokens` pass), from the pass's own
     `moe/held_slot_share`: the counter `moe/rows_per_held_expert` of a step
     record. What the grouped products' arithmetic stands against the read of
     an expert's weights with."""
-    return held_slot_share * n_tokens * k / held / token_chunks(n_tokens)
+    return held_slot_share * pass_tokens(n_tokens, k, held, n_experts) * k / held
 
 
 def sum_rows_per_token(n_tokens: int, k: int, held: int, n_experts: int) -> int:
     """Rows the sum back onto the tokens reads for ONE token in a pass over
     `n_tokens` tokens, from shapes only: the counter `moe/sum_rows_per_token`
     of a step record, which says what form of `put_rows` ran. The whole slot
-    buffer of a `TOKEN_CHUNK` pass under the 0/1 product, a row a choice under
+    buffer of a `pass_tokens` pass under the 0/1 product, a row a choice under
     the gather (`sums_by_gather`); a small call has no buffer and sums one
     result a held expert."""
     if n_tokens * k <= SMALL_CALL_SLOTS:
         return held
-    capacity, choices = slot_capacity(n_tokens // token_chunks(n_tokens), k, held, n_experts), min(k, held)
+    capacity, choices = slot_capacity(pass_tokens(n_tokens, k, held, n_experts), k, held, n_experts), min(k, held)
     return choices if sums_by_gather(capacity, choices) else capacity
 
 
@@ -480,8 +512,11 @@ def first_buffer_share(counts, n_tokens: int, k: int, n_experts: int):
     each layer one call of `n_tokens` tokens) whose held slots fit the slot
     buffer of `held_experts_ffn`: 1.0 says the buffer sized from the shapes
     served every call. A small call has no buffer to overflow and counts as
-    served; a call in token chunks is held to its chunks' buffers together."""
-    chunks, held = token_chunks(n_tokens), counts.shape[-1]
-    small = n_tokens * k <= SMALL_CALL_SLOTS
-    rows = n_tokens * min(k, held) if small else chunks * slot_capacity(n_tokens // chunks, k, held, n_experts)
+    served; a call in several passes is held to its passes' buffers together."""
+    held = counts.shape[-1]
+    if n_tokens * k <= SMALL_CALL_SLOTS:
+        rows = n_tokens * min(k, held)
+    else:
+        tokens = pass_tokens(n_tokens, k, held, n_experts)
+        rows = n_tokens // tokens * slot_capacity(tokens, k, held, n_experts)
     return jnp.mean((jnp.sum(counts, axis=-1) <= rows).astype(jnp.float32))

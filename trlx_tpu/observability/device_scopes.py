@@ -144,10 +144,20 @@ def scope_table(hlo_text):
     no scope (the compiler re-creates some with a bare ``op_name``, as the
     grouped products' ``ragged-dot-none`` kernels, or with none, as the waits
     for a prefetched weight inside a loop) takes the caller's, at any depth.
-    An instruction of the entry computation without ``op_name`` (a copy the
-    compiler added) has no entry: a reader counts it as unattributed."""
+    One that no container covers and whose ``op_name`` holds no name stack at
+    all (the grouped products of a call that goes in ONE pass sit in the entry
+    computation; so does a relayout copy of a weight, named after its
+    parameter) takes the scope of what it reads, else of what reads it: the
+    nearest operand with a scope, looking through instructions without
+    metadata (a tuple's element, a copy's two halves, a bitcast), then the
+    nearest user the same way. Operands first: a weight gradient's one user
+    is the optimizer, its operands are the expert layer's own. It keeps its
+    own pass. An instruction of the entry computation without ``op_name``
+    (a copy the compiler added) has no entry: a reader counts it as
+    unattributed."""
     module, ops, memo, fused, computation = "", {}, {}, False, ""
     inside, caller, bare = {}, {}, []  # instruction -> its computation; computation -> who calls it; no scope of their own
+    reads, read_by, stackless = {}, {}, []  # instruction -> its operands; -> its users; op_name without a name stack
     for line in hlo_text.splitlines():
         if line.startswith("HloModule "):
             module = line[len("HloModule "):].split(",", 1)[0].strip()
@@ -164,9 +174,15 @@ def scope_table(hlo_text):
             for called in (one,) if one else several.replace("%", "").split(", "):
                 caller.setdefault(called, name)
         inside[name] = computation
+        reads[name] = operands = _operands(line, eq + 3)
+        for operand in operands:
+            read_by.setdefault(operand, []).append(name)
         at = line.find('op_name="', eq)
         if at >= 0:
-            ops[name] = _scope_path(line[at + 9: line.find('"', at + 9)], memo)
+            op_name = line[at + 9: line.find('"', at + 9)]
+            ops[name] = _scope_path(op_name, memo)
+            if "/" not in op_name and operands:  # not a parameter, which is named after its argument
+                stackless.append(name)
         if at < 0 or not ops[name][0]:
             bare.append(name)
     for name in bare:
@@ -175,7 +191,47 @@ def scope_table(hlo_text):
             above = caller.get(inside[above])
         if above is not None:
             ops[name] = [ops[above][0], ops[name][1] if name in ops else ops[above][1]]
+    for _ in range(2):  # the second round for one between two of its kind: the grouped products' metadata call
+        for name, chain in [(name, _nearest_scope(name, reads, ops) or _nearest_scope(name, read_by, ops))
+                            for name in stackless if not ops[name][0]]:  # a round's are all found before any is written
+            if chain:
+                ops[name] = [chain, ops[name][1]]
     return {"module": module, "ops": ops}
+
+
+_OPCODE = re.compile(r" [a-z][\w\-]*\(")
+_OPERAND = re.compile(r"%?([A-Za-z_][\w.\-]*)\s*(?:,|$)")
+
+
+def _operands(line, start):
+    """Names of an instruction's operands, in order: what stands between the
+    parentheses after the opcode, each operand's last word (a long-form text
+    puts its shape before it), with or without ``%``."""
+    opcode = _OPCODE.search(line, start)
+    if opcode is None:
+        return ()
+    depth, first = 0, opcode.end()
+    for paren in re.finditer(r"[()]", line[first - 1:]):
+        depth += 1 if paren.group() == "(" else -1
+        if not depth:
+            inner = re.sub(r"/\*.*?\*/", "", line[first: first - 1 + paren.start()])
+            return tuple(_OPERAND.findall(inner))
+    return ()
+
+
+def _nearest_scope(name, edges, ops, reach=4):
+    """The scope chain of the nearest instruction along `edges` that has one,
+    breadth first and in the text's order, looking through instructions with
+    no entry (no metadata), at most `reach` of them deep."""
+    layer, seen = [name], {name}
+    for _ in range(reach):
+        layer = list(dict.fromkeys(n for of in layer for n in edges.get(of, ()) if n not in seen))
+        seen.update(layer)
+        for n in layer:
+            if n in ops and ops[n][0]:
+                return ops[n][0]
+        layer = [n for n in layer if n not in ops]
+    return ""
 
 
 # ---------------------------------------------------------------- the capture

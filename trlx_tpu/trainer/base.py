@@ -1330,12 +1330,13 @@ class JaxBaseTrainer(BaseRLTrainer):
             # (models/lm.py flash_pad_dead_chunk_share); 0.0 by the rule elsewhere
             stats_host.setdefault("flash/pad_dead_chunk_share", 0.0)
         if "moe/held_slot_share" in stats_host:
-            from trlx_tpu.models.moe import rows_per_held_expert, sum_rows_per_token
+            from trlx_tpu.models.moe import pass_tokens, rows_per_held_expert, sum_rows_per_token
 
             cfg, tokens = self.model.cfg, self.config.train.batch_size * self.config.train.seq_length
-            stats_host["moe/rows_per_held_expert"] = rows_per_held_expert(
-                stats_host["moe/held_slot_share"], tokens, cfg.experts_per_token, cfg.held_experts[1])
-            stats_host["moe/sum_rows_per_token"] = sum_rows_per_token(tokens, cfg.experts_per_token, cfg.held_experts[1], cfg.n_experts)
+            shapes = (tokens, cfg.experts_per_token, cfg.held_experts[1], cfg.n_experts)
+            stats_host["moe/passes"] = tokens // pass_tokens(*shapes)
+            stats_host["moe/rows_per_held_expert"] = rows_per_held_expert(stats_host["moe/held_slot_share"], *shapes)
+            stats_host["moe/sum_rows_per_token"] = sum_rows_per_token(*shapes)
         gather_share = weight_gather_share(self._weight_gathers["train"])
         if gather_share is not None:
             stats_host["parallel/weight_gather_share"] = gather_share
