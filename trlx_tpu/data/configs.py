@@ -238,8 +238,11 @@ class TrainConfig:
     # time (or a watchdog/guard event) writes a one-shot incident bundle —
     # thread stacks, device-memory snapshot, metrics tail, profiler trace —
     # under <checkpoint_dir>/incidents/<step>/. 0 disables the step-time
-    # detector (resilience-event capture still requires a factor > 0 to arm
-    # the capture machinery). TRLX_TPU_ANOMALY_FACTOR overrides.
+    # bundle (resilience-event capture still requires a factor > 0 to arm
+    # the capture machinery). TRLX_TPU_ANOMALY_FACTOR overrides. The detector
+    # itself runs in every run at 1.5x for stalls.jsonl (observability/
+    # anomaly.py STALL_FACTOR); this factor is the same median's second
+    # threshold.
     anomaly_factor: float = 0.0
     # Trailing window (observations) for the detector's rolling p50, and the
     # per-run cap on captured incident bundles.

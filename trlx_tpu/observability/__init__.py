@@ -2,7 +2,9 @@
 + graftfleet cross-host federation (PR 14)
 + graftnum streaming numerics observatory (PR 15).
 
-Eight parts, all off-hot-path and off by default:
+Eight parts, all off-hot-path and, but for the flight recorder for stalls
+(``spans``' ring and ``anomaly``'s detector, counters and ``stalls.jsonl``:
+on in every run), off by default:
 
 - ``spans``     — the one way host work is timed: ``with trace_span(name)``
                   is always a profiler annotation and feeds the ``time/*``
@@ -13,8 +15,11 @@ Eight parts, all off-hot-path and off by default:
                   ``memory_analysis``) for every jitted program, real-FLOPs
                   MFU gauges, kernel-routing + device-memory gauges
                   (``train.device_telemetry`` / ``TRLX_TPU_DEVICE_TELEMETRY=1``);
-- ``anomaly``   — rolling-median step-time detector + one-shot incident
-                  bundles under ``<ckpt_dir>/incidents/<step>/``
+- ``anomaly``   — the flight recorder (always on: a rolling-median detector
+                  over every step and rollout at 1.5x, process
+                  counters, the ticker, ``<ckpt_dir>/stalls.jsonl``) +
+                  one-shot incident bundles under
+                  ``<ckpt_dir>/incidents/<step>/`` on the same median
                   (``train.anomaly_factor`` / ``TRLX_TPU_ANOMALY_FACTOR``);
 - ``health``    — streaming RLHF health detectors (reward drift, KL
                   controller, entropy collapse, value EV, rollout sentinels)

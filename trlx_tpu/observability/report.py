@@ -6,7 +6,8 @@
 Merges everything the observability layer wrote during a run into one
 readable document: per-window phase breakdown, MFU trend from compiled-cost
 FLOPs, staleness distribution, kernel-routing table, span-lane accounting
-(with the measured producer/train overlap), and the incident index.
+(with the measured producer/train overlap), the stalls the flight recorder
+caught (stalls.jsonl) and the incident index.
 ``--trace-out`` additionally emits a ``{"traceEvents": [...]}`` wrapper of
 spans.jsonl for chrome://tracing (Perfetto loads the raw JSONL directly).
 
@@ -178,6 +179,52 @@ def _overlap_seconds(spans, lane_a_substr: str, lane_b_substr: str):
 
 
 # ----------------------------------------------------------------- fleet
+
+
+def _stalled_in(record):
+    """Where the stalled thread was: of the spans on the lane of the record's
+    own span (`train/step`, `rollout/generate`), the one with the most
+    self-seconds (its duration less its children's) inside the record."""
+    events = [e for e in record.get("spans", []) if e.get("ph") == "X"]
+    own = "train/step" if record.get("kind") == "train_step" else "rollout/generate"
+    lanes = {e["tid"] for e in events if e["name"] == own}
+    events = [e for e in events if e["tid"] in lanes]
+    self_us = {e["args"]["id"]: e["dur"] for e in events}
+    for e in events:
+        if e["args"]["parent"] in self_us:
+            self_us[e["args"]["parent"]] -= e["dur"]
+    if not self_us:
+        return "?"
+    span_id = max(self_us, key=self_us.get)
+    name = next(e["name"] for e in events if e["args"]["id"] == span_id)
+    return f"{name} ({_fmt(self_us[span_id] * 1e-6, 3)}s self)"
+
+
+def _stalls_section(checkpoint_dir):
+    """Stalls: one row a line of stalls.jsonl (observability/anomaly.py). How
+    to read a row is RUNBOOK section 8's."""
+    records = _load_jsonl(os.path.join(checkpoint_dir, "stalls.jsonl"))
+    lines = ["## Stalls", ""]
+    if not records:
+        return lines + ["None: no logged step or rollout passed 1.5x its rolling median.", ""]
+    lines.append("| kind | step | seconds (p50) | side | wait excess s | largest tick gap s | involuntary switches | stalled in |")
+    lines.append("|---|---|---|---|---|---|---|---|")
+    for r in records:
+        excess, wait = r.get("excess_s", 0.0), r.get("wait_excess_s", 0.0)
+        side = "wait" if excess > 0 and wait >= 0.5 * excess else "host"
+        lines.append(
+            f"| {r.get('kind', '?')} | {r.get('step', '?')} | {_fmt(r.get('seconds'), 3)} ({_fmt(r.get('p50'), 3)}) "
+            f"| {side} | {_fmt(wait, 3)} | {_fmt(r.get('proc/tick_gap_max_s'), 3)} | {_fmt(r.get('proc/nivcsw'), 0)} "
+            f"| {_stalled_in(r)} |"
+        )
+    lines += [
+        "",
+        "A tick gap near the excess: the host (or the interpreter) stopped. Side `wait`, no gap, a quiet process: the device",
+        "or its runtime. Side `host` with involuntary switches up: the process was kept off the CPU. Each line of",
+        "`stalls.jsonl` holds every counter and the spans of the interval (Chrome trace events: Perfetto opens them).",
+        "",
+    ]
+    return lines
 
 
 def _fleet_section(checkpoint_dir):
@@ -634,6 +681,9 @@ def build_report(checkpoint_dir: str) -> str:
     else:
         lines.append("No health records (train.health_monitor off — set it or TRLX_TPU_HEALTH=1).")
     lines.append("")
+
+    # --- stalls -----------------------------------------------------------
+    lines += _stalls_section(checkpoint_dir)
 
     # --- incidents --------------------------------------------------------
     lines += ["## Incidents", ""]

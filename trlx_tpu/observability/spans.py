@@ -17,18 +17,24 @@ not,
   thread's total. ``drain()`` hands both to the loop, which writes them into
   the phase-window record it already writes (``time/generate_s``,
   ``time/boundary_s``, ``time/unspanned_s`` ...). Worker threads accumulate
-  under their own span names.
+  under their own span names;
+- leaves ONE record, the tuple ``(name, lane, t0_ns, t1_ns, id, parent id,
+  iter, args)``, in a ring of the last ``RING_SPANS`` records of every thread
+  (an instant leaves the same tuple with no end and no id). ``recent(t0_ns,
+  t1_ns)`` hands back, as Chrome trace events, what overlaps an interval:
+  the stall record's ``spans`` (observability/anomaly.py), in runs nobody
+  armed.
 
 **Only when armed** (``configure(path=...)``: ``train.trace_spans`` /
-``TRLX_TPU_SPANS=1``) spans are also kept in memory and land as Chrome trace
-events (``ph:"X"``) in ``<checkpoint_dir>/spans.jsonl``, with ``pid`` = the
-JAX process index, ``tid`` = a synthetic per-thread lane id, and
+``TRLX_TPU_SPANS=1``) the same records are also kept for the file and land as
+Chrome trace events (``ph:"X"``) in ``<checkpoint_dir>/spans.jsonl``, with
+``pid`` = the JAX process index, ``tid`` = the thread's lane, and
 ``args.id`` / ``args.parent`` / ``args.iter`` beside the site's own args, so
 Perfetto (https://ui.perfetto.dev opens JSONL event streams directly)
-renders one lane per thread per host. The file is written by ``flush()`` at
-iteration boundaries (PPO: the end of ``post_epoch_callback``; ILQL: the log
-boundary), at an incident and at ``shutdown()``: one ``write(2)`` per batch of
-lines, never from inside a span.
+renders one lane per thread per host. The events are built and the file is
+written by ``flush()`` at iteration boundaries (PPO: the end of
+``post_epoch_callback``; ILQL: the log boundary), at an incident and at
+``shutdown()``: one ``write(2)`` per batch of lines, never from inside a span.
 
 File contracts, as for metrics.jsonl:
 
@@ -53,6 +59,7 @@ Event vocabulary (the Chrome trace-event format's subset we emit):
 
 import itertools
 import json
+from collections import deque
 import os
 import re
 import threading
@@ -74,7 +81,9 @@ __all__ = [
     "set_iteration",
     "iteration",
     "drain",
+    "recent",
     "install_compile_listener",
+    "compile_requests",
     "take_compiles",
     "read_spans",
     "read_fleet_spans",
@@ -82,6 +91,7 @@ __all__ = [
     "SPANS_FILENAME",
     "FLEET_CLOCK_FILENAME",
     "TID_STRIDE",
+    "RING_SPANS",
 ]
 
 SPANS_FILENAME = "spans.jsonl"
@@ -94,6 +104,9 @@ FLEET_CLOCK_FILENAME = "fleet_clock.jsonl"
 # k * TID_STRIDE + t and overlapping tids across hosts can never collide
 # even if a file's pid tags are missing or wrong.
 TID_STRIDE = 1000
+# Records the ring keeps: some 400 train steps' spans, or the last rollout and
+# the steps around it; under 1 MB of tuples.
+RING_SPANS = 4096
 # The event jax.monitoring reports each backend compile request under (cache
 # retrievals included); benchmark/harness.py's CompileLog listens to the same.
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -109,74 +122,73 @@ def host_spans_filename(process_index: int) -> str:
     return f"spans.host{int(process_index)}.jsonl"
 
 
+def _lane() -> int:
+    """The calling thread's lane: a synthetic id per thread OBJECT, stored
+    thread-locally. Raw thread.ident would be simpler but the OS reuses
+    idents: a rollout producer starting after an epoch's prefetch thread
+    exits can inherit its ident, and the stale thread_name metadata would
+    then mislabel (and merge) the two lanes in the viewer."""
+    lane = getattr(_LOCAL, "lane", None)
+    if lane is None:
+        lane = _LOCAL.lane = next(_LANES)
+        # A lane is named before its first record enters the ring, so the
+        # newest RING_SPANS names cover every lane the ring can hold.
+        with _ACC_LOCK:  # once a thread: two threads' first spans may meet here
+            _LANE_NAMES[lane] = threading.current_thread().name
+            while len(_LANE_NAMES) > RING_SPANS:
+                del _LANE_NAMES[next(iter(_LANE_NAMES))]
+    return lane
+
+
+def _lane_event(lane: int, pid: int) -> dict:
+    return {"name": "thread_name", "ph": "M", "pid": pid, "tid": lane,
+            "args": {"name": _LANE_NAMES.get(lane, "?")}}
+
+
+def _event(record, pid: int) -> dict:
+    """The Chrome trace event of one record: a span's ``ph:"X"`` or, where the
+    record has no end, an instant's ``ph:"i"``."""
+    name, lane, t0, t1, span_id, parent, iteration, args = record
+    if t1 is None:
+        event = {"name": name, "ph": "i", "s": "t", "pid": pid, "tid": lane, "ts": t0 // 1000}  # thread-scoped
+        if args:
+            event["args"] = args
+        return event
+    return {
+        "name": name,
+        "ph": "X",
+        "pid": pid,
+        "tid": lane,
+        "ts": t0 // 1000,
+        "dur": max(0, (t1 - t0) // 1000),
+        "args": dict(args, id=span_id, parent=parent, iter=iteration),
+    }
+
+
 class SpanTracer:
-    """Keeps Chrome trace events in memory and appends them to one JSONL
-    file a batch at a time."""
+    """Keeps the records of an armed run and appends them, as Chrome trace
+    events, to one JSONL file a batch at a time."""
 
     def __init__(self, path: str, process_index: int = 0):
         self.path = path
         self.pid = int(process_index)
         self._file = jsonl.open_line_atomic(path)
-        self._events = []  # list.append is atomic under the GIL
-        # Synthetic per-thread-OBJECT lane ids, stored thread-locally. Raw
-        # thread.ident would be simpler but the OS reuses idents: a rollout
-        # producer starting after an epoch's prefetch thread exits can
-        # inherit its ident, and the stale thread_name metadata would then
-        # mislabel (and merge) the two lanes in the viewer.
-        self._local = threading.local()
-        self._next_tid = 0
-        self._name_lock = threading.Lock()
+        self._records = []  # list.append is atomic under the GIL
+        self._named = set()  # lanes whose thread_name record this file has
 
-    def _tid(self) -> int:
-        tid = getattr(self._local, "tid", None)
-        if tid is None:
-            with self._name_lock:
-                self._next_tid += 1
-                tid = self._local.tid = self._next_tid
-            self._events.append(
-                {
-                    "name": "thread_name",
-                    "ph": "M",
-                    "pid": self.pid,
-                    "tid": tid,
-                    "args": {"name": threading.current_thread().name},
-                }
-            )
-        return tid
-
-    def span(self, span, error):
-        args = dict(span.args, id=span.id, parent=span.parent.id if span.parent else None, iter=span.iter)
-        if error is not None:
-            args["error"] = error
-        self._events.append(
-            {
-                "name": span.name,
-                "ph": "X",
-                "pid": self.pid,
-                "tid": self._tid(),
-                "ts": span.t0 // 1000,
-                "dur": max(0, (span.t1 - span.t0) // 1000),
-                "args": args,
-            }
-        )
-
-    def instant(self, name: str, args: dict):
-        self._events.append(
-            {
-                "name": name,
-                "ph": "i",
-                "s": "t",  # thread-scoped instant
-                "pid": self.pid,
-                "tid": self._tid(),
-                "ts": time.time_ns() // 1000,
-                **({"args": args} if args else {}),
-            }
-        )
+    def record(self, record):
+        self._records.append(record)
 
     def flush(self):
-        events, self._events = self._events, []
-        if not events:
+        records, self._records = self._records, []
+        if not records:
             return
+        events = []
+        for record in records:
+            if record[1] not in self._named:  # one thread_name per lane, ahead of its first event
+                self._named.add(record[1])
+                events.append(_lane_event(record[1], self.pid))
+            events.append(_event(record, self.pid))
         try:
             # ONE write call per batch of whole lines -> line-atomic under O_APPEND.
             self._file.write("".join(json.dumps(e) + "\n" for e in events).encode("utf-8"))
@@ -196,11 +208,15 @@ class SpanTracer:
 # Process-global state, armed once by the trainer. Module globals (not trainer
 # attributes) because the emitting sites span orchestrators, pipeline
 # threads, and resilience guards that do not all hold a trainer reference.
-_STATE = {"tracer": None, "iter": 0, "compiles": 0, "listening": False}
+_STATE = {"tracer": None, "iter": 0, "compiles": 0, "compiles_taken": 0, "listening": False}
 # .stack: this thread's open spans, outermost first; .top_ns: nanoseconds it
-# spent inside top-level spans since it last drained
+# spent inside top-level spans since it last drained; .lane: its lane id
 _LOCAL = threading.local()
 _IDS = itertools.count(1)  # next() is atomic under the GIL
+_LANES = itertools.count(1)
+_LANE_NAMES = {}  # lane -> thread name, the newest RING_SPANS lanes
+_RING = deque(maxlen=RING_SPANS)  # the last records of every thread; append is atomic under the GIL
+_RING_APPEND = _RING.append
 _ACC_LOCK = threading.Lock()
 _SELF_S = {}  # span name -> self-seconds since the last drain(), every thread's
 
@@ -290,9 +306,18 @@ class _Span:
             _LOCAL.top_ns = getattr(_LOCAL, "top_ns", 0) + duration
         else:
             self.parent._children_ns += duration
+        # the record, inline: a call or two less on the path every span takes
+        try:
+            lane = _LOCAL.lane
+        except AttributeError:
+            lane = _lane()
+        parent = self.parent
+        record = (self.name, lane, self.t0, self.t1, self.id, None if parent is None else parent.id, self.iter,
+                  self.args if exc_type is None else dict(self.args, error=exc_type.__name__))
+        _RING_APPEND(record)
         tracer = _STATE["tracer"]
         if tracer is not None:
-            tracer.span(self, exc_type.__name__ if exc_type is not None else None)
+            tracer.record(record)
         return False
 
     @property
@@ -328,11 +353,24 @@ def drain() -> dict:
 
 
 def instant(name: str, **args):
-    """Emit a point event (watchdog fired, collective timed out, incident);
-    kept only when armed."""
+    """Emit a point event (watchdog fired, collective timed out, incident, a
+    compile, a late tick): into the ring always, into the file when armed."""
+    record = (name, _lane(), time.time_ns(), None, None, None, _STATE["iter"], args)
+    _RING_APPEND(record)
     tracer = _STATE["tracer"]
     if tracer is not None:
-        tracer.instant(name, args)
+        tracer.record(record)
+
+
+def recent(t0_ns: int, t1_ns: int, pid: int = 0) -> list:
+    """The ring's records that overlap ``[t0_ns, t1_ns]`` (a span by any part
+    of it, an instant by its stamp), every thread's, as the file's own Chrome
+    trace events, oldest first, each lane's ``thread_name`` ahead of them.
+    Spans still open are not in the ring: ask after the span of interest has
+    ended."""
+    records = [r for r in tuple(_RING) if r[2] <= t1_ns and (r[2] if r[3] is None else r[3]) >= t0_ns]
+    lanes = sorted({r[1] for r in records})
+    return [_lane_event(lane, pid) for lane in lanes] + [_event(r, pid) for r in records]
 
 
 def _on_duration(event, duration, **kw):
@@ -349,9 +387,16 @@ def install_compile_listener():
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
+def compile_requests() -> int:
+    """Compile requests the listener has seen, in all (a window's count is
+    the difference of two readings)."""
+    return _STATE["compiles"]
+
+
 def take_compiles() -> int:
     """Compile requests since the last call (the step record's ``obs/compiles``)."""
-    n, _STATE["compiles"] = _STATE["compiles"], 0
+    seen = _STATE["compiles"]
+    n, _STATE["compiles_taken"] = seen - _STATE["compiles_taken"], seen
     return n
 
 

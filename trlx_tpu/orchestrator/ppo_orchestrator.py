@@ -363,6 +363,11 @@ class PPOOrchestrator(Orchestrator):
             worker = ScoreWorker(host_score, depth=depth)
             inflight = deque()
 
+        # The rollout's window of process counters and tick gaps (observability/
+        # anomaly.py): first `rollout/generate` to last `rollout/push`.
+        proc = getattr(rl, "_rollout_proc", None)
+        if proc is not None:
+            proc.open()
         with trace_span("rollout/generate", step=iter_count, dispatch=True) as span:
             with trace_span("rollout/generate_dispatch"):
                 pending = self._generate_next_chunk(snapshot=snapshot)
@@ -489,6 +494,8 @@ class PPOOrchestrator(Orchestrator):
                 # Host decode+reward wall, measured on the worker. Joined, so
                 # the read is race-free.
                 reward_s += worker.busy_s
+            if proc is not None and not aborted:
+                rl._rollout_obs = proc.close()
             if timer is not None and not aborted:
                 timer.add("rollout", gen_s + score_s + push_s)
                 timer.add("score", reward_s)
@@ -777,6 +784,9 @@ class PPOOrchestrator(Orchestrator):
             inflight = deque()
 
         finished_buf = []
+        proc = getattr(rl, "_rollout_proc", None)  # as in make_experience
+        if proc is not None:
+            proc.open()
         aborted = False
         ok = False
         try:
@@ -852,6 +862,8 @@ class PPOOrchestrator(Orchestrator):
             if worker is not None:
                 worker.close()
                 reward_s += worker.busy_s
+            if proc is not None and ok:
+                rl._rollout_obs = proc.close()
             if timer is not None and not aborted:
                 timer.add("rollout", gen_s + score_s + push_s)
                 timer.add("score", reward_s)

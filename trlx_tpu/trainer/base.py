@@ -296,12 +296,20 @@ class JaxBaseTrainer(BaseRLTrainer):
         anomaly_factor = float(
             os.environ.get("TRLX_TPU_ANOMALY_FACTOR", "") or config.train.anomaly_factor
         )
-        self._anomaly = None
+        # The flight recorder (observability/anomaly.py), on in every run: one
+        # detector over the step records' step_time at STALL_FACTOR, with
+        # train.anomaly_factor as the same median's second threshold (the
+        # bundle's); the ticker and the step's window of process counters and
+        # tick gaps; stalls.jsonl, which appears with the first stall.
+        self._anomaly = obs.AnomalyDetector(
+            obs.anomaly.STALL_FACTOR, window=config.train.anomaly_window, bundle_factor=anomaly_factor
+        )
+        self._ticker = obs.anomaly.Ticker()
+        self._step_proc = obs.anomaly.ProcWindow(self._ticker, "step")
+        self._stall_log = obs.anomaly.StallLog(ckpt_dir, process_index=jax.process_index())
+        self._pending_stall = None  # (breach, (record, interval)) found at the log boundary, written after train/step
         self._incidents = None
         if anomaly_factor > 0:
-            self._anomaly = obs.AnomalyDetector(
-                anomaly_factor, window=config.train.anomaly_window
-            )
             self._incidents = self._build_incident_capture(ckpt_dir)
         # Training-health monitor (trlx_tpu/observability/health.py):
         # streaming drift/collapse/sentinel detectors over the stats this
@@ -945,7 +953,9 @@ class JaxBaseTrainer(BaseRLTrainer):
         # the first MFU window must span from HERE (covering every dispatch
         # whose FLOPs the monitor accumulated), not just the last step.
         self._telemetry_t0 = time.time()
-        self._host_t0, self._step_wait_s = None, 0.0
+        obs.anomaly.time_collections(True)
+        self._ticker.start()
+        self._open_step_window()
         obs_spans.set_iteration(0)
 
         def profiler_tick():
@@ -990,6 +1000,9 @@ class JaxBaseTrainer(BaseRLTrainer):
             self._close_batch_feed()
             self._shutdown_experience_pipeline()
             self.end_progress()
+            self._ticker.stop()
+            obs.anomaly.time_collections(False)
+            self._stall_log.close()
             if self._profiling:  # before the flush: a closed session's scope table is written by it
                 jax.profiler.stop_trace()
                 self._profiling = False
@@ -1146,10 +1159,17 @@ class JaxBaseTrainer(BaseRLTrainer):
                     timer.add("train", train_dt)
             self._close_batch_feed()
             self.post_epoch_callback()
-            self._host_t0 = None
+            self._open_step_window()
 
         self._save_at_end()
         return self.evaluate()
+
+    def _open_step_window(self):
+        """The next step is the first of an iteration (at learn()'s start, after
+        a rollout): its host window, and its window of process counters, start
+        where its batch is asked for."""
+        self._host_t0, self._step_wait_s = None, 0.0
+        self._step_proc.open()
 
     def _train_one_step(self, device_batch):
         """One jitted update on ``device_batch`` and what follows it on the
@@ -1240,6 +1260,7 @@ class JaxBaseTrainer(BaseRLTrainer):
                 self.save(block=not self.config.train.async_checkpointing)
             if intervals["do_log"] or intervals["do_eval"]:
                 self._log_step(stats, step_span, intervals)
+        self._write_pending_stall()
 
         # Independent of the log cadence (a nested check would
         # silently thin the histograms to lcm(log, watch)).
@@ -1301,9 +1322,8 @@ class JaxBaseTrainer(BaseRLTrainer):
                 self._flush_resilience()
                 jax.block_until_ready(stats)
             stats_host = {k: float(v) for k, v in stats.items()}
-        with trace_span("train/log") as log:
+        with trace_span("train/log"):
             self._write_step_record(stats_host, step_span, read, wait, intervals)
-        self._last_log_t = log.end_s
         if getattr(self, "_phase_timer", None) is None:
             obs_spans.flush()  # no rollout boundary (ILQL): the log boundary is the iteration's
 
@@ -1341,18 +1361,7 @@ class JaxBaseTrainer(BaseRLTrainer):
         if gather_share is not None:
             stats_host["parallel/weight_gather_share"] = gather_share
         stats_host.update(take_head_call_scalars(self._head_calls["train"], "train"))
-        if self._anomaly is not None and self._anomaly.observe(
-            stats_host["step_time"]
-        ):
-            self._incidents.capture(
-                self.iter_count,
-                "slow_step",
-                detail={
-                    "step_time": stats_host["step_time"],
-                    "p50": self._anomaly.p50(),
-                    "factor": self._anomaly.factor,
-                },
-            )
+        self._observe_step(stats_host, step_span, read, waited)
         if self._devicemon is not None and getattr(self, "_phase_timer", None) is None:
             # Trainers without a phase timer (ILQL) flush the
             # device telemetry here; PPO flushes at its
@@ -1373,13 +1382,6 @@ class JaxBaseTrainer(BaseRLTrainer):
         # the last log (phase attribution: the "data" phase).
         stats_host["data_time"] = getattr(self, "_data_s", 0.0)
         self._data_s = 0.0
-        # Wall since the previous log flushed: step_gap −
-        # step_time = loop overhead outside the jitted step
-        # (callbacks, intervals, logging, loader advance).
-        # _last_log_t is re-stamped AFTER eval+log below so
-        # eval wall never pollutes the next record's gap.
-        if getattr(self, "_last_log_t", None) is not None:
-            stats_host["step_gap"] = read.end_s - self._last_log_t
         if intervals["do_eval"]:
             stats_host.update(self.evaluate())
             # Eval wall must not count as train-phase time in
@@ -1448,6 +1450,55 @@ class JaxBaseTrainer(BaseRLTrainer):
             )
         self.tracker.log(stats_host, step=self.iter_count)
         self.progress_line(stats_host)
+
+    def _observe_step(self, stats_host, step_span, read, waited):
+        """The flight recorder's part of a step record: `stall/*` from the
+        detector, `proc/*` over the step's host window (the previous stats
+        read to this one), and, on a breach, the stall record that
+        `_write_pending_stall` writes once `train/step` has ended."""
+        proc = self._step_proc.close()
+        breach = self._anomaly.observe(stats_host["step_time"], waited)
+        stats_host["stall/excess_s"] = breach.excess_s if breach else 0.0
+        stats_host["stall/wait_excess_s"] = breach.wait_excess_s if breach else 0.0
+        stats_host["proc/nivcsw"] = proc["nivcsw"]
+        stats_host["proc/cpu_s"] = proc["cpu_s"]
+        stats_host["proc/tick_gap_max_s"] = proc["tick_gap_max_s"]
+        if not breach:
+            return
+        self._pending_stall = breach, obs.anomaly.stall_record(
+            "train_step",
+            breach,
+            proc,
+            step=self.iter_count,
+            iter=obs_spans.iteration(),
+            t0=step_span.start_s,
+            t1=read.end_s,
+            seconds=stats_host["step_time"],
+            wait_s=waited,
+            host_ms=stats_host["time/step_host_ms"],
+            compiles=stats_host["obs/compiles"],
+        )
+
+    def _write_pending_stall(self):
+        """After `train/step` has ended (its record is in the spans' ring by
+        then; PPO: after the boundary's spans, for a rollout): the line in
+        stalls.jsonl where the step passed STALL_FACTOR, the incident bundle
+        where it passed train.anomaly_factor."""
+        if self._pending_stall is None:
+            return
+        (breach, (record, window)), self._pending_stall = self._pending_stall, None
+        bundle = (
+            breach.bundle and self._incidents is not None and self._incidents.captured < self._incidents.max_incidents
+        )
+        if breach.excess_s > 0:
+            record = self._stall_log.write(record, window, keep=bundle) or record
+        if bundle:
+            self._incidents.capture(
+                record["step"],
+                "slow_step",
+                detail={"step_time": record["seconds"], "p50": breach.p50, "factor": self._anomaly.bundle_factor},
+                stall=record,
+            )
 
     # ------------------------------------------------------------ checkpoint
 

@@ -26,6 +26,7 @@ from trlx_tpu.ops.fused_logprob import count_head_calls, fused_logprob_eligible,
 from trlx_tpu.ops.generate import make_generate_fn
 from trlx_tpu.ops.modeling import logprobs_from_logits
 from trlx_tpu.ops.rl_losses import kl_penalty_rewards, ppo_loss
+from trlx_tpu.observability import anomaly as obs_anomaly
 from trlx_tpu.observability import numerics as obs_numerics
 from trlx_tpu.observability import spans as obs_spans
 from trlx_tpu.observability.spans import trace_span
@@ -139,6 +140,16 @@ class PPOTrainer(JaxBaseTrainer):
         self._phase_timer = PhaseTimer()
         self._rollout_producer = None
         self._last_exp_stats = None
+        # The flight recorder's rollout side (observability/anomaly.py): the
+        # orchestrator opens and closes the window of process counters and tick
+        # gaps at the rollout's edges (first `rollout/generate` to last
+        # `rollout/push`) and leaves its reading here; the detector holds
+        # each phase record's `time/generate_s` against STALL_FACTOR.
+        self._rollout_proc = obs_anomaly.ProcWindow(self._ticker, "rollout")
+        self._rollout_obs = None
+        self._rollout_anomaly = obs_anomaly.AnomalyDetector(
+            obs_anomaly.STALL_FACTOR, window=config.train.anomaly_window, min_samples=2
+        )
         # Fleet learner/colocated feed (built by _fleet_bootstrap) and the
         # degraded-exit latch (set when the feed raises FleetDegradedExit).
         self._fleet_feed = None
@@ -915,6 +926,7 @@ class PPOTrainer(JaxBaseTrainer):
             )
         with trace_span("boundary/phase_log"):
             self._log_phase_window()
+        self._write_pending_stall()  # a stalled rollout's line, outside every span of the boundary
         obs_spans.flush()
 
     def _prepare_batch(self, batch):
@@ -958,6 +970,7 @@ class PPOTrainer(JaxBaseTrainer):
         stats["time/push_s"] = self_s.get("rollout/push", 0.0)
         stats["time/boundary_s"] = sum(v for k, v in self_s.items() if k.startswith("boundary/"))
         stats["time/unspanned_s"] = max(0.0, stats["time/window_wall_s"] - acc["top_s"])
+        self._observe_rollout(stats, self_s.get("rollout/pull", 0.0))
         window_tokens, self._window_tokens = self._window_tokens, []
         window_fill, self._window_fill = self._window_fill, []
         train_s = stats.get("time/train_s", 0.0)
@@ -1010,6 +1023,39 @@ class PPOTrainer(JaxBaseTrainer):
         # second collective here (the exporter lives on process 0 only).
         if self._metrics_exporter is not None:
             self._metrics_exporter.update(stats, step=self.iter_count)
+
+    def _observe_rollout(self, stats, pull_s):
+        """The flight recorder's part of a phase record: `stall/rollout_excess_s`
+        and `proc/rollout_*` over the rollout this window holds, and on a
+        breach the line `_write_pending_stall` writes once the boundary's
+        spans have ended. The detector serves generation on the main thread
+        (the path every cell runs): a producer's, an engine's or a fleet's
+        rollout gets the counters and no verdict, and a rollout that compiled
+        neither seeds nor trips."""
+        proc, self._rollout_obs = self._rollout_obs, None
+        stats["stall/rollout_excess_s"] = 0.0
+        for key in ("nivcsw", "cpu_s", "tick_gap_max_s"):
+            stats[f"proc/rollout_{key}"] = proc[key] if proc else 0.0
+        on_main = self._rollout_producer is None and self._fleet_feed is None and not self.rollout_engine_enabled
+        if not proc or not on_main or proc["compiles"] > 0:
+            return
+        breach = self._rollout_anomaly.observe(stats["time/generate_s"], pull_s)
+        if not breach:
+            return
+        stats["stall/rollout_excess_s"] = breach.excess_s
+        self._pending_stall = breach, obs_anomaly.stall_record(
+            "rollout",
+            breach,
+            proc,
+            step=self.iter_count,
+            iter=obs_spans.iteration(),
+            t0=proc["t0_ns"] * 1e-9,
+            t1=proc["t1_ns"] * 1e-9,
+            seconds=stats["time/generate_s"],
+            wait_s=pull_s,
+            host_ms=max(0.0, stats["time/generate_s"] - pull_s) * 1e3,
+            compiles=proc["compiles"],
+        )
 
     def learn(self):
         """Fleet-aware learn: a FleetDegradedExit unwinding out of the loop
