@@ -19,7 +19,7 @@ import pytest
 from benchmark.references import gqa_window_early_router_moe_decoder as reference
 from trlx_tpu.models import moe
 from trlx_tpu.models.lm import (ACTIVATIONS, Block, LMConfig, TransformerLM, cache_bytes, init_cache, make_attn_bias,
-                                ring_cache_bytes)
+                                ring_cache_bytes, rope_tables)
 from trlx_tpu.ops.flash_attention import FlashBlocks, flash_attention
 from trlx_tpu.ops.kv_read import attend
 
@@ -168,7 +168,7 @@ def test_the_router_reads_the_block_s_input(router_input):
     cfg = LMConfig.from_dict({**SHORT, **F32, "experts_held": [], "router_input": router_input})
     block = Block(cfg, "experts")
     x = jax.random.normal(jax.random.PRNGKey(1), (B, T, cfg.d_model))
-    bias, positions = make_attn_bias(jnp.ones((B, T), jnp.int32), T, 0), jnp.broadcast_to(jnp.arange(T), (B, T))
+    bias, positions = make_attn_bias(jnp.ones((B, T), jnp.int32), T, 0), rope_tables(cfg, jnp.broadcast_to(jnp.arange(T), (B, T)))
     params = block.init(jax.random.PRNGKey(2), x, bias, positions)["params"]
     assert moe.BIAS_NAME not in params["moe"] and sorted(params["moe"]) == ["experts_down", "experts_gate", "experts_up", "router"]
     run = lambda p, x: block.apply({"params": p}, x, bias, positions)

@@ -14,7 +14,7 @@ import pytest
 from benchmark.references import gqa_window_moe_decoder as reference
 from trlx_tpu.models import moe
 from trlx_tpu.models.lm import (Attention, LMConfig, TransformerLM, cache_bytes, cache_bytes_per_token, init_cache,
-                                init_paged_cache, make_attn_bias, ring_bias, write_ring)
+                                init_paged_cache, make_attn_bias, ring_bias, rope_tables, write_ring)
 from trlx_tpu.ops.flash_attention import FlashBlocks, flash_attention
 from trlx_tpu.ops.kv_read import attend, kv_keys_read
 
@@ -340,7 +340,7 @@ def test_attention_matches_the_published_exaone4_attention():
                   "c_proj": kernel(attn.o_proj), "q_norm": {"scale": jnp.asarray(attn.q_norm.weight.detach().numpy())},
                   "k_norm": {"scale": jnp.asarray(attn.k_norm.weight.detach().numpy())}}
         bias = make_attn_bias(jnp.ones((2, t), jnp.int32), t, 0, window=win)
-        got, _ = Attention(cfg).apply({"params": params}, jnp.asarray(x.numpy()), bias, jnp.asarray(positions.numpy()),
+        got, _ = Attention(cfg).apply({"params": params}, jnp.asarray(x.numpy()), bias, rope_tables(cfg, jnp.asarray(positions.numpy())),
                                       window=win)
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
 

@@ -445,6 +445,51 @@ def test_the_chunked_scan_relays_no_float32_array_64_lanes_wide(v5e_sharding):
     assert sorted(moved) == ["bf16", "bf16", "f32", "f32"], moved
 
 
+def test_rotary_keeps_the_projection_s_output_out_of_float32(v5e_sharding):
+    """A q (or k) projection followed by rotary at Ouro-2.6B's train shape
+    ([8, 1024, 2048] -> [8, 1024, 16, 128], bf16, rotate-half over the whole
+    head), forward and gradient, as the v5e's compiler leaves it. The sliced
+    formula began with a convert, which the compiler folded into the product:
+    the projection's output crossed HBM in float32 (67 MB for 34), was copied
+    whole in float32 and rotated in two halves 64 lanes wide (PERF.md section
+    6, PR 50). `apply_rotary` reads the projection's output in bf16 (the pair
+    swap is a product with a 0/1 matrix) and does its float32 sums inside one
+    fusion: NO instruction of the entry computation has a float32 result of
+    the projection's size. A count, not a rate."""
+    import math
+    import re
+
+    from trlx_tpu.models.lm import apply_rotary, rotary_tables
+
+    b, T, D, H, hd = 8, 1024, 2048, 16, 128
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+
+    def forward(x, w, positions):
+        q = (x @ w).reshape(b, T, H, hd)
+        return apply_rotary(q, rotary_tables(positions, hd, hd, 1e6, True), hd, True)
+
+    def gradient(x, w, positions, ct):
+        y, vjp = jax.vjp(lambda x, w: forward(x, w, positions), x, w)
+        return (y,) + vjp(ct)
+
+    args = (s((b, T, D), jnp.bfloat16), s((D, D), jnp.bfloat16), s((b, T), jnp.int32))
+    for fn, operands in ((forward, args), (gradient, args + (s((b, T, H, hd), jnp.bfloat16),))):
+        text = jax.jit(fn).lower(*operands).compile().as_text()
+        entry = text[text.index("\nENTRY "):]
+        wide, seen = [], 0
+        for line in entry.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+            if not m or m.group(2) in ("parameter", "bitcast", "tuple", "get-tuple-element"):
+                continue
+            for dtype, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", m.group(1)):
+                size = math.prod(int(d) for d in dims.split(","))
+                seen += size == b * T * D
+                if dtype == "f32" and size >= b * T * D:
+                    wide.append((m.group(2), dims))
+        assert seen, "no result of the projection's size in the entry computation: the pattern reads nothing"
+        assert not wide, wide
+
+
 def test_mosaic_kernels_refuse_a_multi_device_jit(v5e_sharding):
     """Why every model-layer gate requires a one-device mesh
     (flash_attention.one_device_tpu): jax will not partition a Mosaic call."""

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from trlx_tpu.models.lm import LMConfig, QDense, apply_rotary, drawn_in, flash_core, rotary_sincos, write_cache
+from trlx_tpu.models.lm import LMConfig, QDense, drawn_in, flash_core, rotate_heads, write_cache
 from trlx_tpu.ops.kv_read import attend_cache, ranged_read
 
 
@@ -70,7 +70,7 @@ class CCAttention(nn.Module):
     cfg: LMConfig
 
     @nn.compact
-    def __call__(self, x, attn_bias, positions, cache=None, cache_index=None, flash_mask=None, token_mask=None):
+    def __call__(self, x, attn_bias, rope, cache=None, cache_index=None, flash_mask=None, token_mask=None):
         cfg = self.cfg
         dtype, f32 = cfg.compute_dtype, jnp.float32
         b, t, _ = x.shape
@@ -116,11 +116,8 @@ class CCAttention(nn.Module):
             k = (unit(k) * jnp.exp(theta)[:, None]).astype(dtype)
             shifted = jnp.concatenate([before_v, shift_src[:, :-1]], axis=1)
             v = jnp.concatenate([dense(shifted_width(cfg), "v_proj")(x), shifted], axis=-1).reshape(b, t, G, hd)
-            if cfg.pos_type == "rotary":
-                rd = cfg.rotary_dim or hd
-                sin, cos = rotary_sincos(positions, rd, cfg.rope_theta)
-                neox = cfg.extra.get("neox_rotary", False)
-                q, k = apply_rotary(q, sin, cos, rd, neox), apply_rotary(k, sin, cos, rd, neox)
+            if rope is not None:
+                q, k = rotate_heads(cfg, q, rope), rotate_heads(cfg, k, rope)
 
         new_cache = written = read = None
         if cache is not None:

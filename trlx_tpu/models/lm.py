@@ -24,6 +24,7 @@ reference: trlx/model/nn/ppo_models.py:35-413):
 """
 
 import collections
+import functools
 import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional, Tuple
@@ -558,28 +559,130 @@ def rotary_sincos(positions: jnp.ndarray, rotary_dim: int, base: float = 10000.0
     return jnp.sin(freqs), jnp.cos(freqs)
 
 
-def apply_rotary(x: jnp.ndarray, sin: jnp.ndarray, cos: jnp.ndarray, rotary_dim: int, neox_style: bool = False):
+@functools.lru_cache(maxsize=None)
+def rotary_swap(width: int, rotary_dim: int, neox_style: bool) -> np.ndarray:
+    """R as a [width, width] matrix of 0 and 1: (x @ R)[j] is the channel that
+    channel j is paired with, x[j +- rotary_dim / 2] (NeoX: halves) or x[j ^ 1]
+    (GPT-J: even/odd neighbours); a column past `rotary_dim` is empty."""
+    j = np.arange(rotary_dim)
+    swap = np.zeros((width, width), np.float32)
+    swap[(j + rotary_dim // 2) % rotary_dim if neox_style else j ^ 1, j] = 1.0
+    return swap
+
+
+def rotary_tables(positions: jnp.ndarray, width: int, rotary_dim: int, base: float = 10000.0,
+                  neox_style: bool = False, inv_freq=None, scale: float = 1.0):
+    """(C, S) for `apply_rotary`, float32 [b, t, 1, width], made once a pass
+    from positions [b, t]: over a head's whole width C is the pair's cos and S
+    its sin with the pair's sign folded in (-sin on the member the formula
+    subtracts for, +sin on the other); past `rotary_dim` C = 1 and S = 0.
+    `scale` multiplies both (YaRN's table factor)."""
+    sin, cos = rotary_sincos(positions, rotary_dim, base, inv_freq)
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
+    if neox_style:
+        c, s = jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)
+    else:
+        c, s = jnp.repeat(cos, 2, axis=-1), jnp.stack([-sin, sin], axis=-1).reshape(sin.shape[:-1] + (rotary_dim,))
+    if rotary_dim < width:
+        rest = ((0, 0), (0, 0), (0, width - rotary_dim))
+        c, s = jnp.pad(c, rest, constant_values=1.0), jnp.pad(s, rest)
+    return c[:, :, None, :], s[:, :, None, :]
+
+
+# Elements of q (or k) from which the pair swap goes to the MXU. Over a train
+# batch, a scoring chunk or a prefill (millions of elements) the product
+# keeps the projection's output in the model's dtype and the rotation in one
+# lane-dense fusion. A decode step's few rows are bound by the count of
+# kernels, not by bytes: there the compiler folds the sliced members into
+# the projection ahead and the cache write behind, and any whole-width swap
+# (product, slices put together, a flip) cost Ouro's loop 1.3 ms a step
+# (PERF.md section 6, PR 50).
+ROTARY_MXU_MIN = 1 << 20
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rotate(x, c, s, rotary_dim, neox_style):
+    """x * C + R(x) * S in float32, rounded once to x's dtype; R puts each
+    pair's other member in a channel's place. Two statements of it, picked by
+    the call's size, equal bit for bit."""
+    f32, width = jnp.float32, x.shape[-1]
+    if x.size >= ROTARY_MXU_MIN:
+        # R as a product with a 0/1 matrix: exact (one term a column, float32
+        # accumulation), and x is read in its own dtype, whole rows of lanes
+        swap = jnp.asarray(rotary_swap(width, rotary_dim, neox_style), x.dtype)
+        paired = jnp.einsum("...d,de->...e", x, swap, precision=jax.lax.Precision.HIGHEST, preferred_element_type=f32)
+        return (x.astype(f32) * c + paired * s).astype(x.dtype)
+    # R by which member meets which: the pairs' first and second members, sliced
+    rot = x[..., :rotary_dim].astype(f32)
+    if neox_style:
+        first, second = slice(0, rotary_dim // 2), slice(rotary_dim // 2, rotary_dim)
+    else:
+        first, second = slice(0, rotary_dim, 2), slice(1, rotary_dim, 2)
+    x1, x2 = rot[..., first], rot[..., second]
+    cos, sin = c[..., first], s[..., second]  # C is one value a pair; S holds -sin on the first member, +sin on the second
+    members = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    out = jnp.concatenate(members, axis=-1) if neox_style else jnp.stack(members, axis=-1).reshape(rot.shape)
+    return out.astype(x.dtype) if rotary_dim == width else jnp.concatenate([out.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+
+def _rotate_fwd(x, c, s, rotary_dim, neox_style):
+    return _rotate(x, c, s, rotary_dim, neox_style), (c, s)
+
+
+def _rotate_bwd(rotary_dim, neox_style, tables, g):
+    # the transpose of a rotation is the rotation back: the same pass over the
+    # cotangent with S negated, float32 sums and one rounding as forward
+    c, s = tables
+    return _rotate(g, c, -s, rotary_dim, neox_style), jnp.zeros_like(c), jnp.zeros_like(s)
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
+
+
+def apply_rotary(x: jnp.ndarray, tables, rotary_dim: int, neox_style: bool = False):
     """Apply rotary embedding to q or k.
 
-    x: [b, t, n_head, head_dim]; sin/cos: [b, t, rotary_dim/2].
+    x: [b, t, n_head, head_dim]; tables: `rotary_tables` of the head's width.
     GPT-J interleaves even/odd pairs; NeoX rotates halves. Both supported —
-    HF-checkpoint numerical fidelity requires matching the layout.
+    HF-checkpoint numerical fidelity requires matching the layout. Either is
+    x * C + R(x) * S over the whole head: the layout picks R (`rotary_swap`)
+    and the tables, the sums are float32 and the result is rounded once.
     """
-    rot = x[..., :rotary_dim].astype(jnp.float32)
-    rest = x[..., rotary_dim:]
-    sin = sin[:, :, None, :]
-    cos = cos[:, :, None, :]
-    if neox_style:
-        half = rotary_dim // 2
-        x1, x2 = rot[..., :half], rot[..., half:]
-        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    else:
-        x1 = rot[..., ::2]
-        x2 = rot[..., 1::2]
-        r1 = x1 * cos - x2 * sin
-        r2 = x2 * cos + x1 * sin
-        out = jnp.stack([r1, r2], axis=-1).reshape(rot.shape)
-    return jnp.concatenate([out.astype(x.dtype), rest], axis=-1) if rotary_dim < x.shape[-1] else out.astype(x.dtype)
+    with jax.named_scope("rotary"):
+        return _rotate(x, *tables, rotary_dim, neox_style)
+
+
+def rotary_layout(cfg: "LMConfig"):
+    """(width, rotary_dim, neox_style) of what a layer of `cfg` rotates: a
+    head's leading `rotary_dim` channels, or latent attention's rope part,
+    whole and in interleaved pairs as the published code rotates it."""
+    if cfg.attention == "mla":
+        return cfg.qk_rope_head_dim, cfg.qk_rope_head_dim, False
+    return cfg.head_dim, cfg.rotary_dim or cfg.head_dim, bool(cfg.extra.get("neox_rotary", False))
+
+
+def rotate_heads(cfg: "LMConfig", x: jnp.ndarray, rope):
+    """q, k or latent attention's rope part `x` [b, t, heads, width] rotated by
+    the pass's tables in the layout `cfg` states."""
+    _, rd, neox = rotary_layout(cfg)
+    return apply_rotary(x, rope, rd, neox)
+
+
+def rope_tables(cfg: "LMConfig", positions: jnp.ndarray):
+    """The pass's rotary tables (every layer and every loop reads the same
+    positions), None without rotary positions."""
+    if cfg.pos_type != "rotary":
+        return None
+    width, rd, neox = rotary_layout(cfg)
+    scaling, inv_freq, scale = cfg.rope_scaling, None, 1.0  # latent attention's alone (LMConfig refuses it elsewhere)
+    if scaling:
+        # YaRN: corrected frequencies, and the published code multiplies the
+        # sin/cos tables by mscale(factor, mscale) / mscale(factor, mscale_all_dim)
+        inv_freq = yarn_inv_freq(rd, cfg.rope_theta, scaling)
+        scale = yarn_mscale(scaling["factor"], scaling.get("mscale", 1)) / yarn_mscale(
+            scaling["factor"], scaling.get("mscale_all_dim", 0) or 0)
+    return rotary_tables(positions, width, rd, cfg.rope_theta, neox, inv_freq, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -903,7 +1006,7 @@ class Attention(nn.Module):
     cfg: LMConfig
 
     @nn.compact
-    def __call__(self, x, attn_bias, positions, cache=None, cache_index=None,
+    def __call__(self, x, attn_bias, rope, cache=None, cache_index=None,
                  flash_mask=None, window=0, use_ring=False, block_tables=None):
         cfg = self.cfg
         dtype = cfg.compute_dtype
@@ -938,11 +1041,7 @@ class Attention(nn.Module):
                 q, k = head_norm("q_norm")(q).astype(dtype), head_norm("k_norm")(k).astype(dtype)
 
         if cfg.pos_type == "rotary" and (cfg.rotary_layers == "all" or window):
-            rd = cfg.rotary_dim or hd
-            sin, cos = rotary_sincos(positions, rd, cfg.rope_theta)
-            neox = cfg.extra.get("neox_rotary", False)
-            q = apply_rotary(q, sin, cos, rd, neox)
-            k = apply_rotary(k, sin, cos, rd, neox)
+            q, k = rotate_heads(cfg, q, rope), rotate_heads(cfg, k, rope)
 
         new_cache = None
         read = None  # set → the einsum read covers a slice of the cache
@@ -1093,7 +1192,7 @@ class LatentAttention(nn.Module):
     cfg: LMConfig
 
     @nn.compact
-    def __call__(self, x, attn_bias, positions, cache=None, cache_index=None,
+    def __call__(self, x, attn_bias, rope, cache=None, cache_index=None,
                  flash_mask=None, window=0, use_ring=False, block_tables=None):
         cfg = self.cfg
         if use_ring or block_tables is not None or window:
@@ -1110,29 +1209,23 @@ class LatentAttention(nn.Module):
         kv_a = dense(rank + dr, "kv_a_proj")(x)
         c_kv = norm("kv_a_norm")(kv_a[..., :rank])
         scaling = cfg.rope_scaling
-        rotate = apply_rotary if cfg.pos_type == "rotary" else (lambda part, sin, cos, dr: part)
-        sin, cos = rotary_sincos(positions, dr, cfg.rope_theta,
-                                 inv_freq=yarn_inv_freq(dr, cfg.rope_theta, scaling) if scaling else None)
+        # Interleaved pairs (0,1), (2,3), ... as the published code rotates them (`rotary_layout`).
+        rotate = (lambda part, rope: rotate_heads(cfg, part, rope)) if rope is not None else (lambda part, rope: part)
         softmax_scale = (dn + dr) ** -0.5
         if scaling:
             # YaRN's attention temperature: the published code multiplies the
-            # softmax scale by mscale(factor, mscale_all_dim)^2 and the sin/cos
-            # tables by mscale(factor, mscale) / mscale(factor, mscale_all_dim).
-            all_dim = yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0) or 0)
-            softmax_scale *= all_dim**2
-            table = yarn_mscale(scaling["factor"], scaling.get("mscale", 1)) / all_dim
-            if table != 1.0:
-                sin, cos = sin * table, cos * table
-        # Interleaved pairs (0,1), (2,3), ... as the published code rotates them.
-        k_rope = rotate(kv_a[:, :, None, rank:], sin, cos, dr)[:, :, 0]
+            # softmax scale by mscale(factor, mscale_all_dim)^2 (and the sin/cos
+            # tables by a factor of their own: `rope_tables`).
+            softmax_scale *= yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0) or 0) ** 2
+        k_rope = rotate(kv_a[:, :, None, rank:], rope)[:, :, 0]
         params = lambda feats, name, fan_in: HeadParams(
             feats, param_dtype=cfg.params_dtype, use_bias=False, draw_dtype=cfg.draw_dtype, name=name)(fan_in, b * q_len)[0].astype(dtype)
         w_qb = params(h * (dn + dr), "q_b_proj" if cfg.q_lora_rank else "q_proj", q_in).reshape(q_in, h, dn + dr)
         w_kvb = params(h * (dn + dv), "kv_b_proj", rank).reshape(rank, h, dn + dv)
 
-        def queries(c_q, sin, cos):
+        def queries(c_q, rope):
             q = jnp.einsum("btc,chn->bthn", c_q, w_qb)
-            return q[..., :dn], rotate(q[..., dn:], sin, cos, dr)
+            return q[..., :dn], rotate(q[..., dn:], rope)
 
         new_cache = None
         if cache is not None:
@@ -1142,11 +1235,11 @@ class LatentAttention(nn.Module):
         at_zero = isinstance(cache_index, (int, np.integer)) and int(cache_index) == 0
         if cache is None or at_zero:
 
-            def unabsorbed(c_q, c_kv, k_rope, sin, cos, mask_or_bias):
+            def unabsorbed(c_q, c_kv, k_rope, rope, mask_or_bias):
                 """[rows, q_len, h * dv] from the rows' latents: everything per
                 head (q, k, v, the padded copies) lives inside."""
                 rows = c_q.shape[0]
-                q = jnp.concatenate(queries(c_q, sin, cos), axis=-1)
+                q = jnp.concatenate(queries(c_q, rope), axis=-1)
                 kv = jnp.einsum("btc,chn->bthn", c_kv, w_kvb)
                 k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope[:, :, None, :], (rows, q_len, h, dr))], axis=-1)
                 v = kv[..., dn:]
@@ -1161,7 +1254,7 @@ class LatentAttention(nn.Module):
                     return out[..., :dv].astype(dtype).reshape(rows, q_len, h * dv)
 
             with jax.named_scope("mla_unabsorbed"):
-                operands = (c_q, c_kv, k_rope, sin, cos, flash_mask if flash_mask is not None else attn_bias)
+                operands = (c_q, c_kv, k_rope, rope, flash_mask if flash_mask is not None else attn_bias)
                 group = MLA_ROW_GROUP
                 if b > group and b % group == 0:
                     # A scoring pass over a whole rollout chunk: per-head keys
@@ -1169,13 +1262,13 @@ class LatentAttention(nn.Module):
                     # tensor beside a resident train state. The rows are
                     # independent, so they go through a group at a time.
                     split = lambda a: a.reshape((b // group, group) + a.shape[1:])
-                    out = jax.lax.map(lambda args: unabsorbed(*args), tuple(split(a) for a in operands))
+                    out = jax.lax.map(lambda args: unabsorbed(*args), jax.tree_util.tree_map(split, operands))
                     out = out.reshape(b, q_len, h * dv)
                 else:
                     out = unabsorbed(*operands)
         else:
             with jax.named_scope("mla_absorbed"):
-                q_nope, q_rope = queries(c_q, sin, cos)
+                q_nope, q_rope = queries(c_q, rope)
                 q_lat = jnp.einsum("bqhn,chn->bqhc", q_nope, w_kvb[..., :dn], preferred_element_type=jnp.float32)
                 read = ranged_read(int(cache[0].shape[1]), q_len, cache_index,
                                    attend_range=attend_latent_range, slot_major=False)
@@ -1224,7 +1317,8 @@ class Block(nn.Module):
     [b, q_len], the real tokens of `x`, in place of a bias; attention "cca",
     models/cca.py, reads it beside the bias). `router_state` [b, q_len,
     router_hidden]: what the router of the block below handed on
-    (`cfg.router_carry`). Returns (x, cache, expert_counts, routing): the tokens
+    (`cfg.router_carry`). `rope`: the pass's rotary tables (`rope_tables`), None
+    without rotary positions. Returns (x, cache, expert_counts, routing): the tokens
     each held expert took in this block, None for a dense one; `routing` is
     None but under `router_carry` or `router_scoring` "softmax_all": {"state":
     this block's router state (None without the carry), "top_weight": the mean
@@ -1235,7 +1329,7 @@ class Block(nn.Module):
     mixer: str = "attention"
 
     @nn.compact
-    def __call__(self, x, attn_bias, positions, cache=None, cache_index=None,
+    def __call__(self, x, attn_bias, rope, cache=None, cache_index=None,
                  flash_mask=None, window=0, use_ring=False, block_tables=None, token_mask=None, router_state=None):
         cfg = self.cfg
         ln = lambda name: make_norm(cfg, name)
@@ -1260,9 +1354,9 @@ class Block(nn.Module):
 
                 if window or use_ring or block_tables is not None:
                     raise NotImplementedError("attention 'cca' is not built for windows, the sp ring or paged caches")
-                return CCAttention(cfg, name="attn")(h, attn_bias, positions, cache, cache_index, flash_mask, token_mask)
+                return CCAttention(cfg, name="attn")(h, attn_bias, rope, cache, cache_index, flash_mask, token_mask)
             attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
-            return attn(h, attn_bias, positions, cache, cache_index, flash_mask, window, use_ring, block_tables)
+            return attn(h, attn_bias, rope, cache, cache_index, flash_mask, window, use_ring, block_tables)
 
         moe = None
         if self.ffn == "experts":
@@ -1542,6 +1636,9 @@ class TransformerLM(nn.Module):
             # returning x, so the compiled program is tap-free.
             x = obs_numerics.probe_tap("embed", x)
 
+        # every layer and every loop of the pass rotates by the same positions:
+        # one pair of tables, an operand of every block (None without rotary)
+        rope = rope_tables(cfg, position_ids)
         use_ring = ring_eligible(cfg, q_len, cache is not None, b)
         # Prefill at a STATIC zero write offset may use flash over the local
         # block (see flash_eligible); decode steps pass a traced cache_index.
@@ -1671,7 +1768,7 @@ class TransformerLM(nn.Module):
                 if cfg.router_carry:  # the block's last operand, after a token mask or None in its place
                     token_mask = (token_mask or (None,)) + (router_state,)
                 x, layer_new_cache, layer_counts, routing = block(
-                    x, layer_bias, position_ids, layer_cache, cache_index,
+                    x, layer_bias, rope, layer_cache, cache_index,
                     flash_mask, window, use_ring, block_tables, *token_mask,
                 )
                 if routing is not None:

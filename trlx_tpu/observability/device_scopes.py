@@ -57,6 +57,7 @@ SCOPES = (
     "attn_window",  # an attention layer's score/softmax/value part, window layer
     "attn_full",  # the same, full-span layer
     "qk_norm",  # the RMSNorm over each query and key head
+    "rotary",  # the rotary signal on q and k (models/lm.py apply_rotary): the pair swap and the float32 multiply-adds; inside `cca_mix` and `mla_*` too
     "flash_attn",  # a flash_attention call site: pad, [b,T,h,d] <-> [bh,T,d] relayouts, kernels, un-pad
     "kv_read",  # a read of the KV cache: the ranged switch, its branches, the int8 scale work
     "mla_absorbed",  # latent attention's read of the latent cache (decode)
