@@ -172,14 +172,14 @@ def test_the_router_reads_the_block_s_input(router_input):
     params = block.init(jax.random.PRNGKey(2), x, bias, positions)["params"]
     assert moe.BIAS_NAME not in params["moe"] and sorted(params["moe"]) == ["experts_down", "experts_gate", "experts_up", "router"]
     run = lambda p, x: block.apply({"params": p}, x, bias, positions)
-    y, _, counts, _ = run(params, x)
+    y, _, counts, *_ = run(params, x)
     assert int(counts.sum()) == B * T * 3
     wave = 1.0 + jnp.sin(jnp.arange(cfg.d_model))  # not a uniform factor: RMSNorm_2 would undo one
     scaled = {**params, "ln_1": {"scale": params["ln_1"]["scale"] * wave}}
-    y_scaled, _, counts_scaled, _ = run(scaled, x)
+    y_scaled, _, counts_scaled, *_ = run(scaled, x)
     assert float(jnp.abs(y_scaled - y).max()) > 1e-3  # attention did change
     assert bool(jnp.all(counts_scaled == counts)) == (router_input == "block")
-    _, _, counts_moved, _ = run(params, x + 0.5 * jax.random.normal(jax.random.PRNGKey(3), x.shape))
+    _, _, counts_moved, *_ = run(params, x + 0.5 * jax.random.normal(jax.random.PRNGKey(3), x.shape))
     assert not bool(jnp.all(counts_moved == counts))
 
 

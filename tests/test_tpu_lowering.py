@@ -375,6 +375,49 @@ def test_a_cca_layer_s_three_passes_compile_for_v5e(v5e_sharding):
     assert k.shape == v.shape == (16, 6144, 2, 128) and window.shape == (16, 2, 1280) and shifted.shape == (16, 1, 128)
 
 
+def test_a_lightning_and_a_sparse_layer_s_three_passes_compile_for_v5e(v5e_sharding):
+    """One lightning layer and the sparse layer of minicpmsala-l4.ppo-10240x2048 at
+    their widths, bf16, under remat: the train pass (forward and backward over the
+    train batch [1, 12288]: the chunked constant-decay pass, the choice and the
+    masked attention a query chunk at a time), the prefill of 4 x 10,240 into the
+    state leaf and the three-leaf cache, and the decode step that updates the
+    state, completes a compressed key and gathers the chosen blocks."""
+    import json
+    import os
+
+    from trlx_tpu.models.lm import LMConfig, TransformerLM, init_cache
+
+    spec = json.load(open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "minicpm-sala-9b-l4.json")))
+    cfg = LMConfig.from_dict({**spec["model_arch"], "dtype": "bfloat16", "param_dtype": "bfloat16", "remat": True,
+                              "n_layer": 2, "mixer_layers": ["lightning", "attention"]})
+    model = TransformerLM(cfg)
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+    ids = jnp.zeros((1, 4), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids, jnp.ones_like(ids))["params"])
+    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), params)
+
+    def train_step(p, ids, mask):
+        def loss(p):
+            out = model.apply({"params": p}, ids, mask, compute_logits=False)
+            return out["hidden"].astype(jnp.float32).sum(), out["sparse_sums"]
+        return jax.value_and_grad(loss, has_aux=True)(p)
+
+    train = jax.jit(train_step).lower(params, s((1, 12288), jnp.int32), s((1, 12288), jnp.int32)).compile()
+    assert train.memory_analysis().temp_size_in_bytes < 6e9
+    assert "tpu_custom_call" not in train.as_text()  # past 97 blocks the sparse layer takes no flash kernel
+    cache = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), jax.eval_shape(lambda: init_cache(cfg, 4, 12288)))
+    prefill = lambda p, ids, mask, cache, cache_mask: model.apply(
+        {"params": p}, ids, mask, cache=cache, cache_index=0, cache_mask=cache_mask, logits_start=10239)
+    jax.jit(prefill).lower(params, s((4, 10240), jnp.int32), s((4, 10240), jnp.int32), cache, s((4, 12288), jnp.int32)).compile()
+    step = lambda p, ids, cache, index, cache_mask: model.apply(
+        {"params": p}, ids, jnp.ones((4, 1), jnp.int32), cache=cache, cache_index=index, cache_mask=cache_mask)
+    args = (params, s((4, 1), jnp.int32), cache, s((), jnp.int32), s((4, 12288), jnp.int32))
+    jax.jit(step).lower(*args).compile()
+    (state,), (k, v, compressed) = jax.eval_shape(step, *args)["cache"]
+    assert state.dtype == jnp.float32 and state.shape == (4, 32, 128, 128)
+    assert k.shape == v.shape == (4, 12288, 2, 128) and compressed.shape == (4, 767, 2, 128)
+
+
 def test_the_delta_rule_pass_does_not_hand_its_inverse_to_autodiff(v5e_sharding):
     """One row of `kda_chunked` at Kimi-Linear's train shapes ([1, 1024], 32
     heads of 128, bf16 operands, chunks of 64: what one step of the mixer's

@@ -137,7 +137,7 @@ class RolloutEngine:
         if model.cfg.has_state:
             raise NotImplementedError(
                 "the rollout engine (and with it the paged pool and spec decode) is not built for a "
-                f"{'state-space' if model.cfg.has_ssm else 'kda'} layer: a slot's state is not carried through "
+                f"{model.cfg.state_layer_name} layer: a slot's state is not carried through "
                 "admission, a block table has nothing to page and a rejected draft needs a snapshot of the state to "
                 "roll back to")
         if model.cfg.attention == "cca":
@@ -145,6 +145,12 @@ class RolloutEngine:
                 "the rollout engine (and with it the paged pool and spec decode) is not built for attention 'cca': a "
                 "slot's convolution window and shifted value are not carried through admission or suffix prefill, a "
                 "block table pages slots and they have none, and a rejected draft needs a snapshot of both to roll back to")
+        if model.cfg.attention == "sparse":
+            raise NotImplementedError(
+                "the rollout engine (and with it the paged pool and spec decode) is not built for attention 'sparse': its "
+                "compressed keys lie in each row's own grid from the row's first slot and a decode step completes one every "
+                "sparse_stride tokens, which admission, suffix prefill and a block table do not carry, and a rejected draft "
+                "needs a snapshot of them to roll back to")
         if model.cfg.n_loops > 1:
             raise NotImplementedError(
                 "the rollout engine (and with it the paged pool and spec decode) is not built for a looped stack "

@@ -112,6 +112,8 @@ class LMWithValueHead(nn.Module):
             "expert_counts": out["expert_counts"],
             "branch_router_state": out["branch_router_state"],
             "router_top_weight": out["router_top_weight"],
+            "sparse_sums": out["sparse_sums"],
+            "sparse_read": out["sparse_read"],
             "exit_probs": out["exit_probs"],
             "logprobs": out["logprobs"],
             "lse": out["lse"],

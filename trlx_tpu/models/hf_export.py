@@ -39,7 +39,8 @@ def validate_exportable(cfg: LMConfig, family: str):
     if cfg.has_state or cfg.pos_type == "none" or cfg.n_loops > 1 or cfg.sandwich_norm or (
             cfg.embedding_multiplier, cfg.residual_multiplier, cfg.logits_scaling, cfg.attention_multiplier) != (1.0, 1.0, 1.0, 0.0):
         raise ValueError(
-            "export to an HF checkpoint is not built for a state-space or kda layer (mixer_layers 'mamba', 'kda'), pos_type "
+            "export to an HF checkpoint is not built for a state-space, lightning or kda layer (mixer_layers 'mamba', 'kda', "
+            "'lightning': the minicpm_sala family's tensor names are not known here), pos_type "
             "'none', a looped stack (n_loops > 1), sandwich norms "
             f"or the embedding / residual / attention / logits multipliers: HF {family} has none of them, and "
             "the checkpoint would compute another model (models/hf_import.py reads granitemoehybrid and kimi_linear; nothing writes them)")

@@ -23,7 +23,8 @@ and attention layers, models/ssm.py); and kimi_linear (gated delta-rule and
 unrotated latent-attention layers, sparse experts: models/kda.py, models/moe.py). `ouro` (the looped family) maps its
 config only: its weights are not imported (`load_hf_trunk` raises); so does `smallthinker` (grouped keys, windows beside
 NoPE layers, a softmax router ahead of attention over ReGLU experts) and `zaya` (attention behind two causal convolutions,
-one expert a token by an MLP router with a carried state, learned residual scaling). With no checkpoint (or `model_arch` given) params
+one expert a token by an MLP router with a carried state, learned residual scaling) and `minicpm_sala` (constant-decay
+linear-attention layers beside block-selected sparse attention, the MiniCPM scale constants). With no checkpoint (or `model_arch` given) params
 initialize from scratch — the randomwalks path
 (reference: examples/randomwalks.py:99-101).
 """
@@ -401,6 +402,68 @@ def lm_config_from_hf(hf, **overrides) -> LMConfig:
             router_carry=True,
             residual_scaling=True,
         )
+    elif t == "minicpm_sala":
+        # MiniCPM-SALA (9B): `mixer_types` names each layer "lightning-attn"
+        # (models/lightning.py) or "minicpm4" (InfLLM-V2's block-selected
+        # sparse attention, models/sparse.py), rotary inside the lightning
+        # layers only, qk-norm in both, an output gate in both, and the MiniCPM
+        # family's three scale constants. What config.json does not say (the
+        # six constants of MiniCPM4's `sparse_config`, the decay's slopes, the
+        # width of the gates and norms) is the program's reading of the
+        # family's papers (models/sparse.py, models/lightning.py): overrides
+        # name them. `dense_len` (a short sequence attended densely by the
+        # published inference code) is not taken. What the program lacks
+        # raises here, by name.
+        given = hf.to_dict() if hasattr(hf, "to_dict") else dict(vars(hf))
+        n_layer, kinds = given["num_hidden_layers"], list(given["mixer_types"])
+        unbuilt = [name for name, on in (
+            ("a mixer type other than 'minicpm4' and 'lightning-attn'", set(kinds) - {"minicpm4", "lightning-attn"}),
+            ("mixer_types of another length than num_hidden_layers", len(kinds) != n_layer),
+            ("attention_bias", given.get("attention_bias")), ("attn_use_rope", given.get("attn_use_rope")),
+            ("lightning layers without rotary (lightning_use_rope false)", not given.get("lightning_use_rope", True)),
+            ("lightning layers without their output norm (use_output_norm false)", not given.get("use_output_norm", True)),
+            ("grouped lightning keys (lightning_nkv != lightning_nh)", given.get("lightning_nkv") != given.get("lightning_nh")),
+            (f"lightning_scale {given.get('lightning_scale')!r}", given.get("lightning_scale", "1/sqrt(d)") != "1/sqrt(d)"),
+            (f"hidden_act {given.get('hidden_act')!r}", given.get("hidden_act") != "silu"),
+            ("rope_scaling", given.get("rope_scaling"))) if on]
+        if unbuilt:
+            raise ValueError(f"minicpm_sala: not built: {'; '.join(unbuilt)}")
+        d = dict(
+            vocab_size=given["vocab_size"],
+            n_layer=n_layer,
+            n_head=given["num_attention_heads"],
+            n_kv_head=given["num_key_value_heads"],
+            head_width=given["head_dim"],
+            d_model=given["hidden_size"],
+            d_ff=given["intermediate_size"],
+            max_position=given["max_position_embeddings"],
+            pos_type="rotary",
+            rotary_layers="lightning",
+            rope_theta=float(given.get("rope_theta", 10000.0)),
+            extra={"neox_rotary": True},
+            attention="sparse",
+            # MiniCPM4's published sparse_config (InfLLM-V2), not keys of this family's config.json
+            sparse_kernel=32, sparse_stride=16, sparse_block=64, sparse_topk=64, sparse_window=2048, sparse_init_blocks=1,
+            attn_output_gate=bool(given.get("attn_use_output_gate", False)),
+            mixer_layers=tuple("lightning" if kind == "lightning-attn" else "attention" for kind in kinds),
+            lightning_heads=given["lightning_nh"],
+            lightning_head_dim=given["lightning_head_dim"],
+            lightning_output_gate=bool(given.get("use_output_gate", False)),
+            qk_norm=bool(given.get("qk_norm", False)),
+            norm="rmsnorm",
+            mlp="gated",
+            activation="silu",
+            ln_eps=given["rms_norm_eps"],
+            parallel_residual=False,
+            fused_qkv=False,
+            qkv_bias=False,
+            out_bias=False,
+            tie_word_embeddings=bool(given.get("tie_word_embeddings", False)),
+            embedding_multiplier=float(given.get("scale_emb", 1.0)),
+            # scale_depth / sqrt(the PUBLISHED depth), whatever depth an override runs
+            residual_multiplier=float(given.get("scale_depth", 1.0)) / float(np.sqrt(n_layer)),
+            logits_scaling=float(given["hidden_size"]) / float(given.get("dim_model_base", given["hidden_size"])),
+        )
     else:
         raise ValueError(f"unsupported HF model_type for conversion: {t}")
     d.update(overrides)
@@ -577,6 +640,11 @@ def load_hf_trunk(model_path: str, cfg: LMConfig, put=None) -> Dict[str, Any]:
             "importing a zaya checkpoint's weights is not built: the names of the family's tensors could not be read "
             "without the network, and a guessed mapping would load another model; `model_arch` (weights from the seed) is "
             "the path that runs")
+    if cfg.has_lightning or cfg.attention == "sparse":
+        raise NotImplementedError(
+            "importing a minicpm_sala checkpoint's weights is not built: the names of the family's tensors could not be "
+            "read without the network, and a guessed mapping would load another model; `model_arch` (weights from the "
+            "seed) is the path that runs")
     if cfg.n_loops > 1:
         raise NotImplementedError(
             "importing a looped checkpoint's weights is not built: the names of the family's tensors (the two "

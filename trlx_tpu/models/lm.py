@@ -146,7 +146,7 @@ class LMConfig:
     # construction, never a quietly different model.
     norm: str = "layernorm"  # "layernorm" | "rmsnorm" (scale only, no mean)
     mlp: str = "dense"  # "dense": c_proj(act(c_fc x)) | "gated": down(act(gate x) * up x), no biases
-    attention: str = "mha"  # "mha": per-head K and V in the cache | "mla": latent attention (below) | "cca": models/cca.py
+    attention: str = "mha"  # "mha": per-head K and V in the cache | "mla": latent attention (below) | "cca": models/cca.py | "sparse": models/sparse.py
     # Grouped keys ("mha" only): K and V are projected to n_kv_head heads and
     # each serves n_head // n_kv_head query heads, in the cache and in every
     # read; 0 -> n_head (one K and V a query head).
@@ -160,7 +160,9 @@ class LMConfig:
     qk_norm: bool = False
     # Which layers rotate q and k (pos_type "rotary"): "all", or "local": the
     # window layers of `attention_layers` only, a global layer has no position
-    # signal at all.
+    # signal at all; or "lightning": the "lightning" layers of `mixer_layers`
+    # rotate (inside the linear layer, all lightning_head_dim channels) and the
+    # attention layers have no position signal.
     rotary_layers: str = "all"
     # What a window layer keeps in the cache: "span": every slot like a global
     # layer, the window by the bias (gpt-neo); "ring": window_size slots,
@@ -265,6 +267,41 @@ class LMConfig:
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
+    # A "lightning" layer of `mixer_layers` is trlx_tpu/models/lightning.py:
+    # linear attention with ONE constant decay a head (a buffer), per-head q
+    # and k of lightning_head_dim, `qk_norm` and rotary (`rotary_layers`
+    # "lightning") inside it, no convolution, an RMSNorm over all of its output
+    # channels and, with lightning_output_gate, a sigmoid gate of that width
+    # from the block's normed input. Its cache is a fixed float32 state
+    # [lightning_heads, lightning_head_dim, lightning_head_dim] a row. It
+    # stands beside attention "mha" or "sparse" and dense feed-forwards; the
+    # engine, the paged pool, spec decode, the sp ring, kv_cache_quant,
+    # decode_weight_quant, soft prompts, packed segments and a looped stack
+    # refuse it.
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    lightning_output_gate: bool = False
+    # attention "sparse" (models/sparse.py): every query chooses the key blocks
+    # it reads. Keys are compressed (the mean of sparse_kernel keys every
+    # sparse_stride tokens), a query's softmax over the compressed keys, summed
+    # over the heads of its K/V group and max-pooled onto blocks of
+    # sparse_block tokens, ranks the blocks; it reads the first
+    # sparse_init_blocks, the blocks of its trailing sparse_window tokens and
+    # the sparse_topk best of the others. The cache keeps the compressed keys
+    # beside K and V; a decode step gathers the chosen blocks. With
+    # attn_output_gate the output takes a sigmoid gate n_head * head_dim wide
+    # from the block's normed input. Built for the static generate path,
+    # scoring and the train step, with grouped keys and no position signal in
+    # the layer; the engine, the paged pool, spec decode, the sp ring,
+    # kv_cache_quant, decode_weight_quant, soft prompts, packed segments,
+    # windows and a looped stack refuse it.
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_block: int = 0
+    sparse_topk: int = 0
+    sparse_window: int = 0
+    sparse_init_blocks: int = 0
+    attn_output_gate: bool = False
     # The four scalars of the granite family: on the token embedding, on the
     # attention scores (0 -> 1/sqrt(head_dim), or 1 without scale_attn), on
     # both residual branches of a block, and dividing the logits (in the fused
@@ -306,7 +343,7 @@ class LMConfig:
                 f"unknown remat_policy {self.remat_policy!r} (expected 'full' or 'dots')"
             )
         for name, kinds in (("norm", ("layernorm", "rmsnorm")), ("mlp", ("dense", "gated")),
-                            ("attention", ("mha", "mla", "cca")), ("rotary_layers", ("all", "local")),
+                            ("attention", ("mha", "mla", "cca", "sparse")), ("rotary_layers", ("all", "local", "lightning")),
                             ("window_cache", ("span", "ring")), ("pos_type", ("learned", "rotary", "none")),
                             ("router_scoring", ("sigmoid", "softmax", "softmax_all")), ("router_input", ("ffn", "block")),
                             ("router_kind", ("linear", "mlp"))):
@@ -338,8 +375,12 @@ class LMConfig:
                 raise ValueError("grouped keys (n_kv_head < n_head) are built for attention 'mha' and 'cca' with separate "
                                  "q/k/v projections (fused_qkv false), and not for the sp ring")
         if (self.qk_norm or self.head_width) and (self.attention == "mla" or self.fused_qkv):
-            raise ValueError("qk_norm and head_width are built for attention 'mha' (head_width: 'cca' too) with separate "
-                             "q/k/v projections (fused_qkv false)")
+            raise ValueError("qk_norm and head_width are built for attention 'mha' and 'sparse' (head_width: 'cca' too) with "
+                             "separate q/k/v projections (fused_qkv false)")
+        if self.rotary_layers == "lightning" and (self.pos_type != "rotary" or not self.has_lightning or self.attention == "mla"
+                                                  or (self.rotary_dim or self.lightning_head_dim) != self.lightning_head_dim):
+            raise ValueError("rotary_layers 'lightning' needs pos_type 'rotary', a 'lightning' layer in mixer_layers whose "
+                             "whole head rotates (rotary_dim 0) and attention layers that rotate nothing")
         if self.rotary_layers == "local" and (self.pos_type != "rotary" or "local" not in self.attention_layers):
             raise ValueError("rotary_layers 'local' needs pos_type 'rotary' and a 'local' layer in attention_layers")
         if self.window_cache == "ring":
@@ -404,8 +445,9 @@ class LMConfig:
                 raise ValueError(f"router_input 'block' (the router ahead of attention) is not built with {', '.join(unbuilt)}")
 
         if self.mixer_layers and (len(self.mixer_layers) != self.n_layer
-                                  or set(self.mixer_layers) - {"attention", "mamba", "kda"}):
-            raise ValueError(f"mixer_layers must name 'attention', 'mamba' or 'kda' for each of {self.n_layer} layers: {self.mixer_layers!r}")
+                                  or set(self.mixer_layers) - {"attention", "mamba", "kda", "lightning"}):
+            raise ValueError(f"mixer_layers must name 'attention', 'mamba', 'kda' or 'lightning' for each of {self.n_layer} "
+                             f"layers: {self.mixer_layers!r}")
         if self.has_ssm:
             sizes = (self.ssm_heads, self.ssm_head_dim, self.ssm_state, self.ssm_conv - 1, self.ssm_chunk)
             if min(sizes) <= 0:
@@ -428,6 +470,48 @@ class LMConfig:
                 ("parallel_residual", self.parallel_residual)) if on]
             if unbuilt:
                 raise ValueError(f"a 'kda' layer (mixer_layers) is not built with {', '.join(unbuilt)}")
+        if self.has_lightning:
+            if min(self.lightning_heads, self.lightning_head_dim) <= 0 or self.lightning_head_dim % 2:
+                raise ValueError("a 'lightning' layer needs lightning_heads and an even lightning_head_dim, got "
+                                 f"{(self.lightning_heads, self.lightning_head_dim)}")
+            unbuilt = [name for name, on in (
+                ("a 'mamba' layer", self.has_ssm), ("a 'kda' layer", self.has_kda),
+                ("attention 'mla' or 'cca'", self.attention in ("mla", "cca")), ("kv_cache_quant", self.kv_cache_quant),
+                ("soft prompts", self.n_soft_tokens > 0), ("the sp ring (sp_size > 1)", self.sp_size > 1),
+                ("windowed attention_layers", "local" in self.attention_layers),
+                ("expert layers", "experts" in self.ffn_layers), ("parallel_residual", self.parallel_residual),
+                ("a looped stack (n_loops > 1)", self.n_loops > 1),
+                ("rotary_layers 'all' (its rotary is rotary_layers 'lightning')",
+                 self.pos_type == "rotary" and self.rotary_layers != "lightning")) if on]
+            if unbuilt:
+                raise ValueError(f"a 'lightning' layer (mixer_layers) is not built with {', '.join(unbuilt)}")
+        elif self.lightning_heads or self.lightning_head_dim or self.lightning_output_gate:
+            raise ValueError("lightning_heads, lightning_head_dim and lightning_output_gate describe 'lightning' layers: "
+                             "mixer_layers names none")
+        sparse = (self.sparse_kernel, self.sparse_stride, self.sparse_block, self.sparse_topk, self.sparse_window)
+        if self.attention == "sparse":
+            if (min(sparse) <= 0 or self.sparse_init_blocks < 0 or self.sparse_kernel % self.sparse_stride
+                    or self.sparse_block % self.sparse_stride or self.sparse_window % self.sparse_block
+                    or self.sparse_kernel > self.sparse_block):
+                raise ValueError(
+                    "attention 'sparse' needs sparse_kernel, sparse_stride, sparse_block, sparse_topk and sparse_window, the "
+                    "stride dividing the kernel and the block, the block dividing the window and holding a kernel: got "
+                    f"{sparse} and sparse_init_blocks {self.sparse_init_blocks}")
+            unbuilt = [name for name, on in (
+                ("fused_qkv", self.fused_qkv), ("qkv_bias or out_bias (it has no such bias)", self.qkv_bias or self.out_bias),
+                ("kv_cache_quant", self.kv_cache_quant), ("windowed attention_layers", "local" in self.attention_layers),
+                ("soft prompts", self.n_soft_tokens > 0), ("the sp ring (sp_size > 1)", self.sp_size > 1),
+                ("a 'mamba' layer", self.has_ssm), ("a 'kda' layer", self.has_kda),
+                ("a looped stack (n_loops > 1)", self.n_loops > 1), ("expert layers", "experts" in self.ffn_layers),
+                ("rotary in the layer (pos_type 'rotary' with rotary_layers other than 'lightning') or learned positions",
+                 self.pos_type == "learned" or (self.pos_type == "rotary" and self.rotary_layers != "lightning")),
+                ("an attention_multiplier or scale_attn false (its scale is 1/sqrt(head_dim) by rule)",
+                 self.attention_multiplier != 0 or not self.scale_attn)) if on]
+            if unbuilt:
+                raise ValueError(f"attention 'sparse' is not built with {', '.join(unbuilt)}")
+        elif max(sparse) or self.sparse_init_blocks or self.attn_output_gate:
+            raise ValueError("sparse_kernel, sparse_stride, sparse_block, sparse_topk, sparse_window, sparse_init_blocks and "
+                             "attn_output_gate describe attention 'sparse'")
         if self.n_loops < 1:
             raise ValueError(f"n_loops must be at least 1, got {self.n_loops}")
         if self.n_loops > 1:
@@ -465,9 +549,19 @@ class LMConfig:
         return "kda" in self.mixer_layers
 
     @property
+    def has_lightning(self) -> bool:
+        """Whether any layer is a constant-decay linear-attention ("lightning") mixer."""
+        return "lightning" in self.mixer_layers
+
+    @property
     def has_state(self) -> bool:
-        """Whether any layer keeps a recurrent state in place of keys ("mamba" or "kda")."""
-        return self.has_ssm or self.has_kda
+        """Whether any layer keeps a recurrent state in place of keys ("mamba", "kda" or "lightning")."""
+        return self.has_ssm or self.has_kda or self.has_lightning
+
+    @property
+    def state_layer_name(self) -> str:
+        """What a refusal calls the stack's recurrent layers: "state-space" ("mamba"), "kda" or "lightning"."""
+        return "state-space" if self.has_ssm else "kda" if self.has_kda else "lightning"
 
     def mixer(self, layer: int) -> str:
         return self.mixer_layers[layer] if self.mixer_layers else "attention"
@@ -659,6 +753,8 @@ def rotary_layout(cfg: "LMConfig"):
     whole and in interleaved pairs as the published code rotates it."""
     if cfg.attention == "mla":
         return cfg.qk_rope_head_dim, cfg.qk_rope_head_dim, False
+    if cfg.rotary_layers == "lightning":  # a lightning layer's whole head, the attention layers rotate nothing
+        return cfg.lightning_head_dim, cfg.lightning_head_dim, bool(cfg.extra.get("neox_rotary", False))
     return cfg.head_dim, cfg.rotary_dim or cfg.head_dim, bool(cfg.extra.get("neox_rotary", False))
 
 
@@ -724,6 +820,8 @@ def flash_eligible(cfg: LMConfig, q_len: int, has_cache: bool, prefill_at_zero: 
     if cfg.attn_impl == "xla":
         return False
     if has_cache and not (q_len > 1 and prefill_at_zero):
+        return False
+    if cfg.attention == "sparse":  # its queries choose their key blocks: no band the kernels could take
         return False
     if cfg.attn_impl == "auto":
         from trlx_tpu.ops.flash_attention import auto_flash_ok, one_device_tpu
@@ -1035,10 +1133,7 @@ class Attention(nn.Module):
         v = v.reshape(b, q_len, kvh, hd)
 
         if cfg.qk_norm:
-            with jax.named_scope("qk_norm"):
-                head_norm = lambda name: nn.RMSNorm(
-                    epsilon=cfg.ln_eps, dtype=jnp.float32, param_dtype=cfg.params_dtype, name=name)
-                q, k = head_norm("q_norm")(q).astype(dtype), head_norm("k_norm")(k).astype(dtype)
+            q, k = qk_normed(cfg, q, k)
 
         if cfg.pos_type == "rotary" and (cfg.rotary_layers == "all" or window):
             q, k = rotate_heads(cfg, q, rope), rotate_heads(cfg, k, rope)
@@ -1302,6 +1397,15 @@ class MLP(nn.Module):
         return dense(cfg.d_model, "c_proj", True)(act(dense(width, "c_fc", True)(x)))
 
 
+def qk_normed(cfg: LMConfig, q, k):
+    """`qk_norm`: RMSNorm over each query head and each key head (one scale of a head's width for all the query
+    heads, `q_norm`, one for the key heads, `k_norm`), in float32, before rotary. Called from a mixer's
+    `__call__`: the two norms are that module's children."""
+    with jax.named_scope("qk_norm"):
+        head_norm = lambda name: nn.RMSNorm(epsilon=cfg.ln_eps, dtype=jnp.float32, param_dtype=cfg.params_dtype, name=name)
+        return head_norm("q_norm")(q).astype(cfg.compute_dtype), head_norm("k_norm")(k).astype(cfg.compute_dtype)
+
+
 def make_norm(cfg: LMConfig, name: str, **kwargs):
     kind = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
     return kind(epsilon=cfg.ln_eps, dtype=cfg.compute_dtype, param_dtype=cfg.params_dtype, name=name, **kwargs)
@@ -1313,16 +1417,20 @@ class Block(nn.Module):
     on each branch's output; with `cfg.residual_scaling` a learned scale and
     bias on both operands of each sum. `ffn` is this layer's feed-forward kind ("dense" | "experts"),
     `mixer` its mixer kind ("attention" | "mamba": models/ssm.py | "kda":
-    models/kda.py; the last two keep a state for a cache and read `token_mask`
-    [b, q_len], the real tokens of `x`, in place of a bias; attention "cca",
-    models/cca.py, reads it beside the bias). `router_state` [b, q_len,
+    models/kda.py | "lightning": models/lightning.py; these keep a state for a
+    cache and read `token_mask` [b, q_len], the real tokens of `x`, in place of
+    a bias; attention "cca", models/cca.py, and "sparse", models/sparse.py, read
+    it beside the bias, "sparse" the cache's occupancy in a decode step). `router_state` [b, q_len,
     router_hidden]: what the router of the block below handed on
     (`cfg.router_carry`). `rope`: the pass's rotary tables (`rope_tables`), None
-    without rotary positions. Returns (x, cache, expert_counts, routing): the tokens
+    without rotary positions. Returns (x, cache, expert_counts, routing, sparse_stats): the tokens
     each held expert took in this block, None for a dense one; `routing` is
     None but under `router_carry` or `router_scoring` "softmax_all": {"state":
     this block's router state (None without the carry), "top_weight": the mean
-    weight of a token's first choice}."""
+    weight of a token's first choice}; `sparse_stats` is what a "sparse"
+    attention layer counted of its own choice (models/sparse.py
+    SparseAttention: four sums in a pass with no cache, two in a decode step),
+    None for every other layer and in a prefill."""
 
     cfg: LMConfig
     ffn: str = "dense"
@@ -1333,7 +1441,7 @@ class Block(nn.Module):
                  flash_mask=None, window=0, use_ring=False, block_tables=None, token_mask=None, router_state=None):
         cfg = self.cfg
         ln = lambda name: make_norm(cfg, name)
-        counts = routing = None
+        counts = routing = sparse_stats = None
         # On a partitioned mesh a pass over many tokens keeps its rows where
         # the batch split put them, at both edges of the block (inside, so
         # that a remat'd backward holds them too), and every product gathers
@@ -1349,6 +1457,18 @@ class Block(nn.Module):
                 from trlx_tpu.models.kda import KDAMixer
 
                 return KDAMixer(cfg, name="kda")(h, token_mask, cache)
+            if self.mixer == "lightning":
+                from trlx_tpu.models.lightning import LightningMixer
+
+                return LightningMixer(cfg, name="lightning")(h, token_mask, rope, cache)
+            if cfg.attention == "sparse":
+                from trlx_tpu.models.sparse import SparseAttention
+
+                if window or use_ring or block_tables is not None:
+                    raise NotImplementedError("attention 'sparse' is not built for windows, the sp ring or paged caches")
+                nonlocal sparse_stats
+                out, new, sparse_stats = SparseAttention(cfg, name="attn")(h, cache, cache_index, token_mask)
+                return out, new
             if cfg.attention == "cca":
                 from trlx_tpu.models.cca import CCAttention
 
@@ -1411,7 +1531,7 @@ class Block(nn.Module):
             attn_out, new_cache = mix(ln("ln_1")(x))
             x = x + branch(out_norm("ln_1_out", attn_out))
             x = x + branch(out_norm("ln_2_out", feed_forward(ln("ln_2")(x))))
-        return hold_rows(x), new_cache, counts, routing
+        return hold_rows(x), new_cache, counts, routing, sparse_stats
 
 
 def make_attn_bias(
@@ -1656,7 +1776,7 @@ class TransformerLM(nn.Module):
         if cfg.has_state and (segment_ids is not None or (cache is not None and (
                 block_tables is not None or jnp.ndim(cache_index) != 0 or (q_len > 1 and not prefill_at_zero)))):
             raise NotImplementedError(
-                f"a {'state-space' if cfg.has_ssm else 'kda'} layer takes a pass with no cache, a prefill at write "
+                f"a {cfg.state_layer_name} layer takes a pass with no cache, a prefill at write "
                 "offset 0 or one token a step for the whole batch (the static generate path): no block table, "
                 "per-row offset, verify window or packed segments")
         if cfg.attention == "cca" and (segment_ids is not None or (cache is not None and (
@@ -1665,6 +1785,12 @@ class TransformerLM(nn.Module):
                 "attention 'cca' takes a pass with no cache, a prefill at write offset 0 or one token a step for the "
                 "whole batch (the static generate path): its convolutions' window and shifted value are one a row, so "
                 "no block table, per-row offset, verify window or packed segments")
+        if cfg.attention == "sparse" and (segment_ids is not None or (cache is not None and (
+                block_tables is not None or jnp.ndim(cache_index) != 0 or (q_len > 1 and not prefill_at_zero)))):
+            raise NotImplementedError(
+                "attention 'sparse' takes a pass with no cache, a prefill at write offset 0 or one token a step for the "
+                "whole batch (the static generate path): its compressed keys lie in each row's own grid from the row's "
+                "first slot, so no block table, per-row offset, verify window or packed segments")
         if cfg.router_carry and (start_layer > 0) != (router_state is not None):
             raise ValueError("router_carry: a pass from start_layer > 0 (the frozen branch's replay) takes the router "
                              "state of the block below as `router_state`, a pass from the first block takes none")
@@ -1675,6 +1801,9 @@ class TransformerLM(nn.Module):
         if use_flash:
             attn_bias = local_bias = None
             flash_mask = attention_mask.astype(jnp.float32)
+        elif cfg.attention == "sparse":  # its layers mask by the tokens' own mask and their choice: no bias is read
+            attn_bias = local_bias = flash_mask = None
+            kv_mask = cache_mask if cache_mask is not None else attention_mask
         else:
             flash_mask = None
             if cache is not None:
@@ -1735,7 +1864,7 @@ class TransformerLM(nn.Module):
 
         branch_hidden = branch_router_state = None
         new_cache = [] if cache is not None else None
-        expert_counts, top_weights = [], []
+        expert_counts, top_weights, sparse_stats = [], [], []
         if cfg.router_carry and router_state is None:  # nothing lies below the first block
             router_state = jnp.zeros((b, q_len, cfg.router_hidden), jnp.float32)
         # All blocks are *defined* every call so the param structure is
@@ -1764,13 +1893,17 @@ class TransformerLM(nn.Module):
                 window = layer_window(cfg, i)
                 layer_bias = local_bias if window else attn_bias
                 # a layer that keeps a state reads the tokens' mask itself, in place of a bias
-                token_mask = (attention_mask,) if cfg.mixer(i) != "attention" or cfg.attention == "cca" else ()
+                token_mask = (attention_mask,) if cfg.mixer(i) != "attention" or cfg.attention in ("cca", "sparse") else ()
+                if cfg.attention == "sparse" and cfg.mixer(i) == "attention" and cache is not None and q_len == 1:
+                    token_mask = (kv_mask,)  # a decode step: the cache's occupancy tells each row's first slot
                 if cfg.router_carry:  # the block's last operand, after a token mask or None in its place
                     token_mask = (token_mask or (None,)) + (router_state,)
-                x, layer_new_cache, layer_counts, routing = block(
+                x, layer_new_cache, layer_counts, routing, layer_sparse = block(
                     x, layer_bias, rope, layer_cache, cache_index,
                     flash_mask, window, use_ring, block_tables, *token_mask,
                 )
+                if layer_sparse is not None:
+                    sparse_stats.append(jnp.stack(layer_sparse))
                 if routing is not None:
                     router_state = routing["state"]
                     top_weights.append(routing["top_weight"])
@@ -1921,6 +2054,11 @@ class TransformerLM(nn.Module):
             # `router_carry`: the router state entering block `collect_hidden_at`, float32
             "branch_router_state": branch_router_state,
             "router_top_weight": jnp.mean(jnp.stack(top_weights)) if top_weights else None,
+            # attention "sparse", summed over its layers, float32. A pass with no cache: [4], (kept pairs,
+            # causal pairs, chosen blocks, query-groups). A decode step: [2], (the share of its filled
+            # slots a step's softmax saw, summed over rows and K/V heads; their count)
+            "sparse_sums": sum(sparse_stats) if sparse_stats and cache is None else None,
+            "sparse_read": sum(sparse_stats) if sparse_stats and cache is not None else None,
             # [b, t, n_loops] float32: where the exit gate would leave the loop
             # (a pass with no cache over a gated looped stack); None otherwise.
             "exit_probs": exit_probs,
@@ -1965,7 +2103,11 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
     state [b, kda_heads, kda_head_dim, kda_head_dim] float32), beside the
     latent leaves of the "mla" layers of the same stack; "cca" (models/cca.py):
     (k, v) [b, T, kv_heads, hd] and, with no slot axis, (window [b, cca_time0 +
-    cca_time1 - 2, (n_head + kv_heads) hd], shifted [b, 1, kv_heads / 2 hd]). A
+    cca_time1 - 2, (n_head + kv_heads) hd], shifted [b, 1, kv_heads / 2 hd]); a
+    "lightning" layer (models/lightning.py): (state [b, lightning_heads,
+    lightning_head_dim, lightning_head_dim] float32,), nothing else; "sparse"
+    (models/sparse.py): (k, v) [b, T, kv_heads, hd] and the compressed keys
+    [b, (T - sparse_kernel) // sparse_stride + 1, kv_heads, hd] in each row's own grid. A
     looped stack (n_loops > 1) keeps n_loops * n_layer groups, entry
     loop * n_layer + layer: loop r's layer reads what loop r's layer wrote."""
     if cfg.kv_cache_quant:
@@ -1974,10 +2116,14 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
 
     def layer(i):
         if cfg.mixer(i) != "attention":
-            from trlx_tpu.models import kda, ssm
+            from trlx_tpu.models import kda, lightning, ssm
 
-            shapes = (ssm if cfg.mixer(i) == "mamba" else kda).cache_shapes(cfg, batch)
+            shapes = {"mamba": ssm, "kda": kda, "lightning": lightning}[cfg.mixer(i)].cache_shapes(cfg, batch)
             return tuple(jnp.zeros(shape, leaf_dtype) for shape, leaf_dtype in shapes)
+        if cfg.attention == "sparse":
+            from trlx_tpu.models import sparse
+
+            return tuple(jnp.zeros(shape, leaf_dtype) for shape, leaf_dtype in sparse.cache_shapes(cfg, batch, max_len))
         if cfg.attention == "mla":
             return jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype), jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype)
         if cfg.attention == "cca":
@@ -2010,7 +2156,7 @@ def init_paged_cache(cfg: LMConfig, n_blocks: int, block_size: int, dtype=None):
     if cfg.attention != "mha" or cfg.window_cache != "span" or cfg.has_state or cfg.n_loops > 1:
         raise NotImplementedError(
             f"the paged pool is not built for attention {cfg.attention!r}, window_cache {cfg.window_cache!r}, a "
-            "state-space or kda layer (a state has no slots to page) or a looped stack (one table a layer, where a "
+            "state-space, lightning or kda layer (a state has no slots to page) or a looped stack (one table a layer, where a "
             "looped stack keeps keys a (loop, layer) pair)")
     shape = (n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_cache_quant:
@@ -2036,8 +2182,9 @@ def cache_partition_spec(cfg: LMConfig, leaf_ndim: int, layer: int = 0):
     batch over the data axes, heads over tp (grouped keys: the kv_heads; a
     ring layer's leaves have the same axes, fewer slots). An "mla" cache has
     no head axis: one latent a token serves every head, so it is whole on
-    every tp shard. A "mamba" or "kda" layer: the state's rows over the data
-    axes and its heads over tp, the convolution's window whole on every tp shard."""
+    every tp shard. A "mamba", "kda" or "lightning" layer: the state's rows over
+    the data axes and its heads over tp, the convolution's window whole on every
+    tp shard. A "sparse" layer's compressed keys have the axes of its keys."""
     from jax.sharding import PartitionSpec
 
     from trlx_tpu.parallel.mesh import AXIS_TP, DATA_AXES
@@ -2071,7 +2218,7 @@ def ring_cache_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
 def state_bytes(cfg: LMConfig, batch: int) -> int:
     """The part of `cache_bytes` that does not grow with the length: what the
     layers with a recurrent state hold ("mamba", "kda": state and convolution
-    window) and what "cca" layers keep beside their slots (`cca_state_bytes`).
+    window; "lightning": the state) and what "cca" layers keep beside their slots (`cca_state_bytes`).
     The counter `rollout/state_bytes`, from `init_cache`'s own shapes."""
     cache = jax.eval_shape(lambda: init_cache(cfg, batch, 1))
     return tree_size_bytes([cache[i] for i in range(cfg.n_layer) if cfg.mixer(i) != "attention"]) + cca_state_bytes(cfg, batch)
@@ -2087,6 +2234,15 @@ def cca_state_bytes(cfg: LMConfig, batch: int) -> int:
     return tree_size_bytes([cache[i][2:] for i in range(cfg.n_layer) if cfg.mixer(i) == "attention"])
 
 
+def compressed_key_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
+    """The part of `cache_bytes` that "sparse" layers' compressed keys take: the
+    counter `rollout/compressed_key_bytes`, from `init_cache`'s own shapes."""
+    if cfg.attention != "sparse":
+        return 0
+    cache = jax.eval_shape(lambda: init_cache(cfg, batch, max_len))
+    return tree_size_bytes([cache[i][2] for i in range(cfg.n_layer) if cfg.mixer(i) == "attention"])
+
+
 def cache_bytes_per_token(cfg: LMConfig) -> int:
     """Bytes the cache holds a token, all layers (a looped stack: all
     n_loops * n_layer entries): the counter
@@ -2094,8 +2250,10 @@ def cache_bytes_per_token(cfg: LMConfig) -> int:
     row of one token. A ring layer counts its ring once: one slot, like a
     full-span layer's, though past window_size tokens it grows no further
     (`rollout/cache_bytes` is the whole allocation). A state-space layer
-    holds nothing a token: its state is `rollout/state_bytes_per_row`."""
-    return cache_bytes(cfg, 1, 1) - state_bytes(cfg, 1)
+    holds nothing a token: its state is `rollout/state_bytes_per_row`. A
+    "sparse" layer's keys and values count; its compressed keys (one every
+    sparse_stride tokens) are `rollout/compressed_key_bytes`."""
+    return cache_bytes(cfg, 1, 1) - state_bytes(cfg, 1) - compressed_key_bytes(cfg, 1, 1)
 
 
 def decode_step_bytes(cfg: LMConfig, batch: int, keys_read: float, weight_bytes: int,

@@ -78,6 +78,12 @@ SCOPES = (
     "kda_scan",  # its chunked pass (or one recurrence step)
     "kda_gate",  # its gated per-head norm
     "kda_out",  # its output projection
+    "lightning_in",  # lightning mixer: the q/k/v and gate projections, the padding's mask on v
+    "lightning_scan",  # its chunked pass (or one recurrence step)
+    "lightning_gate",  # its output norm over all channels and the sigmoid gate
+    "lightning_out",  # its output projection
+    "sparse_select",  # attention "sparse": compressed keys, the scores over them, the pooled block scores and the choice
+    "sparse_attn",  # attention "sparse": attention over the chosen blocks (many tokens: the masked pass; a decode step: the gather)
     "loop_norm",  # a looped stack: the final norm at the end of every loop, the next loop's input
     "exit_gate",  # a looped stack: the exit gate on each loop's output and the exit distribution
     "lm_head",  # the vocabulary head in every form: fused log-probs, dense logits, ILQL's Q heads

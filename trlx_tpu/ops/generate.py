@@ -110,7 +110,8 @@ def generate(
         data = int(mesh.shape[mesh_mod.AXIS_DP]) * int(mesh.shape[mesh_mod.AXIS_FSDP])
         tp = int(mesh.shape[mesh_mod.AXIS_TP])
         if (B % data == 0 and cfg.kv_heads % tp == 0 and (not cfg.has_ssm or cfg.ssm_heads % tp == 0)
-                and (not cfg.has_kda or cfg.kda_heads % tp == 0)):
+                and (not cfg.has_kda or cfg.kda_heads % tp == 0)
+                and (not cfg.has_lightning or cfg.lightning_heads % tp == 0)):
             cache = tuple(
                 jax.tree_util.tree_map(
                     lambda x, i=i: jax.lax.with_sharding_constraint(
@@ -164,6 +165,11 @@ def generate(
         # expert layers, summed over the steps: carried here and read once a
         # rollout (`rollout/experts_touched`), no sync a step.
         state["experts_touched"] = jnp.zeros((), jnp.float32)
+    if cfg.attention == "sparse":
+        # The share of their filled slots the sparse layers' decode steps read,
+        # summed over rows, K/V heads, layers and steps, and its count (the
+        # steps' own `sparse_read`, models/sparse.py): carried like the experts'.
+        state["sparse_read"] = jnp.zeros((2,), jnp.float32)
     if step_stats_fn is not None:
         # eval_shape: discover the stat names/shapes without executing the fn.
         probe = jax.eval_shape(
@@ -232,6 +238,8 @@ def generate(
         if counts_experts:
             touched = jnp.mean(jnp.sum(step_out["expert_counts"] > 0, axis=-1).astype(jnp.float32))
             new_s["experts_touched"] = s["experts_touched"] + touched
+        if "sparse_read" in s:
+            new_s["sparse_read"] = s["sparse_read"] + step_out["sparse_read"]
         if step_stats_fn is not None:
             # Stats read the PRE-step state: Q/V at the position that
             # produced `tok` (state-before-token, matching rollout scoring).
@@ -259,6 +267,8 @@ def generate(
         final = jax.lax.while_loop(cond, body, state)
     if step_stats_fn is not None and counts_experts:
         final["stats"]["experts_touched_per_step"] = final["experts_touched"] / jnp.maximum(final["step"], 1)
+    if step_stats_fn is not None and "sparse_read" in final:
+        final["stats"]["sparse_keys_read_share"] = final["sparse_read"][0] / jnp.maximum(final["sparse_read"][1], 1.0)
     if step_stats_fn is not None and prefill_collect:
         return final["tokens"], final["mask"], final["stats"], prefill_extras
     if step_stats_fn is not None:

@@ -241,3 +241,37 @@ def kv_keys_read(
             width = np.array([hi - lo for lo, hi in kv_read_ranges(cache_len, window)])
             read += int(width[branch].sum())
     return read, full
+
+
+def take_blocks(cache, starts, block: int):
+    """`block` consecutive slots of `cache` [b, T, G, D] from each of `starts`
+    [b, G, n] (one list a row and K/V head, every slice inside the buffer), as
+    [b, G, n, block, D]."""
+    heads_first = jnp.swapaxes(cache, 1, 2)  # [b, G, T, D]
+    one_head = lambda rows, at: jax.vmap(lambda s: jax.lax.dynamic_slice_in_dim(rows, s, block, axis=0))(at)
+    return jax.vmap(jax.vmap(one_head))(heads_first, starts)
+
+
+def attend_selected(q, k_cache, v_cache, starts, keep, block: int, scale, dtype):
+    """A decode step's read of the key blocks it chose (models/sparse.py):
+    `q` [b, 1, h, d] over `block` slots from each of `starts` [b, G, n] of the
+    caches [b, T, G, d], gathered, never the whole cache. A start too near the
+    end of the buffer is moved back so that the slice fits; `keep(slots)` says
+    which of the slots [b, G, n, block] that were gathered the query may see
+    (its own blocks' filled slots): the caller masks by the slots it is handed,
+    never by the starts it asked for. Grouped keys as in `attend`: K/V head j
+    serves query heads [j * g, (j + 1) * g). Softmax in float32. Returns (out
+    [b, 1, h, d], the slots the softmax saw a row and K/V head, int32 [b, G]:
+    the count of the mask it applied)."""
+    b, _, h, d = q.shape
+    groups = k_cache.shape[2]
+    starts = jnp.clip(starts, 0, k_cache.shape[1] - block)
+    slots = starts[..., None] + jnp.arange(block, dtype=starts.dtype)
+    keys, values = take_blocks(k_cache, starts, block), take_blocks(v_cache, starts, block)
+    grouped = q.astype(dtype).reshape(b, groups, h // groups, d)
+    scores = jnp.einsum("bghd,bgnkd->bghnk", grouped, keys.astype(dtype), preferred_element_type=jnp.float32) * scale
+    kept = keep(slots)
+    scores = jnp.where(kept[:, :, None], scores, -1e9)
+    probs = jax.nn.softmax(scores.reshape(scores.shape[:3] + (-1,)), axis=-1).reshape(scores.shape).astype(dtype)
+    out = jnp.einsum("bghnk,bgnkd->bghd", probs, values.astype(dtype), preferred_element_type=jnp.float32)
+    return out.astype(dtype).reshape(b, 1, h, d), jnp.sum(kept, axis=(-1, -2), dtype=jnp.int32)

@@ -27,8 +27,8 @@ import jax
 import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
-from trlx_tpu.models.lm import (cache_bytes, cache_bytes_per_token, cca_state_bytes, decode_step_bytes, layer_window,
-                                ring_cache_bytes, ring_slots, state_bytes)
+from trlx_tpu.models.lm import (cache_bytes, cache_bytes_per_token, cca_state_bytes, compressed_key_bytes, decode_step_bytes,
+                                layer_window, ring_cache_bytes, ring_slots, state_bytes)
 from trlx_tpu.ops.kv_read import kv_keys_read, kv_scale_mults_per_key
 from trlx_tpu.parallel.schedule import weight_gather_share
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
@@ -269,6 +269,7 @@ class PPOOrchestrator(Orchestrator):
         layer_windows = [layer_window(lm_cfg, i) for i in key_layers]
         cache_alloc = gen_rows = gen_len = 0  # bytes of the cache the generate program allocated (the last chunk's), its rows and slots
         kv_keys = np.zeros(2, dtype=np.int64)
+        sparse_read = []  # attention "sparse": the decode steps' own count of the slots they read, one reading a chunk
         experts_touched = []  # a model with expert layers: one reading a chunk
         # Final-chunk stats for logging; placeholders are never logged (the
         # aborted path returns before the tracker call).
@@ -415,10 +416,19 @@ class PPOOrchestrator(Orchestrator):
                 gen_tokens += ds["gen_tokens"]
                 decode_steps.append(ds["decode_steps"])
                 cache_len = mask_h.shape[1] + n_soft
-                kv_keys += np.array(kv_keys_read(
-                    cache_len, P + n_soft, ds["decode_steps"], layer_windows,
-                    [ring_slots(lm_cfg, i, cache_len) for i in key_layers],
-                ))
+                if lm_cfg.attention == "sparse":
+                    from trlx_tpu.models import sparse
+
+                    # a decode step gathers a fixed count of slots (the static shape of ops/kv_read.py
+                    # attend_selected's gather), whatever the ranged read would have taken: from shapes, as
+                    # every cell's; what the step's softmax saw of them is the loop's own counter, below
+                    gathered = sparse.gathered_blocks(lm_cfg, -(-cache_len // lm_cfg.sparse_block)) * lm_cfg.sparse_block
+                    kv_keys += np.array([gathered, cache_len]) * ds["decode_steps"] * len(key_layers)
+                else:
+                    kv_keys += np.array(kv_keys_read(
+                        cache_len, P + n_soft, ds["decode_steps"], layer_windows,
+                        [ring_slots(lm_cfg, i, cache_len) for i in key_layers],
+                    ))
                 cache_alloc = cache_bytes(lm_cfg, mask_h.shape[0], cache_len)
                 gen_rows, gen_len = mask_h.shape[0], cache_len
                 episode_steps.extend(int(v) for v in ds["episode_steps"])
@@ -427,6 +437,8 @@ class PPOOrchestrator(Orchestrator):
                     # The loop's own counter (ops/generate.py), read after
                     # the rollout's grids: the program has finished.
                     experts_touched.append(float(gen_aux[0]["experts_touched_per_step"]))
+                if gen_aux is not None and "sparse_keys_read_share" in gen_aux[0]:
+                    sparse_read.append(float(gen_aux[0]["sparse_keys_read_share"]))
 
                 if getattr(rl, "has_reward_model", False):
                     # On-device learned RM: the whole scoring pass (policy
@@ -558,6 +570,10 @@ class PPOOrchestrator(Orchestrator):
                 # the cache over what keys and values 2 x d_model wide a token a layer would take
                 rl._last_exp_stats["rollout/cca_cache_share"] = cache_alloc / (
                     gen_rows * gen_len * lm_cfg.n_layer * 2 * lm_cfg.d_model * lm_cfg.compute_dtype.itemsize)
+            if lm_cfg.attention == "sparse" and cache_alloc:
+                rl._last_exp_stats["rollout/compressed_key_bytes"] = float(compressed_key_bytes(lm_cfg, gen_rows, gen_len))
+                if sparse_read:
+                    rl._last_exp_stats["rollout/sparse_keys_read_share"] = float(np.mean(sparse_read))
             if experts_touched:
                 rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
             if (lm_cfg.has_state or lm_cfg.n_loops > 1) and cache_alloc:
@@ -578,7 +594,7 @@ class PPOOrchestrator(Orchestrator):
                     rl._last_exp_stats.update({
                         "rollout/state_bytes": float(state_bytes(lm_cfg, gen_rows)),
                         "rollout/state_bytes_per_row": float(state_bytes(lm_cfg, 1)),
-                        ("ssm" if lm_cfg.has_ssm else "kda") + "/state_rw_share": float(state_rw / needed),
+                        ("ssm" if lm_cfg.has_ssm else lm_cfg.state_layer_name) + "/state_rw_share": float(state_rw / needed),
                     })
                 if lm_cfg.n_loops > 1:
                     rl._last_exp_stats.update({

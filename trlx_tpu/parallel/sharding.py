@@ -79,6 +79,13 @@ def lm_partition_rules() -> List[Tuple[str, P]]:
         (r"kda/(f_a_proj|g_a_proj|b_proj)/kernel$", P(AXIS_FSDP, None)),
         (r"kda/o_proj/kernel$", P(AXIS_TP, AXIS_FSDP)),
         (r"kda/(q_conv|k_conv|v_conv|dt_bias|A_log|o_norm)$", P()),
+        # lightning mixer (models/lightning.py) and attention "sparse"'s output
+        # gate: the head-wide projections as the dense kernels they are; the
+        # output norm whole
+        (r"lightning/(q_proj|k_proj|v_proj|g_proj)/kernel$", P(AXIS_FSDP, AXIS_TP)),
+        (r"lightning/o_proj/kernel$", P(AXIS_TP, AXIS_FSDP)),
+        (r"lightning/o_norm$", P()),
+        (r"attn/g_proj/kernel$", P(AXIS_FSDP, AXIS_TP)),
         # MLP up [d_model, d_ff] column-parallel
         (r"mlp/c_fc/kernel$", P(AXIS_FSDP, AXIS_TP)),
         (r"mlp/c_fc/bias$", P(AXIS_TP)),

@@ -112,10 +112,12 @@ class PPOTrainer(JaxBaseTrainer):
         # train-throughput metering for the phase window (satellite of the
         # fused-logprob head work; see make_ppo_train_step).
         self._pack_train_batch = bool(getattr(m, "pack_train_batch", False))
-        if self._pack_train_batch and (self.model.cfg.n_loops > 1 or self.model.cfg.has_kda):
+        if self._pack_train_batch and (self.model.cfg.n_loops > 1 or self.model.cfg.has_kda or self.model.cfg.has_lightning
+                                       or self.model.cfg.attention == "sparse"):
             raise NotImplementedError(
-                "method.pack_train_batch (packed segments) is not built for a looped stack (n_loops > 1) or a kda "
-                "layer (a state crosses a segment's edge)")
+                "method.pack_train_batch (packed segments) is not built for a looped stack (n_loops > 1), a kda layer or a "
+                "lightning layer (a state crosses a segment's edge) or attention 'sparse' (its block grid starts at a "
+                "row's first token)")
         # put_batch shards the leading dim over DATA_AXES — packed row-count
         # buckets must round up to a multiple of that axis product.
         self._pack_rows_multiple = int(np.prod([self.mesh.shape[a] for a in DATA_AXES]))
@@ -252,7 +254,7 @@ class PPOTrainer(JaxBaseTrainer):
                 raise ValueError(
                     "model.decode_weight_quant covers the GPT block's kernels only "
                     "(models/lm.py QUANT_KERNEL_NAMES): it is not built for attention "
-                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r}, expert layers, state-space layers, kda layers or a "
+                    f"{lm_cfg.attention!r}, mlp {lm_cfg.mlp!r}, expert layers, state-space layers, lightning layers, kda layers or a "
                     "looped stack (n_loops > 1)"
                 )
 
@@ -1179,7 +1181,8 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         gate would leave at, mean over the response positions `exit_mask`
         (`policy/expected_exit_loop`, sum over r of r p_r, no gradient). A
         pass through the flash kernels: the live key chunks the batch's own
-        padding `attention_mask` takes out (`flash/pad_dead_chunk_share`)."""
+        padding `attention_mask` takes out (`flash/pad_dead_chunk_share`).
+        Attention "sparse": `sparse/kept_pair_share`, `sparse/chosen_blocks_mean`."""
         share = None if attention_mask is None else flash_pad_dead_chunk_share(model.cfg, attention_mask)
         if share is not None:
             loss, stats = result
@@ -1190,6 +1193,13 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
             weight = exit_mask.astype(p.dtype)
             loss, stats = result
             result = loss, {**stats, "policy/expected_exit_loop": jnp.sum(loops * weight) / jnp.maximum(jnp.sum(weight), 1.0)}
+        if out.get("sparse_sums") is not None:
+            # attention "sparse": of the causal pairs, those in blocks the queries chose, and the blocks a
+            # query's group chose, both from the choice the pass made (models/sparse.py), no gradient
+            kept, causal, blocks, queries = jax.lax.stop_gradient(out["sparse_sums"])
+            loss, stats = result
+            result = loss, {**stats, "sparse/kept_pair_share": kept / jnp.maximum(causal, 1.0),
+                            "sparse/chosen_blocks_mean": blocks / jnp.maximum(queries, 1.0)}
         if out["expert_counts"] is None:
             return result
         from trlx_tpu.models.moe import expert_load_stats, first_buffer_share
