@@ -295,6 +295,98 @@ def test_the_choice_carries_no_gradient():
         np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-4)
 
 
+# ---- the many-token pass in spans: each against the keys up to its own end, the choice made once ----
+
+
+@pytest.mark.parametrize("pad", [13, 63], ids=["padded by 13", "padded by 63"])
+@pytest.mark.parametrize("spans", [2, 3, sparse.SPANS], ids=lambda n: f"{n} spans")
+def test_the_spanned_pass_is_the_one_span_pass(monkeypatch, spans, pad):
+    """`sparse_attention` over [2, 100] in chunks of one block (13 of them, Tp = 104 > T: a last span shorter than
+    the others at every count; the first spans hold at most topk blocks and choose every started one), row 1
+    left-padded by a count that neither the stride nor the block divides: the output and its gradients in q, k and
+    v as with ONE span (every chunk against all keys), compressed keys and the four sums exactly."""
+    cfg, length = LMConfig.from_dict({**ARCH, **F32}), 100
+    monkeypatch.setattr(sparse, "SCORE_BYTES", 1)
+    assert sparse.query_chunk(cfg, B, length, 4) == 8
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v = (jax.random.normal(key, (B, length, heads, 8)) for key, heads in zip(keys, (4, 2, 2)))
+    weight = jax.random.normal(keys[3], (B, length, 4, 8))
+    mask = jnp.ones((B, length), jnp.int32).at[1, :pad].set(0)
+
+    def run(n):
+        monkeypatch.setattr(sparse, "SPANS", n)
+        edges = sparse.span_edges(13)
+        loss = lambda q, k, v: jnp.sum(sparse.sparse_attention(q, k, v, mask, cfg, jnp.float32)[0] * weight)
+        return edges, sparse.sparse_attention(q, k, v, mask, cfg, jnp.float32), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    edges, (out, kc, sums), grads = run(spans)
+    one, (want, want_kc, want_sums), want_grads = run(1)
+    assert one == ((0, 13),) and len(edges) == -(-13 // -(-13 // spans)) > 1
+    assert edges[0][0] == 0 and edges[-1][1] == 13 and all(a[1] == b[0] for a, b in zip(edges, edges[1:]))
+    assert edges[-1][1] - edges[-1][0] < edges[0][1] - edges[0][0]  # the last span is the short one
+    np.testing.assert_allclose(out, want, atol=1e-6, rtol=0)
+    for g, w in zip(grads, want_grads):
+        assert float(jnp.abs(w).max()) > 0.1
+        np.testing.assert_allclose(g, w, atol=1e-6, rtol=0)
+    np.testing.assert_array_equal(kc, want_kc)
+    assert len(sums) == 4 and [float(x) for x in sums] == [float(x) for x in want_sums]
+    assert 0 < float(sums[0]) < float(sums[1])  # the choice bit
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _dots(jaxpr):
+    """(widest operand axis, multiply-adds) of every `dot_general` under `jaxpr`."""
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "dot_general":
+            (contract, _), (lhs, rhs) = eqn.params["dimension_numbers"][0], (v.aval.shape for v in eqn.invars)
+            yield max(lhs + rhs), int(np.prod(eqn.outvars[0].aval.shape)) * int(np.prod([lhs[i] for i in contract]))
+
+
+def test_the_train_pass_stops_at_each_spans_causal_extent_and_chooses_once():
+    """The cell's train shape ([1, 12288], 32 heads over 2 of 128; abstract, nothing runs). Forward: a span's two
+    loops (the choice, the attention) hold no product with an axis past the span's keys, the attention's products
+    are `computed_pairs` and at most 0.65 of the one-span pass's. Differentiated under the block's remat policy:
+    one `top_k` a span that chooses (three before: forward, the block's recomputation, the chunk's backward)."""
+    arch = {**SALA["model_arch"], "dtype": "bfloat16", "param_dtype": "bfloat16", "n_layer": 2, "mixer_layers": ["lightning", "attention"]}
+    cfg = LMConfig.from_dict({**arch, "remat": True})
+    b, length, H, G, D = 1, 12288, cfg.n_head, cfg.kv_heads, cfg.head_dim
+    chunk = sparse.query_chunk(cfg, b, length, H)
+    edges = sparse.span_edges(length // chunk)
+    assert (chunk, len(edges), H, G, D) == (256, sparse.SPANS, 32, 2, 128)
+    a = lambda heads: jax.ShapeDtypeStruct((b, length, heads, D), jnp.bfloat16)
+    forward = jax.make_jaxpr(lambda q, k, v, mask: sparse.sparse_attention(q, k, v, mask, cfg, jnp.bfloat16))(
+        a(H), a(G), a(G), jax.ShapeDtypeStruct((b, length), jnp.int32)).jaxpr
+    loops = [list(_dots(eqn.params["jaxpr"].jaxpr)) for eqn in forward.eqns if eqn.primitive.name == "scan"]
+    assert len(loops) == 2 * len(edges)
+    pairs = 0
+    for (lo, hi), select, attend in zip(edges, loops[::2], loops[1::2]):
+        extent = hi * chunk
+        chooses = extent // cfg.sparse_block > cfg.sparse_topk
+        assert [width for width, _ in select] == [sparse.compressed_slots(cfg, extent)] * chooses
+        assert [width for width, _ in attend] == [extent, extent]  # the scores, the values: no key past the span's end
+        pairs += (hi - lo) * sum(work for _, work in attend)
+    computed = sparse.computed_pairs(cfg, b, length, H)
+    assert pairs == 2 * computed * (H // G) * D  # two products a pair, each over a group's heads and D
+    assert computed == 9 * b * G * length**2 // 16 <= 0.65 * b * G * length**2
+    names = [eqn.primitive.name for eqn in _eqns(forward)]
+    assert names.count("top_k") == sum(hi * chunk // cfg.sparse_block > cfg.sparse_topk for _, hi in edges) == 6
+    assert "pallas_call" not in names
+
+    model = TransformerLM(cfg)
+    ids = jnp.zeros((b, 4), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids, jnp.ones_like(ids))["params"])
+    loss = lambda p, ids, mask: model.apply({"params": p}, ids, mask, compute_logits=False)["hidden"].astype(jnp.float32).sum()
+    tokens = jax.ShapeDtypeStruct((b, length), jnp.int32)
+    names = [eqn.primitive.name for eqn in _eqns(jax.make_jaxpr(jax.grad(loss))(params, tokens, tokens).jaxpr)]
+    assert names.count("top_k") == 6 and "pallas_call" not in names
+
+
 # ---- the frozen branch, the counts, the configuration ----------------------------------------------
 
 
@@ -521,6 +613,9 @@ def test_ppo_two_iterations_on_the_normal_path(tmp_path):
         assert abs(steps[first]["mean_ratio"] - 1.0) < 1e-3, steps[first]["mean_ratio"]
     for r in steps.values():
         assert 0.5 < r["sparse/kept_pair_share"] < 1.0 and 3.0 < r["sparse/chosen_blocks_mean"] <= 6.0
+        # one sparse layer over [8, 80]: two chunks of 64 in two spans, 64 x 64 + 64 x 128 pairs a row and K/V head,
+        # over the causal pairs of rows of 54-80 real tokens
+        assert 12288 / 3240 <= r["sparse/computed_pair_share"] <= 12288 / 1485
         assert "flash/kept_pair_share" not in r
     phases = [r for r in records if "time/window_wall_s" in r and "rollout/state_bytes" in r]
     assert phases

@@ -53,6 +53,8 @@ ACTIVATIONS = {
 ARCH_KEYS_READ_ELSEWHERE = frozenset({"eos_token_id"})
 # models/kda.py names the output of its chunked pass so; a remat'd block keeps what bears the name
 KDA_SCAN_OUT = "kda_scan_out"
+# models/sparse.py names the blocks its many-token pass chose so; a remat'd block keeps them likewise
+SPARSE_CHOSEN = "sparse_chosen"
 
 
 @dataclass(frozen=True)
@@ -1847,8 +1849,12 @@ class TransformerLM(nn.Module):
             # recomputation need not run it a third time.
             # A looped stack likewise: 48 applications of 12 blocks kept every
             # gate and up projection alive, 19.5 GB (PERF.md §6, PR 37).
-            if cfg.has_kda:
-                kept = jax.checkpoint_policies.save_only_these_names(KDA_SCAN_OUT)
+            # A block-selected sparse layer keeps the blocks its queries chose (a
+            # few MB of bool): the choice carries no gradient, so the block's
+            # recomputation need not make it again (models/sparse.py).
+            named = [name for name, held in ((KDA_SCAN_OUT, cfg.has_kda), (SPARSE_CHOSEN, cfg.attention == "sparse")) if held]
+            if named:
+                kept = jax.checkpoint_policies.save_only_these_names(*named)
                 policy = kept if policy is None else jax.checkpoint_policies.save_from_both_policies(policy, kept)
             block_cls = nn.remat(
                 Block, prevent_cse=partitioned() or cfg.has_state or cfg.n_loops > 1, static_argnums=(7, 8), policy=policy

@@ -33,10 +33,18 @@ short, so none would measure a second one).
 
 *Many tokens* (`sparse_attention`: the train step, scoring, the prefill): the
 rows are rolled into their own grid (token r at index r), the compressed keys
-made once, and steps 3-6 run a chunk of queries at a time under `lax.map`,
-each chunk recomputed in its own backward pass (`jax.checkpoint`), the chosen
-set applied to the scores as a mask: every pair is computed, which is correct
-and not yet sparse in arithmetic. *One token* (a decode step): the scores
+made once, and the query chunks cut into at most SPANS consecutive spans. A
+span's queries see no key at or past its end, so its keys, values, compressed
+keys and blocks are static slices up to there and every array of its chunks is
+that wide: with S equal spans (S + 1) / 2S of the chunks-by-all-keys pairs,
+9/16 at 8. A span runs two `lax.map`s over its chunks: steps 3-5, which give
+the chosen sets (no gradient; a remat'd block keeps them by name, so a train
+step chooses once), then step 6, each chunk recomputed in its own backward
+pass (`jax.checkpoint`) with its chosen sets as an argument, applied to the
+scores as a mask. Within a span every pair up to its end is computed: what is
+masked there (the triangle above the diagonal inside the span, the unchosen
+fifth of the causal pairs) is still arithmetic; `computed_pairs` counts it.
+*One token* (a decode step): the scores
 over the cache's compressed keys, the choice, a gather of the chosen blocks
 (`ops/kv_read.py attend_selected`): sparse in bytes. The step counts the
 slots its softmax saw (`attend_selected`'s own mask) over the slots its rows
@@ -53,12 +61,15 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
-from trlx_tpu.models.lm import LMConfig, QDense, qk_normed, write_cache
+from trlx_tpu.models.lm import SPARSE_CHOSEN, LMConfig, QDense, qk_normed, write_cache
 from trlx_tpu.ops.kv_read import attend_selected
 
 # Bytes of one query chunk's float32 scores [b, H, chunk, T] in the many-token pass
 SCORE_BYTES = 1 << 29
+# Spans the many-token pass cuts its query chunks into, each against the keys up to its own end (`span_edges`)
+SPANS = 8
 
 
 def dense_blocks(cfg: LMConfig) -> int:
@@ -106,6 +117,10 @@ def choose_blocks(q, kc, t, cfg: LMConfig, n_blocks: int):
     b, Q, H, D = q.shape
     J, G = kc.shape[1], kc.shape[2]
     stride, kernel, block = cfg.sparse_stride, cfg.sparse_kernel, cfg.sparse_block
+    blk, at = jnp.arange(n_blocks), t[:, None, :, None]
+    started = blk * block <= at
+    if n_blocks <= cfg.sparse_topk:  # every block that has started, whatever the scores say
+        return jnp.broadcast_to(started, (b, G, Q, n_blocks))
     q, kc = jax.lax.stop_gradient(q), jax.lax.stop_gradient(kc)
     scores = jnp.einsum("bqghd,bjgd->bghqj", q.reshape(b, Q, G, H // G, D), kc, preferred_element_type=f32) * D ** -0.5
     exists = ((stride * jnp.arange(J) + kernel - 1)[None, None, :] <= t[:, :, None])[:, None, None]  # [b, 1, 1, Q, J]
@@ -117,12 +132,8 @@ def choose_blocks(q, kc, t, cfg: LMConfig, n_blocks: int):
     need = ratio * n_blocks + pieces - 1
     a = jnp.pad(a, ((0, 0), (0, 0), (0, 0), (pieces - 1, max(0, need - (pieces - 1) - J))))[..., :need]
     pooled = jax.lax.reduce_window(a, -jnp.inf, jax.lax.max, (1, 1, 1, ratio + pieces - 1), (1, 1, 1, ratio), "VALID")
-    blk, at = jnp.arange(n_blocks), t[:, None, :, None]
-    started = blk * block <= at
     forced = started & ((blk < cfg.sparse_init_blocks) | ((blk + 1) * block - 1 >= at - cfg.sparse_window + 1))
     others = started & ~forced
-    if n_blocks <= cfg.sparse_topk:  # every block that has started, whatever the scores say
-        return jnp.broadcast_to(started, (b, G, Q, n_blocks))
     # two neighbouring blocks share the compressed key between them, so equal scores are common: of equal
     # blocks the earlier one is chosen (`lax.top_k` puts the lower index first), by index and not by a threshold
     _, best = jax.lax.top_k(jnp.where(others, pooled, -1.0), cfg.sparse_topk)  # [b, G, Q, topk]
@@ -135,6 +146,21 @@ def query_chunk(cfg: LMConfig, b: int, T: int, H: int) -> int:
     while chunk * 2 <= min(T, 512) and b * H * chunk * 2 * T * 4 <= SCORE_BYTES:
         chunk *= 2
     return chunk
+
+
+def span_edges(n_chunks: int):
+    """((first chunk, past the last), ...) of the many-token pass's spans: at most SPANS runs of equally many whole
+    chunks (the last may be shorter), a function of the pass's shapes alone."""
+    per = -(-n_chunks // SPANS)
+    return tuple((lo, min(lo + per, n_chunks)) for lo in range(0, n_chunks, per))
+
+
+def computed_pairs(cfg: LMConfig, b: int, T: int, H: int) -> int:
+    """Query/key pairs a K/V head the many-token pass computes over [b, T], summed over its G heads: every chunk
+    of a span against the keys up to the span's end (against the causal pairs `sparse_attention` sums: 1.0 would
+    be none above the diagonal)."""
+    chunk = query_chunk(cfg, b, T, H)
+    return sum((hi - lo) * chunk * hi * chunk for lo, hi in span_edges(-(-T // chunk))) * cfg.kv_heads * b
 
 
 def sparse_attention(q, k, v, mask, cfg: LMConfig, dtype):
@@ -152,16 +178,18 @@ def sparse_attention(q, k, v, mask, cfg: LMConfig, dtype):
     q, k, v = grid(q), grid(k), grid(v)
     with jax.named_scope("sparse_select"):
         kc = compress_keys(k, cfg)
-    n_blocks, scale = Tp // block, D ** -0.5
-    keys = jnp.arange(Tp)
+    scale = D ** -0.5
+    positions = lambda start: start + jnp.arange(chunk, dtype=jnp.int32)
 
-    def one_chunk(q_c, start, k, v, kc):
-        t = start + jnp.arange(chunk, dtype=jnp.int32)
+    def select(q_c, start, kc, n_blocks):
         with jax.named_scope("sparse_select"):
-            chosen = choose_blocks(q_c, kc, jnp.broadcast_to(t, (b, chunk)), cfg, n_blocks)  # [b, G, chunk, n_blocks]
+            return choose_blocks(q_c, kc, jnp.broadcast_to(positions(start), (b, chunk)), cfg, n_blocks)
+
+    def attend(q_c, start, chosen, k, v):
+        t, keys = positions(start), jnp.arange(k.shape[1])
         with jax.named_scope("sparse_attn"):
-            seen = (keys[None, None, :] <= t[None, :, None]) & (keys[None, None, :] < n_real[:, None, None])  # [b, chunk, Tp]
-            kept = seen[:, None] & jnp.repeat(chosen, block, axis=-1)  # [b, G, chunk, Tp]
+            seen = (keys[None, None, :] <= t[None, :, None]) & (keys[None, None, :] < n_real[:, None, None])  # [b, chunk, extent]
+            kept = seen[:, None] & jnp.repeat(chosen, block, axis=-1)  # [b, G, chunk, extent]
             scores = jnp.einsum("bqghd,bkgd->bghqk", q_c.reshape(b, chunk, G, H // G, D), k, preferred_element_type=f32)
             probs = jax.nn.softmax(jnp.where(kept[:, :, None], scores * scale, -1e9), axis=-1).astype(dtype)
             out = jnp.einsum("bghqk,bkgd->bqghd", probs, v, preferred_element_type=f32).astype(dtype)
@@ -172,9 +200,21 @@ def sparse_attention(q, k, v, mask, cfg: LMConfig, dtype):
 
     chunks = jnp.moveaxis(q.reshape(b, Tp // chunk, chunk, H, D), 1, 0)
     starts = jnp.arange(Tp // chunk, dtype=jnp.int32) * chunk
-    out, stats = jax.lax.map(lambda xs: jax.checkpoint(one_chunk)(*xs, k, v, kc), (chunks, starts))
-    out = jnp.moveaxis(out, 0, 1).reshape(b, Tp, H, D)[:, :T]
-    return align_rows(out, -first), kc, tuple(jnp.sum(s) for s in stats)
+    outs, sums = [], []
+    for lo, hi in span_edges(Tp // chunk):
+        # a span's queries see no key at or past its end: every array of its chunks stops there, by static slices
+        extent = hi * chunk
+        span = (chunks[lo:hi], starts[lo:hi])
+        k_s, v_s, kc_s = k[:, :extent], v[:, :extent], kc[:, :compressed_slots(cfg, extent)]
+        # the choice carries no gradient: made once, ahead of the chunks that are recomputed in their backward,
+        # and kept by a remat'd block (SPARSE_CHOSEN), so a train step chooses once and not three times
+        chosen = jax.lax.map(lambda xs: select(*xs, kc_s, extent // block), span)  # [chunks, b, G, chunk, extent / block]
+        chosen = checkpoint_name(chosen, SPARSE_CHOSEN)
+        out, stats = jax.lax.map(lambda xs: jax.checkpoint(attend)(*xs, k_s, v_s), (*span, chosen))
+        outs.append(out)
+        sums.append(stats)
+    out = jnp.moveaxis(jnp.concatenate(outs), 0, 1).reshape(b, Tp, H, D)[:, :T]
+    return align_rows(out, -first), kc, tuple(jnp.sum(jnp.concatenate(s)) for s in zip(*sums))
 
 
 class SparseAttention(nn.Module):

@@ -18,7 +18,7 @@ import optax
 from trlx_tpu.data import PackedPPOBatch, PPORLBatch
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.fleet import FleetDegradedExit, validate_fleet_config
-from trlx_tpu.models import kda
+from trlx_tpu.models import kda, sparse
 from trlx_tpu.models.heads import LMWithValueHead, branch_replay_params, extract_branch_params
 from trlx_tpu.models.lm import flash_pad_dead_chunk_share
 from trlx_tpu.models.ssm import lane_fill
@@ -1182,7 +1182,8 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         (`policy/expected_exit_loop`, sum over r of r p_r, no gradient). A
         pass through the flash kernels: the live key chunks the batch's own
         padding `attention_mask` takes out (`flash/pad_dead_chunk_share`).
-        Attention "sparse": `sparse/kept_pair_share`, `sparse/chosen_blocks_mean`."""
+        Attention "sparse": `sparse/kept_pair_share`, `sparse/chosen_blocks_mean`,
+        `sparse/computed_pair_share`."""
         share = None if attention_mask is None else flash_pad_dead_chunk_share(model.cfg, attention_mask)
         if share is not None:
             loss, stats = result
@@ -1197,9 +1198,14 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
             # attention "sparse": of the causal pairs, those in blocks the queries chose, and the blocks a
             # query's group chose, both from the choice the pass made (models/sparse.py), no gradient
             kept, causal, blocks, queries = jax.lax.stop_gradient(out["sparse_sums"])
+            # and the pairs the many-token pass computed for them, a fact of its shapes (1.0: none above the diagonal)
+            cfg = model.cfg
+            layers = sum(cfg.mixer(i) == "attention" for i in range(cfg.n_layer))
+            computed = layers * sparse.computed_pairs(cfg, *attention_mask.shape, cfg.n_head)
             loss, stats = result
             result = loss, {**stats, "sparse/kept_pair_share": kept / jnp.maximum(causal, 1.0),
-                            "sparse/chosen_blocks_mean": blocks / jnp.maximum(queries, 1.0)}
+                            "sparse/chosen_blocks_mean": blocks / jnp.maximum(queries, 1.0),
+                            "sparse/computed_pair_share": computed / jnp.maximum(causal, 1.0)}
         if out["expert_counts"] is None:
             return result
         from trlx_tpu.models.moe import expert_load_stats, first_buffer_share
