@@ -418,6 +418,51 @@ def test_a_lightning_and_a_sparse_layer_s_three_passes_compile_for_v5e(v5e_shard
     assert k.shape == v.shape == (4, 12288, 2, 128) and compressed.shape == (4, 767, 2, 128)
 
 
+def test_an_indexed_latent_layer_s_three_passes_compile_for_v5e(v5e_sharding):
+    """One dense block of glm5-l5.ppo-6144x2048 at its widths (16 heads of 192 + 64 / 256, the
+    indexer's 32 x 128, top-2048), bf16, under remat: the train pass (forward and backward over the train batch
+    [1, 8192]: the index scores, the k-th largest by counts and the masked unabsorbed attention a query chunk at a
+    time), the prefill of 4 x 6,144 into the three-leaf cache, and the decode step that scores the row's index keys,
+    takes the top-2,048 and gathers the chosen latent entries."""
+    import json
+    import os
+
+    from trlx_tpu.models.lm import LMConfig, TransformerLM, init_cache
+
+    spec = json.load(open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs", "glm-5-ep32-tp4-l5.json")))
+    cfg = LMConfig.from_dict({**spec["model_arch"], "dtype": "bfloat16", "param_dtype": "bfloat16", "remat": True,
+                              "n_layer": 1, "ffn_layers": ["dense"]})  # the expert layer compiles in its own cells' tests
+    model = TransformerLM(cfg)
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_sharding)
+    ids = jnp.zeros((1, 4), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids, jnp.ones_like(ids))["params"])
+    params = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), params)
+
+    def train_step(p, ids, mask):
+        def loss(p):
+            out = model.apply({"params": p}, ids, mask, compute_logits=False)
+            return out["hidden"].astype(jnp.float32).sum(), out["dsa_sums"]
+        return jax.value_and_grad(loss, has_aux=True)(p)
+
+    train = jax.jit(train_step).lower(params, s((1, 8192), jnp.int32), s((1, 8192), jnp.int32)).compile()
+    assert train.memory_analysis().temp_size_in_bytes < 4e9
+    # the softmax's row maximum stays a reduction: fused with its subtraction the compiler made ONE reduce-window
+    # 16,383 wide over each [16, 512, 8192] score chunk, 23.6 ms a chunk on the chip (PERF.md section 6, PR 53)
+    assert "size=1x1x16383" not in train.as_text()
+    assert "flash_attention" not in train.as_text()  # past index_topk tokens an indexed layer takes no flash kernel
+    cache = jax.tree_util.tree_map(lambda a: s(a.shape, a.dtype), jax.eval_shape(lambda: init_cache(cfg, 4, 8192)))
+    prefill = lambda p, ids, mask, cache, cache_mask: model.apply(
+        {"params": p}, ids, mask, cache=cache, cache_index=0, cache_mask=cache_mask, logits_start=6143)
+    jax.jit(prefill).lower(params, s((4, 6144), jnp.int32), s((4, 6144), jnp.int32), cache, s((4, 8192), jnp.int32)).compile()
+    step = lambda p, ids, cache, index, cache_mask: model.apply(
+        {"params": p}, ids, jnp.ones((4, 1), jnp.int32), cache=cache, cache_index=index, cache_mask=cache_mask)
+    args = (params, s((4, 1), jnp.int32), cache, s((), jnp.int32), s((4, 8192), jnp.int32))
+    compiled = jax.jit(step).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    for c_kv, k_rope, k_idx in jax.eval_shape(step, *args)["cache"]:
+        assert (c_kv.shape, k_rope.shape, k_idx.shape) == ((4, 8192, 512), (4, 8192, 64), (4, 8192, 128))
+
+
 def test_the_delta_rule_pass_does_not_hand_its_inverse_to_autodiff(v5e_sharding):
     """One row of `kda_chunked` at Kimi-Linear's train shapes ([1, 1024], 32
     heads of 128, bf16 operands, chunks of 64: what one step of the mixer's

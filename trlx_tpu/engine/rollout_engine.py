@@ -151,6 +151,11 @@ class RolloutEngine:
                 "compressed keys lie in each row's own grid from the row's first slot and a decode step completes one every "
                 "sparse_stride tokens, which admission, suffix prefill and a block table do not carry, and a rejected draft "
                 "needs a snapshot of them to roll back to")
+        if model.cfg.index_topk:
+            raise NotImplementedError(
+                "the rollout engine (and with it the paged pool and spec decode) is not built for an indexed latent layer "
+                "(index_topk): its queries choose their keys by slot under one causal edge for the whole batch, which per-slot "
+                "offsets, suffix prefill and a block table do not give, and a rejected draft's index keys would have to be rolled back")
         if model.cfg.n_loops > 1:
             raise NotImplementedError(
                 "the rollout engine (and with it the paged pool and spec decode) is not built for a looped stack "

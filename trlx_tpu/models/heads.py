@@ -114,6 +114,7 @@ class LMWithValueHead(nn.Module):
             "router_top_weight": out["router_top_weight"],
             "sparse_sums": out["sparse_sums"],
             "sparse_read": out["sparse_read"],
+            "dsa_sums": out["dsa_sums"],
             "exit_probs": out["exit_probs"],
             "logprobs": out["logprobs"],
             "lse": out["lse"],
@@ -291,7 +292,10 @@ def trainable_mask(params: dict, cfg: LMConfig, num_layers_unfrozen: int) -> dic
     and ln_f stay trainable, exactly like the reference (which freezes only
     entries of `hidden_layers`). k <= 0 → everything trains. Blocks are
     found by name (`h_<i>`), whatever kind each is; an expert layer's router
-    correction bias is a buffer and never trains (models/moe.py). A looped
+    correction bias is a buffer and never trains (models/moe.py). A latent
+    layer's indexer (models/indexer.py) never trains: its choice carries no
+    gradient and the PPO loss has no term for it, so AdamW's decay alone would
+    shrink it. A looped
     stack's exit gate never trains either: at exit_threshold 1 it decides
     nothing and has no gradient, and AdamW's decay alone would shrink it.
     """
@@ -302,7 +306,7 @@ def trainable_mask(params: dict, cfg: LMConfig, num_layers_unfrozen: int) -> dic
 
     def mask(path, _leaf):
         keys = [str(getattr(k, "key", k)) for k in path]
-        if keys[-1] == BIAS_NAME or "exit_gate" in keys:
+        if keys[-1] == BIAS_NAME or "exit_gate" in keys or "indexer" in keys:
             return False
         if "transformer" in keys and any(fb in keys for fb in frozen_blocks):
             return False

@@ -44,6 +44,10 @@ def validate_exportable(cfg: LMConfig, family: str):
             "'none', a looped stack (n_loops > 1), sandwich norms "
             f"or the embedding / residual / attention / logits multipliers: HF {family} has none of them, and "
             "the checkpoint would compute another model (models/hf_import.py reads granitemoehybrid and kimi_linear; nothing writes them)")
+    if cfg.index_topk:
+        raise ValueError(
+            "export to an HF checkpoint is not built for the glm_moe_dsa family (an indexer on latent attention, index_topk): "
+            f"HF {family} has no such layer, and the family's tensor names are not known here")
     if cfg.router_scoring == "softmax" or cfg.router_input == "block":  # expert layers only: LMConfig holds them to that
         raise ValueError(
             "export to an HF checkpoint is not built for the smallthinker family (a softmax router over the chosen logits, "

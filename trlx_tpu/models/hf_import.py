@@ -24,7 +24,8 @@ unrotated latent-attention layers, sparse experts: models/kda.py, models/moe.py)
 config only: its weights are not imported (`load_hf_trunk` raises); so does `smallthinker` (grouped keys, windows beside
 NoPE layers, a softmax router ahead of attention over ReGLU experts) and `zaya` (attention behind two causal convolutions,
 one expert a token by an MLP router with a carried state, learned residual scaling) and `minicpm_sala` (constant-decay
-linear-attention layers beside block-selected sparse attention, the MiniCPM scale constants). With no checkpoint (or `model_arch` given) params
+linear-attention layers beside block-selected sparse attention, the MiniCPM scale constants) and `glm_moe_dsa` (latent
+attention under a learned indexer, models/indexer.py, sigmoid-routed experts behind leading dense layers). With no checkpoint (or `model_arch` given) params
 initialize from scratch — the randomwalks path
 (reference: examples/randomwalks.py:99-101).
 """
@@ -464,6 +465,66 @@ def lm_config_from_hf(hf, **overrides) -> LMConfig:
             residual_multiplier=float(given.get("scale_depth", 1.0)) / float(np.sqrt(n_layer)),
             logits_scaling=float(given["hidden_size"]) / float(given.get("dim_model_base", given["hidden_size"])),
         )
+    elif t == "glm_moe_dsa":
+        # GLM-5: DeepSeek-V3's latent attention (a query bottleneck, one shared
+        # rotary key, interleaved pairs, no rope scaling) and expert layer (a
+        # sigmoid router with a correction bias over one group, one shared
+        # expert, first_k_dense_replace leading dense layers) under DeepSeek
+        # Sparse Attention's indexer (models/indexer.py): every size of it is a
+        # key of config.json. The MTP block (num_nextn_predict_layers) is not
+        # built and not taken: the trunk's own head gives every log-prob PPO
+        # reads. What the program lacks raises here, by name.
+        given = hf.to_dict() if hasattr(hf, "to_dict") else dict(vars(hf))
+        rope = dict(given.get("rope_parameters") or {})
+        n_layer = given["num_hidden_layers"]
+        unbuilt = [name for name, on in (
+            (f"scoring_func {given.get('scoring_func')!r}", given.get("scoring_func") != "sigmoid"),
+            (f"topk_method {given.get('topk_method')!r}", given.get("topk_method") != "noaux_tc"),
+            ("norm_topk_prob false", not given.get("norm_topk_prob")),
+            ("n_group / topk_group other than 1", (given.get("n_group", 1), given.get("topk_group", 1)) != (1, 1)),
+            ("moe_layer_freq other than 1", given.get("moe_layer_freq", 1) != 1),
+            ("attention_bias", given.get("attention_bias")),
+            (f"rope_type {rope.get('rope_type')!r}", rope.get("rope_type", "default") != "default"),
+            ("rope_interleave / indexer_rope_interleave false (rotate-half pairs)",
+             not given.get("rope_interleave", True) or not given.get("indexer_rope_interleave", True)),
+            ("grouped keys (num_key_value_heads != num_attention_heads)",
+             given.get("num_key_value_heads", given["num_attention_heads"]) != given["num_attention_heads"]),
+            ("no query bottleneck (q_lora_rank null)", not given.get("q_lora_rank")),
+            (f"hidden_act {given.get('hidden_act')!r}", given.get("hidden_act") != "silu")) if on]
+        if unbuilt:
+            raise ValueError(f"glm_moe_dsa: not built: {'; '.join(unbuilt)}")
+        dense_first = int(given.get("first_k_dense_replace", 0))
+        d = dict(
+            vocab_size=given["vocab_size"],
+            n_layer=n_layer,
+            n_head=given["num_attention_heads"],
+            d_model=given["hidden_size"],
+            d_ff=given["intermediate_size"],
+            max_position=given["max_position_embeddings"],
+            pos_type="rotary",
+            rope_theta=float(rope.get("rope_theta", given.get("rope_theta", 10000.0))),
+            norm="rmsnorm",
+            mlp="gated",
+            attention="mla",
+            activation="silu",
+            ln_eps=given["rms_norm_eps"],
+            parallel_residual=False,
+            tie_word_embeddings=bool(given.get("tie_word_embeddings", False)),
+            ffn_layers=tuple("dense" if i < dense_first else "experts" for i in range(n_layer)),
+            q_lora_rank=given["q_lora_rank"],
+            kv_lora_rank=given["kv_lora_rank"],
+            qk_nope_head_dim=given["qk_nope_head_dim"],
+            qk_rope_head_dim=given["qk_rope_head_dim"],
+            v_head_dim=given["v_head_dim"],
+            index_n_heads=given["index_n_heads"],
+            index_head_dim=given["index_head_dim"],
+            index_topk=given["index_topk"],
+            n_experts=given["n_routed_experts"],
+            experts_per_token=given["num_experts_per_tok"],
+            expert_d_ff=given["moe_intermediate_size"],
+            n_shared_experts=given.get("n_shared_experts", 0),
+            routed_scaling_factor=float(given.get("routed_scaling_factor", 1.0)),
+        )
     else:
         raise ValueError(f"unsupported HF model_type for conversion: {t}")
     d.update(overrides)
@@ -645,6 +706,11 @@ def load_hf_trunk(model_path: str, cfg: LMConfig, put=None) -> Dict[str, Any]:
             "importing a minicpm_sala checkpoint's weights is not built: the names of the family's tensors could not be "
             "read without the network, and a guessed mapping would load another model; `model_arch` (weights from the "
             "seed) is the path that runs")
+    if cfg.index_topk:
+        raise NotImplementedError(
+            "importing a glm_moe_dsa checkpoint's weights is not built: the names of the family's tensors (the indexer's "
+            "among them) could not be read without the network, and a guessed mapping would load another model; "
+            "`model_arch` (weights from the seed) is the path that runs")
     if cfg.n_loops > 1:
         raise NotImplementedError(
             "importing a looped checkpoint's weights is not built: the names of the family's tensors (the two "

@@ -190,6 +190,22 @@ class LMConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # A learned indexer on every latent-attention layer (models/indexer.py,
+    # DeepSeek Sparse Attention): index_n_heads query heads of index_head_dim
+    # from the query latent against ONE index key a token (a LayerNorm'd
+    # projection of the block's normed input, its first qk_rope_head_dim
+    # channels rotated), ReLU'd, weighted a head and summed; a query attends to
+    # the index_topk keys of largest score, the same for every attention head
+    # (all of them where it has no more). The choice carries no gradient and
+    # nothing here trains the indexer (models/heads.py `trainable_mask`). The
+    # cache keeps the index keys as a third leaf; a decode step gathers the
+    # chosen latent entries. 0 = none. Built for the static generate path,
+    # scoring and the train step, with a query bottleneck (q_lora_rank) and
+    # rotary positions without scaling; the engine, the paged pool, spec
+    # decode, the sp ring, packed segments and a looped stack refuse it.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # Expert layers: the router is n_experts wide whatever is held here.
     # experts_held = (first, count): the routed experts [first, first+count)
     # this program holds, one chip's share of an expert-parallel deployment;
@@ -368,6 +384,14 @@ class LMConfig:
                 raise ValueError("attention 'mla' is not built with kv_cache_quant, windowed layers or soft prompts")
         elif self.rope_scaling:
             raise ValueError("rope_scaling is built for attention 'mla' only")
+        index = (self.index_n_heads, self.index_head_dim, self.index_topk)
+        if max(index):
+            if (self.attention != "mla" or min(index) <= 0 or not self.q_lora_rank or self.pos_type != "rotary"
+                    or self.rope_scaling or self.index_head_dim < self.qk_rope_head_dim or self.has_state):
+                raise ValueError(
+                    "index_n_heads, index_head_dim and index_topk (all three) describe an indexer on attention 'mla' with a "
+                    "query bottleneck (q_lora_rank), pos_type 'rotary' without rope_scaling, index_head_dim at least "
+                    f"qk_rope_head_dim and no state layer in the stack: got {index} on attention {self.attention!r}")
         if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"rope_scaling type {self.rope_scaling.get('type')!r} is not built (only 'yarn')")
         if self.n_kv_head and self.n_kv_head != self.n_head:
@@ -824,6 +848,8 @@ def flash_eligible(cfg: LMConfig, q_len: int, has_cache: bool, prefill_at_zero: 
     if has_cache and not (q_len > 1 and prefill_at_zero):
         return False
     if cfg.attention == "sparse":  # its queries choose their key blocks: no band the kernels could take
+        return False
+    if cfg.index_topk and q_len > cfg.index_topk:  # an indexed latent layer: past index_topk tokens a query chooses its keys
         return False
     if cfg.attn_impl == "auto":
         from trlx_tpu.ops.flash_attention import auto_flash_ok, one_device_tpu
@@ -1290,7 +1316,7 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_bias, rope, cache=None, cache_index=None,
-                 flash_mask=None, window=0, use_ring=False, block_tables=None):
+                 flash_mask=None, window=0, use_ring=False, block_tables=None, token_mask=None):
         cfg = self.cfg
         if use_ring or block_tables is not None or window:
             raise NotImplementedError("attention 'mla' is not built for the sp ring, paged caches or windows")
@@ -1324,13 +1350,43 @@ class LatentAttention(nn.Module):
             q = jnp.einsum("btc,chn->bthn", c_q, w_qb)
             return q[..., :dn], rotate(q[..., dn:], rope)
 
-        new_cache = None
+        new_cache = stats = None
         if cache is not None:
             new_cache = (write_cache(cache[0], c_kv, cache_index), write_cache(cache[1], k_rope, cache_index))
         # Before the first decode step every cache slot beyond the block is
         # invalid, so a prefill at (static) offset 0 attends over its own block.
         at_zero = isinstance(cache_index, (int, np.integer)) and int(cache_index) == 0
-        if cache is None or at_zero:
+        # An indexed layer (`index_topk`, models/indexer.py): the index keys are
+        # the cache's third leaf; a pass or a cache longer than index_topk
+        # attends to the keys each query chose, a shorter one to every key, by
+        # the paths below.
+        # More than one token through a cache is the prefill at offset 0 (the
+        # trunk refuses every other such pass of an indexed layer), though a
+        # remat'd block sees its offset as a tracer.
+        many, chooses = cache is None or q_len > 1, False
+        if cfg.index_topk:
+            from trlx_tpu.models import indexer
+
+            with jax.named_scope("dsa_index"):
+                q_idx, k_idx, w_idx = indexer.Indexer(cfg, name="indexer")(x, c_q, rope)
+            if cache is not None:
+                new_cache += (write_cache(cache[2], k_idx, cache_index),)
+            chooses = (q_len if many else int(cache[0].shape[1])) > cfg.index_topk
+        if chooses and many:
+            q = jnp.concatenate(queries(c_q, rope), axis=-1)
+            kv = jnp.einsum("btc,chn->bthn", c_kv, w_kvb)
+            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope[:, :, None, :], (b, q_len, h, dr))], axis=-1)
+            mask = token_mask if token_mask is not None else jnp.ones((b, q_len), jnp.int32)
+            out, stats = indexer.indexed_attention(q, k, kv[..., dn:], q_idx, w_idx, k_idx, mask, cfg, softmax_scale, dtype)
+            out, stats = out.reshape(b, q_len, h * dv), stats if cache is None else None
+        elif chooses:
+            q_nope, q_rope = queries(c_q, rope)
+            with jax.named_scope("dsa_attn"):
+                q_lat = jnp.einsum("bqhn,chn->bqhc", q_nope, w_kvb[..., :dn], preferred_element_type=jnp.float32)
+            o_lat, stats = indexer.indexed_read(q_lat, q_rope, q_idx, w_idx, new_cache, token_mask, cfg, softmax_scale, dtype)
+            with jax.named_scope("dsa_attn"):
+                out = jnp.einsum("bqhc,chv->bqhv", o_lat, w_kvb[..., dn:]).reshape(b, q_len, h * dv)
+        elif cache is None or at_zero:
 
             def unabsorbed(c_q, c_kv, k_rope, rope, mask_or_bias):
                 """[rows, q_len, h * dv] from the rows' latents: everything per
@@ -1370,12 +1426,12 @@ class LatentAttention(nn.Module):
                 read = ranged_read(int(cache[0].shape[1]), q_len, cache_index,
                                    attend_range=attend_latent_range, slot_major=False)
                 if read is not None:
-                    o_lat = read((q_lat, q_rope), new_cache, attn_bias, softmax_scale, dtype)
+                    o_lat = read((q_lat, q_rope), new_cache[:2], attn_bias, softmax_scale, dtype)
                 else:
                     with jax.named_scope("kv_read"):
-                        o_lat = attend_latent(q_lat, q_rope, *new_cache, attn_bias, softmax_scale, dtype)
+                        o_lat = attend_latent(q_lat, q_rope, *new_cache[:2], attn_bias, softmax_scale, dtype)
                 out = jnp.einsum("bqhc,chv->bqhv", o_lat, w_kvb[..., dn:]).reshape(b, q_len, h * dv)
-        return dense(cfg.d_model, "c_proj")(out), new_cache
+        return dense(cfg.d_model, "c_proj")(out), new_cache, stats
 
 
 class MLP(nn.Module):
@@ -1422,7 +1478,9 @@ class Block(nn.Module):
     models/kda.py | "lightning": models/lightning.py; these keep a state for a
     cache and read `token_mask` [b, q_len], the real tokens of `x`, in place of
     a bias; attention "cca", models/cca.py, and "sparse", models/sparse.py, read
-    it beside the bias, "sparse" the cache's occupancy in a decode step). `router_state` [b, q_len,
+    it beside the bias, "sparse" the cache's occupancy in a decode step; an
+    "experts" feed-forward reads it so that a padded position takes no routed
+    expert, models/moe.py). `router_state` [b, q_len,
     router_hidden]: what the router of the block below handed on
     (`cfg.router_carry`). `rope`: the pass's rotary tables (`rope_tables`), None
     without rotary positions. Returns (x, cache, expert_counts, routing, sparse_stats): the tokens
@@ -1451,6 +1509,7 @@ class Block(nn.Module):
         x = hold_rows(x)
 
         def mix(h):
+            nonlocal sparse_stats
             if self.mixer == "mamba":
                 from trlx_tpu.models.ssm import SSMMixer
 
@@ -1468,7 +1527,6 @@ class Block(nn.Module):
 
                 if window or use_ring or block_tables is not None:
                     raise NotImplementedError("attention 'sparse' is not built for windows, the sp ring or paged caches")
-                nonlocal sparse_stats
                 out, new, sparse_stats = SparseAttention(cfg, name="attn")(h, cache, cache_index, token_mask)
                 return out, new
             if cfg.attention == "cca":
@@ -1477,8 +1535,11 @@ class Block(nn.Module):
                 if window or use_ring or block_tables is not None:
                     raise NotImplementedError("attention 'cca' is not built for windows, the sp ring or paged caches")
                 return CCAttention(cfg, name="attn")(h, attn_bias, rope, cache, cache_index, flash_mask, token_mask)
-            attn = (LatentAttention if cfg.attention == "mla" else Attention)(cfg, name="attn")
-            return attn(h, attn_bias, rope, cache, cache_index, flash_mask, window, use_ring, block_tables)
+            if cfg.attention == "mla":
+                out, new, sparse_stats = LatentAttention(cfg, name="attn")(
+                    h, attn_bias, rope, cache, cache_index, flash_mask, window, use_ring, block_tables, token_mask)
+                return out, new
+            return Attention(cfg, name="attn")(h, attn_bias, rope, cache, cache_index, flash_mask, window, use_ring, block_tables)
 
         moe = None
         if self.ffn == "experts":
@@ -1499,7 +1560,10 @@ class Block(nn.Module):
                     if cfg.router_carry or cfg.router_scoring == "softmax_all":
                         routing = {"state": state if cfg.router_carry else None,
                                    "top_weight": jnp.mean(jax.lax.stop_gradient(routed[1][:, 0]))}
-                h, counts = moe(h, routed=routed)
+                # a padded position takes no routed expert: `token_mask` where it is the pass's own [b, q_len] (a
+                # sparse or an indexed layer's decode step is handed the cache's occupancy in its place)
+                pads = token_mask if token_mask is not None and token_mask.shape == h.shape[:2] else None
+                h, counts = moe(h, routed=routed, token_mask=pads)
                 return h
             return MLP(cfg, name="mlp")(h)
 
@@ -1793,6 +1857,14 @@ class TransformerLM(nn.Module):
                 "attention 'sparse' takes a pass with no cache, a prefill at write offset 0 or one token a step for the "
                 "whole batch (the static generate path): its compressed keys lie in each row's own grid from the row's "
                 "first slot, so no block table, per-row offset, verify window or packed segments")
+        if cfg.index_topk and (segment_ids is not None or (cache is not None and (
+                block_tables is not None or jnp.ndim(cache_index) != 0 or (q_len > 1 and not prefill_at_zero)))):
+            raise NotImplementedError(
+                "an indexed latent layer (index_topk) takes a pass with no cache, a prefill at write offset 0 or one token a "
+                "step for the whole batch (the static generate path): its queries choose their keys by slot under one "
+                "causal edge, so no block table, per-row offset, verify window or packed segments")
+        # an indexed latent layer past index_topk tokens: its queries mask by the tokens' own mask and their choice
+        chooses = bool(cfg.index_topk) and q_len > cfg.index_topk
         if cfg.router_carry and (start_layer > 0) != (router_state is not None):
             raise ValueError("router_carry: a pass from start_layer > 0 (the frozen branch's replay) takes the router "
                              "state of the block below as `router_state`, a pass from the first block takes none")
@@ -1803,7 +1875,7 @@ class TransformerLM(nn.Module):
         if use_flash:
             attn_bias = local_bias = None
             flash_mask = attention_mask.astype(jnp.float32)
-        elif cfg.attention == "sparse":  # its layers mask by the tokens' own mask and their choice: no bias is read
+        elif cfg.attention == "sparse" or chooses:  # its layers mask by the tokens' own mask and their choice: no bias is read
             attn_bias = local_bias = flash_mask = None
             kv_mask = cache_mask if cache_mask is not None else attention_mask
         else:
@@ -1852,12 +1924,16 @@ class TransformerLM(nn.Module):
             # A block-selected sparse layer keeps the blocks its queries chose (a
             # few MB of bool): the choice carries no gradient, so the block's
             # recomputation need not make it again (models/sparse.py).
-            named = [name for name, held in ((KDA_SCAN_OUT, cfg.has_kda), (SPARSE_CHOSEN, cfg.attention == "sparse")) if held]
+            # An indexed latent layer keeps its queries' chosen keys likewise (models/indexer.py), and its
+            # long passes are held apart from the forward too: merged, the 8,192-token train step of five blocks
+            # at d 6144 kept the forward's projections alive and wanted 9.5 GB of temporaries (PERF.md §6, PR 53).
+            named = [name for name, held in ((KDA_SCAN_OUT, cfg.has_kda),
+                                             (SPARSE_CHOSEN, cfg.attention == "sparse" or chooses)) if held]
             if named:
                 kept = jax.checkpoint_policies.save_only_these_names(*named)
                 policy = kept if policy is None else jax.checkpoint_policies.save_from_both_policies(policy, kept)
             block_cls = nn.remat(
-                Block, prevent_cse=partitioned() or cfg.has_state or cfg.n_loops > 1, static_argnums=(7, 8), policy=policy
+                Block, prevent_cse=partitioned() or cfg.has_state or cfg.n_loops > 1 or chooses, static_argnums=(7, 8), policy=policy
             )
 
         looped = cfg.n_loops > 1
@@ -1898,9 +1974,11 @@ class TransformerLM(nn.Module):
                 layer_cache = cache[loop * cfg.n_layer + i] if cache is not None else None
                 window = layer_window(cfg, i)
                 layer_bias = local_bias if window else attn_bias
-                # a layer that keeps a state reads the tokens' mask itself, in place of a bias
-                token_mask = (attention_mask,) if cfg.mixer(i) != "attention" or cfg.attention in ("cca", "sparse") else ()
-                if cfg.attention == "sparse" and cfg.mixer(i) == "attention" and cache is not None and q_len == 1:
+                # a layer that keeps a state reads the tokens' mask itself, in place of a bias; an expert layer
+                # reads it to leave the pads unrouted (models/moe.py)
+                token_mask = (attention_mask,) if (cfg.mixer(i) != "attention" or cfg.attention in ("cca", "sparse")
+                                                   or cfg.index_topk or block.ffn == "experts") else ()
+                if (cfg.attention == "sparse" or cfg.index_topk) and cfg.mixer(i) == "attention" and cache is not None and q_len == 1:
                     token_mask = (kv_mask,)  # a decode step: the cache's occupancy tells each row's first slot
                 if cfg.router_carry:  # the block's last operand, after a token mask or None in its place
                     token_mask = (token_mask or (None,)) + (router_state,)
@@ -2062,9 +2140,12 @@ class TransformerLM(nn.Module):
             "router_top_weight": jnp.mean(jnp.stack(top_weights)) if top_weights else None,
             # attention "sparse", summed over its layers, float32. A pass with no cache: [4], (kept pairs,
             # causal pairs, chosen blocks, query-groups). A decode step: [2], (the share of its filled
-            # slots a step's softmax saw, summed over rows and K/V heads; their count)
-            "sparse_sums": sum(sparse_stats) if sparse_stats and cache is None else None,
+            # slots a step's softmax saw, summed over rows and K/V heads; their count); an indexed latent
+            # layer's decode step (`index_topk`) counts the same two of the slots it gathered, summed over rows
+            "sparse_sums": sum(sparse_stats) if sparse_stats and cache is None and not cfg.index_topk else None,
             "sparse_read": sum(sparse_stats) if sparse_stats and cache is not None else None,
+            # an indexed latent layer where it chooses, a pass with no cache: [2], (chosen pairs, causal pairs)
+            "dsa_sums": sum(sparse_stats) if sparse_stats and cache is None and cfg.index_topk else None,
             # [b, t, n_loops] float32: where the exit gate would leave the loop
             # (a pass with no cache over a gated looped stack); None otherwise.
             "exit_probs": exit_probs,
@@ -2102,7 +2183,8 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
     (k_i8, v_i8, k_scale, v_scale) with kv_cache_quant (scales [b, T,
     kv_heads]), T = `max_len`, but min(window_size, max_len) on a window layer
     under window_cache "ring"; "mla": per-layer (c_kv [b, T, kv_lora_rank],
-    k_rope [b, T, qk_rope_head_dim]), shared by all heads; a "mamba" layer
+    k_rope [b, T, qk_rope_head_dim]), shared by all heads, and with an indexer
+    (`index_topk`) its keys k_idx [b, T, index_head_dim]; a "mamba" layer
     (models/ssm.py): (conv [b, ssm_conv - 1, channels], state [b, ssm_heads,
     ssm_head_dim, ssm_state] float32), no slot axis whatever `max_len`; a
     "kda" layer (models/kda.py) likewise: (conv [b, kda_conv - 1, 3 x channels],
@@ -2131,7 +2213,8 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None):
 
             return tuple(jnp.zeros(shape, leaf_dtype) for shape, leaf_dtype in sparse.cache_shapes(cfg, batch, max_len))
         if cfg.attention == "mla":
-            return jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype), jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype)
+            widths = (cfg.kv_lora_rank, cfg.qk_rope_head_dim) + ((cfg.index_head_dim,) if cfg.index_topk else ())
+            return tuple(jnp.zeros((batch, max_len, width), dtype) for width in widths)
         if cfg.attention == "cca":
             from trlx_tpu.models import cca
 
@@ -2240,6 +2323,15 @@ def cca_state_bytes(cfg: LMConfig, batch: int) -> int:
     return tree_size_bytes([cache[i][2:] for i in range(cfg.n_layer) if cfg.mixer(i) == "attention"])
 
 
+def index_key_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
+    """The part of `cache_bytes` that the indexers' keys take (`index_topk`): the
+    counter `rollout/index_key_bytes`, from `init_cache`'s own shapes."""
+    if not cfg.index_topk:
+        return 0
+    cache = jax.eval_shape(lambda: init_cache(cfg, batch, max_len))
+    return tree_size_bytes([cache[i][2] for i in range(cfg.n_layer) if cfg.mixer(i) == "attention"])
+
+
 def compressed_key_bytes(cfg: LMConfig, batch: int, max_len: int) -> int:
     """The part of `cache_bytes` that "sparse" layers' compressed keys take: the
     counter `rollout/compressed_key_bytes`, from `init_cache`'s own shapes."""
@@ -2258,12 +2350,13 @@ def cache_bytes_per_token(cfg: LMConfig) -> int:
     (`rollout/cache_bytes` is the whole allocation). A state-space layer
     holds nothing a token: its state is `rollout/state_bytes_per_row`. A
     "sparse" layer's keys and values count; its compressed keys (one every
-    sparse_stride tokens) are `rollout/compressed_key_bytes`."""
+    sparse_stride tokens) are `rollout/compressed_key_bytes`. An indexed
+    latent layer's index keys count: one a token, like the latent."""
     return cache_bytes(cfg, 1, 1) - state_bytes(cfg, 1) - compressed_key_bytes(cfg, 1, 1)
 
 
 def decode_step_bytes(cfg: LMConfig, batch: int, keys_read: float, weight_bytes: int,
-                      stack_bytes: int = 0) -> Tuple[int, int]:
+                      stack_bytes: int = 0, cache_len: int = 0) -> Tuple[int, int]:
     """(bytes a decode step of the static generate path must move, the
     state's part of them), from shapes: the weights read once
     (`weight_bytes`), the state-space layers' state and window read AND
@@ -2271,9 +2364,12 @@ def decode_step_bytes(cfg: LMConfig, batch: int, keys_read: float, weight_bytes:
     (what the ranged read takes at that step). A looped stack reads its
     blocks' weights (`stack_bytes` of `weight_bytes`) once a LOOP: the stack
     does not stay on chip between loops; its keys are a (loop, layer) pair's,
-    which `cache_bytes_per_token` counts. The counters
+    which `cache_bytes_per_token` counts. An indexed latent layer
+    (`index_topk`) reads `keys_read` chosen latent entries and the index key
+    of every one of the cache's `cache_len` slots. The counters
     `rollout/step_bytes_needed`, `ssm/state_rw_share` (a "kda" stack:
     `kda/state_rw_share`) and `loops/weight_read_share`."""
     state = 2 * state_bytes(cfg, batch)
-    keys = int(keys_read * batch * cache_bytes_per_token(cfg))
+    index_keys = index_key_bytes(cfg, 1, 1)
+    keys = int(batch * (keys_read * (cache_bytes_per_token(cfg) - index_keys) + cache_len * index_keys))
     return weight_bytes + (cfg.n_loops - 1) * stack_bytes + state + keys, state

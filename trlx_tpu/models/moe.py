@@ -22,7 +22,9 @@ The layer is told which routed experts it holds (`LMConfig.experts_held`,
 and computes its own experts' part of the result. What the absent experts
 would add is left out; nothing here stands in for the other chips or their
 tokens, and on one chip the layer runs without its exchange. No token is
-dropped: there is no capacity limit. The balance of a trained router is the
+dropped: there is no capacity limit. A padded position (`token_mask`, the
+pass's own, which `models/lm.py Block` hands over) takes no routed expert and
+is in no count: pads are one token and would all choose the same experts. The balance of a trained router is the
 bias `b`, which no gradient moves (`models/heads.py` `trainable_mask`); no
 balance term joins a loss.
 
@@ -454,7 +456,7 @@ class ExpertLayer(nn.Module):
             routed = choose(logits, self.bias, cfg.experts_per_token, cfg.routed_scaling_factor, cfg.router_scoring)
         return routed, state.reshape(h.shape[:-1] + state.shape[-1:])
 
-    def __call__(self, h, router_input=None, routed=None):
+    def __call__(self, h, router_input=None, routed=None, token_mask=None):
         cfg = self.cfg
         dtype = cfg.compute_dtype
         b, t, d = h.shape
@@ -463,6 +465,12 @@ class ExpertLayer(nn.Module):
             routed, _ = self.routing_with_state(h, None)
         if routed is None:  # traced in the order it always was: the router, the stacks, the tokens
             routed = self._route(flat if router_input is None else self._flat(router_input))
+        if token_mask is not None:
+            # `token_mask` [b, t]: a padded position takes no routed expert (its ids name none) and is in no
+            # count. Pads are all one token, so they all choose the same experts: a row's 1,792 on one held expert
+            # passed the slot buffer and sent the call down `dense_held_ffn` (PERF.md section 6, PR 53)
+            ids, weights = routed
+            routed = jnp.where(token_mask.reshape(-1, 1) > 0, ids, cfg.n_experts), weights
         at_use = lambda w, name: use_weight(w.astype(dtype), self.path + (name,), b * t)
         gate, up, down = (at_use(getattr(self, name), name) for name in ("experts_gate", "experts_up", "experts_down"))
         with jax.named_scope("moe_experts"):

@@ -140,12 +140,17 @@ def choose_blocks(q, kc, t, cfg: LMConfig, n_blocks: int):
     return forced | (others & jnp.any(best[..., None] == blk, axis=-2))
 
 
-def query_chunk(cfg: LMConfig, b: int, T: int, H: int) -> int:
-    """Queries of one chunk of the many-token pass: a power of two of whole blocks whose float32 scores fit SCORE_BYTES."""
-    chunk = cfg.sparse_block
+def query_chunk_from(least: int, b: int, T: int, H: int) -> int:
+    """Queries of one chunk of a many-token pass: `least` doubled while the float32 scores [b, H, chunk, T] fit SCORE_BYTES."""
+    chunk = least
     while chunk * 2 <= min(T, 512) and b * H * chunk * 2 * T * 4 <= SCORE_BYTES:
         chunk *= 2
     return chunk
+
+
+def query_chunk(cfg: LMConfig, b: int, T: int, H: int) -> int:
+    """Queries of one chunk of the many-token pass: a power of two of whole blocks whose float32 scores fit SCORE_BYTES."""
+    return query_chunk_from(cfg.sparse_block, b, T, H)
 
 
 def span_edges(n_chunks: int):
@@ -153,6 +158,26 @@ def span_edges(n_chunks: int):
     chunks (the last may be shorter), a function of the pass's shapes alone."""
     per = -(-n_chunks // SPANS)
     return tuple((lo, min(lo + per, n_chunks)) for lo in range(0, n_chunks, per))
+
+
+def over_spans(xs, chunk: int, span_args, select, attend):
+    """The two maps of a many-token pass whose queries choose their keys (here and models/indexer.py). `xs`: arrays
+    [n_chunks, ...], one entry a query chunk, cut into `span_edges`' spans. A span's queries see no key at or past
+    its end, `extent`: `span_args(extent)` gives what its chunks read up to there, (select's, attend's). A span runs
+    `select(*chunk's xs, *select's)` over its chunks, which gives what they chose (no gradient; named SPARSE_CHOSEN,
+    so a remat'd block keeps it and a train step chooses once and not three times), then `attend(*chunk's xs,
+    chosen, *attend's)` -> (out, stats), each chunk recomputed in its own backward pass. Returns (the spans' outs,
+    the spans' stats), each [chunks of the span, ...]."""
+    outs, sums = [], []
+    for lo, hi in span_edges(xs[0].shape[0]):
+        span = tuple(x[lo:hi] for x in xs)
+        for_select, for_attend = span_args(hi * chunk)
+        chosen = jax.lax.map(lambda c: select(*c, *for_select), span)
+        chosen = checkpoint_name(chosen, SPARSE_CHOSEN)
+        out, stats = jax.lax.map(lambda c: jax.checkpoint(attend)(*c, *for_attend), (*span, chosen))
+        outs.append(out)
+        sums.append(stats)
+    return outs, sums
 
 
 def computed_pairs(cfg: LMConfig, b: int, T: int, H: int) -> int:
@@ -200,19 +225,12 @@ def sparse_attention(q, k, v, mask, cfg: LMConfig, dtype):
 
     chunks = jnp.moveaxis(q.reshape(b, Tp // chunk, chunk, H, D), 1, 0)
     starts = jnp.arange(Tp // chunk, dtype=jnp.int32) * chunk
-    outs, sums = [], []
-    for lo, hi in span_edges(Tp // chunk):
-        # a span's queries see no key at or past its end: every array of its chunks stops there, by static slices
-        extent = hi * chunk
-        span = (chunks[lo:hi], starts[lo:hi])
+    # a span's queries see no key at or past its end: every array of its chunks stops there, by static slices
+    def span_args(extent):
         k_s, v_s, kc_s = k[:, :extent], v[:, :extent], kc[:, :compressed_slots(cfg, extent)]
-        # the choice carries no gradient: made once, ahead of the chunks that are recomputed in their backward,
-        # and kept by a remat'd block (SPARSE_CHOSEN), so a train step chooses once and not three times
-        chosen = jax.lax.map(lambda xs: select(*xs, kc_s, extent // block), span)  # [chunks, b, G, chunk, extent / block]
-        chosen = checkpoint_name(chosen, SPARSE_CHOSEN)
-        out, stats = jax.lax.map(lambda xs: jax.checkpoint(attend)(*xs, k_s, v_s), (*span, chosen))
-        outs.append(out)
-        sums.append(stats)
+        return (kc_s, extent // block), (k_s, v_s)
+
+    outs, sums = over_spans((chunks, starts), chunk, span_args, select, attend)
     out = jnp.moveaxis(jnp.concatenate(outs), 0, 1).reshape(b, Tp, H, D)[:, :T]
     return align_rows(out, -first), kc, tuple(jnp.sum(jnp.concatenate(s)) for s in zip(*sums))
 

@@ -84,6 +84,9 @@ SCOPES = (
     "lightning_out",  # its output projection
     "sparse_select",  # attention "sparse": compressed keys, the scores over them, the pooled block scores and the choice
     "sparse_attn",  # attention "sparse": attention over the chosen blocks (many tokens: the masked pass; a decode step: the gather)
+    "dsa_index",  # an indexed latent layer (models/indexer.py): the index queries, keys and weights, the index scores
+    "dsa_select",  # its choice: the k-th largest score and the mask (many tokens), the top-k and the gather's slots (a decode step)
+    "dsa_attn",  # latent attention over the chosen keys (many tokens: the masked unabsorbed pass; a decode step: the gathered absorbed read)
     "loop_norm",  # a looped stack: the final norm at the end of every loop, the next loop's input
     "exit_gate",  # a looped stack: the exit gate on each loop's output and the exit distribution
     "lm_head",  # the vocabulary head in every form: fused log-probs, dense logits, ILQL's Q heads

@@ -165,10 +165,11 @@ def generate(
         # expert layers, summed over the steps: carried here and read once a
         # rollout (`rollout/experts_touched`), no sync a step.
         state["experts_touched"] = jnp.zeros((), jnp.float32)
-    if cfg.attention == "sparse":
+    if cfg.attention == "sparse" or (cfg.index_topk and T > cfg.index_topk):
         # The share of their filled slots the sparse layers' decode steps read,
         # summed over rows, K/V heads, layers and steps, and its count (the
         # steps' own `sparse_read`, models/sparse.py): carried like the experts'.
+        # An indexed latent layer's steps likewise (models/indexer.py).
         state["sparse_read"] = jnp.zeros((2,), jnp.float32)
     if step_stats_fn is not None:
         # eval_shape: discover the stat names/shapes without executing the fn.

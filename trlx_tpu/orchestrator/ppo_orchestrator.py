@@ -28,7 +28,7 @@ import numpy as np
 
 from trlx_tpu.observability.spans import trace_span
 from trlx_tpu.models.lm import (cache_bytes, cache_bytes_per_token, cca_state_bytes, compressed_key_bytes, decode_step_bytes,
-                                layer_window, ring_cache_bytes, ring_slots, state_bytes)
+                                index_key_bytes, layer_window, ring_cache_bytes, ring_slots, state_bytes)
 from trlx_tpu.ops.kv_read import kv_keys_read, kv_scale_mults_per_key
 from trlx_tpu.parallel.schedule import weight_gather_share
 from trlx_tpu.orchestrator import Orchestrator, register_orchestrator
@@ -269,7 +269,7 @@ class PPOOrchestrator(Orchestrator):
         layer_windows = [layer_window(lm_cfg, i) for i in key_layers]
         cache_alloc = gen_rows = gen_len = 0  # bytes of the cache the generate program allocated (the last chunk's), its rows and slots
         kv_keys = np.zeros(2, dtype=np.int64)
-        sparse_read = []  # attention "sparse": the decode steps' own count of the slots they read, one reading a chunk
+        sparse_read = []  # attention "sparse" or an indexed latent layer: the decode steps' own count of the slots they read, one reading a chunk
         experts_touched = []  # a model with expert layers: one reading a chunk
         # Final-chunk stats for logging; placeholders are never logged (the
         # aborted path returns before the tracker call).
@@ -424,6 +424,9 @@ class PPOOrchestrator(Orchestrator):
                     # every cell's; what the step's softmax saw of them is the loop's own counter, below
                     gathered = sparse.gathered_blocks(lm_cfg, -(-cache_len // lm_cfg.sparse_block)) * lm_cfg.sparse_block
                     kv_keys += np.array([gathered, cache_len]) * ds["decode_steps"] * len(key_layers)
+                elif lm_cfg.index_topk and cache_len > lm_cfg.index_topk:
+                    # an indexed latent layer's step gathers index_topk entries (models/indexer.py indexed_read)
+                    kv_keys += np.array([lm_cfg.index_topk, cache_len]) * ds["decode_steps"] * len(key_layers)
                 else:
                     kv_keys += np.array(kv_keys_read(
                         cache_len, P + n_soft, ds["decode_steps"], layer_windows,
@@ -574,9 +577,13 @@ class PPOOrchestrator(Orchestrator):
                 rl._last_exp_stats["rollout/compressed_key_bytes"] = float(compressed_key_bytes(lm_cfg, gen_rows, gen_len))
                 if sparse_read:
                     rl._last_exp_stats["rollout/sparse_keys_read_share"] = float(np.mean(sparse_read))
+            if lm_cfg.index_topk and cache_alloc:
+                rl._last_exp_stats["rollout/index_key_bytes"] = float(index_key_bytes(lm_cfg, gen_rows, gen_len))
+                if sparse_read:
+                    rl._last_exp_stats["rollout/dsa_keys_read_share"] = float(np.mean(sparse_read))
             if experts_touched:
                 rl._last_exp_stats["rollout/experts_touched"] = float(np.mean(experts_touched))
-            if (lm_cfg.has_state or lm_cfg.n_loops > 1) and cache_alloc:
+            if (lm_cfg.has_state or lm_cfg.n_loops > 1 or lm_cfg.index_topk) and cache_alloc:
                 # What a decode step must move, from shapes: the weights once (a
                 # looped stack's blocks once a loop), the state read and written,
                 # the keys the ranged read took (the mean over the rollout's steps).
@@ -588,7 +595,7 @@ class PPOOrchestrator(Orchestrator):
                 # a decode step leaves the exit gate out
                 read_once = tree_size_bytes({k: v for k, v in trunk.items()
                                              if k != "exit_gate" and (k != "wte" or lm_cfg.tie_word_embeddings)})
-                needed, state_rw = decode_step_bytes(lm_cfg, gen_rows, keys_a_step, read_once, stack)
+                needed, state_rw = decode_step_bytes(lm_cfg, gen_rows, keys_a_step, read_once, stack, cache_len=gen_len)
                 rl._last_exp_stats["rollout/step_bytes_needed"] = float(needed)
                 if lm_cfg.has_state:
                     rl._last_exp_stats.update({

@@ -113,11 +113,11 @@ class PPOTrainer(JaxBaseTrainer):
         # fused-logprob head work; see make_ppo_train_step).
         self._pack_train_batch = bool(getattr(m, "pack_train_batch", False))
         if self._pack_train_batch and (self.model.cfg.n_loops > 1 or self.model.cfg.has_kda or self.model.cfg.has_lightning
-                                       or self.model.cfg.attention == "sparse"):
+                                       or self.model.cfg.attention == "sparse" or self.model.cfg.index_topk):
             raise NotImplementedError(
                 "method.pack_train_batch (packed segments) is not built for a looped stack (n_loops > 1), a kda layer or a "
-                "lightning layer (a state crosses a segment's edge) or attention 'sparse' (its block grid starts at a "
-                "row's first token)")
+                "lightning layer (a state crosses a segment's edge), attention 'sparse' (its block grid starts at a "
+                "row's first token) or an indexed latent layer (index_topk: its queries choose among a row's keys)")
         # put_batch shards the leading dim over DATA_AXES — packed row-count
         # buckets must round up to a multiple of that axis product.
         self._pack_rows_multiple = int(np.prod([self.mesh.shape[a] for a in DATA_AXES]))
@@ -1183,7 +1183,8 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
         pass through the flash kernels: the live key chunks the batch's own
         padding `attention_mask` takes out (`flash/pad_dead_chunk_share`).
         Attention "sparse": `sparse/kept_pair_share`, `sparse/chosen_blocks_mean`,
-        `sparse/computed_pair_share`."""
+        `sparse/computed_pair_share`; an indexed latent layer (`index_topk`):
+        `dsa/kept_pair_share`."""
         share = None if attention_mask is None else flash_pad_dead_chunk_share(model.cfg, attention_mask)
         if share is not None:
             loss, stats = result
@@ -1194,6 +1195,11 @@ def make_ppo_loss_fn(model, config, prompt_length, detach_frozen):
             weight = exit_mask.astype(p.dtype)
             loss, stats = result
             result = loss, {**stats, "policy/expected_exit_loop": jnp.sum(loops * weight) / jnp.maximum(jnp.sum(weight), 1.0)}
+        if out.get("dsa_sums") is not None:
+            # an indexed latent layer: of the causal pairs, those the queries chose, from the choice the pass made
+            kept, causal = jax.lax.stop_gradient(out["dsa_sums"])
+            loss, stats = result
+            result = loss, {**stats, "dsa/kept_pair_share": kept / jnp.maximum(causal, 1.0)}
         if out.get("sparse_sums") is not None:
             # attention "sparse": of the causal pairs, those in blocks the queries chose, and the blocks a
             # query's group chose, both from the choice the pass made (models/sparse.py), no gradient
