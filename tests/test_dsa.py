@@ -21,9 +21,9 @@ import pytest
 from benchmark.references import dsa_mla_moe_decoder as reference
 from trlx_tpu.models import indexer, moe, sparse
 from trlx_tpu.models.heads import LMWithValueHead, extract_branch_params, trainable_mask
-from trlx_tpu.models.lm import (LatentAttention, LMConfig, TransformerLM, cache_bytes, cache_bytes_per_token,
-                                cache_partition_spec, decode_step_bytes, flash_eligible, index_key_bytes, init_cache,
-                                init_paged_cache, rope_tables)
+from trlx_tpu.models.lm import (SPAN_PASS_OUT, LatentAttention, LMConfig, TransformerLM, cache_bytes,
+                                cache_bytes_per_token, cache_partition_spec, decode_step_bytes, flash_eligible,
+                                index_key_bytes, init_cache, init_paged_cache, rope_tables)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 GLM = json.load(open(os.path.join(os.path.dirname(HERE), "benchmark", "configs", "glm-5-ep32-tp4-l5.json")))
@@ -345,6 +345,29 @@ def test_the_train_pass_stops_at_each_spans_causal_extent_and_chooses_once():
     tokens = jax.ShapeDtypeStruct((b, length), jnp.int32)
     names = [eqn.primitive.name for eqn in _eqns(jax.make_jaxpr(jax.grad(loss))(params, tokens, tokens).jaxpr)]
     assert names.count("cumsum") == 2 * chooses + 1 and "pallas_call" not in names  # two layers, once each; and the positions from the mask
+
+
+def test_a_remat_d_block_keeps_the_pass_s_output_by_name(monkeypatch):
+    """As attention "sparse" (tests/test_sala.py): a remat'd block holds the pass's joined output, so a span's `attend`
+    runs forward twice a step in each indexed layer and not three times (the softmax's `exp` counts its runs), and the
+    loss and every gradient are, bit for bit, those of a block that keeps the choice alone."""
+    cfg, model, params, ids, mask = _model(arch=TWO, remat=True, length=128)
+    monkeypatch.setattr(indexer, "MIN_CHUNK", 16)
+    monkeypatch.setattr(sparse, "SCORE_BYTES", 1)  # chunks of 16: eight a row
+    monkeypatch.setattr(sparse, "SPANS", 2)
+
+    def step(kept):
+        monkeypatch.setattr(indexer, "SPAN_PASS_OUT", SPAN_PASS_OUT if kept else "kept by no policy")
+        loss = lambda p: jnp.sum(jnp.sin(model.apply({"params": p}, ids, mask)["logits"][:, PAD:]))
+        traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+        softmaxes = sum(eqn.primitive.name == "exp" and "dsa_attn" in str(eqn.source_info.name_stack) for eqn in _eqns(traced.jaxpr.jaxpr))
+        return softmaxes, traced.lower().compile()(params)
+
+    (passes, (loss, grads)), (passes_before, (loss_before, grads_before)) = step(True), step(False)
+    assert (passes, passes_before) == (2 * 2 * cfg.n_layer, 3 * 2 * cfg.n_layer)  # two spans a layer
+    assert float(loss) == float(loss_before)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(grads_before)):
+        np.testing.assert_array_equal(g, w, err_msg=jax.tree_util.keystr(path))
 
 
 # ---- the deployment's shares -----------------------------------------------------------------------

@@ -22,8 +22,9 @@ from benchmark.counts import sala as counts
 from benchmark.references import sala_decoder as reference
 from trlx_tpu.models import lightning, sparse
 from trlx_tpu.models.heads import LMWithValueHead, extract_branch_params
-from trlx_tpu.models.lm import (LMConfig, TransformerLM, cache_bytes, cache_bytes_per_token, cache_partition_spec,
-                                compressed_key_bytes, flash_eligible, init_cache, init_paged_cache, state_bytes)
+from trlx_tpu.models.lm import (KDA_SCAN_OUT, SPAN_PASS_OUT, SPARSE_CHOSEN, LMConfig, TransformerLM, cache_bytes,
+                                cache_bytes_per_token, cache_partition_spec, compressed_key_bytes, flash_eligible,
+                                init_cache, init_paged_cache, state_bytes)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SALA = json.load(open(os.path.join(os.path.dirname(HERE), "benchmark", "configs", "minicpm-sala-9b-l4.json")))
@@ -385,6 +386,59 @@ def test_the_train_pass_stops_at_each_spans_causal_extent_and_chooses_once():
     tokens = jax.ShapeDtypeStruct((b, length), jnp.int32)
     names = [eqn.primitive.name for eqn in _eqns(jax.make_jaxpr(jax.grad(loss))(params, tokens, tokens).jaxpr)]
     assert names.count("top_k") == 6 and "pallas_call" not in names
+
+
+def test_a_remat_d_block_keeps_the_pass_s_output_by_name(monkeypatch):
+    """A trained remat'd block holds the pass's joined output (`SPAN_PASS_OUT`), so its recomputation does not run the
+    spans' forward loops again: a span's `attend` runs forward twice a step (the forward, its chunks' own backward)
+    and not three times (the softmax's `exp` counts its runs), and the loss and every gradient are, bit for bit, those
+    of a block that keeps the choice alone."""
+    cfg, model, params, ids, mask = _model(remat=True, length=128)
+    monkeypatch.setattr(sparse, "SCORE_BYTES", 1)  # chunks of one block: 16 a row
+    monkeypatch.setattr(sparse, "SPANS", 2)
+    assert len(sparse.span_edges(128 // sparse.query_chunk(cfg, B, 128, cfg.n_head))) == 2
+
+    def step(kept):
+        monkeypatch.setattr(sparse, "SPAN_PASS_OUT", SPAN_PASS_OUT if kept else "kept by no policy")
+        loss = lambda p: jnp.sum(jnp.sin(model.apply({"params": p}, ids, mask)["logits"][:, PAD:]))
+        traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+        softmaxes = sum(eqn.primitive.name == "exp" and "sparse_attn" in str(eqn.source_info.name_stack) for eqn in _eqns(traced.jaxpr.jaxpr))
+        return softmaxes, traced.lower().compile()(params)
+
+    (passes, (loss, grads)), (passes_before, (loss_before, grads_before)) = step(True), step(False)
+    assert (passes, passes_before) == (2 * 2, 3 * 2)  # two spans of one sparse layer
+    assert float(loss) == float(loss_before)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(grads_before)):
+        np.testing.assert_array_equal(g, w, err_msg=jax.tree_util.keystr(path))
+        assert "sparse" not in jax.tree_util.keystr(path) or float(jnp.abs(g).max()) > 0
+
+
+@pytest.mark.parametrize("config, named", [
+    ("gptj-6b-l8", []), ("kimi-linear-48b-ep32-l13", [KDA_SCAN_OUT]),
+    ("minicpm-sala-9b-l4", [SPARSE_CHOSEN, SPAN_PASS_OUT]), ("glm-5-ep32-tp4-l5", [SPARSE_CHOSEN, SPAN_PASS_OUT])],
+    ids=lambda value: value if isinstance(value, str) else "")
+def test_what_a_remat_d_block_keeps_by_name(monkeypatch, config, named):
+    """The names a cell's remat policy keeps, at its published widths and as served (abstract; the trace stops where
+    the trunk wraps its blocks): none in a GPT cell, the delta-rule pass's output in Kimi-Linear's as before, and the
+    choice with the pass's joined output where a layer's queries choose their keys."""
+    import flax.linen as nn
+
+    spec = json.load(open(os.path.join(os.path.dirname(HERE), "benchmark", "configs", f"{config}.json")))
+    cfg = LMConfig.from_dict({**spec["model_arch"], **{k: spec["serving"][k] for k in ("dtype", "param_dtype", "remat")}})
+    assert cfg.remat
+
+    class Wrapped(Exception):
+        pass
+
+    def remat(block, policy=None, **_):
+        raise Wrapped(policy)
+
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names", lambda *names: list(names))
+    monkeypatch.setattr(nn, "remat", remat)
+    tokens = jnp.zeros((1, 4096), jnp.int32)  # past GLM-5's index_topk: its queries choose
+    with pytest.raises(Wrapped) as wrapped:
+        jax.eval_shape(lambda: TransformerLM(cfg).init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens)))
+    assert wrapped.value.args == (named or None,)
 
 
 # ---- the frozen branch, the counts, the configuration ----------------------------------------------

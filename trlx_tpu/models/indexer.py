@@ -27,10 +27,11 @@ takes `LatentAttention`'s own path): by slot, the query chunks of
 `models/sparse.py` in its spans (`over_spans`): steps 4-5 a chunk give the
 chosen keys as a mask (no gradient; a remat'd block keeps it by name), then
 UNABSORBED latent attention a chunk with the mask on the scores, each chunk
-recomputed in its own backward pass. Every pair of a chunk up to its span's end
-is arithmetic, chosen or not. The k-th largest score is found by bisection over
-the scores' bits (32 counts a chunk), so the choice is a mask from the start
-and no index is scattered.
+recomputed in its own backward pass; the joined output is kept by name too, so
+the block's recomputation does not run the pass again. Every pair of a chunk up
+to its span's end is arithmetic, chosen or not. The k-th largest score is found
+by bisection over the scores' bits (32 counts a chunk), so the choice is a mask
+from the start and no index is scattered.
 *One token* (a decode step over a cache longer than index_topk): I_t over the
 row's index keys, `lax.top_k`, a gather of the chosen (c_kv, k_rope) entries,
 the absorbed read over the gathered [b, index_topk, ...]: sparse in bytes.
@@ -42,8 +43,9 @@ qk_rope_head_dim], k_idx [b, T, index_head_dim])`.
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from trlx_tpu.models.lm import LMConfig, QDense, apply_rotary
+from trlx_tpu.models.lm import SPAN_PASS_OUT, LMConfig, QDense, apply_rotary
 from trlx_tpu.models.sparse import over_spans, query_chunk_from
 from trlx_tpu.ops.kv_read import attend_latent
 
@@ -160,7 +162,7 @@ def indexed_attention(q, k, v, q_idx, w, k_idx, mask, cfg: LMConfig, scale, dtyp
     span_args = lambda extent: ((k_idx[:, :extent], real[:, :extent]), (k[:, :extent], v[:, :extent], real[:, :extent]))
     outs, sums = over_spans(xs, chunk, span_args, select, attend)
     out = jnp.moveaxis(jnp.concatenate(outs), 0, 1).reshape(b, Tp, h, -1)[:, :T]
-    return out, tuple(jnp.sum(jnp.concatenate(s)) for s in zip(*sums))
+    return checkpoint_name(out, SPAN_PASS_OUT), tuple(jnp.sum(jnp.concatenate(s)) for s in zip(*sums))
 
 
 def choose_slots(q_idx, w, k_idx, filled, topk: int):

@@ -55,6 +55,8 @@ ARCH_KEYS_READ_ELSEWHERE = frozenset({"eos_token_id"})
 KDA_SCAN_OUT = "kda_scan_out"
 # models/sparse.py names the blocks its many-token pass chose so; a remat'd block keeps them likewise
 SPARSE_CHOSEN = "sparse_chosen"
+# models/sparse.py and models/indexer.py name the joined output of that pass so; a remat'd block keeps it likewise
+SPAN_PASS_OUT = "span_pass_out"
 
 
 @dataclass(frozen=True)
@@ -1927,8 +1929,13 @@ class TransformerLM(nn.Module):
             # An indexed latent layer keeps its queries' chosen keys likewise (models/indexer.py), and its
             # long passes are held apart from the forward too: merged, the 8,192-token train step of five blocks
             # at d 6144 kept the forward's projections alive and wanted 9.5 GB of temporaries (PERF.md §6, PR 53).
-            named = [name for name, held in ((KDA_SCAN_OUT, cfg.has_kda),
-                                             (SPARSE_CHOSEN, cfg.attention == "sparse" or chooses)) if held]
+            # Both keep the joined output of their many-token pass too, ONE array a layer in the model's dtype
+            # ([b, T, H, D]: 101 MB at MiniCPM-SALA's 12,288 tokens, 67 MB at GLM-5's 8,192): it is all the block's
+            # recomputation wants of the spans' forward loops, which then fall out of it. A chunk's float32 scores
+            # are still made again in the chunk's own backward pass, where they are read (models/sparse.py
+            # `over_spans`): the pass runs forward twice a step, not three times (PERF.md §6, PR 54).
+            span_pass = cfg.attention == "sparse" or chooses
+            named = [name for name, held in ((KDA_SCAN_OUT, cfg.has_kda), (SPARSE_CHOSEN, span_pass), (SPAN_PASS_OUT, span_pass)) if held]
             if named:
                 kept = jax.checkpoint_policies.save_only_these_names(*named)
                 policy = kept if policy is None else jax.checkpoint_policies.save_from_both_policies(policy, kept)

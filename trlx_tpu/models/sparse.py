@@ -41,9 +41,12 @@ that wide: with S equal spans (S + 1) / 2S of the chunks-by-all-keys pairs,
 the chosen sets (no gradient; a remat'd block keeps them by name, so a train
 step chooses once), then step 6, each chunk recomputed in its own backward
 pass (`jax.checkpoint`) with its chosen sets as an argument, applied to the
-scores as a mask. Within a span every pair up to its end is computed: what is
-masked there (the triangle above the diagonal inside the span, the unchosen
-fifth of the causal pairs) is still arithmetic; `computed_pairs` counts it.
+scores as a mask. A remat'd block keeps the joined output by name as well, so
+a train step runs step 6 forward twice (the forward, the chunks' backward) and
+not a third time in the block's recomputation. Within a span every pair up to
+its end is computed: what is masked there (the triangle above the diagonal
+inside the span, the unchosen fifth of the causal pairs) is still arithmetic;
+`computed_pairs` counts it.
 *One token* (a decode step): the scores
 over the cache's compressed keys, the choice, a gather of the chosen blocks
 (`ops/kv_read.py attend_selected`): sparse in bytes. The step counts the
@@ -63,7 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from trlx_tpu.models.lm import SPARSE_CHOSEN, LMConfig, QDense, qk_normed, write_cache
+from trlx_tpu.models.lm import SPAN_PASS_OUT, SPARSE_CHOSEN, LMConfig, QDense, qk_normed, write_cache
 from trlx_tpu.ops.kv_read import attend_selected
 
 # Bytes of one query chunk's float32 scores [b, H, chunk, T] in the many-token pass
@@ -167,7 +170,8 @@ def over_spans(xs, chunk: int, span_args, select, attend):
     `select(*chunk's xs, *select's)` over its chunks, which gives what they chose (no gradient; named SPARSE_CHOSEN,
     so a remat'd block keeps it and a train step chooses once and not three times), then `attend(*chunk's xs,
     chosen, *attend's)` -> (out, stats), each chunk recomputed in its own backward pass. Returns (the spans' outs,
-    the spans' stats), each [chunks of the span, ...]."""
+    the spans' stats), each [chunks of the span, ...]. The callers join the outs into the rows' own order and name
+    that ONE array SPAN_PASS_OUT: a remat'd block keeps it, so its recomputation does not run these loops again."""
     outs, sums = [], []
     for lo, hi in span_edges(xs[0].shape[0]):
         span = tuple(x[lo:hi] for x in xs)
@@ -232,7 +236,7 @@ def sparse_attention(q, k, v, mask, cfg: LMConfig, dtype):
 
     outs, sums = over_spans((chunks, starts), chunk, span_args, select, attend)
     out = jnp.moveaxis(jnp.concatenate(outs), 0, 1).reshape(b, Tp, H, D)[:, :T]
-    return align_rows(out, -first), kc, tuple(jnp.sum(jnp.concatenate(s)) for s in zip(*sums))
+    return checkpoint_name(align_rows(out, -first), SPAN_PASS_OUT), kc, tuple(jnp.sum(jnp.concatenate(s)) for s in zip(*sums))
 
 
 class SparseAttention(nn.Module):
